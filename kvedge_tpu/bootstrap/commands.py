@@ -92,7 +92,11 @@ def cmd_runtime_boot(argv: list[str], root: str) -> None:
     parser.add_argument("--config", required=True)
     parser.add_argument("--once", action="store_true")
     args = parser.parse_args(argv)
-    boot.boot(config_path=rebase(args.config, root), once=args.once, root=root)
+    try:
+        boot.boot(config_path=rebase(args.config, root), once=args.once,
+                  root=root)
+    except boot.DegradedBoot as e:
+        raise CommandError(f"runtime booted degraded: {e}") from e
 
 
 _BOOTSTRAP_COMMANDS = {"locate": cmd_locate, "apply": cmd_apply}

@@ -111,9 +111,8 @@ def main(argv: list[str] | None = None) -> int:
     if forced:
         # Test/local-verification knob: run the whole boot against an
         # n-device virtual CPU mesh. Must happen here — before any boot
-        # command can touch a JAX backend — because environments that
-        # preload jax pointed at real hardware ignore inherited env vars
-        # alone (see kvedge_tpu/testing/jaxenv.py).
+        # command can touch a JAX backend (env vars and jax.config
+        # both: see kvedge_tpu/testing/jaxenv.py).
         from kvedge_tpu.testing.jaxenv import force_virtual_cpu_devices
 
         force_virtual_cpu_devices(int(forced))

@@ -20,10 +20,7 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
-try:
-    import tomllib
-except ModuleNotFoundError:  # python < 3.11: same API under the old name
-    import tomli as tomllib  # type: ignore[no-redef]
+import tomllib
 from typing import Mapping
 
 
@@ -108,7 +105,7 @@ _VALID_PRESETS = ("", "probe", "flagship")
 
 def _parse_speculative(value):
     """``serving_speculative``: an int draft length or the string
-    "auto" (resolved at serve boot by the relay-economics probe,
+    "auto" (resolved at serve boot by the spec-economics probe,
     models/serving.py resolve_speculation). Type errors surface in
     validate() with the full accepted-values message."""
     if isinstance(value, str):
@@ -361,10 +358,10 @@ class RuntimeConfig:
     # Device-side decode window cap for the paged backend: up to this
     # many greedy steps run in ONE dispatched scan (one host round trip
     # per window instead of per token — the knob that decouples decode
-    # throughput from the relay RTT). Compiled programs stay the powers
-    # of two {2..serving_window}. Tradeoff: a new request joins at the
-    # next window boundary, so admission latency grows with the window
-    # (SERVING.md's performance model). 1 = per-step dispatch. "auto"
+    # throughput from that round trip). Compiled programs stay the
+    # powers of two {2..serving_window}. Tradeoff: a new request joins
+    # at the next window boundary, so admission latency grows with the
+    # window (SERVING.md's performance model). 1 = per-step dispatch. "auto"
     # hands the choice to the online controller (SERVING.md rung 26):
     # every harvested window feeds EWMAs of the measured host
     # turnaround R and per-step device time t, and the next window is
@@ -397,8 +394,9 @@ class RuntimeConfig:
     # SPEC_CROSSOVER_r04.json for the model-size crossover. GREEDY
     # requests' page budgets grow by K slack positions (sampled ones
     # can never accept a draft and reserve nothing extra). "auto"
-    # probes the relay at serve boot (draft length 4) and turns
-    # speculation off when windowed decode dominates its best case;
+    # probes verify-pass and window cost at serve boot (draft length
+    # 4) and turns speculation off when windowed decode dominates its
+    # best case;
     # an explicit K keeps the operator's choice but logs a loud
     # warning under the same test (single-host serve only).
     serving_speculative: int | str = 0
@@ -407,11 +405,10 @@ class RuntimeConfig:
     # — the n-gram drafting, accept/reject, KV commits, budget
     # freezing, and the pending-token chain all run in the scan, so
     # the host round trip amortizes over up to W*(1+K) tokens instead
-    # of taxing every pass (the r05 paged-spec soft spot: 69.5 tok/s
-    # vs 1803 plain paged, one RTT per pass). Requires
-    # serving_speculative > 0 and the overlapped loop; an all-greedy
-    # batch rides windows. Token streams are bit-identical either
-    # way. 0 = off (legacy per-pass speculation).
+    # of taxing every pass (one round trip per pass was the paged-spec
+    # soft spot). Requires serving_speculative > 0 and the overlapped
+    # loop; an all-greedy batch rides windows. Token streams are
+    # bit-identical either way. 0 = off (legacy per-pass speculation).
     serving_spec_window: int = 0
     # Rung 23: keep mixed greedy+sampled batches on the windowed spec
     # path (sampled rows draw their next token on device, exact key

@@ -84,19 +84,14 @@ def _load_native():
             # dependency-checked (a no-op when current), and skipping it
             # would load a STALE library after an in-place source update —
             # dlopen caches by path, so a missing symbol discovered at
-            # bind time is too late to rebuild. Environments without a
-            # toolchain but with a prebuilt, current .so (the runtime
-            # image) still load it: a failed make only raises when no
-            # library exists at all.
-            try:
-                # locklint: allow[io-under-lock] one-time lazy init — the module lock exists precisely to serialize the native build+dlopen; waiters need the finished library anyway, and no request-path lock is held here
-                subprocess.run(
-                    ["make", "-C", str(_NATIVE_DIR)],
-                    check=True, capture_output=True,
-                )
-            except (OSError, subprocess.SubprocessError):
-                if not _LIB_PATH.exists():
-                    raise
+            # bind time is too late to rebuild. A library `make` could
+            # not rebuild is never loaded: native/build/ is git-ignored,
+            # so a leftover .so says nothing about the committed sources.
+            # locklint: allow[io-under-lock] one-time lazy init — the module lock exists precisely to serialize the native build+dlopen; waiters need the finished library anyway, and no request-path lock is held here
+            subprocess.run(
+                ["make", "-C", str(_NATIVE_DIR)],
+                check=True, capture_output=True,
+            )
             lib = ctypes.CDLL(str(_LIB_PATH))
             # Symbol binding stays inside the try: a prebuilt library from
             # an older source revision lacks newer symbols, and that must
@@ -126,7 +121,7 @@ def _load_native():
                 f"fallback ({type(e).__name__}{detail})",
                 RuntimeWarning, stacklevel=3,
             )
-            _lib = False  # cached negative: no toolchain / no / stale lib
+            _lib = False  # cached negative: no toolchain / broken build
             return None
         _lib = lib
         return lib

@@ -108,11 +108,22 @@ def _use_paged_kernel(cfg: TransformerConfig, page_size: int,
     XLA's bulk gather), kv_heads*d_head % 128 == 0 (TPU DMA lane
     alignment; MHA at one kv head takes the gather), and the two-phase
     kernel's VMEM scratch fitting the budget (over-cap pools route to
-    the gather). The kernel is BIT-IDENTICAL to the gather — it stages
-    the gather's own rounded score rows and runs the same softmax +
-    flat V contraction (pinned exactly in tests/test_paged_attention
-    .py) — so "auto" is a pure routing choice, never a numerics one;
-    short-context pools keep the gather only because the kernel's DMA
+    the gather). The kernel stages the gather's own rounded score rows
+    and runs the same softmax and one flat V contraction, so its
+    scores and weights are the gather's bit for bit on any backend;
+    the V contraction is the same products summed in fp32, but as one
+    [H, S] x [S, K*Dh] dot where the gather has a [G, S] x [S, Dh] dot
+    per kv head, and a backend may order those two sums differently.
+    Compiled for the chip it does not: chip_smoke.py FAILS unless, on
+    the TPU, the kernel's outputs at the 209M widths equal the gather's
+    in every bit (live lengths 127/128/129/2047) and an "auto" and a
+    "gather" server return the same greedy streams — so there "auto"
+    is a routing choice, not a numerics one, for as long as that check
+    passes (TPU v5 lite, PR 21: 0 of 4,096 outputs differ). XLA:CPU
+    does order them differently: under the interpreter one output of
+    1,536, a sum that cancels to 4e-6, lands one bf16 step from the
+    gather's in one pinned case (ROADMAP D6).
+    Short-context pools keep the gather only because the kernel's DMA
     loop has nothing to win there. Either choice can be forced with
     "kernel"/"gather"; cfg is a static jit argument, so changing the
     choice retraces rather than silently reusing a cached program.
@@ -120,7 +131,10 @@ def _use_paged_kernel(cfg: TransformerConfig, page_size: int,
     partitioning rule, so tracing it over a sharded pool would poison
     the first decode step on a real slice — SlicePagedKVCache
     additionally pins its cfg to "gather" so even a forced "kernel"
-    cannot reach a sharded trace."""
+    cannot reach a sharded trace. What this function cannot see at
+    trace time — one process whose arrays span several chips — is
+    settled before the pool is built, by
+    :func:`settle_paged_attention`."""
     if cfg.paged_attention == "kernel":
         return True
     if cfg.paged_attention == "gather":
@@ -136,6 +150,40 @@ def _use_paged_kernel(cfg: TransformerConfig, page_size: int,
             and width % 128 == 0
             and decode_scratch_fits_vmem(
                 max_pages, page_size, width, cfg.n_heads))
+
+
+def settle_paged_attention(cfg: TransformerConfig,
+                           params) -> TransformerConfig:
+    """``cfg`` as a pool over ``params`` may trace it: the half of the
+    kernel-or-gather decision that depends on where arrays live, which
+    :func:`_use_paged_kernel` cannot observe under jit.
+
+    Mosaic lowers a kernel only into a program for ONE device. A jitted
+    decode program runs on every device its arguments are placed on,
+    sharded or merely replicated alike, and for more than one the
+    lowering refuses: "Mosaic kernels cannot be automatically
+    partitioned" (tests/test_chip_compile.py compiles both placements
+    for a described v5e:2x2 and gets exactly that). So over params that
+    span several devices "auto" means the gather, and a forced "kernel"
+    is refused here, at construction, like the forced kernel whose
+    scales do not fit (PagedKVCache.__init__) — not downgraded. The
+    repair that would let the kernel run there is a shard_map over the
+    mesh's ``model`` axis on KV heads (ROADMAP S9)."""
+    spans = max(
+        (len(leaf.sharding.device_set)
+         for leaf in jax.tree_util.tree_leaves(params)
+         if hasattr(leaf, "sharding")),
+        default=1,
+    )
+    if spans == 1 or cfg.paged_attention == "gather":
+        return cfg
+    if cfg.paged_attention == "kernel":
+        raise ValueError(
+            f"paged_attention='kernel' is forced, but the params are "
+            f"placed over {spans} devices and the Pallas decode kernel "
+            f"cannot be partitioned; use 'auto' or 'gather' on a mesh "
+            f"of more than one chip")
+    return dataclasses.replace(cfg, paged_attention="gather")
 
 
 class PagedKVCache:
@@ -192,7 +240,7 @@ class PagedKVCache:
         if self.kv_quantized and cfg.paged_attention == "kernel":
             from kvedge_tpu.ops.paged_attention import scales_fit_vmem
 
-            if not scales_fit_vmem(pages * page_size * cfg.kv_heads):
+            if not scales_fit_vmem(pages * page_size, cfg.kv_heads):
                 # A forced kernel that cannot run must refuse at
                 # construction, not silently degrade to the cap-sized
                 # gather at the long-context shapes the force exists
@@ -1046,6 +1094,34 @@ class PagedKVCache:
             self._host_lengths[slot] += int(caps[slot])
         return toks
 
+    def lower_decode_window(self, params, n_steps: int):
+        """Lower, without running, the capped greedy window this pool
+        dispatches, at its live shapes and placements.
+
+        What the device is given is otherwise invisible from outside:
+        "auto" attention, the interpret switch and XLA's own sharding
+        all resolve at trace time. chip_smoke.py reads the result's
+        ``as_text()`` for the Pallas kernel's ``tpu_custom_call`` and
+        compiles it for the collectives of a sharded pool. Shapes and
+        placements only (an uncommitted array, like freshly synced
+        tables, goes where jit would put it), so a state the decode
+        thread has since donated is no obstacle.
+        """
+        a_params, a_state = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(
+                a.shape, a.dtype,
+                sharding=a.sharding if a.committed else None),
+            (params, self.state),
+        )
+
+        def row(dtype):
+            return jax.ShapeDtypeStruct((self.bucket,), dtype)
+
+        return _paged_decode_window_capped.lower(
+            a_params, a_state, row(jnp.int32), self.cfg, n_steps,
+            row(jnp.bool_), row(jnp.int32), row(jnp.int32),
+        )
+
     def harvest_window(self, handle):
         """Force a dispatched window's tokens to the host
         ([n_steps + 2, slots] int32: the produced tokens plus the
@@ -1567,7 +1643,7 @@ def _paged_attend_layer(cfg: TransformerConfig, state: PagedState, x,
     if quantized:
         from kvedge_tpu.ops.paged_attention import scales_fit_vmem
 
-        scales_fit = scales_fit_vmem(new_scale_k.size)
+        scales_fit = scales_fit_vmem(new_scale_k.size // kv, kv)
         if (kernel_eligible and cfg.paged_attention == "kernel"
                 and not scales_fit):
             raise ValueError(
@@ -1585,12 +1661,13 @@ def _paged_attend_layer(cfg: TransformerConfig, state: PagedState, x,
         # over the block table — K/V pages stream up to each row's LIVE
         # length through the Pallas kernel; the padded pool view is
         # never materialized (ops/paged_attention.py).
+        from kvedge_tpu.ops import pallas_interpret
         from kvedge_tpu.ops.paged_attention import paged_decode_attention
 
         att = paged_decode_attention(
             q[:, 0], new_pool_k, new_pool_v, tables, q_positions[:, 0],
             scale_k=new_scale_k, scale_v=new_scale_v,
-            interpret=jax.default_backend() != "tpu",
+            interpret=pallas_interpret(),
         )  # [B, H, Dh], kv-major head layout — same as the einsum's
         x = x + att.reshape(batch, 1, h * dh) @ w_out.astype(dtype)
     else:

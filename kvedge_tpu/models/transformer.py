@@ -23,8 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from kvedge_tpu.compat import shard_map
-
 
 @dataclasses.dataclass(frozen=True)
 class TransformerConfig:
@@ -109,12 +107,14 @@ class TransformerConfig:
     # Paged DECODE attention (models/kvcache.py single-query steps and
     # windows): "gather" materializes the per-sequence pool view
     # (pool[tables] — cost scales with the pool CAP); "kernel" streams
-    # K/V pages block-table-indexed through a Pallas kernel with an
-    # online softmax — per-step cost scales with each sequence's LIVE
-    # length (ops/paged_attention.py; numerically equivalent to the
-    # gather within bf16 rounding, not bit-identical). "auto" picks the
-    # kernel on TPU at long-context caps (max_seq >= 2048, where the
-    # cap-vs-live difference is the bill) and the gather elsewhere.
+    # K/V pages block-table-indexed through a two-phase Pallas kernel —
+    # per-step cost scales with each sequence's LIVE length
+    # (ops/paged_attention.py; bit-identical to the gather when
+    # compiled for the chip, which chip_smoke.py enforces — see
+    # kvcache._use_paged_kernel). "auto" picks the kernel on one TPU
+    # chip at long-context caps (max_seq >= 2048, 128-token pages) and
+    # the gather elsewhere; over several chips see
+    # kvcache.settle_paged_attention.
     # Prefill and the speculative verify pass always use the gather
     # path (multi-query shapes).
     paged_attention: str = "auto"
@@ -437,6 +437,7 @@ def _layer(cfg: TransformerConfig, x, layer_params, mesh=None,
             attended = ulysses_attention(q, k, v, mesh)
         attended = attended.reshape(batch, seq, h * dh)
     elif cfg.attention == "flash":
+        from kvedge_tpu.ops import pallas_interpret
         from kvedge_tpu.ops.attention import flash_attention, pick_block
 
         # [B, T, H, dh] -> [B*H, T, dh] (head-major programs for the grid).
@@ -446,7 +447,7 @@ def _layer(cfg: TransformerConfig, x, layer_params, mesh=None,
         attended = flash_attention(
             heads_to_programs(q), heads_to_programs(k), heads_to_programs(v),
             pick_block(seq),
-            jax.default_backend() != "tpu",  # interpret kernels off-TPU
+            pallas_interpret(),
         )
         attended = (
             attended.reshape(batch, h, seq, dh)
@@ -598,9 +599,10 @@ def _fused_xent_loss(params: dict, inputs, targets,
     * single-device meshes (and mesh=None from non-training callers) run
       the kernel directly.
     """
+    from kvedge_tpu.ops import pallas_interpret
     from kvedge_tpu.ops.xent import fused_xent
 
-    interpret = jax.default_backend() != "tpu"  # interpret kernels off-TPU
+    interpret = pallas_interpret()
     hidden, aux = forward_hidden(params, inputs, cfg, mesh)
     b, t, d = hidden.shape
     rows = hidden.reshape(b * t, d)
@@ -618,7 +620,7 @@ def _fused_xent_loss(params: dict, inputs, targets,
 
         # check_vma off: pallas_call out_shapes don't declare mesh-axis
         # variance, which the checker would otherwise require.
-        per_row = shard_map(
+        per_row = jax.shard_map(
             lambda x, e, tg: fused_xent(x, e, tg, interpret),
             mesh=mesh,
             in_specs=(P("data", None), P(), P("data")),

@@ -69,13 +69,18 @@ _SCALE_VMEM_BUDGET = 8 * 1024 * 1024  # bytes, BOTH scale arrays
 _SCRATCH_VMEM_BUDGET = 12 * 1024 * 1024  # bytes, score + V-image scratch
 
 
-def scales_fit_vmem(scale_elements: int) -> bool:
+def scales_fit_vmem(rows: int, kv_heads: int) -> bool:
     """Whether the int8 kernel variant can run: it maps BOTH whole
-    scale arrays ([P, page, K] fp32 each, ``scale_elements`` elements
-    per array) into VMEM alongside its page buffers. The policy lives
-    here, next to the mechanism — callers route to the gather ("auto")
-    or refuse loudly (forced "kernel") when this is False."""
-    return 2 * scale_elements * 4 <= _SCALE_VMEM_BUDGET
+    scale arrays ([P, page, K] fp32 each, ``rows`` = P * page token
+    rows per array) into VMEM alongside its page buffers. VMEM tiles
+    the minor dim to 128 lanes, so a row of K scales occupies a whole
+    128-lane row there — at K = 4 that is 32x the array's HBM size,
+    which is what the v5e compiler charged when it refused a pool this
+    function used to admit (tests/test_chip_compile.py). The policy
+    lives here, next to the mechanism — callers route to the gather
+    ("auto") or refuse loudly (forced "kernel") when this is False."""
+    lanes = -(-kv_heads // 128) * 128
+    return 2 * rows * lanes * 4 <= _SCALE_VMEM_BUDGET
 
 
 def decode_scratch_fits_vmem(max_pages: int, page: int, width: int,

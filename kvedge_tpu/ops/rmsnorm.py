@@ -1,6 +1,7 @@
 """Pallas fused RMSNorm — the VERDICT r3 #8 experiment.
 
-The round-3 profiler breakdown (SWEEP_r03.json) names ~33 ms/step of
+A round-3 profiler breakdown (pre-PR-1 chip run, record removed in
+PR 21, not comparable with today's code) named ~33 ms/step of
 non-dot device work in the flagship train step, with ``reduce_sum``
 (the norm mean-squares + the readout logsumexp) the largest category.
 This kernel is the one named untried mechanism: fuse each RMSNorm's
@@ -28,6 +29,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from kvedge_tpu.ops import pallas_interpret
 
 _EPS = 1e-6
 
@@ -79,7 +82,6 @@ def _rmsnorm_vjp_fwd(x, gain):
     x2d = x.reshape(-1, d)
     n = x2d.shape[0]
     block = _pick_block_rows(n)
-    interpret = jax.default_backend() != "tpu"
     if block < 8:
         # Degenerate row counts: fall back to the jnp formula rather
         # than a 1-row Pallas grid.
@@ -90,7 +92,7 @@ def _rmsnorm_vjp_fwd(x, gain):
         y = (x * scale.astype(x.dtype)) * gain.astype(x.dtype)
     else:
         y = _rmsnorm_fwd_pallas(
-            x2d, gain, block_rows=block, interpret=interpret
+            x2d, gain, block_rows=block, interpret=pallas_interpret()
         ).reshape(x.shape)
     return y, (x, gain)
 

@@ -48,10 +48,22 @@ FWD_MAX_ROWS = 512
 BWD_MAX_ROWS = 256
 
 
-def pick_vocab_block(vocab: int) -> int:
-    """Largest lane-aligned vocab block that divides ``vocab``."""
+# What one vocab row of a block costs the dE kernel in VMEM, per model
+# dim: the fp32 accumulator, the double-buffered fp32 output block and
+# the double-buffered bf16 embedding block (4 + 8 + 4 bytes). Capped so
+# they leave room under the 16 MB scope for the row-side blocks: at
+# D=512 that still admits 1280; at D=1024 the v5e compiler refused 1280
+# by 80 KB (tests/test_chip_compile.py), and the cap picks 256.
+_BWD_VOCAB_ROW_BYTES = 16
+_BWD_VOCAB_BUDGET = 10 * 1024 * 1024
+
+
+def pick_vocab_block(vocab: int, max_block: int = _VOCAB_BLOCKS[0]) -> int:
+    """Largest lane-aligned vocab block <= max_block dividing ``vocab``
+    (never below the smallest, one 128-lane tile)."""
+    cap = max(max_block, _VOCAB_BLOCKS[-1])
     for block in _VOCAB_BLOCKS:
-        if vocab % block == 0:
+        if block <= cap and vocab % block == 0:
             return block
     raise ValueError(
         f"fused cross-entropy needs vocab divisible by 128, got {vocab} "
@@ -253,7 +265,9 @@ def _fused_xent_bwd(interpret, residuals, g):
     n, d = x.shape
     v = embedding.shape[0]
     bn = pick_row_block(n, BWD_MAX_ROWS)
-    bv = pick_vocab_block(v)
+    bv = pick_vocab_block(
+        v, _BWD_VOCAB_BUDGET // (_BWD_VOCAB_ROW_BYTES * d)
+    )
     tgt = targets.reshape(n, 1).astype(jnp.int32)
     lse2 = lse.reshape(n, 1)
     g2 = g.reshape(n, 1).astype(jnp.float32)

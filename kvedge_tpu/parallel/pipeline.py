@@ -59,8 +59,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from kvedge_tpu.compat import shard_map
-
 
 def _stage_specs(n_arrays: int, data_axis: str | None,
                  seq_axis: str | None):
@@ -229,7 +227,7 @@ def pipeline_layers(x, stacked, layer_fn, mesh, *, n_layers: int,
         {stage_axis} | ({data_axis} if dspec else set())
         | ({seq_axis} if seq_axis is not None else set())
     )
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=_stage_specs(len(stacked), dspec, seq_axis),

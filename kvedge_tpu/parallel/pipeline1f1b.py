@@ -58,8 +58,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from kvedge_tpu.compat import shard_map
-
 from kvedge_tpu.models.transformer import (
     _layer,
     _rmsnorm,
@@ -272,7 +270,7 @@ def pipeline_1f1b_loss_and_grads(params: dict, batch, cfg, mesh):
 
     n_stacked = len(stacked)
     act_spec = P(None, dspec, None, None)
-    d_stacked, d_lnf, d_emb_head, dx0, loss_sum = shard_map(
+    d_stacked, d_lnf, d_emb_head, dx0, loss_sum = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(act_spec, P(None, dspec, None), P(), P(),

@@ -38,8 +38,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from kvedge_tpu.compat import shard_map
-
 # Finite stand-in for -inf: keeps fully-masked rows NaN-free in the online
 # softmax (exp(-BIG - m) == 0 exactly in fp32) without special-casing.
 _MASKED = -1e30
@@ -146,7 +144,7 @@ def ring_attention(q, k, v, mesh, *, seq_axis: str = "seq",
     local = functools.partial(
         _ring_attention_local, axis_name=seq_axis, sp=sp
     )
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec
     )(q, k, v)
 

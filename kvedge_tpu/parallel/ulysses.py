@@ -41,8 +41,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from kvedge_tpu.compat import shard_map
-
 # Same finite -inf stand-in as the ring: exp(_MASKED - m) == 0 in fp32.
 _MASKED = -1e30
 
@@ -126,6 +124,6 @@ def ulysses_attention(q, k, v, mesh, *, seq_axis: str = "seq",
     dspec = data_axis if data_axis in axis_sizes else None
     spec = P(dspec, seq_axis, head_axis, None)
     local = functools.partial(_ulysses_local, axis_name=seq_axis)
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec
     )(q, k, v)

@@ -73,6 +73,14 @@ STATE_MOUNT = "/var/lib/kvedge/state"
 # rescheduling; the status server surfaces it at /status. The filename is
 # owned by the runtime module that reads it back.
 INIT_BIN = "/opt/kvedge/bin/kvedge-init"
+# JAX's persistent compile cache goes on the state volume, so a
+# rescheduled pod does not compile its programs again. JAX reads the
+# variable itself (runtime/compilecache.py sets no directory when it is
+# set); unset, the cache would land in the image's own ephemeral layer.
+COMPILE_CACHE_ENV = {
+    "name": "JAX_COMPILATION_CACHE_DIR",
+    "value": f"{STATE_MOUNT}/jax-cache",
+}
 INIT_EVENTS_PATH = f"{STATE_MOUNT}/{INIT_EVENTS_FILE}"
 SSH_PORT = 22
 # Default status port is owned by RuntimeConfig; the rendered containerPort /
@@ -254,6 +262,7 @@ def runtime_deployment(values: ChartValues) -> dict:
                                     "name": "KVEDGE_EXPECTED_PROCESSES",
                                     "value": "1",
                                 },
+                                COMPILE_CACHE_ENV,
                             ],
                             "resources": {
                                 "requests": {
@@ -418,6 +427,7 @@ def runtime_statefulset(values: ChartValues) -> dict:
             "name": "KVEDGE_EXPECTED_PROCESSES",
             "value": str(values.tpuNumHosts),
         },
+        COMPILE_CACHE_ENV,
     ]
     pod["volumes"] = [v for v in pod["volumes"] if v["name"] != "statedisk"]
     spec["volumeClaimTemplates"] = [

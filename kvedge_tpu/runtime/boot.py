@@ -18,6 +18,7 @@ from typing import Callable
 from kvedge_tpu.config.runtime_config import RuntimeConfig
 from kvedge_tpu.parallel.distributed import DistributedState, maybe_initialize
 from kvedge_tpu.runtime import heartbeat, recovery
+from kvedge_tpu.runtime.compilecache import enable_compile_cache
 from kvedge_tpu.runtime.devicecheck import DeviceCheckResult, run_device_check
 from kvedge_tpu.runtime.profiling import CaptureUnavailable, TraceCapture
 from kvedge_tpu.runtime.status import GenerateUnavailable, StatusServer
@@ -187,6 +188,7 @@ def start_runtime(cfg: RuntimeConfig) -> RuntimeHandle:
     crash-loop the degraded-state design exists to avoid.
     """
     started_at = time.time()
+    enable_compile_cache()  # before the payload's first compile
     boot_count = heartbeat.next_boot_count(cfg.state_dir)
 
     handle: RuntimeHandle = None  # assigned below; closures capture it
@@ -360,12 +362,21 @@ def start_runtime(cfg: RuntimeConfig) -> RuntimeHandle:
     return handle
 
 
+class DegradedBoot(RuntimeError):
+    """``boot(once=True)`` finished with a degraded check."""
+
+
 def boot(config_path: str, once: bool = False, root: str = "/") -> None:
     """Entry for ``kvedge-runtime boot --config <path>``.
 
     ``root`` is accepted for signature symmetry with the other boot
     commands; paths inside the config were already rebased when
     ``kvedge-bootstrap apply`` wrote it.
+
+    A long-running pod whose payload failed stays up degraded, so it
+    can be debugged through /status. ``once=True`` has no such reader:
+    its only result is the exit code, so a degraded check raises
+    :class:`DegradedBoot` after the shutdown.
     """
     del root
     with open(config_path, "r", encoding="utf-8") as fh:
@@ -383,6 +394,8 @@ def boot(config_path: str, once: bool = False, root: str = "/") -> None:
         print(f"[kvedge-runtime] DEGRADED: {handle.check.error}", flush=True)
     if once:
         handle.shutdown()
+        if not handle.check.ok:
+            raise DegradedBoot(handle.check.error)
         return
     try:
         handle.writer.run()  # heartbeat loop on the main thread, forever

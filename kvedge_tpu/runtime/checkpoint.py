@@ -203,16 +203,7 @@ class StateCheckpointer:
                     else self._ocp.args.StandardRestore(abstract_tree))
             tree = self._manager.restore(step, args=args)
         else:
-            try:
-                tree = self._manager.restore(step)
-            except KeyError:
-                # Some orbax versions refuse an argless restore on a
-                # manager that never saved (no handler bound for the
-                # item yet); StandardRestore with topology inference is
-                # the same operation spelled explicitly.
-                tree = self._manager.restore(
-                    step, args=self._ocp.args.StandardRestore()
-                )
+            tree = self._manager.restore(step)
         if abstract_tree is not None:
             _verify_template(abstract_tree, tree, "restored tree")
         return step, tree

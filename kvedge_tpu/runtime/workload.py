@@ -313,6 +313,10 @@ def run_train_payload(cfg: RuntimeConfig) -> DeviceCheckResult:
         batches = _global_batches(cfg, tcfg, mesh, feeder, n_proc)
 
         last_write = 0.0
+        # This pod generation's most recent per-step losses: the curve
+        # an operator (and chip_smoke.py) reads from /status to tell
+        # "trains" from "runs".
+        recent = collections.deque(maxlen=64)
 
         def on_step(step: int, loss: float) -> None:
             # Live progress into /status (and the PVC, so the last known
@@ -322,6 +326,7 @@ def run_train_payload(cfg: RuntimeConfig) -> DeviceCheckResult:
             # persisted JSON would corrupt every later /status body),
             # and a failed write must never abort healthy training.
             nonlocal last_write
+            recent.append(round(loss, 6) if math.isfinite(loss) else None)
             now = time.time()
             if step < cfg.train_steps and now - last_write < 1.0:
                 return
@@ -330,7 +335,8 @@ def run_train_payload(cfg: RuntimeConfig) -> DeviceCheckResult:
                 heartbeat.write_train_progress(cfg.state_dir, {
                     "step": step,
                     "target_steps": cfg.train_steps,
-                    "loss": round(loss, 6) if math.isfinite(loss) else None,
+                    "loss": recent[-1],
+                    "losses": list(recent),
                     "ts": now,
                 })
             except OSError:
@@ -483,19 +489,13 @@ def _restore_latest_params(cfg: RuntimeConfig, tcfg, mesh=None):
     abstract = jax.eval_shape(fresh_state)
     if mesh is not None:
         abstract = abstract_shard_tree(mesh, abstract)
-    # Older orbax has no PLACEHOLDER: fall back to restoring the full
-    # tree and dropping the moments afterwards — correct either way, the
-    # skip is purely a memory optimisation.
-    placeholder = getattr(ocp, "PLACEHOLDER", None)
-    partial = placeholder is not None
-    if partial:
-        abstract["opt_state"] = jax.tree_util.tree_map(
-            lambda _: placeholder, abstract["opt_state"]
-        )
+    abstract["opt_state"] = jax.tree_util.tree_map(
+        lambda _: ocp.PLACEHOLDER, abstract["opt_state"]
+    )
     with StateCheckpointer(
         cfg.state_dir, checkpoint_dir=cfg.checkpoint_dir
     ) as ckpt:
-        restored = ckpt.restore_latest(abstract, partial=partial)
+        restored = ckpt.restore_latest(abstract, partial=True)
     if restored is not None:
         step, tree = restored
         return step, tree["params"]
@@ -1538,8 +1538,8 @@ def _build_serve(cfg, base, tcfg, params, restored_step, *, cache=None,
             elif (spec_draft > 0 and cache is not None
                     and cfg.serving_speculative == "auto"):
                 # "auto" promises measured economics; unmeasured
-                # speculation on a degraded relay is the regression
-                # the mode exists to prevent. Explicit K still runs
+                # speculation over a long host round trip is the
+                # regression the mode exists to prevent. Explicit K still runs
                 # speculation on a slice.
                 decision = paged_server.disable_speculation(
                     "auto unmeasured on a slice"
@@ -2072,6 +2072,10 @@ def _build_serve(cfg, base, tcfg, params, restored_step, *, cache=None,
             return out
 
         serve_fn.stats = serve_stats
+        # The live paged server (None on the contiguous backend), for
+        # in-process drivers that must see the pool and the programs it
+        # runs (chip_smoke.py lowers the decode window it served with).
+        serve_fn.server = paged_server
         # Flight-recorder handle for the HTTP layer: boot.py's /trace
         # closure reads this attribute at request time (None = 404,
         # tracing off). Plain reference — survives revive()/reform.

@@ -3,7 +3,10 @@
 Multi-chip TPU hardware is not available in CI; sharding behavior is tested
 on 8 virtual CPU devices per the build environment contract. See
 ``kvedge_tpu/testing/jaxenv.py`` for why the ordering (env vars *and*
-jax.config, before any backend init) is load-bearing.
+jax.config, before any backend init) is load-bearing. JAX's persistent
+compile cache stays off for the tests and the processes they start
+(``kvedge_tpu/runtime/compilecache.py`` would otherwise fill
+``<repo>/.jax_cache`` from every ``start_runtime``).
 """
 
 import os
@@ -15,13 +18,18 @@ import pytest
 
 from kvedge_tpu.testing.jaxenv import force_virtual_cpu_devices
 
+os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"  # child processes
 force_virtual_cpu_devices(8)
+
+import jax  # noqa: E402
+
+jax.config.update("jax_enable_compilation_cache", False)  # this process
 
 _NATIVE_DIR = pathlib.Path(__file__).resolve().parent.parent / "native"
 
 # One process compiling the whole ~660-test suite accumulates XLA state
 # (jit caches + loaded executables) until XLA's compiler segfaulted at
-# ~619 tests — reproducibly, with 125 GB free (VERDICT.md r4 weak #1).
+# ~619 tests — reproducibly, with 125 GB free.
 # Bound the live population: clear JAX's compilation caches every N
 # tests. Module-level jitted wrappers (e.g. kvcache._paged_decode_step)
 # keep working — their cache entries just recompile on next use. The

@@ -1,6 +1,6 @@
 """bench.py's reporting math (pure functions; the timed paths run on TPU).
 
-The MFU figure in BENCH_r{N}.json is only as honest as the FLOPs model
+The MFU figure bench.py prints is only as honest as the FLOPs model
 behind it — these tests pin that model against hand-derived counts so a
 refactor cannot silently inflate the headline.
 """
@@ -63,9 +63,10 @@ def test_paged_decode_bench_runs_and_counts_tokens():
     assert abs(tps - 3 * sps) < 1e-6
     # The overlapped (double-buffered) leg: positive throughput and a
     # finite speedup ratio vs the serial windowed leg. No lower bound
-    # here — on a sub-ms local relay there is no RTT to hide, so the
-    # ratio legitimately sits near 1.0 (the >= 1.3 expectation applies
-    # only when the measured relay RTT is >= 20 ms).
+    # here — with the host beside the device there is no round trip to
+    # hide, so the ratio legitimately sits near 1.0 (the >= 1.3
+    # prediction applies only when the measured host round trip per
+    # dispatch is >= 20 ms).
     assert overlap_tps > 0
     assert overlap_speedup > 0
 

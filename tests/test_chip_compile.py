@@ -1,0 +1,178 @@
+"""The main path's Pallas kernels, compiled by the TPU's own compiler.
+
+Every other kernel test runs in the Pallas interpreter on the CPU,
+which cannot see what Mosaic refuses: a slice off the tiling, too much
+VMEM, a DMA that is not lane-aligned. libtpu compiles for a chip that
+is described and not attached, so each kernel is lowered here at the
+widths the product runs (the 209M shape chip_smoke.py drives, and the
+``flagship`` preset) against a described ``v5e:2x2`` topology — no chip
+time, about two seconds each. Nothing executes: a pass says the chip's
+compiler accepts the kernel, not that its numbers are right (the
+interpreter tests and chip_smoke.py's kernel-vs-gather comparison say
+that).
+
+The file sorts before ``test_cli.py`` on purpose: the tier-1 clock has
+to reach it.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+import numpy as np
+from jax.sharding import (
+    Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding,
+)
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """The four described chips of a v5e:2x2, persistent cache off.
+
+    A program compiled for a described device lands in the persistent
+    cache but cannot be read back without a chip (the next compile
+    warns and compiles again), so the cache stays off around these.
+    """
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no libtpu in this environment
+        pytest.skip(f"cannot describe a v5e topology here: {e!r}")
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield topo.devices
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was_on)
+        compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def chip(v5e):
+    return SingleDeviceSharding(v5e[0])
+
+
+def _paged(*, batch=8, heads=16, kv=4, dh=64, page=128, max_pages=16,
+           pool_pages=64, int8=False):
+    """(fn, arg shapes) for one paged_decode_attention geometry."""
+    from kvedge_tpu.ops.paged_attention import paged_decode_attention
+
+    pool_dtype = jnp.int8 if int8 else jnp.bfloat16
+    pool = ((pool_pages, page, kv, dh), pool_dtype)
+    args = [((batch, heads, dh), jnp.bfloat16), pool, pool,
+            ((batch, max_pages), jnp.int32), ((batch,), jnp.int32)]
+    if not int8:
+        return paged_decode_attention, args
+    scale = ((pool_pages, page, kv), jnp.float32)
+
+    def quantized(q, pk, pv, tables, pos, sk, sv):
+        return paged_decode_attention(q, pk, pv, tables, pos,
+                                      scale_k=sk, scale_v=sv)
+
+    return quantized, args + [scale, scale]
+
+
+def _flash_fwd_bwd():
+    from kvedge_tpu.ops.attention import flash_attention
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v).astype(jnp.float32).sum()
+
+    qkv = ((512, 2048, 64), jnp.bfloat16)
+    return jax.grad(loss, argnums=(0, 1, 2)), [qkv, qkv, qkv]
+
+
+def _rmsnorm_fwd():
+    from kvedge_tpu.ops.rmsnorm import _rmsnorm_fwd_pallas
+
+    fn = functools.partial(_rmsnorm_fwd_pallas, block_rows=512,
+                           interpret=False)
+    return fn, [((16384, 1024), jnp.bfloat16), ((1024,), jnp.float32)]
+
+
+def _fused_xent_fwd_bwd():
+    from kvedge_tpu.ops.xent import fused_xent
+
+    def loss(x, emb, targets):
+        return fused_xent(x, emb, targets).sum()
+
+    return jax.grad(loss, argnums=(0, 1)), [
+        ((4096, 1024), jnp.bfloat16), ((32000, 1024), jnp.float32),
+        ((4096,), jnp.int32),
+    ]
+
+
+# 209M widths unless said: 16 query / 4 KV heads of 64, 128-token pages,
+# a 2,048-token cap (16 pages per sequence), 8 rows.
+_CASES = {
+    "paged_decode_bf16_209m": lambda: _paged(),
+    # 64 pages (4 slots x 16) x 128 rows, each row of 4 scales padded to
+    # 128 fp32 lanes in VMEM, twice, is exactly _SCALE_VMEM_BUDGET: the
+    # largest int8 pool "auto" routes to the kernel.
+    "paged_decode_int8_209m_scales_at_budget": lambda: _paged(int8=True),
+    # The 8,192-token cap: 64 pages per sequence is the largest score +
+    # V-image scratch decode_scratch_fits_vmem admits at this width.
+    "paged_decode_bf16_cap8192": lambda: _paged(max_pages=64,
+                                                pool_pages=512),
+    "paged_decode_int8_cap8192": lambda: _paged(int8=True, max_pages=64),
+    # The flagship preset serves MHA: 8 KV heads of 64 (width 512).
+    "paged_decode_bf16_flagship": lambda: _paged(heads=8, kv=8),
+    "flash_attention_fwd_bwd_t2048": _flash_fwd_bwd,
+    "rmsnorm_fwd_16384x1024": _rmsnorm_fwd,
+    "fused_xent_fwd_bwd_4096x32000": _fused_xent_fwd_bwd,
+}
+
+
+@pytest.mark.parametrize("case", sorted(_CASES))
+def test_kernel_compiles_for_v5e(chip, case):
+    fn, shapes = _CASES[case]()
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+            for shape, dtype in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text(), (
+        f"{case}: compiled for the chip without a Mosaic kernel in it"
+    )
+
+
+@pytest.mark.parametrize("pool_spec", [
+    P(), P(None, None, "model", None),
+], ids=["replicated", "kv_heads_over_model"])
+def test_mosaic_refuses_the_kernel_over_several_chips(v5e, pool_spec):
+    """Why kvcache.settle_paged_attention sends every pool that spans
+    more than one chip to the gather, a merely replicated one too: a
+    jitted program over four devices cannot hold a Mosaic kernel,
+    however its arguments are laid out (only a shard_map can)."""
+    mesh = Mesh(np.array(v5e).reshape(2, 2), ("data", "model"))
+    fn, shapes = _paged()
+    specs = [P(), pool_spec, pool_spec, P(), P()]
+    args = [jax.ShapeDtypeStruct(shape, dtype,
+                                 sharding=NamedSharding(mesh, spec))
+            for (shape, dtype), spec in zip(shapes, specs)]
+    with pytest.raises(NotImplementedError,
+                       match="cannot be automatically partitioned"):
+        jax.jit(fn).lower(*args)
+
+
+def test_scale_budget_case_sits_on_the_budget():
+    """The int8 case above is only an edge while it equals the budget."""
+    from kvedge_tpu.ops.paged_attention import scales_fit_vmem
+
+    assert scales_fit_vmem(64 * 128, 4)
+    assert not scales_fit_vmem(65 * 128, 4)
+
+
+def test_cap8192_case_sits_on_the_auto_routes_kernel_side():
+    """64 pages of 128 is a cap "auto" still routes to the kernel."""
+    from kvedge_tpu.ops.paged_attention import decode_scratch_fits_vmem
+
+    assert decode_scratch_fits_vmem(64, 128, 256, 16)

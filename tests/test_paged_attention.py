@@ -100,7 +100,14 @@ def test_kernel_bitwise_at_page_boundary_and_longctx():
     page boundary (511/512/513, page 128 — partial page, exact page,
     one-past) and at live 4096, the kernel output equals the gather's
     bit-for-bit. The old online-softmax kernel disagreed here
-    (paged_longctx_token_agreement = 0.92 at live 512)."""
+    (paged_longctx_token_agreement = 0.92 at live 512).
+
+    Red on the CPU under JAX 0.9.0 (ROADMAP D6, root cause there): one
+    of 1,536 outputs, a sum that cancels to 4e-6, differs by one bf16
+    step because XLA:CPU sums the gather's own weights-times-V einsum
+    in another order than the interpreter's flat dot. Compiled for the
+    chip the same comparison is exact, and chip_smoke.py fails if it is
+    not. The assertion stays exact."""
     q, pool_k, pool_v, tables, q_pos = _ragged_pool(
         3, 8, 2, 64, 128, [510, 511, 512])
     want = _gather_reference(q, pool_k, pool_v, tables, q_pos)
@@ -255,6 +262,36 @@ def test_auto_never_picks_kernel_multiprocess(monkeypatch):
     assert not kvmod._use_paged_kernel(cfg, 128, 256)
 
 
+@pytest.mark.parametrize("spec", ["replicated", "sharded"])
+def test_pool_over_several_devices_settles_on_the_gather(params, spec):
+    """Where arrays live is settled before the pool is built
+    (settle_paged_attention): over params that span several devices —
+    split or only replicated, Mosaic refuses both — "auto" means the
+    gather, a forced "kernel" is refused at construction and not
+    downgraded, and an injected pool that would trace the kernel is
+    refused too. On one device nothing changes."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from kvedge_tpu.models.kvcache import settle_paged_attention
+    from kvedge_tpu.models.serving import PagedGenerationServer
+    from kvedge_tpu.parallel import shard_params
+
+    auto = dataclasses.replace(CFG, paged_attention="auto")
+    for cfg in (auto, KERNEL_CFG, CFG):
+        assert settle_paged_attention(cfg, params) is cfg
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2), ("data", "model"))
+    placed = (shard_params(mesh, params) if spec == "sharded" else
+              jax.device_put(params, NamedSharding(mesh, P())))
+    assert settle_paged_attention(auto, placed).paged_attention == "gather"
+    assert settle_paged_attention(CFG, placed) is CFG
+    with pytest.raises(ValueError, match="cannot be partitioned"):
+        PagedGenerationServer(placed, KERNEL_CFG, slots=2, pages=8,
+                              page_size=4)
+    injected = PagedKVCache(auto, slots=2, pages=8, page_size=4)
+    with pytest.raises(ValueError, match="injected cache"):
+        PagedGenerationServer(placed, auto, cache=injected)
+
+
 def test_vmem_refusal_spares_gather_only_traces(params, monkeypatch):
     """The trace-time VMEM refusal fires only where the kernel could
     actually run (single-query decode). Prefill and spec-verify always
@@ -266,7 +303,7 @@ def test_vmem_refusal_spares_gather_only_traces(params, monkeypatch):
     cache = PagedKVCache(cfg, slots=2, pages=20, page_size=4,
                          kv_dtype="int8")
     monkeypatch.setattr("kvedge_tpu.ops.paged_attention.scales_fit_vmem",
-                        lambda n: False)
+                        lambda rows, kv_heads: False)
     cache.admit(0, 3)
     cache.prefill(params, 0, jnp.asarray([5, 9, 2], jnp.int32))
     tokens = np.zeros((2, 2), np.int32)

@@ -297,3 +297,20 @@ def test_singlehost_pod_receives_expected_processes_env():
     env = {e["name"]: e["value"]
            for e in dep["spec"]["template"]["spec"]["containers"][0]["env"]}
     assert env["KVEDGE_EXPECTED_PROCESSES"] == "1"
+
+
+@pytest.mark.parametrize("values, manifest", [
+    (DEFAULT_VALUES, "jax-tpu-runtime.yaml"),
+    (MULTIHOST, "jax-tpu-runtime-multihost.yaml"),
+])
+def test_pods_keep_the_compile_cache_on_the_state_volume(values, manifest):
+    """A rescheduled pod must not compile its programs again: the chart
+    places JAX's persistent cache (JAX reads the variable itself) under
+    the state volume's mount, not in the image's ephemeral layer."""
+    container = render_all(values).manifests[manifest][
+        "spec"]["template"]["spec"]["containers"][0]
+    env = {e["name"]: e["value"] for e in container["env"]}
+    mounts = [m["mountPath"] for m in container["volumeMounts"]
+              if m["name"] == "statedisk"]
+    assert mounts and env["JAX_COMPILATION_CACHE_DIR"].startswith(
+        mounts[0] + "/")

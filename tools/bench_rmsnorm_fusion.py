@@ -54,9 +54,8 @@ def main() -> int:
     transformer._rmsnorm = rmsnorm_fused
     try:
         results.append(run("pallas-fused-rmsnorm"))
-        # Best-of-2 for the variant too: the relay's run-to-run variance
-        # is ~±3%, and a single losing sample must not be recorded as
-        # the mechanism's ceiling.
+        # Best-of-2 for the variant too: a single losing sample must
+        # not be recorded as the mechanism's ceiling.
         second = run("pallas-fused-rmsnorm")
         if second["tokens_per_sec"] > results[-1]["tokens_per_sec"]:
             results[-1] = second
@@ -70,7 +69,7 @@ def main() -> int:
            "note": (
                "VERDICT r3 #8: the one named untried non-dot mechanism, "
                "measured with the headline methodology. See "
-               "SWEEP_r03.json for the full round-3 sweep + profiler "
+               "tools/bench_sweep.py for the full sweep + profiler "
                "breakdown this extends (its scan-unroll negative, and "
                "the dot_general-at-sustained-ceiling evidence, still "
                "stand)."

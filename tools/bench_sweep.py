@@ -66,8 +66,8 @@ def main() -> int:
                 r["fused_xent"], r["seq"], r["steps"])
 
     # Only SUCCESSFUL records pin their variant; failures are retried on
-    # every resume (a transient relay error must not ship as a permanent
-    # "fails to compile" in the committed artifact) — the retry outcome
+    # every resume (a transient runtime error must not ship as a
+    # permanent "fails to compile" in the recorded artifact) — the retry outcome
     # REPLACES the failed record either way.
     done = {variant_key(r) for r in records if r.get("tokens_per_sec")}
 

@@ -9,8 +9,11 @@ step and a live generation — the round-2 half of the end-to-end story
 With ``--flagship`` the run sizes the payload through the ``[model]``
 TOML section instead of the probe default: the 41.6M-param flagship —
 the exact shape bench.py reports numbers for — trains, checkpoints, and
-serves through the same product path, on whatever accelerator is
-visible (the committed cast records a real TPU v5e run).
+serves through the same product path on the TPU. That scene needs the
+chip: on any other backend the device check fails the payload instead
+of quietly running it there (the committed cast's copy of the scene
+says ``platform=cpu``: it was recorded when the scene took whatever
+backend it found, and is due a re-recording on the chip).
 
 Usage: python tools/demo_train_serve.py <corpus.kvfeed> [--flagship]
 """
@@ -49,9 +52,7 @@ def main() -> int:
 
     state_dir = os.path.join(os.path.dirname(os.path.abspath(corpus)),
                              "state" + ("-flagship" if flagship else ""))
-    import jax
-
-    platform = jax.default_backend() if flagship else "cpu"
+    platform = "tpu" if flagship else "cpu"
     base = dataclasses.replace(
         RuntimeConfig(),
         name="edge-tpu-demo",
@@ -68,10 +69,14 @@ def main() -> int:
     )
 
     if flagship:
+        import jax
+
+        device = jax.devices()[0]
         tcfg, _ = train_model_config(base)
         print(f"[model] preset = \"flagship\": {tcfg.param_count:,} params "
               f"(d_model={tcfg.d_model}, layers={tcfg.n_layers}, "
-              f"vocab={tcfg.vocab}) on platform={platform}")
+              f"vocab={tcfg.vocab}) on platform={device.platform} "
+              f"device_kind={device.device_kind!r}")
     print("training 4 steps (checkpoint every 2) through the state volume...")
     result = run_train_payload(dataclasses.replace(base, payload="train"))
     if not result.ok:

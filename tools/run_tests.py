@@ -3,8 +3,8 @@
 ``python -m pytest tests`` accumulates XLA backend state — compiled
 executables, jit caches, the 8-virtual-device CPU client — across ~660
 tests in one process, and XLA's compiler reproducibly segfaulted after
-~619 of them (twice, same site, 125 GB free RAM — not OOM; see
-VERDICT.md round 4 "What's weak" #1). Every file passes in isolation,
+~619 of them (twice, same site, 125 GB free RAM — not OOM). Every
+file passes in isolation,
 so the failure is an at-scale artifact of one process compiling 600+
 programs, not a test bug. Two defenses exist:
 
