@@ -1,0 +1,10 @@
+"""kvedge-tpu's benchmark: one cell of ``BENCHMARK.json`` per process.
+
+``python3 -m benchmark.run --workload <cell> --seed <n> --seconds <s>
+--trace <0|1>`` starts the serve payload through ``start_runtime``,
+drives it over HTTP from a child process that never imports JAX, and
+prints one JSON line. Everything that belongs to one configuration,
+traffic mix, cell or per-layer metric is a file of its own under
+``configs/``, ``traffic/``, ``cells/`` and ``metrics/``, found by the
+name ``BENCHMARK.json`` gives; PERF.md says why each exists.
+"""

@@ -1,0 +1,571 @@
+"""The benchmark's own harness (``benchmark/``, ``BENCHMARK.json``).
+
+Everything here runs on the CPU at a probe size or on data: the
+schedule, the trace reducers on a small recorded trace, the roofline
+counts from shapes, and one whole run (server start, warm-up, ramp,
+window, drain, check) by calling the harness's functions. The command
+itself refuses a CPU backend, and a test shows that it does.
+"""
+
+import json
+import os
+import shutil
+import subprocess
+import sys
+
+import pytest
+
+from benchmark import cellspec, check, metrics, reduce, roofline
+from benchmark import schedule, trace
+
+REPO = cellspec.REPO
+HERE = os.path.dirname(os.path.abspath(__file__))
+with open(os.path.join(REPO, "BENCHMARK.json")) as _fh:
+    BENCH = json.load(_fh)
+CELLS = [w["name"] for w in BENCH["workloads"]]
+
+PROBE_CONFIG = """
+source = "none: a probe size for the CPU tests"
+[model]
+vocab = 256
+d_model = 64
+n_heads = 4
+n_kv_heads = 2
+n_layers = 2
+d_ff = 128
+[mesh]
+axes = { data = 1 }
+[payload]
+seq = 256
+serving_slots = 4
+serving_page_size = 16
+serving_pages = 96
+serving_window = 8
+"""
+PROBE_METRIC = '''
+"""A per-layer metric a later PR might add: requests the window saw."""
+NAMES = ("probe_requests",)
+
+
+def read(ctx):
+    return float(len(ctx["window"]))
+'''
+
+
+def probe_tree(root: str) -> str:
+    """A checkout with one new configuration, mix, cell and per-layer
+    metric, added as files and entries only."""
+    bench = os.path.join(root, "benchmark")
+    shutil.copytree(os.path.join(REPO, "benchmark"), bench,
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    with open(os.path.join(bench, "configs", "probe.toml"), "w") as fh:
+        fh.write(PROBE_CONFIG)
+    with open(os.path.join(bench, "metrics", "probe_requests.py"),
+              "w") as fh:
+        fh.write(PROBE_METRIC)
+    mix = {"prompt": {"dist": "lognormal", "median": 48, "sigma": 0.5,
+                      "min": 32, "max": 96, "multiple": 32},
+           "output": {"dist": "uniform", "min": 16, "max": 40},
+           "pairing_seed": 0}
+    with open(os.path.join(bench, "traffic", "tiny.json"), "w") as fh:
+        json.dump(mix, fh)
+    load = {"loop": "open", "rate_rps": 3.0, "ramp_s": 1.0,
+            "drain_s": 30.0, "decode_window": 8,
+            "programs": {"decode": "paged_decode_window",
+                         "prefill": "paged_prefill"},
+            "check": {"requests": 3, "limits": {"token_gap_max": 0.5,
+                                                "token_gap_mean": 0.01}}}
+    with open(os.path.join(bench, "cells", "probe.tiny.json"), "w") as fh:
+        json.dump(load, fh)
+    closed = {"prompt": {"dist": "uniform", "min": 32, "max": 64,
+                         "multiple": 32},
+              "output": {"dist": "uniform", "min": 40, "max": 120},
+              "pairing_seed": 0}
+    with open(os.path.join(bench, "traffic", "tinyclosed.json"), "w") as fh:
+        json.dump(closed, fh)
+    load = dict(load, loop="closed", clients=3, requests_per_client=40,
+                drain_s=2.0)
+    del load["rate_rps"]
+    with open(os.path.join(bench, "cells", "probe.tinyclosed.json"),
+              "w") as fh:
+        json.dump(load, fh)
+    doc = json.loads(json.dumps(BENCH))
+    doc["workloads"].append({"name": "probe.tinyclosed", "config": "probe",
+                             "traffic": "tinyclosed", "chips": 1,
+                             "why": "probe"})
+    doc["configs"].append({
+        "name": "probe", "source": "none",
+        "file": "benchmark/configs/probe.toml", "reduced": [],
+        "why": "probe"})
+    doc["workloads"].append({"name": "probe.tiny", "config": "probe",
+                             "traffic": "tiny", "chips": 1, "why": "probe"})
+    for name in ("ttft_p90_ms", "tpot_p50_ms"):  # what a chat cell reports
+        doc["end_to_end"].append({
+            "name": name, "unit": "ms", "better": "lower", "bound": 0.1,
+            "source": "host_clock", "workloads": ["probe.tiny"]})
+    doc["per_layer"].append({
+        "name": "probe_requests", "unit": "count", "better": "higher",
+        "source": "program_counter", "layer": "load generator",
+        "moves": "ttft_p90_ms", "workloads": ["probe.tiny"]})
+    doc["per_layer"].append({
+        "name": "queue_wait_ms", "unit": "ms", "better": "lower",
+        "source": "program_counter", "layer": "admission and batching",
+        "moves": "ttft_p90_ms"})
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as fh:
+        json.dump(doc, fh)
+    return root
+
+
+# ---- the schedule --------------------------------------------------------
+
+
+@pytest.mark.parametrize("name", CELLS)
+def test_two_seeds_offer_the_same_work(name):
+    cell = cellspec.load_cell(name)
+    vocab = cell.config["model"]["vocab"]
+    a = schedule.build(cell.traffic, cell.load, 3, BENCH["run_seconds"],
+                       vocab)
+    b = schedule.build(cell.traffic, cell.load, 2**31 + 12345,
+                       BENCH["run_seconds"], vocab)
+    lengths = [sorted((r["prompt"], r["n_new"]) for r in p["requests"])
+               for p in (a, b)]
+    assert lengths[0] == lengths[1]
+    assert a["work"] == b["work"] and a["work"]["requests"] >= 20
+    order = [[(r["prompt"], r["n_new"]) for r in p["requests"]]
+             for p in (a, b)]
+    assert order[0] != order[1]
+    if a["loop"] == "open":
+        dues = [[r["due"] for r in p["requests"]] for p in (a, b)]
+        assert dues[0] != dues[1]
+        assert all(-cell.load["ramp_s"] <= d < BENCH["run_seconds"]
+                   for d in dues[0])
+        inside = [d for d in dues[0] if d >= 0]
+        assert len(inside) == round(cell.load["rate_rps"]
+                                    * BENCH["run_seconds"])
+
+
+@pytest.mark.parametrize("name", CELLS)
+def test_a_cells_lengths_fit_its_configuration(name):
+    cell = cellspec.load_cell(name)
+    plan = schedule.build(cell.traffic, cell.load, 1, BENCH["run_seconds"],
+                          cell.config["model"]["vocab"])
+    asked = plan["requests"] + [
+        {"prompt": w["prompt"], "n_new": w["n_new"]}
+        for w in schedule.warmup_requests(cell.traffic, cell.load)]
+    assert max(r["prompt"] + r["n_new"] for r in asked) \
+        <= cell.config["payload"]["seq"]
+    multiple = cell.traffic["prompt"]["multiple"]
+    assert all(r["prompt"] % multiple == 0 for r in plan["requests"])
+
+
+def test_prompts_repeat_per_seed_and_never_share_a_first_token():
+    a = schedule.prompt_tokens(7, 3, 64, 49152)
+    assert a == schedule.prompt_tokens(7, 3, 64, 49152)
+    assert a != schedule.prompt_tokens(8, 3, 64, 49152)
+    firsts = [schedule.prompt_tokens(7, i, 32, 49152)[0]
+              for i in range(-20, 400)]
+    assert len(set(firsts)) == len(firsts)
+    assert all(0 <= t < 49152 for t in a)
+
+
+def test_the_load_generator_never_imports_jax():
+    code = ("import sys, benchmark.loadgen, benchmark.schedule; "
+            "sys.exit(1 if any(m == 'jax' or m.startswith('jax.') "
+            "for m in sys.modules) else 0)")
+    done = subprocess.run([sys.executable, "-c", code], cwd=REPO,
+                          timeout=120)
+    assert done.returncode == 0
+
+
+# ---- reductions ----------------------------------------------------------
+
+
+def _record(due, first, last, n, prompt=64, error=None):
+    return {"index": 0, "client": -1, "prompt": prompt, "n_new": n,
+            "due": due, "sent": due + 0.001, "first": first, "last": last,
+            "tokens": list(range(n)) if first is not None else [],
+            "error": error,
+            "bursts": ([[first, 1], [last, n - 1]]
+                       if first is not None else [])}
+
+
+def test_end_to_end_reductions():
+    records = [_record(float(i), i + 0.5 + 0.01 * i, i + 2.5 + 0.01 * i, 21)
+               for i in range(10)]
+    records.append(_record(-3.0, -2.0, -1.0, 21))    # the ramp's
+    records.append(_record(4.0, None, None, 5, error="HTTP 503"))
+    window = reduce.window_requests(records, 10.0)
+    assert len(window) == 11 and len(reduce.failed(window)) == 1
+    ttft = reduce.end_to_end("ttft_p90_ms", records, 10.0)
+    assert ttft == pytest.approx(500 + 10 * 8.1)
+    assert reduce.end_to_end("tpot_p50_ms", records, 10.0) \
+        == pytest.approx(100.0)
+    # 10 first tokens and 8 tails of 20 land inside [0, 10) whole; credited
+    # over the 2 s since each stream's first delivery, the two tails that
+    # end after the window's close leave 14.2 and 4.1 of their 20 inside
+    assert reduce.tokens_delivered_whole(records, 10.0) == 10 + 8 * 20
+    assert reduce.end_to_end("out_tok_s", records, 10.0) \
+        == pytest.approx((10 + 8 * 20 + 14.2 + 4.1) / 10.0)
+    assert reduce.percentile([1, 2, 3, 4], 50) == 2.5
+
+
+def test_a_delivery_is_credited_over_the_time_it_was_produced_in():
+    """A stream under way when the window opens and still under way when
+    it closes: 64 tokens every 2 s. The first delivery is credited whole
+    where it falls; every later one evenly over the 2 s before it, by
+    the part of them inside the window."""
+    stream = _record(-5.0, -3.0, 13.0, 9 * 64)
+    stream["bursts"] = [[-3.0 + 2.0 * k, 64] for k in range(9)]
+    # deliveries at -3, -1, 1, ..., 13; window [0, 10): half of the one at
+    # 1, all of 3, 5, 7, 9, half of the one at 11
+    assert reduce.tokens_in_window([stream], 10.0) == pytest.approx(5 * 64)
+    assert reduce.tokens_delivered_whole([stream], 10.0) == 5 * 64
+    # lines of one delivery arrive a few milliseconds apart: one delivery
+    stream["bursts"] = [[1.0 + 0.002 * k, 1] for k in range(64)]
+    assert reduce.deliveries(stream) == [(pytest.approx(1.126), 64)]
+    # a stream that starts inside the window: its first delivery whole
+    late = _record(4.0, 6.0, 8.0, 128)
+    late["bursts"] = [[6.0, 64], [8.0, 64]]
+    assert reduce.tokens_in_window([late], 7.0) == pytest.approx(64 + 32)
+
+
+# ---- the trace reducers, on a small recorded trace -----------------------
+
+
+@pytest.fixture(scope="module")
+def recorded():
+    with open(os.path.join(HERE, "trace_recorded.json")) as fh:
+        return json.load(fh)
+
+
+def test_trace_idle_share_and_busy_time(recorded):
+    events = recorded["events"]
+    lo, hi = trace.span(events)
+    busy = trace.busy_seconds(events)
+    assert 0 < busy <= hi - lo
+    assert busy == pytest.approx(recorded["expect"]["busy_s"], rel=1e-6)
+    gaps = trace.idle_gaps(events)
+    first = trace.devices(events)[0]
+    one = [e for e in events if e["device"] == first]
+    lo1, hi1 = trace.span(one)
+    assert sum(b - a for a, b in gaps) == pytest.approx(
+        (hi1 - lo1) - trace.busy_seconds(one), rel=1e-6)
+
+
+def test_trace_named_programs_time_and_steps(recorded):
+    events = recorded["events"]
+    want = recorded["expect"]
+    decode = trace.program_events(events, "paged_decode_window")
+    assert len(decode) == want["decode_programs"]
+    assert trace.program_seconds(events, "paged_decode_window") \
+        == pytest.approx(want["decode_s"], rel=1e-6)
+    assert [trace.loop_trips(events, p, want["layers"]) for p in decode] \
+        == want["decode_steps"]
+    names = [name for name, _ in trace.top_ops(events, 5)]
+    assert names == want["top_ops"]
+
+
+def test_a_stall_of_the_host_leaves_its_cause_in_the_report():
+    """The garbage collector's pauses of 20 ms and more are kept with
+    their time, shorter ones not."""
+    import gc
+
+    from benchmark.harness import GcMeter
+
+    meter = GcMeter().install()
+    try:
+        gc.collect()
+        n = len(meter.pauses)
+        meter._on_gc("start", {"generation": 2})
+        meter._began -= 0.5  # as if the collection had taken half a second
+        meter._on_gc("stop", {"generation": 2})
+    finally:
+        meter.remove()
+    assert len(meter.pauses) == n + 1
+    at, took, generation = meter.pauses[-1]
+    assert took >= 0.5 and generation == 2
+    gc.collect()
+    assert len(meter.pauses) == n + 1  # removed: no longer listening
+
+
+def test_live_rows_and_tokens_from_records():
+    records = [_record(0.0, 1.0, 3.0, 21, prompt=100),
+               _record(0.0, 2.0, 4.0, 41, prompt=200)]
+    rows, tokens = trace.live_rows_and_tokens(records, 2.0, 3.0, points=100)
+    assert rows == pytest.approx(2.0)
+    # halfway: 100 + 0.75 * 21 and 200 + 0.25 * 41
+    assert tokens == pytest.approx(100 + 15.75 + 200 + 10.25, rel=1e-3)
+
+
+# ---- roofline counts and peaks ------------------------------------------
+
+
+@pytest.mark.parametrize("layers", [16, 30])  # as run; as published
+def test_roofline_counts_from_shapes(layers):
+    import tomllib
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "starcoder2-3b.toml"), "rb") as fh:
+        config = tomllib.load(fh)
+    assert config["model"]["n_layers"] == 16
+    assert config["published"]["num_hidden_layers"] == 30
+    model = dict(config["model"], n_layers=layers)
+    layer = 95_944_704  # 3072 x 3584 + 3072 x 3072 + 2 x 3072 x 12288
+    total = layers * layer + 49152 * 3072
+    assert roofline.layer_params(model) == layer
+    assert roofline.matrix_params(model) == total
+    peak = roofline.peaks("TPU v5 lite")
+    step = roofline.decode_step(model, rows=32, live_tokens=32 * 600)
+    assert step["bytes"] == 2 * total + roofline.kv_bytes_per_token(model) \
+        * (32 * 600 + 32)
+    # a decode step at these batch sizes is bound by memory, not compute
+    assert step["bytes"] / peak["hbm_bytes_per_s"] \
+        > step["flops"] / peak["bf16_flops_per_s"]
+    assert roofline.least_seconds(step, peak, 4) == pytest.approx(
+        roofline.least_seconds(step, peak, 1) / 4)
+
+
+def test_peaks_table_and_unknown_device():
+    peak = roofline.peaks("TPU v5 lite")
+    assert peak == {"bf16_flops_per_s": 197e12, "int8_ops_per_s": 393e12,
+                    "hbm_bytes_per_s": 819e9, "hbm_bytes": 16e9}
+    with pytest.raises(KeyError):
+        roofline.peaks("cpu")
+
+
+# ---- BENCHMARK.json against the files ------------------------------------
+
+
+def test_every_named_thing_has_its_file():
+    found = metrics.readers()
+    for m in BENCH["per_layer"]:
+        assert m["name"] in found, m["name"]
+    # every reader file is read by some cell: none is kept for later
+    read = {m["name"].removesuffix(".closed") for m in BENCH["per_layer"]}
+    assert {name.removesuffix(".closed") for name in found} == read
+    for kind, names in (("cells", CELLS),
+                        ("traffic", [w["traffic"]
+                                     for w in BENCH["workloads"]]),
+                        ("configs", [c["name"] for c in BENCH["configs"]])):
+        files = os.listdir(os.path.join(REPO, "benchmark", kind))
+        assert sorted(f.rsplit(".", 1)[0] for f in files) == sorted(names)
+    for name in CELLS:
+        cell = cellspec.load_cell(name)
+        names = {m["name"] for m in cell.end_to_end}
+        assert "setup_s" in names and len(names) >= 2
+        assert cell.per_layer
+        assert set(cell.load["check"]["limits"]) \
+            == {"token_gap_max", "token_gap_mean"}
+    four = [w for w in BENCH["workloads"] if w["chips"] == 4]
+    assert len(four) <= max(1, len(BENCH["workloads"]) // 4)
+
+
+# ---- one whole run at a probe size, on the CPU ---------------------------
+
+
+@pytest.fixture(scope="module")
+def probe(tmp_path_factory):
+    return probe_tree(str(tmp_path_factory.mktemp("checkout")))
+
+
+def _measure(root, seed, **kw):
+    """One run through ``run.measure``, past its look for a chip. The
+    runtime builds its mesh from ``jax.devices()``: of the eight virtual
+    devices the tests have, it is handed one."""
+    import time
+
+    import jax
+
+    from benchmark import run
+
+    one = jax.devices()[:1]
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "devices", lambda *a, **k: one)
+        return _measure_one(root, seed, run, time, **kw)
+
+
+def _measure_one(root, seed, run, time, name="probe.tiny", **kw):
+    cell = cellspec.load_cell(name, repo=root)
+    device = {"platform": "cpu", "kind": "cpu", "count": 1}
+    said = []
+    line = run.measure(cell, seed, 4.0, False, device,
+                       t_process=time.monotonic(), say=said.append, **kw)
+    return cell, line, said
+
+
+def test_new_files_and_entries_are_enough(probe):
+    """A configuration, a mix, a cell and a per-layer metric added as
+    files and entries: the harness finds each by name."""
+    cell = cellspec.load_cell("probe.tiny", repo=probe)
+    assert cell.config["model"]["d_model"] == 64
+    assert cell.traffic["prompt"]["median"] == 48
+    assert "probe_requests" in {m["name"] for m in cell.per_layer}
+    found = metrics.readers(os.path.join(cell.root, "metrics"))
+    assert found["probe_requests"]({"window": [1, 2, 3]}) == 3.0
+    # the committed cells do not see the newcomer's metric
+    real = cellspec.load_cell(CELLS[0], repo=probe)
+    assert "probe_requests" not in {m["name"] for m in real.per_layer}
+
+
+@pytest.fixture(scope="module")
+def probe_run(probe, tmp_path_factory):
+    """One traced-style run (per-layer metrics, report written)."""
+    out = str(tmp_path_factory.mktemp("out"))
+    cell, line, said = _measure(probe, 5, layers=True, out_dir=out)
+    with open(os.path.join(out, "probe.tiny", "seed5-trace0.json")) as fh:
+        return cell, line, said, json.load(fh)
+
+
+def test_a_whole_run_on_the_cpu_is_correct(probe_run):
+    cell, line, said, report = probe_run
+    assert report["work"]["requests"] == 15 and report["line"] == line
+    assert line["correct"] is True, said
+    assert line["failed"] == 0 and line["attempted"] == 12
+    got = line["metrics"]
+    assert got["probe_requests"]["value"] == 12.0
+    assert got["window_compiles"]["value"] == 0.0
+    assert got["queue_wait_ms"]["value"] >= 0.0
+    assert 0.0 < got["batch_occupancy_pct.closed"]["value"] <= 100.0
+    assert got["delivered_tok_s.closed"]["value"] > 0.0
+    assert got["setup_ramp_s"]["value"] == 1.0
+    # nothing traced: the trace's metrics are left out, not invented
+    assert "device_idle_pct" not in got
+    assert any("token_gap_max" in s and "limit" in s for s in said)
+
+
+def test_a_run_reports_the_end_to_end_metrics(probe):
+    cell, line, said = _measure(probe, 2**31 + 77)
+    assert set(line["metrics"]) == {"ttft_p90_ms", "tpot_p50_ms",
+                                    "out_tok_s", "setup_s"}
+    assert all(v["value"] > 0 for v in line["metrics"].values())
+    assert line["device"]["platform"] == "cpu"
+    assert "breakdown" not in line
+
+
+def test_a_closed_loop_run_on_the_cpu(probe):
+    """The committed cell's kind of load: clients that send their next
+    request when the last one ends, the first cut to a residual life;
+    what is under way at the close is cut and is no failure."""
+    cell, line, said = _measure(probe, 11, name="probe.tinyclosed")
+    assert set(line["metrics"]) == {"out_tok_s", "setup_s"}
+    assert line["metrics"]["out_tok_s"]["value"] > 0
+    assert line["failed"] == 0 and line["attempted"] >= 3
+    assert any("token_gap_mean" in s and s.endswith("ok") for s in said)
+    # three rows of a few dozen tokens end together now and then, and the
+    # server then dispatches a short window in a variant no warm-up reached
+    # (the committed cell's rows never all end within one window): such a
+    # run is not correct, and the check names the program
+    lowered = [s for s in said if "window_compiles" in s][0]
+    assert line["correct"] is lowered.endswith("ok"), said
+    assert lowered.endswith("ok") or "paged_decode_window" in lowered
+
+
+def test_a_closed_loops_chains_are_the_mix_s_not_the_seed_s(probe):
+    """Which requests start, prefill and end inside the window is the
+    same for every seed: the seed deals fixed chains out among the
+    clients (and draws the token ids)."""
+    cell = cellspec.load_cell("probe.tinyclosed", repo=probe)
+    plans = [schedule.build(cell.traffic, cell.load, seed, 4.0, 256)
+             for seed in (1, 2, 3, 4)]
+    chains = []
+    for plan in plans:
+        by_client: dict = {}
+        for r in plan["requests"]:
+            by_client.setdefault(r["client"], []).append(
+                (r["due"], r["prompt"], r["n_new"]))
+        chains.append(by_client)
+    assert all(sorted(c.values()) == sorted(chains[0].values())
+               for c in chains)
+    assert any(c != chains[0] for c in chains)
+    firsts = sorted(chain[0] for chain in chains[0].values())
+    assert [due for due, _, _ in firsts] == pytest.approx(
+        [-1.0 + 0.5 * (j + 0.5) / 3 for j in range(3)])
+    full = schedule.paired_grid(cell.traffic, 3 * 40)
+    assert all(n_new <= max(o for _, o in full) for _, _, n_new in firsts)
+    assert len({n_new for _, _, n_new in firsts}) == 3  # cut by its share
+
+
+def test_a_token_altered_where_it_is_produced_is_not_correct(
+        probe, monkeypatch):
+    """The timed path broken underneath: every eleventh token a harvested
+    window emits is swapped for another, and ``correct`` comes out false."""
+    from kvedge_tpu.models.serving import PagedGenerationServer
+
+    emit_many = PagedGenerationServer._emit_many
+    count = {"n": 0}
+
+    def altered(req, tokens):
+        out = []
+        for token in tokens:
+            count["n"] += 1
+            out.append((token + 1) % 256 if count["n"] % 11 == 0 else token)
+        emit_many(req, out)
+
+    monkeypatch.setattr(PagedGenerationServer, "_emit_many",
+                        staticmethod(altered))
+    cell, line, said = _measure(probe, 9)
+    assert line["failed"] == 0
+    assert line["correct"] is False, said
+    assert any("token_gap_max" in s and "FAILED" in s for s in said)
+
+
+def test_the_int8_control_reads_wider_gaps():
+    """The control at a size a test can hold: over the same streams, the
+    reference computed in int8 puts first tokens that lie several times
+    further below the float32 reference's best than the ones bf16, the
+    precision a sound run serves in, puts first. (On the chip, at the
+    cells' sizes and against the program itself: PERF.md.)"""
+    from benchmark import reference
+
+    model = {"vocab": 2048, "d_model": 256, "n_heads": 4, "n_kv_heads": 2,
+             "n_layers": 4, "d_ff": 512}
+    weights = reference.make_weights(model)
+    streams = [{"index": i, "prompt": 32, "n_new": 480,
+                "tokens": schedule.prompt_tokens(9, 100 + i, 480, 2048)}
+               for i in range(3)]
+    sound = check.control_gaps(model, weights, streams, 9, 2048, reference,
+                               quant="bf16")
+    control = check.control_gaps(model, weights, streams, 9, 2048, reference)
+    assert sound["tokens"] == control["tokens"] == 1440
+    assert control["token_gap_mean"] > 3 * sound["token_gap_mean"] > 0
+    assert control["differ"] > 2 * sound["differ"]
+
+
+def test_the_check_reads_gaps_against_a_reference():
+    class Ref:
+        @staticmethod
+        def logits(model, weights, sequences, first):
+            import numpy as np
+            out = []
+            for seq, f in zip(sequences, first):
+                rows = np.zeros((len(seq) - f, 8), np.float32)
+                rows[:, 3] = 1.0      # the reference always prefers 3
+                rows[:, 5] = 0.75
+                out.append(rows)
+            return out
+
+    served = {"index": 0, "prompt": 4, "n_new": 4, "tokens": [3, 5, 3, 3],
+              "due": 0.0, "first": 0.1, "last": 0.2, "error": None}
+    numbers = check.token_gaps({}, {}, [served], 1, 8, Ref)
+    assert numbers["tokens"] == 4 and numbers["differ"] == 1
+    assert numbers["token_gap_max"] == pytest.approx(0.25)
+    assert numbers["token_gap_mean"] == pytest.approx(0.0625)
+    said = []
+    assert check.verdict(numbers, {"token_gap_max": 0.3,
+                                   "token_gap_mean": 0.1}, said.append)
+    assert not check.verdict(numbers, {"token_gap_max": 0.2,
+                                       "token_gap_mean": 0.1}, said.append)
+    picked = check.sample(
+        [dict(served, index=i, prompt=4 + i) for i in range(6)], 3, 1.0, 3,
+        "open")
+    assert len(picked) == 3 and picked[0]["prompt"] == 9
+
+
+def test_the_command_refuses_a_cpu_backend():
+    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
+    done = subprocess.run(
+        [sys.executable, "-m", "benchmark.run", "--workload", CELLS[0],
+         "--seed", "1", "--seconds", "1", "--trace", "0"],
+        cwd=REPO, env=env, capture_output=True, text=True, timeout=300)
+    assert done.returncode != 0
+    assert "correct" not in done.stdout
+    assert "not a tpu" in done.stderr
