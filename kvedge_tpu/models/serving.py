@@ -42,6 +42,12 @@ from kvedge_tpu.runtime.failures import (
 )
 from kvedge_tpu.runtime.journal import JournalEntry, RequestJournal
 from kvedge_tpu.models.scheduler import AdmissionScheduler, _Hist
+from kvedge_tpu.runtime.tracing import (
+    LOOP_PHASES,
+    Phase,
+    PhaseClock,
+    PhaseSum,
+)
 
 # Stream sentinel objects (token queue carries ints, then one of these).
 _STREAM_DONE = object()
@@ -305,6 +311,16 @@ class PagedGenerationServer:
         # or thread state, so it survives revive() and slice
         # reformation unchanged.
         self.tracer = tracer
+        # Exact counts at the loop's own boundaries (plain ints, under
+        # the lock): decode steps the device ran (sum of window
+        # lengths), row-steps that produced a token, row-steps the
+        # device computed (bucket x window), pages holding tokens of
+        # live rows x window length, and tokens recorded into requests.
+        self._decode_steps = 0
+        self._decode_row_steps = 0
+        self._decode_bucket_steps = 0
+        self._pages_live_steps = 0
+        self._tokens_emitted = 0
         # Device-window cap (steps per dispatched greedy decode scan).
         # The host round trip per dispatch is the paged path's tax — a
         # window amortizes it ~window x.
@@ -394,6 +410,42 @@ class PagedGenerationServer:
         # every token: a stall inflates the request's mean.
         self._hist_itl = _Hist((0.5, 1.0, 2.0, 5.0, 10.0, 20.0,
                                 50.0, 100.0, 200.0, 500.0))
+        # The submit path from inside (phases admit/lock_wait and
+        # admit/prefill_chunk, once per prefill chunk), and the
+        # server's own delay after the first token is picked: pick to
+        # that token's put on the stream (or its append, buffered).
+        _wait_edges = (1.0, 2.0, 5.0, 10.0, 20.0, 50.0, 100.0, 200.0,
+                       500.0, 1000.0, 2000.0, 5000.0, 10000.0)
+        self._hist_prefill_wait = _Hist(_wait_edges)
+        self._hist_prefill_chunk = _Hist(_wait_edges)
+        self._hist_first_emit = _Hist(_wait_edges)
+        # Phases (runtime/tracing.py): named stretches of the decode
+        # loop's and the submit path's time, each with the accumulator
+        # it adds to. Always on as these accumulators (stats() exports
+        # them), profiler annotations while a capture is live, ring
+        # spans when the tracer is on.
+        self._phase = PhaseClock({
+            "loop/lock_wait": PhaseSum(),
+            "loop/wait_work": PhaseSum(),
+            "loop/boundary": PhaseSum(),
+            "loop/dispatch": PhaseSum(),
+            "loop/harvest_wait": self._hist_device,
+            "loop/emit": self._hist_host,
+            "admit/lock_wait": self._hist_prefill_wait,
+            "admit/prefill_chunk": self._hist_prefill_chunk,
+            "admit/first_pick": PhaseSum(),
+        }, tracer, chained=LOOP_PHASES)
+        # The time the loop thread has run, by its own clock (what its
+        # phases must add up to): finished threads in _loop_ran, the
+        # live one since _loop_since, both written by that thread
+        # alone, outside the lock. _lock_wait is the loop's next wait
+        # for the lock, made ahead while it still holds it: how fast
+        # each side asks again after a release decides who wins the
+        # lock, so nothing is built between a release and the next
+        # acquire that was not built there before phases.
+        self._loop_ran = PhaseSum()
+        self._loop_since = 0.0
+        self._lock_wait = self._phase("loop/lock_wait")
         # Completion counters (goodput / shed-rate SLIs): requests
         # that finished NORMALLY and the generated tokens they
         # realized. Cancels/failures don't count — goodput is good.
@@ -1126,6 +1178,15 @@ class PagedGenerationServer:
             if shared_tokens:
                 self._prefix_hits += 1
                 self._prefix_tokens_saved += shared_tokens
+            # This thread's next wait for the lock is made and started
+            # while it still holds it, here and after every chunk:
+            # between a release and the next acquire stands nothing
+            # that was not there before phases (a thread that asks
+            # again at once beats the decode loop, which the release
+            # only wakes; three microseconds more and it loses, and
+            # its next chunk waits a window).
+            off = shared_tokens  # cached prefix K/V are already in place
+            wait = self._admit_wait(req, off)
         # Prefill in chunks, the lock held only PER CHUNK: the decode
         # loop interleaves batched steps for in-flight requests between
         # chunks (they never touch this slot — the loop's active mask
@@ -1137,43 +1198,57 @@ class PagedGenerationServer:
         # mutations must serialize against the step loop.
         chunk = self._prefill_chunk or len(req.prompt)
         activated = False
-        t_prefill = time.perf_counter()
         try:
             logits = None
-            off = shared_tokens  # cached prefix K/V are already in place
             while off < len(req.prompt):
                 piece = req.prompt[off:off + chunk]
                 with self._work:
+                    wait.stop()
                     if self._closed:
                         raise self._refusal()
                     if req.cancelled:
                         raise RequestCancelled(
                             "request cancelled during prefill"
                         )
-                    logits = self._cache.prefill_chunk(
-                        self._params, slot,
-                        jnp.asarray(piece, jnp.int32), off,
-                    )
-                off += len(piece)
+                    with self._phase("admit/prefill_chunk", rid=req.rid,
+                                     ring=req.trace,
+                                     args={"off": off, "n": len(piece)}):
+                        logits = self._cache.prefill_chunk(
+                            self._params, slot,
+                            jnp.asarray(piece, jnp.int32), off,
+                        )
+                    off += len(piece)
+                    wait = self._admit_wait(req, off)
             with self._work:
-                # Re-check under the activation lock: a hard close can
-                # land between the last chunk and here, after which no
-                # loop is alive to serve (or poison) this request.
-                if self._closed:
-                    raise self._refusal()
-                req.next_token = req.pick(logits, 0)
-                t_first = time.perf_counter()
+                picked = wait
+                try:
+                    # Re-check under the activation lock: a hard close
+                    # can land between the last chunk and here, after
+                    # which no loop is alive to serve (or poison) this
+                    # request.
+                    if self._closed:
+                        raise self._refusal()
+                    # The pick reads the logits back: this thread
+                    # waits, lock held, for the chunks above and for
+                    # whatever window the device was given before them.
+                    req.next_token = req.pick(logits, 0)
+                finally:
+                    picked.stop()
+                t_first = picked.t1
                 # Time to first token: submit -> the prefill logits'
                 # pick. This is the serving-visible TTFT (the first
                 # emission rides the next loop iteration, but the
-                # token is decided here). The stamp is kept on the
-                # request: finish pairs it with the final token for
-                # the per-request inter-token gap (rung 25).
+                # token is decided here; first_emit_ms is that ride).
+                # The stamp is kept on the request: finish pairs it
+                # with the final token for the per-request inter-token
+                # gap (rung 25).
                 req.t_first = t_first
                 self._hist_ttft.observe((t_first - req.t_submit) * 1e3)
                 if req.trace:
+                    # Admission to the pick: the parent, by time, of
+                    # this request's chunk phases in the ring.
                     self.tracer.span(
-                        "prefill", "serve", t_prefill, t_first,
+                        "prefill", "serve", req.t_admit, t_first,
                         rid=req.rid,
                         args={"prompt": len(req.prompt),
                               "shared": shared_tokens,
@@ -1208,6 +1283,14 @@ class PagedGenerationServer:
                     self._poison_locked(e)
             raise
         return req
+
+    def _admit_wait(self, req: _Request, off: int) -> Phase:
+        """The submit path's next wait for the lock, started (lock
+        still held): for its next prefill chunk, or past the prompt's
+        end for the pick of its first token."""
+        return self._phase(
+            "admit/lock_wait" if off < len(req.prompt)
+            else "admit/first_pick", rid=req.rid, ring=req.trace).start()
 
     # ---- capacity semantics (SERVING.md rung 21) ------------------------
 
@@ -2652,6 +2735,8 @@ class PagedGenerationServer:
         return out
 
     def _stats_core_locked(self) -> dict:
+        now = time.perf_counter()
+        phase_ms = self._phase.snapshot(now)
         out = {
             "degraded": 1 if self._degraded_reason else 0,
             "in_flight": len(self._active),
@@ -2733,6 +2818,33 @@ class PagedGenerationServer:
                 self._spec_window_fallbacks
             ),
             "stop_finishes_total": self._stop_finishes,
+            # The clock inside the loop and the submit path (phases,
+            # runtime/tracing.py). clock_s is stamped inside this lock
+            # hold: a reader divides a counter's difference by the
+            # time between the two snapshots it has, not by the time
+            # it asked for them (each waits for the lock).
+            "clock_s": now,
+            "phase_ms": phase_ms,
+            "loop_ms_total": self._loop_ms(now),
+            # The loop's phases are a chain: whatever it does outside
+            # its two waits, it does holding the lock.
+            "loop_lock_held_ms_total": sum(
+                phase_ms[name][1] for name in LOOP_PHASES
+                if name not in ("loop/lock_wait", "loop/wait_work")),
+            "prefill_lock_wait_ms": self._hist_prefill_wait.snapshot(),
+            "prefill_chunk_ms": self._hist_prefill_chunk.snapshot(),
+            "first_emit_ms": self._hist_first_emit.snapshot(),
+            # Exact counts at the loop's own boundaries: decode steps
+            # (sum of window lengths), row-steps that produced a token
+            # over row-steps the device computed (live rows over
+            # bucket rows), pages in use x steps (over steps x
+            # pages_total: pages in use against reserved_pages), and
+            # tokens recorded into requests.
+            "decode_steps_total": self._decode_steps,
+            "decode_row_steps_total": self._decode_row_steps,
+            "decode_bucket_steps_total": self._decode_bucket_steps,
+            "pages_live_steps_total": self._pages_live_steps,
+            "tokens_emitted_total": self._tokens_emitted,
         }
         if self._autotune is not None:
             # Online window controller (SERVING.md rung 26): the
@@ -3062,6 +3174,44 @@ class PagedGenerationServer:
             for t in (tokens[skip:] if skip > 0 else tokens):
                 put(t)
 
+    def _note_emitted_locked(self, req: _Request, before: int) -> None:
+        """Book one row's emission (lock held; ``before`` is
+        ``len(req.generated)`` before it): one addition per row,
+        replays after a journal restore included, and for a request's
+        first tokens the ``first_emit_ms`` observation — the pick of
+        the first token (``t_first``) to its put on the stream, or
+        its append for a buffered request."""
+        self._tokens_emitted += len(req.generated) - before
+        if (before == 0 and req.generated
+                and req.stream_resume_at == 0):
+            self._hist_first_emit.observe(
+                (time.perf_counter() - req.t_first) * 1e3)
+
+    def _emit_pending_locked(self, req: _Request) -> None:
+        """Emit the request's pending token alone, and book it."""
+        before = len(req.generated)
+        self._emit(req, req.next_token)
+        self._note_emitted_locked(req, before)
+
+    def _count_steps_locked(self, steps: int, bucket: int,
+                            rows) -> None:
+        """Book one window (or single step, or verify pass) of
+        ``steps`` decode steps over ``bucket`` device rows, before its
+        ``rows`` ((slot, request), live and still admitted) emit and
+        release: the steps, the row-steps the device computed, and
+        the pages in use times the steps — pages holding tokens of
+        the rows, by the lengths the cache keeps on the host (a window
+        still in flight included), a shared prefix page once however
+        many rows lease it. O(rows)."""
+        self._decode_steps += steps
+        self._decode_bucket_steps += bucket * steps
+        page = self._cache.page_size
+        live = len(self._lease)
+        for slot, req in rows:
+            live += (-(-self._cache.slot_length(slot) // page)
+                     - len(req.shared_pages))
+        self._pages_live_steps += live * steps
+
     @staticmethod
     def _draft(req: _Request, k: int) -> list[int]:
         """K prompt-lookup drafts for a greedy request (host-side
@@ -3087,56 +3237,69 @@ class PagedGenerationServer:
         key-schedule exactness holds unchanged."""
         k = self._spec
         n = self._cache.bucket
-        tokens = np.zeros((n, k + 1), np.int32)
-        mask = np.zeros((n,), bool)
-        spec_mask = np.zeros((n,), bool)
-        for slot, req in self._active.items():
-            tokens[slot, 0] = req.next_token
-            mask[slot] = True
-            if req.sampling is None:
-                spec_mask[slot] = True
-                tokens[slot, 1:] = self._draft(req, k)
-        emitted, accepted, logits0 = self._cache.step_spec(
-            self._params, tokens, active=mask, spec_mask=spec_mask
-        )
-        emitted = np.asarray(emitted)
-        sampled_next = self._sample_slots(logits0, {
-            slot: req for slot, req in self._active.items()
-            if req.sampling is not None
-        })
-        self._spec_passes += 1
-        for slot in list(self._active):
-            req = self._active[slot]
-            if req.sampling is not None:
-                self._emit(req, req.next_token)
-                req.next_token = sampled_next[slot]
-                self._note_finish_candidate_locked(slot, req)
-                continue
-            a = int(accepted[slot])
-            room = req.n_new - len(req.generated)
-            seq = [req.next_token] + [int(t) for t in emitted[slot, :a]]
-            emit_n, stopped = 0, False
-            for t in seq[:room]:
-                self._emit(req, t)
-                emit_n += 1
-                if t == req.stop_token:
-                    stopped = True
-                    break
-            self._spec_emitted += emit_n
-            self._spec_slot_passes += 1
-            if stopped:
-                # Passes run at boundaries only (nothing in flight):
-                # the stop finish never needs the deferred path.
-                self._stop_finishes += 1
-                self._finish_request_locked(slot, req)
-            elif len(req.generated) >= req.n_new:
-                self._finish_request_locked(slot, req)
-            else:
-                # room > len(seq) here: room <= len(seq) means the
-                # request just filled its budget and took the finished
-                # branch above. The bonus token becomes pending.
-                req.next_token = int(emitted[slot, a])
-                self._note_finish_candidate_locked(slot, req)
+        phase = self._phase
+        with phase("loop/dispatch"):
+            tokens = np.zeros((n, k + 1), np.int32)
+            mask = np.zeros((n,), bool)
+            spec_mask = np.zeros((n,), bool)
+            for slot, req in self._active.items():
+                tokens[slot, 0] = req.next_token
+                mask[slot] = True
+                if req.sampling is None:
+                    spec_mask[slot] = True
+                    tokens[slot, 1:] = self._draft(req, k)
+        with phase("loop/harvest_wait",
+                   args={"rows": len(self._active), "spec": k}):
+            emitted, accepted, logits0 = self._cache.step_spec(
+                self._params, tokens, active=mask, spec_mask=spec_mask
+            )
+            emitted = np.asarray(emitted)
+            sampled_next = self._sample_slots(logits0, {
+                slot: req for slot, req in self._active.items()
+                if req.sampling is not None
+            })
+        with phase("loop/emit"):
+            self._spec_passes += 1
+            # One verify pass is one decode step of every active row.
+            self._count_steps_locked(1, n, self._active.items())
+            self._decode_row_steps += len(self._active)
+            for slot in list(self._active):
+                req = self._active[slot]
+                if req.sampling is not None:
+                    self._emit_pending_locked(req)
+                    req.next_token = sampled_next[slot]
+                    self._note_finish_candidate_locked(slot, req)
+                    continue
+                a = int(accepted[slot])
+                before = len(req.generated)
+                room = req.n_new - before
+                seq = [req.next_token] + [int(t)
+                                          for t in emitted[slot, :a]]
+                emit_n, stopped = 0, False
+                for t in seq[:room]:
+                    self._emit(req, t)
+                    emit_n += 1
+                    if t == req.stop_token:
+                        stopped = True
+                        break
+                self._note_emitted_locked(req, before)
+                self._spec_emitted += emit_n
+                self._spec_slot_passes += 1
+                if stopped:
+                    # Passes run at boundaries only (nothing in
+                    # flight): the stop finish never needs the
+                    # deferred path.
+                    self._stop_finishes += 1
+                    self._finish_request_locked(slot, req)
+                elif len(req.generated) >= req.n_new:
+                    self._finish_request_locked(slot, req)
+                else:
+                    # room > len(seq) here: room <= len(seq) means the
+                    # request just filled its budget and took the
+                    # finished branch above. The bonus token becomes
+                    # pending.
+                    req.next_token = int(emitted[slot, a])
+                    self._note_finish_candidate_locked(slot, req)
 
     def _window_steps(self) -> int:
         """Steps the next device-side decode window may run (lock held).
@@ -3320,10 +3483,10 @@ class PagedGenerationServer:
                 # token last) was emitted at harvest time.
                 self._finish_request_locked(slot, req)
             elif len(req.generated) + 1 >= req.n_new:
-                self._emit(req, req.next_token)
+                self._emit_pending_locked(req)
                 self._finish_request_locked(slot, req)
             elif req.next_token == req.stop_token:
-                self._emit(req, req.next_token)
+                self._emit_pending_locked(req)
                 self._stop_finishes += 1
                 self._finish_request_locked(slot, req)
         self._finish_ready.clear()
@@ -3506,31 +3669,56 @@ class PagedGenerationServer:
     def _loop(self) -> None:
         step = (self._loop_once_overlap if self._overlap_on
                 else self._loop_once)
+        self._loop_since = self._phase.mark()
         while True:
-            if step() == "exit":
+            # From here to the lock held (the step stops the phase):
+            # the loop's wait for the lock, in which prefill chunks run.
+            with self._lock_wait:
+                # Fair handoff: the loop would otherwise reacquire the
+                # lock immediately, and under CPython's GIL an admission
+                # waiter whose timeout already expired can lose that
+                # race at EVERY boundary while device steps hold the
+                # lock (lock convoy — observed as a waiter never getting
+                # to raise ServerBusy until the occupying request
+                # finished). One zero-sleep with the lock released
+                # yields the GIL so waiters can take it.
+                # locklint: allow[sleep-under-lock] deliberate GIL yield with the lock RELEASED — breaks the decode loop's lock convoy so expired admission waiters win the reacquisition race (rung 17 fair handoff; removing it starves ServerBusy)
+                time.sleep(0)
+                verdict = step()
+            if verdict == "exit":
+                self._loop_ran.observe(
+                    (time.perf_counter() - self._loop_since) * 1e3)
+                self._loop_since = 0.0
                 if self._poison is not None:
                     self._degrade()  # outside the lock, loop exited
                 return
-            # Fair handoff: the loop would otherwise reacquire the lock
-            # immediately, and under CPython's GIL an admission waiter
-            # whose timeout already expired can lose that race at EVERY
-            # boundary while device steps hold the lock (lock convoy —
-            # observed as a waiter never getting to raise ServerBusy
-            # until the occupying request finished). One zero-sleep with
-            # the lock released yields the GIL so waiters can take it.
-            # locklint: allow[sleep-under-lock] deliberate GIL yield with the lock RELEASED — breaks the decode loop's lock convoy so expired admission waiters win the reacquisition race (rung 17 fair handoff; removing it starves ServerBusy)
-            time.sleep(0)
+
+    def _loop_ms(self, now: float) -> float:
+        """Milliseconds the loop thread has run by ``now``: what its
+        phases add up to."""
+        since = self._loop_since
+        live = (now - since) * 1e3 if since else 0.0
+        return self._loop_ran.total + live
+
+    def _lock_taken_locked(self) -> None:
+        """The first thing the loop does with the lock: end its wait
+        for it and make the next one."""
+        self._lock_wait.stop()
+        self._lock_wait = self._phase("loop/lock_wait")
 
     def _loop_once(self) -> str:
         """One decode-loop iteration under the lock ("exit" ends it)."""
         import jax.numpy as jnp
 
+        phase = self._phase
         with self._work:
+            self._lock_taken_locked()
             while (not self._active and not self._closed
                    and not self._sched_attention_locked()
                    and not (self._draining
                             and not self._prefilling)):
-                self._work.wait()
+                with phase("loop/wait_work"):
+                    self._work.wait()
             if (self._draining and not self._active
                     and not self._prefilling
                     and not self._sched.resume_pending_locked()):
@@ -3550,15 +3738,17 @@ class PagedGenerationServer:
                 self._fail_swapped_closed_locked()
                 return "exit"
             try:
-                self._sweep_cancelled_locked()
-                self._sweep_finished_locked()
-                # Scheduler boundary: resume swapped-out requests into
-                # freed capacity, then preempt for a starved head.
-                self._maybe_resume_locked()
-                self._maybe_preempt_locked()
-                self._maybe_step_bucket_locked()
-                self._maybe_checkpoint_locked()
-                self._observe_boundary_locked()
+                with phase("loop/boundary"):
+                    self._sweep_cancelled_locked()
+                    self._sweep_finished_locked()
+                    # Scheduler boundary: resume swapped-out requests
+                    # into freed capacity, then preempt for a starved
+                    # head.
+                    self._maybe_resume_locked()
+                    self._maybe_preempt_locked()
+                    self._maybe_step_bucket_locked()
+                    self._maybe_checkpoint_locked()
+                    self._observe_boundary_locked()
                 if not self._active:
                     return "ran"
                 if (self._spec > 0
@@ -3579,12 +3769,15 @@ class PagedGenerationServer:
                 # The explicit mask (not "every admitted slot") is
                 # what keeps interleaved chunked prefills safe: a
                 # half-prefilled slot is admitted but NOT active.
-                tokens = np.zeros((self._cache.bucket,), np.int32)
-                mask = np.zeros((self._cache.bucket,), bool)
-                for slot, req in self._active.items():
-                    tokens[slot] = req.next_token
-                    mask[slot] = True
-                window = self._window_steps()
+                with phase("loop/dispatch"):
+                    tokens = np.zeros((self._cache.bucket,), np.int32)
+                    mask = np.zeros((self._cache.bucket,), bool)
+                    for slot, req in self._active.items():
+                        tokens[slot] = req.next_token
+                        mask[slot] = True
+                    window = self._window_steps()
+                self._count_steps_locked(window, self._cache.bucket,
+                                         self._active.items())
                 if window > 1:
                     # Device-side window: `window` steps in one
                     # dispatched scan — the host pays one round trip
@@ -3602,89 +3795,90 @@ class PagedGenerationServer:
                         for slot, req in self._active.items()
                         if req.sampling is not None
                     }
-                    t0 = time.perf_counter()
-                    if not samplers:
-                        produced = np.asarray(self._cache.step_window(
-                            self._params, jnp.asarray(tokens), window,
-                            active=mask,
-                        ))
-                    else:
-                        produced = np.asarray(self._sampled_window(
-                            tokens, window, mask, samplers
-                        ))
                     # Serial path: the host blocks for the whole
-                    # dispatch+force, so device time IS the call
+                    # dispatch+force, so the harvest wait IS the call
                     # (rung 25 attribution; no pipeline slack here).
-                    self._hist_device.observe(
-                        (time.perf_counter() - t0) * 1e3
-                    )
-                    if self.tracer is not None:
-                        # Fabric span (ungated): every window stamps,
-                        # sampled request spans hang from them.
-                        self.tracer.span(
-                            "window", "serve", t0,
-                            args={"w": window,
-                                  "rows": len(self._active),
-                                  "depth": 0},
-                        )
-                    for slot, req in list(self._active.items()):
-                        self._emit(req, req.next_token)
-                        finished = False
-                        for i in range(window - 1):
-                            t = int(produced[i, slot])
-                            self._emit(req, t)
-                            if t == req.stop_token:
-                                # Host-side stop truncation: the serial
-                                # window path touches every token here
-                                # anyway, so the uncapped kernels carry
-                                # no device-side stop rows. Nothing is
-                                # in flight — finish immediately.
+                    # With the tracer on the phase is the ring's
+                    # fabric span (ungated): every window stamps,
+                    # sampled request spans hang from them.
+                    with phase("loop/harvest_wait",
+                               args={"w": window,
+                                     "rows": len(self._active),
+                                     "depth": 0}):
+                        if not samplers:
+                            produced = np.asarray(
+                                self._cache.step_window(
+                                    self._params, jnp.asarray(tokens),
+                                    window, active=mask,
+                                ))
+                        else:
+                            produced = np.asarray(self._sampled_window(
+                                tokens, window, mask, samplers
+                            ))
+                    with phase("loop/emit"):
+                        for slot, req in list(self._active.items()):
+                            before = len(req.generated)
+                            self._emit(req, req.next_token)
+                            finished = False
+                            for i in range(window - 1):
+                                t = int(produced[i, slot])
+                                self._emit(req, t)
+                                if t == req.stop_token:
+                                    # Host-side stop truncation: the
+                                    # serial window path touches every
+                                    # token here anyway, so the
+                                    # uncapped kernels carry no
+                                    # device-side stop rows. Nothing
+                                    # is in flight — finish
+                                    # immediately.
+                                    finished = True
+                                    break
+                            self._decode_row_steps += (
+                                len(req.generated) - before)
+                            self._note_emitted_locked(req, before)
+                            if finished:
                                 self._stop_finishes += 1
                                 self._finish_request_locked(slot, req)
-                                finished = True
-                                break
-                        if not finished:
-                            req.next_token = int(
-                                produced[window - 1, slot]
-                            )
-                            self._note_finish_candidate_locked(
-                                slot, req
-                            )
+                            else:
+                                req.next_token = int(
+                                    produced[window - 1, slot]
+                                )
+                                self._note_finish_candidate_locked(
+                                    slot, req
+                                )
                     return "ran"
-                t0 = time.perf_counter()
-                if all(req.sampling is None
-                       for req in self._active.values()):
-                    # All-greedy per-step batch: the fused step+argmax
-                    # program (kvcache.step_tokens) — one dispatch and
-                    # a [B]-int read instead of a dispatch, a second
-                    # argmax dispatch, and a [B, V] logits transfer.
-                    # Token-identical: same argmax on the same logits.
-                    picked = np.asarray(self._cache.step_tokens(
-                        self._params, jnp.asarray(tokens), active=mask
-                    ))
-                    next_tokens = {
-                        slot: int(picked[slot])
-                        for slot in self._active
-                    }
-                else:
-                    logits = self._cache.step(
-                        self._params, jnp.asarray(tokens), active=mask
-                    )
-                    next_tokens = self._next_tokens(logits)
-                # Per-step device time (serial path, rung 25): the
+                # Per-step harvest wait (serial path, rung 25): the
                 # pick inside _next_tokens is the forcing read.
-                self._hist_device.observe(
-                    (time.perf_counter() - t0) * 1e3
-                )
-                if self.tracer is not None:
-                    self.tracer.span(
-                        "step", "serve", t0,
-                        args={"rows": len(self._active)},
-                    )
-                for slot, req in self._active.items():
-                    self._emit(req, req.next_token)
-                    req.next_token = next_tokens[slot]
-                    self._note_finish_candidate_locked(slot, req)
+                with phase("loop/harvest_wait",
+                           args={"rows": len(self._active)}):
+                    if all(req.sampling is None
+                           for req in self._active.values()):
+                        # All-greedy per-step batch: the fused
+                        # step+argmax program (kvcache.step_tokens) —
+                        # one dispatch and a [B]-int read instead of a
+                        # dispatch, a second argmax dispatch, and a
+                        # [B, V] logits transfer. Token-identical:
+                        # same argmax on the same logits.
+                        picked = np.asarray(self._cache.step_tokens(
+                            self._params, jnp.asarray(tokens),
+                            active=mask
+                        ))
+                        next_tokens = {
+                            slot: int(picked[slot])
+                            for slot in self._active
+                        }
+                    else:
+                        logits = self._cache.step(
+                            self._params, jnp.asarray(tokens),
+                            active=mask
+                        )
+                        next_tokens = self._next_tokens(logits)
+                with phase("loop/emit"):
+                    self._decode_row_steps += len(self._active)
+                    for slot, req in self._active.items():
+                        self._emit_pending_locked(req)
+                        req.next_token = next_tokens[slot]
+                        self._note_finish_candidate_locked(slot, req)
             except Exception as e:  # poison: fail every waiter loudly
                 # Typed poisoning (runtime/failures.py): an already-
                 # typed failure (e.g. SliceFollowerLost from the op
@@ -3719,13 +3913,16 @@ class PagedGenerationServer:
         length — kvcache._paged_decode_window_capped_impl), and the
         host truncates each row's emitted stream at its own cap.
         """
+        phase = self._phase
         with self._work:
+            self._lock_taken_locked()
             while (not self._active and self._inflight is None
                    and not self._closed
                    and not self._sched_attention_locked()
                    and not (self._draining
                             and not self._prefilling)):
-                self._work.wait()
+                with phase("loop/wait_work"):
+                    self._work.wait()
             if (self._draining and not self._active
                     and self._inflight is None
                     and not self._prefilling
@@ -3751,19 +3948,21 @@ class PagedGenerationServer:
                 return "exit"
             try:
                 if self._inflight is None:
-                    self._sweep_cancelled_locked()
-                    self._sweep_finished_locked()
-                    # Preemption/resume join ONLY here — the
-                    # non-overlapped boundary, where every row's
-                    # tokens are reconciled and cache state is
-                    # quiescent. Checkpoints share the boundary for
-                    # the same reason: the swapout bytes must cover a
-                    # reconciled, nothing-in-flight snapshot.
-                    self._maybe_resume_locked()
-                    self._maybe_preempt_locked()
-                    self._maybe_step_bucket_locked()
-                    self._maybe_checkpoint_locked()
-                    self._observe_boundary_locked()
+                    with phase("loop/boundary"):
+                        self._sweep_cancelled_locked()
+                        self._sweep_finished_locked()
+                        # Preemption/resume join ONLY here — the
+                        # non-overlapped boundary, where every row's
+                        # tokens are reconciled and cache state is
+                        # quiescent. Checkpoints share the boundary
+                        # for the same reason: the swapout bytes must
+                        # cover a reconciled, nothing-in-flight
+                        # snapshot.
+                        self._maybe_resume_locked()
+                        self._maybe_preempt_locked()
+                        self._maybe_step_bucket_locked()
+                        self._maybe_checkpoint_locked()
+                        self._observe_boundary_locked()
                     if not self._active:
                         return "ran"
                     if (self._spec > 0
@@ -3784,11 +3983,12 @@ class PagedGenerationServer:
                             # co-tenants ride the scan too (rung 23,
                             # knob-gated): one token per pass with
                             # their positional keys split on device.
-                            self._inflight = (
-                                self._dispatch_spec_window_locked(
-                                    first=True
+                            with phase("loop/dispatch"):
+                                self._inflight = (
+                                    self._dispatch_spec_window_locked(
+                                        first=True
+                                    )
                                 )
-                            )
                             return "ran"
                         if self._spec_window > 0:
                             # Mixed batch with the sampled-window knob
@@ -3800,13 +4000,16 @@ class PagedGenerationServer:
                         # boundaries only and never overlap.
                         self._spec_pass()
                         return "ran"
-                    self._inflight = self._dispatch_window_locked(
-                        first=True
-                    )
+                    with phase("loop/dispatch"):
+                        self._inflight = self._dispatch_window_locked(
+                            first=True
+                        )
                     return "ran"
                 prev, self._inflight = self._inflight, None
                 try:
-                    if not self._boundary_wanted_locked(prev):
+                    with phase("loop/boundary"):
+                        collapse = self._boundary_wanted_locked(prev)
+                    if not collapse:
                         # Enqueue N+1 on the carry BEFORE touching
                         # N's result — the device starts N+1 the
                         # moment N retires, while the host is still
@@ -3816,11 +4019,12 @@ class PagedGenerationServer:
                         # state); a kind change joins at a boundary.
                         if prev.get("kind") not in ("spec",
                                                     "spec_sampled"):
-                            self._inflight = (
-                                self._dispatch_window_locked(
-                                    first=False
+                            with phase("loop/dispatch"):
+                                self._inflight = (
+                                    self._dispatch_window_locked(
+                                        first=False
+                                    )
                                 )
-                            )
                         elif (self._spec > 0
                               and self._spec_window > 0):
                             # Kind-matched redispatch: both spec kinds
@@ -3829,11 +4033,12 @@ class PagedGenerationServer:
                             # whose sampled rows all finished simply
                             # redispatches as plain "spec" on the same
                             # carry.
-                            self._inflight = (
-                                self._dispatch_spec_window_locked(
-                                    first=False
+                            with phase("loop/dispatch"):
+                                self._inflight = (
+                                    self._dispatch_spec_window_locked(
+                                        first=False
+                                    )
                                 )
-                            )
                         else:
                             # Speculation was disabled with a spec
                             # window in flight — collapse to a
@@ -3988,7 +4193,7 @@ class PagedGenerationServer:
             req.inflight += adv
         self._hist_depth.observe(0.0 if first else 1.0)
         return {"window": w, "parts": recs, "handle": handle,
-                "depth": 0 if first else 1,
+                "depth": 0 if first else 1, "bucket": n,
                 "t0": time.perf_counter()}
 
     def _harvest_locked(self, rec: dict) -> None:
@@ -3997,75 +4202,86 @@ class PagedGenerationServer:
         token. Each row's stream truncates at its own dispatch-time
         cap (``adv``) — rows past their cap were frozen on device and
         their produced entries merely repeat the last live token."""
-        t_force = time.perf_counter()
-        produced = np.asarray(self._cache.harvest_window(rec["handle"]))
-        t_harvest = time.perf_counter()
-        # Device-time attribution (rung 25): the forced transfer is
-        # where the host actually waits on the device — the RTT minus
-        # this is pure host bookkeeping and pipeline slack.
-        self._hist_device.observe((t_harvest - t_force) * 1e3)
-        self._hist_rtt.observe((t_harvest - rec["t0"]) * 1e3)
+        with self._phase("loop/harvest_wait") as waited:
+            produced = np.asarray(
+                self._cache.harvest_window(rec["handle"]))
+        # Attribution (rung 25): the forced transfer is where the host
+        # actually waits on the device (host clock, never device
+        # time) — the RTT minus this is pure host bookkeeping and
+        # pipeline slack.
+        t_harvest = waited.t1
+        rtt_ms = (t_harvest - rec["t0"]) * 1e3
+        self._hist_rtt.observe(rtt_ms)
         if self.tracer is not None:
             # Dispatch -> harvest span with the pipeline depth the
-            # window was dispatched at (0 = boundary, 1 = overlapped).
+            # window was dispatched at (0 = boundary, 1 = overlapped):
+            # it spans the loop's phases, so it is no phase itself.
             self.tracer.span(
                 "window", "serve", rec["t0"], t_harvest,
                 args={"w": rec["window"],
                       "rows": len(rec["parts"]),
                       "depth": rec.get("depth", 0)},
             )
-        t_host = time.perf_counter()
-        rec["counted"] = True
-        self._ckpt_clock += 1  # window of progress at risk (rung 22)
-        for _, req, adv in rec["parts"]:
-            req.inflight -= adv
         w = rec["window"]
-        stop_row = produced[w + 1]
-        for slot, req, adv in rec["parts"]:
-            if self._active.get(slot) is not req or req.stopped:
-                # Released while in flight (hard-close/cancel races
-                # resolve at boundaries, so normally unreachable), or
-                # stop-terminated at an earlier harvest with its
-                # finish deferred — nothing to emit into.
-                continue
-            # Device-resident finish bookkeeping (rung 23): rows
-            # n_steps and n_steps+1 of the harvested block are the
-            # packed per-slot finish reason (0 window-capped /
-            # 1 budget-frozen / 2 stop) and the 1-based step of the
-            # first stop hit — ONE transfer carries tokens and
-            # bookkeeping both, and the host never compares per-token.
-            stop_at = int(stop_row[slot])
-            if 0 < stop_at and not req.cancelled:
-                # Emit the pending token plus everything up to AND
-                # INCLUDING the stop token, then finish; steps past
-                # the stop decoded garbage inside the granted cap and
-                # are discarded (the slot releases, so the device-side
-                # over-advance is moot).
-                room = req.n_new - len(req.generated)
-                seq = [req.next_token]
-                seq += produced[:stop_at, slot].tolist()
-                self._emit_many(req, seq[:room])
-                self._finish_stopped_locked(slot, req)
-                continue
-            # Bulk emission: one C-level column->list conversion per
-            # LIVE row (rows the window advanced — O(changes), idle
-            # bucket slots never touched), one extend, no per-token
-            # Python frames.
-            toks = produced[:adv, slot].tolist()
-            self._emit_many(req, [req.next_token] + toks[:-1])
-            req.next_token = toks[-1]
-            if (len(req.generated) + 1 >= req.n_new
-                    and not req.cancelled):
-                # Inline finish: with the pipeline saturated the loop
-                # may never visit a boundary, so a filled budget must
-                # complete here. The cancelled guard preserves the
-                # serial cancel-beats-finish order — the cancel sweep
-                # at the forced boundary takes it.
-                self._emit(req, req.next_token)
-                self._finish_request_locked(slot, req)
-        self._overlap_windows += 1
-        host_ms = (time.perf_counter() - t_host) * 1e3
-        self._hist_host.observe(host_ms)
+        with self._phase("loop/emit") as emit:
+            rec["counted"] = True
+            self._ckpt_clock += 1  # window of progress at risk (rung 22)
+            for _, req, adv in rec["parts"]:
+                req.inflight -= adv
+            self._decode_row_steps += sum(
+                adv for _, _, adv in rec["parts"])
+            self._count_steps_locked(
+                w, rec["bucket"],
+                [(slot, req) for slot, req, _ in rec["parts"]
+                 if self._active.get(slot) is req])
+            stop_row = produced[w + 1]
+            for slot, req, adv in rec["parts"]:
+                if self._active.get(slot) is not req or req.stopped:
+                    # Released while in flight (hard-close/cancel races
+                    # resolve at boundaries, so normally unreachable),
+                    # or stop-terminated at an earlier harvest with its
+                    # finish deferred — nothing to emit into.
+                    continue
+                before = len(req.generated)
+                # Device-resident finish bookkeeping (rung 23): rows
+                # n_steps and n_steps+1 of the harvested block are the
+                # packed per-slot finish reason (0 window-capped /
+                # 1 budget-frozen / 2 stop) and the 1-based step of the
+                # first stop hit — ONE transfer carries tokens and
+                # bookkeeping both, and the host never compares
+                # per-token.
+                stop_at = int(stop_row[slot])
+                if 0 < stop_at and not req.cancelled:
+                    # Emit the pending token plus everything up to AND
+                    # INCLUDING the stop token, then finish; steps past
+                    # the stop decoded garbage inside the granted cap
+                    # and are discarded (the slot releases, so the
+                    # device-side over-advance is moot).
+                    room = req.n_new - len(req.generated)
+                    seq = [req.next_token]
+                    seq += produced[:stop_at, slot].tolist()
+                    self._emit_many(req, seq[:room])
+                    self._note_emitted_locked(req, before)
+                    self._finish_stopped_locked(slot, req)
+                    continue
+                # Bulk emission: one C-level column->list conversion
+                # per LIVE row (rows the window advanced — O(changes),
+                # idle bucket slots never touched), one extend, no
+                # per-token Python frames.
+                toks = produced[:adv, slot].tolist()
+                self._emit_many(req, [req.next_token] + toks[:-1])
+                self._note_emitted_locked(req, before)
+                req.next_token = toks[-1]
+                if (len(req.generated) + 1 >= req.n_new
+                        and not req.cancelled):
+                    # Inline finish: with the pipeline saturated the
+                    # loop may never visit a boundary, so a filled
+                    # budget must complete here. The cancelled guard
+                    # preserves the serial cancel-beats-finish order —
+                    # the cancel sweep at the forced boundary takes it.
+                    self._emit_pending_locked(req)
+                    self._finish_request_locked(slot, req)
+            self._overlap_windows += 1
         if self._autotune is not None:
             # Close the rung-16 loop (rung 26): feed the controller
             # this window's measured split and adopt its pick for the
@@ -4074,9 +4290,8 @@ class PagedGenerationServer:
             # the device carry is one token row, shape-independent of
             # the window.
             self._autotune.observe(
-                rtt_ms=(t_harvest - rec["t0"]) * 1e3,
-                device_ms=(t_harvest - t_force) * 1e3,
-                host_ms=host_ms, window=w,
+                rtt_ms=rtt_ms, device_ms=waited.ms, host_ms=emit.ms,
+                window=w,
             )
             self._window = self._autotune.window()
 
@@ -4175,7 +4390,7 @@ class PagedGenerationServer:
         return {"kind": "spec_sampled" if samplers else "spec",
                 "window": w, "parts": recs,
                 "handle": handle, "depth": 0 if first else 1,
-                "t0": time.perf_counter()}
+                "bucket": n, "t0": time.perf_counter()}
 
     def _harvest_spec_window_locked(self, rec: dict) -> None:
         """Force an in-flight spec window's results and reconcile
@@ -4186,14 +4401,14 @@ class PagedGenerationServer:
         device-side overshoot (the last live pass may exceed the
         budget by up to K) never over-emits, exactly like the legacy
         path's room cap."""
-        t_force = time.perf_counter()
-        emitted, counts, _pending = self._cache.harvest_spec_window(
-            rec["handle"]
-        )
-        t_harvest = time.perf_counter()
-        # Device-time attribution (rung 25), as in _harvest_locked.
-        self._hist_device.observe((t_harvest - t_force) * 1e3)
-        self._hist_rtt.observe((t_harvest - rec["t0"]) * 1e3)
+        with self._phase("loop/harvest_wait") as waited:
+            emitted, counts, _pending = self._cache.harvest_spec_window(
+                rec["handle"]
+            )
+        # Attribution (rung 25), as in _harvest_locked.
+        t_harvest = waited.t1
+        rtt_ms = (t_harvest - rec["t0"]) * 1e3
+        self._hist_rtt.observe(rtt_ms)
         if self.tracer is not None:
             self.tracer.span(
                 "spec-window", "serve", rec["t0"], t_harvest,
@@ -4201,75 +4416,84 @@ class PagedGenerationServer:
                       "rows": len(rec["parts"]),
                       "depth": rec.get("depth", 0)},
             )
-        t_host = time.perf_counter()
-        rec["counted"] = True
-        self._ckpt_clock += 1  # window of progress at risk (rung 22)
-        for _, req, cap in rec["parts"]:
-            req.inflight -= cap
-        self._spec_passes += rec["window"]
-        for slot, req, cap in rec["parts"]:
-            if self._active.get(slot) is not req or req.stopped:
-                # Released while in flight (normally unreachable —
-                # cancels resolve at boundaries) or stop-terminated at
-                # an earlier harvest awaiting its deferred finish;
-                # nothing to emit into.
-                continue
-            before = len(req.generated)
-            stopped = False
-            counts_col = counts[:, slot].tolist()
-            for p in range(rec["window"]):
-                c = counts_col[p]
-                if c == 0:
-                    # Frozen pass: the row's budget ran out on device
-                    # (rem <= 0) — no tokens, no pending advance.
+        with self._phase("loop/emit") as emit:
+            rec["counted"] = True
+            self._ckpt_clock += 1  # window of progress at risk (rung 22)
+            for _, req, cap in rec["parts"]:
+                req.inflight -= cap
+            self._spec_passes += rec["window"]
+            # A step of a spec window is one draft+verify pass.
+            self._count_steps_locked(
+                rec["window"], rec["bucket"],
+                [(slot, req) for slot, req, _ in rec["parts"]
+                 if self._active.get(slot) is req])
+            for slot, req, cap in rec["parts"]:
+                if self._active.get(slot) is not req or req.stopped:
+                    # Released while in flight (normally unreachable —
+                    # cancels resolve at boundaries) or stop-terminated
+                    # at an earlier harvest awaiting its deferred
+                    # finish; nothing to emit into.
                     continue
-                room = max(req.n_new - len(req.generated), 0)
-                # Sampled rows advance exactly one token per pass
-                # (c == 1): seq is just the pending token and the
-                # device-sampled token becomes the next pending —
-                # the legacy _spec_pass semantics, scanned.
-                row = emitted[p, slot, :c].tolist()
-                seq = ([req.next_token] + row[:-1])[:room]
-                try:
-                    # Host-side stop truncation, now a C-level list
-                    # search instead of a per-token compare loop:
-                    # later passes decoded garbage and are discarded.
-                    stop_i = seq.index(req.stop_token)
-                    seq = seq[:stop_i + 1]
-                    stopped = True
-                except ValueError:
-                    pass
-                self._emit_many(req, seq)
-                emit_n = len(seq)
-                req.next_token = row[-1]
-                if req.sampling is None:
-                    # Greedy acceleration stats only — sampled rows
-                    # ride at one token per pass by construction and
-                    # would drag the realized-acceptance gauge down.
-                    self._spec_emitted += emit_n
-                    self._spec_slot_passes += 1
-                if stopped:
-                    break
-            self._hist_spec_tokens.observe(
-                float(len(req.generated) - before)
-            )
-            if stopped and not req.cancelled:
-                self._finish_stopped_locked(slot, req)
-            elif (len(req.generated) >= req.n_new
-                    and not req.cancelled):
-                # Inline finish, as in the plain harvest: a saturated
-                # pipeline may never visit a boundary. The cancelled
-                # guard preserves cancel-beats-finish ordering.
-                self._finish_request_locked(slot, req)
-            else:
-                # The carried pending may itself be the stop token (a
-                # sampled row's device-sampled next, or a bonus token):
-                # register it for the boundary sweep.
-                self._note_finish_candidate_locked(slot, req)
-        self._spec_windows += 1
-        self._overlap_windows += 1
-        host_ms = (time.perf_counter() - t_host) * 1e3
-        self._hist_host.observe(host_ms)
+                before = len(req.generated)
+                stopped = False
+                counts_col = counts[:, slot].tolist()
+                for p in range(rec["window"]):
+                    c = counts_col[p]
+                    if c == 0:
+                        # Frozen pass: the row's budget ran out on
+                        # device (rem <= 0) — no tokens, no pending
+                        # advance.
+                        continue
+                    self._decode_row_steps += 1
+                    room = max(req.n_new - len(req.generated), 0)
+                    # Sampled rows advance exactly one token per pass
+                    # (c == 1): seq is just the pending token and the
+                    # device-sampled token becomes the next pending —
+                    # the legacy _spec_pass semantics, scanned.
+                    row = emitted[p, slot, :c].tolist()
+                    seq = ([req.next_token] + row[:-1])[:room]
+                    try:
+                        # Host-side stop truncation, now a C-level
+                        # list search instead of a per-token compare
+                        # loop: later passes decoded garbage and are
+                        # discarded.
+                        stop_i = seq.index(req.stop_token)
+                        seq = seq[:stop_i + 1]
+                        stopped = True
+                    except ValueError:
+                        pass
+                    self._emit_many(req, seq)
+                    emit_n = len(seq)
+                    req.next_token = row[-1]
+                    if req.sampling is None:
+                        # Greedy acceleration stats only — sampled rows
+                        # ride at one token per pass by construction
+                        # and would drag the realized-acceptance gauge
+                        # down.
+                        self._spec_emitted += emit_n
+                        self._spec_slot_passes += 1
+                    if stopped:
+                        break
+                self._note_emitted_locked(req, before)
+                self._hist_spec_tokens.observe(
+                    float(len(req.generated) - before)
+                )
+                if stopped and not req.cancelled:
+                    self._finish_stopped_locked(slot, req)
+                elif (len(req.generated) >= req.n_new
+                        and not req.cancelled):
+                    # Inline finish, as in the plain harvest: a
+                    # saturated pipeline may never visit a boundary.
+                    # The cancelled guard preserves cancel-beats-finish
+                    # ordering.
+                    self._finish_request_locked(slot, req)
+                else:
+                    # The carried pending may itself be the stop token
+                    # (a sampled row's device-sampled next, or a bonus
+                    # token): register it for the boundary sweep.
+                    self._note_finish_candidate_locked(slot, req)
+            self._spec_windows += 1
+            self._overlap_windows += 1
         if self._autotune is not None:
             # Spec-depth channel (rung 26): verify passes have their
             # own per-pass device cost t_v, so the spec window keeps
@@ -4279,10 +4503,8 @@ class PagedGenerationServer:
             # kind-matched carry redispatches, and never above the
             # operator's configured depth cap.
             self._autotune.observe(
-                rtt_ms=(t_harvest - rec["t0"]) * 1e3,
-                device_ms=(t_harvest - t_force) * 1e3,
-                host_ms=host_ms, window=rec["window"],
-                channel="spec",
+                rtt_ms=rtt_ms, device_ms=waited.ms, host_ms=emit.ms,
+                window=rec["window"], channel="spec",
             )
             if self._inflight is None and self._spec_window_cap > 0:
                 pick = self._autotune.window(

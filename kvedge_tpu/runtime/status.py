@@ -441,6 +441,19 @@ _SERVE_HISTOGRAM_FIELDS = (
     ("itl_ms", "serve_itl_ms",
      "per-request mean inter-token gap in ms (first token to finish "
      "over generated tokens - 1; observed once per normal finish)"),
+    # The submit path from inside (runtime/tracing.py phases
+    # admit/lock_wait and admit/prefill_chunk, once per prefill chunk)
+    # and the server's own delay between picking a request's first
+    # token and handing it over.
+    ("prefill_lock_wait_ms", "serve_prefill_lock_wait_ms",
+     "time a prefill chunk waited for the work lock in ms (asking "
+     "for it to holding it; the decode loop holds it for a window)"),
+    ("prefill_chunk_ms", "serve_prefill_chunk_ms",
+     "time a prefill chunk held the work lock in ms (the chunk "
+     "dispatched and whatever that blocked on)"),
+    ("first_emit_ms", "serve_first_emit_ms",
+     "time from the pick of a request's first token to its put on "
+     "the request's stream (or its append, buffered) in ms"),
 )
 
 

@@ -42,6 +42,44 @@ Design constraints (SERVING.md rung 18):
   tracer object survives ``revive()`` and slice reformation unchanged
   (it holds no device or thread state).
 
+Phases (ISSUE 24) — one primitive, three sinks. A *phase* is a named
+stretch of ONE thread's time, entered as a context manager
+(:class:`PhaseClock`, one per serving pool). Which sink is on when:
+
+* **always**: its duration and a count go to an accumulator the server
+  owns — a histogram where a distribution is wanted, a
+  :class:`PhaseSum` (count, total ms) otherwise — which ``stats()``
+  exports (``phase_ms`` and the histograms) and the benchmark reads
+  with ``serving_trace`` off;
+* **while a profiler session is live** (``POST /profile``, the
+  benchmark's ``--trace 1``): it is a
+  ``jax.profiler.TraceAnnotation("kvedge/<phase>")`` on its host
+  thread's line of the SAME ``.xplane.pb`` as the device's operations,
+  on the profiler's clock — open the capture in Perfetto/XProf and the
+  ``kvedge/...`` rows lie above the device rows. With no session it is
+  one flag test;
+* **with the Tracer on** (``serving_trace``): it is the span in the
+  ring (``rid`` set for request phases, which share the request's
+  sampling fate).
+
+The decode-loop thread's phases cover its whole time
+(:data:`LOOP_PHASES`): ``loop/lock_wait`` (from wanting the work lock
+to holding it, the GIL yield before it included: this is when prefill
+chunks run), ``loop/wait_work`` (inside ``Condition.wait``, nothing to
+do), ``loop/boundary`` (sweeps, resume/preempt, bucket step,
+checkpoint, observe), ``loop/dispatch`` (building and enqueueing a
+window), ``loop/harvest_wait`` (the blocking read of a window's
+tokens; the ``window_device_ms`` histogram) and ``loop/emit``
+(bookkeeping after it; ``window_host_ms``). The submit path's, per
+prefill chunk on the caller's thread (:data:`ADMIT_PHASES`):
+``admit/lock_wait`` (asking for the work lock to holding it;
+``prefill_lock_wait_ms``) and ``admit/prefill_chunk`` (lock held, the
+chunk dispatched and whatever it blocks on; ``prefill_chunk_ms``);
+once per request ``admit/first_pick`` (asking for the lock again to
+the first token read back from the prefill's logits: the caller
+waits, lock held, for its chunks and for the window the device was
+given before them).
+
 Export targets:
 
 * ``GET /trace`` (runtime/status.py) returns
@@ -277,3 +315,152 @@ class Tracer:
                 "sample": self.sample,
             },
         }
+
+
+# ---- phases: one primitive, three sinks ----------------------------------
+
+# The decode-loop thread's phases: together they cover its whole time.
+LOOP_PHASES = ("loop/lock_wait", "loop/wait_work", "loop/boundary",
+               "loop/dispatch", "loop/harvest_wait", "loop/emit")
+# The submit path's, on the caller's thread: the first two once per
+# prefill chunk, the third once per request.
+ADMIT_PHASES = ("admit/lock_wait", "admit/prefill_chunk",
+                "admit/first_pick")
+
+
+class PhaseSum:
+    """Count and total milliseconds of a phase whose distribution
+    nobody asks for. Same ``observe`` / ``n`` / ``total`` shape as the
+    serving histograms, so a phase takes either as its accumulator.
+    Mutated by the one thread that owns the phase, or under the work
+    lock; a reader sees the value before or after an addition."""
+
+    __slots__ = ("n", "total")
+
+    def __init__(self):
+        self.n = 0
+        self.total = 0.0
+
+    def observe(self, ms: float) -> None:
+        self.n += 1
+        self.total += ms
+
+
+class Phase:
+    """One entry into a phase (see the module docstring for the three
+    sinks). ``stop()`` ends it before the ``with`` block does — the
+    lock-wait phases end the moment the lock is held, inside the block
+    that holds it. ``t0``/``t1``/``ms`` stay readable after.
+
+    A phase of a *chain* (``chain``: the clock whose ``last`` stamp it
+    shares with the other phases of its thread) starts where the one
+    before it ended, so the bytecode between two phases is counted in
+    the second and the chain's phases add up to the thread's time with
+    nothing left over. The profiler annotation begins and ends in real
+    time either way: it is made on entry, because making one starts it.
+
+    A phase may be made well before it is entered, and entered with
+    :meth:`start` where no ``with`` block fits. The waits for the work
+    lock use both: whoever asks first after a release gets the lock
+    (the release only wakes the other side), so the thread that will
+    wait makes its phase while it still holds the lock, and the submit
+    path also starts it there, a moment before the release: between
+    its release and its next acquire it then runs what it ran before
+    phases, and ``stop()`` ends the wait once the lock is held."""
+
+    __slots__ = ("name", "args", "t0", "t1", "ms", "_sink", "_annotate",
+                 "_note", "_ring", "_rid", "_chain")
+
+    def __init__(self, name: str, sink, annotate, ring, rid: str, args,
+                 chain):
+        self.name = name
+        self.args = args
+        self.t0 = self.t1 = None
+        self.ms = 0.0
+        self._sink = sink
+        self._annotate = annotate
+        self._note = None
+        self._ring = ring
+        self._rid = rid
+        self._chain = chain
+
+    def start(self) -> "Phase":
+        self._note = self._annotate("kvedge/" + self.name)
+        chain = self._chain
+        if chain is None:
+            self.t0 = time.perf_counter()
+        else:
+            self.t0 = chain.last
+            chain.open = self.name
+        return self
+
+    __enter__ = start
+
+    def stop(self) -> None:
+        if self.t1 is not None:
+            return
+        self.t1 = t1 = time.perf_counter()
+        if self._chain is not None:
+            self._chain.last = t1
+            self._chain.open = None
+        self._note.__exit__(None, None, None)
+        self.ms = (t1 - self.t0) * 1e3
+        self._sink.observe(self.ms)
+        if self._ring is not None:
+            self._ring.span(self.name, "serve", self.t0, t1,
+                            rid=self._rid, args=self.args)
+
+    def __exit__(self, *exc) -> bool:
+        self.stop()
+        return False
+
+
+class PhaseClock:
+    """The phase factory of one serving pool: the accumulator of every
+    phase it may enter (``sinks``: name -> a :class:`PhaseSum` or a
+    histogram, anything with ``observe``/``n``/``total``), the tracer
+    (or None) and the profiler's annotation type. The phases named in
+    ``chained`` are one thread's and form a chain (:class:`Phase`);
+    that thread calls :meth:`mark` as it starts. ``last`` is where the
+    chain's last phase ended and ``open`` the name of the one under
+    way, if any."""
+
+    def __init__(self, sinks: dict, tracer: "Tracer | None" = None,
+                 chained: tuple = ()):
+        # Imported here, not at the top: this module stays importable
+        # (request ids, the ring) by code that never loads JAX.
+        from jax.profiler import TraceAnnotation
+
+        self._annotate = TraceAnnotation
+        self.sinks = sinks
+        self.tracer = tracer
+        self._chained = frozenset(chained)
+        self.last = time.perf_counter()
+        self.open = None
+
+    def mark(self) -> float:
+        """Start the chain now; returns the stamp."""
+        self.last = time.perf_counter()
+        return self.last
+
+    def __call__(self, name: str, *, rid: str = "", ring: bool = True,
+                 args: dict | None = None) -> Phase:
+        """``ring=False`` keeps an unsampled request's phase out of the
+        ring (its other two sinks stay on)."""
+        return Phase(name, self.sinks[name], self._annotate,
+                     self.tracer if ring else None, rid, args,
+                     self if name in self._chained else None)
+
+    def snapshot(self, now: float) -> dict:
+        """name -> [count, total ms], for ``stats()``. The chain's
+        phase under way at ``now`` is counted as far as it has got (a
+        snapshot is taken with the work lock held, so what is open is
+        the loop's wait for that lock or for work, seconds long): the
+        chain's totals then differ between two snapshots by the time
+        between them, whatever was open at either."""
+        out = {name: [acc.n, acc.total]
+               for name, acc in self.sinks.items()}
+        name = self.open
+        if name is not None:
+            out[name][1] += max(0.0, now - self.last) * 1e3
+        return out

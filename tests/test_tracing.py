@@ -27,6 +27,8 @@ from kvedge_tpu.models.serving import PagedGenerationServer
 from kvedge_tpu.runtime.failures import ServingFailure
 from kvedge_tpu.runtime.status import StatusServer, render_metrics
 from kvedge_tpu.runtime.tracing import (
+    ADMIT_PHASES,
+    LOOP_PHASES,
     POSTMORTEM_EVENTS,
     Tracer,
     clean_request_id,
@@ -225,7 +227,14 @@ def test_tracing_is_token_bit_identical(params):
         assert off == on, f"tracing changed tokens (overlap={overlap})"
         names = {rec[3] for rec in tr._snapshot()}
         assert {"prefill", "decode", "queue"} <= names
-        assert "window" in names or "step" in names
+        # The new sites: every phase of the loop and of the submit
+        # path is a span of the ring, and the pipelined loop keeps its
+        # dispatch-to-harvest "window" span (it spans phases).
+        # (whether the loop ever parks for work is up to the race
+        # between its start and the first admission)
+        assert (set(LOOP_PHASES) - {"loop/wait_work"}
+                | set(ADMIT_PHASES)) <= names
+        assert (overlap == "on") == ("window" in names)
     assert off[0] == reference(params, [5, 9, 2, 7], 9)
 
 
@@ -273,6 +282,80 @@ def test_stage_histograms_always_on(params):
         assert len(hist["counts"]) == len(hist["edges"]) + 1
         assert hist["count"] == sum(hist["counts"]) >= 1
     assert "trace_events" not in stats  # no tracer, no trace gauges
+
+
+def test_the_prefill_span_is_the_parent_of_its_chunk_phases(params):
+    """One request's ring spans, by time: queue, then ``prefill`` from
+    the admission to the pick of the first token, holding the request's
+    chunk phases (lock wait, chunk, lock wait, chunk, ...) and its
+    ``admit/first_pick``, each with the request's id; the loop's phases
+    carry none."""
+    tr = Tracer(sample=1.0)
+    server = PagedGenerationServer(params, CFG, slots=2, pages=16,
+                                   prefill_chunk=2, tracer=tr)
+    try:
+        server.submit([5, 9, 2, 7, 1], n_new=4, request_id="req-abc")
+    finally:
+        server.close()
+    mine = sorted((rec for rec in tr._snapshot() if rec[5] == "req-abc"
+                   and rec[0] == "X"), key=lambda rec: rec[1])
+    (prefill,) = [rec for rec in mine if rec[3] == "prefill"]
+    (queue,) = [rec for rec in mine if rec[3] == "queue"]
+    inside = [rec for rec in mine if rec[3].startswith("admit/")]
+    assert [rec[3] for rec in inside] == [
+        "admit/lock_wait", "admit/prefill_chunk"] * 3 + ["admit/first_pick"]
+    lo, hi = prefill[1], prefill[1] + prefill[2]
+    assert all(lo <= rec[1] and rec[1] + rec[2] <= hi + 1e-9
+               for rec in inside)
+    assert queue[1] + queue[2] <= lo + 1e-9
+    assert hi == pytest.approx(inside[-1][1] + inside[-1][2])
+    chunk_args = [rec[6] for rec in inside if rec[3] == "admit/prefill_chunk"]
+    assert chunk_args == [{"off": 0, "n": 2}, {"off": 2, "n": 2},
+                          {"off": 4, "n": 1}]
+    loop = [rec for rec in tr._snapshot() if rec[3] in LOOP_PHASES]
+    assert loop and all(rec[5] == "" for rec in loop)
+    _check_chrome(tr.export_chrome())
+
+
+def test_an_unsampled_requests_phases_stay_out_of_the_ring(params):
+    tr = Tracer(sample=0.0001)
+    rid = next(f"req-{i}" for i in range(1000)
+               if not tr.sampled(f"req-{i}"))
+    server = PagedGenerationServer(params, CFG, slots=2, pages=16,
+                                   tracer=tr)
+    try:
+        server.submit([5, 9, 2], n_new=4, request_id=rid)
+        stats = server.stats()
+    finally:
+        server.close()
+    names = {rec[3] for rec in tr._snapshot()}
+    assert set(LOOP_PHASES) - {"loop/wait_work"} <= names
+    assert not names & set(ADMIT_PHASES)
+    # the other two sinks do not ask the sampler
+    assert stats["prefill_chunk_ms"]["count"] == 1
+    assert stats["phase_ms"]["admit/first_pick"][0] == 1
+
+
+@pytest.mark.parametrize("key", ["prefill_lock_wait_ms", "prefill_chunk_ms",
+                                 "first_emit_ms"])
+def test_metrics_render_the_phase_histograms(params, key):
+    """The three new histograms reach /metrics the way serve_ttft_ms
+    does, with no tracer; the counters and phase_ms stay in stats()."""
+    server = PagedGenerationServer(params, CFG, slots=2, pages=16)
+    try:
+        server.submit([5, 9, 2], n_new=4)
+        stats = server.stats()
+    finally:
+        server.close()
+    text = render_metrics({"ok": True, "boot_count": 1, "uptime_s": 2.5,
+                           "heartbeat_seq": 3, "heartbeat_age_s": 0.1,
+                           "serving": stats})
+    families = check_prometheus_text(text)
+    assert families[f"kvedge_serve_{key}"] == "histogram"
+    m = re.search(rf"^kvedge_serve_{key}_count (\d+)$", text, re.M)
+    assert m and int(m.group(1)) == stats[key]["count"] == 1
+    assert "phase_ms" not in text and "clock_s" not in text
+    assert stats["clock_s"] > 0 and stats["tokens_emitted_total"] == 4
 
 
 def test_tracer_survives_poison_and_revive(params):
