@@ -514,7 +514,7 @@ def _window_program(smoke: Smoke, handle, entry: dict, *,
     smoke.say(f"decode window program: attention path = {path} "
               f"(prefill: gather, always); tpu_custom_call "
               f"x{custom_calls}, collectives {present or 'none'}; KV pool "
-              f"[layers, pages, page, kv_heads, d_head] placed "
+              f"[layers, pages, page, kv_heads * d_head] placed "
               f"{placement}")
     if (path == "kernel") != want_kernel:
         raise SmokeFailure(
@@ -656,7 +656,10 @@ def check_near_ties(smoke: Smoke, tcfg, params, a: dict, b: dict,
 def phase_attention_op(smoke: Smoke) -> None:
     """The decode kernel against a plain gather reference on one random
     pool at the smoke's widths, live lengths on and around a page
-    boundary and at the cap. Compiled for the TPU the two must agree in
+    boundary and at the cap. The pool is handed over as the server
+    stores it, two layers of [pages, page, kv_heads * d_head], and the
+    kernel reads the second where it lies; the reference gathers that
+    layer's pages per head. Compiled for the TPU the two must agree in
     every bit: that is the contract ``paged_attention = "auto"`` rests
     on (kvcache._use_paged_kernel), held here where it holds. The CPU
     interpreter sums the weights-times-V contraction in another order
@@ -678,9 +681,10 @@ def phase_attention_op(smoke: Smoke) -> None:
         keys = jax.random.split(jax.random.PRNGKey(smoke.seed), 3)
         pages = len(lives) * max_pages + 1
         q = jax.random.normal(keys[0], (len(lives), heads, dh), jnp.bfloat16)
-        pool_k = jax.random.normal(keys[1], (pages, page, kv, dh),
+        layer = 1
+        pool_k = jax.random.normal(keys[1], (2, pages, page, kv * dh),
                                    jnp.bfloat16)
-        pool_v = jax.random.normal(keys[2], (pages, page, kv, dh),
+        pool_v = jax.random.normal(keys[2], (2, pages, page, kv * dh),
                                    jnp.bfloat16)
         tables = jnp.asarray(
             1 + np.arange(len(lives) * max_pages).reshape(len(lives), -1),
@@ -689,8 +693,8 @@ def phase_attention_op(smoke: Smoke) -> None:
 
         def gather(q, pool_k, pool_v, tables, pos):
             b, span = q.shape[0], max_pages * page
-            k = pool_k[tables].reshape(b, span, kv, dh)
-            v = pool_v[tables].reshape(b, span, kv, dh)
+            k = pool_k[layer, tables].reshape(b, span, kv, dh)
+            v = pool_v[layer, tables].reshape(b, span, kv, dh)
             qg = q.reshape(b, 1, kv, heads // kv, dh)
             scores = jnp.einsum("bqkgd,bskd->bkgqs", qg, k) / (dh ** 0.5)
             seen = jnp.arange(span)[None, :] <= pos[:, None]
@@ -705,7 +709,7 @@ def phase_attention_op(smoke: Smoke) -> None:
         got = np.asarray(jax.jit(
             lambda *a: paged_decode_attention(
                 *a, interpret=pallas_interpret())
-        )(q, pool_k, pool_v, tables, pos))
+        )(q, pool_k, pool_v, tables, pos, jnp.asarray(layer, jnp.int32)))
         bits = lambda x: x.view(np.uint16).astype(np.int32)  # noqa: E731
         differing = int((bits(got) != bits(want)).sum())
         worst = float(np.abs(got.astype(np.float32)

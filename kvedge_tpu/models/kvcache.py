@@ -9,12 +9,14 @@ is bounded by the page size.
 
 TPU-first shape discipline:
 
-* The pool ``[L, P, page, K, Dh]`` and block tables ``[B, max_pages]`` are
+* The pool ``[L, P, page, K*Dh]`` and block tables ``[B, max_pages]`` are
   **static**; growth happens by table entries, never by reshaping arrays —
   nothing retraces as sequences come and go.
-* The per-step gather (``pool[tables]``) and scatter (one page row per
-  sequence) are batched ``take``/``scatter`` ops XLA lowers to dynamic
-  gathers — no per-sequence Python.
+* The pool is never taken apart: the layer loop carries it whole, each
+  layer scatters its new rows in place (``pool.at[l, page, offset]``) and
+  reads either through the decode kernel, which DMAs pages out of
+  ``pool[l]`` where they lie, or by one gather (``pool[l, tables]``) —
+  batched ops XLA lowers to dynamic gathers, no per-sequence Python.
 * Allocation policy (free lists, admission) is host-side Python — it is
   control plane, runs once per request, and must not live inside ``jit``.
 
@@ -46,6 +48,16 @@ class PagedState:
     """Device-side paged cache state (a pytree; host policy lives in
     :class:`PagedKVCache`).
 
+    The pools are stored as the decode kernel reads them: the kv heads
+    merged K-major into the lane dimension, ``[L, P, page, K*Dh]``
+    (column ``k * Dh + d`` is head k's element d), so a page is one
+    contiguous 128-aligned DMA and no program relays a layer's slab
+    before attending over it. What leaves the device keeps the
+    per-head shape ``[L, n, page, K, Dh]`` (snapshots, swaps, the
+    prefix cache's files): :func:`_gather_pages_impl` and
+    :func:`_scatter_pages_impl` reshape at that boundary, where the
+    arrays are a few pages.
+
     ``scale_k``/``scale_v`` ([L, P, page, K] fp32) exist only for an
     int8-quantized pool (``kv_dtype="int8"``): each token row of each
     kv head carries one scale — the standard per-token KV quantization
@@ -54,8 +66,8 @@ class PagedState:
     pre-quantization ones (None is an empty pytree node).
     """
 
-    pool_k: jax.Array   # [L, P, page, K, Dh]
-    pool_v: jax.Array   # [L, P, page, K, Dh]
+    pool_k: jax.Array   # [L, P, page, K*Dh]
+    pool_v: jax.Array   # [L, P, page, K*Dh]
     tables: jax.Array   # [B, max_pages] int32 page ids (0 = also a real page;
                         # entries past a sequence's page count are unused)
     lengths: jax.Array  # [B] int32 valid positions per sequence
@@ -119,7 +131,12 @@ def _use_paged_kernel(cfg: TransformerConfig, page_size: int,
     in every bit (live lengths 127/128/129/2047) and an "auto" and a
     "gather" server return the same greedy streams — so there "auto"
     is a routing choice, not a numerics one, for as long as that check
-    passes (TPU v5 lite, PR 21: 0 of 4,096 outputs differ). XLA:CPU
+    passes (TPU v5 lite, PR 21: 0 of 4,096 outputs differ). That is
+    heads of 64, whose score scale 1/8 divides exactly; at heads of 128
+    (24 query / 2 kv: the benchmark's widths) the two paths round the
+    division by sqrt(128) at different points and 10,407 of 15,360
+    outputs differ by one or two bf16 steps on the chip, before the
+    pool was carried whole as after (PR 25, PERF.md section 6). XLA:CPU
     does order them differently: under the interpreter one output of
     1,536, a sum that cancels to 4e-6, lands one bf16 step from the
     gather's in one pinned case (ROADMAP D6).
@@ -253,7 +270,7 @@ class PagedKVCache:
                     "pool/page geometry or use 'auto'/'gather'"
                 )
         dtype = jnp.int8 if self.kv_quantized else jnp.dtype(cfg.dtype)
-        shape = (cfg.n_layers, pages, page_size, cfg.kv_heads, cfg.d_head)
+        shape = (cfg.n_layers, pages, page_size, cfg.kv_heads * cfg.d_head)
         self.state = self._init_state(shape, dtype)
         self._free: list[int] = list(range(pages))[::-1]  # pop() -> lowest last
         self._pages_of: dict[int, list[int]] = {}
@@ -320,7 +337,8 @@ class PagedKVCache:
         def scale():
             # Two DISTINCT arrays: the jitted steps donate the whole
             # state, and donating one buffer twice is an error.
-            return (jnp.zeros(shape[:-1], jnp.float32)
+            return (jnp.zeros(shape[:-1] + (self.cfg.kv_heads,),
+                              jnp.float32)
                     if self.kv_quantized else None)
 
         return PagedState(
@@ -647,12 +665,9 @@ class PagedKVCache:
         (write_pages re-quantizes on the way in), at the cost of one
         extra quantization round trip whose error is bounded by one
         int8 step of the row's amax."""
-        idx = jnp.asarray(ids, jnp.int32)
-        out = [self.state.pool_k[:, idx], self.state.pool_v[:, idx]]
-        if self.kv_quantized:
-            out += [self.state.scale_k[:, idx],
-                    self.state.scale_v[:, idx]]
-        return tuple(out)
+        return _gather_pages_impl(
+            self.state, jnp.asarray(ids, jnp.int32), self.cfg.kv_heads
+        )
 
     @staticmethod
     def snapshot_to_host(snapshot):
@@ -682,27 +697,15 @@ class PagedKVCache:
         caller owns allocation/refcounts for these pages. Values arrive
         unquantized (see snapshot_pages); an int8 pool re-quantizes
         them per row here."""
-        idx = jnp.asarray(ids, jnp.int32)
         if self.kv_quantized:
             k_q, k_s = _kv_quantize(jnp.asarray(k_vals, jnp.float32))
             v_q, v_s = _kv_quantize(jnp.asarray(v_vals, jnp.float32))
-            self.state = dataclasses.replace(
-                self.state,
-                pool_k=self.state.pool_k.at[:, idx].set(k_q),
-                pool_v=self.state.pool_v.at[:, idx].set(v_q),
-                scale_k=self.state.scale_k.at[:, idx].set(k_s),
-                scale_v=self.state.scale_v.at[:, idx].set(v_s),
-            )
-            return
-        dtype = self.state.pool_k.dtype
-        self.state = dataclasses.replace(
-            self.state,
-            pool_k=self.state.pool_k.at[:, idx].set(
-                jnp.asarray(k_vals, dtype)
-            ),
-            pool_v=self.state.pool_v.at[:, idx].set(
-                jnp.asarray(v_vals, dtype)
-            ),
+            arrays = (k_q, v_q, k_s, v_s)
+        else:
+            dtype = self.state.pool_k.dtype
+            arrays = (jnp.asarray(k_vals, dtype), jnp.asarray(v_vals, dtype))
+        self.state = _scatter_pages_impl(
+            self.state, jnp.asarray(ids, jnp.int32), arrays
         )
 
     # ---- preemptive swap (scheduler layer, SERVING.md rung 17) ----------
@@ -712,7 +715,9 @@ class PagedKVCache:
         immune to the decode steps' buffer donation). The slice cache
         overrides this to broadcast an OP_SWAPOUT so followers replay
         the gather in the totally-ordered op stream."""
-        return _gather_pages_impl(self.state, jnp.asarray(ids, jnp.int32))
+        return _gather_pages_impl(
+            self.state, jnp.asarray(ids, jnp.int32), self.cfg.kv_heads
+        )
 
     def swapout_pages(self, ids: list[int]) -> tuple:
         """Host copies of pages ``ids`` EXACTLY as the pool stores them
@@ -1465,14 +1470,20 @@ def _note_trace(name: str) -> None:
     _TRACE_EVENTS[name] = _TRACE_EVENTS.get(name, 0) + 1
 
 
-def _gather_pages_impl(state: PagedState, idx):
-    """Pages ``idx`` of every pool slab, as stored: a 2-or-4 tuple of
-    fresh ``[L, n, page, K, Dh]`` / ``[L, n, page, K]`` arrays. Shared
-    by the single-host swap-out seam (plain dispatch) and the slice
-    cache's jitted replicated gather (runtime/sliceserve.py jits it
-    with ``out_shardings`` replicated, so the leader can read the swap
-    snapshot host-side while followers hold the same bytes)."""
-    out = [state.pool_k[:, idx], state.pool_v[:, idx]]
+def _gather_pages_impl(state: PagedState, idx, kv_heads: int):
+    """Pages ``idx`` of every pool slab, values as stored: a 2-or-4
+    tuple of fresh ``[L, n, page, K, Dh]`` / ``[L, n, page, K]`` arrays
+    (the pool's merged ``K*Dh`` lane dimension is split here, on the
+    few pages gathered — what leaves the device keeps the per-head
+    shape, PagedState docstring). Shared by the snapshot and swap-out
+    seams (plain dispatch) and the slice cache's jitted replicated
+    gather (runtime/sliceserve.py jits it with ``out_shardings``
+    replicated, so the leader can read the swap snapshot host-side
+    while followers hold the same bytes)."""
+    def per_head(pages):  # [L, n, page, K*Dh] -> [L, n, page, K, Dh]
+        return pages.reshape(*pages.shape[:3], kv_heads, -1)
+
+    out = [per_head(state.pool_k[:, idx]), per_head(state.pool_v[:, idx])]
     if state.scale_k is not None:
         out += [state.scale_k[:, idx], state.scale_v[:, idx]]
     return tuple(out)
@@ -1480,13 +1491,17 @@ def _gather_pages_impl(state: PagedState, idx):
 
 def _scatter_pages_impl(state: PagedState, idx, arrays) -> PagedState:
     """Scatter as-stored ``arrays`` (a :func:`_gather_pages_impl`
-    tuple) into pages ``idx`` — ONE batched update per slab, no dtype
-    conversion (the swap-in path's bit-exactness contract). Shared by
-    the single-host seam and the slice cache's jitted donating
-    scatter."""
+    tuple, ``[L, n, page, K, Dh]`` pools) into pages ``idx`` — ONE
+    batched update per slab, no dtype conversion (the swap-in path's
+    bit-exactness contract); the heads merge into the pool's lane
+    dimension here. Shared by the single-host seams (swap-in,
+    write_pages) and the slice cache's jitted donating scatter."""
+    def merged(pages):  # [L, n, page, K, Dh] -> [L, n, page, K*Dh]
+        return pages.reshape(*pages.shape[:3], -1)
+
     fields = dict(
-        pool_k=state.pool_k.at[:, idx].set(arrays[0]),
-        pool_v=state.pool_v.at[:, idx].set(arrays[1]),
+        pool_k=state.pool_k.at[:, idx].set(merged(arrays[0])),
+        pool_v=state.pool_v.at[:, idx].set(merged(arrays[1])),
     )
     if state.scale_k is not None:
         fields.update(
@@ -1499,7 +1514,8 @@ def _scatter_pages_impl(state: PagedState, idx, arrays) -> PagedState:
 def _cow_page_impl(state: PagedState, src, dst) -> PagedState:
     """Copy page ``src`` into page ``dst`` across every pool slab — the
     COW divergence primitive. Bytes move device-to-device as stored
-    (no dequantization; int8 scale slabs ride along), so a diverged
+    (no dequantization, no reshape: a page is a page in either layout;
+    int8 scale slabs ride along), so a diverged
     copy is bit-identical to its source. ``src``/``dst`` arrive as
     traced int32 scalars: the slice cache jits this impl once and
     every (src, dst) pair replays the same compiled program."""
@@ -1515,50 +1531,69 @@ def _cow_page_impl(state: PagedState, src, dst) -> PagedState:
     return dataclasses.replace(state, **fields)
 
 
-def _gathered(state: PagedState, layer_slabs, dtype):
-    """pool[L] pages -> per-sequence contiguous [B, S_max, K, Dh] views
-    (dequantized to ``dtype`` when the pool is int8)."""
-    pool_k_l, pool_v_l, scale_k_l, scale_v_l = layer_slabs
-    batch, max_pages = state.tables.shape
-    page, kv, dh = pool_k_l.shape[1:]
-    k = pool_k_l[state.tables]  # [B, max_pages, page, K, Dh]
-    v = pool_v_l[state.tables]
-    if scale_k_l is not None:
-        k = _kv_dequantize(k, scale_k_l[state.tables], dtype)
-        v = _kv_dequantize(v, scale_v_l[state.tables], dtype)
-    return (
-        k.reshape(batch, max_pages * page, kv, dh),
-        v.reshape(batch, max_pages * page, kv, dh),
-    )
+def _gathered(pools, layer, tables, kv: int, dtype):
+    """Layer ``layer``'s pages -> per-sequence contiguous
+    [B, S_max, K, Dh] views (dequantized to ``dtype`` when the pool is
+    int8): ONE gather per pool, ``pool[layer, tables]``, and what it
+    gathered split back into heads — the pool itself is not touched."""
+    pool_k, pool_v, scale_k, scale_v = pools
+    batch, max_pages = tables.shape
+    span = max_pages * pool_k.shape[2]
+
+    def view(pool):  # [B, max_pages, page, K*Dh] -> [B, S_max, K, Dh]
+        return pool[layer, tables].reshape(batch, span, kv, -1)
+
+    k, v = view(pool_k), view(pool_v)
+    if scale_k is not None:
+        k = _kv_dequantize(
+            k, scale_k[layer, tables].reshape(batch, span, kv), dtype)
+        v = _kv_dequantize(
+            v, scale_v[layer, tables].reshape(batch, span, kv), dtype)
+    return k, v
 
 
-def _scatter_token(pool, scales, tables, lengths, kv_new, active):
+def _scatter_rows(pool, scales, layer, page_idx, offset, rows):
+    """Write token rows ``rows`` [N, K, Dh] into layer ``layer`` of the
+    whole pool [L, P, page, K*Dh], IN PLACE on the layer loop's carry:
+    row n lands at ``pool[layer, page_idx[n], offset[n]]``; a
+    ``page_idx`` past the pool is dropped. ``scales`` non-None = int8
+    pool: each row quantizes per (n, head) and its scale scatters
+    alongside. Returns ``(pool, scales)``."""
+    if scales is not None:
+        rows, row_scale = _kv_quantize(rows)
+        scales = scales.at[layer, page_idx, offset].set(
+            row_scale, mode="drop")
+    rows = rows.reshape(rows.shape[0], -1)
+    return pool.at[layer, page_idx, offset].set(rows, mode="drop"), scales
+
+
+def _scatter_token(pool, scales, layer, tables, lengths, kv_new, active):
     """Write one [B, K, Dh] token row into each sequence's current page.
 
-    pool [P, page, K, Dh]; the target of row b is
-    page ``tables[b, lengths[b] // page]``, offset ``lengths[b] % page``.
-    Inactive slots (empty table rows would alias page 0) are routed
-    out-of-bounds and dropped. ``scales`` non-None = int8 pool: the row
-    quantizes per (b, head) and its scale scatters alongside. Returns
-    ``(pool, scales)``.
+    The target of row b is page ``tables[b, lengths[b] // page]``,
+    offset ``lengths[b] % page`` of ``pool[layer]``. Inactive slots
+    (empty table rows would alias page 0) are routed out-of-bounds and
+    dropped. Returns ``(pool, scales)`` (:func:`_scatter_rows`).
     """
-    pages, page = pool.shape[:2]
+    pages, page = pool.shape[1:3]
     page_idx = jnp.take_along_axis(
         tables, (lengths // page)[:, None], axis=1
     )[:, 0]                                   # [B] page ids
     page_idx = jnp.where(active, page_idx, pages)  # OOB => dropped
-    offset = lengths % page                    # [B]
-    if scales is not None:
-        kv_new, row_scale = _kv_quantize(kv_new)
-        scales = scales.at[page_idx, offset].set(row_scale, mode="drop")
-    return pool.at[page_idx, offset].set(kv_new, mode="drop"), scales
+    return _scatter_rows(pool, scales, layer, page_idx, lengths % page,
+                         kv_new)
 
 
 def _paged_attend_layer(cfg: TransformerConfig, state: PagedState, x,
-                        layer_params, layer_slabs, q_positions, slot=None,
+                        layer_params, layer, pools, q_positions, slot=None,
                         write_mask=None):
     """Shared block body. x: [B, Q, D]; q_positions: [B, Q] absolute
-    positions of the new tokens. ``slot`` non-None = single-sequence
+    positions of the new tokens. ``pools`` is the WHOLE pool
+    ``(pool_k, pool_v, scale_k, scale_v)`` as the layer loop carries
+    it, ``layer`` this block's index into it: the block writes its new
+    rows at ``[layer, page, offset]`` and reads ``pool[layer]`` where
+    it lies; it returns the updated pools. ``state`` supplies tables
+    and lengths only. ``slot`` non-None = single-sequence
     prefill (B == 1 view of that slot). ``write_mask`` [B, Q] bool
     (batched paths only) gates which query offsets persist K/V — the
     speculative verify pass drops sampled rows' draft-position writes so
@@ -1571,8 +1606,9 @@ def _paged_attend_layer(cfg: TransformerConfig, state: PagedState, x,
     h, kv, dh = cfg.n_heads, cfg.kv_heads, cfg.d_head
     group = h // kv
     dtype = x.dtype
-    pool_k_l, pool_v_l, scale_k_l, scale_v_l = layer_slabs
-    quantized = scale_k_l is not None
+    new_pool_k, new_pool_v, new_scale_k, new_scale_v = pools
+    quantized = new_scale_k is not None
+    page = new_pool_k.shape[2]
 
     normed = _rmsnorm(x, ln_attn)
     q, k, v = split_qkv(cfg, normed @ w_qkv.astype(dtype))
@@ -1596,18 +1632,16 @@ def _paged_attend_layer(cfg: TransformerConfig, state: PagedState, x,
         # a verify pass persists the drafts' K/V in the same program
         # that scores them (intra-pass causality is free: writes land
         # before the gather, and the mask is on absolute positions).
-        new_pool_k, new_pool_v = pool_k_l, pool_v_l
-        new_scale_k, new_scale_v = scale_k_l, scale_v_l
         for i in range(q_len):
             w_active = (active if write_mask is None
                         else active & write_mask[:, i])
             new_pool_k, new_scale_k = _scatter_token(
-                new_pool_k, new_scale_k, tables, lengths + i, k[:, i],
-                w_active,
+                new_pool_k, new_scale_k, layer, tables, lengths + i,
+                k[:, i], w_active,
             )
             new_pool_v, new_scale_v = _scatter_token(
-                new_pool_v, new_scale_v, tables, lengths + i, v[:, i],
-                w_active,
+                new_pool_v, new_scale_v, layer, tables, lengths + i,
+                v[:, i], w_active,
             )
     else:
         # Prefill: scatter q_len rows of one slot at their ABSOLUTE
@@ -1615,19 +1649,13 @@ def _paged_attend_layer(cfg: TransformerConfig, state: PagedState, x,
         # positions are offset..offset+q_len-1; the first/whole-prompt
         # chunk starts at zero).
         tables = state.tables[slot][None]
-        page = pool_k_l.shape[1]
         positions = q_positions[0]
         page_idx = tables[0][positions // page]
         offset = positions % page
-        k_rows, v_rows = k[0], v[0]
-        new_scale_k, new_scale_v = scale_k_l, scale_v_l
-        if quantized:
-            k_rows, sk = _kv_quantize(k_rows)
-            v_rows, sv = _kv_quantize(v_rows)
-            new_scale_k = scale_k_l.at[page_idx, offset].set(sk)
-            new_scale_v = scale_v_l.at[page_idx, offset].set(sv)
-        new_pool_k = pool_k_l.at[page_idx, offset].set(k_rows)
-        new_pool_v = pool_v_l.at[page_idx, offset].set(v_rows)
+        new_pool_k, new_scale_k = _scatter_rows(
+            new_pool_k, new_scale_k, layer, page_idx, offset, k[0])
+        new_pool_v, new_scale_v = _scatter_rows(
+            new_pool_v, new_scale_v, layer, page_idx, offset, v[0])
 
     # int8 pools use the kernel too (pages stream AS STORED — half the
     # DMA bytes — with scales folded in post-dot), as long as both
@@ -1643,38 +1671,39 @@ def _paged_attend_layer(cfg: TransformerConfig, state: PagedState, x,
     if quantized:
         from kvedge_tpu.ops.paged_attention import scales_fit_vmem
 
-        scales_fit = scales_fit_vmem(new_scale_k.size // kv, kv)
+        scale_rows = new_pool_k.shape[1] * page  # per layer: P * page
+        scales_fit = scales_fit_vmem(scale_rows, kv)
         if (kernel_eligible and cfg.paged_attention == "kernel"
                 and not scales_fit):
             raise ValueError(
                 "paged_attention='kernel' forced but the int8 scale "
-                f"arrays ({new_scale_k.size} fp32 elements x2) exceed "
+                f"arrays ({scale_rows * kv} fp32 elements x2) exceed "
                 "the kernel's VMEM budget — shrink the pool/page "
                 "geometry or use 'auto'/'gather'"
             )
     else:
         scales_fit = True
     if (kernel_eligible and scales_fit
-            and _use_paged_kernel(cfg, pool_k_l.shape[1], kv * dh,
+            and _use_paged_kernel(cfg, page, kv * dh,
                                   max_pages=tables.shape[1])):
         # Single-query decode (steps and windows): attention directly
         # over the block table — K/V pages stream up to each row's LIVE
-        # length through the Pallas kernel; the padded pool view is
-        # never materialized (ops/paged_attention.py).
+        # length out of pool[layer] through the Pallas kernel; neither
+        # the layer's slab nor the padded pool view is ever
+        # materialized (ops/paged_attention.py).
         from kvedge_tpu.ops import pallas_interpret
         from kvedge_tpu.ops.paged_attention import paged_decode_attention
 
         att = paged_decode_attention(
             q[:, 0], new_pool_k, new_pool_v, tables, q_positions[:, 0],
-            scale_k=new_scale_k, scale_v=new_scale_v,
+            layer, scale_k=new_scale_k, scale_v=new_scale_v,
             interpret=pallas_interpret(),
         )  # [B, H, Dh], kv-major head layout — same as the einsum's
         x = x + att.reshape(batch, 1, h * dh) @ w_out.astype(dtype)
     else:
         gk, gv = _gathered(
-            dataclasses.replace(state, tables=tables),
             (new_pool_k, new_pool_v, new_scale_k, new_scale_v),
-            dtype,
+            layer, tables, kv, dtype,
         )
         qg = q.reshape(batch, q_len, kv, group, dh)
         scores = jnp.einsum("bqkgd,bskd->bkgqs", qg, gk) / (dh ** 0.5)
@@ -1704,18 +1733,25 @@ def _paged_attend_layer(cfg: TransformerConfig, state: PagedState, x,
 
 def _run_paged(cfg, params, state, x, q_positions, slot=None,
                all_positions: bool = False, write_mask=None):
+    """The layer loop of every paged program. The pool rides the carry
+    WHOLE, beside the activations; only the layer's weights and its
+    index are scanned over. As ``xs``/``ys`` of the scan each layer's
+    slab was sliced out of the stacked pool and stacked back into a new
+    one (and the new one copied into the enclosing window's carry): at
+    a 1.6 GB pool that was 14 of a decode step's 23 ms (PERF.md §5)."""
     def body(carry, xs):
-        layer_params, slabs = xs
-        out, slabs = _paged_attend_layer(
-            cfg, state, carry, layer_params, slabs,
+        x, pools = carry
+        layer_params, layer = xs
+        return _paged_attend_layer(
+            cfg, state, x, layer_params, layer, pools,
             q_positions, slot, write_mask,
-        )
-        return out, slabs
+        ), None
 
-    x, new_slabs = jax.lax.scan(
-        body, x,
+    (x, new_slabs), _ = jax.lax.scan(
+        body,
+        (x, (state.pool_k, state.pool_v, state.scale_k, state.scale_v)),
         (stacked_layer_params(params, cfg),
-         (state.pool_k, state.pool_v, state.scale_k, state.scale_v)),
+         jnp.arange(cfg.n_layers, dtype=jnp.int32)),
     )
     x = _rmsnorm(x, params["ln_final"])
     logits = tied_readout(

@@ -11,7 +11,12 @@ for (4k-8k+), a half-empty pool still pays full price every step —
 exactly where vLLM-class paged attention earns its keep.
 
 This kernel computes decode attention DIRECTLY over the block table,
-in TWO PHASES so its numerics are the GATHER'S numerics, bitwise:
+in TWO PHASES so its numerics are the GATHER'S numerics, bitwise. It is
+handed the pool as PagedState stores it, whole — [L, P, page, K*Dh],
+the kv heads merged into the lane dim — and the layer's index as a
+scalar-prefetch argument, and DMAs page ``tables[b, j]`` of layer ``l``
+from where it lies (``hbm.at[l, tables[b, j]]``): no caller slices a
+layer's slab out or reshapes it for the kernel's sake.
 
 * grid = (batch,): ONE program per sequence, whose page loop is a
   ``fori_loop`` bounded by that row's LIVE page count (read from the
@@ -97,19 +102,23 @@ def decode_scratch_fits_vmem(max_pages: int, page: int, width: int,
     return need <= _SCRATCH_VMEM_BUDGET
 
 
-def _decode_flat_kernel(tables_ref, pos_ref, q_ref, *rest, page: int,
-                        width: int, dh: int, dtype, quantized: bool):
+def _decode_flat_kernel(tables_ref, pos_ref, layer_ref, q_ref, *rest,
+                        page: int, width: int, dh: int, dtype,
+                        quantized: bool):
     """One program per SEQUENCE, two phases (module docstring).
 
-    Layout: the pools arrive as [P, page, width] views (width = K*Dh,
-    the kv heads merged into the lane dim — TPU DMA slices need a
-    128-aligned minor dim, which [page, K, 64] is not). kbuf/vbuf
+    Layout: the pools arrive whole, [L, P, page, width] as PagedState
+    stores them (width = K*Dh, the kv heads merged into the lane dim —
+    TPU DMA slices need a 128-aligned minor dim, which [page, K, 64] is
+    not), and stay in HBM: page j of row b is DMA'd from
+    ``hbm.at[layer_ref[0], tables_ref[b, j]]``, so no layer's slab is
+    ever sliced out of the pool for the kernel's sake. kbuf/vbuf
     [2, page, width] double buffers in the POOL dtype (int8 pools
     stream as stored, half the DMA bytes); sems [2, 2] one DMA
     semaphore per (slot, k|v). ``scores`` [H, S_cap] fp32 and ``vimg``
     [S_cap, width] compute-dtype hold the assembled row for phase 2.
-    For int8 pools the per-(row, kv-head) scales ([P, page, K] fp32, a
-    few MB whole in VMEM, indexed by page id) are widened across each
+    For int8 pools the layer's per-(row, kv-head) scales ([P, page, K]
+    fp32, a few MB whole in VMEM, indexed by page id) are widened across each
     head's Dh columns by a 0/1 dot and applied with the gather's exact
     dequant formula BEFORE any compute touches the page — from there
     the two variants share one body, which is how the int8 kernel
@@ -126,7 +135,7 @@ def _decode_flat_kernel(tables_ref, pos_ref, q_ref, *rest, page: int,
 
     def dma(slot, j, hbm, buf, which):
         return pltpu.make_async_copy(
-            hbm.at[tables_ref[b, j]], buf.at[slot],
+            hbm.at[layer_ref[0], tables_ref[b, j]], buf.at[slot],
             sems.at[slot, which],
         )
 
@@ -226,26 +235,34 @@ def _decode_flat_kernel(tables_ref, pos_ref, q_ref, *rest, page: int,
 
 
 def paged_decode_attention(q, pool_k, pool_v, tables, q_positions,
-                           *, scale_k=None, scale_v=None,
+                           layer, *, scale_k=None, scale_v=None,
                            interpret: bool = False):
-    """Decode attention over a paged KV pool, block-table-indexed.
+    """Decode attention over layer ``layer`` of a paged KV pool,
+    block-table-indexed.
 
     q [B, H, Dh] (post-rotary, ONE query token per sequence, kv-major
     head layout: head h = kv_head * group + g — split_qkv's layout);
-    pool_k/pool_v [P, page, K, Dh]; tables [B, max_pages] int32;
-    q_positions [B] int32 (row b attends key positions 0..q_positions[b],
-    whose K/V — including the current token's — are already scattered).
-    ``scale_k``/``scale_v`` ([P, page, K] fp32) mark an int8 pool: the
-    kernel streams pages as stored and dequantizes in VMEM with the
+    pool_k/pool_v [L, P, page, K*Dh], the whole pool as PagedState holds
+    it (kv heads merged K-major into the lane dim); ``layer`` an int32
+    scalar, traced or not; tables [B, max_pages] int32; q_positions [B]
+    int32 (row b attends key positions 0..q_positions[b], whose K/V —
+    including the current token's — are already scattered).
+    ``scale_k``/``scale_v`` ([L, P, page, K] fp32) mark an int8 pool:
+    the kernel streams pages as stored and dequantizes in VMEM with the
     gather's exact formula. Returns [B, H, Dh], BIT-IDENTICAL to the
     gather path's decode attention. DMA cost scales with each row's
-    LIVE page count.
+    LIVE page count; the pool itself is neither sliced nor reshaped.
     """
     batch, h, dh = q.shape
-    pages_total, page, kv, _ = pool_k.shape
+    _, _, page, width = pool_k.shape
     _, max_pages = tables.shape
+    if width % dh:
+        raise ValueError(
+            f"pool width {width} is not a whole number of heads of "
+            f"{dh} (q is [B, H, Dh], the pools [L, P, page, K*Dh])"
+        )
+    kv = width // dh
     group = h // kv
-    width = kv * dh
     s_cap = max_pages * page
     quantized = scale_k is not None
     if width % 128 and not interpret:
@@ -269,10 +286,7 @@ def paged_decode_attention(q, pool_k, pool_v, tables, q_positions,
             f"use paged_attention='gather' for this pool geometry"
         )
 
-    # kv heads merged into the lane dim: a [page, width] slice is a
-    # contiguous, 128-aligned DMA (the [page, K, 64] layout is not).
-    k_view = pool_k.reshape(pages_total, page, width)
-    v_view = pool_v.reshape(pages_total, page, width)
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
     # Placed queries: head h = k'*group + g occupies columns
     # [k'*Dh, (k'+1)*Dh), zeros elsewhere — the full-width dot then
     # yields exactly the per-head scores (zero slots contribute nothing
@@ -282,7 +296,7 @@ def paged_decode_attention(q, pool_k, pool_v, tables, q_positions,
     place = (head_slot[:, None] == col_slot[None, :])  # [H, width]
     q2 = jnp.where(place[None], jnp.tile(q, (1, 1, kv)), 0)
 
-    q_spec = pl.BlockSpec((1, h, width), lambda b, t, p: (b, 0, 0))
+    q_spec = pl.BlockSpec((1, h, width), lambda b, t, p, l: (b, 0, 0))
     pool_specs = [
         pl.BlockSpec(memory_space=pl.ANY),  # pools stay in HBM;
         pl.BlockSpec(memory_space=pl.ANY),  # the kernel DMAs pages
@@ -295,29 +309,30 @@ def paged_decode_attention(q, pool_k, pool_v, tables, q_positions,
         pltpu.SemaphoreType.DMA((2, 2)),
     ]
     if quantized:
-        # Scale arrays ride whole in VMEM (a few MB) and are indexed
-        # by page id — no extra DMA machinery.
+        # The layer's scale arrays ride whole in VMEM (a few MB) and
+        # are indexed by page id — no extra DMA machinery.
         in_specs = [q_spec,
                     pl.BlockSpec(memory_space=pltpu.VMEM),
                     pl.BlockSpec(memory_space=pltpu.VMEM),
                     *pool_specs]
         args = (tables.astype(jnp.int32), q_positions.astype(jnp.int32),
-                q2, scale_k.astype(jnp.float32),
-                scale_v.astype(jnp.float32), k_view, v_view)
+                layer, q2,
+                scale_k[layer[0]].astype(jnp.float32),
+                scale_v[layer[0]].astype(jnp.float32), pool_k, pool_v)
     else:
         in_specs = [q_spec, *pool_specs]
         args = (tables.astype(jnp.int32), q_positions.astype(jnp.int32),
-                q2, k_view, v_view)
+                layer, q2, pool_k, pool_v)
     kernel = functools.partial(
         _decode_flat_kernel, page=page, width=width, dh=dh,
         dtype=q.dtype, quantized=quantized,
     )
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(batch,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, h, width), lambda b, t, p: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, h, width), lambda b, t, p, l: (b, 0, 0)),
         scratch_shapes=scratch,
     )
     out_wide = pl.pallas_call(

@@ -116,10 +116,12 @@ _COALESCABLE = frozenset((
 
 def _slice_kernels(mesh, cfg, quantized: bool = False):
     """The paged kernels re-jitted with pinned output shardings: the
-    K/V pools shard over the ``model`` axis on the kv-heads dim (the
-    per-token K/V a model-sharded layer produces is already
-    head-sharded, so scatters stay local and no host ever materializes
-    the whole pool), falling back to replication when the heads don't
+    K/V pools shard over the ``model`` axis on their merged ``K*Dh``
+    lane dim — K-major, so a chip's columns are whole kv heads, the
+    same bytes a kv-heads dim would give it (the per-token K/V a
+    model-sharded layer produces is already head-sharded, so scatters
+    stay local and no host ever materializes the whole pool), falling
+    back to replication when the heads don't
     divide; logits/tokens/tables pin REPLICATED so each process reads
     them from its own addressable shard (``addressable_data(0)``) with
     no extra collective. Compiled programs are the single-host impl
@@ -133,10 +135,10 @@ def _slice_kernels(mesh, cfg, quantized: bool = False):
     model = axis_sizes.get("model", 1)
     head_sharded = model > 1 and cfg.kv_heads % model == 0
     pool_sh = (
-        NamedSharding(mesh, P(None, None, None, "model", None))
+        NamedSharding(mesh, P(None, None, None, "model"))
         if head_sharded else rep
     )
-    # int8 scales [L, P, page, K] shard with the pool's kv-head dim.
+    # int8 scales [L, P, page, K] shard with the pool's kv heads.
     scale_sh = (
         (NamedSharding(mesh, P(None, None, None, "model"))
          if head_sharded else rep)
@@ -203,7 +205,10 @@ def _slice_kernels(mesh, cfg, quantized: bool = False):
     # replicated page bytes back into the sharded pools (each process
     # keeps its own head shard of the update). No dtype conversion in
     # either — the swap path's bit-exactness contract.
-    swap_gather = jax.jit(_gather_pages_impl, out_shardings=rep)
+    swap_gather = jax.jit(
+        _gather_pages_impl, static_argnames=("kv_heads",),
+        out_shardings=rep,
+    )
     swap_scatter = jax.jit(
         _scatter_pages_impl, donate_argnums=(0,), out_shardings=state_sh,
     )
@@ -337,7 +342,8 @@ class SlicePagedKVCache(PagedKVCache):
         quantized = self.kv_quantized
 
         def scale():
-            return (jnp.zeros(shape[:-1], jnp.float32)
+            return (jnp.zeros(shape[:-1] + (self.cfg.kv_heads,),
+                              jnp.float32)
                     if quantized else None)
 
         return jax.jit(
@@ -861,7 +867,8 @@ class SlicePagedKVCache(PagedKVCache):
 
     def _exec_swapout(self, ids: np.ndarray):
         out = self._k_swapout(
-            self.state, self._global(ids.astype(np.int32))
+            self.state, self._global(ids.astype(np.int32)),
+            kv_heads=self.cfg.kv_heads,
         )
         return tuple(self._read(x) for x in out)
 
@@ -912,7 +919,8 @@ class SlicePagedKVCache(PagedKVCache):
         (as stored — [L, n, page, K, Dh] pools plus fp32 scale slabs
         for an int8 pool)."""
         pk = self.state.pool_k
-        shape = (pk.shape[0], n) + tuple(pk.shape[2:])
+        shape = (pk.shape[0], n, pk.shape[2],
+                 self.cfg.kv_heads, self.cfg.d_head)
         out = [np.zeros((n,), np.int32),
                np.zeros(shape, pk.dtype), np.zeros(shape, pk.dtype)]
         if self.kv_quantized:

@@ -898,7 +898,7 @@ def _spec_draft_len(cfg) -> int:
 
 def _serving_page_bytes(cfg, tcfg) -> int:
     """HBM bytes ONE pool page costs: K and V slabs across every layer
-    (``[n_layers, page_size, kv_heads, d_head]`` each), plus the two
+    (``[n_layers, page_size, kv_heads * d_head]`` each), plus the two
     fp32 scale slabs an int8 pool carries alongside (kvcache.PagedState
     docstring). This mirrors ``PagedKVCache.__init__``'s allocation
     exactly — the budget arithmetic and the arrays it pays for must

@@ -63,20 +63,23 @@ def chip(v5e):
 
 
 def _paged(*, batch=8, heads=16, kv=4, dh=64, page=128, max_pages=16,
-           pool_pages=64, int8=False):
-    """(fn, arg shapes) for one paged_decode_attention geometry."""
+           pool_pages=64, layers=2, int8=False):
+    """(fn, arg shapes) for one paged_decode_attention geometry: the
+    whole [L, P, page, K*Dh] pool and a traced layer index, as the
+    layer loop hands them over."""
     from kvedge_tpu.ops.paged_attention import paged_decode_attention
 
     pool_dtype = jnp.int8 if int8 else jnp.bfloat16
-    pool = ((pool_pages, page, kv, dh), pool_dtype)
+    pool = ((layers, pool_pages, page, kv * dh), pool_dtype)
     args = [((batch, heads, dh), jnp.bfloat16), pool, pool,
-            ((batch, max_pages), jnp.int32), ((batch,), jnp.int32)]
+            ((batch, max_pages), jnp.int32), ((batch,), jnp.int32),
+            ((), jnp.int32)]
     if not int8:
         return paged_decode_attention, args
-    scale = ((pool_pages, page, kv), jnp.float32)
+    scale = ((layers, pool_pages, page, kv), jnp.float32)
 
-    def quantized(q, pk, pv, tables, pos, sk, sv):
-        return paged_decode_attention(q, pk, pv, tables, pos,
+    def quantized(q, pk, pv, tables, pos, layer, sk, sv):
+        return paged_decode_attention(q, pk, pv, tables, pos, layer,
                                       scale_k=sk, scale_v=sv)
 
     return quantized, args + [scale, scale]
@@ -145,7 +148,7 @@ def test_kernel_compiles_for_v5e(chip, case):
 
 
 @pytest.mark.parametrize("pool_spec", [
-    P(), P(None, None, "model", None),
+    P(), P(None, None, None, "model"),
 ], ids=["replicated", "kv_heads_over_model"])
 def test_mosaic_refuses_the_kernel_over_several_chips(v5e, pool_spec):
     """Why kvcache.settle_paged_attention sends every pool that spans
@@ -154,13 +157,118 @@ def test_mosaic_refuses_the_kernel_over_several_chips(v5e, pool_spec):
     however its arguments are laid out (only a shard_map can)."""
     mesh = Mesh(np.array(v5e).reshape(2, 2), ("data", "model"))
     fn, shapes = _paged()
-    specs = [P(), pool_spec, pool_spec, P(), P()]
+    specs = [P(), pool_spec, pool_spec, P(), P(), P()]
     args = [jax.ShapeDtypeStruct(shape, dtype,
                                  sharding=NamedSharding(mesh, spec))
             for (shape, dtype), spec in zip(shapes, specs)]
     with pytest.raises(NotImplementedError,
                        match="cannot be automatically partitioned"):
         jax.jit(fn).lower(*args)
+
+
+# The benchmark's cell (benchmark/configs/starcoder2-3b.toml): 16 layers
+# of StarCoder2-3B's widths, 768 pages of 128 tokens, 64 slots of 24
+# pages, and the product's window of 64 steps and prefill chunk of 64.
+_CELL = dict(vocab=49152, d_model=3072, n_heads=24, n_kv_heads=2,
+             n_layers=16, d_ff=12288, max_seq=3072)
+_CELL_PAGES, _CELL_PAGE, _CELL_SLOTS = 768, 128, 64
+
+
+def _cell_program(program: str, chip, monkeypatch):
+    """The capped decode window or the prefill chunk, lowered at the
+    cell's shapes from abstract arguments placed on the described chip.
+    What "auto" and the interpret switch would ask the backend, which
+    is the CPU here, is answered as on the chip: the kernel, compiled."""
+    import kvedge_tpu.ops
+    from kvedge_tpu.models import TransformerConfig, init_params
+    from kvedge_tpu.models import kvcache
+
+    monkeypatch.setattr(kvedge_tpu.ops, "pallas_interpret", lambda: False)
+    cfg = TransformerConfig(**_CELL, paged_attention="kernel")
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    params = jax.tree_util.tree_map(
+        lambda a: on_chip(a.shape, a.dtype),
+        jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    pool = on_chip((cfg.n_layers, _CELL_PAGES, _CELL_PAGE,
+                    cfg.kv_heads * cfg.d_head), jnp.bfloat16)
+    state = kvcache.PagedState(
+        pool_k=pool, pool_v=pool,
+        tables=on_chip((_CELL_SLOTS, cfg.max_seq // _CELL_PAGE), jnp.int32),
+        lengths=on_chip((_CELL_SLOTS,), jnp.int32))
+    if program == "prefill":
+        return params, kvcache._paged_prefill.lower(
+            params, state, on_chip((64,), jnp.int32),
+            on_chip((), jnp.int32), cfg, on_chip((), jnp.int32))
+
+    def row(dtype):
+        return on_chip((_CELL_SLOTS,), dtype)
+
+    return params, kvcache._paged_decode_window_capped.lower(
+        params, state, row(jnp.int32), cfg, 64, row(jnp.bool_),
+        row(jnp.int32), row(jnp.int32))
+
+
+def _pool_sized_operations(hlo: str, sizes: set) -> list:
+    """Instructions of optimised HLO whose array result has one of
+    ``sizes`` elements and that move data: everything but the plumbing
+    (parameters, tuple elements, bitcasts) and the in-place scatters (a
+    ``scatter``, or a fusion whose computation holds one)."""
+    import re
+
+    scatters = {
+        name for name, body in re.findall(
+            r"^%?([\w.\-]+) \([^\n]*\{\n(.*?)^\}", hlo, re.M | re.S)
+        if " scatter(" in body}
+    found = []
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = \w+\[([\d,]*)\]\S* "
+                     r"([\w\-]+)\(", line)
+        if not m or not m.group(2):
+            continue
+        name, dims, opcode = m.groups()
+        if np.prod([int(d) for d in dims.split(",")]) not in sizes:
+            continue
+        if opcode in ("parameter", "get-tuple-element", "bitcast", "scatter"):
+            continue
+        called = re.search(r"calls=%?([\w.\-]+)", line)
+        if opcode == "fusion" and called and called.group(1) in scatters:
+            continue
+        found.append(f"{name} = {opcode} [{dims}]")
+    return found
+
+
+@pytest.mark.parametrize("program", ["decode_window", "prefill"])
+def test_cell_programs_leave_the_pool_where_it_is(chip, monkeypatch,
+                                                  program):
+    """The pool stays where it is (kvcache._run_paged): compiled for the
+    chip at the benchmark cell's shapes, neither the 64-step decode
+    window nor the prefill chunk holds an operation whose result has a
+    whole pool's or a layer's slab's elements, other than the scatters
+    that write the new rows in place: no copy, reshape, dynamic-slice
+    or dynamic-update-slice of 0.8 GB or 50 MB, which were 14 of a
+    decode step's 23 ms (PERF.md section 5). And so no temporary of a
+    pool's size: what is left is the bf16 copies of the weights
+    (ROADMAP S3) and the step's activations."""
+    params, lowered = _cell_program(program, chip, monkeypatch)
+    compiled = lowered.compile()
+    hlo = compiled.as_text()
+    # The window attends through the Mosaic kernel, the prefill gathers.
+    assert ("tpu_custom_call" in hlo) == (program == "decode_window")
+    slab = _CELL_PAGES * _CELL_PAGE * 256
+    moved = _pool_sized_operations(hlo, {slab, _CELL["n_layers"] * slab})
+    assert not moved, f"{program} moves the pool about: {moved}"
+    temporaries = compiled.memory_analysis().temp_size_in_bytes
+    weights_bf16 = 2 * sum(
+        a.size for a in jax.tree_util.tree_leaves(params))
+    print(f"{program} at the cell's shapes: {temporaries / 1e9:.3f} GB of "
+          f"temporaries; a bf16 copy of every weight is "
+          f"{weights_bf16 / 1e9:.3f} GB")
+    assert temporaries < weights_bf16 + 2 * slab * 2 * 4, (
+        f"{program}: {temporaries / 1e9:.2f} GB of temporaries is a "
+        f"pool's size (0.8 GB) over its weights' bf16 copies")
 
 
 def test_scale_budget_case_sits_on_the_budget():

@@ -481,3 +481,105 @@ def test_config_prefix_knobs_round_trip_and_validate():
     assert default.serving_prefix_host_mb == 0
     with pytest.raises(RuntimeConfigError):
         RuntimeConfig.parse("[payload]\nserving_prefix_host_mb = -1\n")
+
+
+# ---- the pool's layout stops at the device's edge ------------------------
+#
+# On the device the pool is [L, P, page, K*Dh] (kvcache.PagedState); what
+# leaves it — snapshots, swaps, the prefix cache's files — keeps the
+# per-head shape [L, n, page, K, Dh] it had before the pool was merged.
+
+
+def _per_head_pages(n: int, kv_dtype: str):
+    """K and V for ``n`` pages, [L, n, page, K, Dh], every element its
+    own bf16-exact value (a multiple of 1/8 under 32: a head or an
+    offset that lands elsewhere changes the numbers)."""
+    shape = (CFG.n_layers, n, 4, CFG.kv_heads, CFG.d_head)
+    k = (np.arange(np.prod(shape)).reshape(shape) % 251) / 8.0
+    if kv_dtype == "int8":
+        # One amax of 127/8 a row: x / scale is then a whole number and
+        # the quantisation round trip is exact.
+        k = k % 16.0
+        k[..., 0] = 127 / 8.0
+    return k.astype(np.float32), (k[..., ::-1] / 2).astype(np.float32)
+
+
+@pytest.mark.parametrize("kv_dtype", ["", "int8"], ids=["bf16", "int8"])
+@pytest.mark.parametrize("path", ["snapshot_write", "swap", "cow"])
+def test_pages_cross_the_devices_edge_per_head(path, kv_dtype):
+    """write_pages -> snapshot_pages, swap out -> swap in and a
+    copy-on-write page give back the same [L, n, page, K, Dh] arrays,
+    whatever the layout on the device, where column k * Dh + d of a row
+    is head k's element d."""
+    cache = kvcache_mod.PagedKVCache(CFG, slots=2, pages=12, page_size=4,
+                                     kv_dtype=kv_dtype)
+    L, K, Dh = CFG.n_layers, CFG.kv_heads, CFG.d_head
+    assert cache.state.pool_k.shape == (L, 12, 4, K * Dh)
+    k, v = _per_head_pages(3, kv_dtype)
+    ids = [5, 2, 9]
+    cache.write_pages(ids, k, v)
+    got_k, got_v = cache.read_pages(ids)
+    assert got_k.shape == got_v.shape == (L, 3, 4, K, Dh)
+    np.testing.assert_array_equal(got_k, k)
+    np.testing.assert_array_equal(got_v, v)
+    if not kv_dtype:
+        on_device = np.asarray(cache.state.pool_k[:, 2], np.float32)
+        np.testing.assert_array_equal(
+            on_device.reshape(L, 4, K, Dh), k[:, 1])
+    if path == "snapshot_write":
+        snapshot = cache.snapshot_pages(ids)
+        assert [a.shape for a in snapshot] == (
+            [(L, 3, 4, K, Dh)] * 2
+            + ([(L, 3, 4, K)] * 2 if kv_dtype else []))
+        return
+    stored = cache.swapout_pages(ids)
+    assert stored[0].shape == stored[1].shape == (L, 3, 4, K, Dh)
+    if path == "swap":
+        cache.swapin_pages([0, 1, 3], stored)
+        again = cache.swapout_pages([0, 1, 3])
+    else:
+        # Slot 0 takes pages 11, 10, 8 off the free list; a second holder
+        # of its first page makes the next write there diverge.
+        cache.admit(0, 12)
+        src = cache.slot_pages(0)[0]
+        cache.swapin_pages(cache.slot_pages(0), stored)
+        cache.retain_pages([src])
+        dst = cache.cow_page(0, 0)
+        assert dst is not None and dst != src
+        again = cache.swapout_pages([dst] + cache.slot_pages(0)[1:])
+    for a, b in zip(again, stored):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(a, b)
+
+
+def test_prefix_file_keeps_its_shapes_and_pr24s_file_loads(params,
+                                                           tmp_path):
+    """The persisted prefix cache holds [L, n, page, K, Dh] float32
+    arrays, as it did before the pool's heads were merged on the device:
+    a file written by PR 24's tree (tests/fixtures/prefix/pr24_bf16.npz:
+    this CFG, PRNGKey(0), STEM + [7, 7] and 6 new tokens, page size 4)
+    loads, its three entries serve the stem warm and the stream is the
+    one that tree emitted; and a dump from this tree has the same
+    arrays in it."""
+    import pathlib
+
+    old = pathlib.Path(__file__).parent / "fixtures/prefix/pr24_bf16.npz"
+    prompt, emitted = STEM + [7, 7], [90, 104, 104, 69, 36, 106]
+    server = PagedGenerationServer(params, CFG, slots=2, pages=24,
+                                   page_size=4)
+    try:
+        assert server.load_prefix_cache(str(old), "pr24") == 3
+        assert server.submit(prompt, n_new=6) == prompt + emitted
+        assert server.stats()["prefix_hits"] == 1
+        mine = str(tmp_path / "pc.npz")
+        assert server.dump_prefix_cache(mine, "pr25") >= 3
+    finally:
+        server.close()
+    with np.load(old) as was, np.load(mine) as now:
+        for name in ("pool_k", "pool_v"):
+            assert now[name].dtype == was[name].dtype == np.float32
+            assert now[name].shape[2:] == was[name].shape[2:] == (
+                4, CFG.kv_heads, CFG.d_head)
+            assert now[name].shape[0] == CFG.n_layers
+        np.testing.assert_array_equal(now["pool_k"][:, :3], was["pool_k"])
+        np.testing.assert_array_equal(now["pool_v"][:, :3], was["pool_v"])

@@ -164,9 +164,7 @@ def test_sharded_pool_matches_reference(params):
     mesh = Mesh(devs, ("data", "model"))
     cache = SlicePagedKVCache(CFG, slots=2, pages=16, page_size=8,
                               mesh=mesh)
-    assert cache.state.pool_k.sharding.spec == P(
-        None, None, None, "model", None
-    )
+    assert cache.state.pool_k.sharding.spec == P(None, None, None, "model")
     server = PagedGenerationServer(params, CFG, cache=cache)
     try:
         prompt = [5, 9, 2, 7, 1]
