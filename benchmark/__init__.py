@@ -4,7 +4,8 @@
 --trace <0|1>`` starts the serve payload through ``start_runtime``,
 drives it over HTTP from a child process that never imports JAX, and
 prints one JSON line. Everything that belongs to one configuration,
-traffic mix, cell or per-layer metric is a file of its own under
-``configs/``, ``traffic/``, ``cells/`` and ``metrics/``, found by the
-name ``BENCHMARK.json`` gives; PERF.md says why each exists.
+block, traffic mix, cell or per-layer metric is a file of its own under
+``configs/``, ``references/``, ``traffic/``, ``cells/`` and ``metrics/``,
+found by the name ``BENCHMARK.json`` or the configuration gives; PERF.md
+says why each exists.
 """

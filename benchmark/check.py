@@ -2,7 +2,8 @@
 
 Once the window has closed and the server's arrays are freed, a sample
 of the requests it finished (drawn from the seed, the longest always in
-it) is run through ``reference.py``, prompt and served tokens together.
+it) is run through the reference of the cell's own block
+(``references/<block>.py``), prompt and served tokens together.
 At each served position the reference's best logit is compared with its
 logit of the token the server chose: 0 where they agree, the gap where
 the server's arithmetic (bf16, its kernels, its cache) tipped a near

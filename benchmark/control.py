@@ -71,7 +71,7 @@ def main(argv: list[str] | None = None) -> int:
                         help="names the output file")
     args = parser.parse_args(argv)
 
-    from benchmark import cellspec, check, reference, run
+    from benchmark import cellspec, check, run
 
     def say(text):
         print(text, flush=True)
@@ -89,7 +89,8 @@ def main(argv: list[str] | None = None) -> int:
                       say)
     model = cell.config["model"]
     n = int(cell.load["check"]["requests"])
-    weights = reference.make_weights(model, run._layer_sharding(model))
+    reference = cell.reference
+    weights = reference.make_weights(model)
     rows = []
     for seed, records in by_seed.items():
         chosen = check.sample(records, seed, args.seconds, n,

@@ -11,8 +11,9 @@ traced runs only), ``setup``, ``cell``, ``plan``, ``peak``.
 
 from __future__ import annotations
 
-import importlib.util
 import os
+
+from benchmark import cellspec
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
@@ -23,11 +24,8 @@ def readers(directory: str = HERE) -> dict:
     for file in sorted(os.listdir(directory)):
         if not file.endswith(".py") or file.startswith("_"):
             continue
-        spec = importlib.util.spec_from_file_location(
-            "benchmark_metric_" + file[:-3].replace(".", "_"),
-            os.path.join(directory, file))
-        module = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(module)
+        module = cellspec.load_module("benchmark_metric_" + file[:-3],
+                                      os.path.join(directory, file))
         for name in module.NAMES:
             if name in found:
                 raise ValueError(f"two readers for metric {name!r}")

@@ -32,6 +32,25 @@ def live_in_window(records: list, seconds: float,
             and (r["last"] is None or r["last"] >= 0.0)]
 
 
+def clients_dry(requests: list, records: list, seconds: float) -> dict:
+    """Closed-loop clients whose chain of planned ``requests`` ended
+    inside the window, and the second the first of them did: from then
+    on that client's row stood empty, and ``out_tok_s`` reads less than
+    the server could have given. A cell's chains are sized so that none
+    does (PERF.md, section 4)."""
+    planned: dict = {}
+    for r in requests:
+        if r["client"] >= 0:
+            planned[r["client"]] = planned.get(r["client"], 0) + 1
+    ended: dict = {}
+    for r in finished(records):
+        if r["client"] in planned:
+            ended.setdefault(r["client"], []).append(r["last"])
+    dry = sorted(max(lasts) for client, lasts in ended.items()
+                 if len(lasts) == planned[client] and max(lasts) < seconds)
+    return {"count": len(dry), "first_s": dry[0] if dry else None}
+
+
 def failed(records: list) -> list:
     return [r for r in records if r["error"] and not r.get("cut")]
 

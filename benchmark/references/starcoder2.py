@@ -1,6 +1,11 @@
-"""The plain reference: the configuration's forward pass in float32.
+"""The StarCoder2 block: its plain reference and its counts.
 
-Straightforward ``jax.numpy``, ``default_matmul_precision("highest")``,
+Everything the benchmark believes about this block's mathematics is in
+this file, behind the three functions ``cellspec.py`` asks of a block's
+file: ``make_weights``, ``logits`` and ``decode_step``.
+
+The reference is the configuration's forward pass in float32:
+straightforward ``jax.numpy``, ``default_matmul_precision("highest")``,
 no cache, no kernels, no batching, one layer at a time. It imports
 nothing of the program and takes nothing the program made: the weights
 are drawn here, from the same published recipe the serve payload uses
@@ -11,6 +16,10 @@ grouped-query attention over a pre-norm residual stream, an ungated
 tanh-GELU feed-forward, RMSNorm with epsilon 1e-6, a tied head.
 Departures from the published StarCoder2 block are the configuration
 file's ``departures``.
+
+The counts are what the model's equations require of one decode step,
+not what the program happens to read: the matrices once a step in the
+type they are multiplied in (bf16), the live keys and values once.
 """
 
 from __future__ import annotations
@@ -20,8 +29,10 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 WEIGHT_SEED = 0
+BF16 = 2
 
 
 def _shapes(model: dict) -> dict:
@@ -37,11 +48,19 @@ def _shapes(model: dict) -> dict:
     }
 
 
-def make_weights(model: dict, sharding=None) -> dict:
-    """The float32 weights, made on the device in one jitted call each
-    (``sharding``: where a stacked matrix's layers go when one device
-    cannot hold them all)."""
+def _layer_sharding(model: dict):
+    """Where the stacked matrices go: their layers split over the chips
+    when there are several (a model one chip cannot hold)."""
+    devices = jax.devices()
+    if len(devices) == 1 or model["n_layers"] % len(devices):
+        return None
+    return NamedSharding(Mesh(devices, ("layers",)), PartitionSpec("layers"))
+
+
+def make_weights(model: dict) -> dict:
+    """The float32 weights, made on the device in one jitted call each."""
     shapes = _shapes(model)
+    sharding = _layer_sharding(model)
     keys = jax.random.split(jax.random.PRNGKey(WEIGHT_SEED), len(shapes))
     out = {}
     for key, (name, (shape, scale)) in zip(keys, shapes.items()):
@@ -137,3 +156,38 @@ def logits(model: dict, weights: dict, sequences: list,
         # host: one program per padded length, not one per prompt length
         return [np.asarray(readout(x, weights["embedding"], quant))[f:]
                 for x, f in zip(xs, first)]
+
+
+# ---- what one decode step needs, from shapes -----------------------------
+
+
+def layer_params(model: dict) -> int:
+    """Matrix parameters of one block: fused q|k|v, output projection,
+    feed-forward up and down (no biases, the gains are not counted)."""
+    d, h, kv, f = (model["d_model"], model["n_heads"], model["n_kv_heads"],
+                   model["d_ff"])
+    dh = d // h
+    return d * (h + 2 * kv) * dh + h * dh * d + 2 * d * f
+
+
+def matrix_params(model: dict) -> int:
+    """All matrices a token passes through, the tied head included."""
+    return (model["n_layers"] * layer_params(model)
+            + model["vocab"] * model["d_model"])
+
+
+def kv_bytes_per_token(model: dict) -> int:
+    dh = model["d_model"] // model["n_heads"]
+    return model["n_layers"] * 2 * model["n_kv_heads"] * dh * BF16
+
+
+def decode_step(model: dict, rows: float, live_tokens: float) -> dict:
+    """One decode step over ``rows`` sequences holding ``live_tokens``
+    cached positions between them."""
+    d, h = model["d_model"], model["n_heads"]
+    dh = d // h
+    flops = (2.0 * matrix_params(model) * rows
+             + 4.0 * model["n_layers"] * h * dh * live_tokens)
+    nbytes = (BF16 * matrix_params(model)
+              + kv_bytes_per_token(model) * (live_tokens + rows))
+    return {"flops": flops, "bytes": nbytes}

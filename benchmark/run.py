@@ -67,8 +67,7 @@ def measure(cell, seed: int, seconds: float, trace_on: bool, device: dict,
     """Everything after the chip is found; returns the result line as a
     dict. Tests call this on the CPU at a probe size (``layers``: report
     the per-layer metrics although nothing is traced)."""
-    from benchmark import check, metrics, reduce, reference, roofline
-    from benchmark import schedule, trace
+    from benchmark import check, metrics, reduce, roofline, schedule, trace
     from benchmark.harness import Harness
 
     model = cell.config["model"]
@@ -128,40 +127,43 @@ def measure(cell, seed: int, seconds: float, trace_on: bool, device: dict,
     t_check = time.monotonic()
     chosen = check.sample(records, seed, seconds,
                           int(cell.load["check"]["requests"]), plan["loop"])
-    weights = reference.make_weights(model, _layer_sharding(model))
+    weights = cell.reference.make_weights(model)
     numbers = check.token_gaps(model, weights, chosen, seed, model["vocab"],
-                               reference)
+                               cell.reference)
     del weights
     say(f"[check] the reference took {time.monotonic() - t_check:.1f} s")
-    correct = check.verdict(numbers, cell.load["check"]["limits"], say)
+    limits = cell.load["check"]["limits"]
+    correct = check.verdict(numbers, limits, say)
     if failed:
         say(f"[check] {len(failed)} request(s) of the window failed, the "
             f"first: {failed[0]['error']}  FAILED")
     lowered = run["window_lowered"]
     say(f"[check] window_compiles = {len(lowered)}  limit 0  "
         f"{'ok' if not lowered else 'FAILED: ' + ', '.join(lowered)}")
+    dry = reduce.clients_dry(plan["requests"], records, seconds)
+    say(f"[check] clients_dry = {dry['count']}  " + (
+        "ok" if not dry["count"] else
+        f"the first at {dry['first_s']:.1f} s: their rows stood empty from "
+        "then on and out_tok_s reads less than the server could give (not "
+        "part of correct)"))
     correct = correct and not failed and not lowered and bool(live)
     line = {"correct": bool(correct), "attempted": len(live),
             "failed": len(failed), "metrics": result_metrics,
             "device": device_out}
     if breakdown is not None:
         line["breakdown"] = breakdown
+    # every number compared, beside its limit: last in the line
+    line["check"] = {
+        **{name: {"value": numbers[name], "limit": limit}
+           for name, limit in limits.items()},
+        "failed": {"value": len(failed), "limit": 0},
+        "window_compiles": {"value": len(lowered), "limit": 0}}
     if out_dir:
         _write_report(out_dir, cell, seed, trace_on, line, plan, records,
-                      setup, numbers, run, events, harness.meter)
+                      setup, numbers, run, events, harness.meter,
+                      {"clients_dry": dry,
+                       "decode_work": ctx.get("decode_work")})
     return line
-
-
-def _layer_sharding(model: dict):
-    """Where the reference's stacked weights go: layers split over the
-    chips when there are several (a model one chip cannot hold)."""
-    import jax
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec
-
-    devices = jax.devices()
-    if len(devices) == 1 or model["n_layers"] % len(devices):
-        return None
-    return NamedSharding(Mesh(devices, ("layers",)), PartitionSpec("layers"))
 
 
 def _label_gaps(events: list, run: dict, n: int) -> list:
@@ -184,7 +186,7 @@ def _label_gaps(events: list, run: dict, n: int) -> list:
 
 
 def _write_report(out_dir, cell, seed, trace_on, line, plan, records,
-                  setup, numbers, run, events, meter) -> None:
+                  setup, numbers, run, events, meter, more) -> None:
     from benchmark import trace
 
     path = os.path.join(out_dir, cell.name)
@@ -195,7 +197,7 @@ def _write_report(out_dir, cell, seed, trace_on, line, plan, records,
         "gc_pauses": run["gc_pauses"],
         "loadgen_stall_max_s": run["loadgen_stall_max_s"],
         "lowered": [[name, at - run["t0"]] for name, at in meter.programs],
-        "records": records,
+        "records": records, **more,
     }
     name = f"seed{seed}-trace{int(trace_on)}"
     if events:
@@ -228,6 +230,9 @@ def main(argv: list[str] | None = None) -> int:
                                        "benchmark")
     line = measure(cell, args.seed, args.seconds, bool(args.trace), device,
                    out_dir=out_dir)
+    for name, held in line["check"].items():  # the record keeps stderr's end
+        print(f"[check] {name} = {held['value']:.6g}  limit "
+              f"{held['limit']:.6g}", file=sys.stderr)
     print(json.dumps(line), flush=True)
     return 0
 

@@ -150,16 +150,41 @@ def _sorted_ops(events: list, device: str):
     return _INDEX[key]
 
 
-def loop_trips(events: list, program: dict, inner: int) -> int:
-    """How many steps one execution of a scanned program ran: its most
-    frequent operation runs once in every layer of every step, so its
-    count over the ``inner`` layers is the number of steps."""
-    counts: dict = {}
-    for e in ops_inside(events, program):
-        counts[e["name"]] = counts.get(e["name"], 0) + 1
-    if not counts:
-        return 0
-    return max(1, round(max(counts.values()) / inner))
+EDGE_S = 1e-3
+
+
+def whole_programs(events: list, needle: str,
+                   device: str | None = None) -> list[dict]:
+    """The named programs' executions that lie wholly inside the
+    capture. One under way when the profiler started or stopped is in
+    the trace cut short, from the capture's first instant or to its
+    last, with part of its steps: it is left out."""
+    programs = program_events(events, needle, device)
+    if not programs:
+        return []
+    lo, hi = span([e for e in events
+                   if e["device"] == programs[0]["device"]])
+    return [p for p in programs if p["start"] > lo + EDGE_S
+            and p["start"] + p["dur"] < hi - EDGE_S]
+
+
+def steps_per_program(ctx: dict) -> float | None:
+    """Decode steps one dispatched decode program ran, by the server's
+    own counts between the window's two snapshots: ``decode_steps_total``
+    adds each harvested program's steps, and the loop enters its phase
+    ``loop/harvest_wait`` once for each program it harvests, whichever
+    path dispatched it. Nothing here knows what a step is made of."""
+    def counted(snap: dict) -> tuple:
+        harvests = (snap.get("phase_ms") or {}).get("loop/harvest_wait")
+        return snap.get("decode_steps_total"), harvests and harvests[0]
+
+    steps_a, programs_a = counted(ctx["stats_start"])
+    steps_b, programs_b = counted(ctx["stats_end"])
+    if None in (steps_a, programs_a, steps_b, programs_b):
+        return None  # a program from before these counters
+    if steps_b <= steps_a or programs_b <= programs_a:
+        return None
+    return (steps_b - steps_a) / (programs_b - programs_a)
 
 
 def live_rows_and_tokens(records: list, lo: float, hi: float,
@@ -181,18 +206,21 @@ def live_rows_and_tokens(records: list, lo: float, hi: float,
 
 def decode_work(ctx: dict) -> dict | None:
     """The decode programs' seconds and steps in the trace, and the mean
-    batch they ran on."""
+    batch they ran on: the seconds of the executions the capture holds
+    whole, and for each of them the steps the server counted to a
+    program over the window (its every program runs the same number of
+    steps under steady load; where they differ this is their mean)."""
     events = ctx.get("events")
     if not events:
         return None
     if "decode_work" not in ctx:
         needle = ctx["cell"].load["programs"]["decode"]
-        layers = ctx["cell"].config["model"]["n_layers"]
-        programs = program_events(events, needle)
+        programs = whole_programs(events, needle)
+        each = steps_per_program(ctx)
         rows, live = live_rows_and_tokens(ctx["records"], *ctx["trace_span"])
         ctx["decode_work"] = {
             "seconds": sum(p["dur"] for p in programs),
-            "steps": sum(loop_trips(events, p, layers) for p in programs),
+            "steps": each * len(programs) if each else 0,
             "programs": len(programs), "rows": rows, "live_tokens": live}
     return ctx["decode_work"]
 

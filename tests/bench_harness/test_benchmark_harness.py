@@ -7,12 +7,15 @@ window, drain, check) by calling the harness's functions. The command
 itself refuses a CPU backend, and a test shows that it does.
 """
 
+import hashlib
 import json
 import os
 import shutil
 import subprocess
 import sys
+import tomllib
 
+import numpy as np
 import pytest
 
 from benchmark import cellspec, check, metrics, reduce, roofline
@@ -26,6 +29,7 @@ CELLS = [w["name"] for w in BENCH["workloads"]]
 
 PROBE_CONFIG = """
 source = "none: a probe size for the CPU tests"
+reference = "starcoder2"
 [model]
 vocab = 256
 d_model = 64
@@ -41,7 +45,17 @@ serving_slots = 4
 serving_page_size = 16
 serving_pages = 96
 serving_window = 8
+# as in the committed configuration: the default dumps the prefix cache every
+# 30 s through small programs of new shapes. A run alone opens its window 15 s
+# after the server started; beside five busy test workers set-up takes over
+# 30 s and the dump fell inside the 4 s window (window_compiles 4 to 9).
+serving_prefix_persist = false
 """
+# A configuration of another block (the repo's expert feed-forward), which
+# ``references/starcoder2.py`` cannot compute: its own file beside it.
+PROBE2_CONFIG = PROBE_CONFIG.replace(
+    'reference = "starcoder2"', 'reference = "probe2"').replace(
+    "d_ff = 128", "d_ff = 128\nexperts = 4\nexpert_top_k = 2")
 PROBE_METRIC = '''
 """A per-layer metric a later PR might add: requests the window saw."""
 NAMES = ("probe_requests",)
@@ -53,13 +67,18 @@ def read(ctx):
 
 
 def probe_tree(root: str) -> str:
-    """A checkout with one new configuration, mix, cell and per-layer
-    metric, added as files and entries only."""
+    """A checkout with two new configurations (one of the committed
+    block, one of a block with a reference file of its own), mixes,
+    cells and a per-layer metric, added as files and entries only."""
     bench = os.path.join(root, "benchmark")
     shutil.copytree(os.path.join(REPO, "benchmark"), bench,
                     ignore=shutil.ignore_patterns("__pycache__"))
     with open(os.path.join(bench, "configs", "probe.toml"), "w") as fh:
         fh.write(PROBE_CONFIG)
+    with open(os.path.join(bench, "configs", "probe2.toml"), "w") as fh:
+        fh.write(PROBE2_CONFIG)
+    shutil.copy(os.path.join(HERE, "probe2_reference.py"),
+                os.path.join(bench, "references", "probe2.py"))
     with open(os.path.join(bench, "metrics", "probe_requests.py"),
               "w") as fh:
         fh.write(PROBE_METRIC)
@@ -75,8 +94,9 @@ def probe_tree(root: str) -> str:
                          "prefill": "paged_prefill"},
             "check": {"requests": 3, "limits": {"token_gap_max": 0.5,
                                                 "token_gap_mean": 0.01}}}
-    with open(os.path.join(bench, "cells", "probe.tiny.json"), "w") as fh:
-        json.dump(load, fh)
+    for cell in ("probe.tiny", "probe2.tiny"):
+        with open(os.path.join(bench, "cells", cell + ".json"), "w") as fh:
+            json.dump(load, fh)
     closed = {"prompt": {"dist": "uniform", "min": 32, "max": 64,
                          "multiple": 32},
               "output": {"dist": "uniform", "min": 40, "max": 120},
@@ -99,14 +119,21 @@ def probe_tree(root: str) -> str:
         "why": "probe"})
     doc["workloads"].append({"name": "probe.tiny", "config": "probe",
                              "traffic": "tiny", "chips": 1, "why": "probe"})
+    doc["configs"].append({
+        "name": "probe2", "source": "none",
+        "file": "benchmark/configs/probe2.toml", "reduced": [],
+        "why": "probe of another block"})
+    doc["workloads"].append({"name": "probe2.tiny", "config": "probe2",
+                             "traffic": "tiny", "chips": 1, "why": "probe"})
+    chat = ["probe.tiny", "probe2.tiny"]
     for name in ("ttft_p90_ms", "tpot_p50_ms"):  # what a chat cell reports
         doc["end_to_end"].append({
             "name": name, "unit": "ms", "better": "lower", "bound": 0.1,
-            "source": "host_clock", "workloads": ["probe.tiny"]})
+            "source": "host_clock", "workloads": chat})
     doc["per_layer"].append({
         "name": "probe_requests", "unit": "count", "better": "higher",
         "source": "program_counter", "layer": "load generator",
-        "moves": "ttft_p90_ms", "workloads": ["probe.tiny"]})
+        "moves": "ttft_p90_ms", "workloads": chat})
     doc["per_layer"].append({
         "name": "queue_wait_ms", "unit": "ms", "better": "lower",
         "source": "program_counter", "layer": "admission and batching",
@@ -229,6 +256,34 @@ def test_a_delivery_is_credited_over_the_time_it_was_produced_in():
     assert reduce.tokens_in_window([late], 7.0) == pytest.approx(64 + 32)
 
 
+def test_a_chain_that_ends_inside_the_window_is_a_dry_client():
+    """Three clients with chains of two: one ends its second request at
+    7 s of a 10 s window and stands idle after, one is cut at the
+    window's end, one's second request failed. Open-loop requests have
+    no client and never count."""
+    plan = [{"client": c, "index": 2 * c + k} for c in range(3)
+            for k in range(2)] + [{"client": -1, "index": 9}]
+
+    def rec(client, due, last, **kw):
+        return dict(_record(due, due + 0.5, last, 21), client=client, **kw)
+
+    records = [rec(0, -2.0, 3.0), rec(0, 3.0, 7.0),
+               rec(1, -2.0, 4.0), rec(1, 4.0, 10.5, error="cut", cut=True),
+               rec(2, -2.0, 5.0), rec(2, 5.0, 6.0, error="HTTP 503"),
+               rec(-1, 1.0, 2.0)]
+    assert reduce.clients_dry(plan, records, 10.0) \
+        == {"count": 1, "first_s": 7.0}
+    # a chain that ends after the window closed kept its row to the end
+    assert reduce.clients_dry(plan, records, 6.5) \
+        == {"count": 0, "first_s": None}
+    # two dry: the first of them is reported
+    records[3] = rec(1, 4.0, 6.0)
+    assert reduce.clients_dry(plan, records, 10.0) \
+        == {"count": 2, "first_s": 6.0}
+    cell = cellspec.load_cell(CELLS[0])
+    assert cell.load["requests_per_client"] == 16
+
+
 # ---- the trace reducers, on a small recorded trace -----------------------
 
 
@@ -252,6 +307,12 @@ def test_trace_idle_share_and_busy_time(recorded):
         (hi1 - lo1) - trace.busy_seconds(one), rel=1e-6)
 
 
+def _stats(steps: int, programs: int) -> dict:
+    """A ``stats()`` snapshot as far as the step count reads it."""
+    return {"decode_steps_total": steps,
+            "phase_ms": {"loop/harvest_wait": [programs, 0.0]}}
+
+
 def test_trace_named_programs_time_and_steps(recorded):
     events = recorded["events"]
     want = recorded["expect"]
@@ -259,10 +320,99 @@ def test_trace_named_programs_time_and_steps(recorded):
     assert len(decode) == want["decode_programs"]
     assert trace.program_seconds(events, "paged_decode_window") \
         == pytest.approx(want["decode_s"], rel=1e-6)
-    assert [trace.loop_trips(events, p, want["layers"]) for p in decode] \
-        == want["decode_steps"]
     names = [name for name, _ in trace.top_ops(events, 5)]
     assert names == want["top_ops"]
+    # the recording was trimmed to the first three steps of its one decode
+    # window: an execution the capture's end cut short. Its operations
+    # still say three steps of 16 layers, but it is no whole program, and
+    # a capture that holds none has no step time to give.
+    most = max(_op_counts(events, decode[0]).values())
+    assert [round(most / want["layers"])] == want["decode_steps"]
+    assert trace.whole_programs(events, "paged_decode_window") == []
+    cell = cellspec.load_cell(CELLS[0])
+    ctx = {"events": events, "cell": cell, "records": [],
+           "trace_span": trace.span(events),
+           "stats_start": _stats(640, 10), "stats_end": _stats(2560, 40)}
+    assert trace.decode_work(ctx)["steps"] == 0
+    found = metrics.readers()
+    assert found["decode_step_dev_ms.closed"](ctx) is None
+    assert found["decode_roofline_pct.closed"](ctx) is None
+
+
+def _op_counts(events: list, program: dict) -> dict:
+    counts: dict = {}
+    for e in trace.ops_inside(events, program):
+        counts[e["name"]] = counts.get(e["name"], 0) + 1
+    return counts
+
+
+def two_kinds_of_layer_trace(steps=(4, 4, 4), step_s=0.010) -> list:
+    """A hand-made capture of a model whose step is one dense layer and
+    three expert layers, each of which runs ``expert_ffn`` for two
+    experts: the most frequent operation runs six times a step, not once
+    a layer. Decode programs of ``steps`` steps each, the first under
+    way when the capture began and the last when it ended (both cut
+    short, to half their steps), a prefill between two of them."""
+    device, events, t = "/device:TPU:0", [], 0.0
+    op_s = step_s / 14
+
+    def program(name, n_steps, body):
+        nonlocal t
+        start = t
+        for _ in range(n_steps):
+            for op in body:
+                events.append({"device": device, "line": trace.OPS_LINE,
+                               "name": op, "start": t, "dur": op_s * 0.9})
+                t += op_s
+        events.append({"device": device, "line": trace.MODULES_LINE,
+                       "name": name, "start": start, "dur": t - start})
+        t += 0.002  # the host between two programs
+
+    step = (["attention", "dense_ffn"]
+            + ["attention", "router", "expert_ffn", "expert_ffn"] * 3)
+    assert len(step) == 14
+    last = len(steps) - 1
+    for i, n in enumerate(steps):
+        cut = n // 2 if i in (0, last) else n
+        program("jit__paged_decode_window_capped_impl(7)", cut, step)
+        if i == 0:
+            program("jit__paged_prefill_impl(9)", 1, ["attention"] * 4)
+    # a cut execution starts with the capture or ends with it
+    first = min(e["start"] for e in events)
+    for e in events:
+        e["start"] -= first
+    return events
+
+
+def test_steps_are_the_servers_count_not_an_operations_frequency():
+    """Two kinds of layer in a step: the old count (the most frequent
+    operation's runs over the layers) is wrong, the server's count over
+    the executions the capture holds whole is right."""
+    events = two_kinds_of_layer_trace(steps=(4, 4, 4, 4))
+    decode = trace.program_events(events, "paged_decode_window")
+    assert len(decode) == 4
+    whole = trace.whole_programs(events, "paged_decode_window")
+    assert whole == decode[1:3]
+    layers = 4  # one dense, three of experts
+    old = [max(1, round(max(_op_counts(events, p).values()) / layers))
+           for p in decode]
+    assert old == [3, 6, 6, 3]  # 4 steps ran in a whole one: not 6
+    cell = cellspec.load_cell(CELLS[0])
+    # the window's snapshots: 30 programs harvested, 120 steps
+    ctx = {"events": events, "cell": cell, "records": [],
+           "trace_span": trace.span(events), "peak": None,
+           "stats_start": _stats(640, 160), "stats_end": _stats(760, 190)}
+    assert trace.steps_per_program(ctx) == 4.0
+    work = trace.decode_work(ctx)
+    assert work["programs"] == 2 and work["steps"] == 8.0
+    assert work["seconds"] == pytest.approx(2 * 4 * 0.010, rel=1e-6)
+    read = metrics.readers()["decode_step_dev_ms.closed"]
+    assert read(ctx) == pytest.approx(10.0, rel=1e-6)
+    # a program from before the counters: nothing to read, not a guess
+    old_program = dict(ctx, stats_start={}, stats_end={})
+    del old_program["decode_work"]
+    assert trace.steps_per_program(old_program) is None
+    assert read(old_program) is None
 
 
 def test_a_stall_of_the_host_leaves_its_cause_in_the_report():
@@ -302,7 +452,6 @@ def test_live_rows_and_tokens_from_records():
 
 @pytest.mark.parametrize("layers", [16, 30])  # as run; as published
 def test_roofline_counts_from_shapes(layers):
-    import tomllib
     with open(os.path.join(REPO, "benchmark", "configs",
                            "starcoder2-3b.toml"), "rb") as fh:
         config = tomllib.load(fh)
@@ -311,11 +460,14 @@ def test_roofline_counts_from_shapes(layers):
     model = dict(config["model"], n_layers=layers)
     layer = 95_944_704  # 3072 x 3584 + 3072 x 3072 + 2 x 3072 x 12288
     total = layers * layer + 49152 * 3072
-    assert roofline.layer_params(model) == layer
-    assert roofline.matrix_params(model) == total
+    block = cellspec.load_cell("starcoder2-3b.batchgen").reference
+    assert block.__file__ == os.path.join(REPO, "benchmark", "references",
+                                          "starcoder2.py")
+    assert block.layer_params(model) == layer
+    assert block.matrix_params(model) == total
     peak = roofline.peaks("TPU v5 lite")
-    step = roofline.decode_step(model, rows=32, live_tokens=32 * 600)
-    assert step["bytes"] == 2 * total + roofline.kv_bytes_per_token(model) \
+    step = block.decode_step(model, rows=32, live_tokens=32 * 600)
+    assert step["bytes"] == 2 * total + block.kv_bytes_per_token(model) \
         * (32 * 600 + 32)
     # a decode step at these batch sizes is bound by memory, not compute
     assert step["bytes"] / peak["hbm_bytes_per_s"] \
@@ -348,8 +500,20 @@ def test_every_named_thing_has_its_file():
                         ("configs", [c["name"] for c in BENCH["configs"]])):
         files = os.listdir(os.path.join(REPO, "benchmark", kind))
         assert sorted(f.rsplit(".", 1)[0] for f in files) == sorted(names)
+    # a configuration names its block's file, and every file there is some
+    # configuration's
+    blocks = set()
+    for conf in BENCH["configs"]:
+        with open(os.path.join(REPO, conf["file"]), "rb") as fh:
+            blocks.add(tomllib.load(fh)["reference"])
+    files = [f for f in os.listdir(os.path.join(REPO, "benchmark",
+                                                "references"))
+             if not f.startswith("__")]
+    assert sorted(files) == sorted(b + ".py" for b in blocks)
     for name in CELLS:
         cell = cellspec.load_cell(name)
+        for function in ("make_weights", "logits", "decode_step"):
+            assert callable(getattr(cell.reference, function))
         names = {m["name"] for m in cell.end_to_end}
         assert "setup_s" in names and len(names) >= 2
         assert cell.per_layer
@@ -404,6 +568,120 @@ def test_new_files_and_entries_are_enough(probe):
     # the committed cells do not see the newcomer's metric
     real = cellspec.load_cell(CELLS[0], repo=probe)
     assert "probe_requests" not in {m["name"] for m in real.per_layer}
+
+
+@pytest.mark.parametrize("config, missing", [
+    ({}, 'reference = "<stem>"'),
+    ({"reference": ""}, 'reference = "<stem>"'),
+    ({"reference": "nowhere"}, os.path.join("references", "nowhere.py")),
+])
+def test_a_configuration_without_its_blocks_file_is_an_error(
+        probe, config, missing):
+    """Never a default: the error names the configuration's file and the
+    key or the file it lacks."""
+    root = os.path.join(probe, "benchmark")
+    with pytest.raises(SystemExit) as refused:
+        cellspec.load_reference({"file": "benchmark/configs/x.toml"},
+                                config, root)
+    assert "benchmark/configs/x.toml" in str(refused.value)
+    assert missing in str(refused.value)
+
+
+# The float32 logits of ``benchmark/reference.py`` on the parent of the PR
+# that moved it (ISSUE 26), at the probe size, for the input below: sha256
+# over the three arrays' bytes, for the reference and its two ``quant``s.
+PARENT_LOGITS = {
+    "": "6ec3771e9dbbb8d15f0ddb9a1f275a0f135738841995daba25edc680be847102",
+    "int8":
+        "5f1252542b5d77e2b7479674196d2a463c21e7cc549ec4879db72d3be8ea1c63",
+    "bf16":
+        "3b24c4eaaee5d431853a6140f511e91f35574e3e07ee0a61132b0bbb0ce321a8",
+}
+
+
+@pytest.mark.parametrize("quant", sorted(PARENT_LOGITS))
+def test_the_moved_reference_computes_what_it_did_before(quant):
+    """Bit for bit: the move changed where the block's file is, not its
+    arithmetic."""
+    block = cellspec.load_cell(CELLS[0]).reference
+    model = {"vocab": 256, "d_model": 64, "n_heads": 4, "n_kv_heads": 2,
+             "n_layers": 2, "d_ff": 128}
+    weights = block.make_weights(model)
+    sequences = [schedule.prompt_tokens(26, i, n, 256)
+                 for i, n in enumerate((64, 96, 128))]
+    rows = block.logits(model, weights, sequences, [31, 0, 100], quant=quant)
+    assert [r.shape for r in rows] == [(33, 256), (96, 256), (28, 256)]
+    digest = hashlib.sha256()
+    for r in rows:
+        assert r.dtype == np.float32
+        digest.update(np.ascontiguousarray(r).tobytes())
+    assert digest.hexdigest() == PARENT_LOGITS[quant]
+
+
+def test_a_block_of_its_own_runs_by_its_own_file(probe, tmp_path):
+    """A configuration of another block, added as a toml, a file under
+    ``references/``, a cell file and entries: a whole CPU run is correct
+    by that file's ``logits`` over that file's weights, and the committed
+    block's file, asked about the same served tokens, says they are not
+    its model's."""
+    out = str(tmp_path)
+    cell, line, said = _measure(probe, 26, name="probe2.tiny", out_dir=out)
+    block = cell.reference
+    assert block.__file__ == os.path.join(probe, "benchmark", "references",
+                                          "probe2.py")
+    assert block.CALLS == ["make_weights", "logits"]
+    assert line["correct"] is True, said
+    assert line["failed"] == 0 and line["attempted"] == 12
+    held = line["check"]
+    assert list(line)[-1] == "check"  # the numbers compared come last
+    assert held["token_gap_mean"]["value"] <= held["token_gap_mean"]["limit"]
+    assert set(held) == {"token_gap_max", "token_gap_mean", "failed",
+                         "window_compiles"}
+    # the same served tokens by the block the benchmark already had
+    other = cellspec.load_cell("probe.tiny", repo=probe).reference
+    assert other.__name__ != block.__name__
+    with open(os.path.join(out, "probe2.tiny", "seed26-trace0.json")) as fh:
+        report = json.load(fh)
+    records, ours = report["records"], report["check"]
+    assert ours["tokens"] > 50
+    assert ours["token_gap_mean"] == held["token_gap_mean"]["value"]
+    chosen = check.sample(records, 26, 4.0, 3, "open")
+    model = cell.config["model"]
+    theirs = check.token_gaps(model, other.make_weights(model), chosen, 26,
+                              256, other)
+    assert theirs["tokens"] == ours["tokens"]
+    assert theirs["token_gap_mean"] > 10 * held["token_gap_mean"]["limit"]
+    assert not check.verdict(theirs, cell.load["check"]["limits"],
+                             lambda text: None)
+
+
+def test_the_roofline_share_is_of_the_cells_own_blocks_count(probe):
+    """``decode_roofline_pct`` takes the operations and bytes of a step
+    from the file the cell's configuration names."""
+    events = two_kinds_of_layer_trace()
+    peak = roofline.peaks("TPU v5 lite")
+    read = metrics.readers(os.path.join(probe, "benchmark", "metrics"))[
+        "decode_roofline_pct.closed"]
+    shares = {}
+    for name in ("probe.tiny", "probe2.tiny"):
+        cell = cellspec.load_cell(name, repo=probe)
+        ctx = {"events": events, "cell": cell, "peak": peak,
+               "records": [_record(-1.0, -0.5, 9.0, 400, prompt=100),
+                           _record(-1.0, -0.5, 9.0, 400, prompt=60)],
+               "trace_span": trace.span(events),
+               "stats_start": _stats(0, 0), "stats_end": _stats(120, 30)}
+        shares[name] = read(ctx)
+        if name == "probe2.tiny":  # the reader went to this cell's file
+            assert cell.reference.CALLS == ["decode_step"]
+        work = ctx["decode_work"]
+        assert work["rows"] == 2.0 and work["steps"] == 4.0
+        step = cell.reference.decode_step(cell.config["model"], 2.0,
+                                          work["live_tokens"])
+        assert shares[name] == pytest.approx(
+            100.0 * roofline.least_seconds(step, peak) * 4.0
+            / work["seconds"])
+    # four experts of which two rows can reach all: more bytes a step
+    assert shares["probe2.tiny"] > 1.5 * shares["probe.tiny"] > 0
 
 
 @pytest.fixture(scope="module")
@@ -514,8 +792,7 @@ def test_the_int8_control_reads_wider_gaps():
     further below the float32 reference's best than the ones bf16, the
     precision a sound run serves in, puts first. (On the chip, at the
     cells' sizes and against the program itself: PERF.md.)"""
-    from benchmark import reference
-
+    reference = cellspec.load_cell(CELLS[0]).reference
     model = {"vocab": 2048, "d_model": 256, "n_heads": 4, "n_kv_heads": 2,
              "n_layers": 4, "d_ff": 512}
     weights = reference.make_weights(model)
