@@ -17,6 +17,7 @@ from kvedge_tpu.models.transformer import (
     forward_with_aux,
     loss_fn,
     make_train_step,
+    serving_params,
 )
 from kvedge_tpu.models.decode import (
     KVCache,
@@ -38,6 +39,7 @@ __all__ = [
     "forward_with_aux",
     "loss_fn",
     "make_train_step",
+    "serving_params",
     "KVCache",
     "init_cache",
     "prefill",

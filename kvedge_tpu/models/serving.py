@@ -77,6 +77,21 @@ def _raw_key_data(key) -> np.ndarray:
     return np.asarray(arr, np.uint32)
 
 
+def weights_summary(params: dict) -> tuple[float, str]:
+    """(GB, dtype) of the tree the programs read, from the leaves'
+    shapes alone (no device call): all its bytes, and the dtype that
+    holds most of them — ``bfloat16`` for a tree cast at load
+    (``transformer.serving_params``: the norm gains and the router stay
+    float32), ``float32`` for masters handed over as they are."""
+    import jax
+
+    by_dtype: dict[str, int] = {}
+    for leaf in jax.tree_util.tree_leaves(params):
+        name = str(leaf.dtype)
+        by_dtype[name] = by_dtype.get(name, 0) + leaf.nbytes
+    return sum(by_dtype.values()) / 1e9, max(by_dtype, key=by_dtype.get)
+
+
 class ServerBusy(RuntimeError):
     """No slot/page capacity became available within the timeout."""
 
@@ -304,6 +319,7 @@ class PagedGenerationServer:
                 "kernel over params that span several devices; build "
                 "it with paged_attention='gather'")
         self._params = params
+        self._weights_gb, self._weights_dtype = weights_summary(params)
         self._cfg = cfg
         # Request-scoped tracing (runtime/tracing.py, SERVING.md rung
         # 18): a shared flight recorder, or None (off — every emission
@@ -2456,6 +2472,15 @@ class PagedGenerationServer:
                 print(f"[kvedge-serve] on_degraded observer failed: "
                       f"{e!r}", flush=True)
 
+    def set_params(self, params: dict) -> None:
+        """Serve from ``params`` from the next dispatch on (the recovery
+        supervisor's checkpoint re-restore). Same shapes and dtypes as
+        the tree it replaces run the programs already compiled."""
+        summary = weights_summary(params)
+        with self._lock:
+            self._params = params
+            self._weights_gb, self._weights_dtype = summary
+
     def revive(self, *, prefill_wait_s: float = 30.0) -> int:
         """Warm-restart a poisoned pool in place (recovery supervisor).
         Returns the number of journaled in-flight requests re-admitted.
@@ -2756,6 +2781,9 @@ class PagedGenerationServer:
             "window": self._window,
             "kv_dtype": ("int8" if self._cache.kv_quantized
                          else str(self._cfg.dtype)),
+            # The tree the programs read: set where it is installed.
+            "weights_gb": self._weights_gb,
+            "weights_dtype": self._weights_dtype,
             "prefix_entries": len(self._prefix_entry_nodes),
             "prefix_hits": self._prefix_hits,
             "prefix_lookups": self._prefix_lookups,

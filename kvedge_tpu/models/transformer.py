@@ -7,7 +7,8 @@ Design notes (why it looks like this, not like a CUDA/torch port):
   ``lax.scan`` over that axis — XLA compiles ONE layer body regardless of
   depth, and the layer axis is never sharded.
 * **bf16 compute, fp32 master params.** Matmuls (the MXU work) run in
-  bfloat16; params and optimizer state stay float32.
+  bfloat16; params and optimizer state stay float32. Serve alone holds
+  its matrices in bfloat16, cast once at load (:func:`serving_params`).
 * **Static shapes everywhere**; the causal mask is a compile-time constant.
 * **Sharding is annotation-only** (see parallel/sharding.py): this file
   contains no collectives — XLA inserts them from the in_shardings.
@@ -242,6 +243,13 @@ PRESETS: dict[str, dict] = {
 }
 
 
+# ``draw * scale`` into the draw's own buffer: the eager product held a
+# leaf twice, and two float32 copies of the largest one beside the rest
+# of the tree were the device's peak (9.17 of 16 GB at the benchmark
+# cell's shapes, PERF.md section 5), above anything serving holds after.
+_scaled = jax.jit(lambda draw, scale: draw * scale, donate_argnums=0)
+
+
 def init_params(key, cfg: TransformerConfig) -> dict:
     """Initialize the flat, layer-stacked param tree (fp32)."""
     cfg.validate()
@@ -252,7 +260,7 @@ def init_params(key, cfg: TransformerConfig) -> dict:
     )
 
     def normal(k, shape, scale):
-        return (jax.random.normal(k, shape, jnp.float32) * scale)
+        return _scaled(jax.random.normal(k, shape, jnp.float32), scale)
 
     params = {
         "embedding": normal(k_embed, (cfg.vocab, d), 0.02),
@@ -310,6 +318,39 @@ def stacked_layer_params(params: dict, cfg: TransformerConfig) -> tuple:
         params["w_qkv"], params["w_out"], params["w_up"], params["w_down"],
         params["ln_attn"], params["ln_mlp"],
     )
+
+
+# The leaves every serving program casts to ``cfg.dtype`` before their
+# first use: the matmul operands (dense and expert forms) and the
+# embedding (gathered, and transposed for the tied head). The router is
+# read in float32 (moe._route) and so is not among them; the norm gains
+# are a few kilobytes that ``_rmsnorm`` casts per program, bit-identical
+# either way, and are left as they are.
+_COMPUTE_DTYPE_LEAVES = frozenset({
+    "embedding", "w_qkv", "w_out", "w_up", "w_down",
+    "w_up_experts", "w_down_experts",
+})
+
+
+def serving_params(params: dict, cfg: TransformerConfig) -> dict:
+    """The tree serving programs read: ``params`` with the leaves they
+    would cast to ``cfg.dtype`` anyway held in that dtype already.
+
+    The layer bodies keep their ``.astype(dtype)``: on a leaf of this
+    tree it is nothing, on float32 masters (the trainer, ``eval``, a
+    server handed them) it is the same rounding made inside the program,
+    every time it runs. A float32 ``cfg.dtype`` returns ``params``
+    itself. Works on any subset of the tree's leaves, so a loader can
+    hand over one leaf at a time and let each master go as its copy
+    exists; a sharded leaf's copy is sharded alike.
+    """
+    dtype = jnp.dtype(cfg.dtype)
+    if dtype == jnp.float32:
+        return params
+    return {
+        name: leaf.astype(dtype) if name in _COMPUTE_DTYPE_LEAVES else leaf
+        for name, leaf in params.items()
+    }
 
 
 def _remat_policy(cfg: TransformerConfig):

@@ -140,7 +140,7 @@ class RecoverySupervisor:
         self.prefix_fingerprint = prefix_fingerprint
         # Optional () -> params: re-restore from the latest checkpoint
         # during warm restart (workload wires StateCheckpointer via
-        # _restore_latest_params; single-host only — a slice restore is
+        # _restore_serving_params; single-host only — a slice restore is
         # a collective the supervisor thread must not run alone).
         self.restore_params = restore_params
         self._rng = random.Random(seed)
@@ -407,7 +407,7 @@ class RecoverySupervisor:
             try:
                 params = self.restore_params()
                 if params is not None:
-                    server._params = params
+                    server.set_params(params)
             except Exception as e:
                 print(f"[kvedge-recover] checkpoint re-restore skipped "
                       f"({e!r}); serving with in-memory params",

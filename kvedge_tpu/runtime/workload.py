@@ -459,7 +459,8 @@ def _restore_latest_params(cfg: RuntimeConfig, tcfg, mesh=None):
     """(step | None, params) from the latest checkpoint, or the fresh
     deterministic init when the volume has none.
 
-    Shared by ``eval`` and ``serve``: the abstract tree MUST mirror
+    Shared by ``eval`` and ``serve`` (which reaches it through
+    :func:`_restore_serving_params`): the abstract tree MUST mirror
     models/training.py's ``fresh_state`` exactly (params AND optimizer
     state, seed 0) — that is the structure orbax wrote, and drift
     surfaces only as a tree-structure mismatch at restore time, so there
@@ -503,6 +504,30 @@ def _restore_latest_params(cfg: RuntimeConfig, tcfg, mesh=None):
     # optimizer moments only to discard them.
     params = init_params(jax.random.PRNGKey(0), tcfg)
     return None, params if mesh is None else shard_params(mesh, params)
+
+
+def _restore_serving_params(cfg: RuntimeConfig, tcfg, mesh=None):
+    """``_restore_latest_params`` for serve: (step | None, the tree the
+    serving programs read), cast once here so that no program converts
+    the float32 masters again each time it runs
+    (``transformer.serving_params``). Every serve consumer sits behind
+    this: the single-host server and its recovery re-restore, the
+    slice's leader and followers, the contiguous ``generate``.
+
+    Cast leaf by leaf, each master dropped as soon as its copy exists
+    (and waited for: a dispatch that ran ahead would hold every copy
+    beside every master), so the load never holds two trees.
+    """
+    import jax
+
+    from kvedge_tpu.models import serving_params
+
+    step, masters = _restore_latest_params(cfg, tcfg, mesh=mesh)
+    params = {}
+    for name in list(masters):
+        params.update(jax.block_until_ready(
+            serving_params({name: masters.pop(name)}, tcfg)))
+    return step, params
 
 
 def run_eval_payload(cfg: RuntimeConfig) -> DeviceCheckResult:
@@ -709,7 +734,7 @@ def _run_multihost_serve(cfg: RuntimeConfig, base, tcfg, mesh):
             "storage: every process restores the same checkpoint "
             "(README 'Multi-host')"
         )
-    restored_step, params = _restore_latest_params(cfg, tcfg, mesh=mesh)
+    restored_step, params = _restore_serving_params(cfg, tcfg, mesh=mesh)
     if cfg.payload_serving == "paged":
         return _run_multihost_paged_serve(
             cfg, base, tcfg, mesh, restored_step, params
@@ -981,6 +1006,7 @@ def _run_multihost_paged_serve(cfg, base, tcfg, mesh, restored_step,
 
     import jax
 
+    from kvedge_tpu.models.serving import weights_summary
     from kvedge_tpu.runtime.sliceserve import (
         SlicePagedKVCache,
         follow_paged,
@@ -1039,9 +1065,13 @@ def _run_multihost_paged_serve(cfg, base, tcfg, mesh, restored_step,
                 "Service routes to ordinal 0)"
             )
 
+        weights_gb, weights_dtype = weights_summary(params)
         follower_fn.stats = lambda: {
             "backend": "multihost-paged-follower",
             "processes": jax.process_count(),
+            # The tree this pod replays the leader's programs over.
+            "weights_gb": weights_gb,
+            "weights_dtype": weights_dtype,
         }
         follower_fn.close = lambda drain=False: None
         follower_fn.join = thread.join
@@ -1321,13 +1351,13 @@ def run_serve_payload(cfg: RuntimeConfig):
         # tensor parallelism to fit serves over the same axes — decode
         # runs under jit with the input shardings driving XLA's SPMD
         # partitioner, exactly like the train step.
-        restored_step, params = _restore_latest_params(cfg, tcfg, mesh=mesh)
+        restored_step, params = _restore_serving_params(cfg, tcfg, mesh=mesh)
         # The recovery supervisor's warm restart re-reads the latest
         # checkpoint (single-host only: a slice restore is a collective
         # the supervisor's thread must not run alone).
         return _build_serve(
             cfg, base, tcfg, params, restored_step,
-            restore_params=lambda: _restore_latest_params(
+            restore_params=lambda: _restore_serving_params(
                 cfg, tcfg, mesh=mesh
             )[1],
         )

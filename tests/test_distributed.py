@@ -493,6 +493,7 @@ _PAGED_SERVE_WORKER = textwrap.dedent("""
                 final = item
         print("STREAMED " + json.dumps(final["tokens"]), flush=True)
         print(f"BACKEND {serve_fn.stats()['backend']}", flush=True)
+        print(f"WEIGHTS {serve_fn.stats()['weights_dtype']}", flush=True)
         serve_fn.close(drain=True)
     else:
         try:
@@ -501,6 +502,7 @@ _PAGED_SERVE_WORKER = textwrap.dedent("""
             sys.exit(1)
         except Exception as e:
             print(f"FOLLOWER503 {type(e).__name__}", flush=True)
+        print(f"WEIGHTS {serve_fn.stats()['weights_dtype']}", flush=True)
         serve_fn.join(timeout=240)
     sys.exit(0)
 """)
@@ -540,6 +542,9 @@ def test_two_process_paged_serve_slice_trained_checkpoint(tmp_path):
     leader_out = outs[0]
     assert "BACKEND multihost-paged" in leader_out
     assert any("FOLLOWER503 GenerateUnavailable" in o for o in outs)
+    # Leader and follower read trees of the same dtype: both restored
+    # through workload._restore_serving_params, cast once at load.
+    assert all("WEIGHTS bfloat16" in o for o in outs), outs
 
     # Reference: the SAME shared checkpoint restored single-host here.
     import jax

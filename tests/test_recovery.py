@@ -231,6 +231,68 @@ def test_single_host_revive_reloads_prefix_and_params(params, tmp_path):
         plan.close()
 
 
+def test_revive_installs_the_cast_tree_through_the_server(tmp_path):
+    """The re-restore the serve payload wires in
+    (workload._restore_serving_params) hands over the tree serve holds,
+    cast once at load, not the float32 masters; and the supervisor
+    installs it through the server's own method, which keeps
+    ``stats()`` telling which tree the programs read."""
+    import dataclasses
+
+    from kvedge_tpu.config.runtime_config import RuntimeConfig
+    from kvedge_tpu.runtime.workload import (
+        _restore_serving_params, train_model_config,
+    )
+
+    rcfg = dataclasses.replace(
+        RuntimeConfig(), state_dir=str(tmp_path / "state"), train_seq=32)
+    tcfg, _ = train_model_config(rcfg)
+    assert tcfg.dtype == "bfloat16"
+
+    def restore_params():
+        return _restore_serving_params(rcfg, tcfg)[1]
+
+    masters = init_params(jax.random.PRNGKey(0), tcfg)
+    plan = FaultPlan(seed=1, kinds=("raise",), fire_window=(3, 4))
+    cache = FaultyCache(tcfg, slots=2, pages=16, page_size=4, plan=plan)
+    # Handed float32 masters, the server still runs, and says so.
+    server = PagedGenerationServer(masters, tcfg, cache=cache)
+    assert server.stats()["weights_dtype"] == "float32"
+    installed = []
+    set_params = server.set_params
+    server.set_params = lambda tree: (installed.append(tree),
+                                      set_params(tree))
+    sup = RecoverySupervisor(
+        server, policy=RecoveryPolicy(max_attempts=2, **FAST),
+        restore_params=restore_params, seed=5,
+    ).attach()
+    prompt = [7, 7, 7, 7, 2, 4, 6, 8, 1]
+    dying = server._thread
+    try:
+        with pytest.raises(ServingFailure):
+            server.submit(prompt, n_new=8)
+        _join_dying(dying)
+        assert sup.wait_settled(timeout=60.0) == HEALTHY
+        (tree,) = installed
+        assert server._params is tree
+        assert set(tree) == set(masters)
+        for name, leaf in tree.items():
+            want = jnp.float32 if name.startswith("ln_") else jnp.bfloat16
+            assert leaf.dtype == want, (name, leaf.dtype)
+        stats = server.stats()
+        assert stats["weights_dtype"] == "bfloat16"
+        assert stats["weights_gb"] == pytest.approx(
+            sum(leaf.nbytes for leaf in tree.values()) / 1e9)
+        # The same rounding, made once: the tokens are the masters'.
+        out = generate(masters, jnp.asarray([prompt], jnp.int32), tcfg,
+                       n_new=8)
+        assert server.submit(prompt, n_new=8) == [
+            int(t) for t in np.asarray(out)[0]]
+    finally:
+        server.close()
+        plan.close()
+
+
 def test_revive_requires_a_poisoned_pool(params):
     cache = FaultyCache(CFG, slots=2, pages=16, page_size=4, plan=None)
     server = PagedGenerationServer(params, CFG, cache=cache)

@@ -388,16 +388,26 @@ def test_slice_overlap_server_greedy_and_sampled_match_plain(params,
         plain.close()
         sliced.close()
 
-def test_slice_multi_frame_follower_replay_matches_leader(params, mesh):
-    """Coalesced broadcasts (SERVING.md rung 23), end to end: a page
+@pytest.mark.parametrize("tree", ["masters", "cast"])
+def test_slice_multi_frame_follower_replay_matches_leader(params, mesh,
+                                                          tree):
+    """Leader and follower read the same tree, float32 masters or, as
+    the serve payload hands both (workload._restore_serving_params), the
+    one cast at load: the replay is exact over either.
+
+    Coalesced broadcasts (SERVING.md rung 23), end to end: a page
     boundary queues the table sync, and the window dispatch a moment
     later flushes sync + dispatch as ONE framed OP_MULTI broadcast.
     The leader's recorded op stream — frames included — replayed
     through the REAL follower loop on a second cache reproduces the
     leader's device tokens bit-exactly, which pins both the frame
     carving (_multi_templates offsets) and the shared exec path."""
+    from kvedge_tpu.models import serving_params
     from kvedge_tpu.runtime.sliceserve import OP_MULTI, follow_paged
 
+    if tree == "cast":
+        params = serving_params(params, CFG)
+        assert params["w_qkv"].dtype == jnp.bfloat16
     leader = SlicePagedKVCache(CFG, slots=2, pages=16, page_size=4,
                                mesh=mesh)
     log = []
