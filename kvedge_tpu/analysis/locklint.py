@@ -249,8 +249,8 @@ def _is_zero(node: ast.AST) -> bool:
 
 def _self_method_refs(value: ast.AST) -> set:
     """Method names a value expression may alias (``self.m``, or an
-    IfExp choosing between several) — resolves the decode loop's
-    ``step = self._loop_once_overlap if ... else self._loop_once``."""
+    IfExp choosing between several, as in
+    ``step = self._a if ... else self._b``)."""
     out: set = set()
     for node in ast.walk(value):
         if (isinstance(node, ast.Attribute)

@@ -376,22 +376,12 @@ class RuntimeConfig:
     # bounds the compiled-program set and admission latency.
     serving_window_min: int = 1
     serving_window_max: int = 256
-    # Overlapped window dispatch for the paged backend: "auto"/"on"
-    # run the double-buffered decode loop (window N+1 is enqueued on a
-    # device-resident carry before window N is harvested, so host
-    # processing and the dispatch RTT hide under device execution —
-    # steps/s approaches 1/max(RTT, window*t_step) instead of
-    # 1/(RTT + window*t_step), SERVING.md rung 16); "off" keeps the
-    # serial windowed loop. Token streams are bit-identical either
-    # way. Price: one extra in-flight window of admission latency.
-    serving_overlap: str = "auto"
     # Server-wide speculative decoding for the paged backend: draft
     # length K (0 = off), or "auto". Greedy traffic advances by batched
     # verify passes — K prompt-lookup drafts per slot, up to K+1 tokens
     # per slot per model forward, token-for-token identical to plain
     # greedy decode (drafts accept only where they equal the model's
-    # own argmax). Pays where decode is weight-bandwidth-bound: see
-    # SPEC_CROSSOVER_r04.json for the model-size crossover. GREEDY
+    # own argmax). Pays where decode is weight-bandwidth-bound. GREEDY
     # requests' page budgets grow by K slack positions (sampled ones
     # can never accept a draft and reserve nothing extra). "auto"
     # probes verify-pass and window cost at serve boot (draft length
@@ -693,10 +683,6 @@ class RuntimeConfig:
                     payload_doc.get("serving_window_max",
                                     cls.serving_window_max)
                 ),
-                serving_overlap=str(
-                    payload_doc.get("serving_overlap",
-                                    cls.serving_overlap)
-                ),
                 serving_spec_window=int(
                     payload_doc.get("serving_spec_window",
                                     cls.serving_spec_window)
@@ -954,11 +940,6 @@ class RuntimeConfig:
                 "[payload] serving_window_min must be <= "
                 "serving_window_max (controller bounds)"
             )
-        if self.serving_overlap not in ("auto", "on", "off"):
-            raise RuntimeConfigError(
-                "[payload] serving_overlap must be 'auto', 'on' or "
-                "'off'"
-            )
         if self.serving_speculative != "auto" and not (
             isinstance(self.serving_speculative, int)
             and 0 <= self.serving_speculative <= 16
@@ -1168,7 +1149,6 @@ class RuntimeConfig:
             f"{s(self.serving_window) if isinstance(self.serving_window, str) else self.serving_window}\n"
             f"serving_window_min = {self.serving_window_min}\n"
             f"serving_window_max = {self.serving_window_max}\n"
-            f"serving_overlap = {s(self.serving_overlap)}\n"
             "serving_speculative = "
             f"{s(self.serving_speculative) if isinstance(self.serving_speculative, str) else self.serving_speculative}\n"
             f"serving_spec_window = {self.serving_spec_window}\n"

@@ -911,112 +911,6 @@ class PagedKVCache:
         )
         return logits
 
-    def step_tokens(self, params, tokens, active=None) -> jax.Array:
-        """One batched GREEDY decode step with the token pick fused
-        into the dispatched program: same growth/length discipline as
-        :meth:`step`, but returns next tokens [slots] int32 instead of
-        [slots, V] logits — the per-step host read shrinks to one int
-        per slot and the argmax stops costing its own dispatch (the
-        bulk of the per-step "hostloop" tax the windowed path was
-        measured against). Sampled slots need the logits and stay on
-        :meth:`step`."""
-        slots = self._step_slots(active)
-        grew = False
-        for slot in slots:
-            grew |= self.grow(slot)
-        if grew:
-            self._sync()
-        toks = self._device_step_tokens(params, tokens, active)
-        for slot in slots:
-            self._host_lengths[slot] += 1
-        return toks
-
-    def _device_step_tokens(self, params, tokens, active):
-        """Device seam: fused step+argmax (see :meth:`step_tokens`)."""
-        toks, self.state = _paged_decode_step_tokens(
-            params, self.state, tokens, self.cfg,
-            self._active_array(self.state, active),
-        )
-        return toks
-
-    def step_window(self, params, tokens, n_steps: int, active=None):
-        """``n_steps`` greedy decode steps in ONE dispatched program.
-
-        The per-token host round trip is the paged path's tax: page
-        tables only change at page boundaries, so between boundaries the
-        decode loop is a pure device-side recurrence — scan it. Pages
-        for the whole window are allocated up front (legal because the
-        serving layer reserves each request's worst-case budget at
-        admission), the greedy argmax feeds back inside the scan, and
-        the host pays one dispatch + one transfer for ``n_steps`` tokens
-        instead of ``n_steps`` of each.
-
-        ``tokens`` is [slots] int32 (each active slot's pending token).
-        Returns generated tokens [n_steps, slots]; row ``i`` is the
-        token produced by feeding row ``i-1`` (row 0 fed ``tokens``).
-        Greedy only — mixed batches with sampled slots use
-        :meth:`step_window_sampled`, whose scan carries the sampled
-        rows' key schedule on device (base indices are host-known at
-        dispatch).
-        """
-        slots = self._step_slots(active)
-        grew = False
-        for slot in slots:
-            grew |= self.grow_to(slot, n_steps)
-        if grew:
-            self._sync()
-        toks = self._device_window(params, tokens, n_steps, active)
-        for slot in slots:
-            self._host_lengths[slot] += n_steps
-        return toks
-
-    def _device_window(self, params, tokens, n_steps: int, active):
-        """Device seam: ``n_steps`` greedy steps in one program."""
-        toks, self.state = _paged_decode_window(
-            params, self.state, tokens, self.cfg, n_steps,
-            self._active_array(self.state, active),
-        )
-        return toks
-
-    def step_window_sampled(self, params, tokens, n_steps: int, active,
-                            key_data, base_steps, temps, top_ps,
-                            sampled_mask):
-        """``n_steps`` mixed greedy/sampled decode steps in ONE
-        dispatched program (see :func:`_paged_decode_window_sampled_impl`
-        for the key-schedule argument). Same growth/length discipline
-        as :meth:`step_window`; all per-row sampling inputs are host
-        arrays ([B]-shaped; ``key_data`` [B, 2] uint32)."""
-        slots = self._step_slots(active)
-        grew = False
-        for slot in slots:
-            grew |= self.grow_to(slot, n_steps)
-        if grew:
-            self._sync()
-        toks = self._device_window_sampled(
-            params, tokens, n_steps, active, key_data, base_steps,
-            temps, top_ps, sampled_mask,
-        )
-        for slot in slots:
-            self._host_lengths[slot] += n_steps
-        return toks
-
-    def _device_window_sampled(self, params, tokens, n_steps: int,
-                               active, key_data, base_steps, temps,
-                               top_ps, sampled_mask):
-        """Device seam: mixed window (overridden by the slice cache)."""
-        import numpy as _np
-
-        toks, self.state = _paged_decode_window_sampled(
-            params, self.state, jnp.asarray(tokens, jnp.int32),
-            self.cfg, n_steps, self._active_array(self.state, active),
-            jnp.asarray(_np.asarray(key_data, _np.uint32)),
-            jnp.asarray(_np.asarray(base_steps, _np.int32)),
-            jnp.asarray(_np.asarray(temps, _np.float32)),
-            jnp.asarray(_np.asarray(top_ps, _np.float32)),
-            jnp.asarray(_np.asarray(sampled_mask, bool)),
-        )
-        return toks
-
     # ---- overlapped (double-buffered) windows ---------------------------
 
     def _window_caps(self, n_steps: int, steps_left) -> "np.ndarray":
@@ -1031,12 +925,21 @@ class PagedKVCache:
 
     def dispatch_window(self, params, tokens, n_steps: int, active=None,
                         steps_left=None, stop_tokens=None):
-        """Enqueue a greedy decode window WITHOUT forcing its result.
+        """Enqueue ``n_steps`` greedy decode steps as ONE program,
+        WITHOUT forcing its result.
 
-        The pipelined twin of :meth:`step_window`: returns the produced
-        tokens as an unforced device value (JAX async dispatch — the
-        program is queued, the host keeps running) to be forced later
-        with :meth:`harvest_window`. Because the device stream executes
+        The per-token host round trip is the paged path's tax: page
+        tables only change at page boundaries, so between boundaries the
+        decode loop is a pure device-side recurrence — scan it. Pages
+        for the whole window are allocated up front (legal because the
+        serving layer reserves each request's worst-case budget at
+        admission) and the greedy argmax feeds back inside the scan.
+        ``tokens`` is [slots] int32 (each active slot's pending token);
+        row ``i`` of the result is the token produced by feeding row
+        ``i-1`` (row 0 fed ``tokens``). The produced tokens come back
+        as an unforced device value (JAX async dispatch — the program
+        is queued, the host keeps running) to be forced later with
+        :meth:`harvest_window`. Because the device stream executes
         in order, a second dispatch may be enqueued before the first is
         harvested; ``tokens=None`` feeds the previous dispatch's final
         token row (the device-resident carry), so no host round trip
@@ -1077,8 +980,10 @@ class PagedKVCache:
                                 top_ps, sampled_mask, steps_left=None,
                                 stop_tokens=None):
         """Mixed greedy/sampled :meth:`dispatch_window` (same carry,
-        cap, growth, and stop-token discipline; sampling inputs as in
-        :meth:`step_window_sampled`)."""
+        cap, growth, and stop-token discipline; see
+        :func:`_paged_decode_window_sampled_capped_impl` for the
+        key-schedule argument). All per-row sampling inputs are host
+        arrays ([B]-shaped; ``key_data`` [B, 2] uint32)."""
         import numpy as _np
 
         slots = self._step_slots(active)
@@ -1747,7 +1652,7 @@ def _run_paged(cfg, params, state, x, q_positions, slot=None,
             q_positions, slot, write_mask,
         ), None
 
-    (x, new_slabs), _ = jax.lax.scan(
+    (x, new_pools), _ = jax.lax.scan(
         body,
         (x, (state.pool_k, state.pool_v, state.scale_k, state.scale_v)),
         (stacked_layer_params(params, cfg),
@@ -1757,13 +1662,13 @@ def _run_paged(cfg, params, state, x, q_positions, slot=None,
     logits = tied_readout(
         x if all_positions else x[:, -1], params["embedding"]
     )
-    return logits, new_slabs
+    return logits, new_pools
 
 
-def _with_slabs(state: PagedState, slabs, **extra) -> PagedState:
-    """A state whose pools/scales are replaced by ``slabs`` (the
-    4-tuple every paged kernel returns), plus any other field."""
-    new_k, new_v, new_sk, new_sv = slabs
+def _with_pools(state: PagedState, pools, **extra) -> PagedState:
+    """A state whose pools/scales are replaced by ``pools`` (the
+    4-tuple ``_run_paged`` returns), plus any other field."""
+    new_k, new_v, new_sk, new_sv = pools
     return dataclasses.replace(
         state, pool_k=new_k, pool_v=new_v, scale_k=new_sk,
         scale_v=new_sv, **extra,
@@ -1779,10 +1684,10 @@ def _paged_prefill_impl(params: dict, state: PagedState, prompt, slot,
     dtype = jnp.dtype(cfg.dtype)
     x = params["embedding"][prompt][None].astype(dtype)  # [1, T, D]
     q_positions = (offset + jnp.arange(prompt.shape[0]))[None]
-    logits, slabs = _run_paged(
+    logits, pools = _run_paged(
         cfg, params, state, x, q_positions, slot
     )
-    return logits[0], _with_slabs(state, slabs)
+    return logits[0], _with_pools(state, pools)
 
 
 _paged_prefill = functools.partial(
@@ -1806,9 +1711,9 @@ def _decode_step_core(params: dict, state: PagedState, tokens,
     masked = dataclasses.replace(
         state, lengths=jnp.where(active, state.lengths, 0)
     )
-    logits, slabs = _run_paged(cfg, params, masked, x, q_positions)
-    return logits, _with_slabs(
-        state, slabs,
+    logits, pools = _run_paged(cfg, params, masked, x, q_positions)
+    return logits, _with_pools(
+        state, pools,
         lengths=state.lengths + active.astype(jnp.int32),
     )
 
@@ -1816,25 +1721,6 @@ def _decode_step_core(params: dict, state: PagedState, tokens,
 _paged_decode_step = functools.partial(
     jax.jit, static_argnames=("cfg",), donate_argnums=(1,)
 )(_decode_step_core)
-
-
-def _decode_step_tokens_core(params: dict, state: PagedState, tokens,
-                             cfg: TransformerConfig, active):
-    """Fused greedy pick: :func:`_decode_step_core` plus the argmax in
-    ONE compiled program, so a per-step loop pays one dispatch and a
-    [B]-int read instead of a dispatch, a second argmax dispatch, and
-    a [B, V] logits transfer. The argmax is the same jnp op the host
-    path ran on the same logits — token-identical by construction
-    (and pinned transitively by the window-vs-step exactness tests,
-    whose scan feeds back this very pick)."""
-    logits, state = _decode_step_core(params, state, tokens, cfg,
-                                      active)
-    return jnp.argmax(logits, axis=-1).astype(jnp.int32), state
-
-
-_paged_decode_step_tokens = functools.partial(
-    jax.jit, static_argnames=("cfg",), donate_argnums=(1,)
-)(_decode_step_tokens_core)
 
 
 def _spec_verify_core(params: dict, state: PagedState, tokens,
@@ -1882,7 +1768,7 @@ def _spec_verify_core(params: dict, state: PagedState, tokens,
     # only for rows that can accept them.
     write_mask = (spec_mask[:, None]
                   | (jnp.arange(1 + k_len) == 0)[None, :])
-    logits, slabs = _run_paged(
+    logits, pools = _run_paged(
         cfg, params, masked, x, q_positions, all_positions=True,
         write_mask=write_mask,
     )  # [B, 1+K, V]
@@ -1898,8 +1784,8 @@ def _spec_verify_core(params: dict, state: PagedState, tokens,
         jnp.concatenate([draft, y[:, -1:]], axis=1),
         jnp.take_along_axis(y, accepted[:, None], axis=1),
     ).astype(jnp.int32)
-    state = _with_slabs(
-        state, slabs,
+    state = _with_pools(
+        state, pools,
         lengths=state.lengths + active.astype(jnp.int32) * (1 + accepted),
     )
     return emitted, accepted, logits[:, 0], state
@@ -2090,45 +1976,21 @@ _paged_spec_window_sampled = functools.partial(
 )(_paged_spec_window_sampled_impl)
 
 
-def _paged_decode_window_impl(params: dict, state: PagedState, tokens,
-                              cfg: TransformerConfig, n_steps: int,
-                              active):
-    """``n_steps`` decode steps with greedy feedback, one program.
-
-    The scan carries (state, pending token); each step feeds the pending
-    token and emits its greedy successor. Inactive slots produce garbage
-    tokens that are never read (their scatters drop, their lengths hold).
-    """
-    _note_trace("window")
-
-    def body(carry, _):
-        state, toks = carry
-        logits, state = _decode_step_core(params, state, toks, cfg, active)
-        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return (state, nxt), nxt
-
-    (state, _), produced = jax.lax.scan(
-        body, (state, tokens), length=n_steps
-    )
-    return produced, state
-
-
-_paged_decode_window = functools.partial(
-    jax.jit, static_argnames=("cfg", "n_steps"), donate_argnums=(1,)
-)(_paged_decode_window_impl)
-
-
 def _paged_decode_window_capped_impl(params: dict, state: PagedState,
                                      tokens, cfg: TransformerConfig,
                                      n_steps: int, active, steps_left,
                                      stop_tokens):
-    """Greedy window with PER-SLOT stop detection in the scan carry.
+    """``n_steps`` decode steps with greedy feedback in one program,
+    with PER-SLOT stop detection in the scan carry.
 
-    The overlap pipeline (serving.py) dispatches window N+1 before the
-    host has harvested window N, so the host can no longer shrink the
-    window to the tightest slot's remaining budget the way the serial
-    path does (_window_steps). Instead each row carries its own budget
-    cap: ``steps_left`` [B] int32 is how many steps row b may still
+    The scan carries (state, pending token); each step feeds the pending
+    token and emits its greedy successor. Inactive slots produce garbage
+    tokens that are never read (their scatters drop, their lengths hold).
+
+    The decode loop (serving.py) dispatches window N+1 before the host
+    has harvested window N, so the host cannot shrink the window to the
+    tightest slot's remaining budget. Instead each row carries its own
+    budget cap: ``steps_left`` [B] int32 is how many steps row b may still
     decode, and the per-step done flag ``i >= steps_left[b]`` freezes a
     finished row — its length holds and its K/V scatters drop (the
     same ``active`` gate chunked prefill relies on), so a speculatively
@@ -2192,13 +2054,22 @@ def _paged_decode_window_sampled_capped_impl(
         base_steps, temps, top_ps, sampled_mask, steps_left,
         stop_tokens):
     """Mixed greedy/sampled window with the per-slot done flag of
-    :func:`_paged_decode_window_capped_impl`. Live rows run the exact
-    key schedule of the serial sampled window (``fold_in(seed,
-    base + i)``), so pipelined and serial sampled decode emit identical
-    tokens; frozen rows' draws are computed and discarded (their
-    outputs are never read and their state never advances). Packs the
-    same ``[fin, stop_at]`` finish-bookkeeping rows onto the produced
-    tokens as the greedy capped window."""
+    :func:`_paged_decode_window_capped_impl`.
+
+    The per-token sampling key is ``fold_in(row_seed, t)`` with ``t`` a
+    pure function of the request's emitted count — host-known at
+    dispatch — so the whole key schedule rides the scan as
+    ``base_steps + i``. Each step applies the SAME nucleus filter and
+    categorical draw as ``decode.generate`` (decode.sample_token), then
+    selects sampled vs greedy per row by ``sampled_mask``: one host
+    round trip serves a window of sampled tokens exactly as it does
+    greedy ones, token for token what per-step sampling would emit.
+    Frozen rows' draws are computed and discarded (their outputs are
+    never read and their state never advances). ``key_data`` is raw
+    uint32 key data ([B, 2] for threefry), wrapped on device — raw data
+    crosses process boundaries (the slice op-stream) where typed key
+    arrays cannot. Packs the same ``[fin, stop_at]`` finish-bookkeeping
+    rows onto the produced tokens as the greedy capped window."""
     _note_trace("window_sampled_capped")
     keys = jax.random.wrap_key_data(key_data)
 
@@ -2238,53 +2109,3 @@ def _paged_decode_window_sampled_capped_impl(
 _paged_decode_window_sampled_capped = functools.partial(
     jax.jit, static_argnames=("cfg", "n_steps"), donate_argnums=(1,)
 )(_paged_decode_window_sampled_capped_impl)
-
-
-def _paged_decode_window_sampled_impl(params: dict, state: PagedState,
-                                      tokens, cfg: TransformerConfig,
-                                      n_steps: int, active, key_data,
-                                      base_steps, temps, top_ps,
-                                      sampled_mask):
-    """``n_steps`` decode steps with mixed greedy/sampled feedback.
-
-    The round-5 fix for the sampled-RTT tax (VERDICT r4 #3): the
-    per-token sampling key is ``fold_in(row_seed, t)`` with ``t`` a
-    pure function of the request's emitted count — host-known at
-    dispatch — so the whole key schedule rides the scan carry as
-    ``base_steps + i``. Each step applies the SAME nucleus filter and
-    categorical draw as the host path (decode.sample_token), then
-    selects sampled vs greedy per row by ``sampled_mask``; one host
-    round trip serves a window of sampled tokens exactly as it does
-    greedy ones, and one sampled co-tenant no longer drags the whole
-    batch onto per-step dispatch.
-
-    ``key_data`` is raw uint32 key data ([B, 2] for threefry), wrapped
-    on device — raw data crosses process boundaries (the slice
-    op-stream) where typed key arrays cannot.
-    """
-    _note_trace("window_sampled")
-    keys = jax.random.wrap_key_data(key_data)
-
-    def body(carry, i):
-        state, toks = carry
-        logits, state = _decode_step_core(params, state, toks, cfg,
-                                          active)
-        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        from kvedge_tpu.models.decode import sample_token
-
-        step_keys = jax.vmap(jax.random.fold_in)(keys, base_steps + i)
-        sampled = sample_token(
-            logits, step_keys, temps[:, None], top_ps[:, None]
-        )
-        nxt = jnp.where(sampled_mask, sampled, greedy).astype(jnp.int32)
-        return (state, nxt), nxt
-
-    (state, _), produced = jax.lax.scan(
-        body, (state, tokens), jnp.arange(n_steps)
-    )
-    return produced, state
-
-
-_paged_decode_window_sampled = functools.partial(
-    jax.jit, static_argnames=("cfg", "n_steps"), donate_argnums=(1,)
-)(_paged_decode_window_sampled_impl)

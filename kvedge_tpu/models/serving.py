@@ -212,8 +212,9 @@ class _Request:
 
     def pick(self, logits_row, step: int) -> int:
         """Next token from a [V] logits row, greedy or sampled. Used at
-        prefill (one row); the decode loop batches every slot's pick
-        into one device call instead (see ``_next_tokens``)."""
+        prefill (one row); the decode windows pick every row's token
+        on the device (``kvcache.dispatch_window`` and
+        ``dispatch_window_sampled``)."""
         import jax.numpy as jnp
 
         if self.sampling is None:
@@ -283,7 +284,7 @@ class PagedGenerationServer:
                  window_min: int = 1, window_max: int = 256,
                  kv_dtype: str = "", cache=None,
                  retry_after_s: float | None = None,
-                 overlap: str = "auto", sched_policy: str = "strict",
+                 sched_policy: str = "strict",
                  sched_weights: dict | None = None,
                  sched_max_queue_depth: int = 0,
                  sched_max_queue_wait_s: float = 0.0,
@@ -344,9 +345,10 @@ class PagedGenerationServer:
         # throughput to that round trip (VERDICT r4 weak #2); the cap
         # is now an operator knob ([payload] serving_window, default
         # 64). The compiled program set stays the powers of two
-        # {2..window} (see _window_steps); the tradeoff is admission
-        # latency — a submitter joins at the next window boundary, so
-        # worst-case wait grows with the window (SERVING.md).
+        # {1, 2..window} (see _dispatch_window_locked); the tradeoff
+        # is admission latency — a submitter joins at the next window
+        # boundary, so worst-case wait grows with the window
+        # (SERVING.md).
         # "auto" hands the choice to the online controller (SERVING.md
         # rung 26): _window starts at the bounds cap and is re-picked
         # at every harvested window from EWMAs of the measured host
@@ -367,19 +369,13 @@ class PagedGenerationServer:
         if window < 1:
             raise ValueError("window must be >= 1")
         self._window = window
-        # Overlapped (double-buffered) window dispatch ([payload]
-        # serving_overlap): the decode loop enqueues window N+1 before
-        # harvesting window N, so the host's round trip and bookkeeping
-        # for N hide under the device's execution of N+1 — steps/s
-        # moves from 1/(R + W*t) toward 1/max(R, W*t) (SERVING.md
-        # rung 16). "auto" and "on" both pipeline (the loop itself
-        # falls back to non-overlapped boundaries whenever exactness
-        # needs one: admissions, cancellations, speculative passes);
-        # "off" keeps the serial loop verbatim.
-        if overlap not in ("auto", "on", "off"):
-            raise ValueError("overlap must be 'auto', 'on' or 'off'")
-        self._overlap = overlap
-        self._overlap_on = overlap != "off"
+        # Double-buffered window dispatch: the decode loop enqueues
+        # window N+1 before harvesting window N, so the host's round
+        # trip and bookkeeping for N hide under the device's execution
+        # of N+1 — steps/s moves from 1/(R + W*t) toward
+        # 1/max(R, W*t) (SERVING.md rung 16). The loop falls back to a
+        # non-overlapped boundary whenever exactness needs one:
+        # admissions, cancellations, legacy speculative passes.
         # The one in-flight (dispatched, unharvested) window record:
         # {"window": steps, "parts": [(slot, req, adv)], "handle":
         # unforced device tokens, "t0": dispatch stamp}. Depth is at
@@ -518,11 +514,8 @@ class PagedGenerationServer:
         # configured but a boundary ran the legacy per-pass path
         # anyway. "sampled" = mixed batch with the sampled-window knob
         # off; "spec_off" = speculation disabled with a spec carry in
-        # flight; "overlap_off" = spec windows need the overlap
-        # pipeline but the serial loop is running.
-        self._spec_window_fallbacks = {
-            "sampled": 0, "spec_off": 0, "overlap_off": 0,
-        }
+        # flight.
+        self._spec_window_fallbacks = {"sampled": 0, "spec_off": 0}
         # Device-resident finish bookkeeping (rung 23): slots whose
         # NEXT boundary sweep should examine them for completion —
         # registered by every site that sets a pending token that
@@ -1351,8 +1344,8 @@ class PagedGenerationServer:
     def _ensure_bucket_locked(self) -> bool:
         """Admission's bucket clause: True iff a free slot INSIDE the
         current device bucket exists. When every free slot lies above
-        the bucket, resize directly if the cache is quiescent (serial
-        loop, or an idle pipeline); otherwise flag the step-up for the
+        the bucket, resize directly if the cache is quiescent (an idle
+        pipeline); otherwise flag the step-up for the
         decode loop's next boundary and keep the caller parked — it is
         woken when the resize lands."""
         if self._free_slots and self._free_slots[0] < self._cache.bucket:
@@ -2304,11 +2297,11 @@ class PagedGenerationServer:
         active[0] = True
         spec_mask = active.copy()
         # The probed window must fit the model (positions 1..1+w) and
-        # be one the serving loop can actually run: _window_steps
-        # floors to a power of two, so probe the floored value — timing
-        # an unrealizable window would overstate the windowed rate near
-        # the crossover (and compile a program real traffic never
-        # reuses).
+        # be one the serving loop can actually run:
+        # _dispatch_window_locked floors to a power of two, so probe
+        # the floored value — timing an unrealizable window would
+        # overstate the windowed rate near the crossover (and compile
+        # a program real traffic never reuses).
         window = min(self._window, self._cfg.max_seq - 1 - k)
         if window > 1:
             window = 1 << (window.bit_length() - 1)
@@ -2334,10 +2327,14 @@ class PagedGenerationServer:
                 return emitted
 
             def run_window():
-                return self._cache.step_window(
-                    self._params, jnp.asarray(step_tokens), window,
-                    active=active,
+                # The capped window the decode loop dispatches, forced
+                # at once: one dispatch + harvest on slot 0.
+                handle = self._cache.dispatch_window(
+                    self._params, step_tokens, window, active=active,
                 )
+                produced = self._cache.harvest_window(handle)
+                self._cache.drop_carry()
+                return produced
 
             def run_spec_window():
                 # One full spec-window dispatch+harvest on slot 0 —
@@ -2804,7 +2801,6 @@ class PagedGenerationServer:
             "prefix_evictions": dict(self._prefix_evictions),
             "journal_shadow_nodes": len(self._prefix_shadow),
             "journal_shadow_bytes": self._journal.extra_bytes,
-            "overlap": 1 if self._overlap_on else 0,
             "overlap_windows_total": self._overlap_windows,
             "overlap_inflight_depth":
                 1 if self._inflight is not None else 0,
@@ -2971,7 +2967,6 @@ class PagedGenerationServer:
             "pages_total": self._pages_total,
             "page_size": self._cache.page_size,
             "window": self._window,
-            "overlap": self._overlap,
             "speculative": self._spec,
             "spec_window": self._spec_window,
             "spec_sampled_window": int(self._spec_sampled_window),
@@ -3329,85 +3324,11 @@ class PagedGenerationServer:
                     req.next_token = int(emitted[slot, a])
                     self._note_finish_candidate_locked(slot, req)
 
-    def _window_steps(self) -> int:
-        """Steps the next device-side decode window may run (lock held).
-
-        Bounded by the tightest remaining budget MINUS the pending token
-        (which the finish-check emits without a step), so no slot ever
-        decodes past its budget; capped at the operator window and
-        floored to a power of two so the set of compiled window programs
-        stays small ({2, 4, ..., window}). Multi-page windows are legal:
-        ``grow_to`` allocates every page the window's scatters need up
-        front, inside the request's admission-time reservation. Sampled
-        requests ride windows too (round 5): their per-token keys are
-        ``fold_in(seed, base + i)`` with ``base`` host-known at
-        dispatch, so the schedule lives in the scan carry
-        (kvcache.step_window_sampled).
-        """
-        w = min(req.n_new - len(req.generated) - 1
-                for req in self._active.values())
-        w = min(w, self._window)
-        if w <= 1:
-            return 1
-        return 1 << (w.bit_length() - 1)
-
-    def _sampled_window(self, tokens, window: int, mask, samplers):
-        """Dispatch one mixed greedy/sampled device window (lock held).
-
-        Builds the per-row sampling inputs: row seeds (raw key data),
-        base token indices (``len(generated) + 1`` — the same schedule
-        the per-step host path folds, so windowed and per-step sampled
-        tokens are identical), temperature/top-p, and the sampled-row
-        mask. Greedy rows get neutral values (temp 1, top_p 1, zero
-        key) that the kernel's per-row select never reads."""
-        n = self._cache.bucket
-        key_data = np.zeros((n,) + self._key_data_shape(samplers),
-                            np.uint32)
-        base_steps = np.zeros((n,), np.int32)
-        temps = np.ones((n,), np.float32)
-        top_ps = np.ones((n,), np.float32)
-        smask = np.zeros((n,), bool)
-        for slot, req in samplers.items():
-            key_data[slot] = req.key_data
-            base_steps[slot] = len(req.generated) + 1
-            temps[slot] = float(req.sampling[1])
-            top_ps[slot] = float(req.sampling[2])
-            smask[slot] = True
-        return self._cache.step_window_sampled(
-            self._params, tokens, window, mask, key_data, base_steps,
-            temps, top_ps, smask,
-        )
-
     @staticmethod
     def _key_data_shape(samplers) -> tuple:
         """Trailing shape of one row's raw key data (threefry: (2,));
         taken from a live request so the impl is never hardcoded."""
         return next(iter(samplers.values())).key_data.shape
-
-    def _next_tokens(self, logits) -> dict[int, int]:
-        """Every active slot's next token from the step's [slots, V]
-        logits — ONE batched argmax plus (when any request samples) ONE
-        batched fold_in/filter/categorical call and one host transfer,
-        instead of per-slot eager chains under the lock."""
-        import jax
-        import jax.numpy as jnp
-
-        from kvedge_tpu.models.decode import sample_token
-
-        samplers = {
-            slot: req for slot, req in self._active.items()
-            if req.sampling is not None
-        }
-        out: dict[int, int] = {}
-        if len(samplers) < len(self._active):
-            # Greedy slots exist: one batched argmax + one host read.
-            greedy = np.asarray(jnp.argmax(logits, axis=-1))
-            out = {
-                slot: int(greedy[slot])
-                for slot in self._active if slot not in samplers
-            }
-        out.update(self._sample_slots(logits, samplers))
-        return out
 
     @staticmethod
     def _sample_slots(logits, samplers: dict) -> dict[int, int]:
@@ -3695,8 +3616,6 @@ class PagedGenerationServer:
             )
 
     def _loop(self) -> None:
-        step = (self._loop_once_overlap if self._overlap_on
-                else self._loop_once)
         self._loop_since = self._phase.mark()
         while True:
             # From here to the lock held (the step stops the phase):
@@ -3712,7 +3631,7 @@ class PagedGenerationServer:
                 # yields the GIL so waiters can take it.
                 # locklint: allow[sleep-under-lock] deliberate GIL yield with the lock RELEASED — breaks the decode loop's lock convoy so expired admission waiters win the reacquisition race (rung 17 fair handoff; removing it starves ServerBusy)
                 time.sleep(0)
-                verdict = step()
+                verdict = self._loop_once()
             if verdict == "exit":
                 self._loop_ran.observe(
                     (time.perf_counter() - self._loop_since) * 1e3)
@@ -3735,197 +3654,12 @@ class PagedGenerationServer:
         self._lock_wait = self._phase("loop/lock_wait")
 
     def _loop_once(self) -> str:
-        """One decode-loop iteration under the lock ("exit" ends it)."""
-        import jax.numpy as jnp
-
-        phase = self._phase
-        with self._work:
-            self._lock_taken_locked()
-            while (not self._active and not self._closed
-                   and not self._sched_attention_locked()
-                   and not (self._draining
-                            and not self._prefilling)):
-                with phase("loop/wait_work"):
-                    self._work.wait()
-            if (self._draining and not self._active
-                    and not self._prefilling
-                    and not self._sched.resume_pending_locked()):
-                # Drained: every accepted request — including any
-                # whose chunked prefill was in flight when the drain
-                # began, and any swapped-out awaiting resume — has
-                # finished.
-                return "exit"
-            if self._closed:
-                for req in self._active.values():
-                    req.error = ServerClosed("server shut down mid-"
-                                             "request")
-                    if req.stream is not None:
-                        req.stream.put(req.error)
-                    req.done.set()
-                self._active.clear()
-                self._fail_swapped_closed_locked()
-                return "exit"
-            try:
-                with phase("loop/boundary"):
-                    self._sweep_cancelled_locked()
-                    self._sweep_finished_locked()
-                    # Scheduler boundary: resume swapped-out requests
-                    # into freed capacity, then preempt for a starved
-                    # head.
-                    self._maybe_resume_locked()
-                    self._maybe_preempt_locked()
-                    self._maybe_step_bucket_locked()
-                    self._maybe_checkpoint_locked()
-                    self._observe_boundary_locked()
-                if not self._active:
-                    return "ran"
-                if (self._spec > 0
-                        and any(req.sampling is None
-                                for req in self._active.values())):
-                    # Speculative mode: greedy slots advance by verify
-                    # passes (sampled slots ride along one token at a
-                    # time); an all-sampled batch falls through to the
-                    # cheaper single-query step below.
-                    if self._spec_window > 0:
-                        # Spec windows ride the overlap pipeline; the
-                        # serial loop can only run legacy passes.
-                        self._spec_window_fallbacks["overlap_off"] += 1
-                    self._spec_pass()
-                    return "ran"
-                # Feed every active slot's pending token through ONE
-                # batched step; inactive slots carry zeros (masked).
-                # The explicit mask (not "every admitted slot") is
-                # what keeps interleaved chunked prefills safe: a
-                # half-prefilled slot is admitted but NOT active.
-                with phase("loop/dispatch"):
-                    tokens = np.zeros((self._cache.bucket,), np.int32)
-                    mask = np.zeros((self._cache.bucket,), bool)
-                    for slot, req in self._active.items():
-                        tokens[slot] = req.next_token
-                        mask[slot] = True
-                    window = self._window_steps()
-                self._count_steps_locked(window, self._cache.bucket,
-                                         self._active.items())
-                if window > 1:
-                    # Device-side window: `window` steps in one
-                    # dispatched scan — the host pays one round trip
-                    # per window, not per token. Admission re-syncs
-                    # between windows (a submitter blocks on this lock
-                    # until the window returns, then joins the next
-                    # one). Greedy-only batches run the plain argmax
-                    # scan; a batch with sampled rows runs the mixed
-                    # kernel, whose on-device key schedule emits the
-                    # SAME tokens as the per-step path (pinned by
-                    # tests) — one sampled co-tenant no longer drags
-                    # the batch onto per-step dispatch.
-                    samplers = {
-                        slot: req
-                        for slot, req in self._active.items()
-                        if req.sampling is not None
-                    }
-                    # Serial path: the host blocks for the whole
-                    # dispatch+force, so the harvest wait IS the call
-                    # (rung 25 attribution; no pipeline slack here).
-                    # With the tracer on the phase is the ring's
-                    # fabric span (ungated): every window stamps,
-                    # sampled request spans hang from them.
-                    with phase("loop/harvest_wait",
-                               args={"w": window,
-                                     "rows": len(self._active),
-                                     "depth": 0}):
-                        if not samplers:
-                            produced = np.asarray(
-                                self._cache.step_window(
-                                    self._params, jnp.asarray(tokens),
-                                    window, active=mask,
-                                ))
-                        else:
-                            produced = np.asarray(self._sampled_window(
-                                tokens, window, mask, samplers
-                            ))
-                    with phase("loop/emit"):
-                        for slot, req in list(self._active.items()):
-                            before = len(req.generated)
-                            self._emit(req, req.next_token)
-                            finished = False
-                            for i in range(window - 1):
-                                t = int(produced[i, slot])
-                                self._emit(req, t)
-                                if t == req.stop_token:
-                                    # Host-side stop truncation: the
-                                    # serial window path touches every
-                                    # token here anyway, so the
-                                    # uncapped kernels carry no
-                                    # device-side stop rows. Nothing
-                                    # is in flight — finish
-                                    # immediately.
-                                    finished = True
-                                    break
-                            self._decode_row_steps += (
-                                len(req.generated) - before)
-                            self._note_emitted_locked(req, before)
-                            if finished:
-                                self._stop_finishes += 1
-                                self._finish_request_locked(slot, req)
-                            else:
-                                req.next_token = int(
-                                    produced[window - 1, slot]
-                                )
-                                self._note_finish_candidate_locked(
-                                    slot, req
-                                )
-                    return "ran"
-                # Per-step harvest wait (serial path, rung 25): the
-                # pick inside _next_tokens is the forcing read.
-                with phase("loop/harvest_wait",
-                           args={"rows": len(self._active)}):
-                    if all(req.sampling is None
-                           for req in self._active.values()):
-                        # All-greedy per-step batch: the fused
-                        # step+argmax program (kvcache.step_tokens) —
-                        # one dispatch and a [B]-int read instead of a
-                        # dispatch, a second argmax dispatch, and a
-                        # [B, V] logits transfer. Token-identical:
-                        # same argmax on the same logits.
-                        picked = np.asarray(self._cache.step_tokens(
-                            self._params, jnp.asarray(tokens),
-                            active=mask
-                        ))
-                        next_tokens = {
-                            slot: int(picked[slot])
-                            for slot in self._active
-                        }
-                    else:
-                        logits = self._cache.step(
-                            self._params, jnp.asarray(tokens),
-                            active=mask
-                        )
-                        next_tokens = self._next_tokens(logits)
-                with phase("loop/emit"):
-                    self._decode_row_steps += len(self._active)
-                    for slot, req in self._active.items():
-                        self._emit_pending_locked(req)
-                        req.next_token = next_tokens[slot]
-                        self._note_finish_candidate_locked(slot, req)
-            except Exception as e:  # poison: fail every waiter loudly
-                # Typed poisoning (runtime/failures.py): an already-
-                # typed failure (e.g. SliceFollowerLost from the op
-                # watchdog) passes through; anything else is wrapped as
-                # PoolPoisoned with the cause chained. Waiters get the
-                # typed error, new submits get _refusal()'s retry-after
-                # hint, and the degraded flag flips for stats/healthz.
-                self._poison_locked(classify_failure(e))
-                return "exit"
-        return "ran"
-
-    # ---- overlapped decode loop ------------------------------------------
-
-    def _loop_once_overlap(self) -> str:
-        """One iteration of the double-buffered decode loop.
+        """One iteration of the double-buffered decode loop ("exit"
+        ends it).
 
         Two alternating shapes. At a NON-OVERLAPPED BOUNDARY
-        (``_inflight is None``) it reconciles exactly like the serial
-        loop — cancel sweep, finish sweep, admissions implicitly via
+        (``_inflight is None``) it reconciles with nothing in flight —
+        cancel sweep, finish sweep, admissions implicitly via
         ``_active``, speculative passes — then DISPATCHES a window
         without harvesting it. With a window IN FLIGHT it first
         enqueues the next window on the device-resident carry (no host
@@ -3933,7 +3667,8 @@ class PagedGenerationServer:
         harvests and processes the previous window's tokens while the
         next one runs. Whenever exactness needs a boundary (a cancel
         arrived, a newcomer admitted, budgets exhausted) it harvests
-        WITHOUT dispatching, so the next iteration reconciles serially.
+        WITHOUT dispatching, so the next iteration reconciles at a
+        boundary.
 
         A speculatively dispatched window can never corrupt state: each
         row's device-side ``steps_left`` cap freezes it at its true
@@ -3960,7 +3695,7 @@ class PagedGenerationServer:
                 # Hard close: abandon the in-flight window unforced
                 # (the device finishes it harmlessly; never block a
                 # close on a potentially dead op stream) and fail the
-                # waiters, as in the serial loop.
+                # waiters.
                 rec, self._inflight = self._inflight, None
                 if rec is not None:
                     for _, req, adv in rec["parts"]:
@@ -4166,9 +3901,9 @@ class PagedGenerationServer:
         if not parts:
             return None
         # The widest remaining budget sets the window (pow2-floored,
-        # same compiled-program set as the serial path): rows with
-        # less budget freeze mid-window on device instead of dragging
-        # every co-tenant down to the tightest budget.
+        # so the compiled-program set stays {1, 2, 4, ..., window}):
+        # rows with less budget freeze mid-window on device instead of
+        # dragging every co-tenant down to the tightest budget.
         w = min(self._window, max(cap for _, _, cap in parts))
         if w > 1:
             w = 1 << (w.bit_length() - 1)
@@ -4198,10 +3933,10 @@ class PagedGenerationServer:
             smask = np.zeros((n,), bool)
             for slot, req in samplers.items():
                 key_data[slot] = req.key_data
-                # Committed position: the serial schedule's
-                # len(generated)+1 with the unharvested advance
-                # folded in, so token t still samples with
-                # fold_in(seed, t) regardless of pipelining.
+                # Committed position: len(generated)+1 with the
+                # unharvested advance folded in, so token t samples
+                # with fold_in(seed, t) (decode.generate's schedule)
+                # regardless of pipelining.
                 base_steps[slot] = (len(req.generated)
                                     + req.inflight + 1)
                 temps[slot] = float(req.sampling[1])
@@ -4305,8 +4040,8 @@ class PagedGenerationServer:
                     # Inline finish: with the pipeline saturated the
                     # loop may never visit a boundary, so a filled
                     # budget must complete here. The cancelled guard
-                    # preserves the serial cancel-beats-finish order —
-                    # the cancel sweep at the forced boundary takes it.
+                    # keeps cancel-beats-finish — the cancel sweep at
+                    # the forced boundary takes it.
                     self._emit_pending_locked(req)
                     self._finish_request_locked(slot, req)
             self._overlap_windows += 1

@@ -11,9 +11,8 @@ chooses.
 
 Expectation going in (recorded so the result reads honestly either
 way): XLA already emits a fused bandwidth-bound loop for this pattern,
-so parity is the likely outcome — but "likely" is not a measurement,
-and the ceiling file needs the number (tools/bench_rmsnorm_fusion.py
-writes it to SWEEP_r04.json).
+so parity is the likely outcome — but "likely" is not a measurement.
+No record of an A/B on today's code exists (ROADMAP D5).
 
 Numerics mirror models/transformer.py ``_rmsnorm`` exactly in forward
 (fp32 mean-square, scale cast to the compute dtype before the

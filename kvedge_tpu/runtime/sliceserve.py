@@ -71,9 +71,7 @@ from kvedge_tpu.models.kvcache import (
     _decode_step_core,
     _gather_pages_impl,
     _paged_decode_window_capped_impl,
-    _paged_decode_window_impl,
     _paged_decode_window_sampled_capped_impl,
-    _paged_decode_window_sampled_impl,
     _paged_prefill_impl,
     _paged_spec_window_impl,
     _paged_spec_window_sampled_impl,
@@ -82,21 +80,22 @@ from kvedge_tpu.models.kvcache import (
 )
 
 # Op codes (header[0]). STOP ends the follower loop. WINDOWP/WSAMPLEP
-# are the pipelined (overlap) window pair: dispatched WITHOUT reading
+# are the decode-window pair: dispatched WITHOUT reading
 # the result, so the leader can broadcast window N+1 while window N is
 # still executing — followers likewise replay the dispatch and never
-# block on a result (they never read tokens at all). New codes append
-# at the end: the numbering is wire protocol.
-(OP_STOP, OP_SYNC, OP_PREFILL, OP_STEP, OP_WINDOW, OP_SPEC,
- OP_WSAMPLE, OP_WINDOWP, OP_WSAMPLEP, OP_SWAPOUT, OP_SWAPIN,
- OP_SPECW, OP_SPECWS, OP_MULTI, OP_COWP) = range(15)
+# block on a result (they never read tokens at all). The numbering is
+# wire protocol within a run, not across versions: leader and followers
+# boot from one image, so a code means what this file says on every
+# process of a slice.
+(OP_STOP, OP_SYNC, OP_PREFILL, OP_STEP, OP_SPEC, OP_WINDOWP,
+ OP_WSAMPLEP, OP_SWAPOUT, OP_SWAPIN, OP_SPECW, OP_SPECWS, OP_MULTI,
+ OP_COWP) = range(13)
 _HEADER_LEN = 4  # [op, a, b, c] — meanings per op below.
 
 # Human names for follower-side replay spans (runtime/tracing.py).
 _OP_NAMES = {
     OP_STOP: "stop", OP_SYNC: "sync", OP_PREFILL: "prefill",
-    OP_STEP: "step", OP_WINDOW: "window", OP_SPEC: "spec",
-    OP_WSAMPLE: "wsample", OP_WINDOWP: "windowp",
+    OP_STEP: "step", OP_SPEC: "spec", OP_WINDOWP: "windowp",
     OP_WSAMPLEP: "wsamplep", OP_SWAPOUT: "swapout",
     OP_SWAPIN: "swapin", OP_SPECW: "specw", OP_SPECWS: "specws",
     OP_MULTI: "multi", OP_COWP: "cowp",
@@ -156,18 +155,9 @@ def _slice_kernels(mesh, cfg, quantized: bool = False):
         _decode_step_core, static_argnames=("cfg",),
         donate_argnums=(1,), out_shardings=(rep, state_sh),
     )
-    window = jax.jit(
-        _paged_decode_window_impl, static_argnames=("cfg", "n_steps"),
-        donate_argnums=(1,), out_shardings=(rep, state_sh),
-    )
     spec = jax.jit(
         _spec_verify_core, static_argnames=("cfg",),
         donate_argnums=(1,), out_shardings=(rep, rep, rep, state_sh),
-    )
-    wsample = jax.jit(
-        _paged_decode_window_sampled_impl,
-        static_argnames=("cfg", "n_steps"), donate_argnums=(1,),
-        out_shardings=(rep, state_sh),
     )
     window_capped = jax.jit(
         _paged_decode_window_capped_impl,
@@ -219,9 +209,9 @@ def _slice_kernels(mesh, cfg, quantized: bool = False):
     cow = jax.jit(
         _cow_pair_core, donate_argnums=(0,), out_shardings=state_sh,
     )
-    return (rep, state_sh, prefill, step, window, spec, wsample,
-            window_capped, wsample_capped, swap_gather, swap_scatter,
-            specw, specws, cow)
+    return (rep, state_sh, prefill, step, spec, window_capped,
+            wsample_capped, swap_gather, swap_scatter, specw, specws,
+            cow)
 
 
 def _cow_pair_core(state, pair):
@@ -263,7 +253,7 @@ class SlicePagedKVCache(PagedKVCache):
         cfg = dataclasses.replace(cfg, paged_attention="gather")
         self.mesh = mesh
         (self._rep, self._state_sh, self._k_prefill, self._k_step,
-         self._k_window, self._k_spec, self._k_wsample,
+         self._k_spec,
          self._k_window_capped, self._k_wsample_capped,
          self._k_swapout, self._k_swapin,
          self._k_specw, self._k_specws,
@@ -662,77 +652,6 @@ class SlicePagedKVCache(PagedKVCache):
             self.cfg, self._global(mask.astype(bool)),
         )
         return self._read(logits)
-
-    def _device_step_tokens(self, params, tokens, active):
-        """Leader: the fused step+argmax seam rides the existing
-        OP_STEP broadcast (a new fused op kind would buy the slice
-        path little — the logits already come back replicated) and
-        picks on the host copy. Token-identical to the base class's
-        on-device argmax: same logits, same argmax tie-breaking
-        (lowest index) in numpy and XLA."""
-        logits = self._device_step(params, tokens, active)
-        return np.argmax(logits, axis=-1).astype(np.int32)
-
-    def _device_window(self, params, tokens, n_steps: int, active):
-        self._check_live()
-        self._flush_ops()
-        tokens = np.asarray(tokens, np.int32)
-        mask = self._active_np(active)
-
-        def op():
-            self._send_header(OP_WINDOW, n_steps)
-            sent, m = self._bcast((tokens, mask))
-            return self._exec_window(params, np.asarray(sent),
-                                     np.asarray(m), n_steps)
-
-        return self._traced_run(("window", n_steps), op)
-
-    def _exec_window(self, params, tokens: np.ndarray, mask: np.ndarray,
-                     n_steps: int):
-        toks, self.state = self._k_window(
-            params, self.state, self._global(tokens.astype(np.int32)),
-            self.cfg, n_steps, self._global(mask.astype(bool)),
-        )
-        return self._read(toks)
-
-    def _device_window_sampled(self, params, tokens, n_steps: int,
-                               active, key_data, base_steps, temps,
-                               top_ps, sampled_mask):
-        self._check_live()
-        self._flush_ops()
-        tokens = np.asarray(tokens, np.int32)
-        key_data = np.asarray(key_data, np.uint32)
-        mask = self._active_np(active)
-
-        def op():
-            self._send_header(OP_WSAMPLE, n_steps, key_data.shape[1])
-            payload = self._bcast((
-                tokens, mask, key_data,
-                np.asarray(base_steps, np.int32),
-                np.asarray(temps, np.float32),
-                np.asarray(top_ps, np.float32),
-                np.asarray(sampled_mask, bool),
-            ))
-            return self._exec_window_sampled(
-                params, *(np.asarray(x) for x in payload),
-                n_steps=n_steps,
-            )
-
-        return self._traced_run(("wsample", n_steps), op)
-
-    def _exec_window_sampled(self, params, tokens, mask, key_data,
-                             base_steps, temps, top_ps, smask, *,
-                             n_steps: int):
-        toks, self.state = self._k_wsample(
-            params, self.state, self._global(tokens.astype(np.int32)),
-            self.cfg, n_steps, self._global(mask.astype(bool)),
-            self._global(key_data.astype(np.uint32)),
-            self._global(base_steps.astype(np.int32)),
-            self._global(temps.astype(np.float32)),
-            self._global(top_ps.astype(np.float32)),
-            self._global(smask.astype(bool)),
-        )
-        return self._read(toks)
 
     # ---- pipelined (overlap) window pair --------------------------------
 
@@ -1209,29 +1128,6 @@ class SlicePagedKVCache(PagedKVCache):
                 np.zeros((self.slots,), bool),
             ))
             self._exec_step(params, np.asarray(tokens), np.asarray(mask))
-        elif op == OP_WINDOW:
-            tokens, mask = self._bcast((
-                np.zeros((self.slots,), np.int32),
-                np.zeros((self.slots,), bool),
-            ))
-            self._exec_window(params, np.asarray(tokens),
-                              np.asarray(mask), a)
-        elif op == OP_WSAMPLE:
-            # a = n_steps, b = key-data width (impl-dependent: 2 for
-            # threefry) — the follower's zero templates must match the
-            # leader's broadcast shapes exactly.
-            payload = self._bcast((
-                np.zeros((self.slots,), np.int32),
-                np.zeros((self.slots,), bool),
-                np.zeros((self.slots, b), np.uint32),
-                np.zeros((self.slots,), np.int32),
-                np.zeros((self.slots,), np.float32),
-                np.zeros((self.slots,), np.float32),
-                np.zeros((self.slots,), bool),
-            ))
-            self._exec_window_sampled(
-                params, *(np.asarray(x) for x in payload), n_steps=a
-            )
         elif op == OP_SPEC:
             tokens, mask, smask = self._bcast((
                 np.zeros((self.slots, a + 1), np.int32),

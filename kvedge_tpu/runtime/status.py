@@ -151,12 +151,8 @@ _SERVE_METRIC_FIELDS = (
     ("window", "serve_window", "gauge",
      "device decode window cap in steps (paged backend, "
      "serving_window)"),
-    # Overlapped window pipeline (serving_overlap): whether the
-    # double-buffered decode loop is active, how many windows it has
+    # The double-buffered decode loop: how many windows it has
     # harvested, and whether one is in flight right now.
-    ("overlap", "serve_overlap", "gauge",
-     "1 if the overlapped (double-buffered) window pipeline is "
-     "enabled (paged backend, serving_overlap)"),
     ("overlap_windows_total", "serve_overlap_windows_total", "counter",
      "decode windows harvested by the overlapped pipeline"),
     ("overlap_inflight_depth", "serve_overlap_inflight_depth", "gauge",
@@ -538,8 +534,7 @@ def render_metrics(snapshot: dict) -> str:
             f"# HELP {name} decode rounds that fell off the windowed "
             "spec path, by cause (sampled = mixed batch with "
             "serving_spec_sampled_window off; spec_off = speculation "
-            "disabled mid-flight; overlap_off = serial loop with "
-            "spec windows configured)")
+            "disabled mid-flight)")
         lines.append(f"# TYPE {name} counter")
         for cause in sorted(fallbacks):
             lines.append(

@@ -1480,13 +1480,10 @@ def _build_serve(cfg, base, tcfg, params, restored_step, *, cache=None,
                 min_bucket=cfg.serving_min_bucket,
                 page_low_watermark=cfg.serving_page_low_watermark,
                 page_high_watermark=cfg.serving_page_high_watermark,
-                # Overlapped window pipeline ([payload]
-                # serving_overlap). Multi-host note: revive() after a
-                # recovery restarts _loop, which re-selects the
-                # pipelined body — the slice cache's reform() dropped
-                # its device carry, so the revived pipeline re-enters
-                # cleanly from host tokens on every recovery cycle.
-                overlap=cfg.serving_overlap,
+                # Multi-host note: revive() after a recovery restarts
+                # _loop — the slice cache's reform() dropped its device
+                # carry, so the revived pipeline re-enters cleanly from
+                # host tokens on every recovery cycle.
                 tracer=tracer,
                 # Lock-discipline assertions ([payload]
                 # serving_debug_locks, SERVING.md rung 19): runtime

@@ -152,19 +152,6 @@ class FaultyCache(PagedKVCache):
         self._seam("step")
         return super()._device_step(params, tokens, active)
 
-    def _device_window(self, params, tokens, n_steps: int, active):
-        self._seam(f"window[{n_steps}]")
-        return super()._device_window(params, tokens, n_steps, active)
-
-    def _device_window_sampled(self, params, tokens, n_steps: int,
-                               active, key_data, base_steps, temps,
-                               top_ps, sampled_mask):
-        self._seam(f"wsample[{n_steps}]")
-        return super()._device_window_sampled(
-            params, tokens, n_steps, active, key_data, base_steps,
-            temps, top_ps, sampled_mask,
-        )
-
     def _device_spec(self, params, tokens, active, spec_mask):
         self._seam("spec")
         return super()._device_spec(params, tokens, active, spec_mask)
@@ -182,9 +169,9 @@ class FaultyCache(PagedKVCache):
         self._seam("swapin")
         return super()._device_swapin(ids, arrays)
 
-    # Overlapped-pipeline seams (models/serving.py _loop_once_overlap):
-    # dispatch and harvest are SEPARATE failure boundaries now — a
-    # dispatch can die while an earlier window is still in flight, and
+    # Decode-window seams (models/serving.py _loop_once): dispatch
+    # and harvest are SEPARATE failure boundaries — a dispatch can
+    # die while an earlier window is still in flight, and
     # a harvest can die on a window that was dispatched healthy. Both
     # must drain cleanly into the poison path.
     def _device_window_dispatch(self, params, tokens, n_steps: int,

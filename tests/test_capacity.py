@@ -208,12 +208,13 @@ def test_bucket_steps_down_when_load_drains(params):
 # ---- bit-identity across bucket transitions ------------------------------
 
 
-@pytest.mark.parametrize("overlap", ["off", "on"])
-def test_bucketed_tokens_match_pinned_path(params, overlap):
+@pytest.mark.parametrize("window", [1, 64], ids=["w1", "w64"])
+def test_bucketed_tokens_match_pinned_path(params, window):
     """The same request set through a bucketed server (stepping 1->2->4
     under load) and a slots-pinned server produces IDENTICAL tokens —
     and both match contiguous generate. Carries migrate or drop at
-    bucket steps without moving a single token."""
+    bucket steps without moving a single token, at one-step windows
+    (a trip a token) and at the default."""
     requests = [
         ([5, 9, 2], 8),
         ([1, 1, 4, 3, 7, 7], 4),
@@ -224,7 +225,7 @@ def test_bucketed_tokens_match_pinned_path(params, overlap):
     for min_bucket in (0, 1):
         server = PagedGenerationServer(
             params, CFG, slots=4, pages=32, page_size=4,
-            min_bucket=min_bucket, overlap=overlap, prefix_cache=False,
+            min_bucket=min_bucket, window=window, prefix_cache=False,
         )
         try:
             outs.append(run_concurrent(server, requests))
@@ -249,7 +250,7 @@ def test_bucketed_spec_window_overlap_bit_identical(params):
     ]
     server = PagedGenerationServer(
         params, CFG, slots=4, pages=32, page_size=4, min_bucket=1,
-        overlap="on", speculative=2, spec_window=2, prefix_cache=False,
+        speculative=2, spec_window=2, prefix_cache=False,
     )
     try:
         first = server.submit(requests[0][0], requests[0][1])
@@ -565,27 +566,18 @@ def _wait_degraded(server, timeout_s=30.0):
 
 
 def _arm_kill(server, ready, message):
-    """Raise at the first decode seam (serial window or overlapped
-    harvest, whichever this server shape uses) where ``ready()`` holds."""
+    """Raise at the first window harvest where ``ready()`` holds."""
     cache = server._cache
-    real_h, real_w = cache.harvest_window, cache._device_window
+    real_h = cache.harvest_window
     state = {"arm": True}
 
-    def fire():
+    def dying_h(handle):
         if state["arm"] and ready():
             state["arm"] = False
             raise RuntimeError(message)
-
-    def dying_h(handle):
-        fire()
         return real_h(handle)
 
-    def dying_w(*args):
-        fire()
-        return real_w(*args)
-
     cache.harvest_window = dying_h
-    cache._device_window = dying_w
 
 
 def test_poison_with_swapped_victim_revives_all(params):
@@ -665,11 +657,15 @@ def test_checkpointed_spec_overlap_revive_bit_identical(params):
     stream completes bit-identical with no replayed token."""
     server = PagedGenerationServer(
         params, CFG, slots=4, pages=32, page_size=4, min_bucket=1,
-        overlap="on", speculative=2, spec_window=2, checkpoint_every=1,
+        speculative=2, spec_window=2, checkpoint_every=1,
         prefix_cache=False,
     )
-    prompt = [5, 9, 2]
-    want = reference(params, prompt, 10)
+    # Long enough that the second checkpoint is reached whatever the
+    # drafts accept: two spec windows of two passes emit at most
+    # 2 * 2 * (2 + 1) = 12 tokens, and the boundary after them
+    # checkpoints a request that is still running.
+    prompt, n_new = [5, 9, 2], 30
+    want = reference(params, prompt, n_new)
     cache = server._cache
     real = cache.swapout_pages
     calls = [0]
@@ -683,7 +679,7 @@ def test_checkpointed_spec_overlap_revive_bit_identical(params):
     cache.swapout_pages = dying
     dying_thread = server._thread
     try:
-        got, done, errs = _stream_in_background(server, prompt, 10)
+        got, done, errs = _stream_in_background(server, prompt, n_new)
         _wait_degraded(server)
         cache.swapout_pages = real
         dying_thread.join(timeout=30)

@@ -11,8 +11,9 @@ typed failures only.
 Two legs share one harness (``testing/chaos.py``):
 
 * a short deterministic subset — pinned server shapes, seeds chosen to
-  exercise revive-with-restore on the serial loop, the overlapped
-  pipeline, and windowed speculation — fast enough for tier-1;
+  exercise revive-with-restore at one-step windows, at longer windows,
+  under legacy speculative passes and under windowed speculation —
+  fast enough for tier-1;
 * the seeded soak — ``@slow``, 24 campaigns whose whole decision
   stream (server shape, prompts, consumer mix, fault plans) derives
   from the campaign seed.
@@ -22,6 +23,8 @@ seeded page leak (a FaultyCache subclass stealing a page at the admit
 seam) must poison the pool with the typed, non-retryable
 ``PageAccountingError`` at the next quiescent boundary.
 """
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -45,15 +48,24 @@ CFG = TransformerConfig(
 )
 
 # Pinned server shapes for the deterministic tier-1 subset: one per
-# decode body the durability machinery hooks into.
-SERIAL = dict(checkpoint_every=1, overlap="off", window=2,
-              speculative=0, spec_window=0)
-OVERLAP = dict(checkpoint_every=1, overlap="on", window=2,
+# shape of decode trip the durability machinery hooks into. With each,
+# the decode-loop seam indices (prefill seams not counted) at which a
+# raise is SURE to poison with a journaled request still in flight,
+# whatever the interleaving: the first boundary checkpoints
+# (checkpoint_every=1) and a request of n_new=6 outlives the seams up
+# to 3; under SPEC (checkpoint_every=2, up to three tokens a pass)
+# only seam 2 comes after the first checkpoint and before a request
+# can finish.
+ONESTEP = dict(checkpoint_every=1, window=1,
                speculative=0, spec_window=0)
-SPEC = dict(checkpoint_every=2, overlap="off", window=2,
+OVERLAP = dict(checkpoint_every=1, window=2,
+               speculative=0, spec_window=0)
+SPEC = dict(checkpoint_every=2, window=2,
             speculative=2, spec_window=0)
-SPECW = dict(checkpoint_every=1, overlap="off", window=2,
+SPECW = dict(checkpoint_every=1, window=2,
              speculative=2, spec_window=2)
+SURE = (1, 3)
+SURE_SPEC = (2, 2)
 
 ROUNDS = 2
 PER_ROUND = 3
@@ -86,21 +98,46 @@ def oracle(params):
 # ---- deterministic subset (tier-1): revive-with-restore per shape --------
 
 
+def _loop_seams_only(round_i, server, cache, plan):
+    """The plan counts only the decode loop's seams: a fault on a
+    submitter's prefill fails that one request and poisons nothing,
+    and where the prefills fall among the loop's seams is thread
+    interleaving. Every seam that is left poisons the pool."""
+    count = plan.at_seam
+    plan.at_seam = lambda label: (
+        None if label.startswith("prefill") else count(label))
+
+
+def _assert_armed(res, sure):
+    """The campaign's decisions (a pure function of its seed) armed a
+    raise at a seam the loop is sure to reach. A seed that stops doing
+    so — the decision stream shifted — is re-pinned, not retried."""
+    lo, hi = sure
+    plans = [re.search(r"kind=(\w+) fire_at=(\d+)", ln)
+             for ln in res.trace if ln.startswith("[plan]")]
+    assert any(m[1] == "raise" and lo <= int(m[2]) <= hi
+               for m in plans), (
+        f"seed {res.seed} arms no raise at loop seams {lo}..{hi}: "
+        f"re-pin it ({[m[0] for m in plans]})")
+
+
 @pytest.mark.parametrize(
-    "seed,config",
-    [(11, SERIAL), (17, SERIAL), (3, OVERLAP), (17, SPEC)],
-    ids=["serial-11", "serial-17", "overlap-3", "spec-17"],
+    "seed,config,sure",
+    [(17, ONESTEP, SURE), (19, ONESTEP, SURE), (2, OVERLAP, SURE),
+     (5, SPEC, SURE_SPEC), (9, SPECW, SURE)],
+    ids=["w1-17", "w1-19", "overlap-2", "spec-5", "specw-9"],
 )
-def test_deterministic_campaign(params, oracle, seed, config):
+def test_deterministic_campaign(params, oracle, seed, config, sure):
     """Seeds pinned to poison at least once per campaign: the run must
     revive, restore journaled requests, and finish every survivor
     bit-identical (the harness raises InvariantViolation otherwise)."""
     res = run_chaos_campaign(
         params, CFG, seed=seed, rounds=ROUNDS,
         requests_per_round=PER_ROUND, n_new=6, config=config,
-        oracle=oracle,
+        oracle=oracle, wound=_loop_seams_only,
     )
     assert res.completed + res.failed == ROUNDS * PER_ROUND
+    _assert_armed(res, sure)
     # These seeds are chosen BECAUSE they poison mid-flight with
     # journaled work to bring back — a campaign that stops exercising
     # the restore path is a regression even if nothing else breaks.
@@ -119,10 +156,10 @@ def test_campaign_decisions_replay_from_seed(params, oracle):
     firing seam."""
     a = run_chaos_campaign(params, CFG, seed=9, rounds=ROUNDS,
                            requests_per_round=PER_ROUND, n_new=6,
-                           config=SERIAL, oracle=oracle)
+                           config=ONESTEP, oracle=oracle)
     b = run_chaos_campaign(params, CFG, seed=9, rounds=ROUNDS,
                            requests_per_round=PER_ROUND, n_new=6,
-                           config=SERIAL, oracle=oracle)
+                           config=ONESTEP, oracle=oracle)
     assert a.config == b.config
     # Decision lines (plans, submissions) are positionally identical;
     # runtime lines (revives, outcomes) may interleave differently.
@@ -141,8 +178,8 @@ def test_campaign_decisions_replay_from_seed(params, oracle):
 @pytest.mark.prefix
 @pytest.mark.parametrize(
     "seed,config",
-    [(1, SERIAL), (5, SERIAL), (5, OVERLAP)],
-    ids=["serial-1", "serial-5", "overlap-5"],
+    [(13, ONESTEP), (15, ONESTEP), (32, OVERLAP)],
+    ids=["w1-13", "w1-15", "overlap-32"],
 )
 def test_prefix_mix_campaign(params, oracle, seed, config):
     """Chaos with the prefix cache ON and prompts sharing page-sized
@@ -155,9 +192,10 @@ def test_prefix_mix_campaign(params, oracle, seed, config):
     res = run_chaos_campaign(
         params, CFG, seed=seed, rounds=ROUNDS,
         requests_per_round=PER_ROUND, n_new=6, config=config,
-        oracle=oracle, prefix_mix=True,
+        oracle=oracle, prefix_mix=True, wound=_loop_seams_only,
     )
     assert res.completed + res.failed == ROUNDS * PER_ROUND
+    _assert_armed(res, (0, SURE[1]))
     assert res.revives >= 1, res.fired
 
 

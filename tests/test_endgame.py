@@ -111,7 +111,7 @@ def test_stop_token_truncates_and_counts(params):
     assert len(want_cut) < len(want_full)  # the stop really fires
 
     server = PagedGenerationServer(params, CFG, slots=2, pages=32,
-                                   page_size=4, window=4, overlap="on")
+                                   page_size=4, window=4)
     try:
         got = server.submit(prompt, 16, stop_token=stop)
         assert got == want_cut
@@ -136,7 +136,7 @@ def test_stop_mid_pipeline_defers_without_perturbing_cotenant(params):
     want_go = reference(params, p_go, 20)
 
     server = PagedGenerationServer(params, CFG, slots=2, pages=32,
-                                   page_size=4, window=4, overlap="on")
+                                   page_size=4, window=4)
     try:
         results: dict[str, list[int]] = {}
 
@@ -249,7 +249,7 @@ def test_poison_revive_restores_sampled_stop_request(params):
 
     server = PagedGenerationServer(
         params, CFG, slots=2, pages=24, page_size=4, window=2,
-        overlap="on", checkpoint_every=1, prefix_cache=False,
+        checkpoint_every=1, prefix_cache=False,
     )
     cache = server._cache
     real_h = cache.harvest_window

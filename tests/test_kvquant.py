@@ -76,8 +76,8 @@ def test_int8_cache_decode_near_bf16():
             logits = c.step(params, jnp.asarray(toks))
             toks = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
             out.append(toks.copy())
-        prod = np.asarray(c.step_window(params, jnp.asarray(toks),
-                                        n - n // 2))
+        w = n - n // 2
+        prod = c.harvest_window(c.dispatch_window(params, toks, w))[:w]
         for row in prod:
             out.append(np.asarray(row, np.int32))
         return np.stack(out)
@@ -140,14 +140,17 @@ def test_int8_prefix_persistence_round_trip(params, tmp_path):
     try:
         base = [7, 3, 9, 1, 5, 5, 2, 8]
         first = server.submit(base + [4, 6], n_new=6)
-        assert server.dump_prefix_cache(path, "int8-fp") == 2
+        # A finished request registers its whole committed stream
+        # (prompt and generated tokens, the last one has no K/V yet):
+        # 10 + 6 - 1 = 15 tokens, three full pages of 4.
+        assert server.dump_prefix_cache(path, "int8-fp") == 3
     finally:
         server.close()
 
     fresh = PagedGenerationServer(params, CFG, slots=2, pages=24,
                                   page_size=4, kv_dtype="int8")
     try:
-        assert fresh.load_prefix_cache(path, "int8-fp") == 2
+        assert fresh.load_prefix_cache(path, "int8-fp") == 3
         again = fresh.submit(base + [4, 6], n_new=6)
         assert fresh.stats()["prefix_hits"] == 1
         assert again == first
@@ -172,7 +175,8 @@ def test_int8_slice_cache_matches_local(params):
             logits = cache.prefill(params, s, jnp.asarray(pr, jnp.int32))
             toks[s] = int(np.argmax(np.asarray(logits)))
         out = [toks.copy()]
-        prod = np.asarray(cache.step_window(params, jnp.asarray(toks), n))
+        prod = cache.harvest_window(
+            cache.dispatch_window(params, toks, n))[:n]
         for row in prod:
             out.append(np.asarray(row, np.int32))
         return np.stack(out)
@@ -185,11 +189,21 @@ def test_int8_slice_cache_matches_local(params):
 
 
 def test_kv_bytes_metric_halves():
-    from bench import kv_cache_bytes_per_token
+    """What a cached token costs in the pool itself: the bytes of the
+    pool and scale arrays over ``pages * page_size`` positions."""
+    pages, page_size = 16, 4
 
-    gqa = dataclasses.replace(CFG)
-    bf16 = kv_cache_bytes_per_token(gqa)
-    i8 = kv_cache_bytes_per_token(gqa, "int8")
+    def bytes_per_token(kv_dtype):
+        state = PagedKVCache(CFG, slots=2, pages=pages,
+                             page_size=page_size, kv_dtype=kv_dtype).state
+        arrays = [state.pool_k, state.pool_v, state.scale_k,
+                  state.scale_v]
+        held = sum(a.nbytes for a in arrays if a is not None)
+        assert held % (pages * page_size) == 0
+        return held // (pages * page_size)
+
+    bf16 = bytes_per_token("")
+    i8 = bytes_per_token("int8")
     assert bf16 == CFG.n_layers * 2 * CFG.kv_heads * CFG.d_head * 2
     assert i8 == CFG.n_layers * 2 * CFG.kv_heads * (CFG.d_head + 4)
     assert i8 < 0.8 * bf16  # d_head 8 here; ~0.53x at d_head 64
@@ -218,7 +232,7 @@ def test_int8_kernel_path_matches_int8_gather(params):
         logits = np.asarray(c.step(params, jnp.asarray(toks)),
                             np.float32)
         nxt = jnp.asarray(np.argmax(logits, -1), jnp.int32)
-        window = np.asarray(c.step_window(params, nxt, 6))
+        window = c.harvest_window(c.dispatch_window(params, nxt, 6))[:6]
         return logits, window
 
     lk, wk = step_logits("kernel")

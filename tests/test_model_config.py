@@ -2,8 +2,8 @@
 product path.
 
 Round 3's verdict: the entire train -> checkpoint -> serve loop could
-only ever run the hard-coded probe shape, while the flagship model the
-bench numbers describe lived exclusively in bench.py. These tests pin
+only ever run the hard-coded probe shape, while the flagship model
+lived exclusively in a benchmark script. These tests pin
 the fix: `derive_model_config` resolves [model] (preset + overrides)
 against the mesh — preset-derived values adapt, explicitly-set values
 are authoritative and refuse impossible meshes loudly — and the
@@ -64,7 +64,7 @@ def test_flagship_preset_resolves():
 
 def test_flagship_is_the_bench_model():
     """One definition: the [model] preset must be exactly the shape
-    __graft_entry__/bench.py report numbers for."""
+    __graft_entry__ reports numbers for."""
     from __graft_entry__ import FLAGSHIP
 
     tcfg, _ = derive_model_config(

@@ -1,12 +1,14 @@
 """Overlapped-window decode pipeline (SERVING.md rung 16) exactness.
 
-The pipelined loop dispatches window N+1 on a device-resident carry
+The decode loop dispatches window N+1 on a device-resident carry
 BEFORE window N's tokens are read back. The contract is that this is a
 pure latency optimization: greedy and sampled token streams are
-BIT-IDENTICAL to the serial windowed path (``serving_overlap = off``),
-under chunked prefill, mid-window cancellation, and mid-overlap pool
+BIT-IDENTICAL to ``decode.generate`` — at ``window = 1`` (a program of
+its own that harvests one token a trip) and at longer windows, under
+chunked prefill, mid-window cancellation, and mid-overlap pool
 poisoning — where recovery must drain the in-flight window before the
-pool reforms. All fixed-seed and fast: these run in the tier-1 gate.
+pool reforms; and a window is n calls of the one-step program. All
+fixed-seed and fast: these run in the tier-1 gate.
 """
 
 import jax
@@ -41,25 +43,37 @@ def reference(params, prompt, n_new):
     return [int(t) for t in np.asarray(out)[0]]
 
 
-def _both_modes(params, fn, **server_kw):
-    """Run ``fn(server)`` under serial and pipelined loops; return both
-    results. Any divergence between the pair IS the bug this file
-    exists to catch."""
-    out = []
-    for overlap in ("off", "on"):
-        server = PagedGenerationServer(params, CFG, overlap=overlap,
-                                       **server_kw)
-        try:
-            out.append(fn(server))
-        finally:
-            server.close()
-    return out
+def _served(params, fn, **server_kw):
+    """``fn(server)`` on a fresh server, closed afterwards."""
+    server = PagedGenerationServer(params, CFG, **server_kw)
+    try:
+        return fn(server)
+    finally:
+        server.close()
 
 
-# ---- bit-identity: pipelined == serial == contiguous ---------------------
+def _single_steps(cache, params, pend, n, active=None):
+    """The reference a window is held to: ``n`` calls of the one-step
+    logits program, the greedy pick fed back by the host."""
+    toks = jnp.asarray(pend, jnp.int32)
+    rows = []
+    for _ in range(n):
+        logits = cache.step(params, toks, active=active)
+        toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        rows.append(np.asarray(toks))
+    return np.stack(rows)
 
 
-def test_greedy_pipelined_matches_serial_and_generate(params):
+# window = 1 is a compiled program of its own and harvests one token a
+# trip; the product's default is 64.
+WINDOWS = pytest.mark.parametrize("window", [1, 64], ids=["w1", "w64"])
+
+
+# ---- bit-identity: the loop == contiguous generate -----------------------
+
+
+@WINDOWS
+def test_greedy_pipelined_matches_generate(params, window):
     requests = [
         ([5, 9, 2], 8),
         ([1, 1, 4, 3, 7, 7], 4),
@@ -90,17 +104,17 @@ def test_greedy_pipelined_matches_serial_and_generate(params):
         assert not errors, errors
         return results
 
-    serial, pipelined = _both_modes(params, run, slots=3, pages=24)
-    assert serial == pipelined
+    pipelined = _served(params, run, slots=3, pages=24, window=window)
     for i, (prompt, n_new) in enumerate(requests):
         assert pipelined[i] == reference(params, prompt, n_new), (
             f"request {i} diverged from contiguous generate"
         )
 
 
-def test_sampled_pipelined_matches_serial(params):
+def test_sampled_pipelined_matches_generate(params):
     """The sampled key schedule fold_in(seed, base+i) is positional, so
-    re-windowing under the pipeline must not move a single sample."""
+    windowing under the pipeline must not move a single sample: the
+    server's sampled stream is contiguous generate's."""
     key = jax.random.fold_in(jax.random.PRNGKey(3), 0)
     sampling = (key, jnp.float32(0.8), jnp.float32(0.9))
 
@@ -110,22 +124,27 @@ def test_sampled_pipelined_matches_serial(params):
                                 sampling=sampling)
         return greedy, sampled
 
-    serial, pipelined = _both_modes(params, run, slots=2, pages=16)
-    assert serial == pipelined
-    assert serial[0] == reference(params, [5, 9, 2, 7], 9)
-    assert len(serial[1]) == 4 + 24  # prompt + full sampled budget
+    greedy, sampled = _served(params, run, slots=2, pages=16)
+    assert greedy == reference(params, [5, 9, 2, 7], 9)
+    want = generate(
+        params, jnp.asarray([[1, 2, 3, 4]], jnp.int32), CFG, n_new=24,
+        sampling=(key[None], jnp.float32(0.8), jnp.float32(0.9)),
+        sampled=True,
+    )
+    assert sampled == [int(t) for t in np.asarray(want)[0]]
 
 
-def test_chunked_prefill_pipelined_matches_serial(params):
+@WINDOWS
+def test_chunked_prefill_pipelined_matches_generate(params, window):
     prompt = list(np.asarray(jax.random.randint(
         jax.random.PRNGKey(2), (11,), 0, 128)).tolist())
 
     def run(server):
         return server.submit(prompt, n_new=10)
 
-    serial, pipelined = _both_modes(params, run, slots=2, pages=16,
-                                    prefill_chunk=3)
-    assert serial == pipelined == reference(params, prompt, 10)
+    served = _served(params, run, slots=2, pages=16, prefill_chunk=3,
+                     window=window)
+    assert served == reference(params, prompt, 10)
 
 
 def test_mid_window_cancellation_under_overlap(params):
@@ -134,8 +153,7 @@ def test_mid_window_cancellation_under_overlap(params):
     capacity decodes unperturbed."""
     import time
 
-    server = PagedGenerationServer(params, CFG, slots=1, pages=8,
-                                   overlap="on")
+    server = PagedGenerationServer(params, CFG, slots=1, pages=8)
     try:
         src = server.submit_stream([1, 2, 3], n_new=60)
         next(src)  # windows (plural, pipelined) are in flight now
@@ -160,7 +178,7 @@ def test_mid_window_cancellation_under_overlap(params):
 def test_capped_window_freezes_finished_rows(params):
     """dispatch_window with per-slot step caps: a row past its cap
     re-emits its last token, stops advancing its length, and writes no
-    KV — the live prefix is bit-identical to an uncapped window."""
+    KV — the live prefix is bit-identical to n single steps."""
     prompts = {0: [5, 9, 2], 2: [7, 7, 7, 7, 7]}  # slot 1 inactive
 
     def fresh():
@@ -175,7 +193,7 @@ def test_capped_window_freezes_finished_rows(params):
 
     n = 7
     cache_u, pend = fresh()
-    full = np.asarray(cache_u.step_window(params, jnp.asarray(pend), n))
+    full = _single_steps(cache_u, params, pend, n)
 
     cache_c, pend = fresh()
     caps = np.array([3, 0, 7], np.int32)
@@ -187,7 +205,7 @@ def test_capped_window_freezes_finished_rows(params):
     # The harvest block is [n_steps + 2, slots]: the produced tokens
     # plus the packed [fin, stop_at] finish-bookkeeping rows (rung 23).
     assert capped.shape[0] == n + 2
-    # Live prefixes match the uncapped program exactly.
+    # Live prefixes match the one-step program exactly.
     assert capped[:3, 0].tolist() == full[:3, 0].tolist()
     assert capped[:n, 2].tolist() == full[:, 2].tolist()
     # Past its cap the frozen row re-emits its last live token.
@@ -203,10 +221,10 @@ def test_capped_window_freezes_finished_rows(params):
     assert cache_c._host_lengths[1] == 0
 
 
-def test_pipeline_carry_matches_serial_window(params):
+def test_pipeline_carry_matches_single_steps(params):
     """Two pipelined windows — the second dispatched on the device
-    carry BEFORE the first is harvested — equal one serial window of
-    the combined length."""
+    carry BEFORE the first is harvested — equal as many single steps
+    as their combined length."""
     prompt = [3, 1, 4, 1, 5]
 
     def fresh():
@@ -219,8 +237,7 @@ def test_pipeline_carry_matches_serial_window(params):
 
     active = np.array([True, False])
     cache_s, pend = fresh()
-    serial = np.asarray(cache_s.step_window(
-        params, jnp.asarray(pend), 8, active=active))
+    stepped = _single_steps(cache_s, params, pend, 8, active=active)
 
     cache_p, pend = fresh()
     h1 = cache_p.dispatch_window(params, jnp.asarray(pend), 4,
@@ -232,7 +249,7 @@ def test_pipeline_carry_matches_serial_window(params):
     got = np.concatenate([np.asarray(cache_p.harvest_window(h1))[:4],
                           np.asarray(cache_p.harvest_window(h2))[:4]])
     cache_p.drop_carry()
-    assert got[:, 0].tolist() == serial[:, 0].tolist()
+    assert got[:, 0].tolist() == stepped[:, 0].tolist()
     assert cache_p._host_lengths == cache_s._host_lengths
 
 
@@ -251,8 +268,7 @@ def test_poison_mid_overlap_drains_inflight_then_revives(params):
     drain the in-flight window (bookkeeping AND the device handle)
     before the pool poisons — and revive() restarts the pipeline from
     host tokens (carry dropped), serving bit-identical afterwards."""
-    server = PagedGenerationServer(params, CFG, slots=2, pages=24,
-                                   overlap="on")
+    server = PagedGenerationServer(params, CFG, slots=2, pages=24)
     prompt = [3, 1, 4, 1, 5]
     try:
         assert server.submit(prompt, n_new=4) == reference(
@@ -292,12 +308,10 @@ def test_poison_mid_overlap_drains_inflight_then_revives(params):
 
 
 def test_overlap_stats_and_histograms(params):
-    server = PagedGenerationServer(params, CFG, slots=2, pages=16,
-                                   overlap="on")
+    server = PagedGenerationServer(params, CFG, slots=2, pages=16)
     try:
         server.submit([5, 9, 2], n_new=8)
         stats = server.stats()
-        assert stats["overlap"] == 1
         assert stats["overlap_windows_total"] >= 1
         assert stats["overlap_inflight_depth"] in (0, 1)
         for key in ("window_dispatch_harvest_ms", "window_host_ms",
@@ -308,20 +322,3 @@ def test_overlap_stats_and_histograms(params):
             assert hist["sum"] >= 0.0
     finally:
         server.close()
-
-
-def test_overlap_off_reports_serial(params):
-    server = PagedGenerationServer(params, CFG, slots=2, pages=16,
-                                   overlap="off")
-    try:
-        server.submit([5, 9, 2], n_new=4)
-        stats = server.stats()
-        assert stats["overlap"] == 0
-        assert stats["overlap_windows_total"] == 0
-    finally:
-        server.close()
-
-
-def test_overlap_knob_validates():
-    with pytest.raises(ValueError):
-        PagedGenerationServer({}, CFG, overlap="sometimes")

@@ -40,12 +40,17 @@ CHUNK = 4
 # (prompt, n_new): distinct first tokens, so no prompt shares a prefix.
 REQUESTS = [([5, 9, 2, 7, 1, 3, 4, 6, 8], 9), ([11, 12, 13, 14, 15], 12),
             ([21, 22, 23, 24, 25, 26, 27], 1), ([31, 32, 33], 10)]
+# The shapes a trip of the one decode loop takes: greedy windows; a
+# batch with a sampled row (``dispatch_window_sampled``); one-step
+# windows (a program of its own, a harvest a token); legacy
+# speculative passes at the loop's boundaries; device-resident
+# speculative windows.
 LOOPS = {
-    "overlap": {"overlap": "on"},
-    "serial": {"overlap": "off"},
-    "serial-steps": {"overlap": "off", "window": 1},
-    "spec": {"overlap": "off", "speculative": 2},
-    "spec-window": {"overlap": "on", "speculative": 2, "spec_window": 2},
+    "overlap": {},
+    "sampled": {},
+    "one-step": {"window": 1},
+    "spec": {"speculative": 2},
+    "spec-window": {"speculative": 2, "spec_window": 2},
 }
 
 
@@ -60,12 +65,18 @@ def _server(params, **kw):
                                  page_size=PAGE, prefill_chunk=CHUNK, **kw)
 
 
-def _serve(server, requests=REQUESTS):
-    """The fixed set, all at once; returns the generated tokens."""
+def _serve(server, requests=REQUESTS, loop=""):
+    """The fixed set, all at once; returns the generated tokens. Under
+    the ``sampled`` shape the second request (the longest) samples."""
     out = [None] * len(requests)
+    sampling = (jax.random.fold_in(jax.random.PRNGKey(3), 0),
+                jnp.float32(0.8), jnp.float32(0.9))
 
     def one(i, prompt, n_new):
-        out[i] = server.submit(prompt, n_new)[len(prompt):]
+        out[i] = server.submit(
+            prompt, n_new,
+            sampling=sampling if loop == "sampled" and i == 1 else None,
+        )[len(prompt):]
 
     threads = [threading.Thread(target=one, args=(i, p, n))
                for i, (p, n) in enumerate(requests)]
@@ -151,7 +162,7 @@ def test_between_two_snapshots_the_loops_phases_gain_the_time_between(
 def test_the_loops_phases_add_up_to_the_loop_threads_time(params, loop):
     server = _server(params, **LOOPS[loop])
     try:
-        _serve(server)
+        _serve(server, loop=loop)
     finally:
         server.close(drain=True)
     stats = server.stats()
@@ -195,8 +206,6 @@ def _count_steps_at_the_cache(server):
     wrap("dispatch_window", lambda a, kw: a[2])
     wrap("dispatch_window_sampled", lambda a, kw: a[2])
     wrap("dispatch_spec_window", lambda a, kw: a[2])
-    wrap("step_window", lambda a, kw: a[2])
-    wrap("step_tokens", lambda a, kw: 1)
     wrap("step", lambda a, kw: 1)
     wrap("step_spec", lambda a, kw: 1)
     return seen
@@ -208,7 +217,7 @@ def test_count_identities_after_a_fixed_set_of_requests(params, loop):
     seen = _count_steps_at_the_cache(server)
     clocks = [server.stats()["clock_s"]]
     try:
-        generated = _serve(server)
+        generated = _serve(server, loop=loop)
         clocks.append(server.stats()["clock_s"])
     finally:
         server.close(drain=True)

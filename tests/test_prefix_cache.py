@@ -104,7 +104,7 @@ def test_cow_divergence_bit_identical(params, sampled):
     admits via cow_page and must emit exactly what a prefix_cache=off
     server emits — with the overlapped pipeline AND device-resident
     spec windows on, greedy and sampled (the acceptance pin)."""
-    kw = dict(slots=3, pages=48, page_size=4, window=4, overlap="on",
+    kw = dict(slots=3, pages=48, page_size=4, window=4,
               speculative=2, spec_window=2)
     warm = STEM + [5, 3]
     probe = STEM + [5, 8, 9]  # shares 1 token of warm's third page
@@ -368,7 +368,7 @@ def test_lease_outlives_registry_eviction(params):
     bit-identical, and the books settle to an all-free pool."""
     server = PagedGenerationServer(
         params, CFG, prefix_cache=True, slots=3, pages=48,
-        page_size=4, window=2, overlap="off")
+        page_size=4, window=2)
     try:
         server.submit(STEM + [5], n_new=4)  # register the stem
         pa, pb = STEM + [7, 2], STEM + [8, 3]

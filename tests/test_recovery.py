@@ -444,11 +444,11 @@ def test_revive_restores_prepoison_bucket_without_retrace(params):
     cache = FaultyCache(CFG, slots=2, pages=8, page_size=16,
                         min_bucket=1)
     server = PagedGenerationServer(params, CFG, cache=cache, window=2,
-                                   checkpoint_every=1, overlap="off",
+                                   checkpoint_every=1,
                                    prefix_cache=False)
     prompts = ([5, 9, 2], [1, 4, 3])
     wants = [reference(params, p, 12) for p in prompts]
-    real = cache._device_window
+    real = cache._device_window_dispatch
     state = {"arm": False}
 
     def dying(*args):
@@ -461,7 +461,7 @@ def test_revive_restores_prepoison_bucket_without_retrace(params):
             raise RuntimeError("injected: died with bucket stepped up")
         return real(*args)
 
-    cache._device_window = dying
+    cache._device_window_dispatch = dying
 
     def round_trip():
         state["arm"] = True

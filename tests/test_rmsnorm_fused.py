@@ -1,9 +1,9 @@
 """ops/rmsnorm.py: the Pallas fused RMSNorm (VERDICT r3 #8 experiment).
 
-Correctness gates for the A/B candidate (tools/bench_rmsnorm_fusion.py):
-forward must match the jnp reference bit-for-bit (same cast chain), the
-custom VJP must match autodiff of the reference, and the train step must
-be swappable without changing the loss.
+Correctness gates for the A/B candidate: forward must match the jnp
+reference bit-for-bit (same cast chain), the custom VJP must match
+autodiff of the reference, and the train step must be swappable
+without changing the loss.
 """
 
 import jax

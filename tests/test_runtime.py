@@ -606,7 +606,6 @@ def test_metrics_render_overlap_gauges_and_histograms():
     from kvedge_tpu.runtime.status import render_metrics
 
     snapshot = {"serving": {
-        "overlap": 1,
         "overlap_windows_total": 7,
         "overlap_inflight_depth": 1,
         "window_dispatch_harvest_ms": {
@@ -620,7 +619,6 @@ def test_metrics_render_overlap_gauges_and_histograms():
         "window_host_ms": {"edges": [1.0], "counts": [1]},  # malformed
     }}
     body = render_metrics(snapshot)
-    assert "kvedge_serve_overlap 1" in body
     assert "kvedge_serve_overlap_windows_total 7" in body
     assert "kvedge_serve_overlap_inflight_depth 1" in body
     name = "kvedge_serve_window_dispatch_harvest_ms"

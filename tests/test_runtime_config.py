@@ -234,24 +234,6 @@ def test_serving_window_and_auto_speculative_round_trip():
             RuntimeConfig.parse(f"[payload]\n{bad}\n")
 
 
-def test_serving_overlap_knob_round_trips_and_validates():
-    cfg = RuntimeConfig.parse(
-        "[payload]\nserving = 'paged'\nserving_overlap = 'off'\n"
-    )
-    assert cfg.serving_overlap == "off"
-    assert RuntimeConfig.parse(cfg.to_toml()) == cfg
-    assert RuntimeConfig.parse("").serving_overlap == "auto"
-    for value in ("auto", "on", "off"):
-        parsed = RuntimeConfig.parse(
-            f"[payload]\nserving_overlap = '{value}'\n"
-        )
-        assert parsed.serving_overlap == value
-        assert RuntimeConfig.parse(parsed.to_toml()) == parsed
-    for bad in ("serving_overlap = 'sometimes'", "serving_overlap = 1"):
-        with pytest.raises(RuntimeConfigError):
-            RuntimeConfig.parse(f"[payload]\n{bad}\n")
-
-
 def test_serving_trace_knob_round_trips_and_validates():
     cfg = RuntimeConfig.parse(
         "[payload]\nserving = 'paged'\nserving_trace = 'on'\n"

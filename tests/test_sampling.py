@@ -154,9 +154,9 @@ def test_serve_endpoint_paged_and_contiguous_sampling_agree(tmp_path):
 
 def test_sampled_windows_match_per_step_and_contiguous(params):
     """Round-5 on-device sampling: sampled requests decoded through
-    multi-step device windows (kvcache.step_window_sampled) emit
-    exactly the tokens of (a) the per-step host-sampling path
-    (window=1) and (b) the contiguous scan backend — the key schedule
+    multi-step device windows (kvcache.dispatch_window_sampled) emit
+    exactly the tokens of (a) one-step windows (window=1, a harvest
+    a token) and (b) the contiguous scan backend — the key schedule
     fold_in(seed, base + i) rides the scan carry bit-exactly."""
     import threading
 

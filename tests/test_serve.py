@@ -751,9 +751,13 @@ def test_prefix_cache_persists_across_serve_restarts(tmp_path):
         # node — a no-op).
         stats = revived_fn.stats()
         assert stats["prefix_entries"] == 3, stats
+        # The boot probe of run 2 already hit its own loaded page (the
+        # radix cache shares down to one token, copy-on-write), so the
+        # request's hit is counted from here.
+        hits = stats["prefix_hits"]
         warm = revived_fn({"tokens": [prompt], "n_new": 4})["tokens"]
         assert warm == cold
-        assert revived_fn.stats()["prefix_hits"] == 1
+        assert revived_fn.stats()["prefix_hits"] == hits + 1
     finally:
         revived_fn.close()
 

@@ -109,7 +109,7 @@ def test_mid_stream_admission_does_not_perturb_in_flight(params):
 
 
 def test_request_admitted_mid_window_matches_generate(params):
-    """The device-side decode window (kvcache.step_window) must re-sync
+    """The device-side decode window (kvcache.dispatch_window) must re-sync
     with admission between windows: a request submitted while another is
     mid-decode (windows running — proven by consuming streamed tokens
     first) joins the batch and BOTH results equal their own contiguous
@@ -128,8 +128,8 @@ def test_request_admitted_mid_window_matches_generate(params):
 
 
 def test_window_steps_equal_single_steps():
-    """kvcache.step_window is the SAME program as n repeated step()s:
-    same tokens out, same lengths, same page growth."""
+    """A dispatched and harvested window is the SAME program as n
+    repeated step()s: same tokens out, same lengths, same page growth."""
     from kvedge_tpu.models.kvcache import PagedKVCache
 
     cfg = TransformerConfig(
@@ -150,7 +150,9 @@ def test_window_steps_equal_single_steps():
 
     n = 7  # crosses a page boundary (page_size=4) inside the window
     cache_w, pend = fresh()
-    window = np.asarray(cache_w.step_window(p, jnp.asarray(pend), n))
+    window = cache_w.harvest_window(
+        cache_w.dispatch_window(p, jnp.asarray(pend), n))[:n]
+    cache_w.drop_carry()
 
     cache_s, toks = fresh()
     singles = []
@@ -295,24 +297,15 @@ def test_admission_control_rejects_impossible_and_times_out(params):
 
         server.submit([9, 9, 9], n_new=44)  # compile prefill + windows
 
-        real_window = server._cache.step_window
         real_dispatch = server._cache.dispatch_window
 
-        def slow_window(*args, **kwargs):
+        def slow_dispatch(*args, **kwargs):
             # Sleep > the competitor's full timeout: even a single
             # window outlasts it, so scheduling jitter cannot let the
             # occupier finish early.
             time_mod.sleep(0.25)
-            return real_window(*args, **kwargs)
-
-        def slow_dispatch(*args, **kwargs):
-            # The overlapped loop (serving_overlap, the default) goes
-            # through dispatch_window instead of step_window — slow
-            # both so the test pins admission timing on either path.
-            time_mod.sleep(0.25)
             return real_dispatch(*args, **kwargs)
 
-        server._cache.step_window = slow_window
         server._cache.dispatch_window = slow_dispatch
         t = threading.Thread(
             target=lambda: server.submit([1, 2, 3], n_new=44)
@@ -331,7 +324,6 @@ def test_admission_control_rejects_impossible_and_times_out(params):
         with pytest.raises(ServerBusy):
             server.submit([4, 5], n_new=2, timeout=0.2)
         t.join(timeout=300)
-        server._cache.step_window = real_window
         server._cache.dispatch_window = real_dispatch
     finally:
         server.close()
@@ -510,6 +502,9 @@ def test_grow_under_registry_pressure_evicts_instead_of_poisoning(params):
     # and never reach the pressure this test exists to exercise.
     server = PagedGenerationServer(params, CFG, slots=2, pages=18,
                                    page_size=4, window=4)
+    # Compile the C-cycles' prefill first: compiled inside the first
+    # cycle it would hand B seconds in which to finish unpressed.
+    server.submit([9] * 8, n_new=4)
     relief_calls = [0]
     orig_relief = server._relieve_pool_pressure_locked
 
@@ -518,13 +513,18 @@ def test_grow_under_registry_pressure_evicts_instead_of_poisoning(params):
         return orig_relief(needed)
 
     server._cache.pressure_relief = counting_relief
-    real_window = server._cache.step_window
+    real_window = server._cache.dispatch_window
 
     def slow_window(*args, **kwargs):
         time.sleep(0.25)  # keep B in flight while C-cycles pin pages
         return real_window(*args, **kwargs)
 
-    server._cache.step_window = slow_window
+    server._cache.dispatch_window = slow_window
+    # References first: compiling one between two C-cycles would hand
+    # B seconds in which to finish before the later cycles pin pages.
+    cycles = [[10 + i] * 8 for i in range(4)]
+    c_want = [reference(params, c, 4) for c in cycles]
+    b_want = reference(params, [3, 1, 4, 1], 56)
     try:
         b_result: list = []
         b_errors: list = []
@@ -544,12 +544,11 @@ def test_grow_under_registry_pressure_evicts_instead_of_poisoning(params):
         # Distinct 2-page prompts complete while B decodes; each
         # completion pins pages the registry holds beyond any
         # reservation. B's later grows must reclaim them.
-        for i in range(4):
-            c = [10 + i] * 8
-            assert server.submit(c, n_new=4) == reference(params, c, 4)
+        for c, want in zip(cycles, c_want):
+            assert server.submit(c, n_new=4) == want
         t.join(timeout=180)
         assert not b_errors, b_errors
-        assert b_result[0] == reference(params, [3, 1, 4, 1], 56)
+        assert b_result[0] == b_want
         assert relief_calls[0] >= 1, (
             "the scenario never exercised pool-pressure relief — "
             "tighten it"
@@ -559,7 +558,7 @@ def test_grow_under_registry_pressure_evicts_instead_of_poisoning(params):
             params, [9, 9], 2
         )
     finally:
-        server._cache.step_window = real_window
+        server._cache.dispatch_window = real_window
         server.close()
 
 
@@ -989,24 +988,15 @@ def test_multipage_window_matches_generate(params):
     server = PagedGenerationServer(params, CFG, slots=2, pages=32,
                                    page_size=4, window=16)
     windows: list[int] = []
-    real_window = server._cache.step_window
     real_dispatch = server._cache.dispatch_window
-
-    def spy_window(params_, tokens, n_steps, active=None):
-        windows.append(n_steps)
-        return real_window(params_, tokens, n_steps, active=active)
 
     def spy_dispatch(params_, tokens, n_steps, active=None,
                      steps_left=None, stop_tokens=None):
-        # The overlapped loop (default serving_overlap) dispatches
-        # through here; the window plan is identical to the serial
-        # path's, so the assertions below hold for both loop bodies.
         windows.append(n_steps)
         return real_dispatch(params_, tokens, n_steps, active=active,
                              steps_left=steps_left,
                              stop_tokens=stop_tokens)
 
-    server._cache.step_window = spy_window
     server._cache.dispatch_window = spy_dispatch
     try:
         prompt = [11, 3, 8]
@@ -1017,7 +1007,6 @@ def test_multipage_window_matches_generate(params):
         assert max(windows) == 16
         assert len(windows) <= 6
     finally:
-        server._cache.step_window = real_window
         server._cache.dispatch_window = real_dispatch
         server.close()
 

@@ -55,8 +55,10 @@ def _programs(cfg, params):
     pending = jnp.stack([tok, jnp.int32(0)])
     out["step"] = cache.step(params, pending, active)
     pending = jnp.argmax(out["step"], axis=-1).astype(jnp.int32)
-    out["window"] = cache.step_window(params, pending, 4, active)
-    last = out["window"][-1, 0]
+    out["window"] = cache.harvest_window(
+        cache.dispatch_window(params, pending, 4, active))[:4]
+    cache.drop_carry()
+    last = jnp.int32(out["window"][-1, 0])
     draft = jnp.stack([jnp.stack([last, last, last]),
                        jnp.zeros((3,), jnp.int32)])
     emitted, accepted, logits0 = cache.step_spec(

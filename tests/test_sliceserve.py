@@ -55,6 +55,40 @@ def reference(params, prompt, n_new):
     return [int(t) for t in np.asarray(out)[0]]
 
 
+def test_every_slice_op_is_sent_and_handled():
+    """The op table has no dead entry: every ``OP_*`` code is sent by
+    a leader method and replayed by the follower, none is replayed
+    that nothing sends, and each has its span name."""
+    import ast
+    import inspect
+
+    from kvedge_tpu.runtime import sliceserve
+
+    ops = {name for name in vars(sliceserve) if name.startswith("OP_")}
+    codes = {name: getattr(sliceserve, name) for name in ops}
+    assert sorted(codes.values()) == list(range(len(ops)))
+    assert set(sliceserve._OP_NAMES) == set(codes.values())
+
+    mentions: dict = {}
+    tree = ast.parse(inspect.getsource(SlicePagedKVCache))
+    for fn in tree.body[0].body:
+        if isinstance(fn, ast.FunctionDef):
+            mentions[fn.name] = {
+                node.id for node in ast.walk(fn)
+                if isinstance(node, ast.Name) and node.id in ops}
+    follower = ("_follow_op", "_replay_packed", "_multi_templates")
+    sent = set().union(*(names for fn, names in mentions.items()
+                         if fn not in follower))
+    coalesced = {name for name in ops
+                 if codes[name] in sliceserve._COALESCABLE}
+    # A coalescable op needs its payload's shapes and its replay; the
+    # others are branches of _follow_op itself.
+    assert mentions["_multi_templates"] == coalesced
+    assert mentions["_replay_packed"] == coalesced
+    handled = mentions["_follow_op"] | coalesced
+    assert sent == handled == ops, (sent ^ handled, ops - sent)
+
+
 def test_slice_cache_matches_plain_cache_step_and_window(params, mesh):
     """Direct cache equality: chunked prefill + per-token steps + a
     device window produce identical tokens through both caches."""
@@ -79,9 +113,9 @@ def test_slice_cache_matches_plain_cache_step_and_window(params, mesh):
             )
             tok = int(np.argmax(np.asarray(step_logits)[0]))
             toks.append(tok)
-        window = np.asarray(cache.step_window(
-            params, jnp.asarray([tok, 0], jnp.int32), 4, active=active
-        ))
+        window = cache.harvest_window(cache.dispatch_window(
+            params, np.asarray([tok, 0], np.int32), 4, active=active
+        ))[:4]
         toks.extend(int(t) for t in window[:, 0])
         seqs.append(toks)
     assert seqs[0] == seqs[1]
@@ -371,9 +405,8 @@ def test_slice_overlap_server_greedy_and_sampled_match_plain(params,
     bit-identical across backends under one seed."""
     key = jax.random.fold_in(jax.random.PRNGKey(3), 0)
     prompt_g, prompt_s = [5, 9, 2, 7, 1], [1, 2, 3, 4]
-    plain = PagedGenerationServer(params, CFG, slots=3, pages=24,
-                                  overlap="on")
-    sliced = _slice_server(params, mesh, overlap="on")
+    plain = PagedGenerationServer(params, CFG, slots=3, pages=24)
+    sliced = _slice_server(params, mesh)
     try:
         results = []
         for server in (plain, sliced):
@@ -462,7 +495,7 @@ def test_slice_server_sampled_spec_window_matches_plain(params, mesh):
     def build(cache=None, **kw):
         return PagedGenerationServer(
             params, CFG, cache=cache, speculative=3, spec_window=4,
-            overlap="on", **kw)
+            **kw)
 
     results = []
     for backend in ("plain", "slice"):

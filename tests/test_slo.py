@@ -358,14 +358,15 @@ def _decode_pair(params, server, label):
 
 
 @pytest.mark.parametrize("shape", [
-    dict(overlap="off"),
-    dict(overlap="on"),
-    dict(overlap="on", speculative=3, spec_window=2),
-], ids=["serial", "overlap", "spec-window"])
+    dict(window=1),
+    dict(),
+    dict(speculative=3, spec_window=2),
+], ids=["one-step", "overlap", "spec-window"])
 def test_observability_on_is_token_bit_identical(params, shape):
     """The acceptance bar: SLO engine + occupancy ring + full-sample
-    tracing all ON change no served token — greedy and sampled, serial
-    and pipelined loops, device-resident spec windows included."""
+    tracing all ON change no served token — greedy and sampled, at
+    one-step windows and the default, device-resident spec windows
+    included."""
     off_server = PagedGenerationServer(params, CFG, slots=2, pages=32,
                                        **shape)
     try:
@@ -389,7 +390,7 @@ def test_observability_on_is_token_bit_identical(params, shape):
 
 def test_device_time_itl_and_occupancy_fill(params):
     server = PagedGenerationServer(params, CFG, slots=2, pages=16,
-                                   overlap="on", **_OBS)
+                                   **_OBS)
     try:
         server.submit([5, 9, 2], n_new=6)
         stats = server.stats()
@@ -463,7 +464,7 @@ def test_slo_shed_requires_objectives(params):
 def test_flight_bundle_complete_and_consistent_after_poison(params):
     tr = Tracer(sample=1.0)
     server = PagedGenerationServer(params, CFG, slots=2, pages=24,
-                                   overlap="on", tracer=tr, **_OBS)
+                                   tracer=tr, **_OBS)
     try:
         server.submit([3, 1, 4, 1, 5], n_new=4, request_id="req-a")
         cache = server._cache
@@ -533,10 +534,8 @@ def test_bundle_persists_next_to_last_failure(tmp_path):
         def die(*a, **k):
             raise RuntimeError("injected: decode seam died")
 
-        for seam in ("dispatch_window", "step_window",
-                     "harvest_window", "step"):
-            if hasattr(server._cache, seam):
-                setattr(server._cache, seam, die)
+        for seam in ("dispatch_window", "harvest_window"):
+            setattr(server._cache, seam, die)
         with pytest.raises((ServingFailure, GenerateUnavailable)):
             serve_fn({"tokens": [[1, 2, 3]], "n_new": 8})
         bundle = None

@@ -114,21 +114,6 @@ def test_windowed_spec_matches_legacy_and_greedy(params):
     assert total - len(REQUESTS) <= hist["sum"] <= total
 
 
-def test_spec_window_serial_overlap_off_still_exact(params):
-    """serving_overlap=off keeps the serial loop: spec windows are a
-    pipeline feature, so the legacy per-pass path serves — tokens must
-    be identical either way."""
-    server = PagedGenerationServer(params, CFG, slots=4, pages=64,
-                                   page_size=4, speculative=3,
-                                   spec_window=4, overlap="off")
-    try:
-        got = run_concurrent(server)
-    finally:
-        server.close()
-    for i, (prompt, n_new) in enumerate(REQUESTS):
-        assert got[i] == reference(params, prompt, n_new), i
-
-
 SAMPLING = (jax.random.fold_in(jax.random.PRNGKey(7), 0),
             jnp.float32(0.8), jnp.float32(0.9))
 PROMPT_G, PROMPT_S = [5, 9, 2, 7], [1, 2, 3, 4]
@@ -248,8 +233,7 @@ def test_poison_mid_spec_window_drains_inflight_then_revives(params):
     plan = FaultPlan(0, kinds=("raise",), fire_window=(3, 4))
     cache = FaultyCache(CFG, slots=2, pages=24, page_size=4, plan=plan)
     server = PagedGenerationServer(params, CFG, cache=cache,
-                                   speculative=3, spec_window=2,
-                                   overlap="on")
+                                   speculative=3, spec_window=2)
     prompt = [3, 1, 4, 1, 5]
     try:
         dying_thread = server._thread

@@ -206,35 +206,36 @@ def _decode_pair(params, server, label):
 
 
 def test_tracing_is_token_bit_identical(params):
-    """The acceptance bar: greedy AND sampled streams, serial AND
-    pipelined loops — the traced run's tokens equal the untraced run's
-    bit for bit, and the traced run actually recorded its spans."""
-    for overlap in ("off", "on"):
+    """The acceptance bar: greedy AND sampled streams, one-step
+    windows AND the default — the traced run's tokens equal the
+    untraced run's bit for bit, and the traced run actually recorded
+    its spans."""
+    for window in (1, 64):
         off_server = PagedGenerationServer(params, CFG, slots=2,
-                                           pages=16, overlap=overlap)
+                                           pages=16, window=window)
         try:
             off = _decode_pair(params, off_server, "off")
         finally:
             off_server.close()
         tr = Tracer(sample=1.0)
         on_server = PagedGenerationServer(params, CFG, slots=2,
-                                          pages=16, overlap=overlap,
+                                          pages=16, window=window,
                                           tracer=tr)
         try:
             on = _decode_pair(params, on_server, "on")
         finally:
             on_server.close()
-        assert off == on, f"tracing changed tokens (overlap={overlap})"
+        assert off == on, f"tracing changed tokens (window={window})"
         names = {rec[3] for rec in tr._snapshot()}
         assert {"prefill", "decode", "queue"} <= names
         # The new sites: every phase of the loop and of the submit
-        # path is a span of the ring, and the pipelined loop keeps its
+        # path is a span of the ring, and the loop keeps its
         # dispatch-to-harvest "window" span (it spans phases).
         # (whether the loop ever parks for work is up to the race
         # between its start and the first admission)
         assert (set(LOOP_PHASES) - {"loop/wait_work"}
                 | set(ADMIT_PHASES)) <= names
-        assert (overlap == "on") == ("window" in names)
+        assert "window" in names
     assert off[0] == reference(params, [5, 9, 2, 7], 9)
 
 
@@ -364,7 +365,7 @@ def test_tracer_survives_poison_and_revive(params):
     in the same timeline as the spans they interrupt."""
     tr = Tracer(sample=1.0)
     server = PagedGenerationServer(params, CFG, slots=2, pages=24,
-                                   overlap="on", tracer=tr)
+                                   tracer=tr)
     prompt = [3, 1, 4, 1, 5]
     try:
         baseline = server.submit(prompt, n_new=4, request_id="req-a")
@@ -582,10 +583,8 @@ def test_poison_embeds_flight_recorder_in_last_failure(tmp_path):
         def die(*a, **k):
             raise RuntimeError("injected: decode seam died")
 
-        for seam in ("dispatch_window", "step_window",
-                     "harvest_window", "step"):
-            if hasattr(server._cache, seam):
-                setattr(server._cache, seam, die)
+        for seam in ("dispatch_window", "harvest_window"):
+            setattr(server._cache, seam, die)
         with pytest.raises((ServingFailure, GenerateUnavailable)):
             serve_fn({"tokens": [[1, 2, 3]], "n_new": 8})
         record = None

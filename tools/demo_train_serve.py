@@ -8,7 +8,7 @@ step and a live generation — the round-2 half of the end-to-end story
 
 With ``--flagship`` the run sizes the payload through the ``[model]``
 TOML section instead of the probe default: the 41.6M-param flagship —
-the exact shape bench.py reports numbers for — trains, checkpoints, and
+the exact shape ``__graft_entry__`` reports numbers for — trains, checkpoints, and
 serves through the same product path on the TPU. That scene needs the
 chip: on any other backend the device check fails the payload instead
 of quietly running it there (the committed cast's copy of the scene
