@@ -655,28 +655,36 @@ def check_near_ties(smoke: Smoke, tcfg, params, a: dict, b: dict,
 
 def phase_attention_op(smoke: Smoke) -> None:
     """The decode kernel against a plain gather reference on one random
-    pool at the smoke's widths, live lengths on and around a page
-    boundary and at the cap. The pool is handed over as the server
-    stores it, two layers of [pages, page, kv_heads * d_head], and the
-    kernel reads the second where it lies; the reference gathers that
-    layer's pages per head. Compiled for the TPU the two must agree in
-    every bit: that is the contract ``paged_attention = "auto"`` rests
-    on (kvcache._use_paged_kernel), held here where it holds. The CPU
-    interpreter sums the weights-times-V contraction in another order
-    than XLA:CPU's own einsum, so there it is held to rounding only."""
+    pool at the smoke's widths: query positions on and around a page
+    boundary, on and around the boundary of one fetched block of pages,
+    and at the cap, between two rows that are not decoding (position
+    -1, tables far outside the pool). The pool is handed over as the
+    server stores it, two layers of [pages, page, kv_heads * d_head],
+    and the kernel reads the second where it lies; the reference
+    gathers that layer's pages per head. Compiled for the TPU a live
+    row must agree with it in every bit: that is the contract
+    ``paged_attention = "auto"`` rests on (kvcache._use_paged_kernel),
+    held here where it holds; a dead row must come back as zeros. The
+    CPU interpreter sums the weights-times-V contraction in another
+    order than XLA:CPU's own einsum, so there live rows are held to
+    rounding only."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from kvedge_tpu.ops import pallas_interpret
-    from kvedge_tpu.ops.paged_attention import paged_decode_attention
+    from kvedge_tpu.ops.paged_attention import (
+        block_pages, paged_decode_attention,
+    )
 
     s = smoke.shape
     model = tomllib.loads(s.model)
     heads, kv = model["n_heads"], model["n_kv_heads"]
     dh, page = model["d_model"] // heads, s.page_size
     max_pages = s.seq // page
-    lives = [page - 1, page, page + 1, s.seq - 1]
+    block = block_pages(max_pages, page, kv * dh) * page  # its tokens
+    lives = sorted({page - 1, page, page + 1, block - 1, block, s.seq - 1})
+    lives = [-1, *(p for p in lives if p < s.seq), -1]
     with smoke.phase("attention-op") as entry:
         keys = jax.random.split(jax.random.PRNGKey(smoke.seed), 3)
         pages = len(lives) * max_pages + 1
@@ -686,10 +694,11 @@ def phase_attention_op(smoke: Smoke) -> None:
                                    jnp.bfloat16)
         pool_v = jax.random.normal(keys[2], (2, pages, page, kv * dh),
                                    jnp.bfloat16)
-        tables = jnp.asarray(
-            1 + np.arange(len(lives) * max_pages).reshape(len(lives), -1),
-            jnp.int32)
+        tables = 1 + np.arange(len(lives) * max_pages).reshape(len(lives), -1)
         pos = jnp.asarray(lives, jnp.int32)
+        live = np.asarray(lives) >= 0
+        tables = jnp.asarray(np.where(live[:, None], tables, 2 ** 30),
+                             jnp.int32)
 
         def gather(q, pool_k, pool_v, tables, pos):
             b, span = q.shape[0], max_pages * page
@@ -705,20 +714,31 @@ def phase_attention_op(smoke: Smoke) -> None:
             out = jnp.einsum("bkgqs,bskd->bqkgd", weights, v)
             return out.reshape(b, heads, dh)
 
-        want = np.asarray(jax.jit(gather)(q, pool_k, pool_v, tables, pos))
+        want = np.asarray(jax.jit(gather)(
+            q, pool_k, pool_v, jnp.where(live[:, None], tables, 0), pos))
         got = np.asarray(jax.jit(
             lambda *a: paged_decode_attention(
                 *a, interpret=pallas_interpret())
         )(q, pool_k, pool_v, tables, pos, jnp.asarray(layer, jnp.int32)))
         bits = lambda x: x.view(np.uint16).astype(np.int32)  # noqa: E731
+        dead_nonzero = int((bits(got[~live]) != 0).sum())
+        got, want = got[live], want[live]
         differing = int((bits(got) != bits(want)).sum())
         worst = float(np.abs(got.astype(np.float32)
                              - want.astype(np.float32)).max())
         entry.update(elements=int(got.size), differing=differing,
-                     max_abs_diff=worst, live_lengths=lives)
-        smoke.say(f"attention op, kernel vs plain gather at live lengths "
-                  f"{lives}: {differing} of {got.size} bf16 outputs "
-                  f"differ, max |diff| {worst:.3g}")
+                     max_abs_diff=worst, live_lengths=lives,
+                     dead_nonzero=dead_nonzero)
+        smoke.say(f"attention op, kernel vs plain gather at query "
+                  f"positions {lives} (-1: a row that is not decoding): "
+                  f"held bit for bit in live rows, {differing} of "
+                  f"{got.size} bf16 outputs differ, max |diff| "
+                  f"{worst:.3g}; held to zeros in dead rows, "
+                  f"{dead_nonzero} outputs are not")
+        if dead_nonzero:
+            raise SmokeFailure(
+                f"the decode kernel wrote {dead_nonzero} nonzero outputs "
+                f"for rows that are not decoding")
         if differing and not pallas_interpret():
             raise SmokeFailure(
                 f"the compiled decode kernel is not bit-identical to the "

@@ -115,23 +115,30 @@ def _use_paged_kernel(cfg: TransformerConfig, page_size: int,
     Pallas block-table kernel where it wins: TPU, long-context caps
     (max_seq >= 2048), page_size % 128 == 0 (each page's score columns
     land at lane offset j * page in the kernel's phase-2 scratch, which
-    Mosaic requires tile-aligned — and the per-page DMA loop is
-    latency-bound anyway: at 16-token pages its 4 KB copies lose to
-    XLA's bulk gather), kv_heads*d_head % 128 == 0 (TPU DMA lane
+    Mosaic requires tile-aligned — and a copy is a page: at 16-token
+    pages 4 KB copies lost to XLA's bulk gather when they were made
+    one at a time, and were not measured again since they are made in
+    blocks), kv_heads*d_head % 128 == 0 (TPU DMA lane
     alignment; MHA at one kv head takes the gather), and the two-phase
     kernel's VMEM scratch fitting the budget (over-cap pools route to
-    the gather). The kernel stages the gather's own rounded score rows
+    the gather). The kernel works in proportion to live tokens: a row
+    that is not decoding is handed over at position -1 and costs
+    nothing, live pages arrive in blocks with the next live row's
+    first block already in flight (ops/paged_attention.py). For a live
+    row it stages the gather's own rounded score rows
     and runs the same softmax and one flat V contraction, so its
     scores and weights are the gather's bit for bit on any backend;
     the V contraction is the same products summed in fp32, but as one
     [H, S] x [S, K*Dh] dot where the gather has a [G, S] x [S, Dh] dot
     per kv head, and a backend may order those two sums differently.
     Compiled for the chip it does not: chip_smoke.py FAILS unless, on
-    the TPU, the kernel's outputs at the 209M widths equal the gather's
-    in every bit (live lengths 127/128/129/2047) and an "auto" and a
+    the TPU, the kernel's live rows at the 209M widths equal the
+    gather's in every bit (query positions 127/128/129, 1023/1024 on
+    the edge of a fetched block, and 2047, between two dead rows that
+    must come back as zeros) and an "auto" and a
     "gather" server return the same greedy streams — so there "auto"
     is a routing choice, not a numerics one, for as long as that check
-    passes (TPU v5 lite, PR 21: 0 of 4,096 outputs differ). That is
+    passes (TPU v5 lite, PR 31: 0 of 6,144 live outputs differ). That is
     heads of 64, whose score scale 1/8 divides exactly; at heads of 128
     (24 query / 2 kv: the benchmark's widths) the two paths round the
     division by sqrt(128) at different points and 10,407 of 15,360
@@ -1595,12 +1602,17 @@ def _paged_attend_layer(cfg: TransformerConfig, state: PagedState, x,
         # over the block table — K/V pages stream up to each row's LIVE
         # length out of pool[layer] through the Pallas kernel; neither
         # the layer's slab nor the padded pool view is ever
-        # materialized (ops/paged_attention.py).
+        # materialized (ops/paged_attention.py). A row that is not
+        # decoding (an empty slot of the bucket, or a half-prefilled
+        # one, which carries its final length) is handed over at
+        # position -1: the kernel reads nothing for it and returns
+        # zeros, where the gather attends over whatever its table holds.
         from kvedge_tpu.ops import pallas_interpret
         from kvedge_tpu.ops.paged_attention import paged_decode_attention
 
         att = paged_decode_attention(
-            q[:, 0], new_pool_k, new_pool_v, tables, q_positions[:, 0],
+            q[:, 0], new_pool_k, new_pool_v, tables,
+            jnp.where(active, q_positions[:, 0], -1),
             layer, scale_k=new_scale_k, scale_v=new_scale_v,
             interpret=pallas_interpret(),
         )  # [B, H, Dh], kv-major head layout — same as the einsum's

@@ -123,11 +123,19 @@ _CASES = {
     # 128 fp32 lanes in VMEM, twice, is exactly _SCALE_VMEM_BUDGET: the
     # largest int8 pool "auto" routes to the kernel.
     "paged_decode_int8_209m_scales_at_budget": lambda: _paged(int8=True),
-    # The 8,192-token cap: 64 pages per sequence is the largest score +
-    # V-image scratch decode_scratch_fits_vmem admits at this width.
+    # The 8,192-token cap, 64 pages per sequence, and the largest cap
+    # whose score rows, V image and landing pads (two blocks of 8 pages,
+    # K and V) decode_scratch_fits_vmem admits at this width: 142 pages.
     "paged_decode_bf16_cap8192": lambda: _paged(max_pages=64,
                                                 pool_pages=512),
     "paged_decode_int8_cap8192": lambda: _paged(int8=True, max_pages=64),
+    "paged_decode_bf16_scratch_at_budget": lambda: _paged(max_pages=142,
+                                                          pool_pages=512),
+    # The benchmark cell's kernel alone: 64 rows, 24 query / 2 KV heads
+    # of 128, 24 pages a row, 768 pages, 16 layers.
+    "paged_decode_bf16_cell": lambda: _paged(
+        batch=64, heads=24, kv=2, dh=128, max_pages=24, pool_pages=768,
+        layers=16),
     # The flagship preset serves MHA: 8 KV heads of 64 (width 512).
     "paged_decode_bf16_flagship": lambda: _paged(heads=8, kv=8),
     "flash_attention_fwd_bwd_t2048": _flash_fwd_bwd,
@@ -305,7 +313,17 @@ def test_scale_budget_case_sits_on_the_budget():
 
 
 def test_cap8192_case_sits_on_the_auto_routes_kernel_side():
-    """64 pages of 128 is a cap "auto" still routes to the kernel."""
-    from kvedge_tpu.ops.paged_attention import decode_scratch_fits_vmem
+    """64 pages of 128 is a cap "auto" still routes to the kernel, and
+    the case above named for the budget is the edge: at 16 heads and a
+    width of 256 a page of cap costs 8 KB of score rows and 64 KB of V
+    image, and the landing pads are two blocks of 8 pages, K and V, 2
+    MB where they were four single pages: 12 MB hold 142 pages of cap
+    (167 before the pads grew) and not 143."""
+    from kvedge_tpu.ops.paged_attention import (
+        block_pages, decode_scratch_fits_vmem,
+    )
 
+    assert block_pages(64, 128, 256) == 8
     assert decode_scratch_fits_vmem(64, 128, 256, 16)
+    assert decode_scratch_fits_vmem(142, 128, 256, 16)
+    assert not decode_scratch_fits_vmem(143, 128, 256, 16)

@@ -8,8 +8,10 @@ the same softmax + flat V contraction, so every comparison here is
 exact (raw-bits equality). On CPU the kernel runs under the Pallas
 interpreter (cfg.paged_attention = "kernel" forces it; "auto" resolves
 to the gather here), which is how these tests pin it without TPU
-hardware; the bench's long-context leg re-asserts the same bit-identity
-on the real chip before timing.
+hardware; chip_smoke.py re-asserts the same bit-identity on the real
+chip. A row handed over at a negative position is not decoding: the
+kernel does nothing for it and returns zeros, and the rows around it
+keep their bits (the gather has no such rows to be held to).
 """
 
 import dataclasses
@@ -230,6 +232,180 @@ def test_layer_indexed_kernel_equals_kernel_on_that_layers_slab(layer, int8):
         k = (k.astype(jnp.float32) * sk[layer][..., None]).astype(q.dtype)
         v = (v.astype(jnp.float32) * sv[layer][..., None]).astype(q.dtype)
     _assert_bit_identical(got, _gather_reference(q, k, v, tables, q_pos))
+
+
+def _pool_with_dead_rows(q_pos_list, *, int8, page=16, H=8, KV=2, Dh=64,
+                         seed=0):
+    """A pool for rows of which some are dead (position < 0), as
+    ``_paged_attend_layer`` hands a row that is not decoding. Page 0 is
+    the alias unused table entries point at, page 1 is poison (NaN: in
+    the data of a bf16 pool, in the scales of an int8 one); a dead
+    row's table holds the poison page among ids far outside the pool.
+    Returns the kernel's operands and, for the reference, the pool in
+    the compute dtype (an int8 pool dequantized as the gather does)."""
+    live_pages = [qp // page + 1 for qp in q_pos_list if qp >= 0]
+    B, MP, P = len(q_pos_list), max(live_pages + [1]) + 1, sum(live_pages) + 2
+    keys = jax.random.split(jax.random.PRNGKey(seed), 5)
+    q = jax.random.normal(keys[0], (B, H, Dh), jnp.bfloat16)
+    shape = (P, page, KV, Dh)
+    if int8:
+        pool_k = jax.random.randint(keys[1], shape, -127, 128, jnp.int8)
+        pool_v = jax.random.randint(keys[2], shape, -127, 128, jnp.int8)
+        sk = jax.random.uniform(keys[3], shape[:-1], jnp.float32, 0.001, 0.02)
+        sv = jax.random.uniform(keys[4], shape[:-1], jnp.float32, 0.001, 0.02)
+        sk, sv = sk.at[1].set(jnp.nan), sv.at[1].set(jnp.nan)
+        scales = (sk, sv)
+        ref_k = (pool_k.astype(jnp.float32) * sk[..., None]).astype(q.dtype)
+        ref_v = (pool_v.astype(jnp.float32) * sv[..., None]).astype(q.dtype)
+    else:
+        pool_k = jax.random.normal(keys[1], shape, jnp.bfloat16).at[1].set(
+            jnp.nan)
+        pool_v = jax.random.normal(keys[2], shape, jnp.bfloat16).at[1].set(
+            jnp.nan)
+        scales, ref_k, ref_v = (), pool_k, pool_v
+    tables = np.zeros((B, MP), np.int32)
+    nxt = 2
+    for b, qp in enumerate(q_pos_list):
+        if qp < 0:
+            tables[b] = 2 ** 30 + np.arange(MP)
+            tables[b, 0] = 1
+            continue
+        for j in range(qp // page + 1):
+            tables[b, j] = nxt
+            nxt += 1
+    return (q, pool_k, pool_v, jnp.asarray(tables),
+            jnp.asarray(q_pos_list, jnp.int32), scales, ref_k, ref_v)
+
+
+def _assert_dead_rows_do_nothing(q_pos_list, *, int8, page=16):
+    """Live rows: the gather's bits, and the bits the same rows get in
+    a batch of their own. Dead rows: exact +0.0, whatever their tables
+    hold."""
+    q, pool_k, pool_v, tables, q_pos, scales, ref_k, ref_v = (
+        _pool_with_dead_rows(q_pos_list, int8=int8, page=page))
+    got = np.asarray(_kernel(q, pool_k, pool_v, tables, q_pos, *scales))
+    live = np.flatnonzero(np.asarray(q_pos_list) >= 0)
+    dead = np.flatnonzero(np.asarray(q_pos_list) < 0)
+    assert not got[dead].view(np.uint16).any()
+    if not live.size:
+        return
+    _assert_bit_identical(got[live], _gather_reference(
+        q[live], ref_k, ref_v, tables[live], q_pos[live]))
+    alone = _kernel(q[live], pool_k, pool_v, tables[live], q_pos[live],
+                    *scales)
+    _assert_bit_identical(got[live], alone)
+
+
+# Positions of a batch's rows; -1 is a row that is not decoding. The
+# cross-row prefetch has two edges: a live row behind a dead one (its
+# first block was started by an earlier row, or by row 0 on its behalf)
+# and a dead row at the end of the grid (nothing is started for it).
+_DEAD_ROW_BATCHES = {
+    "interleaved": [-1, 40, -1, 17, 3, -1],
+    "live_behind_dead_and_dead_last": [17, -1, 40, -1],
+    "dead_first_rows": [-1, -1, 5, 33],
+    "one_live_row": [21],
+    "one_dead_row": [-1],
+    "all_dead": [-1, -1, -1],
+}
+
+
+@pytest.mark.window
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("batch", sorted(_DEAD_ROW_BATCHES))
+def test_dead_rows_do_nothing_and_live_rows_keep_their_bits(batch, int8):
+    """A dead row's program starts no copy and writes zeros (its table
+    points at a poison page and far outside the pool); a live row's
+    output does not depend on which rows around it are dead, B = 1
+    included, for the bf16 and the int8 pool alike."""
+    _assert_dead_rows_do_nothing(_DEAD_ROW_BATCHES[batch], int8=int8)
+
+
+@pytest.mark.window
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+def test_lengths_around_a_block_boundary(monkeypatch, int8):
+    """Pages arrive in blocks; with blocks of two 16-token pages, live
+    lengths of 31, 32 and 33 (the last column of a block, a full block,
+    one past) and of 64, 65 and 96 (two and three blocks, the second
+    slot reused), a dead row among them."""
+    import kvedge_tpu.ops.paged_attention as pa
+
+    monkeypatch.setattr(pa, "_MAX_BLOCK_PAGES", 2)
+    assert pa.block_pages(7, 16, 128) == 2
+    # The depth is read when the kernel is traced, and traces are kept.
+    pa.paged_decode_attention.clear_cache()
+    try:
+        _assert_dead_rows_do_nothing([30, 31, 32, 63, -1, 64, 95], int8=int8)
+    finally:
+        pa.paged_decode_attention.clear_cache()
+
+
+@pytest.mark.window
+def test_lengths_around_the_kernels_own_block_boundary():
+    """The same at the depth the kernel chooses for 128-token pages of
+    this width, eight pages: live lengths 1,023, 1,024 and 1,025."""
+    from kvedge_tpu.ops.paged_attention import block_pages
+
+    assert block_pages(10, 128, 128) == 8
+    _assert_dead_rows_do_nothing([1022, 1023, 1024], int8=False, page=128)
+
+
+def test_block_depth_follows_page_bytes_and_cap():
+    """Landing pads are a fixed share of the scratch budget: 8 pages a
+    block at the benchmark cell's 64 KB pages, 4 at an MHA width's 128
+    KB, 8 for an int8 pool of that width, never more than a row has."""
+    from kvedge_tpu.ops.paged_attention import (
+        block_pages, decode_scratch_fits_vmem,
+    )
+
+    assert block_pages(24, 128, 256) == 8
+    assert block_pages(16, 128, 512) == 4
+    assert block_pages(16, 128, 512, itemsize=1) == 8
+    assert block_pages(3, 128, 256) == 3
+    assert block_pages(1, 16, 128) == 1
+    assert decode_scratch_fits_vmem(24, 128, 256, 24)
+
+
+@pytest.mark.window
+@pytest.mark.parametrize("kv_dtype", ["", "int8"], ids=["bf16", "int8"])
+def test_half_prefilled_slot_is_a_dead_row_of_the_step(params, kv_dtype):
+    """A half-prefilled slot is inactive but carries its final length
+    (``_decode_step_core``): the kernel is told, reads none of its
+    pages, and the decoding row beside it gets the logits, bit for bit,
+    that it gets under the gather; the slot's length stays, its logits
+    are finite, and once its prefill is finished both rows decode to
+    the gather's tokens."""
+    prompts = [[5, 9, 2, 7, 7, 1], [3, 3, 8, 1, 4, 4, 6, 2, 9]]
+
+    def run(cfg):
+        cache = PagedKVCache(cfg, slots=2, pages=16, page_size=4,
+                             kv_dtype=kv_dtype)
+        cache.admit(0, len(prompts[0]))
+        first = cache.prefill(params, 0, jnp.asarray(prompts[0], jnp.int32))
+        cache.admit(1, len(prompts[1]))
+        cache.prefill_chunk(params, 1, jnp.asarray(prompts[1][:4],
+                                                   jnp.int32), 0)
+        tokens = np.asarray([int(jnp.argmax(first)), 0], np.int32)
+        logits = np.asarray(cache.step(
+            params, jnp.asarray(tokens), active=np.array([True, False])
+        ).astype(jnp.float32))
+        lengths = np.asarray(cache.state.lengths).tolist()
+        last = cache.prefill_chunk(
+            params, 1, jnp.asarray(prompts[1][4:], jnp.int32), 4)
+        tokens = np.asarray([int(np.argmax(logits[0])),
+                             int(jnp.argmax(last))], np.int32)
+        after = cache.harvest_window(
+            cache.dispatch_window(params, tokens, 6))[:6]
+        return logits, lengths, np.asarray(after).tolist()
+
+    gather_logits, gather_lengths, gather_tokens = run(CFG)
+    logits, lengths, tokens = run(KERNEL_CFG)
+    np.testing.assert_array_equal(logits[0].view(np.uint32),
+                                  gather_logits[0].view(np.uint32))
+    assert np.isfinite(logits[1]).all()
+    assert lengths == gather_lengths == [len(prompts[0]) + 1,
+                                         len(prompts[1])]
+    assert tokens == gather_tokens
 
 
 def _greedy_tokens(cfg, params, prompts, n_new):
