@@ -1,8 +1,8 @@
 """The StarCoder2 block: its plain reference and its counts.
 
 Everything the benchmark believes about this block's mathematics is in
-this file, behind the three functions ``cellspec.py`` asks of a block's
-file: ``make_weights``, ``logits`` and ``decode_step``.
+this file, behind the four functions ``cellspec.py`` asks of a block's
+file: ``model_of``, ``make_weights``, ``logits`` and ``decode_step``.
 
 The reference is the configuration's forward pass in float32:
 straightforward ``jax.numpy``, ``default_matmul_precision("highest")``,
@@ -33,6 +33,20 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 WEIGHT_SEED = 0
 BF16 = 2
+
+
+def model_of(config: dict) -> dict:
+    """The program's ``[model]`` from the published keys: the one place
+    that says which of the program's sizes each is. The published keys
+    this leaves unread (``rope_theta``, ``use_bias``, ``norm_type``,
+    ``sliding_window``, ...) are ones the program's block cannot be told:
+    the configuration's ``departures`` names each."""
+    return {"vocab": config["vocab_size"],
+            "d_model": config["hidden_size"],
+            "n_heads": config["num_attention_heads"],
+            "n_kv_heads": config["num_key_value_heads"],
+            "n_layers": config["num_hidden_layers"],
+            "d_ff": config["intermediate_size"]}
 
 
 def _shapes(model: dict) -> dict:

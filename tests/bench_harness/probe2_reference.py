@@ -27,6 +27,20 @@ BF16 = 2
 CALLS: list = []
 
 
+def model_of(config: dict) -> dict:
+    """The program's ``[model]`` from the published keys: the experts this
+    chip holds (``num_local_experts`` as reduced) are the program's
+    ``experts``, the experts a token takes its ``expert_top_k``."""
+    return {"vocab": config["vocab_size"],
+            "d_model": config["hidden_size"],
+            "n_heads": config["num_attention_heads"],
+            "n_kv_heads": config["num_key_value_heads"],
+            "n_layers": config["num_hidden_layers"],
+            "d_ff": config["intermediate_size"],
+            "experts": config["num_local_experts"],
+            "expert_top_k": config["num_experts_per_tok"]}
+
+
 def _sizes(model: dict) -> tuple:
     d, h, kv = model["d_model"], model["n_heads"], model["n_kv_heads"]
     return (d, h, kv, d // h, model["d_ff"], model["n_layers"],
