@@ -176,6 +176,42 @@ class ModelSpec:
     # O(stages) activation stash (dense models, standard attention —
     # parallel/pipeline1f1b.py documents the refusals).
     pipeline_schedule: str = ""
+    # A patterned block (models/hybrid.py; SERVING.md "Recurrent
+    # state"): one period of layer kinds, "mamba" or "attention",
+    # repeated to ``n_layers``. () = every layer rotary attention with a
+    # GELU feed-forward, the block above. With a pattern every layer's
+    # feed-forward is ``experts`` routed experts of width ``d_ff``,
+    # ``expert_top_k`` (any number) a token, plus a shared expert of
+    # width ``shared_ff``, SiLU-gated when ``ffn_gated``; this device
+    # holds ``experts_held`` of them (0 = all) from ``expert_first`` on.
+    # Served by ``serving = "paged"`` on one device, nothing else.
+    layer_pattern: tuple = ()
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_conv: int = 0   # 0 = 4
+    ssm_chunk: int = 0  # 0 = 256
+    experts_held: int = 0
+    expert_first: int = 0
+    shared_ff: int = 0
+    ffn_gated: bool = False
+    # 0.0 = the plain block's: 1, 1, 1/sqrt(d_head), 1.
+    embedding_multiplier: float = 0.0
+    residual_multiplier: float = 0.0
+    attention_multiplier: float = 0.0
+    logits_scaling: float = 0.0
+    rotary: bool = True
+    norm_eps: float = 0.0  # 0.0 = 1e-6
+
+    _PATTERN_INTS = (
+        "ssm_heads", "ssm_head_dim", "ssm_state", "ssm_conv", "ssm_chunk",
+        "experts_held", "expert_first", "shared_ff",
+    )
+    _PATTERN_FLOATS = (
+        "embedding_multiplier", "residual_multiplier",
+        "attention_multiplier", "logits_scaling", "norm_eps",
+    )
+    _PATTERN_KEYS = _PATTERN_INTS + ("ffn_gated",) + _PATTERN_FLOATS
 
     def validate(self) -> None:
         if self.preset not in _VALID_PRESETS:
@@ -197,10 +233,31 @@ class ModelSpec:
                 "[model] expert_capacity_factor must be >= 0 "
                 "(0 = drop-free capacity)"
             )
-        if self.expert_top_k not in (0, 1, 2):
+        if self.expert_top_k not in (0, 1, 2) and not self.layer_pattern:
             raise RuntimeConfigError(
                 "[model] expert_top_k must be 1 or 2 (0 = default 1)"
             )
+        if not all(isinstance(kind, str) for kind in self.layer_pattern):
+            raise RuntimeConfigError(
+                "[model] layer_pattern must be a list of \"mamba\" and "
+                "\"attention\"")
+        for field_name in self._PATTERN_INTS:
+            value = getattr(self, field_name)
+            if not isinstance(value, int) or isinstance(value, bool) \
+                    or value < 0:
+                raise RuntimeConfigError(
+                    f"[model] {field_name} must be a non-negative int")
+        for field_name in self._PATTERN_FLOATS:
+            if getattr(self, field_name) < 0:
+                raise RuntimeConfigError(
+                    f"[model] {field_name} must be >= 0 (0 = the plain "
+                    "block's)")
+        if not self.layer_pattern:
+            stray = [k for k in self._PATTERN_KEYS if getattr(self, k)]
+            if stray or not self.rotary:
+                raise RuntimeConfigError(
+                    "[model] " + ", ".join(stray or ["rotary = false"])
+                    + " belong to a patterned block: set layer_pattern")
         if self.pipeline_schedule not in ("", "gpipe", "1f1b"):
             raise RuntimeConfigError(
                 "[model] pipeline_schedule must be 'gpipe' or '1f1b' "
@@ -596,6 +653,14 @@ class RuntimeConfig:
                         model_doc.get("pipeline_schedule",
                                       ModelSpec.pipeline_schedule)
                     ),
+                    layer_pattern=tuple(
+                        model_doc.get("layer_pattern", ())),
+                    ffn_gated=bool(model_doc.get("ffn_gated", False)),
+                    rotary=bool(model_doc.get("rotary", True)),
+                    **{key: int(model_doc.get(key, 0))
+                       for key in ModelSpec._PATTERN_INTS},
+                    **{key: float(model_doc.get(key, 0.0))
+                       for key in ModelSpec._PATTERN_FLOATS},
                 ),
                 distributed=DistributedSpec(
                     num_processes=int(
@@ -1060,6 +1125,8 @@ class RuntimeConfig:
                 "[payload] serving_occupancy_ring must be >= 0 "
                 "(0 = off; otherwise the ring depth in samples)"
             )
+        if self.model.layer_pattern:
+            self._validate_pattern_payload()
         if self.payload == "train" and not self.train_corpus:
             raise RuntimeConfigError(
                 "[payload] kind = 'train' requires corpus = '<path>' "
@@ -1082,6 +1149,49 @@ class RuntimeConfig:
         self.mesh.validate()
         self.model.validate()
         self.distributed.validate()
+
+    def _validate_pattern_payload(self) -> None:
+        """What cannot run a block with ``[model] layer_pattern``: it
+        is written once, in the paged serving path, and its recurrent
+        state is a row's whole past in one fixed-size array."""
+        if self.payload in ("train", "eval", "transformer-probe",
+                            "inference-probe"):
+            raise RuntimeConfigError(
+                f"[payload] kind = {self.payload!r} cannot run a model "
+                "with [model] layer_pattern: the patterned block exists "
+                "in the paged serving path only (kind = \"serve\", "
+                "serving = \"paged\")")
+        if self.payload == "serve" and self.payload_serving != "paged":
+            raise RuntimeConfigError(
+                "[model] layer_pattern needs [payload] serving = "
+                "\"paged\": the contiguous cache has no patterned block")
+        if self.serving_prefix_cache:
+            raise RuntimeConfigError(
+                "[payload] serving_prefix_cache = true cannot serve a "
+                "model with [model] layer_pattern: a recurrent state "
+                "holds a row's whole prefix in one array and cannot be "
+                "shared by page; set serving_prefix_cache = false")
+        if self.serving_speculative != 0:
+            raise RuntimeConfigError(
+                "[payload] serving_speculative must be 0 for a model "
+                "with [model] layer_pattern: a recurrent state cannot "
+                "be rewound past the drafts a verify pass rejects")
+
+    def _pattern_toml(self) -> str:
+        """The patterned block's ``[model]`` keys; nothing for the plain
+        block, whose document stays as it was."""
+        m = self.model
+        if not m.layer_pattern:
+            return ""
+        kinds = ", ".join(_toml_str(kind) for kind in m.layer_pattern)
+        lines = [f"layer_pattern = [{kinds}]",
+                 f"rotary = {str(m.rotary).lower()}"]
+        for key in m._PATTERN_KEYS:
+            value = getattr(m, key)
+            lines.append(f"{key} = "
+                         + (str(value).lower() if isinstance(value, bool)
+                            else repr(value)))
+        return "\n".join(lines) + "\n"
 
     def to_toml(self) -> str:
         """Serialize back to TOML (the form written by ``config apply``).
@@ -1115,6 +1225,7 @@ class RuntimeConfig:
             f"expert_top_k = {self.model.expert_top_k}\n"
             f"expert_capacity_factor = {self.model.expert_capacity_factor}\n"
             f"pipeline_schedule = {s(self.model.pipeline_schedule)}\n"
+            + self._pattern_toml() +
             "\n[distributed]\n"
             f"num_processes = {self.distributed.num_processes}\n"
             f"coordinator_address = {s(self.distributed.coordinator_address)}\n"

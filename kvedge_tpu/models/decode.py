@@ -35,6 +35,7 @@ from kvedge_tpu.models.transformer import (
     TransformerConfig,
     _rmsnorm,
     _rotary,
+    refuse_pattern,
     split_qkv,
     stacked_layer_params,
     tied_readout,
@@ -64,6 +65,7 @@ def init_cache(cfg: TransformerConfig, batch: int,
     from kvedge_tpu.models.moe import warn_if_train_serve_divergence
 
     cfg.validate()
+    refuse_pattern(cfg, "the contiguous cache (models/decode.py)")
     warn_if_train_serve_divergence(cfg)
     shape = (
         cfg.n_layers, batch, max_seq or cfg.max_seq, cfg.kv_heads, cfg.d_head,

@@ -1,0 +1,329 @@
+"""The patterned block: Mamba-2 and attention mixers in a repeating
+pattern of layers, every layer with routed and shared gated experts.
+
+Written once, for the paged serving path (models/kvcache.py runs it
+from ``_run_paged``); the trainer, the contiguous cache and a mesh of
+several devices refuse a model with a ``layer_pattern``. The equations
+(``e``, ``r``, ``s`` are ``cfg.embedding_multiplier``,
+``residual_multiplier``, ``logits_scaling``; RMSNorm with a gain and
+``cfg.norm_eps``):
+
+    x = e * E[tokens]
+    per layer:  x = x + r * Mixer(norm(x));  h = norm(x)
+                x = x + r * (Routed(h) + Shared(h))
+    logits = norm(x) @ E.T / s
+
+``Mixer`` is models/ssm.py's Mamba-2 mixer or kvcache's paged attention
+(no rotary when ``cfg.rotary`` is false, scores scaled by
+``cfg.attention_multiplier``); ``Routed`` is moe.held_experts_ffn over
+the experts this device holds; ``Shared`` a gated SiLU MLP every token
+passes.
+
+**Weights** are a named tree per layer kind, every leaf stacked over
+``[periods, layers of that kind in a period, ...]``:
+``params["mamba"]``, ``params["attention"]``, ``params["ffn"]`` (one
+entry per layer of the period), beside ``embedding`` and ``ln_final``.
+The layer loop scans over periods and runs the period's layers in its
+body, so one period's program is compiled whatever the depth.
+
+**The initialiser draws leaf by leaf, layer by layer, in the serving
+dtype** (:func:`init_params`): each leaf is one jitted call that maps
+over its layers, each layer from its own key, so no float32 copy of
+the tree, nor of one stacked leaf, ever stands on the device (the
+float32 tree of the benchmark's configuration is 19 GB). The recipe,
+which the benchmark's reference copies: every draw is float32 from
+``fold_in(fold_in(PRNGKey(seed), leaf number), layer)``, an expert's
+from that key folded with its global index; matrices are normal times
+fan-in ** -0.5, the conv's bias normal times 0.02, gains one; the
+embedding is normal times 0.02 / ``embedding_multiplier``, so that the
+residual stream starts at the 0.02 it starts at in the plain block (at
+0.02 itself the tied head's logit for the token just read stood 15
+standard deviations above every other's, 12 * 0.02 * sqrt(4096), and
+a greedy row repeated its prompt's last token for ever: nothing a
+check of served tokens could tell one precision from another by); for the SSM the Mamba-2 paper's own: ``A`` uniform in
+[1, 16) (``A_log`` its log), ``dt`` log-uniform in [0.001, 0.1) and
+``dt_bias`` its inverse softplus, ``D`` one.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from kvedge_tpu.models.moe import ffn_activation, held_experts_ffn
+from kvedge_tpu.models.ssm import mamba_mixer
+from kvedge_tpu.models.transformer import TransformerConfig, _rmsnorm
+
+# Leaf numbers of the recipe: a leaf keeps its number when others are
+# added after it, so a tree drawn today is drawn again tomorrow.
+_LEAVES = {
+    "embedding": 0,
+    ("mamba", "w_in"): 1, ("mamba", "conv_w"): 2, ("mamba", "conv_b"): 3,
+    ("mamba", "A_log"): 4, ("mamba", "dt_bias"): 5, ("mamba", "w_out"): 6,
+    ("attention", "w_qkv"): 7, ("attention", "w_out"): 8,
+    ("ffn", "router"): 9, ("ffn", "experts_in"): 10,
+    ("ffn", "experts_out"): 11, ("ffn", "shared_in"): 12,
+    ("ffn", "shared_out"): 13,
+}
+
+
+def layers_of(cfg: TransformerConfig, kind: str) -> list[int]:
+    """Global indices of the layers of ``kind`` ("ffn": every layer)."""
+    period = len(cfg.layer_pattern)
+    return [i for i in range(cfg.n_layers)
+            if kind == "ffn" or cfg.layer_pattern[i % period] == kind]
+
+
+def _normal(scale):
+    return lambda key, shape: jax.random.normal(key, shape,
+                                                jnp.float32) * scale
+
+
+def _recipes(cfg: TransformerConfig) -> dict:
+    """(kind, leaf) -> (one layer's shape, draw(key, shape) -> float32,
+    whether the leading dimension is one key per held expert)."""
+    d, f, sf = cfg.d_model, cfg.d_ff, cfg.shared_ff
+    h, kv, dh = cfg.n_heads, cfg.kv_heads, cfg.d_head
+    heads, n, k = cfg.ssm_heads, cfg.ssm_state, cfg.ssm_conv
+    inner, conv_dim = cfg.ssm_inner, cfg.ssm_conv_dim
+    gate = 2 if cfg.ffn_gated else 1
+
+    def a_log(key, shape):
+        return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0,
+                                          16.0))
+
+    def dt_bias(key, shape):
+        dt = jnp.exp(jax.random.uniform(
+            key, shape, jnp.float32, math.log(1e-3), math.log(1e-1)))
+        return dt + jnp.log(-jnp.expm1(-dt))  # softplus(dt_bias) == dt
+
+    out = {}
+    if "mamba" in cfg.layer_pattern:
+        out.update({
+            ("mamba", "w_in"): ((d, 2 * inner + 2 * n + heads),
+                                _normal(d ** -0.5), False),
+            ("mamba", "conv_w"): ((k, conv_dim), _normal(k ** -0.5), False),
+            ("mamba", "conv_b"): ((conv_dim,), _normal(0.02), False),
+            ("mamba", "A_log"): ((heads,), a_log, False),
+            ("mamba", "dt_bias"): ((heads,), dt_bias, False),
+            ("mamba", "w_out"): ((inner, d), _normal(inner ** -0.5), False),
+        })
+    if "attention" in cfg.layer_pattern:
+        out.update({
+            ("attention", "w_qkv"): ((d, (h + 2 * kv) * dh),
+                                     _normal(d ** -0.5), False),
+            ("attention", "w_out"): ((h * dh, d),
+                                     _normal((h * dh) ** -0.5), False),
+        })
+    out.update({
+        ("ffn", "router"): ((d, cfg.n_experts), _normal(d ** -0.5), False),
+        ("ffn", "experts_in"): ((cfg.held_experts, d, gate * f),
+                                _normal(d ** -0.5), True),
+        ("ffn", "experts_out"): ((cfg.held_experts, f, d),
+                                 _normal(f ** -0.5), True),
+    })
+    if sf:
+        out.update({
+            ("ffn", "shared_in"): ((d, gate * sf), _normal(d ** -0.5),
+                                   False),
+            ("ffn", "shared_out"): ((sf, d), _normal(sf ** -0.5), False),
+        })
+    return out
+
+
+# Leaves the equations read in float32 (transformer.serving_params
+# names the ones held in the compute dtype).
+_FLOAT32 = frozenset({"router", "A_log", "dt_bias"})
+
+
+def init_params(key, cfg: TransformerConfig) -> dict:
+    """The patterned block's tree, each leaf in the dtype serving reads
+    it in (module docstring). ``key`` is ``PRNGKey(seed)``."""
+    cfg.validate()
+    if not cfg.layer_pattern:
+        raise ValueError("hybrid.init_params draws a patterned block's "
+                         "tree: cfg.layer_pattern is empty")
+    dtype = jnp.dtype(cfg.dtype)
+    periods = cfg.n_layers // len(cfg.layer_pattern)
+    first = cfg.expert_first
+
+    def stacked(kind, leaf, shape, draw, per_expert):
+        leaf_key = jax.random.fold_in(key, _LEAVES[kind, leaf])
+        layers = jnp.asarray(layers_of(cfg, kind), jnp.int32)
+        to = jnp.float32 if leaf in _FLOAT32 else dtype
+
+        def one_layer(layer):
+            k = jax.random.fold_in(leaf_key, layer)
+            if per_expert:
+                ks = jax.vmap(lambda e: jax.random.fold_in(k, e))(
+                    first + jnp.arange(shape[0]))
+                return jax.vmap(lambda ke: draw(ke, shape[1:]))(ks).astype(to)
+            return draw(k, shape).astype(to)
+
+        flat = jax.jit(lambda ls: lax.map(one_layer, ls))(layers)
+        return jax.block_until_ready(
+            flat.reshape(periods, len(layers) // periods, *shape))
+
+    params: dict = {"mamba": {}, "attention": {}, "ffn": {}}
+    for (kind, leaf), recipe in _recipes(cfg).items():
+        params[kind][leaf] = stacked(kind, leaf, *recipe)
+
+    def ones(kind, leaf, width):
+        n = len(layers_of(cfg, kind)) // periods
+        params[kind][leaf] = jnp.ones((periods, n, width), jnp.float32)
+
+    if params["mamba"]:
+        ones("mamba", "D", cfg.ssm_heads)
+        ones("mamba", "norm", cfg.ssm_inner)
+        ones("mamba", "ln", cfg.d_model)
+    if params["attention"]:
+        ones("attention", "ln", cfg.d_model)
+    ones("ffn", "ln", cfg.d_model)
+    scale = 0.02 / cfg.embedding_multiplier
+    params["embedding"] = jax.jit(
+        lambda k: (jax.random.normal(k, (cfg.vocab, cfg.d_model),
+                                     jnp.float32) * scale).astype(dtype)
+    )(jax.random.fold_in(key, _LEAVES["embedding"]))
+    params["ln_final"] = jnp.ones((cfg.d_model,), jnp.float32)
+    return {name: leaf for name, leaf in params.items() if len(leaf)}
+
+
+def _shared_expert(cfg: TransformerConfig, h, w_in, w_out):
+    act = ffn_activation(h @ w_in.astype(h.dtype), cfg.ffn_gated)
+    return act @ w_out.astype(h.dtype)
+
+
+def feed_forward(cfg: TransformerConfig, x, w: dict, live):
+    """``x + r * (Routed(norm(x)) + Shared(norm(x)))`` over x [R, Q, D]
+    and the picks of the ``live`` rows' tokens (held_experts_ffn)."""
+    rows, q_len, d = x.shape
+    with jax.named_scope("kvedge/experts"):
+        h = _rmsnorm(x, w["ln"], cfg.norm_eps).reshape(rows * q_len, d)
+        out, picks = held_experts_ffn(
+            h, w["router"], w["experts_in"], w["experts_out"],
+            top_k=cfg.expert_top_k, first=cfg.expert_first,
+            gated=cfg.ffn_gated, renormalize=True,
+            live=None if live is None else jnp.repeat(live, q_len))
+        if "shared_in" in w:
+            out = out + _shared_expert(cfg, h, w["shared_in"],
+                                       w["shared_out"])
+        r = jnp.asarray(cfg.residual_multiplier, x.dtype)
+        return x + r * out.reshape(rows, q_len, d), picks
+
+
+def run_layers(cfg: TransformerConfig, params: dict, x, pools, recurrent,
+               attend, slot, live):
+    """The layer loop of a patterned block: a scan over periods, the
+    period's layers in its body. ``pools`` (the page pool's 4-tuple)
+    and ``recurrent`` (``ssm`` [mamba layers, slots, H * P, N], ``conv``
+    [mamba layers, slots, (K-1)*C], ``picks``) ride the carry whole and
+    are updated in place. ``attend(h, w, layer, pools) -> (out, pools)``
+    is the caller's paged attention over normed activations, ``layer``
+    its index into the pool. ``x``'s rows are the first R slots, or,
+    with ``slot`` given, that one prefilling slot; ``live`` [R] says
+    which of them advance (None = all). Returns
+    ``(x, pools, recurrent)``.
+    """
+    pattern = cfg.layer_pattern
+    n_mamba, n_att = pattern.count("mamba"), pattern.count("attention")
+    r = jnp.asarray(cfg.residual_multiplier, x.dtype)
+
+    def at(tree, i):
+        return jax.tree_util.tree_map(lambda a: a[i], tree)
+
+    if slot is None:
+        n_rows = x.shape[0]
+
+        def take(state, layer):
+            return state[layer, :n_rows]
+
+        def put(state, layer, new):
+            return state.at[layer, :n_rows].set(new)
+    else:
+        def take(state, layer):
+            return state[layer, slot][None]
+
+        def put(state, layer, new):
+            return state.at[layer, slot].set(new[0])
+
+    def body(carry, xs):
+        x, pools, ssm, conv, picks = carry
+        weights, period = xs
+        seen = {"mamba": 0, "attention": 0}
+        for j, kind in enumerate(pattern):
+            w = at(weights[kind], seen[kind])
+            h = _rmsnorm(x, w["ln"], cfg.norm_eps)
+            if kind == "mamba":
+                layer = period * n_mamba + seen[kind]
+                with jax.named_scope("kvedge/ssm"):
+                    out, new_ssm, new_tail = mamba_mixer(
+                        cfg, h, w, take(ssm, layer), take(conv, layer),
+                        live)
+                    ssm = put(ssm, layer, new_ssm)
+                    conv = put(conv, layer, new_tail)
+            else:
+                with jax.named_scope("kvedge/attention"):
+                    out, pools = attend(
+                        h, w, period * n_att + seen[kind], pools)
+            seen[kind] += 1
+            x = x + r * out
+            x, layer_picks = feed_forward(cfg, x, at(weights["ffn"], j),
+                                          live)
+            picks = picks + layer_picks
+        return (x, pools, ssm, conv, picks), None
+
+    periods = cfg.n_layers // len(pattern)
+    weights = {kind: params.get(kind, {})
+               for kind in ("mamba", "attention", "ffn")}
+    (x, pools, ssm, conv, picks), _ = lax.scan(
+        body,
+        (x, pools, recurrent["ssm"], recurrent["conv"],
+         recurrent["picks"]),
+        (weights, jnp.arange(periods, dtype=jnp.int32)),
+    )
+    return x, pools, {"ssm": ssm, "conv": conv, "picks": picks}
+
+
+def fresh_recurrent(cfg: TransformerConfig, slots: int) -> dict:
+    """Zeroed recurrent state for ``slots`` rows: float32 SSM state,
+    the conv's tail in the compute dtype, flat so that its minor
+    dimension is whole lanes, and the window's pick counters."""
+    layers = cfg.ssm_layers
+    return {
+        "ssm": jnp.zeros((layers, slots, cfg.ssm_inner, cfg.ssm_state),
+                         jnp.float32),
+        "conv": jnp.zeros((layers, slots,
+                           (cfg.ssm_conv - 1) * cfg.ssm_conv_dim),
+                          jnp.dtype(cfg.dtype)),
+        "picks": jnp.zeros((2 + cfg.held_experts,), jnp.int32),
+    }
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def reset_rows(recurrent: dict, slot):
+    """Zero slot ``slot``'s state in every layer (admission)."""
+    return {
+        "ssm": recurrent["ssm"].at[:, slot].set(0.0),
+        "conv": recurrent["conv"].at[:, slot].set(0),
+        "picks": recurrent["picks"],
+    }
+
+
+@jax.jit
+def gather_rows(recurrent: dict, slot):
+    """Fresh copies of slot ``slot``'s state, as stored."""
+    return recurrent["ssm"][:, slot], recurrent["conv"][:, slot]
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def scatter_rows(recurrent: dict, slot, ssm, conv):
+    """Write :func:`gather_rows`' arrays back into slot ``slot``."""
+    return {
+        "ssm": recurrent["ssm"].at[:, slot].set(ssm),
+        "conv": recurrent["conv"].at[:, slot].set(conv),
+        "picks": recurrent["picks"],
+    }

@@ -26,6 +26,7 @@ paged and contiguous decoding agree bit-for-bit on the same prompts.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 
@@ -73,6 +74,12 @@ class PagedState:
     lengths: jax.Array  # [B] int32 valid positions per sequence
     scale_k: "jax.Array | None" = None  # [L, P, page, K] fp32 (int8 only)
     scale_v: "jax.Array | None" = None
+    # A patterned block's state of the second kind (models/hybrid.py
+    # fresh_recurrent): per mamba layer and SLOT (not bucket row: it
+    # cannot be rebuilt from the host as tables are) the float32 SSM
+    # state and the conv's tail, and the window's expert-pick counters.
+    # The pool then holds the attention layers only. None otherwise.
+    recurrent: "dict | None" = None
 
     @property
     def page_size(self) -> int:
@@ -277,8 +284,21 @@ class PagedKVCache:
                     "pool/page geometry or use 'auto'/'gather'"
                 )
         dtype = jnp.int8 if self.kv_quantized else jnp.dtype(cfg.dtype)
-        shape = (cfg.n_layers, pages, page_size, cfg.kv_heads * cfg.d_head)
+        shape = (cfg.kv_layers, pages, page_size, cfg.kv_heads * cfg.d_head)
         self.state = self._init_state(shape, dtype)
+        # What the window programs counted of the routed experts' picks
+        # (a patterned block): [all, on held experts, each held expert],
+        # summed at harvest from what each window returns beside its
+        # tokens (``_picks_of``: the windows not harvested yet).
+        self.expert_picks = None
+        self._picks_of: dict = {}
+        if cfg.layer_pattern:
+            import numpy as _np
+
+            self.expert_picks = _np.zeros(2 + cfg.held_experts, _np.int64)
+        # A phase of the serving layer's clock around the state reset
+        # of an admission (``admit/state_reset``), when it has one.
+        self.reset_phase = contextlib.nullcontext
         self._free: list[int] = list(range(pages))[::-1]  # pop() -> lowest last
         self._pages_of: dict[int, list[int]] = {}
         self._host_tables = [
@@ -356,7 +376,15 @@ class PagedKVCache:
             lengths=jnp.zeros((self.bucket,), jnp.int32),
             scale_k=scale(),
             scale_v=scale(),
+            recurrent=self._init_recurrent(),
         )
+
+    def _init_recurrent(self):
+        if not self.cfg.layer_pattern:
+            return None
+        from kvedge_tpu.models.hybrid import fresh_recurrent
+
+        return fresh_recurrent(self.cfg, self.slots)
 
     # ---- bucketed device batch dim --------------------------------------
 
@@ -580,6 +608,20 @@ class PagedKVCache:
             row[i] = page
         self._host_lengths[slot] = prompt_len
         self._sync()
+        if self.state.recurrent is not None:
+            # Whoever had the slot left its state there (release needs
+            # nothing): a row starts from zeros, or from what
+            # swapin_row writes over them.
+            with self.reset_phase():
+                self._device_reset_row(slot)
+
+    def _device_reset_row(self, slot: int) -> None:
+        """Device seam: zero one slot's recurrent state."""
+        from kvedge_tpu.models.hybrid import reset_rows
+
+        self.state = dataclasses.replace(
+            self.state, recurrent=reset_rows(
+                self.state.recurrent, jnp.asarray(slot, jnp.int32)))
 
     def grow(self, slot: int) -> bool:
         """Ensure the slot can hold one more token, allocating a page at a
@@ -760,6 +802,51 @@ class PagedKVCache:
                 "(kv_dtype mismatch between swap-out and swap-in?)"
             )
         self._device_swapin(ids, arrays)
+
+    def row_state_bytes(self) -> int:
+        """Bytes of one slot's recurrent state (0 where none is kept):
+        what :meth:`swapout_row` adds to a swap snapshot."""
+        rec = self.state.recurrent
+        if rec is None:
+            return 0
+        return (rec["ssm"].nbytes + rec["conv"].nbytes) // self.slots
+
+    def swapout_row(self, slot: int) -> tuple:
+        """Host copies of slot ``slot``'s recurrent state exactly as
+        stored (``()`` for a block that keeps none): what travels with
+        :meth:`swapout_pages`' arrays when a row is preempted or
+        journaled, and comes back through :meth:`swapin_slot`. A row is
+        never resumed on a zero state: its state is its past, as its
+        pages are."""
+        import numpy as np
+
+        if self.state.recurrent is None:
+            return ()
+        from kvedge_tpu.models.hybrid import gather_rows
+
+        return tuple(np.asarray(a) for a in gather_rows(
+            self.state.recurrent, jnp.asarray(slot, jnp.int32)))
+
+    def swapin_slot(self, slot: int, arrays: tuple,
+                    skip_pages: int = 0) -> None:
+        """Write a swap snapshot back into freshly admitted ``slot``:
+        :meth:`swapout_pages`' arrays into its pages from
+        ``skip_pages`` on and, where the snapshot carries them,
+        :meth:`swapout_row`'s into its recurrent state."""
+        n = 4 if self.kv_quantized else 2
+        self.swapin_pages(self.slot_pages(slot)[skip_pages:], arrays[:n])
+        if self.state.recurrent is not None:
+            if len(arrays) != n + 2:
+                raise PagedCacheError(
+                    f"swap snapshot carries {len(arrays)} arrays; a "
+                    f"block with recurrent state needs {n + 2} (a row "
+                    "cannot resume on a zero state)")
+            from kvedge_tpu.models.hybrid import scatter_rows
+
+            self.state = dataclasses.replace(
+                self.state, recurrent=scatter_rows(
+                    self.state.recurrent, jnp.asarray(slot, jnp.int32),
+                    *(jnp.asarray(a) for a in arrays[n:])))
 
     def cow_page(self, slot: int, index: int) -> int | None:
         """Copy-on-write divergence for table position ``index`` of
@@ -1047,7 +1134,22 @@ class PagedKVCache:
         window is already queued behind it (the overlap)."""
         import numpy as _np
 
+        picks = self._picks_of.pop(id(handle), None)
+        if picks is not None:
+            self.expert_picks += _np.asarray(picks)
         return _np.asarray(handle)
+
+    def _note_window(self, out, n_steps: int):
+        """Keep a dispatched window's carry; a patterned block's window
+        returns its pick counters beside its tokens, kept until the
+        tokens are harvested."""
+        if self.state.recurrent is not None:
+            toks, picks = out
+            self._picks_of[id(toks)] = picks
+        else:
+            toks = out
+        self._carry = (toks, n_steps)
+        return toks
 
     def _carry_tokens(self):
         if self._carry is None:
@@ -1067,6 +1169,7 @@ class PagedKVCache:
         self._carry = None
         self._spec_carry = None
         self._spec_unharvested = [0] * self.slots
+        self._picks_of.clear()
         # The operand memo holds device arrays from the same stream
         # the carries rode — a revived pool must re-upload.
         self._dev_memo.clear()
@@ -1084,7 +1187,7 @@ class PagedKVCache:
         act = (self._active_array(self.state, active)
                if active is None else
                self._dev_const("w_act", _np.asarray(active, bool)))
-        toks, self.state = _paged_decode_window_capped(
+        out, self.state = _paged_decode_window_capped(
             params, self.state, toks_in, self.cfg, n_steps,
             act,
             self._dev_const("w_caps",
@@ -1092,8 +1195,7 @@ class PagedKVCache:
             self._dev_const("w_stops",
                             _np.asarray(stop_tokens, _np.int32)),
         )
-        self._carry = (toks, n_steps)
-        return toks
+        return self._note_window(out, n_steps)
 
     def _device_window_sampled_dispatch(self, params, tokens,
                                         n_steps: int, active, key_data,
@@ -1111,7 +1213,7 @@ class PagedKVCache:
         act = (self._active_array(self.state, active)
                if active is None else
                self._dev_const("ws_act", _np.asarray(active, bool)))
-        toks, self.state = _paged_decode_window_sampled_capped(
+        out, self.state = _paged_decode_window_sampled_capped(
             params, self.state, toks_in, self.cfg, n_steps,
             act,
             jnp.asarray(_np.asarray(key_data, _np.uint32)),
@@ -1127,8 +1229,7 @@ class PagedKVCache:
             self._dev_const("ws_stops",
                             _np.asarray(stop_tokens, _np.int32)),
         )
-        self._carry = (toks, n_steps)
-        return toks
+        return self._note_window(out, n_steps)
 
     def step_spec(self, params, tokens, active, spec_mask):
         """One speculative verify pass (see :func:`_spec_verify_core`).
@@ -1500,35 +1601,66 @@ def _paged_attend_layer(cfg: TransformerConfig, state: PagedState, x,
                         layer_params, layer, pools, q_positions, slot=None,
                         write_mask=None):
     """Shared block body. x: [B, Q, D]; q_positions: [B, Q] absolute
-    positions of the new tokens. ``pools`` is the WHOLE pool
-    ``(pool_k, pool_v, scale_k, scale_v)`` as the layer loop carries
-    it, ``layer`` this block's index into it: the block writes its new
-    rows at ``[layer, page, offset]`` and reads ``pool[layer]`` where
-    it lies; it returns the updated pools. ``state`` supplies tables
-    and lengths only. ``slot`` non-None = single-sequence
-    prefill (B == 1 view of that slot). ``write_mask`` [B, Q] bool
-    (batched paths only) gates which query offsets persist K/V — the
-    speculative verify pass drops sampled rows' draft-position writes so
-    those rows need no slack pages; None = every offset writes."""
+    positions of the new tokens. ``pools``, ``layer``, ``slot`` and
+    ``write_mask`` are :func:`_paged_attention`'s; returns the block's
+    output and the updated pools."""
     if cfg.n_experts:
         w_qkv, w_out, router, w_up, w_down, ln_attn, ln_mlp = layer_params
     else:
         w_qkv, w_out, w_up, w_down, ln_attn, ln_mlp = layer_params
-    batch, q_len, _ = x.shape
+    dtype = x.dtype
+    attended, pools = _paged_attention(
+        cfg, state, _rmsnorm(x, ln_attn), w_qkv, w_out, layer, pools,
+        q_positions, slot, write_mask)
+    x = x + attended
+
+    normed = _rmsnorm(x, ln_mlp)
+    if cfg.n_experts:
+        from kvedge_tpu.models.moe import routed_ffn_block
+
+        x = x + routed_ffn_block(
+            normed, router, w_up, w_down, top_k=cfg.expert_top_k
+        )
+    else:
+        x = x + jax.nn.gelu(normed @ w_up.astype(dtype)) @ w_down.astype(dtype)
+    return x, pools
+
+
+def _paged_attention(cfg: TransformerConfig, state: PagedState, normed,
+                     w_qkv, w_out, layer, pools, q_positions, slot=None,
+                     write_mask=None):
+    """The attention mixer of every paged program, over normed
+    activations [B, Q, D]; q_positions: [B, Q] absolute
+    positions of the new tokens. ``pools`` is the WHOLE pool
+    ``(pool_k, pool_v, scale_k, scale_v)`` as the layer loop carries
+    it, ``layer`` this block's index into it: the block writes its new
+    rows at ``[layer, page, offset]`` and reads ``pool[layer]`` where
+    it lies; it returns the mixer's output (what the residual stream
+    adds) and the updated pools. ``state`` supplies tables
+    and lengths only. ``slot`` non-None = single-sequence
+    prefill (B == 1 view of that slot). ``write_mask`` [B, Q] bool
+    (batched paths only) gates which query offsets persist K/V — the
+    speculative verify pass drops sampled rows' draft-position writes so
+    those rows need no slack pages; None = every offset writes.
+    ``cfg.rotary`` false leaves q and k as projected (no positional
+    encoding); ``cfg.attention_multiplier`` non-zero scales the scores
+    by it and not by 1/sqrt(Dh)."""
+    batch, q_len, _ = normed.shape
     h, kv, dh = cfg.n_heads, cfg.kv_heads, cfg.d_head
     group = h // kv
-    dtype = x.dtype
+    dtype = normed.dtype
     new_pool_k, new_pool_v, new_scale_k, new_scale_v = pools
     quantized = new_scale_k is not None
     page = new_pool_k.shape[2]
 
-    normed = _rmsnorm(x, ln_attn)
     q, k, v = split_qkv(cfg, normed @ w_qkv.astype(dtype))
     # rotary wants [T]-shaped positions; rows share a position vector only
     # in prefill (B=1). Decode/verify rows each carry their own
     # positions: apply per-row via vmap (q_len 1 for plain decode,
     # 1 + draft_len for a speculative verify pass).
-    if slot is None:
+    if not cfg.rotary:
+        pass
+    elif slot is None:
         rot = jax.vmap(lambda t, p: _rotary(t[None], p)[0])
         q = rot(q, q_positions)
         k = rot(k, q_positions)
@@ -1615,15 +1747,20 @@ def _paged_attend_layer(cfg: TransformerConfig, state: PagedState, x,
             jnp.where(active, q_positions[:, 0], -1),
             layer, scale_k=new_scale_k, scale_v=new_scale_v,
             interpret=pallas_interpret(),
+            score_scale=cfg.attention_multiplier or None,
         )  # [B, H, Dh], kv-major head layout — same as the einsum's
-        x = x + att.reshape(batch, 1, h * dh) @ w_out.astype(dtype)
+        out = att.reshape(batch, 1, h * dh) @ w_out.astype(dtype)
     else:
         gk, gv = _gathered(
             (new_pool_k, new_pool_v, new_scale_k, new_scale_v),
             layer, tables, kv, dtype,
         )
         qg = q.reshape(batch, q_len, kv, group, dh)
-        scores = jnp.einsum("bqkgd,bskd->bkgqs", qg, gk) / (dh ** 0.5)
+        scores = jnp.einsum("bqkgd,bskd->bkgqs", qg, gk)
+        if cfg.attention_multiplier:
+            scores = scores * jnp.asarray(cfg.attention_multiplier, dtype)
+        else:
+            scores = scores / (dh ** 0.5)
         key_pos = jnp.arange(gk.shape[1])
         allowed = (key_pos[None, None, :]
                    <= q_positions[:, :, None])  # [B, Q, S]
@@ -1634,18 +1771,8 @@ def _paged_attend_layer(cfg: TransformerConfig, state: PagedState, x,
             scores.astype(jnp.float32), axis=-1
         ).astype(dtype)
         attended = jnp.einsum("bkgqs,bskd->bqkgd", weights, gv)
-        x = x + attended.reshape(batch, q_len, h * dh) @ w_out.astype(dtype)
-
-    normed = _rmsnorm(x, ln_mlp)
-    if cfg.n_experts:
-        from kvedge_tpu.models.moe import routed_ffn_block
-
-        x = x + routed_ffn_block(
-            normed, router, w_up, w_down, top_k=cfg.expert_top_k
-        )
-    else:
-        x = x + jax.nn.gelu(normed @ w_up.astype(dtype)) @ w_down.astype(dtype)
-    return x, (new_pool_k, new_pool_v, new_scale_k, new_scale_v)
+        out = attended.reshape(batch, q_len, h * dh) @ w_out.astype(dtype)
+    return out, (new_pool_k, new_pool_v, new_scale_k, new_scale_v)
 
 
 def _run_paged(cfg, params, state, x, q_positions, slot=None,
@@ -1655,7 +1782,14 @@ def _run_paged(cfg, params, state, x, q_positions, slot=None,
     index are scanned over. As ``xs``/``ys`` of the scan each layer's
     slab was sliced out of the stacked pool and stacked back into a new
     one (and the new one copied into the enclosing window's carry): at
-    a 1.6 GB pool that was 14 of a decode step's 23 ms (PERF.md §5)."""
+    a 1.6 GB pool that was 14 of a decode step's 23 ms (PERF.md §5).
+    A patterned block's recurrent state rides it the same way, and the
+    scan is over the pattern's periods (:func:`_run_paged_pattern`).
+    Returns the logits and the state's new ``(pools, recurrent)``."""
+    if cfg.layer_pattern:
+        return _run_paged_pattern(cfg, params, state, x, q_positions, slot,
+                                  all_positions, write_mask)
+
     def body(carry, xs):
         x, pools = carry
         layer_params, layer = xs
@@ -1674,16 +1808,58 @@ def _run_paged(cfg, params, state, x, q_positions, slot=None,
     logits = tied_readout(
         x if all_positions else x[:, -1], params["embedding"]
     )
-    return logits, new_pools
+    return logits, (new_pools, state.recurrent)
 
 
-def _with_pools(state: PagedState, pools, **extra) -> PagedState:
-    """A state whose pools/scales are replaced by ``pools`` (the
-    4-tuple ``_run_paged`` returns), plus any other field."""
-    new_k, new_v, new_sk, new_sv = pools
+def _run_paged_pattern(cfg, params, state, x, q_positions, slot,
+                       all_positions, write_mask):
+    """:func:`_run_paged` for a block with a layer pattern
+    (models/hybrid.py has the block and its equations): the attention
+    layers through :func:`_paged_attention` on the pool, which holds
+    only them, the mamba layers on ``state.recurrent``. A batched row
+    that is not decoding (length 0 in ``state``, as the decode step
+    masks it) gets its recurrent state back untouched."""
+    from kvedge_tpu.models import hybrid
+
+    if slot is None and x.shape[1] != 1:
+        raise ValueError(
+            "a block with recurrent layers takes one token a row and "
+            "step: a verify pass over drafts would have to rewind the "
+            "state of the drafts it rejects (serving_speculative)")
+
+    def attend(normed, w, layer, pools):
+        return _paged_attention(
+            cfg, state, normed, w["w_qkv"], w["w_out"], layer, pools,
+            q_positions, slot, write_mask)
+
+    x, pools, recurrent = hybrid.run_layers(
+        cfg, params, x,
+        (state.pool_k, state.pool_v, state.scale_k, state.scale_v),
+        state.recurrent, attend, slot,
+        None if slot is not None else state.lengths > 0)
+    x = _rmsnorm(x, params["ln_final"], cfg.norm_eps)
+    logits = tied_readout(
+        x if all_positions else x[:, -1], params["embedding"]
+    ) / cfg.logits_scaling
+    return logits, (pools, recurrent)
+
+
+def _embed(cfg: TransformerConfig, params: dict, tokens):
+    """The residual stream's first value, in the compute dtype."""
+    x = params["embedding"][tokens].astype(jnp.dtype(cfg.dtype))
+    if cfg.embedding_multiplier != 1.0:
+        x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
+    return x
+
+
+def _with_pools(state: PagedState, carried, **extra) -> PagedState:
+    """A state whose pools/scales and recurrent state are replaced by
+    ``carried`` (what ``_run_paged`` returns beside the logits), plus
+    any other field."""
+    (new_k, new_v, new_sk, new_sv), recurrent = carried
     return dataclasses.replace(
         state, pool_k=new_k, pool_v=new_v, scale_k=new_sk,
-        scale_v=new_sv, **extra,
+        scale_v=new_sv, recurrent=recurrent, **extra,
     )
 
 
@@ -1693,8 +1869,7 @@ def _paged_prefill_impl(params: dict, state: PagedState, prompt, slot,
     # so XLA compiles one program per CHUNK length, not one per
     # (slot, offset, length) triple.
     _note_trace("prefill")
-    dtype = jnp.dtype(cfg.dtype)
-    x = params["embedding"][prompt][None].astype(dtype)  # [1, T, D]
+    x = _embed(cfg, params, prompt)[None]  # [1, T, D]
     q_positions = (offset + jnp.arange(prompt.shape[0]))[None]
     logits, pools = _run_paged(
         cfg, params, state, x, q_positions, slot
@@ -1717,8 +1892,7 @@ def _decode_step_core(params: dict, state: PagedState, tokens,
     half-prefilled slot is admitted with its final length but must not
     be touched by decode)."""
     _note_trace("decode_step")
-    dtype = jnp.dtype(cfg.dtype)
-    x = params["embedding"][tokens][:, None].astype(dtype)  # [B, 1, D]
+    x = _embed(cfg, params, tokens)[:, None]  # [B, 1, D]
     q_positions = state.lengths[:, None]  # [B, 1]
     masked = dataclasses.replace(
         state, lengths=jnp.where(active, state.lengths, 0)
@@ -1768,9 +1942,8 @@ def _spec_verify_core(params: dict, state: PagedState, tokens,
     write, exactly like plain decode.
     """
     _note_trace("spec_verify")
-    dtype = jnp.dtype(cfg.dtype)
     k_len = tokens.shape[1] - 1
-    x = params["embedding"][tokens].astype(dtype)  # [B, 1+K, D]
+    x = _embed(cfg, params, tokens)  # [B, 1+K, D]
     q_positions = (state.lengths[:, None]
                    + jnp.arange(1 + k_len)[None])  # [B, 1+K]
     masked = dataclasses.replace(
@@ -1988,6 +2161,34 @@ _paged_spec_window_sampled = functools.partial(
 )(_paged_spec_window_sampled_impl)
 
 
+def _picks_zeroed(state: PagedState) -> PagedState:
+    """A window counts its own expert picks: a patterned block's
+    counters start each window at zero (what prefill chunks added
+    since the last one is dropped; ``stats()`` counts decode steps)."""
+    if state.recurrent is None:
+        return state
+    zeros = jnp.zeros_like(state.recurrent["picks"])
+    return dataclasses.replace(
+        state, recurrent={**state.recurrent, "picks": zeros})
+
+
+def _window_result(produced, state: PagedState, active, steps_left,
+                   n_steps: int, stop_at):
+    """What a capped window hands the host: the produced tokens with
+    the packed ``[fin, stop_at]`` rows and, for a patterned block, the
+    window's expert-pick counters beside them."""
+    fin = jnp.where(
+        stop_at > 0, 2,
+        jnp.where(active & (steps_left <= n_steps), 1, 0),
+    ).astype(jnp.int32)
+    produced = jnp.concatenate(
+        [produced, fin[None], stop_at[None]], axis=0
+    )
+    if state.recurrent is None:
+        return produced
+    return produced, state.recurrent["picks"]
+
+
 def _paged_decode_window_capped_impl(params: dict, state: PagedState,
                                      tokens, cfg: TransformerConfig,
                                      n_steps: int, active, steps_left,
@@ -2043,16 +2244,10 @@ def _paged_decode_window_capped_impl(params: dict, state: PagedState,
 
     stop0 = jnp.zeros(tokens.shape[0], jnp.int32)
     (state, _, stop_at), produced = jax.lax.scan(
-        body, (state, tokens, stop0), jnp.arange(n_steps)
+        body, (_picks_zeroed(state), tokens, stop0), jnp.arange(n_steps)
     )
-    fin = jnp.where(
-        stop_at > 0, 2,
-        jnp.where(active & (steps_left <= n_steps), 1, 0),
-    ).astype(jnp.int32)
-    produced = jnp.concatenate(
-        [produced, fin[None], stop_at[None]], axis=0
-    )
-    return produced, state
+    return _window_result(produced, state, active, steps_left, n_steps,
+                          stop_at), state
 
 
 _paged_decode_window_capped = functools.partial(
@@ -2106,16 +2301,10 @@ def _paged_decode_window_sampled_capped_impl(
 
     stop0 = jnp.zeros(tokens.shape[0], jnp.int32)
     (state, _, stop_at), produced = jax.lax.scan(
-        body, (state, tokens, stop0), jnp.arange(n_steps)
+        body, (_picks_zeroed(state), tokens, stop0), jnp.arange(n_steps)
     )
-    fin = jnp.where(
-        stop_at > 0, 2,
-        jnp.where(active & (steps_left <= n_steps), 1, 0),
-    ).astype(jnp.int32)
-    produced = jnp.concatenate(
-        [produced, fin[None], stop_at[None]], axis=0
-    )
-    return produced, state
+    return _window_result(produced, state, active, steps_left, n_steps,
+                          stop_at), state
 
 
 _paged_decode_window_sampled_capped = functools.partial(

@@ -61,6 +61,8 @@ def warn_if_train_serve_divergence(cfg) -> None:
     """
     import warnings
 
+    if cfg.layer_pattern:
+        return  # served only: no trainer whose capacity could bind
     if (cfg.n_experts
             and cfg.expert_capacity_factor * cfg.expert_top_k
             < cfg.n_experts):
@@ -85,22 +87,28 @@ def expert_capacity(n_tokens: int, n_experts: int,
     return max(1, math.ceil(n_tokens * capacity_factor / n_experts))
 
 
-def _route(x, router_w, top_k: int):
+def _route(x, router_w, top_k: int, renormalize: bool | None = None):
     """Shared routing decision. Returns (probs [N, E], idx [N, k],
     gates [N, k] fp32).
 
     Gate convention follows the source papers: top-1 uses the raw router
     probability (Switch); top-2 normalizes the pair to sum to 1 (GShard).
+    ``renormalize`` says it outright (None = as above): true makes the
+    gates a softmax over the ``top_k`` picked logits, which is the
+    picked probabilities normalized, for any ``top_k``.
     Both training dispatch and the dropless serving path call this, so
-    the two cannot disagree about gating.
+    the two cannot disagree about gating. The logits are a float32
+    product in full: a token's picks are read off them, and the
+    device's one-pass float32 product moves near-ties.
     """
-    logits = x.astype(jnp.float32) @ router_w.astype(jnp.float32)
+    logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
     probs = jax.nn.softmax(logits, axis=-1)                 # [N, E]
     topk_probs, topk_idx = lax.top_k(probs, top_k)          # [N, k]
-    if top_k == 1:
-        gates = topk_probs
-    else:
+    if top_k > 1 if renormalize is None else renormalize:
         gates = topk_probs / jnp.sum(topk_probs, axis=-1, keepdims=True)
+    else:
+        gates = topk_probs
     return probs, topk_idx, gates
 
 
@@ -179,73 +187,77 @@ def moe_ffn(x, router_w, w_up, w_down, *, capacity_factor: float,
     return out, aux_loss
 
 
-def moe_ffn_dropless(x, router_w, w_up, w_down, *, top_k: int = 1):
-    """Per-token routed FFN without capacity limits — the serving path.
+def ffn_activation(up, gated: bool):
+    """A feed-forward's hidden activation from its up-projection:
+    ``silu(u) * g`` over ``up = u | g`` when ``gated``, else
+    ``gelu(up)``."""
+    if not gated:
+        return jax.nn.gelu(up)
+    half = up.shape[-1] // 2
+    return jax.nn.silu(up[..., :half]) * up[..., half:]
 
-    x: [N, D]; router_w [D, E] fp32; w_up [E, D, F] / w_down [E, F, D]
-    (compute dtype). Returns [N, D].
 
-    At decode time there is no load to balance and no batch-wide cumsum
-    to keep static: each token simply runs through its top-k experts,
-    combined with the same gates the training path uses (:func:`_route`),
-    so cached decode agrees with the teacher-forced forward pass
-    *provided training capacity never bound* (capacity_factor * top_k >=
-    n_experts guarantees zero drops — see
-    :func:`warn_if_train_serve_divergence`; a dispatch dropped in
-    training forward but served here would diverge).
+def held_experts_ffn(x, router_w, w_in, w_out, *, top_k: int,
+                     first: int = 0, gated: bool = False,
+                     renormalize: bool | None = None, live=None):
+    """The routed experts held here, for every token: the serving path.
 
-    Implementation gathers each token's expert weights ([N, D, F] per
-    choice) — ideal for decode (N = batch). Large prefills go through
-    :func:`routed_ffn_block`, which switches to einsum dispatch past
-    ``_GATHER_MAX_TOKENS``.
+    x: [N, D]; router_w [D, E] fp32 over ALL ``E`` routed experts;
+    w_in [Eh, D, F] (``[Eh, D, 2F]`` when ``gated``: u | g, the expert
+    is ``(silu(u) * g) @ w_out``; ungated it is ``gelu(x @ w_in) @
+    w_out``) and w_out [Eh, F, D] are the ``Eh`` experts this device
+    holds, global indices ``first`` to ``first + Eh - 1``. Each token
+    routes over all ``E`` (:func:`_route`), droplessly, and the result
+    is the part of its gated sum that the held experts give: with
+    ``Eh == E`` the whole layer, on a chip that shares the layer the
+    half that its all-reduce would add to the other chip's. Returns
+    ``(out [N, D], picks int32 [2 + Eh])``: of the ``live`` tokens'
+    picks (all, when ``live`` is None) how many there were, how many
+    fell on a held expert, and the count for each held expert.
+
+    One product over all held experts and all tokens: every expert
+    matrix is read once, whatever the routing, and a token's gate for
+    an expert it did not pick is zero. At decode and at a prefill
+    chunk the tokens are few (a batch, 64 positions) and the weights
+    are what the step reads, so the work not needed (Eh / top_k times
+    the operations) costs less than its bytes. The per-token gather of
+    ``w_in[idx]`` this replaced copied a token's matrices out for each
+    of its picks: ``N * top_k`` matrices a layer where this reads
+    ``Eh`` (PERF.md section 6 has both timed at the benchmark's
+    widths).
     """
-    _, topk_idx, gates = _route(x, router_w, top_k)
+    _, topk_idx, gates = _route(x, router_w, top_k, renormalize)
+    held = w_in.shape[0]
     dtype = x.dtype
-    out = None
-    for choice in range(top_k):
-        idx = topk_idx[:, choice]
-        w_up_tok = w_up[idx].astype(dtype)                  # [N, D, F]
-        w_down_tok = w_down[idx].astype(dtype)              # [N, F, D]
-        hidden = jax.nn.gelu(jnp.einsum("nd,ndf->nf", x, w_up_tok))
-        contrib = jnp.einsum("nf,nfd->nd", hidden, w_down_tok)
-        contrib = contrib * gates[:, choice, None].astype(dtype)
-        out = contrib if out is None else out + contrib
-    return out
-
-
-# The per-token weight gather materializes [chunk, D, F] weight copies —
-# ideal at decode (chunk = batch) but ~N/E x the whole layer's weights
-# for a long prefill. Past this many tokens the serving block runs the
-# SAME gather in lax.map'd chunks: routing stays per-token identical,
-# memory stays bounded at one chunk's weight copies, and cost stays
-# linear in N (matmul rounding can differ across chunk shapes, as it
-# already does between the gather and training-dispatch paths). A
-# dropless einsum dispatch is NOT a substitute here: guaranteeing zero
-# drops needs capacity = k*N, making the dispatch one-hots O(N^2).
-_GATHER_MAX_TOKENS = 64
+    # [N, k, Eh]: pick j of token n is held expert e.
+    hit = (topk_idx[:, :, None] - first
+           == jnp.arange(held, dtype=topk_idx.dtype)[None, None, :])
+    gate = jnp.sum(jnp.where(hit, gates[:, :, None], 0.0), axis=1)  # [N, Eh]
+    act = ffn_activation(jnp.einsum("nd,edf->enf", x, w_in.astype(dtype)),
+                         gated)
+    act = act * gate.T[:, :, None].astype(dtype)
+    out = jnp.einsum("enf,efd->nd", act, w_out.astype(dtype))
+    counted = hit if live is None else hit & live[:, None, None]
+    by_expert = jnp.sum(counted, axis=(0, 1), dtype=jnp.int32)
+    n_live = (x.shape[0] if live is None
+              else jnp.sum(live, dtype=jnp.int32))
+    picks = jnp.concatenate([
+        jnp.stack([jnp.asarray(n_live * top_k, jnp.int32),
+                   jnp.sum(by_expert)]), by_expert])
+    return out, picks
 
 
 def routed_ffn_block(normed, router_w, w_up, w_down, *, top_k: int = 1):
     """The serving layers' MoE MLP block: [B, Q, D] in, [B, Q, D] out.
 
     Shared by the contiguous (decode.py) and paged (kvcache.py) decode
-    paths so the two cannot drift. Decode steps gather per-token expert
-    weights directly; long prefills run the identical gather chunked
-    under ``lax.map`` so weight-copy memory stays bounded.
+    paths so the two cannot drift: every expert is held, ungated, the
+    Switch/GShard gates of :func:`_route`. Dropless, so cached decode
+    agrees with the teacher-forced forward pass *provided training
+    capacity never bound* (:func:`warn_if_train_serve_divergence`).
     """
     batch, q_len, d = normed.shape
-    n_tokens = batch * q_len
-    flat = normed.reshape(n_tokens, d)
-    if n_tokens <= _GATHER_MAX_TOKENS:
-        out = moe_ffn_dropless(flat, router_w, w_up, w_down, top_k=top_k)
-    else:
-        chunk = _GATHER_MAX_TOKENS
-        pad = -n_tokens % chunk
-        padded = jnp.pad(flat, ((0, pad), (0, 0)))
-        out = lax.map(
-            lambda c: moe_ffn_dropless(
-                c, router_w, w_up, w_down, top_k=top_k
-            ),
-            padded.reshape(-1, chunk, d),
-        ).reshape(-1, d)[:n_tokens]
+    out, _ = held_experts_ffn(
+        normed.reshape(batch * q_len, d), router_w, w_up, w_down,
+        top_k=top_k)
     return out.reshape(batch, q_len, d)

@@ -25,6 +25,7 @@ capability the repo's own README listed as future work.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 import heapq
 import json
@@ -319,6 +320,18 @@ class PagedGenerationServer:
                 "the injected cache would trace the Pallas decode "
                 "kernel over params that span several devices; build "
                 "it with paged_attention='gather'")
+        if cfg.layer_pattern and prefix_cache:
+            raise ValueError(
+                "prefix_cache (serving_prefix_cache) cannot serve a block "
+                "with recurrent layers (layer_pattern): a recurrent "
+                "state holds a row's whole prefix in one array and "
+                "cannot be shared by page; pass prefix_cache=False")
+        if cfg.layer_pattern and speculative:
+            raise ValueError(
+                "speculative (serving_speculative) cannot serve a block "
+                "with recurrent layers (layer_pattern): a recurrent "
+                "state cannot be rewound past the drafts a verify pass "
+                "rejects")
         self._params = params
         self._weights_gb, self._weights_dtype = weights_summary(params)
         self._cfg = cfg
@@ -446,6 +459,10 @@ class PagedGenerationServer:
             "admit/lock_wait": self._hist_prefill_wait,
             "admit/prefill_chunk": self._hist_prefill_chunk,
             "admit/first_pick": PhaseSum(),
+            # A block with recurrent layers zeroes the slot's state as
+            # it admits a request (kvcache.PagedKVCache.admit).
+            **({"admit/state_reset": PhaseSum()}
+               if cfg.layer_pattern else {}),
         }, tracer, chained=LOOP_PHASES)
         # The time the loop thread has run, by its own clock (what its
         # phases must add up to): finished threads in _loop_ran, the
@@ -566,6 +583,9 @@ class PagedGenerationServer:
                                 // page_size),
             kv_dtype=kv_dtype, min_bucket=min_bucket,
         )
+        if cfg.layer_pattern:
+            self._cache.reset_phase = functools.partial(
+                self._phase, "admit/state_reset")
         # Bucketed compile cache (SERVING.md rung 21): the device batch
         # dim is the cache's current BUCKET, not ``slots`` — every
         # dispatch-array site below sizes on ``self._cache.bucket``.
@@ -1453,7 +1473,7 @@ class PagedGenerationServer:
                     new_shadow_spans[node] = (len(all_ids), sh_n)
                     all_ids.extend(ids[:sh_n])
             jobs.append((req, saved_len, sh_n if shared else 0, node,
-                         own_span))
+                         own_span, slot))
         if not jobs:
             return
         batch = (self._cache.swapout_pages(all_ids)
@@ -1468,8 +1488,10 @@ class PagedGenerationServer:
 
         new_shadows = {node: _slice(span)
                        for node, span in new_shadow_spans.items()}
-        for req, saved_len, sh_n, node, own_span in jobs:
-            own = _slice(own_span)
+        for req, saved_len, sh_n, node, own_span, slot in jobs:
+            # With the pages, the row's recurrent state as it stands
+            # at this boundary (empty for a block that keeps none).
+            own = _slice(own_span) + self._cache.swapout_row(slot)
             if node is not None:
                 ok = self._checkpoint_shared_locked(
                     req, saved_len, sh_n, own,
@@ -2655,18 +2677,14 @@ class PagedGenerationServer:
                             req.prompt[:entry.prefix_tokens], fresh)
                         pins = node_pages[node] = tuple(fresh)
                     self._cache.admit(slot, entry.saved_len, pins)
-                    self._cache.swapin_pages(
-                        self._cache.slot_pages(slot)[sh_n:],
-                        entry.arrays,
-                    )
+                    self._cache.swapin_slot(slot, entry.arrays,
+                                            skip_pages=sh_n)
                     self._lease_take_locked(pins)
                     req.shared_pages = pins
                     req.prefix_node = node
                 else:
                     self._cache.admit(slot, entry.saved_len)
-                    self._cache.swapin_pages(
-                        self._cache.slot_pages(slot), entry.arrays
-                    )
+                    self._cache.swapin_slot(slot, entry.arrays)
                 entries.pop(0)
         except Exception:
             # Transactional unwind: put everything back — restored
@@ -2870,6 +2888,19 @@ class PagedGenerationServer:
             "pages_live_steps_total": self._pages_live_steps,
             "tokens_emitted_total": self._tokens_emitted,
         }
+        recurrent = self._cache.state.recurrent
+        if recurrent is not None:
+            # State of the second kind (SERVING.md "Recurrent state"):
+            # every slot holds its rows' whether a request is in it or
+            # not, so slots bound admission as memory too. The picks
+            # are the decode windows' own counts, summed at harvest.
+            picks = self._cache.expert_picks
+            out["state_rows"] = self._cache.slots
+            out["state_gb"] = (recurrent["ssm"].nbytes
+                               + recurrent["conv"].nbytes) / 1e9
+            out["expert_picks_total"] = int(picks[0])
+            out["expert_picks_held_total"] = int(picks[1])
+            out["expert_picks_by_expert"] = [int(n) for n in picks[2:]]
         if self._autotune is not None:
             # Online window controller (SERVING.md rung 26): the
             # current pick and its EWMA inputs — R (host turnaround
@@ -3480,7 +3511,8 @@ class PagedGenerationServer:
         if include_inflight:
             n_tokens += req.inflight
         n_pages = -(-n_tokens // self._cache.page_size)
-        return n_pages * self._page_bytes_locked()
+        return (n_pages * self._page_bytes_locked()
+                + self._cache.row_state_bytes())
 
     def _page_bytes_locked(self) -> int:
         """Host bytes one KV page occupies (lock held; lazy — the
@@ -3564,9 +3596,7 @@ class PagedGenerationServer:
             self._active[slot] = req
             self._note_finish_candidate_locked(slot, req)
             self._cache.admit(slot, head.saved_len)
-            self._cache.swapin_pages(
-                self._cache.slot_pages(slot), arrays
-            )
+            self._cache.swapin_slot(slot, arrays)
 
     def _maybe_preempt_locked(self) -> None:
         """Swap out lower-class victims while the policy head is
@@ -3595,7 +3625,11 @@ class PagedGenerationServer:
             # slot_pages is position-ordered; pages grown past the
             # live length hold no committed K/V and are simply freed.
             ids = self._cache.slot_pages(victim)[:n_pages]
-            arrays = self._cache.swapout_pages(ids)
+            # The row's recurrent state (a block that keeps one) goes
+            # with its pages, verbatim: it is resumed on neither
+            # without the other.
+            arrays = (self._cache.swapout_pages(ids)
+                      + self._cache.swapout_row(victim))
             del self._active[victim]
             # A preempted victim becomes SELF-CONTAINED: the verbatim
             # gather above copied its shared-prefix pages too, so its

@@ -119,6 +119,63 @@ class TransformerConfig:
     # Prefill and the speculative verify pass always use the gather
     # path (multi-query shapes).
     paged_attention: str = "auto"
+    # A patterned block (models/hybrid.py; served by the paged path
+    # only). ``layer_pattern`` is one period of layer kinds, "mamba" or
+    # "attention", repeated ``n_layers / len(layer_pattern)`` times; ()
+    # is the block above: every layer rotary attention and a GELU
+    # feed-forward. With a pattern every layer's feed-forward is
+    # ``n_experts`` routed experts of width ``d_ff`` (``expert_top_k``
+    # a token, gates a softmax over the picked logits) plus a shared
+    # expert of width ``shared_ff``, all SiLU-gated when ``ffn_gated``.
+    # ``experts_held`` of the routed experts live here, from index
+    # ``expert_first`` on (0 held = all): the layer routes over all
+    # ``n_experts`` and returns the held experts' part of the sum.
+    layer_pattern: tuple = ()
+    ssm_heads: int = 0      # Mamba-2 heads ...
+    ssm_head_dim: int = 0   # ... of this many channels each
+    ssm_state: int = 0      # state size N per channel
+    ssm_conv: int = 4       # causal conv width over x|B|C
+    ssm_chunk: int = 256    # the chunk form's block of positions
+    experts_held: int = 0
+    expert_first: int = 0
+    shared_ff: int = 0
+    ffn_gated: bool = False
+    # x = embedding_multiplier * E[tokens]; each residual add takes
+    # residual_multiplier * f(norm(x)); attention scores are
+    # attention_multiplier * q.k (0 = 1/sqrt(d_head)); logits are
+    # divided by logits_scaling.
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
+    logits_scaling: float = 1.0
+    rotary: bool = True     # False = no positional encoding at all
+    norm_eps: float = 1e-6
+
+    @property
+    def kv_layers(self) -> int:
+        """Layers that keep keys and values in the page pool."""
+        if not self.layer_pattern:
+            return self.n_layers
+        return (self.n_layers // len(self.layer_pattern)
+                * self.layer_pattern.count("attention"))
+
+    @property
+    def ssm_layers(self) -> int:
+        """Layers that keep a recurrent state per slot."""
+        return self.n_layers - self.kv_layers
+
+    @property
+    def held_experts(self) -> int:
+        return self.experts_held or self.n_experts
+
+    @property
+    def ssm_inner(self) -> int:
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """Channels the causal conv runs over: x | B | C, one group."""
+        return self.ssm_inner + 2 * self.ssm_state
 
     @property
     def d_head(self) -> int:
@@ -174,8 +231,16 @@ class TransformerConfig:
             raise ValueError("n_experts must be >= 0 (0 = dense FFN)")
         if self.n_experts and self.expert_capacity_factor <= 0:
             raise ValueError("expert_capacity_factor must be > 0")
+        if self.layer_pattern:
+            self._validate_pattern()
+        elif (self.experts_held or self.expert_first or self.shared_ff
+              or self.ffn_gated or not self.rotary):
+            raise ValueError(
+                "experts_held, expert_first, shared_ff, ffn_gated and "
+                "rotary = false belong to a patterned block: set "
+                "layer_pattern")
         if self.n_experts:
-            if self.expert_top_k not in (1, 2):
+            if self.expert_top_k not in (1, 2) and not self.layer_pattern:
                 raise ValueError("expert_top_k must be 1 or 2")
             if self.expert_top_k > self.n_experts:
                 raise ValueError(
@@ -187,6 +252,10 @@ class TransformerConfig:
                 f"remat_policy must be 'full' or 'dots', got "
                 f"{self.remat_policy!r}"
             )
+        if self.layer_pattern and self.pipeline_stages > 1:
+            raise ValueError(
+                "layer_pattern: a patterned block is served by the paged "
+                "path on one device; it has no pipeline schedule")
         if self.pipeline_stages < 0:
             raise ValueError("pipeline_stages must be >= 0 (0 = off)")
         if self.pipeline_microbatches < 0:
@@ -226,6 +295,35 @@ class TransformerConfig:
                     "or disable fused_xent)"
                 )
 
+    def _validate_pattern(self) -> None:
+        kinds = set(self.layer_pattern)
+        if not kinds <= {"mamba", "attention"}:
+            raise ValueError(
+                "layer_pattern holds 'mamba' and 'attention', got "
+                f"{sorted(kinds - {'mamba', 'attention'})}")
+        if self.n_layers % len(self.layer_pattern):
+            raise ValueError(
+                f"n_layers {self.n_layers} must be whole periods of "
+                f"layer_pattern ({len(self.layer_pattern)} layers)")
+        if "mamba" in kinds and not (self.ssm_heads and self.ssm_head_dim
+                                     and self.ssm_state
+                                     and self.ssm_conv > 1
+                                     and self.ssm_chunk > 0):
+            raise ValueError(
+                "layer_pattern has mamba layers: ssm_heads, ssm_head_dim "
+                "and ssm_state must be set, ssm_conv > 1, ssm_chunk > 0")
+        if not self.n_experts:
+            raise ValueError(
+                "layer_pattern: the patterned block's feed-forward is "
+                "routed experts; set n_experts")
+        held = self.held_experts
+        if not (0 <= self.expert_first
+                and self.expert_first + held <= self.n_experts):
+            raise ValueError(
+                f"experts {self.expert_first} to "
+                f"{self.expert_first + held - 1} are not among the "
+                f"{self.n_experts} routed experts")
+
 
 # Named model shapes for the runtime's [model] TOML section. One
 # definition shared by the payload pipeline (runtime/workload.py), the
@@ -250,9 +348,20 @@ PRESETS: dict[str, dict] = {
 _scaled = jax.jit(lambda draw, scale: draw * scale, donate_argnums=0)
 
 
+def refuse_pattern(cfg: TransformerConfig, where: str) -> None:
+    """The patterned block is written once, in the paged serving path
+    (models/hybrid.py): every other path refuses it by the key's name."""
+    if cfg.layer_pattern:
+        raise ValueError(
+            f"[model] layer_pattern is set and {where} has no patterned "
+            "block: it is served by serving = \"paged\" on one device "
+            "(models/hybrid.py, SERVING.md \"Recurrent state\")")
+
+
 def init_params(key, cfg: TransformerConfig) -> dict:
     """Initialize the flat, layer-stacked param tree (fp32)."""
     cfg.validate()
+    refuse_pattern(cfg, "init_params (hybrid.init_params draws its tree)")
     k_embed, k_qkv, k_out, k_up, k_down = jax.random.split(key, 5)
     d, h, kv, dh, f, layers = (
         cfg.d_model, cfg.n_heads, cfg.kv_heads, cfg.d_head, cfg.d_ff,
@@ -329,6 +438,11 @@ def stacked_layer_params(params: dict, cfg: TransformerConfig) -> tuple:
 _COMPUTE_DTYPE_LEAVES = frozenset({
     "embedding", "w_qkv", "w_out", "w_up", "w_down",
     "w_up_experts", "w_down_experts",
+    # The patterned block's (models/hybrid.py), nested by layer kind.
+    # Its router, the SSM's A_log, dt_bias and D, and every gain stay
+    # float32: they are read in float32 by the equations.
+    "w_in", "conv_w", "conv_b", "experts_in", "experts_out",
+    "shared_in", "shared_out",
 })
 
 
@@ -348,7 +462,9 @@ def serving_params(params: dict, cfg: TransformerConfig) -> dict:
     if dtype == jnp.float32:
         return params
     return {
-        name: leaf.astype(dtype) if name in _COMPUTE_DTYPE_LEAVES else leaf
+        name: (serving_params(leaf, cfg) if isinstance(leaf, dict)
+               else leaf.astype(dtype) if name in _COMPUTE_DTYPE_LEAVES
+               else leaf)
         for name, leaf in params.items()
     }
 
@@ -360,10 +476,10 @@ def _remat_policy(cfg: TransformerConfig):
     return None
 
 
-def _rmsnorm(x, gain):
+def _rmsnorm(x, gain, eps: float = 1e-6):
     scale = jax.lax.rsqrt(
         jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
-        + 1e-6
+        + eps
     )
     return (x * scale.astype(x.dtype)) * gain.astype(x.dtype)
 
@@ -540,6 +656,7 @@ def forward_hidden(params: dict, tokens, cfg: TransformerConfig,
     seq-sharded between layers so the LN/MLP work stays sequence-parallel
     too.
     """
+    refuse_pattern(cfg, "the trainer's forward pass")
     dtype = jnp.dtype(cfg.dtype)
     embedding = params["embedding"]
     x = embedding[tokens].astype(dtype)  # [B, T, D]

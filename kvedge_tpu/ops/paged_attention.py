@@ -136,7 +136,7 @@ def decode_scratch_fits_vmem(max_pages: int, page: int, width: int,
 
 def _decode_flat_kernel(tables_ref, pos_ref, layer_ref, q_ref, *rest,
                         page: int, width: int, dh: int, dtype,
-                        quantized: bool):
+                        quantized: bool, score_scale: float | None):
     """One program per SEQUENCE, two phases (module docstring).
 
     Layout: the pools arrive whole, [L, P, page, width] as PagedState
@@ -233,7 +233,11 @@ def _decode_flat_kernel(tables_ref, pos_ref, layer_ref, q_ref, *rest,
         state_ref[1] = n_pages
 
         q2 = q_ref[0]  # [H, width], zero outside each head's own slot
-        scale = jnp.asarray(dh ** 0.5, dtype)
+        # The gather's own arithmetic either way: scores divided by
+        # sqrt(Dh), or, for a block that states its multiplier,
+        # multiplied by that.
+        scale = jnp.asarray(
+            dh ** 0.5 if score_scale is None else score_scale, dtype)
         # Dead pages' score columns are never stored: pre-fill the whole
         # row with the exact fp32 image of the gather's masked entries
         # (finfo(dtype).min upcast), so phase 2's softmax sees the same
@@ -280,7 +284,8 @@ def _decode_flat_kernel(tables_ref, pos_ref, layer_ref, q_ref, *rest,
             )  # [H, page] — exact per-head scores (zero slots add nothing)
             # Mirror the gather path's visible rounding: dtype scores,
             # dtype scale division, then the fp32 upcast its softmax does.
-            s16 = s32.astype(dtype) / scale
+            s16 = (s32.astype(dtype) / scale if score_scale is None
+                   else s32.astype(dtype) * scale)
             key_pos = j * page + jax.lax.broadcasted_iota(
                 jnp.int32, s16.shape, 1
             )
@@ -349,10 +354,12 @@ def _decode_flat_kernel(tables_ref, pos_ref, layer_ref, q_ref, *rest,
 # for each of seven buckets at the benchmark cell's start-up) calls this
 # with the same shapes, and tracing the kernel's body is the costliest
 # part of lowering one.
-@functools.partial(jax.jit, static_argnames=("interpret",), inline=True)
+@functools.partial(jax.jit, static_argnames=("interpret", "score_scale"),
+                   inline=True)
 def paged_decode_attention(q, pool_k, pool_v, tables, q_positions,
                            layer, *, scale_k=None, scale_v=None,
-                           interpret: bool = False):
+                           interpret: bool = False,
+                           score_scale: float | None = None):
     """Decode attention over layer ``layer`` of a paged KV pool,
     block-table-indexed.
 
@@ -367,7 +374,8 @@ def paged_decode_attention(q, pool_k, pool_v, tables, q_positions,
     read and its output is zeros).
     ``scale_k``/``scale_v`` ([L, P, page, K] fp32) mark an int8 pool:
     the kernel streams pages as stored and dequantizes in VMEM with the
-    gather's exact formula. Returns [B, H, Dh], a live row's
+    gather's exact formula. ``score_scale`` multiplies the scores
+    (None = divide them by sqrt(Dh)). Returns [B, H, Dh], a live row's
     BIT-IDENTICAL to the gather path's decode attention. DMA cost and
     program time scale with the LIVE rows' page counts; the pool itself
     is neither sliced nor reshaped.
@@ -446,7 +454,7 @@ def paged_decode_attention(q, pool_k, pool_v, tables, q_positions,
                 layer, q2, pool_k, pool_v)
     kernel = functools.partial(
         _decode_flat_kernel, page=page, width=width, dh=dh,
-        dtype=q.dtype, quantized=quantized,
+        dtype=q.dtype, quantized=quantized, score_scale=score_scale,
     )
 
     grid_spec = pltpu.PrefetchScalarGridSpec(

@@ -97,6 +97,13 @@ def derive_model_config(cfg: RuntimeConfig, *, seq: int):
             "and needs a 'seq' axis in the mesh"
         )
     spec = cfg.model
+    if spec.layer_pattern and mesh.devices.size > 1:
+        raise MeshConfigError(
+            f"[model] layer_pattern cannot run on a mesh of "
+            f"{mesh.devices.size} devices {axis_sizes}: the patterned "
+            "block has no sharding rules and its expert layer no "
+            "exchange; it is served on one device that holds its share "
+            "([model] experts_held)")
     base = PRESETS[spec.preset or "probe"]
     n_heads = spec.n_heads or max(base["n_heads"], model_axis)
     group = sp * model_axis
@@ -174,6 +181,22 @@ def derive_model_config(cfg: RuntimeConfig, *, seq: int):
         pipeline_stages=stages if stages > 1 else 0,
         pipeline_schedule=spec.pipeline_schedule or "gpipe",
         paged_attention=cfg.payload_paged_attention or "auto",
+        layer_pattern=tuple(spec.layer_pattern),
+        ssm_heads=spec.ssm_heads,
+        ssm_head_dim=spec.ssm_head_dim,
+        ssm_state=spec.ssm_state,
+        ssm_conv=spec.ssm_conv or TransformerConfig.ssm_conv,
+        ssm_chunk=spec.ssm_chunk or TransformerConfig.ssm_chunk,
+        experts_held=spec.experts_held,
+        expert_first=spec.expert_first,
+        shared_ff=spec.shared_ff,
+        ffn_gated=spec.ffn_gated,
+        embedding_multiplier=spec.embedding_multiplier or 1.0,
+        residual_multiplier=spec.residual_multiplier or 1.0,
+        attention_multiplier=spec.attention_multiplier,
+        logits_scaling=spec.logits_scaling or 1.0,
+        rotary=spec.rotary,
+        norm_eps=spec.norm_eps or TransformerConfig.norm_eps,
     )
     try:
         # Cross-field architecture errors (d_model % n_heads, GQA head
@@ -522,6 +545,13 @@ def _restore_serving_params(cfg: RuntimeConfig, tcfg, mesh=None):
 
     from kvedge_tpu.models import serving_params
 
+    if tcfg.layer_pattern:
+        # Served only, so no trainer ever wrote a checkpoint of it: the
+        # tree is drawn, leaf by leaf in the serving dtype (a float32
+        # tree of the benchmark's configuration would not fit the chip).
+        from kvedge_tpu.models import hybrid
+
+        return None, hybrid.init_params(jax.random.PRNGKey(0), tcfg)
     step, masters = _restore_latest_params(cfg, tcfg, mesh=mesh)
     params = {}
     for name in list(masters):
@@ -933,7 +963,7 @@ def _serving_page_bytes(cfg, tcfg) -> int:
     page_size = cfg.serving_page_size
     itemsize = (1 if cfg.serving_kv_dtype == "int8"
                 else jnp.dtype(tcfg.dtype).itemsize)
-    row = tcfg.n_layers * page_size * tcfg.kv_heads
+    row = tcfg.kv_layers * page_size * tcfg.kv_heads
     per_page = row * tcfg.d_head * itemsize * 2  # K + V
     if cfg.serving_kv_dtype == "int8":
         per_page += row * 4 * 2  # fp32 scale_k + scale_v

@@ -174,7 +174,7 @@ def test_mosaic_refuses_the_kernel_over_several_chips(v5e, pool_spec):
         jax.jit(fn).lower(*args)
 
 
-# The benchmark's cell (benchmark/configs/starcoder2-3b.toml): 16 layers
+# The benchmark's cell (benchmark/configs/starcoder2-3b.json): 16 layers
 # of StarCoder2-3B's widths, 768 pages of 128 tokens, 64 slots of 24
 # pages, and the product's window of 64 steps and prefill chunk of 64.
 _CELL = dict(vocab=49152, d_model=3072, n_heads=24, n_kv_heads=2,
@@ -302,6 +302,103 @@ def test_cell_programs_cast_no_weight(chip, monkeypatch, program):
         if m and np.prod([int(d) for d in m.group(1).split(",")]) in sizes:
             cast.append(line.strip()[:120])
     assert not cast, f"{program} casts weights each time it runs: {cast}"
+
+
+def _patterned_cell_program(program: str, chip, monkeypatch):
+    """The other cell (benchmark/configs/granite-4.0-h-small.json): one
+    period of the patterned block at its published widths, 36 of 72
+    experts held, 64 slots of recurrent state beside 1,536 pages of one
+    attention layer, a window of 16 steps; the sizes made as the server
+    makes them, from the file through ``model_of`` and ``[model]``."""
+    import kvedge_tpu.ops
+    from benchmark import cellspec
+    from kvedge_tpu.config.runtime_config import RuntimeConfig
+    from kvedge_tpu.models import hybrid, kvcache
+    from kvedge_tpu.runtime.workload import derive_model_config
+
+    monkeypatch.setattr(kvedge_tpu.ops, "pallas_interpret", lambda: False)
+    cell = cellspec.load_cell("granite-4.0-h-small.batchgen")
+    payload = cell.config["payload"]
+    one = jax.devices()[:1]
+    with monkeypatch.context() as patch:
+        patch.setattr(jax, "devices", lambda *a, **k: one)
+        cfg, _ = derive_model_config(
+            RuntimeConfig.from_mapping(
+                cellspec.runtime_document(cell, "<dir>", "cpu")),
+            seq=payload["seq"])
+    import dataclasses
+
+    cfg = dataclasses.replace(cfg, dtype="bfloat16",
+                              paged_attention="kernel")
+    slots, pages = payload["serving_slots"], payload["serving_pages"]
+    page = payload["serving_page_size"]
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def abstract(make):
+        return jax.tree_util.tree_map(
+            lambda a: on_chip(a.shape, a.dtype), jax.eval_shape(make))
+
+    params = abstract(lambda: hybrid.init_params(jax.random.PRNGKey(0),
+                                                 cfg))
+    pool = on_chip((cfg.kv_layers, pages, page, cfg.kv_heads * cfg.d_head),
+                   jnp.bfloat16)
+    state = kvcache.PagedState(
+        pool_k=pool, pool_v=pool,
+        tables=on_chip((slots, cfg.max_seq // page), jnp.int32),
+        lengths=on_chip((slots,), jnp.int32),
+        recurrent=abstract(lambda: hybrid.fresh_recurrent(cfg, slots)))
+    if program == "prefill":
+        lowered = kvcache._paged_prefill.lower(
+            params, state, on_chip((64,), jnp.int32),
+            on_chip((), jnp.int32), cfg, on_chip((), jnp.int32))
+    else:
+        def row(dtype):
+            return on_chip((slots,), dtype)
+
+        lowered = kvcache._paged_decode_window_capped.lower(
+            params, state, row(jnp.int32), cfg, payload["serving_window"],
+            row(jnp.bool_), row(jnp.int32), row(jnp.int32))
+    return cfg, params, state, lowered
+
+
+@pytest.mark.parametrize("program", ["decode_window", "prefill"])
+def test_the_patterned_cell_fits_and_leaves_its_state_where_it_is(
+        chip, monkeypatch, program):
+    """Compiled for the chip at the cell's shapes: the weights are 9.5
+    GB in bf16 and no leaf is float32 but the small ones; the window and
+    the prefill chunk fit the chip beside them with room (ISSUE 33's
+    line for falling back to 48 slots is 15.0 GB); and the recurrent
+    state rides the layer loop's carry as the pool does: no temporary of
+    its size (2.4 GB) or of a layer's (0.27 GB: a prefill chunk once
+    copied every row's state into another layout and back, 2.4 GB of
+    temporaries)."""
+    cfg, params, state, lowered = _patterned_cell_program(program, chip,
+                                                          monkeypatch)
+    leaves = jax.tree_util.tree_leaves(params)
+    weights = sum(a.size * a.dtype.itemsize for a in leaves)
+    assert 9.4e9 < weights < 9.6e9
+    assert max(a.size for a in leaves if a.dtype == jnp.float32) \
+        == cfg.n_layers * cfg.d_model * cfg.n_experts  # the router
+    rows = state.recurrent["ssm"]
+    assert rows.shape == (9, 64, 8192, 128) and rows.dtype == jnp.float32
+    compiled = lowered.compile()
+    assert ("tpu_custom_call" in compiled.as_text()) \
+        == (program == "decode_window")
+    memory = compiled.memory_analysis()
+    needs = (memory.argument_size_in_bytes + memory.output_size_in_bytes
+             - memory.alias_size_in_bytes + memory.temp_size_in_bytes)
+    print(f"{program} at the patterned cell's shapes: needs "
+          f"{needs / 1e9:.3f} GB, {memory.temp_size_in_bytes / 1e9:.3f} GB "
+          f"of it temporaries")
+    assert needs < 15.0e9
+    layer_state = rows.size * 4 // rows.shape[0]
+    assert memory.temp_size_in_bytes < layer_state // 2, (
+        f"{program}: {memory.temp_size_in_bytes / 1e9:.2f} GB of "
+        "temporaries is a layer's recurrent state or more")
+    # donated and updated in place: what comes out aliases what went in
+    assert memory.alias_size_in_bytes >= rows.size * 4
 
 
 def test_scale_budget_case_sits_on_the_budget():

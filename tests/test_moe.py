@@ -267,12 +267,12 @@ SERVE_CFG = dataclasses.replace(
 
 
 @pytest.mark.parametrize("top_k", [1, 2])
-@pytest.mark.parametrize("tokens", [150, 160])  # non-multiple + multiple of 64
-def test_serving_block_chunked_path_matches_gather(top_k, tokens):
-    # Past _GATHER_MAX_TOKENS the serving block runs the same per-token
-    # gather chunked under lax.map — routing is per-token identical
-    # (padding included); only matmul rounding may differ across chunk
-    # shapes.
+@pytest.mark.parametrize("tokens", [150, 160])
+def test_serving_block_matches_per_token_gather(top_k, tokens):
+    # The serving block is one product over every held expert
+    # (moe.held_experts_ffn). Against it, each token run through the
+    # matrices of its own picks, gathered token by token: what the block
+    # used to do, kept here as the plain statement of dropless routing.
     from kvedge_tpu.models import moe
 
     key = jax.random.PRNGKey(8)
@@ -282,17 +282,22 @@ def test_serving_block_chunked_path_matches_gather(top_k, tokens):
     x = jax.random.normal(key, (2, tokens // 2, 16), jnp.float32)
 
     big = moe.routed_ffn_block(x, router, w_up, w_down, top_k=top_k)
-    gathered = moe.moe_ffn_dropless(
-        x.reshape(tokens, 16), router, w_up, w_down, top_k=top_k
-    ).reshape(x.shape)
+    flat = x.reshape(tokens, 16)
+    _, idx, gates = moe._route(flat, router, top_k)
+    gathered = sum(
+        jnp.einsum("nf,nfd->nd",
+                   jax.nn.gelu(jnp.einsum("nd,ndf->nf", flat,
+                                          w_up[idx[:, c]])),
+                   w_down[idx[:, c]]) * gates[:, c, None]
+        for c in range(top_k)).reshape(x.shape)
     np.testing.assert_allclose(
         np.asarray(big), np.asarray(gathered), rtol=1e-4, atol=1e-4
     )
 
 
 def test_moe_long_prompt_prefill_matches_forward():
-    # A prompt past _GATHER_MAX_TOKENS routes prefill through the einsum
-    # dispatch path; greedy decode must still agree with teacher forcing.
+    # A long prompt's prefill is the same product over all experts as a
+    # decode step's; greedy decode must still agree with teacher forcing.
     from kvedge_tpu.models import generate
     from kvedge_tpu.models.transformer import forward
 
