@@ -18,8 +18,9 @@ Everything worth reading goes on earlier lines; the last line of stdout
 is one JSON object, ``{"ok": true, "device": {"platform": "tpu",
 "kind": "...", "count": 1}}``. Exit code 0 only with ``"ok": true``:
 not on a CPU backend, not on a degraded runtime handle, not when a
-request fails or the pool had to heal itself, and not when the compiled
-decode kernel and the gather give different bits or tokens.
+request fails or the pool had to heal itself, not when the compiled
+decode kernel and the gather give different bits or tokens, and not
+when the SSM step kernel and the plain one-token form part.
 
 One process holds the chip; nothing here starts a child that needs it.
 """
@@ -748,6 +749,73 @@ def phase_attention_op(smoke: Smoke) -> None:
                                "gather reference beyond bf16 rounding")
 
 
+def phase_ssm_op(smoke: Smoke) -> None:
+    """The one-pass SSM step kernel (ops/ssm_step.py) against the plain
+    ``models.ssm._one_token`` on one random stacked state at a small
+    size it tiles (3 layers, 5 slots, 4 heads of 64, state 128; the
+    first 4 slots are the batch's rows, one of them not decoding): the
+    live rows' new state and ``y`` within float32 rounding, and every
+    other layer, the row that is not decoding and the slot past the
+    batch unchanged in every bit, after a call that donates the state.
+    Compiled on the TPU, interpreted elsewhere."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from kvedge_tpu.models.ssm import _one_token
+    from kvedge_tpu.ops import pallas_interpret
+    from kvedge_tpu.ops.ssm_step import ssm_step
+
+    layers, slots, rows, heads, p, n, layer = 3, 5, 4, 4, 64, 128, 1
+    with smoke.phase("ssm-op") as entry:
+        keys = jax.random.split(jax.random.PRNGKey(smoke.seed), 6)
+        state = jax.random.normal(keys[0], (layers, slots, heads * p, n),
+                                  jnp.float32)
+        x = jax.random.normal(keys[1], (rows, heads, p), jnp.float32)
+        b = jax.random.normal(keys[2], (rows, n), jnp.float32)
+        c = jax.random.normal(keys[3], (rows, n), jnp.float32)
+        dt = jax.random.uniform(keys[4], (rows, heads), jnp.float32,
+                                1e-3, 1e-1)
+        a = -jax.random.uniform(keys[5], (heads,), jnp.float32, 1.0, 16.0)
+        live = np.array([True, True, False, True])
+        before = np.asarray(state)
+        want_y, want = jax.jit(_one_token)(state[layer, :rows], x, b, c,
+                                           dt, a)
+        got_y, got = jax.jit(
+            lambda *args: ssm_step(*args, interpret=pallas_interpret()),
+            donate_argnums=(0,),
+        )(state, jnp.asarray(layer, jnp.int32), x, b, c, dt, a,
+          jnp.asarray(live))
+        got, got_y = np.asarray(got), np.asarray(got_y)[live]
+        want, want_y = np.asarray(want)[live], np.asarray(want_y)[live]
+        touched = np.zeros(before.shape[:2], bool)
+        touched[layer, :rows] = live
+        moved = int((got[~touched].view(np.uint32)
+                     != before[~touched].view(np.uint32)).sum())
+        new = got[layer, :rows][live]
+        differing = int((new.view(np.uint32) != want.view(np.uint32)).sum())
+        state_gap = float(np.abs(new - want).max() / np.abs(want).max())
+        y_gap = float(np.abs(got_y - want_y).max() / np.abs(want_y).max())
+        entry.update(elements=int(new.size), differing=differing,
+                     state_rel_gap=state_gap, y_rel_gap=y_gap,
+                     untouched_moved=moved)
+        smoke.say(f"ssm op, kernel vs plain one-token form at {rows} rows "
+                  f"of {heads} heads of {p}, state {n}, layer {layer} of "
+                  f"{layers}: {differing} of {new.size} float32 elements "
+                  f"of the new state differ, largest gap {state_gap:.3g} "
+                  f"of its scale; y within {y_gap:.3g} of its scale; "
+                  f"{moved} elements of the other layers, the dead row "
+                  f"and the slot past the batch moved")
+        if moved:
+            raise SmokeFailure(
+                f"the ssm step kernel changed {moved} elements of state "
+                f"it was not asked to touch")
+        if not (state_gap <= 1e-6 and y_gap <= 1e-5):
+            raise SmokeFailure("the ssm step kernel disagrees with the "
+                               "plain one-token form beyond float32 "
+                               "rounding")
+
+
 def phase_reference_one_chip(smoke: Smoke):
     """What the four-chip path is compared with: the same steps and the
     same greedy requests on ONE chip of this process — params and pool
@@ -836,6 +904,7 @@ def run_one_chip(smoke: Smoke) -> None:
     parted = compare_tokens(smoke, "paged_attention auto (kernel on the "
                             "chip) vs gather", kernel, gather)
     phase_attention_op(smoke)
+    phase_ssm_op(smoke)
     if parted:
         # "auto" is a routing choice only while both paths give one
         # answer; the op comparison above says how far apart they are.

@@ -55,7 +55,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from kvedge_tpu.models.moe import ffn_activation, held_experts_ffn
-from kvedge_tpu.models.ssm import mamba_mixer
+from kvedge_tpu.models.ssm import mamba_mixer, step_in_kernel
 from kvedge_tpu.models.transformer import TransformerConfig, _rmsnorm
 
 # Leaf numbers of the recipe: a leaf keeps its number when others are
@@ -250,6 +250,10 @@ def run_layers(cfg: TransformerConfig, params: dict, x, pools, recurrent,
         def put(state, layer, new):
             return state.at[layer, slot].set(new[0])
 
+    # A decode step on the chip: the mixer's kernel works on the stacked
+    # state where it lies, so there is nothing to take or put.
+    in_kernel = step_in_kernel(cfg, slot, x.shape[1])
+
     def body(carry, xs):
         x, pools, ssm, conv, picks = carry
         weights, period = xs
@@ -260,10 +264,15 @@ def run_layers(cfg: TransformerConfig, params: dict, x, pools, recurrent,
             if kind == "mamba":
                 layer = period * n_mamba + seen[kind]
                 with jax.named_scope("kvedge/ssm"):
-                    out, new_ssm, new_tail = mamba_mixer(
-                        cfg, h, w, take(ssm, layer), take(conv, layer),
-                        live)
-                    ssm = put(ssm, layer, new_ssm)
+                    if in_kernel:
+                        out, ssm, new_tail = mamba_mixer(
+                            cfg, h, w, ssm, take(conv, layer), live,
+                            layer=layer)
+                    else:
+                        out, new_ssm, new_tail = mamba_mixer(
+                            cfg, h, w, take(ssm, layer),
+                            take(conv, layer), live)
+                        ssm = put(ssm, layer, new_ssm)
                     conv = put(conv, layer, new_tail)
             else:
                 with jax.named_scope("kvedge/attention"):

@@ -18,7 +18,15 @@ layout and back on every prefill chunk)
 * the **one-token form** (a decode step, Q = 1) is that line, once, for
   every row of the batch: elementwise over the rows' states, which is
   what a step reads and writes (4 MB a row and layer at 128 heads of
-  64 with state 128) and almost no operations;
+  64 with state 128) and almost no operations. Written in
+  ``jax.numpy`` (:func:`_one_token`) XLA makes two passes of it, the
+  update in place and then ``y`` as a reduction of its own, so the
+  state is read twice; in a decode step or window on a TPU backend, at
+  sizes it tiles, it is one pass in a kernel instead
+  (ops/ssm_step.py, handed the stacked state whole and updating it in
+  place; :func:`step_in_kernel` decides, from the trace alone).
+  ``_one_token`` stays the form of a one-token prefill piece, of every
+  other backend and size, and what the kernel is held to;
 * the **chunk form** (a prefill chunk, Q > 1) is the same sum written
   out over a block of positions (the state-space dual form): within a
   block ``y_t = sum_{s<=t} exp(a_t - a_s) (C_t . B_s) dt_s x_s`` with
@@ -43,7 +51,27 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from kvedge_tpu.ops import ssm_step
+
 _HIGHEST = lax.Precision.HIGHEST
+
+
+def _on_tpu() -> bool:
+    """Apart from ``ops.pallas_interpret`` so that a CPU test can take
+    the kernel and still run it in the interpreter."""
+    return jax.default_backend() == "tpu"
+
+
+def step_in_kernel(cfg, slot, q_len: int) -> bool:
+    """Whether this trace's one-token form is the kernel
+    (ops/ssm_step.py): a decode step or window (the batch's rows are
+    the first slots and each brings one token: the test kvcache makes
+    for the paged-attention kernel), on a TPU backend, at sizes the
+    kernel tiles. Decided from what the trace can see; there is no
+    option. A one-token prefill piece, every other backend and every
+    other size keep :func:`_one_token`."""
+    return (slot is None and q_len == 1 and _on_tpu()
+            and ssm_step.tiles(cfg.ssm_inner, cfg.ssm_state))
 
 
 def _one_token(S, x, B, C, dt, A):
@@ -78,7 +106,7 @@ def _block(S, x, B, C, dt, A):
     return y, S
 
 
-def mamba_mixer(cfg, h, w: dict, ssm, tail, live=None):
+def mamba_mixer(cfg, h, w: dict, ssm, tail, live=None, layer=None):
     """The mixer over normed activations ``h`` [R, Q, D] of R rows.
 
     ``w``: one layer's ``w_in`` [D, 2I + 2N + H], ``conv_w`` [K, C],
@@ -89,6 +117,12 @@ def mamba_mixer(cfg, h, w: dict, ssm, tail, live=None):
     that is not live gets its state back untouched. Q == 1 runs the
     one-token form, Q > 1 the chunk form. Returns
     ``(out [R, Q, D], ssm, tail)``.
+
+    With ``layer`` given (:func:`step_in_kernel` said so), ``ssm`` is
+    the whole stacked state [mamba layers, slots, H * P, N], the rows
+    are its first R slots, and the one-token form is the kernel on
+    layer ``layer`` of it, in place: what comes back is the stacked
+    state.
     """
     rows, q_len, _ = h.shape
     heads, p, n = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state
@@ -116,7 +150,14 @@ def mamba_mixer(cfg, h, w: dict, ssm, tail, live=None):
     dt = jax.nn.softplus(dt.astype(f32) + w["dt_bias"])
     a_neg = -jnp.exp(w["A_log"])
 
-    if q_len == 1:
+    if layer is not None:
+        from kvedge_tpu.ops import pallas_interpret
+
+        y, new_ssm = ssm_step.ssm_step(
+            ssm, layer, x[:, 0], b_in[:, 0], c_in[:, 0], dt[:, 0], a_neg,
+            live, interpret=pallas_interpret())
+        y = y[:, None]
+    elif q_len == 1:
         y, new_ssm = _one_token(ssm, x[:, 0], b_in[:, 0], c_in[:, 0],
                                 dt[:, 0], a_neg)
         y = y[:, None]
@@ -136,6 +177,7 @@ def mamba_mixer(cfg, h, w: dict, ssm, tail, live=None):
         jnp.mean(gated * gated, axis=-1, keepdims=True) + cfg.norm_eps)
     out = (gated * w["norm"]).astype(dtype) @ w["w_out"].astype(dtype)
     if live is not None:
-        new_ssm = jnp.where(live[:, None, None], new_ssm, ssm)
+        if layer is None:  # the kernel left those rows as they were
+            new_ssm = jnp.where(live[:, None, None], new_ssm, ssm)
         new_tail = jnp.where(live[:, None], new_tail, tail)
     return out, new_ssm, new_tail.astype(tail.dtype)

@@ -20,6 +20,7 @@ import os
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -313,10 +314,13 @@ def _patterned_cell_program(program: str, chip, monkeypatch):
     import kvedge_tpu.ops
     from benchmark import cellspec
     from kvedge_tpu.config.runtime_config import RuntimeConfig
-    from kvedge_tpu.models import hybrid, kvcache
+    from kvedge_tpu.models import hybrid, kvcache, ssm
     from kvedge_tpu.runtime.workload import derive_model_config
 
     monkeypatch.setattr(kvedge_tpu.ops, "pallas_interpret", lambda: False)
+    # jax.default_backend() is the CPU here; on the chip the decode
+    # window's one-token SSM form is the kernel (ssm.step_in_kernel).
+    monkeypatch.setattr(ssm, "_on_tpu", lambda: True)
     cell = cellspec.load_cell("granite-4.0-h-small.batchgen")
     payload = cell.config["payload"]
     one = jax.devices()[:1]
@@ -373,7 +377,11 @@ def test_the_patterned_cell_fits_and_leaves_its_state_where_it_is(
     state rides the layer loop's carry as the pool does: no temporary of
     its size (2.4 GB) or of a layer's (0.27 GB: a prefill chunk once
     copied every row's state into another layout and back, 2.4 GB of
-    temporaries)."""
+    temporaries). The decode window holds the one-pass SSM step kernel
+    (ops/ssm_step.py) once for each of the period's nine mamba layers,
+    handed the stacked state whole: no copy of it, and no more memory
+    than the 12.81 GB the window needed with the state updated by XLA's
+    own fusions (PERF.md section 4, PR 33)."""
     cfg, params, state, lowered = _patterned_cell_program(program, chip,
                                                           monkeypatch)
     leaves = jax.tree_util.tree_leaves(params)
@@ -384,8 +392,21 @@ def test_the_patterned_cell_fits_and_leaves_its_state_where_it_is(
     rows = state.recurrent["ssm"]
     assert rows.shape == (9, 64, 8192, 128) and rows.dtype == jnp.float32
     compiled = lowered.compile()
-    assert ("tpu_custom_call" in compiled.as_text()) \
-        == (program == "decode_window")
+    text = compiled.as_text()
+    # The window's step body: nine mamba layers and the attention layer.
+    # A prefill chunk, one row's slot given, takes neither kernel.
+    window = program == "decode_window"
+    assert text.count('custom_call_target="tpu_custom_call"') \
+        == (10 if window else 0)
+    assert lowered.as_text().count('kernel_name = "ssm_step"') \
+        == (9 if window else 0)
+    if window:
+        # Nothing but the kernel makes an array of the state's size: it
+        # comes in as a parameter and goes through the nine calls.
+        makers = set(re.findall(
+            r" = f32\[9,64,8192,128\]\{[^}]*\} ([\w-]+)\(", text))
+        assert makers <= {"custom-call", "parameter", "get-tuple-element"}, \
+            makers
     memory = compiled.memory_analysis()
     needs = (memory.argument_size_in_bytes + memory.output_size_in_bytes
              - memory.alias_size_in_bytes + memory.temp_size_in_bytes)
@@ -393,6 +414,8 @@ def test_the_patterned_cell_fits_and_leaves_its_state_where_it_is(
           f"{needs / 1e9:.3f} GB, {memory.temp_size_in_bytes / 1e9:.3f} GB "
           f"of it temporaries")
     assert needs < 15.0e9
+    if window:
+        assert needs <= 12.815e9
     layer_state = rows.size * 4 // rows.shape[0]
     assert memory.temp_size_in_bytes < layer_state // 2, (
         f"{program}: {memory.temp_size_in_bytes / 1e9:.2f} GB of "

@@ -42,7 +42,8 @@ def test_smoke_phases_rehearsed_on_cpu(chips, tmp_path, monkeypatch, capsys):
     phases = report["phases"]
     mesh = "{ data = 2, model = 2 }"
     wanted = (
-        ["train[state]", "serve[auto]", "serve[gather]", "attention-op"]
+        ["train[state]", "serve[auto]", "serve[gather]", "attention-op",
+         "ssm-op"]
         if chips == 1 else
         ["reference-train[1 chip]", "reference-serve[1 chip]",
          "train[state]", f"serve[{mesh}]"])
