@@ -816,6 +816,90 @@ def phase_ssm_op(smoke: Smoke) -> None:
                                "rounding")
 
 
+def phase_delta_op(smoke: Smoke) -> None:
+    """The delta-rule mixer's two forms (models/delta.py) against the
+    recurrence written out, on one random stacked state at a small size
+    (3 layers, 5 slots, 4 heads of 128 key and 128 value channels, 48
+    positions): the recurrence position by position in numpy float64 is
+    what both are held to; the one-token form steps layer 1's first 4
+    slots through the positions, the chunk form runs the same positions
+    in blocks of 16 from the same state (its triangular solve as the
+    backend expands it). Compiled by the backend's own compiler: on the
+    TPU this is where a product or a solve in less than float32 would
+    show."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from kvedge_tpu.models import delta
+
+    layers, slots, rows, heads, dk, dv, layer = 3, 5, 4, 4, 128, 128, 1
+    positions, block = 48, 16
+    with smoke.phase("delta-op") as entry:
+        keys = jax.random.split(jax.random.PRNGKey(smoke.seed), 6)
+        state = 0.1 * jax.random.normal(
+            keys[0], (layers, slots, heads, dk, dv), jnp.float32)
+        shape = (rows, positions, heads)
+        q = delta._l2norm(jax.random.normal(keys[1], (*shape, dk))) \
+            * dk ** -0.5
+        k = delta._l2norm(jax.random.normal(keys[2], (*shape, dk)))
+        v = jax.random.normal(keys[3], (*shape, dv))
+        # decays from none at all to all of it within a position
+        g = -jnp.exp(jax.random.uniform(keys[4], (*shape, dk), jnp.float32,
+                                        -12.0, 4.0))
+        beta = 2.0 * jax.nn.sigmoid(
+            2.0 * jax.random.normal(keys[5], shape))
+        start = state[layer, :rows]
+
+        @jax.jit
+        def stepped(S):
+            def step(S, now):
+                o, S = delta._one_token(S, *now)
+                return S, o
+            S, o = jax.lax.scan(
+                step, S, tuple(a.swapaxes(0, 1) for a in (q, k, v, g, beta)))
+            return o.swapaxes(0, 1), S
+
+        @jax.jit
+        def chunked(S):
+            out = []
+            for lo in range(0, positions, block):
+                o, S = jax.vmap(delta._block)(
+                    S, *(a[:, lo:lo + block] for a in (q, k, v, g, beta)))
+                out.append(o)
+            return jnp.concatenate(out, axis=1), S
+
+        f64 = [np.asarray(a, np.float64) for a in (q, k, v, g, beta)]
+        want_S = np.asarray(start, np.float64)
+        want_o = np.zeros((rows, positions, heads, dv))
+        for t in range(positions):
+            qt, kt, vt, gt, bt = (a[:, t] for a in f64)
+            decayed = np.exp(gt)[..., None] * want_S
+            u = bt[..., None] * (vt - np.einsum("rhkv,rhk->rhv", decayed,
+                                                kt))
+            want_S = decayed + kt[..., None] * u[:, :, None, :]
+            want_o[:, t] = np.einsum("rhkv,rhk->rhv", want_S, qt)
+        gaps = {}
+        for name, form in (("one-token", stepped), ("chunk", chunked)):
+            o, S = form(start)
+            gaps[name] = (
+                float(np.abs(np.asarray(o) - want_o).max()
+                      / np.abs(want_o).max()),
+                float(np.abs(np.asarray(S) - want_S).max()
+                      / np.abs(want_S).max()))
+        entry.update(positions=positions, block=block, gaps=gaps)
+        smoke.say(
+            f"delta op, {rows} rows of {heads} heads of {dk} x {dv} over "
+            f"{positions} positions against the recurrence in float64: "
+            + "; ".join(f"the {name} form's outputs within {o:.3g} of their "
+                        f"scale and its state within {S:.3g}"
+                        for name, (o, S) in gaps.items()))
+        if not all(o <= 2e-5 and S <= 2e-5 for o, S in gaps.values()):
+            raise SmokeFailure("a form of the delta-rule mixer disagrees "
+                               "with the recurrence beyond float32 "
+                               "rounding")
+
+
 def phase_reference_one_chip(smoke: Smoke):
     """What the four-chip path is compared with: the same steps and the
     same greedy requests on ONE chip of this process — params and pool
@@ -905,6 +989,7 @@ def run_one_chip(smoke: Smoke) -> None:
                             "chip) vs gather", kernel, gather)
     phase_attention_op(smoke)
     phase_ssm_op(smoke)
+    phase_delta_op(smoke)
     if parted:
         # "auto" is a routing choice only while both paths give one
         # answer; the op comparison above says how far apart they are.

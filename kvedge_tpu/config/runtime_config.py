@@ -177,13 +177,17 @@ class ModelSpec:
     # parallel/pipeline1f1b.py documents the refusals).
     pipeline_schedule: str = ""
     # A patterned block (models/hybrid.py; SERVING.md "Recurrent
-    # state"): one period of layer kinds, "mamba" or "attention",
+    # state"): one period of layer kinds, "attention" and one
+    # recurrent kind ("mamba" or "delta", sized by the ``ssm_*`` keys),
     # repeated to ``n_layers``. () = every layer rotary attention with a
     # GELU feed-forward, the block above. With a pattern every layer's
     # feed-forward is ``experts`` routed experts of width ``d_ff``,
     # ``expert_top_k`` (any number) a token, plus a shared expert of
     # width ``shared_ff``, SiLU-gated when ``ffn_gated``; this device
     # holds ``experts_held`` of them (0 = all) from ``expert_first`` on.
+    # ``head_dim`` is the attention heads' size (0 = d_model / n_heads),
+    # ``attention_gate`` an output gate on the attention layers,
+    # ``untied_head`` a head that is not the embedding.
     # Served by ``serving = "paged"`` on one device, nothing else.
     layer_pattern: tuple = ()
     ssm_heads: int = 0
@@ -191,10 +195,14 @@ class ModelSpec:
     ssm_state: int = 0
     ssm_conv: int = 0   # 0 = 4
     ssm_chunk: int = 0  # 0 = 256
+    ssm_gate_rank: int = 0
     experts_held: int = 0
     expert_first: int = 0
     shared_ff: int = 0
+    head_dim: int = 0
     ffn_gated: bool = False
+    attention_gate: bool = False
+    untied_head: bool = False
     # 0.0 = the plain block's: 1, 1, 1/sqrt(d_head), 1.
     embedding_multiplier: float = 0.0
     residual_multiplier: float = 0.0
@@ -206,12 +214,18 @@ class ModelSpec:
     _PATTERN_INTS = (
         "ssm_heads", "ssm_head_dim", "ssm_state", "ssm_conv", "ssm_chunk",
         "experts_held", "expert_first", "shared_ff",
+        "ssm_gate_rank", "head_dim",
     )
+    _PATTERN_BOOLS = ("ffn_gated", "attention_gate", "untied_head")
     _PATTERN_FLOATS = (
         "embedding_multiplier", "residual_multiplier",
         "attention_multiplier", "logits_scaling", "norm_eps",
     )
-    _PATTERN_KEYS = _PATTERN_INTS + ("ffn_gated",) + _PATTERN_FLOATS
+    _PATTERN_KEYS = _PATTERN_INTS + _PATTERN_BOOLS + _PATTERN_FLOATS
+    # Keys a document states only where they are set: a block without
+    # them keeps the document it had before they existed.
+    _PATTERN_LATER = ("ssm_gate_rank", "head_dim", "attention_gate",
+                      "untied_head")
 
     def validate(self) -> None:
         if self.preset not in _VALID_PRESETS:
@@ -239,8 +253,8 @@ class ModelSpec:
             )
         if not all(isinstance(kind, str) for kind in self.layer_pattern):
             raise RuntimeConfigError(
-                "[model] layer_pattern must be a list of \"mamba\" and "
-                "\"attention\"")
+                "[model] layer_pattern must be a list of \"mamba\", "
+                "\"delta\" and \"attention\"")
         for field_name in self._PATTERN_INTS:
             value = getattr(self, field_name)
             if not isinstance(value, int) or isinstance(value, bool) \
@@ -655,8 +669,9 @@ class RuntimeConfig:
                     ),
                     layer_pattern=tuple(
                         model_doc.get("layer_pattern", ())),
-                    ffn_gated=bool(model_doc.get("ffn_gated", False)),
                     rotary=bool(model_doc.get("rotary", True)),
+                    **{key: bool(model_doc.get(key, False))
+                       for key in ModelSpec._PATTERN_BOOLS},
                     **{key: int(model_doc.get(key, 0))
                        for key in ModelSpec._PATTERN_INTS},
                     **{key: float(model_doc.get(key, 0.0))
@@ -1188,6 +1203,8 @@ class RuntimeConfig:
                  f"rotary = {str(m.rotary).lower()}"]
         for key in m._PATTERN_KEYS:
             value = getattr(m, key)
+            if key in m._PATTERN_LATER and not value:
+                continue
             lines.append(f"{key} = "
                          + (str(value).lower() if isinstance(value, bool)
                             else repr(value)))

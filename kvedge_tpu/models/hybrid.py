@@ -1,5 +1,6 @@
-"""The patterned block: Mamba-2 and attention mixers in a repeating
-pattern of layers, every layer with routed and shared gated experts.
+"""The patterned block: recurrent mixers (Mamba-2, or a delta rule)
+and attention mixers in a repeating pattern of layers, every layer with
+routed and shared gated experts.
 
 Written once, for the paged serving path (models/kvcache.py runs it
 from ``_run_paged``); the trainer, the contiguous cache and a mesh of
@@ -11,18 +12,22 @@ several devices refuse a model with a ``layer_pattern``. The equations
     x = e * E[tokens]
     per layer:  x = x + r * Mixer(norm(x));  h = norm(x)
                 x = x + r * (Routed(h) + Shared(h))
-    logits = norm(x) @ E.T / s
+    logits = norm(x) @ E.T / s          (``head.T`` for ``E.T`` where the
+                                         tree has a head of its own)
 
-``Mixer`` is models/ssm.py's Mamba-2 mixer or kvcache's paged attention
-(no rotary when ``cfg.rotary`` is false, scores scaled by
-``cfg.attention_multiplier``); ``Routed`` is moe.held_experts_ffn over
-the experts this device holds; ``Shared`` a gated SiLU MLP every token
-passes.
+``Mixer`` is the pattern's recurrent kind, models/ssm.py's Mamba-2
+mixer ("mamba") or models/delta.py's delta rule ("delta"; a pattern
+holds one of the two), or kvcache's paged attention (no rotary when
+``cfg.rotary`` is false, scores scaled by ``cfg.attention_multiplier``,
+``sigmoid(h W_gate)`` times what it attended where the layer has a
+``w_gate``); ``Routed`` is moe.held_experts_ffn over the experts this
+device holds; ``Shared`` a gated SiLU MLP every token passes.
 
 **Weights** are a named tree per layer kind, every leaf stacked over
 ``[periods, layers of that kind in a period, ...]``:
-``params["mamba"]``, ``params["attention"]``, ``params["ffn"]`` (one
-entry per layer of the period), beside ``embedding`` and ``ln_final``.
+``params["mamba"]`` or ``params["delta"]``, ``params["attention"]``,
+``params["ffn"]`` (one entry per layer of the period), beside
+``embedding``, ``ln_final`` and, with ``cfg.untied_head``, ``head``.
 The layer loop scans over periods and runs the period's layers in its
 body, so one period's program is compiled whatever the depth.
 
@@ -40,9 +45,12 @@ residual stream starts at the 0.02 it starts at in the plain block (at
 0.02 itself the tied head's logit for the token just read stood 15
 standard deviations above every other's, 12 * 0.02 * sqrt(4096), and
 a greedy row repeated its prompt's last token for ever: nothing a
-check of served tokens could tell one precision from another by); for the SSM the Mamba-2 paper's own: ``A`` uniform in
-[1, 16) (``A_log`` its log), ``dt`` log-uniform in [0.001, 0.1) and
-``dt_bias`` its inverse softplus, ``D`` one.
+check of served tokens could tell one precision from another by); a
+head of its own is normal times 0.02; for the SSM the Mamba-2 paper's
+own: ``A`` uniform in [1, 16) (``A_log`` its log), ``dt`` log-uniform in
+[0.001, 0.1) and ``dt_bias`` its inverse softplus, ``D`` one; a delta
+layer's decay takes the same two draws, ``A_log`` a head and
+``dt_bias`` a key channel.
 """
 
 from __future__ import annotations
@@ -54,6 +62,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from kvedge_tpu.models import delta
 from kvedge_tpu.models.moe import ffn_activation, held_experts_ffn
 from kvedge_tpu.models.ssm import mamba_mixer, step_in_kernel
 from kvedge_tpu.models.transformer import TransformerConfig, _rmsnorm
@@ -68,7 +77,16 @@ _LEAVES = {
     ("ffn", "router"): 9, ("ffn", "experts_in"): 10,
     ("ffn", "experts_out"): 11, ("ffn", "shared_in"): 12,
     ("ffn", "shared_out"): 13,
+    ("delta", "w_qkv"): 14, ("delta", "conv_w"): 15, ("delta", "w_low"): 16,
+    ("delta", "w_f2"): 17, ("delta", "w_g2"): 18, ("delta", "A_log"): 19,
+    ("delta", "dt_bias"): 20, ("delta", "w_out"): 21,
+    ("attention", "w_gate"): 22, "head": 23,
 }
+
+_KINDS = ("mamba", "delta", "attention", "ffn")
+# A recurrent kind's mixer and the scope that names it in a trace.
+_MIXERS = {"mamba": (mamba_mixer, "kvedge/ssm"),
+           "delta": (delta.delta_mixer, "kvedge/delta")}
 
 
 def layers_of(cfg: TransformerConfig, kind: str) -> list[int]:
@@ -90,6 +108,7 @@ def _recipes(cfg: TransformerConfig) -> dict:
     h, kv, dh = cfg.n_heads, cfg.kv_heads, cfg.d_head
     heads, n, k = cfg.ssm_heads, cfg.ssm_state, cfg.ssm_conv
     inner, conv_dim = cfg.ssm_inner, cfg.ssm_conv_dim
+    keys, rank = heads * n, cfg.ssm_gate_rank
     gate = 2 if cfg.ffn_gated else 1
 
     def a_log(key, shape):
@@ -112,6 +131,20 @@ def _recipes(cfg: TransformerConfig) -> dict:
             ("mamba", "dt_bias"): ((heads,), dt_bias, False),
             ("mamba", "w_out"): ((inner, d), _normal(inner ** -0.5), False),
         })
+    if "delta" in cfg.layer_pattern:
+        out.update({
+            ("delta", "w_qkv"): ((d, 2 * keys + inner), _normal(d ** -0.5),
+                                 False),
+            ("delta", "conv_w"): ((k, delta.conv_dim(cfg)),
+                                  _normal(k ** -0.5), False),
+            ("delta", "w_low"): ((d, 2 * rank + heads), _normal(d ** -0.5),
+                                 False),
+            ("delta", "w_f2"): ((rank, keys), _normal(rank ** -0.5), False),
+            ("delta", "w_g2"): ((rank, inner), _normal(rank ** -0.5), False),
+            ("delta", "A_log"): ((heads,), a_log, False),
+            ("delta", "dt_bias"): ((keys,), dt_bias, False),
+            ("delta", "w_out"): ((inner, d), _normal(inner ** -0.5), False),
+        })
     if "attention" in cfg.layer_pattern:
         out.update({
             ("attention", "w_qkv"): ((d, (h + 2 * kv) * dh),
@@ -119,6 +152,9 @@ def _recipes(cfg: TransformerConfig) -> dict:
             ("attention", "w_out"): ((h * dh, d),
                                      _normal((h * dh) ** -0.5), False),
         })
+        if cfg.attention_gate:
+            out[("attention", "w_gate")] = (
+                (d, h * dh), _normal(d ** -0.5), False)
     out.update({
         ("ffn", "router"): ((d, cfg.n_experts), _normal(d ** -0.5), False),
         ("ffn", "experts_in"): ((cfg.held_experts, d, gate * f),
@@ -168,7 +204,7 @@ def init_params(key, cfg: TransformerConfig) -> dict:
         return jax.block_until_ready(
             flat.reshape(periods, len(layers) // periods, *shape))
 
-    params: dict = {"mamba": {}, "attention": {}, "ffn": {}}
+    params: dict = {kind: {} for kind in _KINDS}
     for (kind, leaf), recipe in _recipes(cfg).items():
         params[kind][leaf] = stacked(kind, leaf, *recipe)
 
@@ -180,14 +216,22 @@ def init_params(key, cfg: TransformerConfig) -> dict:
         ones("mamba", "D", cfg.ssm_heads)
         ones("mamba", "norm", cfg.ssm_inner)
         ones("mamba", "ln", cfg.d_model)
+    if params["delta"]:
+        ones("delta", "norm", cfg.ssm_head_dim)
+        ones("delta", "ln", cfg.d_model)
     if params["attention"]:
         ones("attention", "ln", cfg.d_model)
     ones("ffn", "ln", cfg.d_model)
-    scale = 0.02 / cfg.embedding_multiplier
-    params["embedding"] = jax.jit(
-        lambda k: (jax.random.normal(k, (cfg.vocab, cfg.d_model),
-                                     jnp.float32) * scale).astype(dtype)
-    )(jax.random.fold_in(key, _LEAVES["embedding"]))
+    def table(leaf, scale):
+        return jax.jit(
+            lambda k: (jax.random.normal(k, (cfg.vocab, cfg.d_model),
+                                         jnp.float32) * scale).astype(dtype)
+        )(jax.random.fold_in(key, _LEAVES[leaf]))
+
+    params["embedding"] = table("embedding",
+                                0.02 / cfg.embedding_multiplier)
+    if cfg.untied_head:
+        params["head"] = table("head", 0.02)
     params["ln_final"] = jnp.ones((cfg.d_model,), jnp.float32)
     return {name: leaf for name, leaf in params.items() if len(leaf)}
 
@@ -219,8 +263,9 @@ def run_layers(cfg: TransformerConfig, params: dict, x, pools, recurrent,
                attend, slot, live):
     """The layer loop of a patterned block: a scan over periods, the
     period's layers in its body. ``pools`` (the page pool's 4-tuple)
-    and ``recurrent`` (``ssm`` [mamba layers, slots, H * P, N], ``conv``
-    [mamba layers, slots, (K-1)*C], ``picks``) ride the carry whole and
+    and ``recurrent`` (``ssm`` [recurrent layers, slots, a layer's
+    state: :func:`state_shape`], ``conv`` [recurrent layers, slots,
+    (K-1)*C], ``picks``) ride the carry whole and
     are updated in place. ``attend(h, w, layer, pools) -> (out, pools)``
     is the caller's paged attention over normed activations, ``layer``
     its index into the pool. ``x``'s rows are the first R slots, or,
@@ -229,7 +274,8 @@ def run_layers(cfg: TransformerConfig, params: dict, x, pools, recurrent,
     ``(x, pools, recurrent)``.
     """
     pattern = cfg.layer_pattern
-    n_mamba, n_att = pattern.count("mamba"), pattern.count("attention")
+    n_recurrent = len(pattern) - pattern.count("attention")
+    n_att = pattern.count("attention")
     r = jnp.asarray(cfg.residual_multiplier, x.dtype)
 
     def at(tree, i):
@@ -250,34 +296,36 @@ def run_layers(cfg: TransformerConfig, params: dict, x, pools, recurrent,
         def put(state, layer, new):
             return state.at[layer, slot].set(new[0])
 
-    # A decode step on the chip: the mixer's kernel works on the stacked
-    # state where it lies, so there is nothing to take or put.
-    in_kernel = step_in_kernel(cfg, slot, x.shape[1])
+    # A decode step on the chip: the mamba mixer's kernel works on the
+    # stacked state where it lies, so there is nothing to take or put.
+    in_kernel = (cfg.recurrent_kind == "mamba"
+                 and step_in_kernel(cfg, slot, x.shape[1]))
 
     def body(carry, xs):
         x, pools, ssm, conv, picks = carry
         weights, period = xs
-        seen = {"mamba": 0, "attention": 0}
+        seen = dict.fromkeys(pattern, 0)
         for j, kind in enumerate(pattern):
             w = at(weights[kind], seen[kind])
             h = _rmsnorm(x, w["ln"], cfg.norm_eps)
-            if kind == "mamba":
-                layer = period * n_mamba + seen[kind]
-                with jax.named_scope("kvedge/ssm"):
+            if kind == "attention":
+                with jax.named_scope("kvedge/attention"):
+                    out, pools = attend(
+                        h, w, period * n_att + seen[kind], pools)
+            else:
+                layer = period * n_recurrent + seen[kind]
+                mixer, scope = _MIXERS[kind]
+                with jax.named_scope(scope):
                     if in_kernel:
                         out, ssm, new_tail = mamba_mixer(
                             cfg, h, w, ssm, take(conv, layer), live,
                             layer=layer)
                     else:
-                        out, new_ssm, new_tail = mamba_mixer(
+                        out, new_ssm, new_tail = mixer(
                             cfg, h, w, take(ssm, layer),
                             take(conv, layer), live)
                         ssm = put(ssm, layer, new_ssm)
                     conv = put(conv, layer, new_tail)
-            else:
-                with jax.named_scope("kvedge/attention"):
-                    out, pools = attend(
-                        h, w, period * n_att + seen[kind], pools)
             seen[kind] += 1
             x = x + r * out
             x, layer_picks = feed_forward(cfg, x, at(weights["ffn"], j),
@@ -286,8 +334,7 @@ def run_layers(cfg: TransformerConfig, params: dict, x, pools, recurrent,
         return (x, pools, ssm, conv, picks), None
 
     periods = cfg.n_layers // len(pattern)
-    weights = {kind: params.get(kind, {})
-               for kind in ("mamba", "attention", "ffn")}
+    weights = {kind: params.get(kind, {}) for kind in _KINDS}
     (x, pools, ssm, conv, picks), _ = lax.scan(
         body,
         (x, pools, recurrent["ssm"], recurrent["conv"],
@@ -297,18 +344,28 @@ def run_layers(cfg: TransformerConfig, params: dict, x, pools, recurrent,
     return x, pools, {"ssm": ssm, "conv": conv, "picks": picks}
 
 
+def state_shape(cfg: TransformerConfig) -> tuple:
+    """The shape of one layer's recurrent state for one slot, and the
+    channels its conv runs over, by the pattern's recurrent kind: a
+    mamba layer's ``[H * P, N]`` or a delta layer's ``[H, dk, dv]``."""
+    if cfg.recurrent_kind == "delta":
+        return ((cfg.ssm_heads, cfg.ssm_state, cfg.ssm_head_dim),
+                delta.conv_dim(cfg))
+    return (cfg.ssm_inner, cfg.ssm_state), cfg.ssm_conv_dim
+
+
 def fresh_recurrent(cfg: TransformerConfig, slots: int) -> dict:
-    """Zeroed recurrent state for ``slots`` rows: float32 SSM state,
-    the conv's tail in the compute dtype, flat so that its minor
-    dimension is whole lanes, and the window's pick counters."""
+    """Zeroed recurrent state for ``slots`` rows: the float32 state of
+    the pattern's recurrent kind (:func:`state_shape`), the conv's tail
+    in the compute dtype, flat so that its minor dimension is whole
+    lanes, and the window's pick counters (moe.held_experts_ffn)."""
     layers = cfg.ssm_layers
+    shape, channels = state_shape(cfg)
     return {
-        "ssm": jnp.zeros((layers, slots, cfg.ssm_inner, cfg.ssm_state),
-                         jnp.float32),
-        "conv": jnp.zeros((layers, slots,
-                           (cfg.ssm_conv - 1) * cfg.ssm_conv_dim),
+        "ssm": jnp.zeros((layers, slots, *shape), jnp.float32),
+        "conv": jnp.zeros((layers, slots, (cfg.ssm_conv - 1) * channels),
                           jnp.dtype(cfg.dtype)),
-        "picks": jnp.zeros((2 + cfg.held_experts,), jnp.int32),
+        "picks": jnp.zeros((3 + cfg.held_experts,), jnp.int32),
     }
 
 

@@ -75,8 +75,8 @@ class PagedState:
     scale_k: "jax.Array | None" = None  # [L, P, page, K] fp32 (int8 only)
     scale_v: "jax.Array | None" = None
     # A patterned block's state of the second kind (models/hybrid.py
-    # fresh_recurrent): per mamba layer and SLOT (not bucket row: it
-    # cannot be rebuilt from the host as tables are) the float32 SSM
+    # fresh_recurrent): per recurrent layer and SLOT (not bucket row:
+    # it cannot be rebuilt from the host as tables are) the float32
     # state and the conv's tail, and the window's expert-pick counters.
     # The pool then holds the attention layers only. None otherwise.
     recurrent: "dict | None" = None
@@ -287,15 +287,16 @@ class PagedKVCache:
         shape = (cfg.kv_layers, pages, page_size, cfg.kv_heads * cfg.d_head)
         self.state = self._init_state(shape, dtype)
         # What the window programs counted of the routed experts' picks
-        # (a patterned block): [all, on held experts, each held expert],
-        # summed at harvest from what each window returns beside its
-        # tokens (``_picks_of``: the windows not harvested yet).
+        # (a patterned block): [all, on held experts, each held expert,
+        # held experts with a pick by layer and step], summed at harvest
+        # from what each window returns beside its tokens
+        # (``_picks_of``: the windows not harvested yet).
         self.expert_picks = None
         self._picks_of: dict = {}
         if cfg.layer_pattern:
             import numpy as _np
 
-            self.expert_picks = _np.zeros(2 + cfg.held_experts, _np.int64)
+            self.expert_picks = _np.zeros(3 + cfg.held_experts, _np.int64)
         # A phase of the serving layer's clock around the state reset
         # of an admission (``admit/state_reset``), when it has one.
         self.reset_phase = contextlib.nullcontext
@@ -1628,7 +1629,7 @@ def _paged_attend_layer(cfg: TransformerConfig, state: PagedState, x,
 
 def _paged_attention(cfg: TransformerConfig, state: PagedState, normed,
                      w_qkv, w_out, layer, pools, q_positions, slot=None,
-                     write_mask=None):
+                     write_mask=None, w_gate=None):
     """The attention mixer of every paged program, over normed
     activations [B, Q, D]; q_positions: [B, Q] absolute
     positions of the new tokens. ``pools`` is the WHOLE pool
@@ -1644,7 +1645,10 @@ def _paged_attention(cfg: TransformerConfig, state: PagedState, normed,
     those rows need no slack pages; None = every offset writes.
     ``cfg.rotary`` false leaves q and k as projected (no positional
     encoding); ``cfg.attention_multiplier`` non-zero scales the scores
-    by it and not by 1/sqrt(Dh)."""
+    by it and not by 1/sqrt(Dh). ``w_gate`` [D, H*Dh], where the layer
+    has one, gates what was attended, channel by channel, before the
+    output projection: ``(sigmoid(normed @ w_gate) * attended) @
+    w_out``."""
     batch, q_len, _ = normed.shape
     h, kv, dh = cfg.n_heads, cfg.kv_heads, cfg.d_head
     group = h // kv
@@ -1654,6 +1658,14 @@ def _paged_attention(cfg: TransformerConfig, state: PagedState, normed,
     page = new_pool_k.shape[2]
 
     q, k, v = split_qkv(cfg, normed @ w_qkv.astype(dtype))
+
+    def gated(attended):
+        if w_gate is None:
+            return attended
+        gate = jax.nn.sigmoid(
+            (normed @ w_gate.astype(dtype)).astype(jnp.float32))
+        return attended * gate.astype(dtype)
+
     # rotary wants [T]-shaped positions; rows share a position vector only
     # in prefill (B=1). Decode/verify rows each carry their own
     # positions: apply per-row via vmap (q_len 1 for plain decode,
@@ -1749,7 +1761,7 @@ def _paged_attention(cfg: TransformerConfig, state: PagedState, normed,
             interpret=pallas_interpret(),
             score_scale=cfg.attention_multiplier or None,
         )  # [B, H, Dh], kv-major head layout — same as the einsum's
-        out = att.reshape(batch, 1, h * dh) @ w_out.astype(dtype)
+        out = gated(att.reshape(batch, 1, h * dh)) @ w_out.astype(dtype)
     else:
         gk, gv = _gathered(
             (new_pool_k, new_pool_v, new_scale_k, new_scale_v),
@@ -1771,7 +1783,8 @@ def _paged_attention(cfg: TransformerConfig, state: PagedState, normed,
             scores.astype(jnp.float32), axis=-1
         ).astype(dtype)
         attended = jnp.einsum("bkgqs,bskd->bqkgd", weights, gv)
-        out = attended.reshape(batch, q_len, h * dh) @ w_out.astype(dtype)
+        out = (gated(attended.reshape(batch, q_len, h * dh))
+               @ w_out.astype(dtype))
     return out, (new_pool_k, new_pool_v, new_scale_k, new_scale_v)
 
 
@@ -1816,7 +1829,7 @@ def _run_paged_pattern(cfg, params, state, x, q_positions, slot,
     """:func:`_run_paged` for a block with a layer pattern
     (models/hybrid.py has the block and its equations): the attention
     layers through :func:`_paged_attention` on the pool, which holds
-    only them, the mamba layers on ``state.recurrent``. A batched row
+    only them, the recurrent layers on ``state.recurrent``. A batched row
     that is not decoding (length 0 in ``state``, as the decode step
     masks it) gets its recurrent state back untouched."""
     from kvedge_tpu.models import hybrid
@@ -1830,7 +1843,7 @@ def _run_paged_pattern(cfg, params, state, x, q_positions, slot,
     def attend(normed, w, layer, pools):
         return _paged_attention(
             cfg, state, normed, w["w_qkv"], w["w_out"], layer, pools,
-            q_positions, slot, write_mask)
+            q_positions, slot, write_mask, w.get("w_gate"))
 
     x, pools, recurrent = hybrid.run_layers(
         cfg, params, x,
@@ -1838,8 +1851,10 @@ def _run_paged_pattern(cfg, params, state, x, q_positions, slot,
         state.recurrent, attend, slot,
         None if slot is not None else state.lengths > 0)
     x = _rmsnorm(x, params["ln_final"], cfg.norm_eps)
+    # a head of its own where the tree has one, [V, D] as the embedding
     logits = tied_readout(
-        x if all_positions else x[:, -1], params["embedding"]
+        x if all_positions else x[:, -1],
+        params.get("head", params["embedding"])
     ) / cfg.logits_scaling
     return logits, (pools, recurrent)
 

@@ -211,9 +211,13 @@ def held_experts_ffn(x, router_w, w_in, w_out, *, top_k: int,
     is the part of its gated sum that the held experts give: with
     ``Eh == E`` the whole layer, on a chip that shares the layer the
     half that its all-reduce would add to the other chip's. Returns
-    ``(out [N, D], picks int32 [2 + Eh])``: of the ``live`` tokens'
+    ``(out [N, D], picks int32 [3 + Eh])``: of the ``live`` tokens'
     picks (all, when ``live`` is None) how many there were, how many
-    fell on a held expert, and the count for each held expert.
+    fell on a held expert, the count for each held expert and, last,
+    how many held experts got one or more (with many small experts and
+    few tokens some get none, and the product below reads them all the
+    same: summed over layers and steps this is what a product that
+    skipped the untouched would still have to read).
 
     One product over all held experts and all tokens: every expert
     matrix is read once, whatever the routing, and a token's gate for
@@ -243,7 +247,8 @@ def held_experts_ffn(x, router_w, w_in, w_out, *, top_k: int,
               else jnp.sum(live, dtype=jnp.int32))
     picks = jnp.concatenate([
         jnp.stack([jnp.asarray(n_live * top_k, jnp.int32),
-                   jnp.sum(by_expert)]), by_expert])
+                   jnp.sum(by_expert)]), by_expert,
+        jnp.sum(by_expert > 0, dtype=jnp.int32)[None]])
     return out, picks
 
 

@@ -120,26 +120,40 @@ class TransformerConfig:
     # path (multi-query shapes).
     paged_attention: str = "auto"
     # A patterned block (models/hybrid.py; served by the paged path
-    # only). ``layer_pattern`` is one period of layer kinds, "mamba" or
-    # "attention", repeated ``n_layers / len(layer_pattern)`` times; ()
-    # is the block above: every layer rotary attention and a GELU
-    # feed-forward. With a pattern every layer's feed-forward is
+    # only). ``layer_pattern`` is one period of layer kinds, "attention"
+    # and one recurrent kind, "mamba" (models/ssm.py) or "delta"
+    # (models/delta.py), repeated ``n_layers / len(layer_pattern)``
+    # times; () is the block above: every layer rotary attention and a
+    # GELU feed-forward. With a pattern every layer's feed-forward is
     # ``n_experts`` routed experts of width ``d_ff`` (``expert_top_k``
     # a token, gates a softmax over the picked logits) plus a shared
     # expert of width ``shared_ff``, all SiLU-gated when ``ffn_gated``.
     # ``experts_held`` of the routed experts live here, from index
     # ``expert_first`` on (0 held = all): the layer routes over all
     # ``n_experts`` and returns the held experts' part of the sum.
+    # The recurrent layers' sizes are one set of keys for either kind:
+    # a mamba layer's state is [heads * head_dim, state] a row, a delta
+    # layer's [heads, state, head_dim] (``ssm_state`` its key channels,
+    # ``ssm_head_dim`` its value channels; ``ssm_gate_rank`` the width
+    # of its two low-rank gates). ``head_dim`` is the attention heads'
+    # size where it is not ``d_model / n_heads``; ``attention_gate``
+    # gives an attention layer an output gate (``w_gate``) and
+    # ``untied_head`` the tree a head of its own (``head``): the layer
+    # bodies read both from the tree they are handed.
     layer_pattern: tuple = ()
-    ssm_heads: int = 0      # Mamba-2 heads ...
-    ssm_head_dim: int = 0   # ... of this many channels each
-    ssm_state: int = 0      # state size N per channel
-    ssm_conv: int = 4       # causal conv width over x|B|C
+    ssm_heads: int = 0      # recurrent heads ...
+    ssm_head_dim: int = 0   # ... of this many (value) channels each
+    ssm_state: int = 0      # state size N per channel (key channels)
+    ssm_conv: int = 4       # causal conv width over the mixer's inputs
     ssm_chunk: int = 256    # the chunk form's block of positions
+    ssm_gate_rank: int = 0
     experts_held: int = 0
     expert_first: int = 0
     shared_ff: int = 0
     ffn_gated: bool = False
+    head_dim: int = 0
+    attention_gate: bool = False
+    untied_head: bool = False
     # x = embedding_multiplier * E[tokens]; each residual add takes
     # residual_multiplier * f(norm(x)); attention scores are
     # attention_multiplier * q.k (0 = 1/sqrt(d_head)); logits are
@@ -178,7 +192,15 @@ class TransformerConfig:
         return self.ssm_inner + 2 * self.ssm_state
 
     @property
+    def recurrent_kind(self) -> str:
+        """The pattern's recurrent layer kind ("" where it has none)."""
+        return next((kind for kind in ("mamba", "delta")
+                     if kind in self.layer_pattern), "")
+
+    @property
     def d_head(self) -> int:
+        if self.head_dim:
+            return self.head_dim
         assert self.d_model % self.n_heads == 0
         return self.d_model // self.n_heads
 
@@ -213,7 +235,7 @@ class TransformerConfig:
         return self.n_kv_heads or self.n_heads
 
     def validate(self) -> None:
-        if self.d_model % self.n_heads:
+        if not self.head_dim and self.d_model % self.n_heads:
             raise ValueError("d_model must be divisible by n_heads")
         if self.n_kv_heads and self.n_heads % self.n_kv_heads:
             raise ValueError("n_heads must be divisible by n_kv_heads")
@@ -234,9 +256,11 @@ class TransformerConfig:
         if self.layer_pattern:
             self._validate_pattern()
         elif (self.experts_held or self.expert_first or self.shared_ff
-              or self.ffn_gated or not self.rotary):
+              or self.ffn_gated or not self.rotary or self.head_dim
+              or self.attention_gate or self.untied_head):
             raise ValueError(
-                "experts_held, expert_first, shared_ff, ffn_gated and "
+                "experts_held, expert_first, shared_ff, ffn_gated, "
+                "head_dim, attention_gate, untied_head and "
                 "rotary = false belong to a patterned block: set "
                 "layer_pattern")
         if self.n_experts:
@@ -297,21 +321,30 @@ class TransformerConfig:
 
     def _validate_pattern(self) -> None:
         kinds = set(self.layer_pattern)
-        if not kinds <= {"mamba", "attention"}:
+        if not kinds <= {"mamba", "delta", "attention"}:
             raise ValueError(
-                "layer_pattern holds 'mamba' and 'attention', got "
-                f"{sorted(kinds - {'mamba', 'attention'})}")
+                "layer_pattern holds 'mamba', 'delta' and 'attention', "
+                f"got {sorted(kinds - {'mamba', 'delta', 'attention'})}")
+        if {"mamba", "delta"} <= kinds:
+            raise ValueError(
+                "layer_pattern holds one recurrent kind, 'mamba' or "
+                "'delta': a slot's state is one array a layer, sized by "
+                "the kind")
         if self.n_layers % len(self.layer_pattern):
             raise ValueError(
                 f"n_layers {self.n_layers} must be whole periods of "
                 f"layer_pattern ({len(self.layer_pattern)} layers)")
-        if "mamba" in kinds and not (self.ssm_heads and self.ssm_head_dim
-                                     and self.ssm_state
-                                     and self.ssm_conv > 1
-                                     and self.ssm_chunk > 0):
+        if self.recurrent_kind and not (
+                self.ssm_heads and self.ssm_head_dim and self.ssm_state
+                and self.ssm_conv > 1 and self.ssm_chunk > 0):
             raise ValueError(
-                "layer_pattern has mamba layers: ssm_heads, ssm_head_dim "
+                f"layer_pattern has {self.recurrent_kind} layers: "
+                "ssm_heads, ssm_head_dim "
                 "and ssm_state must be set, ssm_conv > 1, ssm_chunk > 0")
+        if "delta" in kinds and self.ssm_gate_rank <= 0:
+            raise ValueError(
+                "layer_pattern has delta layers: ssm_gate_rank, the "
+                "width of their two low-rank gates, must be set")
         if not self.n_experts:
             raise ValueError(
                 "layer_pattern: the patterned block's feed-forward is "
@@ -443,6 +476,9 @@ _COMPUTE_DTYPE_LEAVES = frozenset({
     # float32: they are read in float32 by the equations.
     "w_in", "conv_w", "conv_b", "experts_in", "experts_out",
     "shared_in", "shared_out",
+    # A delta layer's low-rank gates, an attention layer's output gate
+    # and a head of its own.
+    "w_low", "w_f2", "w_g2", "w_gate", "head",
 })
 
 

@@ -187,6 +187,10 @@ def derive_model_config(cfg: RuntimeConfig, *, seq: int):
         ssm_state=spec.ssm_state,
         ssm_conv=spec.ssm_conv or TransformerConfig.ssm_conv,
         ssm_chunk=spec.ssm_chunk or TransformerConfig.ssm_chunk,
+        ssm_gate_rank=spec.ssm_gate_rank,
+        head_dim=spec.head_dim,
+        attention_gate=spec.attention_gate,
+        untied_head=spec.untied_head,
         experts_held=spec.experts_held,
         expert_first=spec.expert_first,
         shared_ff=spec.shared_ff,

@@ -305,11 +305,12 @@ def test_cell_programs_cast_no_weight(chip, monkeypatch, program):
     assert not cast, f"{program} casts weights each time it runs: {cast}"
 
 
-def _patterned_cell_program(program: str, chip, monkeypatch):
-    """The other cell (benchmark/configs/granite-4.0-h-small.json): one
-    period of the patterned block at its published widths, 36 of 72
+def _patterned_cell_program(program: str, chip, monkeypatch,
+                            name: str = "granite-4.0-h-small.batchgen"):
+    """A patterned block's cell (benchmark/configs/granite-4.0-h-small.json:
+    one period of the patterned block at its published widths, 36 of 72
     experts held, 64 slots of recurrent state beside 1,536 pages of one
-    attention layer, a window of 16 steps; the sizes made as the server
+    attention layer, a window of 16 steps); the sizes made as the server
     makes them, from the file through ``model_of`` and ``[model]``."""
     import kvedge_tpu.ops
     from benchmark import cellspec
@@ -321,7 +322,7 @@ def _patterned_cell_program(program: str, chip, monkeypatch):
     # jax.default_backend() is the CPU here; on the chip the decode
     # window's one-token SSM form is the kernel (ssm.step_in_kernel).
     monkeypatch.setattr(ssm, "_on_tpu", lambda: True)
-    cell = cellspec.load_cell("granite-4.0-h-small.batchgen")
+    cell = cellspec.load_cell(name)
     payload = cell.config["payload"]
     one = jax.devices()[:1]
     with monkeypatch.context() as patch:
@@ -421,6 +422,51 @@ def test_the_patterned_cell_fits_and_leaves_its_state_where_it_is(
         f"{program}: {memory.temp_size_in_bytes / 1e9:.2f} GB of "
         "temporaries is a layer's recurrent state or more")
     # donated and updated in place: what comes out aliases what went in
+    assert memory.alias_size_in_bytes >= rows.size * 4
+
+
+@pytest.mark.parametrize("program", ["decode_window", "prefill"])
+def test_the_delta_cell_fits_and_leaves_its_state_where_it_is(
+        chip, monkeypatch, program):
+    """``solar-open2-250b.batchgen`` compiled for the chip at its
+    shapes, held to ISSUE 36's arithmetic: 3.308 B parameters, 6.62 GB
+    in bf16, no leaf float32 but the small ones; 64 slots of three
+    delta layers' state, [64 x 128, 128] float32 a layer and slot, the
+    same array a mamba layer keeps; the 32-step window and the prefill
+    chunk need under 9.5 GB with no temporary of a layer's state
+    (0.27 GB) or of a weight's size, and the state donated and updated
+    in place. The window attends through the paged kernel at a query
+    group of 8 (64 query heads); the delta mixer has no kernel: XLA's
+    own fusions read and write the state."""
+    cfg, params, state, lowered = _patterned_cell_program(
+        program, chip, monkeypatch, "solar-open2-250b.batchgen")
+    leaves = jax.tree_util.tree_leaves(params)
+    weights = sum(a.size * a.dtype.itemsize for a in leaves)
+    assert sum(a.size for a in leaves) == pytest.approx(3.308e9, rel=1e-3)
+    assert 6.6e9 < weights < 6.65e9
+    assert max(a.size for a in leaves if a.dtype == jnp.float32) \
+        == cfg.n_layers * cfg.d_model * cfg.n_experts  # the router
+    assert (cfg.n_heads, cfg.kv_heads, cfg.d_head) == (64, 8, 128)
+    rows = state.recurrent["ssm"]
+    assert rows.shape == (3, 64, 64, 128, 128) and rows.dtype == jnp.float32
+    assert state.recurrent["conv"].shape == (3, 64, 3 * 24576)
+    assert state.pool_k.shape == (1, 1536, 128, 1024)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    window = program == "decode_window"
+    assert text.count('custom_call_target="tpu_custom_call"') \
+        == (1 if window else 0)
+    memory = compiled.memory_analysis()
+    needs = (memory.argument_size_in_bytes + memory.output_size_in_bytes
+             - memory.alias_size_in_bytes + memory.temp_size_in_bytes)
+    print(f"{program} at the delta cell's shapes: needs "
+          f"{needs / 1e9:.3f} GB, {memory.temp_size_in_bytes / 1e9:.3f} GB "
+          f"of it temporaries")
+    assert needs < 9.5e9
+    layer_state = rows.size * 4 // rows.shape[0]
+    assert memory.temp_size_in_bytes < layer_state // 2, (
+        f"{program}: {memory.temp_size_in_bytes / 1e9:.2f} GB of "
+        "temporaries is a layer's recurrent state or more")
     assert memory.alias_size_in_bytes >= rows.size * 4
 
 

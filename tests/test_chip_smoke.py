@@ -43,7 +43,7 @@ def test_smoke_phases_rehearsed_on_cpu(chips, tmp_path, monkeypatch, capsys):
     mesh = "{ data = 2, model = 2 }"
     wanted = (
         ["train[state]", "serve[auto]", "serve[gather]", "attention-op",
-         "ssm-op"]
+         "ssm-op", "delta-op"]
         if chips == 1 else
         ["reference-train[1 chip]", "reference-serve[1 chip]",
          "train[state]", f"serve[{mesh}]"])
@@ -101,6 +101,29 @@ def test_smoke_fails_on_a_degraded_payload(tmp_path, capsys):
         with pytest.raises(chip_smoke.SmokeFailure, match="degraded"):
             chip_smoke.phase_train(smoke, state="state",
                                    mesh="{ data = 0, model = 1 }")
+
+
+def test_the_delta_op_phase_holds_both_forms_to_the_recurrence(
+        tmp_path, capsys, monkeypatch):
+    """The phase alone, as the chip runs it: both forms of the
+    delta-rule mixer within float32 rounding of the recurrence in
+    float64, and a form that strays fails the smoke."""
+    from kvedge_tpu.models import delta
+
+    with chip_smoke.CompileMeter() as meter:
+        smoke = chip_smoke.Smoke(PROBE, chips=1, platform="cpu", seed=0,
+                                 workdir=str(tmp_path), meter=meter)
+        chip_smoke.phase_delta_op(smoke)
+        gaps = smoke.report["phases"]["delta-op"]["gaps"]
+        assert set(gaps) == {"one-token", "chunk"}
+        assert all(0 < gap <= 2e-5 for pair in gaps.values() for gap in pair)
+        assert "delta op, 4 rows of 4 heads of 128 x 128" \
+            in capsys.readouterr().out
+        # the solve left out: the chunk form is no longer the recurrence
+        monkeypatch.setattr(delta.jax.scipy.linalg, "solve_triangular",
+                            lambda a, b, **kw: b)
+        with pytest.raises(chip_smoke.SmokeFailure, match="delta-rule"):
+            chip_smoke.phase_delta_op(smoke)
 
 
 def test_boot_once_on_a_degraded_runtime_exits_nonzero(tmp_path):

@@ -1,18 +1,28 @@
-"""The patterned block (models/hybrid.py, models/ssm.py, moe.held_experts_ffn)
-on the paged serving path, held to the benchmark's plain reference
-(benchmark/references/granite_moe_hybrid.py), never to decode.generate:
-the block exists once.
+"""The patterned block (models/hybrid.py, models/ssm.py, models/delta.py,
+moe.held_experts_ffn) on the paged serving path, held to the benchmark's
+plain references (benchmark/references/granite_moe_hybrid.py and
+solar_open2.py), never to decode.generate: the block exists once.
 
-A preset with every kind of layer at a size the CPU runs in seconds:
-pattern m m a m over two periods, 8 routed experts of which 4 are held,
-3 a token, a shared expert, 4 SSM heads of 8 with state 16, all four
-multipliers other than 1, no rotary. The program computes in float32
-here, so that what separates it from the float32 reference is the order
-of its sums and nothing else.
+Two presets, one for each recurrent layer kind, at sizes the CPU runs in
+seconds, and every test that says the same of both runs on both:
+
+* ``mamba``: pattern m m a m over two periods, 8 routed experts of which
+  4 are held, 3 a token, a shared expert, 4 SSM heads of 8 with state
+  16, all four multipliers other than 1, no rotary, a tied head;
+* ``delta``: pattern a d d d over two periods, 16 routed experts of
+  which 2 are held (one of eight shares), 3 a token, a shared expert, 4
+  delta-rule heads of 8 key and 8 value channels with gates of rank 8,
+  4 attention heads of 16 over a hidden size of 32 (so the heads are not
+  the hidden size divided up), an output gate on the attention layer, a
+  head of its own.
+
+The program computes in float32 here, so that what separates it from the
+float32 reference is the order of its sums and nothing else.
 """
 
 import dataclasses
 import os
+import types
 
 import jax
 import jax.numpy as jnp
@@ -25,14 +35,16 @@ from kvedge_tpu.models import hybrid, kvcache, moe
 from kvedge_tpu.models.serving import PagedGenerationServer
 from kvedge_tpu.models.transformer import TransformerConfig
 
-REFERENCE = cellspec.load_module(
-    "granite_moe_hybrid_for_tests",
-    os.path.join(cellspec.REPO, "benchmark", "references",
-                 "granite_moe_hybrid.py"))
 
-# The preset under the published key names, as a configuration's file
+def _reference(stem: str):
+    return cellspec.load_module(
+        stem + "_for_tests",
+        os.path.join(cellspec.REPO, "benchmark", "references", stem + ".py"))
+
+
+# The presets under the published key names, as a configuration's file
 # holds them: the reference's model_of makes the program's [model] of it.
-PUBLISHED = {
+_MAMBA = {
     "attention_bias": False, "attention_multiplier": 0.2,
     "embedding_multiplier": 3.0, "hidden_act": "silu", "hidden_size": 32,
     "intermediate_size": 16,
@@ -48,29 +60,103 @@ PUBLISHED = {
     "rms_norm_eps": 1e-5, "shared_intermediate_size": 24,
     "tie_word_embeddings": True, "vocab_size": 128,
 }
-MODEL = REFERENCE.model_of(PUBLISHED)
+_DELTA = {
+    "model_type": "solar_open2", "partial_rotary_factor": 1,
+    "linear_attn_config": {"short_conv_kernel_size": 4, "head_dim": 8,
+                           "num_heads": 4, "num_kv_heads": None},
+    "hidden_size": 32, "num_hidden_layers": 8, "num_attention_heads": 4,
+    "head_dim": 16, "num_key_value_heads": 2, "vocab_size": 128,
+    "intermediate_size": 64, "moe_intermediate_size": 16,
+    "rms_norm_eps": 1e-5, "rope_theta": 10000, "tie_word_embeddings": False,
+    "max_position_embeddings": 4096, "first_k_dense_replace": 0,
+    "use_rope": False, "gqa_interval": 3, "gqa_layers": [0, 4],
+    "use_gqa_gate": True, "kda_use_full_proj": False,
+    "kda_allow_neg_eigval": True, "n_routed_experts": 2,
+    "published": {"n_routed_experts": 16}, "n_shared_experts": 1,
+    "norm_topk_prob": True, "routed_scaling_factor": 1,
+    "num_experts_per_tok": 3,
+}
+
+
+def _block(kind: str, published: dict, stem: str, **said) -> types.SimpleNamespace:
+    reference = _reference(stem)
+    return types.SimpleNamespace(
+        kind=kind, reference=reference, published=published,
+        model=reference.model_of(published), **said)
+
+
+# What the tests below need to be told of a preset: the layers' leaves
+# under the reference's names, the leaves the equations read in float32,
+# one slot's state and conv tail, and the tolerance of the comparison
+# that is this file's first test, with the readings it was set from.
+BLOCKS = {
+    "mamba": _block(
+        "mamba", _MAMBA, "granite_moe_hybrid",
+        pattern=("mamba", "mamba", "attention", "mamba"),
+        names={("mamba", "w_out"): "m_out", ("attention", "w_out"): "a_out"},
+        float32={"router", "A_log", "dt_bias", "D", "ln", "norm"},
+        state=(32, 16), tail=3 * (32 + 2 * 16), state_tolerance=2e-5,
+        # Both sides are float32 and differ in the order of their sums
+        # alone (a product over all held experts for a loop over them,
+        # the chunk form of the SSM for the literal recurrence, a cache
+        # for none). Read here, on logits of size 0.07: 1.6e-7 between
+        # them; 1.9e-3 with the recurrent state kept in bf16 (each of
+        # the 63 positions' states rounded to 8 bits); 1.9e-2 from the
+        # reference's int8 control. 2e-5 leaves the program a hundred
+        # times its reading and fails both of the others ninety times
+        # over.
+        tolerance=2e-5),
+    "delta": _block(
+        "delta", _DELTA, "solar_open2",
+        pattern=("attention", "delta", "delta", "delta"),
+        names={("delta", "w_qkv"): "d_qkv", ("delta", "w_out"): "d_out",
+               ("attention", "w_out"): "a_out"},
+        float32={"router", "A_log", "dt_bias", "ln", "norm"},
+        state=(4, 8, 8), tail=3 * 3 * 32,
+        # A row's state after 128 positions, prefilled in pieces of two
+        # lengths: the chunk form alone is within 1e-6 of the one-token
+        # recurrence (tests/test_delta_block.py), but eight layers of
+        # L2-normalised 8-channel keys carry a rounding on: 6.5e-5 read
+        # between the two on states of size 2; the logits still agree
+        # to 2e-5.
+        state_tolerance=2e-4,
+        # The same comparison (the chunk form's triangular solve and
+        # the decode step's two reads for the literal recurrence), on
+        # logits of size 0.4 (a head of its own at 0.02): 7.0e-6
+        # between them; 3.7e-2 with the recurrent state kept in bf16;
+        # 2.6e-1 from the reference's int8 control. 1e-4 leaves the
+        # program fourteen times its reading and fails the others 370
+        # and 2,600 times over.
+        tolerance=1e-4),
+}
 SEQ = 256
 
 
+@pytest.fixture(scope="module", params=list(BLOCKS))
+def block(request):
+    return BLOCKS[request.param]
+
+
 def document(payload: dict | None = None, model: dict | None = None,
-             mesh: dict | None = None, **more) -> dict:
+             mesh: dict | None = None, block=BLOCKS["mamba"], **more) -> dict:
     return {
         "runtime": {"name": "hybrid-test", "state_dir": "/tmp/unused"},
         "tpu": {"platform": "cpu", "expected_chips": 1},
         "mesh": mesh or {"axes": {"data": 1}},
-        "model": {**MODEL, **(model or {})},
+        "model": {**block.model, **(model or {})},
         "payload": {"kind": "serve", "serving": "paged", "seq": SEQ,
                     "serving_prefix_cache": False, **(payload or {})},
         **more,
     }
 
 
-def config_of(model: dict | None = None) -> TransformerConfig:
+def config_of(model: dict | None = None,
+              block=BLOCKS["mamba"]) -> TransformerConfig:
     """The program's config through the product's own path ([model] ->
     ModelSpec -> derive_model_config), in float32."""
     from kvedge_tpu.runtime.workload import derive_model_config
 
-    cfg = RuntimeConfig.from_mapping(document(model=model))
+    cfg = RuntimeConfig.from_mapping(document(model=model, block=block))
     one = jax.devices()[:1]  # of the tests' eight virtual devices
     with pytest.MonkeyPatch.context() as patch:
         patch.setattr(jax, "devices", lambda *a, **k: one)
@@ -79,8 +165,8 @@ def config_of(model: dict | None = None) -> TransformerConfig:
 
 
 @pytest.fixture(scope="module")
-def cfg():
-    return config_of()
+def cfg(block):
+    return config_of(block=block)
 
 
 @pytest.fixture(scope="module")
@@ -89,13 +175,13 @@ def params(cfg):
 
 
 @pytest.fixture(scope="module")
-def weights():
-    return REFERENCE.make_weights(MODEL)
+def weights(block):
+    return block.reference.make_weights(block.model)
 
 
 def prompt_of(seed: int, n: int) -> list:
     return [int(t) for t in
-            np.random.default_rng(seed).integers(0, MODEL["vocab"], n)]
+            np.random.default_rng(seed).integers(0, _MAMBA["vocab_size"], n)]
 
 
 def server_of(params, cfg, **kw):
@@ -128,18 +214,10 @@ def teacher_forced(cfg, params, sequence: list, n_prompt: int,
 
 # ---- (a) the served path against the reference's full forward pass -------
 
-# Both sides are float32 and differ in the order of their sums alone (a
-# product over all held experts for a loop over them, the chunk form of
-# the SSM for the literal recurrence, a cache for none). Read here, on
-# logits of size 0.07: 1.6e-7 between them; 1.9e-3 with the recurrent
-# state kept in bf16 (each of the 63 positions' states rounded to 8
-# bits); 1.9e-2 from the reference's int8 control. 2e-5 leaves the
-# program a hundred times its reading and fails both of the others
-# ninety times over.
-LOGIT_TOLERANCE = 2e-5
 
-
-def test_served_tokens_and_logits_are_the_reference_s(cfg, params, weights):
+def test_served_tokens_and_logits_are_the_reference_s(block, cfg, params,
+                                                      weights):
+    tolerance = block.tolerance
     prompt, n_new = prompt_of(1, 40), 24
     server = server_of(params, cfg)
     try:
@@ -148,17 +226,16 @@ def test_served_tokens_and_logits_are_the_reference_s(cfg, params, weights):
         server.close()
     sequence, generated = served, served[len(prompt):]
     assert sequence[:len(prompt)] == prompt and len(generated) == n_new
-    (want,) = REFERENCE.logits(MODEL, weights, [sequence],
-                               [len(prompt) - 1])
+    (want,) = block.reference.logits(block.model, weights, [sequence],
+                                     [len(prompt) - 1])
     # every served token is the reference's choice, or ties with it
     gaps = want[:n_new].max(axis=-1) - want[np.arange(n_new), generated]
-    assert gaps.max() <= LOGIT_TOLERANCE
+    assert gaps.max() <= tolerance
     # and the logits the cache's programs give at those positions (prefill
     # in chunks of 16, then step by step over the served tokens) are the
     # reference's
     got, _ = teacher_forced(cfg, params, sequence[:-1], len(prompt))
-    np.testing.assert_allclose(got, want[:n_new], rtol=0,
-                               atol=LOGIT_TOLERANCE)
+    np.testing.assert_allclose(got, want[:n_new], rtol=0, atol=tolerance)
 
     # the tolerance tells the precisions apart: a bf16 recurrent state ...
     def in_bf16(recurrent):
@@ -167,46 +244,104 @@ def test_served_tokens_and_logits_are_the_reference_s(cfg, params, weights):
     rough, cache = teacher_forced(cfg, params, sequence[:-1], len(prompt),
                                   recurrent=in_bf16)
     assert cache.state.recurrent["ssm"].dtype == jnp.bfloat16
-    assert np.abs(rough - want[:n_new]).max() > 10 * LOGIT_TOLERANCE
+    assert np.abs(rough - want[:n_new]).max() > 10 * tolerance
     # ... and the reference's own int8 control
-    (control,) = REFERENCE.logits(MODEL, weights, [sequence],
-                                  [len(prompt) - 1], quant="int8")
-    assert np.abs(control[:n_new] - want[:n_new]).max() \
-        > 10 * LOGIT_TOLERANCE
+    (control,) = block.reference.logits(block.model, weights, [sequence],
+                                        [len(prompt) - 1], quant="int8")
+    assert np.abs(control[:n_new] - want[:n_new]).max() > 10 * tolerance
+
+
+def test_rows_admitted_at_different_steps_read_the_reference_s_logits(
+        block, cfg, params, weights):
+    """Three sequences through two slots of one cache, teacher-forced:
+    A is prefilled in chunks of 16 and decodes alone, B is prefilled in
+    chunks of 8 into the other slot while A is ten steps in and decodes
+    beside it, A finishes and C takes its slot (reset, not inherited)
+    while B goes on. Every row of logits the cache's programs gave, at
+    whatever step and beside whatever neighbour, is the reference's full
+    forward pass over that sequence at that position."""
+    lengths = {"A": (24, 40), "B": (40, 64), "C": (16, 30)}
+    tokens = {name: prompt_of(30 + i, total)
+              for i, (name, (_, total)) in enumerate(lengths.items())}
+    cache = kvcache.PagedKVCache(cfg, slots=2, pages=32, page_size=16)
+    got = {name: [] for name in lengths}
+    slot_of, at = {}, {}
+
+    def admit(name, slot, chunk):
+        n_prompt = lengths[name][0]
+        cache.admit(slot, n_prompt)
+        for lo in range(0, n_prompt, chunk):
+            out = cache.prefill_chunk(
+                params, slot, jnp.asarray(
+                    tokens[name][lo:min(n_prompt, lo + chunk)], jnp.int32),
+                lo)
+        got[name].append(np.asarray(out))
+        slot_of[slot], at[name] = name, n_prompt
+
+    def step():
+        fed, active = [0, 0], [False, False]
+        for slot, name in slot_of.items():
+            fed[slot], active[slot] = tokens[name][at[name]], True
+        logits = cache.step(params, jnp.asarray(fed, jnp.int32),
+                            active=active)
+        for slot, name in list(slot_of.items()):
+            got[name].append(np.asarray(logits[slot]))
+            at[name] += 1
+            if at[name] == lengths[name][1]:
+                cache.release(slot)
+                del slot_of[slot]
+
+    admit("A", 0, 16)
+    for _ in range(10):
+        step()
+    admit("B", 1, 8)
+    while "A" in slot_of.values():
+        step()
+    admit("C", 0, 16)
+    while slot_of:
+        step()
+    names = list(lengths)
+    want = block.reference.logits(
+        block.model, weights, [tokens[n] for n in names],
+        [lengths[n][0] - 1 for n in names])
+    for name, rows in zip(names, want):
+        assert len(got[name]) == len(rows)
+        np.testing.assert_allclose(np.stack(got[name]), rows, rtol=0,
+                                   atol=block.tolerance, err_msg=name)
 
 
 # ---- (b) the share -------------------------------------------------------
 
 
-def test_the_two_halves_and_the_shared_expert_add_up_to_the_whole_layer():
-    """The parts of the routed sum that the two chips of the deployment
-    give, with the shared expert counted once, are the uncut reference's
-    layer: experts 0-3 here, 4-7 on the other chip, all 8 in the
-    reference."""
+def test_the_shares_and_the_shared_expert_add_up_to_the_whole_layer(block):
+    """The parts of the routed sum that the chips of the deployment
+    give (two halves of 8 experts; eight shares of 2 of 16), with the
+    shared expert counted once, are the uncut reference's layer."""
+    reference, model = block.reference, block.model
+    total_experts, held = model["experts"], model["experts_held"]
+    top_k = model["expert_top_k"]
     with jax.default_matmul_precision("highest"):
-        h = jax.random.normal(jax.random.PRNGKey(3), (24, MODEL["d_model"]))
-        whole = REFERENCE.layer_weights(MODEL, 1, held=(0, 8))
-        want, _ = REFERENCE.feed_forward(h, whole,
-                                         top_k=MODEL["expert_top_k"])
-        shared = REFERENCE._gated(h, whole["shared_in"],
-                                  whole["shared_out"], "")
-        total = shared
-        picks = np.zeros(3, np.int64)
-        for first in (0, 4):
-            half = REFERENCE.layer_weights(MODEL, 1, held=(first, 4))
+        h = jax.random.normal(jax.random.PRNGKey(3), (24, model["d_model"]))
+        whole = reference.layer_weights(model, 1, held=(0, total_experts))
+        want, _ = reference.feed_forward(h, whole, top_k=top_k)
+        total = reference._gated(h, whole["shared_in"], whole["shared_out"],
+                                 "")
+        picks = np.zeros(2, np.int64)
+        for first in range(0, total_experts, held):
+            share = reference.layer_weights(model, 1, held=(first, held))
             part, counted = moe.held_experts_ffn(
-                h, half["router"], half["experts_in"], half["experts_out"],
-                top_k=MODEL["expert_top_k"], first=first, gated=True,
+                h, share["router"], share["experts_in"],
+                share["experts_out"], top_k=top_k, first=first, gated=True,
                 renormalize=True)
             total = total + part
-            picks += np.asarray(counted[:2].tolist() + [0])
+            picks += np.asarray(counted[:2])
             # the reference, given the same share, gives the same part
-            ref_part, _ = REFERENCE.routed(h, half,
-                                           top_k=MODEL["expert_top_k"])
+            ref_part, _ = reference.routed(h, share, top_k=top_k)
             np.testing.assert_allclose(part, ref_part, atol=1e-5)
     np.testing.assert_allclose(total, want, atol=1e-5)
-    # every pick falls on one chip or the other
-    assert picks[1] == 24 * 3 and picks[0] == 2 * 24 * 3
+    # every pick falls on one chip or another
+    shares = total_experts // held
+    assert picks[1] == 24 * top_k and picks[0] == shares * 24 * top_k
 
 
 # ---- (c), (d) slots and dead rows ---------------------------------------
@@ -253,7 +388,8 @@ def test_a_dead_row_s_state_stands_still_across_a_window(cfg, params):
 
 
 @pytest.mark.parametrize("chunk", [16, 64])
-def test_prefill_in_chunks_equals_prefill_in_one_piece(cfg, params, chunk):
+def test_prefill_in_chunks_equals_prefill_in_one_piece(block, cfg, params,
+                                                       chunk):
     prompt = prompt_of(9, 128)
     whole, one = teacher_forced(cfg, params, prompt, len(prompt), chunk=128)
     pieces, many = teacher_forced(cfg, params, prompt, len(prompt),
@@ -264,7 +400,8 @@ def test_prefill_in_chunks_equals_prefill_in_one_piece(cfg, params, chunk):
     for leaf in ("ssm", "conv"):
         np.testing.assert_allclose(
             np.asarray(many.state.recurrent[leaf][:, 1]),
-            np.asarray(one.state.recurrent[leaf][:, 1]), atol=2e-5)
+            np.asarray(one.state.recurrent[leaf][:, 1]),
+            atol=block.state_tolerance)
 
 
 # ---- (f) what refuses to start, each by name ----------------------------
@@ -276,21 +413,22 @@ def test_prefill_in_chunks_equals_prefill_in_one_piece(cfg, params, chunk):
     ({"payload": {"kind": "train", "corpus": "/tmp/x"}}, "kind = 'train'"),
     ({"payload": {"serving": "contiguous"}}, "serving = \"paged\""),
 ])
-def test_the_runtime_config_refuses_what_cannot_run_the_block(change, named):
+def test_the_runtime_config_refuses_what_cannot_run_the_block(block, change,
+                                                              named):
     with pytest.raises(RuntimeConfigError) as refused:
-        RuntimeConfig.from_mapping(document(**change))
+        RuntimeConfig.from_mapping(document(block=block, **change))
     assert named in str(refused.value)
     assert "layer_pattern" in str(refused.value)
 
 
-def test_a_mesh_of_several_devices_refuses_the_block():
+def test_a_mesh_of_several_devices_refuses_the_block(block):
     from kvedge_tpu.runtime.workload import (
         MeshConfigError, derive_model_config,
     )
 
     # "data": 0 takes every device there is: the tests' eight
     cfg = RuntimeConfig.from_mapping(
-        document(mesh={"axes": {"data": 0}}))
+        document(block=block, mesh={"axes": {"data": 0}}))
     with pytest.raises(MeshConfigError, match="layer_pattern"):
         derive_model_config(cfg, seq=SEQ)
 
@@ -319,6 +457,19 @@ def test_the_other_paths_refuse_the_block_by_the_key_s_name(cfg, params):
     # the pattern's own keys mean nothing without it
     with pytest.raises(ValueError, match="set layer_pattern"):
         dataclasses.replace(cfg, layer_pattern=(), n_experts=0).validate()
+
+
+@pytest.mark.parametrize("change, named", [
+    ({"layer_pattern": ("attention", "mamba", "delta", "delta")},
+     "one recurrent kind"),
+    ({"layer_pattern": ("attention", "window")}, "'window'"),
+    ({"ssm_gate_rank": 0}, "ssm_gate_rank"),
+    ({"ssm_heads": 0}, "delta layers: ssm_heads"),
+])
+def test_a_pattern_the_block_cannot_run_is_refused_by_name(change, named):
+    cfg = config_of(block=BLOCKS["delta"])
+    with pytest.raises(ValueError, match=named):
+        dataclasses.replace(cfg, **change).validate()
 
 
 # ---- (g) preemption carries the row's state with its pages --------------
@@ -418,6 +569,7 @@ def test_a_swap_snapshot_without_the_state_is_refused(cfg, params):
     with pytest.raises(kvcache.PagedCacheError, match="zero state"):
         cache.swapin_slot(1, pages)
     cache.swapin_slot(1, pages + state)
+    # bit for bit what was taken out, in the other slot
     for leaf, want in zip(("ssm", "conv"), state):
         np.testing.assert_array_equal(
             np.asarray(cache.state.recurrent[leaf][:, 1]), want)
@@ -426,10 +578,15 @@ def test_a_swap_snapshot_without_the_state_is_refused(cfg, params):
 # ---- (h) the pick counters ----------------------------------------------
 
 
-def test_the_pick_counters_are_the_router_s_own_picks(cfg, params, weights):
+def test_the_pick_counters_are_the_router_s_own_picks(block, cfg, params,
+                                                      weights):
     """``stats()`` counts the decode windows' picks; counted again here
     on the host from the reference's router logits over the same
-    tokens (the prompt's last position is prefill's and is not in it)."""
+    tokens (the prompt's last position is prefill's and is not in it):
+    every pick, those on a held expert, each held expert's, and
+    ``expert_touched_total``, the (layer, held expert, step) triples in
+    which the expert got one or more."""
+    model = block.model
     prompt, n_new = prompt_of(15, 32), 17
     server = server_of(params, cfg)
     try:
@@ -439,89 +596,136 @@ def test_the_pick_counters_are_the_router_s_own_picks(cfg, params, weights):
         server.close()
     sequence = served
     picks: list = []
-    REFERENCE.logits(MODEL, weights, [sequence], [0], picks=picks)
+    block.reference.logits(model, weights, [sequence], [0], picks=picks)
     decoded = slice(len(prompt), len(sequence) - 1)  # fed to a decode step
-    by_expert = np.zeros(MODEL["experts"], np.int64)
+    by_expert = np.zeros(model["experts"], np.int64)
+    first, n_held = model["expert_first"], model["experts_held"]
+    touched = 0
     for layer_picks in picks:
         np.add.at(by_expert, layer_picks[0][decoded].ravel(), 1)
+        # one live row: a step touches the held experts among its picks
+        touched += int(((layer_picks[0][decoded] >= first)
+                        & (layer_picks[0][decoded] < first + n_held)).sum())
     steps = n_new - 1
     assert stats["expert_picks_total"] == (
-        steps * MODEL["n_layers"] * MODEL["expert_top_k"])
+        steps * model["n_layers"] * model["expert_top_k"])
     assert stats["expert_picks_total"] == by_expert.sum()
-    held = by_expert[:MODEL["experts_held"]]
+    held = by_expert[first:first + n_held]
     assert stats["expert_picks_by_expert"] == held.tolist()
     assert stats["expert_picks_held_total"] == held.sum()
+    assert stats["expert_touched_total"] == touched
+    assert stats["expert_reads_per_step"] == model["n_layers"] * n_held
+    assert 0 < touched <= steps * stats["expert_reads_per_step"]
     assert stats["state_rows"] == 4
-    # float32 here: 6 mamba layers, 4 slots, [32, 16] of state and
-    # a conv tail of 3 x 64
+    # float32 here: 6 recurrent layers, 4 slots, a slot's state and a
+    # conv tail of 3 positions
     assert stats["state_gb"] == pytest.approx(
-        6 * 4 * (32 * 16 + 3 * 64) * 4 / 1e9)
+        6 * 4 * (np.prod(block.state) + block.tail) * 4 / 1e9)
+
+
+def test_two_rows_touch_an_expert_once_a_step():
+    """``expert_touched_total`` by hand where rows share an expert: 6
+    tokens over 4 of 8 experts held from the third on, 2 a token; a dead
+    row's picks touch nothing."""
+    d, e, f = 8, 8, 4
+    key = jax.random.PRNGKey(4)
+    x = jax.random.normal(key, (6, d))
+    router = jax.random.normal(jax.random.fold_in(key, 1), (d, e))
+    w_in = jax.random.normal(jax.random.fold_in(key, 2), (4, d, 2 * f))
+    w_out = jax.random.normal(jax.random.fold_in(key, 3), (4, f, d))
+    live = np.asarray([True, True, False, True, True, False])
+    _, idx, _ = moe._route(x, router, 2)
+    idx = np.asarray(idx)
+    _, picks = moe.held_experts_ffn(x, router, w_in, w_out, top_k=2, first=2,
+                                    gated=True, renormalize=True,
+                                    live=jnp.asarray(live))
+    picks = np.asarray(picks)
+    want = [int((idx[live] == expert).sum()) for expert in range(2, 6)]
+    assert picks.shape == (3 + 4,)
+    assert picks[0] == 4 * 2 and picks[1] == sum(want)
+    assert picks[2:-1].tolist() == want
+    assert picks[-1] == sum(n > 0 for n in want) <= 4
+    _, every = moe.held_experts_ffn(x, router, w_in, w_out, top_k=2, first=2,
+                                    gated=True, renormalize=True)
+    assert np.asarray(every)[-1] >= picks[-1]
 
 
 # ---- the weights, leaf by leaf -------------------------------------------
 
 
-def test_the_initialiser_draws_what_the_reference_draws(cfg, params):
+def test_the_initialiser_draws_what_the_reference_draws(block, cfg, params):
     """One recipe, stated in hybrid.py and copied by the reference: the
     program's tree, leaf by leaf, is the reference's layer by layer."""
-    names = {"w_out": {"mamba": "m_out", "attention": "a_out"}}
-    seen = {"mamba": 0, "attention": 0}
+    reference, model = block.reference, block.model
+    seen = dict.fromkeys(cfg.layer_pattern, 0)
     pattern = cfg.layer_pattern
+    assert pattern == block.pattern
     for layer in range(cfg.n_layers):
-        want = REFERENCE.layer_weights(MODEL, layer)
+        want = reference.layer_weights(model, layer)
         period, j = divmod(layer, len(pattern))
         kind = pattern[j]
         assert want["kind"] == kind
         index = seen[kind] % pattern.count(kind)
         seen[kind] += 1
         for leaf, got in params[kind].items():
-            if leaf in ("ln", "norm"):
+            if leaf in ("ln", "norm", "D"):
                 assert np.all(np.asarray(got) == 1.0)
                 continue
-            name = names.get(leaf, {}).get(kind, leaf)
+            name = block.names.get((kind, leaf), leaf)
             np.testing.assert_array_equal(np.asarray(got[period, index]),
                                           np.asarray(want[name]), leaf)
         for leaf, got in params["ffn"].items():
             if leaf != "ln":
                 np.testing.assert_array_equal(
                     np.asarray(got[period, j]), np.asarray(want[leaf]), leaf)
-    np.testing.assert_array_equal(np.asarray(params["embedding"]),
-                                  np.asarray(REFERENCE.embedding(MODEL)))
+    tables = reference.make_weights(model)
+    assert set(tables) == {"embedding", "head"} & set(params)
+    for name, table in tables.items():
+        np.testing.assert_array_equal(np.asarray(params[name]),
+                                      np.asarray(table))
 
 
-def test_the_served_tree_is_drawn_in_the_serving_dtype(cfg):
+def test_the_served_tree_is_drawn_in_the_serving_dtype(block, cfg):
     """No float32 tree stands on the device: each matrix leaf comes out
-    of its own jitted call in the compute dtype; the router and the
-    SSM's A_log, dt_bias and D stay float32, and serving_params has
-    nothing left to cast."""
+    of its own jitted call in the compute dtype; the router, the
+    recurrent layers' A_log, dt_bias and D and the gains stay float32,
+    and serving_params has nothing left to cast."""
     from kvedge_tpu.models.transformer import serving_params
 
     served = dataclasses.replace(cfg, dtype="bfloat16")
     tree = hybrid.init_params(jax.random.PRNGKey(0), served)
-    float32 = {"router", "A_log", "dt_bias", "D", "ln", "norm"}
-    for kind in ("mamba", "attention", "ffn"):
+    assert set(tree) - {"embedding", "head", "ln_final"} == {
+        block.kind, "attention", "ffn"}
+    for kind in (block.kind, "attention", "ffn"):
         for leaf, array in tree[kind].items():
-            assert array.dtype == (jnp.float32 if leaf in float32
+            assert array.dtype == (jnp.float32 if leaf in block.float32
                                    else jnp.bfloat16), (kind, leaf)
     assert tree["embedding"].dtype == jnp.bfloat16
+    assert ("head" in tree) == served.untied_head
+    assert ("w_gate" in tree["attention"]) == served.attention_gate
     again = serving_params(tree, served)
     for a, b in zip(jax.tree_util.tree_leaves(tree),
                     jax.tree_util.tree_leaves(again)):
         assert a.dtype == b.dtype
-    # bf16 state beside it: float32 SSM state, the conv's tail as computed
+    # bf16 state beside it: float32 state, the conv's tail as computed
     recurrent = hybrid.fresh_recurrent(served, 3)
     assert recurrent["ssm"].dtype == jnp.float32
-    assert recurrent["ssm"].shape == (6, 3, 32, 16)
+    assert recurrent["ssm"].shape == (6, 3, *block.state)
     assert recurrent["conv"].dtype == jnp.bfloat16
-    assert recurrent["conv"].shape == (6, 3, 3 * (32 + 2 * 16))
+    assert recurrent["conv"].shape == (6, 3, block.tail)
+    assert recurrent["picks"].shape == (3 + served.held_experts,)
 
 
-def test_the_model_section_round_trips_through_toml():
-    cfg = RuntimeConfig.from_mapping(document())
-    again = RuntimeConfig.parse(cfg.to_toml())
+def test_the_model_section_round_trips_through_toml(block):
+    cfg = RuntimeConfig.from_mapping(document(block=block))
+    text = cfg.to_toml()
+    again = RuntimeConfig.parse(text)
     assert again.model == cfg.model
-    assert again.model.layer_pattern == ("mamba", "mamba", "attention",
-                                         "mamba")
+    assert again.model.layer_pattern == block.pattern
+    # a block without the later keys keeps the document it had
+    for key in ("ssm_gate_rank", "head_dim", "attention_gate",
+                "untied_head"):
+        assert (f"\n{key} = " in text) == (block.kind == "delta"), key
     # the plain block's document has none of the new keys
     plain = RuntimeConfig.from_mapping({
         "payload": {"kind": "serve", "serving": "paged"}}).to_toml()
