@@ -1038,7 +1038,12 @@ class PagedKVCache:
         in order, a second dispatch may be enqueued before the first is
         harvested; ``tokens=None`` feeds the previous dispatch's final
         token row (the device-resident carry), so no host round trip
-        separates back-to-back windows.
+        separates back-to-back windows. The choice is per row: an
+        entry of ``tokens`` below 0 takes that row of the carry and
+        every other entry is fed as given, joined on the device
+        (:func:`_join_carry`), so a row that sat out the window in
+        flight (a newcomer, whose first token the host knows) enters
+        the next one beside rows whose tokens the host has not seen.
 
         ``steps_left`` [slots] int32 is each row's remaining decode
         budget (None = no cap): row b advances ``min(n_steps,
@@ -1152,14 +1157,38 @@ class PagedKVCache:
         self._carry = (toks, n_steps)
         return toks
 
-    def _carry_tokens(self):
+    def _host_tokens(self, tokens) -> "np.ndarray":
+        """A window's input row as the host states it: [bucket] int32,
+        an entry below 0 standing for the carry's (``None``: all of
+        them)."""
+        import numpy as _np
+
+        if tokens is None:
+            return _np.full((self.bucket,), -1, _np.int32)
+        return _np.asarray(tokens, _np.int32)
+
+    def _with_carry(self, tokens):
+        """``tokens`` (on the device) with every entry below 0 replaced
+        by the carry's: the last token row of the window dispatched
+        before this one, which the host may not have read yet."""
         if self._carry is None:
             raise PagedCacheError(
                 "no window in flight to carry tokens from — the first "
                 "window of a pipeline must pass explicit tokens"
             )
-        toks, n = self._carry
-        return toks[n - 1]
+        block, n = self._carry
+        return _join_carry(block, tokens, n - 1)
+
+    def _window_tokens(self, tokens, memo: str):
+        """Device seam's input row: the host's where it states every
+        row (the first window of a pipeline), else joined with the
+        carry. A pipeline whose rows stand states the same row window
+        after window (every live row the carry's), and it rides the
+        memo."""
+        host = self._host_tokens(tokens)
+        if (host >= 0).all():
+            return jnp.asarray(host)
+        return self._with_carry(self._dev_const(memo, host))
 
     def drop_carry(self) -> None:
         """Forget the device-resident carries (recovery: a revived pool
@@ -1180,8 +1209,7 @@ class PagedKVCache:
         """Device seam: enqueue a capped greedy window (no read)."""
         import numpy as _np
 
-        toks_in = (self._carry_tokens() if tokens is None
-                   else jnp.asarray(_np.asarray(tokens, _np.int32)))
+        toks_in = self._window_tokens(tokens, "w_toks")
         # Steady-state pipelining redispatches with identical mask/
         # caps/stops rows — the memo turns three device_puts per
         # window into zero (host-path elimination, rung 26).
@@ -1206,8 +1234,7 @@ class PagedKVCache:
         """Device seam: enqueue a capped mixed window (no read)."""
         import numpy as _np
 
-        toks_in = (self._carry_tokens() if tokens is None
-                   else jnp.asarray(_np.asarray(tokens, _np.int32)))
+        toks_in = self._window_tokens(tokens, "ws_toks")
         # key_data/base_steps change every window (positions advance);
         # the mask/sampling-constant/cap rows repeat in steady state
         # and ride the memo like the greedy dispatch's.
@@ -2174,6 +2201,18 @@ _paged_spec_window_sampled = functools.partial(
     jax.jit, static_argnames=("cfg", "n_passes", "k_len"),
     donate_argnums=(1,),
 )(_paged_spec_window_sampled_impl)
+
+
+@functools.partial(jax.jit, static_argnames=("row",))
+def _join_carry(block, tokens, row: int):
+    """A window's input tokens on the device: row ``row`` of the window
+    before it (``block``: its harvest block, [steps + 2, B], perhaps
+    still running) where ``tokens`` [B] int32 is below 0, ``tokens``
+    itself elsewhere. One small program a (window length, bucket): it
+    stands where the eager slice of that row stood, and every
+    overlapped dispatch runs it, newcomer or none, so a warm-up that
+    overlaps two windows has compiled what an admission needs."""
+    return jnp.where(tokens < 0, block[row], tokens)
 
 
 def _picks_zeroed(state: PagedState) -> PagedState:

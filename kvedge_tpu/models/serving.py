@@ -405,6 +405,16 @@ class PagedGenerationServer:
         self._hist_host = _Hist((0.1, 0.2, 0.5, 1.0, 2.0, 5.0, 10.0,
                                  20.0, 50.0, 100.0))
         self._hist_depth = _Hist((0.0, 1.0))
+        # What keeps a window queued behind the running one and what
+        # does not: rows that entered an overlapped window from the
+        # host's row (a newcomer joins on the carry), and the times the
+        # pipeline fell back to a boundary, by cause
+        # (pipeline_collapses_total{cause=...}; _boundary_wanted_locked
+        # names them).
+        self._pipeline_joins = 0
+        self._pipeline_collapses = dict.fromkeys(
+            ("cancel", "newcomer", "bucket", "stop", "checkpoint",
+             "scheduler"), 0)
         # Per-request stage histograms (ms; always on — cheap
         # perf_counter stamps, independent of the tracer): time to
         # first token (submit -> prefill logits picked), the
@@ -2832,6 +2842,8 @@ class PagedGenerationServer:
             # minus device is host bookkeeping + pipeline slack.
             "window_device_ms": self._hist_device.snapshot(),
             "window_inflight_depth": self._hist_depth.snapshot(),
+            "pipeline_joins_total": self._pipeline_joins,
+            "pipeline_collapses": dict(self._pipeline_collapses),
             # Per-request stage histograms (SERVING.md rung 18):
             # TTFT and the queue-vs-decode split.
             "ttft_ms": self._hist_ttft.snapshot(),
@@ -3705,10 +3717,15 @@ class PagedGenerationServer:
         enqueues the next window on the device-resident carry (no host
         round trip between the two — this is the overlap), then
         harvests and processes the previous window's tokens while the
-        next one runs. Whenever exactness needs a boundary (a cancel
-        arrived, a newcomer admitted, budgets exhausted) it harvests
-        WITHOUT dispatching, so the next iteration reconciles at a
-        boundary.
+        next one runs. An admission does not end this: a newcomer
+        (active, nothing of it in flight, its first token picked on
+        the host) enters the next overlapped window from the host's
+        row beside the rows that ride the carry. Whenever exactness
+        needs a boundary (``_boundary_wanted_locked``: a cancel, a
+        bucket step, a stop, a due checkpoint, the scheduler's resume
+        or preemption, a newcomer to a server that speculates or
+        checkpoints) it harvests WITHOUT dispatching, so the next
+        iteration reconciles at a boundary.
 
         A speculatively dispatched window can never corrupt state: each
         row's device-side ``steps_left`` cap freezes it at its true
@@ -3812,7 +3829,16 @@ class PagedGenerationServer:
                 try:
                     with phase("loop/boundary"):
                         collapse = self._boundary_wanted_locked(prev)
-                    if not collapse:
+                    if collapse:
+                        # Overlap boundary: the pipeline collapses so a
+                        # cancel/swap/resize can join reconciled.
+                        self._pipeline_collapses[collapse] += 1
+                        if self.tracer is not None:
+                            self.tracer.event(
+                                "boundary", "serve",
+                                args={"reason": "reconcile",
+                                      "cause": collapse})
+                    else:
                         # Enqueue N+1 on the carry BEFORE touching
                         # N's result — the device starts N+1 the
                         # moment N retires, while the host is still
@@ -3848,11 +3874,6 @@ class PagedGenerationServer:
                             # boundary (counted: the next boundary
                             # runs the non-windowed path).
                             self._spec_window_fallbacks["spec_off"] += 1
-                    elif self.tracer is not None:
-                        # Overlap boundary: the pipeline collapses so a
-                        # cancel/newcomer/swap can join reconciled.
-                        self.tracer.event("boundary", "serve",
-                                          args={"reason": "reconcile"})
                     if prev.get("kind") in ("spec", "spec_sampled"):
                         self._harvest_spec_window_locked(prev)
                     else:
@@ -3872,30 +3893,59 @@ class PagedGenerationServer:
                 return "exit"
         return "ran"
 
-    def _boundary_wanted_locked(self, prev: dict) -> bool:
+    def _boundary_wanted_locked(self, prev: dict) -> str:
         """Should the pipeline fall back to a non-overlapped boundary
-        instead of dispatching the next window? Yes when a cancel must
-        be honored, or when a slot is active that the in-flight window
-        never dispatched (a newcomer admission — it may only join at a
-        boundary, where its first token is host-known; the carry row
-        of a slot that sat out the previous window is garbage). The
-        scheduler adds a third reason: a resumable or starved-but-
-        preemptable head collapses the pipeline to a boundary, where
-        the swap may join. A pending bucket step is a fourth: the
-        device batch dim can only resize with nothing in flight."""
-        dispatched = {slot for slot, _, _ in prev["parts"]}
-        for slot, req in self._active.items():
-            if req.cancelled or slot not in dispatched:
-                return True
-        # A fifth: an overdue checkpoint clock (rung 22). A saturated
-        # pipeline can run windows back-to-back indefinitely; durability
-        # needs a real boundary every ``checkpoint_every`` windows, so
-        # the due clock forces the collapse the checkpoint rides.
-        return (self._bucket_step_wanted
-                or self._stops_pending > 0
-                or (self._checkpoint_every > 0
-                    and self._ckpt_clock >= self._checkpoint_every)
-                or self._sched_attention_locked(ignore_inflight=True))
+        instead of dispatching the next window? The cause if so (a key
+        of ``pipeline_collapses``), "" if not.
+
+        ``cancel``: a cancel must be honored. ``newcomer``: a slot is
+        active that the in-flight window never dispatched, and the
+        server speculates: a speculative window's carry (``spec`` /
+        ``spec_sampled``) holds each row's drafting context, which a
+        newcomer does not have on the device, and a plain window under
+        speculation (every row sampled) changes kind with a greedy
+        newcomer, so there it may only join at a boundary; or the
+        server checkpoints (``checkpoint_every``): the boundary a
+        newcomer joins at ticks the checkpoint clock, and at a cadence
+        of 1 journals it before its first step, which rung 22 keeps.
+        Otherwise a window's carry is one token a row, and the
+        newcomer's is host-known (its pick is done, nothing of it is
+        in flight): the next overlapped dispatch feeds that row from
+        the host and the others from the carry
+        (``_dispatch_window_locked``), so a newcomer to such a
+        pipeline is no cause. ``scheduler``: a
+        resumable or starved-but-preemptable head collapses the
+        pipeline to a boundary, where the swap may join. ``bucket``:
+        the device batch dim can only resize with nothing in flight.
+        ``stop``: a finish waits for its boundary's sweep: a stop
+        token's, deferred while a window still wrote to its row, or
+        that of a row with nothing in flight whose pending token ends
+        its request with no step at all (a newcomer asked for one
+        token, or whose first token is its stop)."""
+        if any(req.cancelled for req in self._active.values()):
+            return "cancel"
+        if (self._spec > 0 or self._checkpoint_every > 0
+                or prev.get("kind") in ("spec", "spec_sampled")):
+            dispatched = {slot for slot, _, _ in prev["parts"]}
+            if any(slot not in dispatched for slot in self._active):
+                return "newcomer"
+        if self._bucket_step_wanted:
+            return "bucket"
+        if self._stops_pending > 0 or any(
+                req is not None and req.inflight == 0
+                for req in map(self._active.get, self._finish_ready)):
+            return "stop"
+        # ``checkpoint``: an overdue checkpoint clock (rung 22). A
+        # saturated pipeline can run windows back-to-back indefinitely;
+        # durability needs a real boundary every ``checkpoint_every``
+        # windows, so the due clock forces the collapse the checkpoint
+        # rides.
+        if (self._checkpoint_every > 0
+                and self._ckpt_clock >= self._checkpoint_every):
+            return "checkpoint"
+        if self._sched_attention_locked(ignore_inflight=True):
+            return "scheduler"
+        return ""
 
     def _fail_swapped_closed_locked(self) -> None:
         """Hard close reaches the swap set like the active set: a
@@ -3917,10 +3967,15 @@ class PagedGenerationServer:
         when no slot can advance.
 
         ``first`` distinguishes the boundary dispatch (explicit
-        host-known pending tokens) from the overlapped dispatch
-        (``tokens=None`` — the cache feeds the previous window's final
-        token row, still resident on device). The per-row cap is
-        ``n_new - len(generated) - inflight - 1``: committed position
+        host-known pending tokens) from the overlapped dispatch: there
+        a row with steps in flight states no token (-1) and the cache
+        feeds it the previous window's final token row, still resident
+        on device, while a row with nothing in flight (a newcomer: it
+        sat out the window in flight, and ``next_token`` is its pick)
+        is fed from the host like a first window's; where every row is
+        such a one (all that were in flight end with that window), the
+        whole row is the host's and the carry is not read. The per-row
+        cap is ``n_new - len(generated) - inflight - 1``: committed position
         plus the pending token the finish-check emits stepless, so a
         speculative window can never decode past a budget the host
         has not reconciled yet. A row whose previous window froze it
@@ -3953,16 +4008,22 @@ class PagedGenerationServer:
         steps_left = np.zeros((n,), np.int32)
         stop_tokens = np.full((n,), -1, np.int32)
         recs = []
+        joins = 0
         for slot, req, cap in parts:
             adv = min(w, cap)
-            tokens[slot] = req.next_token
+            if first:
+                tokens[slot] = req.next_token
+            elif req.inflight == 0:
+                tokens[slot] = req.next_token
+                joins += 1
+            else:
+                tokens[slot] = -1  # the carry's
             mask[slot] = True
             steps_left[slot] = adv
             stop_tokens[slot] = req.stop_token
             recs.append((slot, req, adv))
         samplers = {slot: req for slot, req, _ in parts
                     if req.sampling is not None}
-        tok_arg = tokens if first else None
         if samplers:
             key_data = np.zeros(
                 (n,) + self._key_data_shape(samplers), np.uint32
@@ -3983,17 +4044,18 @@ class PagedGenerationServer:
                 top_ps[slot] = float(req.sampling[2])
                 smask[slot] = True
             handle = self._cache.dispatch_window_sampled(
-                self._params, tok_arg, w, mask, key_data, base_steps,
+                self._params, tokens, w, mask, key_data, base_steps,
                 temps, top_ps, smask, steps_left=steps_left,
                 stop_tokens=stop_tokens,
             )
         else:
             handle = self._cache.dispatch_window(
-                self._params, tok_arg, w, active=mask,
+                self._params, tokens, w, active=mask,
                 steps_left=steps_left, stop_tokens=stop_tokens,
             )
         for _, req, adv in recs:
             req.inflight += adv
+        self._pipeline_joins += joins
         self._hist_depth.observe(0.0 if first else 1.0)
         return {"window": w, "parts": recs, "handle": handle,
                 "depth": 0 if first else 1, "bucket": n,

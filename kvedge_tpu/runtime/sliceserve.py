@@ -658,18 +658,18 @@ class SlicePagedKVCache(PagedKVCache):
     def _device_window_dispatch(self, params, tokens, n_steps: int,
                                 active, steps_left, stop_tokens):
         """Leader: broadcast + enqueue a capped window WITHOUT reading
-        the result. ``tokens=None`` selects the device-resident carry
-        (header flag ``b``) — the previous window's final token row,
-        which every process slices locally from its own replicated
+        the result. An entry of ``tokens`` below 0 (``None``: every
+        entry) selects the device-resident carry (header flag ``b``:
+        some entry does) — the previous window's final token row,
+        which every process joins locally with its own replicated
         copy, so neither the leader nor any follower blocks on the
-        previous window between the pair. A zero placeholder still
-        rides the broadcast so the payload shape is op-independent.
+        previous window between the pair. The row rides the broadcast
+        either way, so the payload shape is op-independent.
         The dispatch is a flush seam (rung 23): a buffered table sync
         rides the same framed broadcast."""
         self._check_live()
-        carry = 0 if tokens is not None else 1
-        tokens_np = (np.zeros((self.slots,), np.int32) if carry
-                     else np.asarray(tokens, np.int32))
+        tokens_np = self._host_tokens(tokens)
+        carry = int((tokens_np < 0).any())
         mask = self._active_np(active)
         caps = np.asarray(steps_left, np.int32)
         stops = np.asarray(stop_tokens, np.int32)
@@ -688,8 +688,9 @@ class SlicePagedKVCache(PagedKVCache):
                                mask: np.ndarray, caps: np.ndarray,
                                stops: np.ndarray, *,
                                n_steps: int, carry: bool):
-        toks_in = (self._carry_tokens() if carry
-                   else self._global(tokens.astype(np.int32)))
+        toks_in = self._global(tokens.astype(np.int32))
+        if carry:
+            toks_in = self._with_carry(toks_in)
         toks, self.state = self._k_window_capped(
             params, self.state, toks_in, self.cfg, n_steps,
             self._global_const("w_act", mask.astype(bool)),
@@ -705,9 +706,8 @@ class SlicePagedKVCache(PagedKVCache):
                                         sampled_mask, steps_left,
                                         stop_tokens):
         self._check_live()
-        carry = 0 if tokens is not None else 1
-        tokens_np = (np.zeros((self.slots,), np.int32) if carry
-                     else np.asarray(tokens, np.int32))
+        tokens_np = self._host_tokens(tokens)
+        carry = int((tokens_np < 0).any())
         key_data = np.asarray(key_data, np.uint32)
         mask = self._active_np(active)
         payload = (
@@ -733,8 +733,9 @@ class SlicePagedKVCache(PagedKVCache):
                                        key_data, base_steps, temps,
                                        top_ps, smask, caps, stops, *,
                                        n_steps: int, carry: bool):
-        toks_in = (self._carry_tokens() if carry
-                   else self._global(tokens.astype(np.int32)))
+        toks_in = self._global(tokens.astype(np.int32))
+        if carry:
+            toks_in = self._with_carry(toks_in)
         # key_data/base_steps advance every window; the rest repeat
         # in steady state and ride the memo.
         toks, self.state = self._k_wsample_capped(

@@ -158,6 +158,9 @@ _SERVE_METRIC_FIELDS = (
     ("overlap_inflight_depth", "serve_overlap_inflight_depth", "gauge",
      "dispatched-but-unharvested windows right now (0 or 1 — the "
      "pipeline is double-buffered, never deeper)"),
+    ("pipeline_joins_total", "serve_pipeline_joins_total", "counter",
+     "rows that entered an overlapped window from the host's row (a "
+     "newcomer joining on the carry, no boundary taken)"),
     ("spec_passes", "serve_spec_passes_total", "counter",
      "speculative verify passes run (paged backend, "
      "serving_speculative > 0)"),
@@ -539,6 +542,19 @@ def render_metrics(snapshot: dict) -> str:
         for cause in sorted(fallbacks):
             lines.append(
                 f'{name}{{cause="{cause}"}} {fallbacks[cause]}')
+    # Why the decode pipeline fell back to a boundary instead of
+    # queueing the next window behind the running one.
+    collapses = serving.get("pipeline_collapses")
+    if isinstance(collapses, dict) and collapses:
+        name = "kvedge_serve_pipeline_collapses_total"
+        lines.append(
+            f"# HELP {name} overlapped decode pipelines that collapsed "
+            "to a boundary, by cause (an admission is none: newcomer "
+            "counts servers that speculate or checkpoint only)")
+        lines.append(f"# TYPE {name} counter")
+        for cause in sorted(collapses):
+            lines.append(
+                f'{name}{{cause="{cause}"}} {collapses[cause]}')
     # Prefix-cache evictions by cause (rung 24): admission = LRU sweep
     # to fit an arrival; pressure = mid-decode pool-relief callback;
     # revive = post-poison scrub (device bytes untrusted, never

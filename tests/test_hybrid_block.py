@@ -362,6 +362,38 @@ def test_a_reused_slot_starts_from_a_zero_state(cfg, params):
     assert resets == 3
 
 
+def test_a_newcomer_joins_a_running_pipeline_on_its_own_state(cfg, params):
+    """An admission does not drain the decode pipeline (ISSUE 37): the
+    newcomer's state is zeroed and prefilled by programs queued behind
+    the window in flight, in which its row is dead and stands still,
+    and it enters the next overlapped window from the host's row. Its
+    tokens, and those of the request it joined, are what each gets
+    alone."""
+    import time
+
+    long, late = (prompt_of(11, 24), 150), (prompt_of(12, 40), 30)
+    server = server_of(params, cfg)
+    try:
+        alone = [server.submit(*long), server.submit(*late)]
+        harvest = server._cache.harvest_window
+
+        def slow(handle):  # a window is in flight while `late` admits
+            time.sleep(0.02)
+            return harvest(handle)
+
+        server._cache.harvest_window = slow
+        stream = server.submit_stream(*long)
+        first = next(stream)
+        joined = server.submit(*late)
+        together = long[0] + [first] + list(stream)
+        stats = server.stats()
+    finally:
+        server.close()
+    assert stats["pipeline_joins_total"] == 1
+    assert not any(stats["pipeline_collapses"].values())
+    assert [together, joined] == alone
+
+
 def test_a_dead_row_s_state_stands_still_across_a_window(cfg, params):
     cache = kvcache.PagedKVCache(cfg, slots=4, pages=32, page_size=16)
     for slot, seed in ((0, 7), (2, 8)):

@@ -7,9 +7,17 @@ BIT-IDENTICAL to ``decode.generate`` — at ``window = 1`` (a program of
 its own that harvests one token a trip) and at longer windows, under
 chunked prefill, mid-window cancellation, and mid-overlap pool
 poisoning — where recovery must drain the in-flight window before the
-pool reforms; and a window is n calls of the one-step program. All
-fixed-seed and fast: these run in the tier-1 gate.
+pool reforms; and a window is n calls of the one-step program. An
+admission does not drain the pipeline (ISSUE 37): a newcomer joins the
+next overlapped window from the host's row, beside rows on the carry,
+and its tokens are generate's all the same; what still forces a
+boundary is counted by cause. All fixed-seed and fast: these run in
+the tier-1 gate.
 """
+
+import dataclasses
+import threading
+import time
 
 import jax
 import jax.numpy as jnp
@@ -17,12 +25,14 @@ import numpy as np
 import pytest
 
 from kvedge_tpu.models import TransformerConfig, generate, init_params
+from kvedge_tpu.models import kvcache as kvcache_mod
 from kvedge_tpu.models.kvcache import PagedCacheError, PagedKVCache
 from kvedge_tpu.models.serving import (
     PagedGenerationServer,
     RequestCancelled,
 )
 from kvedge_tpu.runtime.failures import ServingFailure
+from kvedge_tpu.runtime.status import render_metrics
 
 pytestmark = pytest.mark.overlap
 
@@ -320,5 +330,262 @@ def test_overlap_stats_and_histograms(params):
             assert len(hist["counts"]) == len(hist["edges"]) + 1
             assert hist["count"] == sum(hist["counts"]) >= 1
             assert hist["sum"] >= 0.0
+    finally:
+        server.close()
+
+
+# ---- an admission joins on the carry (ISSUE 37) --------------------------
+
+# Room for a request that outlives every newcomer's admission.
+LONG_CFG = dataclasses.replace(CFG, max_seq=256)
+LONG = ([3, 1, 4], 200)
+KEY = jax.random.fold_in(jax.random.PRNGKey(3), 0)
+SAMPLING = (KEY, jnp.float32(0.8), jnp.float32(0.9))
+NEWCOMERS = [
+    ([5, 9, 2, 7, 1, 1, 4], 21, None),
+    ([1, 2, 3, 4], 24, SAMPLING),
+    ([100, 50, 7, 7, 7, 2, 9, 9, 4, 1, 6], 12, None),
+]
+
+
+def long_reference(params, prompt, n_new, sampling=None):
+    kw = {}
+    if sampling is not None:
+        kw = {"sampling": (sampling[0][None],) + sampling[1:],
+              "sampled": True}
+    out = generate(params, jnp.asarray([prompt], jnp.int32), LONG_CFG,
+                   n_new=n_new, **kw)
+    return [int(t) for t in np.asarray(out)[0]]
+
+
+def _slowed(server, seconds):
+    """Every harvest takes ``seconds`` longer, lock held as the loop
+    holds it: a window is then in flight for as long as it takes a
+    newcomer to be admitted, on any machine."""
+    real = server._cache.harvest_window
+
+    def slow(handle):
+        time.sleep(seconds)
+        return real(handle)
+
+    server._cache.harvest_window = slow
+
+
+def _join_run(params, newcomers, *, slow_s, **server_kw):
+    """The long request streams; once its first token is out (windows
+    are in flight from then on) the newcomers are submitted at once.
+    Returns everyone's tokens and the server's last stats. Each
+    newcomer has been served alone before (its programs are compiled:
+    its admission then takes milliseconds of the long request's
+    second or more), with the same tokens."""
+    server = PagedGenerationServer(params, LONG_CFG, **server_kw)
+    try:
+        alone = [server.submit(prompt, n_new, sampling=sampling)
+                 for prompt, n_new, sampling in newcomers]
+        _slowed(server, slow_s)
+        stream = server.submit_stream(*LONG)
+        first = next(stream)
+        got: dict = {}
+        errors: list = []
+
+        def worker(i, prompt, n_new, sampling):
+            try:
+                got[i] = server.submit(prompt, n_new, sampling=sampling)
+            except Exception as e:
+                errors.append(e)
+
+        threads = [threading.Thread(target=worker, args=(i, *req))
+                   for i, req in enumerate(newcomers)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=300)
+        assert not errors, errors
+        assert [got[i] for i in range(len(newcomers))] == alone
+        got["long"] = LONG[0] + [first] + list(stream)
+        return got, server.stats()
+    finally:
+        server.close()
+
+
+@pytest.mark.parametrize("chunk", [0, 3], ids=["whole", "chunked"])
+@pytest.mark.parametrize("window", [1, 4, 16])
+def test_newcomers_join_the_pipeline_and_match_generate(params, window,
+                                                        chunk):
+    """Greedy and sampled newcomers admitted while the long request's
+    windows are in flight: nobody's tokens depend on which window a
+    row joined, no admission collapsed the pipeline, and every
+    newcomer entered an overlapped window from the host's row."""
+    got, stats = _join_run(
+        params, NEWCOMERS, slow_s=1.0 / (LONG[1] // window), slots=4,
+        pages=80, window=window, prefill_chunk=chunk)
+    assert got["long"] == long_reference(params, *LONG)
+    for i, (prompt, n_new, sampling) in enumerate(NEWCOMERS):
+        assert got[i] == long_reference(params, prompt, n_new, sampling), (
+            f"newcomer {i} diverged from contiguous generate")
+    assert stats["pipeline_joins_total"] == len(NEWCOMERS)
+    assert not any(stats["pipeline_collapses"].values())
+    # One window was handed to an empty device, the long request's
+    # first; every other one, the newcomers' first among them, was
+    # queued behind a window in flight.
+    # (the three served alone beforehand took a boundary each.)
+    depth = stats["window_inflight_depth"]
+    assert depth["counts"][0] == 1 + len(NEWCOMERS)
+    assert depth["sum"] == depth["count"] - depth["counts"][0]
+
+
+def test_a_joined_row_rides_the_second_window_of_a_pair(params):
+    """The cache's half, without a server: window two is dispatched
+    before window one is read, a row that sat window one out states
+    its token and the other rides the carry; both equal as many single
+    steps. Done twice over fresh pools, the second time traces no
+    window program anew."""
+    prompts = {0: [3, 1, 4, 1, 5], 1: [2, 7]}
+
+    def fresh():
+        cache = PagedKVCache(CFG, slots=2, pages=16, page_size=4)
+        pend = np.zeros((2,), np.int32)
+        for slot, prompt in prompts.items():
+            cache.admit(slot, len(prompt))
+            logits = cache.prefill(params, slot,
+                                   jnp.asarray(prompt, jnp.int32))
+            pend[slot] = int(jnp.argmax(logits))
+        return cache, pend
+
+    only0 = np.array([True, False])
+    cache_s, pend = fresh()
+    first = _single_steps(cache_s, params, pend, 4, active=only0)
+    both = _single_steps(cache_s, params,
+                         np.array([first[-1, 0], pend[1]]), 4)
+
+    def pair():
+        cache_p, pend = fresh()
+        h1 = cache_p.dispatch_window(params, pend, 4, active=only0)
+        h2 = cache_p.dispatch_window(
+            params, np.array([-1, pend[1]], np.int32), 4)
+        one = np.asarray(cache_p.harvest_window(h1))[:4]
+        two = np.asarray(cache_p.harvest_window(h2))[:4]
+        cache_p.drop_carry()
+        return cache_p, one, two
+
+    cache_p, one, two = pair()
+    assert one[:, 0].tolist() == first[:, 0].tolist()
+    assert two.tolist() == both.tolist()
+    assert cache_p._host_lengths == cache_s._host_lengths
+    pinned = kvcache_mod.trace_count()
+    _, one_again, two_again = pair()
+    assert kvcache_mod.trace_count() == pinned
+    assert (one_again.tolist(), two_again.tolist()) == (one.tolist(),
+                                                        two.tolist())
+
+
+def test_a_warm_server_traces_nothing_when_a_newcomer_joins(params):
+    """Round two of the same admissions into a running pipeline finds
+    every window and prefill program round one traced: a row fed from
+    the host beside rows on the carry is no new program."""
+    server = PagedGenerationServer(params, LONG_CFG, slots=4, pages=80,
+                                   window=4, prefix_cache=False)
+    try:
+        alone = server.submit([5, 9, 2, 7], 9)
+        _slowed(server, 0.02)
+
+        def round_trip():
+            stream = server.submit_stream(*LONG)
+            first = next(stream)
+            got = server.submit([5, 9, 2, 7], 9)
+            return [first] + list(stream), got
+
+        want = round_trip()
+        assert want[1] == alone
+        assert server.stats()["pipeline_joins_total"] == 1
+        pinned = kvcache_mod.trace_count()
+        assert round_trip() == want
+        assert kvcache_mod.trace_count() == pinned
+        assert server.stats()["pipeline_joins_total"] == 2
+    finally:
+        server.close()
+
+
+def test_a_cancel_still_collapses_the_pipeline(params):
+    server = PagedGenerationServer(params, LONG_CFG, slots=2, pages=80,
+                                   window=4)
+    try:
+        _slowed(server, 0.02)
+        keeper = server.submit_stream(*LONG)
+        next(keeper)
+        src = server.submit_stream([1, 2, 3], 150)
+        next(src)
+        src.cancel()
+        with pytest.raises(RequestCancelled):
+            list(src)
+        stats = server.stats()
+        assert stats["pipeline_collapses"]["cancel"] >= 1
+        assert stats["pipeline_joins_total"] == 1
+        text = render_metrics({"serving": stats})
+        assert "kvedge_serve_pipeline_joins_total 1" in text
+        assert ('kvedge_serve_pipeline_collapses_total{cause="cancel"} '
+                f'{stats["pipeline_collapses"]["cancel"]}') in text
+        assert 'pipeline_collapses_total{cause="newcomer"} 0' in text
+        keeper.cancel()
+    finally:
+        server.close()
+
+
+def test_a_bucket_step_still_collapses_the_pipeline(params):
+    """The newcomer's row lies above the device bucket: the resize
+    needs nothing in flight, so the pipeline falls to a boundary, for
+    that cause; the newcomer, parked until then, is prefilled beside
+    the windows dispatched after it."""
+    got, stats = _join_run(params, NEWCOMERS[:1], slow_s=0.02, slots=2,
+                           pages=80, window=4, min_bucket=1)
+    prompt, n_new, _ = NEWCOMERS[0]
+    assert got["long"] == long_reference(params, *LONG)
+    assert got[0] == long_reference(params, prompt, n_new)
+    assert stats["pipeline_collapses"]["bucket"] >= 1
+    assert stats["pipeline_collapses"]["newcomer"] == 0
+
+
+@pytest.mark.parametrize("server_kw", [
+    {"speculative": 3, "spec_window": 4}, {"checkpoint_every": 1},
+], ids=["speculating", "checkpointing"])
+def test_a_newcomer_still_collapses_such_a_pipeline(params, server_kw):
+    """A spec window's carry holds every row's drafting context, which
+    a newcomer does not have on the device; a server that checkpoints
+    journals a newcomer at the boundary it joins at (rung 22). Either
+    way it joins at a boundary, as before."""
+    got, stats = _join_run(params, NEWCOMERS[:1], slow_s=0.02, slots=2,
+                           pages=80, window=4, **server_kw)
+    prompt, n_new, _ = NEWCOMERS[0]
+    assert got["long"] == long_reference(params, *LONG)
+    assert got[0] == long_reference(params, prompt, n_new)
+    if "spec_window" in server_kw:
+        assert stats["spec_windows_total"] >= 1
+        assert stats["pipeline_collapses"]["newcomer"] >= 1
+    else:
+        # at a cadence of 1 every other iteration is a boundary anyway:
+        # the newcomer may find one open and collapse nothing itself
+        assert stats["checkpoints_total"] >= 2
+        assert stats["pipeline_collapses"]["checkpoint"] >= 1
+    assert stats["pipeline_joins_total"] == 0
+
+
+def test_one_token_newcomer_finishes_beside_a_running_pipeline(params):
+    """A newcomer asked for one token needs no step: its finish is the
+    boundary sweep's, so it collapses the pipeline (cause ``stop``) and
+    does not wait for the long request to end."""
+    server = PagedGenerationServer(params, LONG_CFG, slots=2, pages=80,
+                                   window=4)
+    try:
+        alone = server.submit([5, 9, 2], 1)
+        assert alone == long_reference(params, [5, 9, 2], 1)
+        _slowed(server, 0.02)
+        stream = server.submit_stream(*LONG)
+        first = next(stream)
+        assert server.submit([5, 9, 2], 1, timeout=20.0) == alone
+        stats = server.stats()
+        assert stats["in_flight"] == 1  # the long request lives on
+        assert stats["pipeline_collapses"]["stop"] >= 1
+        assert LONG[0] + [first] + list(stream) \
+            == long_reference(params, *LONG)
     finally:
         server.close()

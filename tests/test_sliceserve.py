@@ -367,32 +367,45 @@ def test_slice_stop_after_dead_stream_is_bounded(params, mesh):
     release.set()
 
 
-def test_slice_pipelined_windows_replay_matches_plain(params, mesh):
+@pytest.mark.parametrize("joined", [False, True],
+                         ids=["carry", "newcomer-joins"])
+def test_slice_pipelined_windows_replay_matches_plain(params, mesh,
+                                                      joined):
     """OP_WINDOWP protocol replay (degenerate single-process broadcast):
     two pipelined windows — the second dispatched on the device carry
     BEFORE the first is harvested, header + payload riding the ordered
     op stream, the harvest deliberately NOT a broadcast — produce the
-    plain cache's pipelined tokens exactly."""
-    prompt = [3, 1, 4, 1, 5, 9, 2]
+    plain cache's pipelined tokens exactly. ``joined``: a second row
+    sat the first window out and enters the second from the host's
+    row (its entry of the broadcast row states its token, the other is
+    below 0 and takes the carry's), joined on every process alike."""
+    prompts = {0: [3, 1, 4, 1, 5, 9, 2]}
+    if joined:
+        prompts[1] = [2, 7, 1]
     seqs = []
     for cache in (
         PagedKVCache(CFG, slots=2, pages=16, page_size=4),
         SlicePagedKVCache(CFG, slots=2, pages=16, page_size=4,
                           mesh=mesh),
     ):
-        cache.admit(0, len(prompt))
-        logits = cache.prefill(params, 0,
-                               jnp.asarray(prompt, jnp.int32))
         pend = np.zeros((2,), np.int32)
-        pend[0] = int(np.argmax(np.asarray(logits)))
+        for slot, prompt in prompts.items():
+            cache.admit(slot, len(prompt))
+            logits = cache.prefill(params, slot,
+                                   jnp.asarray(prompt, jnp.int32))
+            pend[slot] = int(np.argmax(np.asarray(logits)))
         active = np.array([True, False])
         h1 = cache.dispatch_window(params, jnp.asarray(pend), 4,
                                    active=active)
-        h2 = cache.dispatch_window(params, None, 4, active=active)
+        if joined:
+            h2 = cache.dispatch_window(
+                params, np.array([-1, pend[1]], np.int32), 4)
+        else:
+            h2 = cache.dispatch_window(params, None, 4, active=active)
         toks = np.concatenate([np.asarray(cache.harvest_window(h1)),
                                np.asarray(cache.harvest_window(h2))])
         cache.drop_carry()
-        seqs.append(toks[:, 0].tolist())
+        seqs.append(toks[:, :len(prompts)].tolist())
         assert cache._carry is None
     assert seqs[0] == seqs[1]
 
