@@ -106,7 +106,10 @@ RULE_IDS = {
 # parameter, e.g. AdmissionScheduler's shared server lock).
 _LOCK_NAME_RE = re.compile(r"(?:^|_)(lock|work|mutex|cv)\d*$")
 
-_LOCK_FACTORIES = {"Lock", "RLock", "DebugLock", "make_lock"}
+_LOCK_FACTORIES = {"Lock", "RLock", "DebugLock", "make_lock", "TimedLock"}
+# A with-block on ``self._hold("<holder>")`` takes the class's lock
+# under that holder's name (runtime/tracing.py Hold).
+_HOLD_METHODS = {"_hold"}
 _COND_FACTORIES = {"Condition", "DebugCondition", "make_condition"}
 _EVENT_FACTORIES = {"Event"}
 _THREAD_FACTORIES = {"Thread", "Timer"}
@@ -296,6 +299,8 @@ class _ScopeLint(ast.NodeVisitor):
             if k is not None:
                 return k
             return "lock" if _LOCK_NAME_RE.search(expr.id) else None
+        if self._is_hold(expr):
+            return "lock"
         if isinstance(expr, ast.Attribute):
             if (isinstance(expr.value, ast.Name)
                     and expr.value.id == "self"):
@@ -315,7 +320,17 @@ class _ScopeLint(ast.NodeVisitor):
     def _is_lockish(self, expr: ast.AST) -> bool:
         return self._expr_kind(expr) in ("lock", "cond")
 
+    @staticmethod
+    def _is_hold(expr: ast.AST) -> bool:
+        return (isinstance(expr, ast.Call)
+                and isinstance(expr.func, ast.Attribute)
+                and isinstance(expr.func.value, ast.Name)
+                and expr.func.value.id == "self"
+                and expr.func.attr in _HOLD_METHODS)
+
     def _is_own_lock(self, expr: ast.AST) -> bool:
+        if self._is_hold(expr):
+            return True
         return (isinstance(expr, ast.Attribute)
                 and isinstance(expr.value, ast.Name)
                 and expr.value.id == "self"

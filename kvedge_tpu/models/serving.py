@@ -24,6 +24,8 @@ capability the repo's own README listed as future work.
 
 from __future__ import annotations
 
+import collections
+import contextlib
 import dataclasses
 import functools
 import hashlib
@@ -45,9 +47,12 @@ from kvedge_tpu.runtime.journal import JournalEntry, RequestJournal
 from kvedge_tpu.models.scheduler import AdmissionScheduler, _Hist
 from kvedge_tpu.runtime.tracing import (
     LOOP_PHASES,
-    Phase,
+    REQUEST_STATES,
+    Hold,
+    LockLedger,
     PhaseClock,
     PhaseSum,
+    TimedLock,
 )
 
 # Stream sentinel objects (token queue carries ints, then one of these).
@@ -202,6 +207,18 @@ class _Request:
     # finish stamp it yields the request's mean inter-token gap — the
     # per-request ITL the rung-25 SLO engine computes its p99 over.
     t_first: float = 0.0
+    # The request's ledger (ISSUE 38): what it is doing now
+    # (runtime/tracing.py REQUEST_STATES) and since when, and the
+    # milliseconds each state has had. A boundary is one stamp: the
+    # state before it ends and the next one starts on it, so at the
+    # end (``t_done``) the states add up to ``t_done - t_submit``.
+    state: str = "queued"
+    t_state: float = 0.0
+    state_ms: dict = dataclasses.field(default_factory=dict)
+    t_done: float = 0.0
+    # The put of the first token on the stream (or its append): where
+    # ``first_emit_ms`` ends and the handler's ``first_write`` starts.
+    t_emit: float = 0.0
     # Exactly-once delivery watermark (rung 22): tokens at indices
     # below this were already streamed to the consumer before a
     # journal restore rewound ``generated`` to the checkpoint —
@@ -260,6 +277,16 @@ class StreamHandle:
 
     def cancel(self) -> None:
         self._server.cancel(self._req)
+
+    def first_written(self) -> None:
+        """The consumer's line with the request's first token is
+        flushed (the HTTP handler says so, once): the time since that
+        token's put on this stream is the request's ``first_write``.
+        One append from the caller's thread, no lock."""
+        t_emit = self._req.t_emit
+        if t_emit:
+            self._server._first_writes.append(
+                (time.perf_counter() - t_emit) * 1e3)
 
 
 class PagedGenerationServer:
@@ -709,10 +736,30 @@ class PagedGenerationServer:
         # the locklint static analyzer. Plain Lock in production.
         if debug_locks:
             from kvedge_tpu.runtime.debuglock import DebugLock
-            self._lock = DebugLock()
+            inner = DebugLock()
         else:
-            self._lock = threading.Lock()
+            inner = threading.Lock()
+        # The one lock keeps its own account (runtime/tracing.py): the
+        # time it is held at all, and by whom. Every site below takes
+        # it through ``self._hold(name)``. Two holders' waits are
+        # phases that were there before the ledger: one record each.
+        self._lock = TimedLock(inner)
         self._work = threading.Condition(self._lock)
+        self._ledger = LockLedger(self._lock, tracer, waits={
+            "loop": self._phase.sinks["loop/lock_wait"],
+            "admit/prefill_chunk": self._hist_prefill_wait,
+        })
+        self._hold = self._ledger.hold
+        # What finished requests did with their time, state by state
+        # (the states of each add up to its life), and the handlers'
+        # first writes: each adds its own from its own thread, and a
+        # snapshot folds them in.
+        self._request_ms = {state: PhaseSum() for state in REQUEST_STATES}
+        self._first_writes: collections.deque = collections.deque()
+        self._first_write_ms = PhaseSum()
+        # The newest core snapshot a lock holder published: what
+        # stats() returns when it cannot have the lock at once.
+        self._published: dict | None = None
         # Admission scheduler (models/scheduler.py, SERVING.md rung 17):
         # per-class ticketed queue + preemption/shed policy. It SHARES
         # the server lock — queue order, slot state, and page
@@ -930,7 +977,7 @@ class PagedGenerationServer:
         waiter (blocked ``submit`` / stream consumer) gets
         :class:`RequestCancelled`.
         """
-        with self._work:
+        with self._hold("cancel", rid=req.rid, ring=req.trace):
             req.cancelled = True
             # Cancel-while-swapped-out (or parked in the journal of a
             # poisoned pool awaiting revive): the request holds no slot
@@ -941,12 +988,8 @@ class PagedGenerationServer:
             if not dropped and req not in self._active.values():
                 dropped = self._journal.pop(req) is not None
             if dropped:
-                req.error = RequestCancelled(
-                    "request cancelled while swapped out"
-                )
-                if req.stream is not None:
-                    req.stream.put(req.error)
-                req.done.set()
+                self._fail_request(req, RequestCancelled(
+                    "request cancelled while swapped out"))
             # Cancel-while-parked: the waiter owns its ticket — wake
             # every parked thread so the cancelled one can dequeue
             # itself without consuming a slot or reservation.
@@ -1026,8 +1069,6 @@ class PagedGenerationServer:
                 f"{self._pages_total}"
             )
 
-        import jax.numpy as jnp
-
         tr = self.tracer
         req = _Request(
             prompt=list(prompt), n_new=n_new, sampling=sampling,
@@ -1043,11 +1084,28 @@ class PagedGenerationServer:
             trace=tr is not None and tr.sampled(request_id),
             t_submit=time.perf_counter(),
         )
+        req.t_state = req.t_submit
         deadline = time.monotonic() + timeout
         if deadline_ms is not None:
             deadline = min(deadline,
                            time.monotonic() + deadline_ms / 1000.0)
-        with self._work:
+        try:
+            return self._admit(req, pages_needed, deadline, priority,
+                               deadline_ms)
+        except BaseException:
+            # Shed, refused, timed out, cancelled or failed before it
+            # was active: its ledger ends here.
+            self._end_request(req, time.perf_counter())
+            raise
+
+    def _admit(self, req: _Request, pages_needed: int, deadline: float,
+               priority: str, deadline_ms: int | None) -> _Request:
+        """The rest of :meth:`_start`, from its first hold of the work
+        lock on: admission, the prefill in chunks, the pick."""
+        import jax.numpy as jnp
+
+        with self._hold("admit/start", rid=req.rid,
+                        ring=req.trace) as hold:
             if self._closed or self._draining:
                 raise self._refusal()
             # Overload shedding: reject BEFORE parking when the queue
@@ -1055,7 +1113,7 @@ class PagedGenerationServer:
             # per-class wait as the retry hint (falling back to the
             # recovery machinery's hint).
             shed = self._sched.shed_check_locked(priority, deadline_ms,
-                                                 rid=request_id)
+                                                 rid=req.rid)
             if shed is None:
                 # Page-watermark shed (capacity semantics, SERVING.md
                 # rung 21): when granting this request's worst-case
@@ -1154,7 +1212,10 @@ class PagedGenerationServer:
                             + (f"; retry after ~{hint:.1f}s"
                                if hint is not None else "") + ")"
                         )
+                    # Parked in the scheduler's queue: not a hold.
+                    hold.pause()
                     ticket.cond.wait(timeout=remaining)
+                    hold.resume()
                 self._sched.admit_locked(ticket)
                 ticket = None  # admitted: the finally must not remove
             finally:
@@ -1162,6 +1223,7 @@ class PagedGenerationServer:
                     self._sched.remove_locked(ticket)
             req.admit_seq = self._sched.next_admit_seq_locked()
             req.t_admit = time.perf_counter()
+            self._to_state(req, "admit", req.t_admit)
             self._hist_queue.observe(
                 (req.t_admit - req.t_submit) * 1e3
             )
@@ -1225,7 +1287,7 @@ class PagedGenerationServer:
             # only wakes; three microseconds more and it loses, and
             # its next chunk waits a window).
             off = shared_tokens  # cached prefix K/V are already in place
-            wait = self._admit_wait(req, off)
+            next_lock = self._admit_wait(req, off)
         # Prefill in chunks, the lock held only PER CHUNK: the decode
         # loop interleaves batched steps for in-flight requests between
         # chunks (they never touch this slot — the loop's active mask
@@ -1241,8 +1303,8 @@ class PagedGenerationServer:
             logits = None
             while off < len(req.prompt):
                 piece = req.prompt[off:off + chunk]
-                with self._work:
-                    wait.stop()
+                with next_lock as hold:
+                    self._to_state(req, "prefill", hold.t0)
                     if self._closed:
                         raise self._refusal()
                     if req.cancelled:
@@ -1257,9 +1319,9 @@ class PagedGenerationServer:
                             jnp.asarray(piece, jnp.int32), off,
                         )
                     off += len(piece)
-                    wait = self._admit_wait(req, off)
-            with self._work:
-                picked = wait
+                    next_lock = self._admit_wait(req, off)
+            with next_lock as hold:
+                picked = hold.waited
                 try:
                     # Re-check under the activation lock: a hard close
                     # can land between the last chunk and here, after
@@ -1272,8 +1334,13 @@ class PagedGenerationServer:
                     # whatever window the device was given before them.
                     req.next_token = req.pick(logits, 0)
                 finally:
-                    picked.stop()
+                    # The pick's hold ends with the token read, on one
+                    # stamp with the phase (its wait for the lock and
+                    # this hold are the phase); the activation below
+                    # is the admission's.
+                    picked.stop(hold.switch("admit/start"))
                 t_first = picked.t1
+                self._to_state(req, "join_wait", t_first)
                 # Time to first token: submit -> the prefill logits'
                 # pick. This is the serving-visible TTFT (the first
                 # emission rides the next loop iteration, but the
@@ -1304,7 +1371,7 @@ class PagedGenerationServer:
                 )
                 self._work.notify_all()  # wake the decode loop
         except Exception as e:
-            with self._work:
+            with self._hold("admit/start", rid=req.rid, ring=req.trace):
                 if not activated:
                     self._prefilling -= 1
                     self._release_locked(slot, req.pages_reserved,
@@ -1323,13 +1390,57 @@ class PagedGenerationServer:
             raise
         return req
 
-    def _admit_wait(self, req: _Request, off: int) -> Phase:
-        """The submit path's next wait for the lock, started (lock
-        still held): for its next prefill chunk, or past the prompt's
-        end for the pick of its first token."""
-        return self._phase(
-            "admit/lock_wait" if off < len(req.prompt)
-            else "admit/first_pick", rid=req.rid, ring=req.trace).start()
+    def _admit_wait(self, req: _Request, off: int) -> Hold:
+        """The submit path's next hold of the lock, made and its wait
+        started (lock still held): for its next prefill chunk, or past
+        the prompt's end for the pick of its first token, whose phase
+        goes on through the hold to the token read."""
+        more = off < len(req.prompt)
+        wait = self._phase(
+            "admit/lock_wait" if more else "admit/first_pick",
+            rid=req.rid, ring=req.trace).start()
+        self._to_state(req, "prefill_wait" if more else "pick", wait.t0)
+        return self._hold(
+            "admit/prefill_chunk" if more else "admit/first_pick",
+            waited=wait, ends_wait=more, rid=req.rid, ring=req.trace)
+
+    # ---- the request's ledger (ISSUE 38) -------------------------------
+
+    def _to_state(self, req: _Request, state: str, now: float) -> None:
+        """The request passes a boundary (``now``: a stamp taken
+        there): the state it was in gets the time since the last one,
+        and for a sampled request the states with no span of their own
+        (the queue's, the chunk phases and the pick have theirs) go
+        to the ring."""
+        was = req.state
+        req.state_ms[was] = (req.state_ms.get(was, 0.0)
+                             + (now - req.t_state) * 1e3)
+        if req.trace and was in ("admit", "join_wait", "swapped"):
+            self.tracer.span(was, "serve", req.t_state, now, rid=req.rid)
+        req.state, req.t_state = state, now
+
+    def _fail_request(self, req: _Request, err: Exception) -> None:
+        """The request ends without the rest of its tokens: its waiter
+        (and its stream) gets ``err``."""
+        req.error = err
+        if req.stream is not None:
+            req.stream.put(err)
+        self._end_request(req, time.perf_counter())
+        req.done.set()
+
+    def _end_request(self, req: _Request, now: float) -> None:
+        """The request's life ends at ``now``, however it ends: its
+        last state is closed, and for a sampled request the root span
+        its states lie in is recorded. Once."""
+        if req.t_done:
+            return
+        self._to_state(req, "done", now)
+        req.t_done = now
+        if req.trace:
+            self.tracer.span(
+                "request", "serve", req.t_submit, now, rid=req.rid,
+                args={"class": req.pclass,
+                      "tokens": len(req.generated)})
 
     # ---- capacity semantics (SERVING.md rung 21) ------------------------
 
@@ -1639,6 +1750,8 @@ class PagedGenerationServer:
         if entry is None:
             return False
         entry.emitted = max(entry.emitted, len(req.generated))
+        # Out of the pool until revive() puts it back.
+        self._to_state(req, "swapped", time.perf_counter())
         return True
 
     def _journal_swapped_locked(self, entry) -> bool:
@@ -1676,10 +1789,7 @@ class PagedGenerationServer:
             req = entry.req
             if req.done.is_set():
                 continue
-            req.error = err
-            if req.stream is not None:
-                req.stream.put(err)
-            req.done.set()
+            self._fail_request(req, err)
 
     def capacity_probe(self) -> dict:
         """Lock-free capacity snapshot for /healthz: like
@@ -1719,10 +1829,7 @@ class PagedGenerationServer:
                 survivors += 1
                 continue
             failed += 1
-            req.error = failure
-            if req.stream is not None:
-                req.stream.put(failure)
-            req.done.set()
+            self._fail_request(req, failure)
         self._active.clear()
         # Degraded mode reaches the swap set too (rung 14 x rung 17):
         # a swapped-out request's device pages are gone and no healthy
@@ -1735,10 +1842,7 @@ class PagedGenerationServer:
                 survivors += 1
                 continue
             failed += 1
-            entry.req.error = failure
-            if entry.req.stream is not None:
-                entry.req.stream.put(failure)
-            entry.req.done.set()
+            self._fail_request(entry.req, failure)
         if self.tracer is not None:
             # The poison instant anchors the flight-recorder tail the
             # post-mortem (last-failure.json) embeds.
@@ -2067,7 +2171,7 @@ class PagedGenerationServer:
         against the decode loop."""
         import json
 
-        with self._lock:
+        with self._hold("control"):
             entries = [
                 {"tokens": self._node_tokens(node),
                  "pages": list(entry["pages"])}
@@ -2137,7 +2241,7 @@ class PagedGenerationServer:
             return 0
         old_pos = {p: i for i, p in enumerate(doc["page_ids"])}
         loaded = 0
-        with self._lock:
+        with self._hold("control"):
             if (not self._prefix_enabled or self._closed
                     or self._prefix_entry_nodes):
                 # Boot-time only: loading into a registry that already
@@ -2197,7 +2301,7 @@ class PagedGenerationServer:
         def loop() -> None:
             dumped_at = 0
             while not self._persist_stop.wait(interval):
-                with self._lock:
+                with self._hold("control"):
                     registered = self._prefix_registrations
                 if registered == dumped_at:
                     continue
@@ -2249,7 +2353,7 @@ class PagedGenerationServer:
         round trip is the exact regression auto mode exists to prevent,
         so unmeasured resolves to windows. Operators who want speculation
         on a slice set an explicit K."""
-        with self._work:
+        with self._hold("control"):
             self._spec = 0
         decision = {"mode": f"windowed ({reason})",
                     "windows_dominate": None}
@@ -2308,7 +2412,7 @@ class PagedGenerationServer:
                 f"per slot); {action}", flush=True,
             )
             if auto:
-                with self._work:
+                with self._hold("control"):
                     self._spec = 0
         self._spec_decision = decision
         return decision
@@ -2337,7 +2441,7 @@ class PagedGenerationServer:
         window = min(self._window, self._cfg.max_seq - 1 - k)
         if window > 1:
             window = 1 << (window.bit_length() - 1)
-        with self._work:
+        with self._hold("control"):
             import jax.numpy as jnp
 
             def timed(op) -> float:
@@ -2411,7 +2515,7 @@ class PagedGenerationServer:
             # the pool tears down would read dying device state.
             self._persist_stop.set()
             self._persist_thread.join(timeout=60)
-        with self._work:
+        with self._hold("control"):
             if drain:
                 self._draining = True
             else:
@@ -2428,7 +2532,7 @@ class PagedGenerationServer:
             # deciding the thread is dead.
             self._thread.join(timeout=60)
         if drain:
-            with self._work:
+            with self._hold("control"):
                 self._closed = True
                 self._sched.wake_all_locked()
                 self._work.notify_all()
@@ -2445,7 +2549,7 @@ class PagedGenerationServer:
         # skip the release rather than hang close() too. stop() itself
         # is also deadline-bounded, so close() stays bounded even when
         # the followers die between the last op and the STOP broadcast.
-        with self._work:
+        with self._hold("control"):
             # A closed pool is never revived: journaled survivors of a
             # poison must not park forever behind a teardown — fail
             # them with the poison (retryable, hint attached) or plain
@@ -2457,7 +2561,7 @@ class PagedGenerationServer:
                 )
         stop = getattr(self._cache, "stop", None)
         if stop is not None and not self._thread.is_alive():
-            with self._work:
+            with self._hold("control"):
                 stop()
 
     def lower_decode_window(self, n_steps: int | None = None):
@@ -2506,7 +2610,7 @@ class PagedGenerationServer:
         supervisor's checkpoint re-restore). Same shapes and dtypes as
         the tree it replaces run the programs already compiled."""
         summary = weights_summary(params)
-        with self._lock:
+        with self._hold("control"):
             self._params = params
             self._weights_gb, self._weights_dtype = summary
 
@@ -2540,7 +2644,7 @@ class PagedGenerationServer:
         if self._thread.is_alive():
             raise RuntimeError("decode loop still running; cannot revive")
         deadline = time.monotonic() + prefill_wait_s
-        with self._work:
+        with self._hold("control") as hold:
             if self._poison is None:
                 raise RuntimeError("pool is not poisoned; nothing to revive")
             # Chunked prefills caught mid-flight by the poison fail on
@@ -2553,7 +2657,9 @@ class PagedGenerationServer:
                         f"{self._prefilling} prefill(s) still in flight "
                         f"after {prefill_wait_s:g}s; cannot revive"
                     )
+                hold.pause()
                 self._work.wait(timeout=left)
+                hold.resume()
             for node in list(self._prefix_entry_nodes):
                 # "revive" never demotes: device K/V are suspect after
                 # a poison. The host tier and the journal's shadow
@@ -2664,6 +2770,7 @@ class PagedGenerationServer:
                 slot = heapq.heappop(self._free_slots)
                 self._reserved += entry.pages_reserved
                 self._active[slot] = req
+                self._to_state(req, "join_wait", time.perf_counter())
                 # In ``restored`` BEFORE the device calls: a faulting
                 # admit/swapin must find its slot and reservation in
                 # the unwind below (the entry is then briefly in both
@@ -2770,23 +2877,62 @@ class PagedGenerationServer:
         # happen after release, so a scrape no longer taxes a decode
         # boundary with their assembly. The Prometheus text rendering
         # itself (runtime/status.py) was always outside.
-        with self._lock:
-            out = self._stats_core_locked()
+        #
+        # A reader does not wait for the lock it measures: if it does
+        # not get the lock within 10 ms, it gets the newest snapshot a
+        # holder published (the loop, at the end of every hold), which
+        # is one hold's counters with that hold's ``clock_s`` like any
+        # other. Only a server that has published nothing yet is
+        # waited for.
+        hold = self._hold("stats")
+        if hold.acquire(-1 if self._published is None else 0.01):
+            try:
+                # The newest snapshot there is, so the one to leave
+                # for the next reader too: no reader is ever given an
+                # older one than another was given before.
+                # locklint: allow[unlocked-call] held: the acquire above is the hold's own, with a time limit, so no with-block fits
+                self._publish_locked(time.perf_counter())
+            finally:
+                hold.release()
+        out = dict(self._published)
         self._stats_merge_unlocked(out)
         return out
+
+    def _publish_locked(self, now: float) -> None:
+        """Leave the core snapshot as of ``now`` (a stamp taken in
+        this hold) for the readers that find the lock taken: some
+        20 us of copying."""
+        self._published = self._stats_core_locked(now)
+
+    @contextlib.contextmanager
+    def _published_on_exit(self):
+        """Around the body of a hold of the loop's: on the way out,
+        whichever way, the snapshot is published as of the end of the
+        loop's last phase, so a reader who comes for what this hold
+        did (a request it finished) finds it there."""
+        try:
+            yield
+        finally:
+            # locklint: allow[unlocked-call] held: this is entered in the loop's with-statement, after its hold, and so left before it
+            self._publish_locked(self._phase.last)
 
     def _stats_locked(self) -> dict:
         # The flight bundle's variant: ONE acquisition covers the
         # whole document so metrics/SLO/books stay mutually
         # consistent (the chaos invariant). The merge helpers are
         # lock-free reads, safe to run with the lock held too.
-        out = self._stats_core_locked()
+        out = self._stats_core_locked(time.perf_counter())
         self._stats_merge_unlocked(out)
         return out
 
-    def _stats_core_locked(self) -> dict:
-        now = time.perf_counter()
+    def _stats_core_locked(self, now: float) -> dict:
         phase_ms = self._phase.snapshot(now)
+        ledger = self._ledger.snapshot(now)
+        # The handlers' first writes since the last snapshot (each a
+        # deque.append from its own thread), folded in under the lock.
+        writes = self._first_writes
+        while writes:
+            self._first_write_ms.observe(writes.popleft())
         out = {
             "degraded": 1 if self._degraded_reason else 0,
             "in_flight": len(self._active),
@@ -2880,11 +3026,21 @@ class PagedGenerationServer:
             "clock_s": now,
             "phase_ms": phase_ms,
             "loop_ms_total": self._loop_ms(now),
-            # The loop's phases are a chain: whatever it does outside
-            # its two waits, it does holding the lock.
-            "loop_lock_held_ms_total": sum(
-                phase_ms[name][1] for name in LOOP_PHASES
-                if name not in ("loop/lock_wait", "loop/wait_work")),
+            # The work lock's ledger (runtime/tracing.py): the time it
+            # was held at all, and each holder's waits and holds. The
+            # loop's share is its entry, under the name it had before
+            # the ledger.
+            **ledger,
+            "loop_lock_held_ms_total": ledger["lock_held_ms"]["loop"][1],
+            # What finished requests did with their time, state by
+            # state: [requests, total ms]. A request's states add up
+            # to its life, and every state is over the same requests.
+            "request_ms": {
+                **{state: [acc.n, acc.total]
+                   for state, acc in self._request_ms.items()},
+                "first_write": [self._first_write_ms.n,
+                                self._first_write_ms.total],
+            },
             "prefill_lock_wait_ms": self._hist_prefill_wait.snapshot(),
             "prefill_chunk_ms": self._hist_prefill_chunk.snapshot(),
             "first_emit_ms": self._hist_first_emit.snapshot(),
@@ -3042,7 +3198,7 @@ class PagedGenerationServer:
         consistent (the chaos invariant compares them). Works on a
         poisoned pool: nothing here touches device state beyond the
         same host-side books stats() already reads."""
-        with self._lock:
+        with self._hold("control"):
             doc = {
                 "bundle_version": 1,
                 "reason": self._degraded_reason,
@@ -3174,11 +3330,19 @@ class PagedGenerationServer:
                 (t1 - req.t_first) * 1e3 / (len(req.generated) - 1)
             )
         if req.trace:
+            # The state the request ends in, with what it made.
             self.tracer.span(
-                "decode", "serve", req.t_admit or t1, t1, rid=req.rid,
+                "decode", "serve",
+                req.t_state if req.state == "decode" else t1, t1,
+                rid=req.rid,
                 args={"tokens": len(req.generated),
                       "class": req.pclass},
             )
+        # Its ledger closes on the same stamp, and its states join
+        # those of the requests that finished before it.
+        self._end_request(req, t1)
+        for state, ms in req.state_ms.items():
+            self._request_ms[state].observe(ms)
         del self._active[slot]
         self._journal.pop(req)  # a finished request never resumes
         if self._prefix_enabled:
@@ -3256,8 +3420,9 @@ class PagedGenerationServer:
         self._tokens_emitted += len(req.generated) - before
         if (before == 0 and req.generated
                 and req.stream_resume_at == 0):
+            req.t_emit = time.perf_counter()
             self._hist_first_emit.observe(
-                (time.perf_counter() - req.t_first) * 1e3)
+                (req.t_emit - req.t_first) * 1e3)
 
     def _emit_pending_locked(self, req: _Request) -> None:
         """Emit the request's pending token alone, and book it."""
@@ -3320,6 +3485,10 @@ class PagedGenerationServer:
                 if req.sampling is None:
                     spec_mask[slot] = True
                     tokens[slot, 1:] = self._draft(req, k)
+        joined = phase.last  # the end of loop/dispatch
+        for req in self._active.values():
+            if req.state != "decode":
+                self._to_state(req, "decode", joined)
         with phase("loop/harvest_wait",
                    args={"rows": len(self._active), "spec": k}):
             emitted, accepted, logits0 = self._cache.step_spec(
@@ -3426,12 +3595,8 @@ class PagedGenerationServer:
             self._journal.pop(req)  # a cancelled request never resumes
             self._release_locked(slot, self._pages_for(req),
                                  req.shared_pages)
-            req.error = RequestCancelled(
-                "request cancelled mid-decode"
-            )
-            if req.stream is not None:
-                req.stream.put(req.error)
-            req.done.set()
+            self._fail_request(req, RequestCancelled(
+                "request cancelled mid-decode"))
 
     def _note_finish_candidate_locked(self, slot: int,
                                       req: _Request) -> None:
@@ -3615,6 +3780,8 @@ class PagedGenerationServer:
             self._note_finish_candidate_locked(slot, req)
             self._cache.admit(slot, head.saved_len)
             self._cache.swapin_slot(slot, arrays)
+            # Back in the pool, and waiting for a window again.
+            self._to_state(req, "join_wait", time.perf_counter())
 
     def _maybe_preempt_locked(self) -> None:
         """Swap out lower-class victims while the policy head is
@@ -3648,6 +3815,7 @@ class PagedGenerationServer:
             # without the other.
             arrays = (self._cache.swapout_pages(ids)
                       + self._cache.swapout_row(victim))
+            self._to_state(req, "swapped", time.perf_counter())
             del self._active[victim]
             # A preempted victim becomes SELF-CONTAINED: the verbatim
             # gather above copied its shared-prefix pages too, so its
@@ -3699,12 +3867,6 @@ class PagedGenerationServer:
         live = (now - since) * 1e3 if since else 0.0
         return self._loop_ran.total + live
 
-    def _lock_taken_locked(self) -> None:
-        """The first thing the loop does with the lock: end its wait
-        for it and make the next one."""
-        self._lock_wait.stop()
-        self._lock_wait = self._phase("loop/lock_wait")
-
     def _loop_once(self) -> str:
         """One iteration of the double-buffered decode loop ("exit"
         ends it).
@@ -3734,15 +3896,20 @@ class PagedGenerationServer:
         host truncates each row's emitted stream at its own cap.
         """
         phase = self._phase
-        with self._work:
-            self._lock_taken_locked()
+        with self._hold("loop", waited=self._lock_wait) as hold, \
+                self._published_on_exit():
+            # The wait for the lock ended on the acquire's stamp; the
+            # next one is made now.
+            self._lock_wait = phase("loop/lock_wait")
             while (not self._active and self._inflight is None
                    and not self._closed
                    and not self._sched_attention_locked()
                    and not (self._draining
                             and not self._prefilling)):
+                hold.pause()
                 with phase("loop/wait_work"):
                     self._work.wait()
+                hold.resume()
             if (self._draining and not self._active
                     and self._inflight is None
                     and not self._prefilling
@@ -3758,11 +3925,8 @@ class PagedGenerationServer:
                     for _, req, adv in rec["parts"]:
                         req.inflight -= adv
                 for req in self._active.values():
-                    req.error = ServerClosed("server shut down mid-"
-                                             "request")
-                    if req.stream is not None:
-                        req.stream.put(req.error)
-                    req.done.set()
+                    self._fail_request(req, ServerClosed(
+                        "server shut down mid-request"))
                 self._active.clear()
                 self._fail_swapped_closed_locked()
                 return "exit"
@@ -3953,12 +4117,8 @@ class PagedGenerationServer:
         fail its waiter and free the host snapshot."""
         for entry in self._sched.take_swapped_locked():
             entry.arrays = ()  # nothing will journal this snapshot
-            entry.req.error = ServerClosed(
-                "server shut down mid-request (swapped out)"
-            )
-            if entry.req.stream is not None:
-                entry.req.stream.put(entry.req.error)
-            entry.req.done.set()
+            self._fail_request(entry.req, ServerClosed(
+                "server shut down mid-request (swapped out)"))
         self._sched.wake_all_locked()
 
     def _dispatch_window_locked(self, first: bool) -> dict | None:
@@ -4053,13 +4213,17 @@ class PagedGenerationServer:
                 self._params, tokens, w, active=mask,
                 steps_left=steps_left, stop_tokens=stop_tokens,
             )
+        t0 = time.perf_counter()
         for _, req, adv in recs:
             req.inflight += adv
+            if req.state != "decode":
+                # The first window that carries the row: a newcomer's
+                # wait since its pick ends on the dispatch's stamp.
+                self._to_state(req, "decode", t0)
         self._pipeline_joins += joins
         self._hist_depth.observe(0.0 if first else 1.0)
         return {"window": w, "parts": recs, "handle": handle,
-                "depth": 0 if first else 1, "bucket": n,
-                "t0": time.perf_counter()}
+                "depth": 0 if first else 1, "bucket": n, "t0": t0}
 
     def _harvest_locked(self, rec: dict) -> None:
         """Force an in-flight window's tokens and reconcile (lock
@@ -4247,15 +4411,18 @@ class PagedGenerationServer:
                 self._params, None, w, k, budgets, sampling=sampling,
             )
         recs = []
+        t0 = time.perf_counter()
         for slot, req in parts:
             cap = int(handle["caps"][slot])
             req.inflight += cap
             recs.append((slot, req, cap))
+            if req.state != "decode":
+                self._to_state(req, "decode", t0)
         self._hist_depth.observe(0.0 if first else 1.0)
         return {"kind": "spec_sampled" if samplers else "spec",
                 "window": w, "parts": recs,
                 "handle": handle, "depth": 0 if first else 1,
-                "bucket": n, "t0": time.perf_counter()}
+                "bucket": n, "t0": t0}
 
     def _harvest_spec_window_locked(self, rec: dict) -> None:
         """Force an in-flight spec window's results and reconcile

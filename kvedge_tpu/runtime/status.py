@@ -555,6 +555,20 @@ def render_metrics(snapshot: dict) -> str:
         for cause in sorted(collapses):
             lines.append(
                 f'{name}{{cause="{cause}"}} {collapses[cause]}')
+    # The work lock's ledger (runtime/tracing.py): each holder's time
+    # with the lock and its time waiting for it ({holder: [count,
+    # ms]}). The holds add up to the time the lock was held at all.
+    for what, verb in (("held", "held"), ("wait", "waited for")):
+        ledger = serving.get(f"lock_{what}_ms")
+        if isinstance(ledger, dict) and ledger:
+            name = f"kvedge_serve_lock_{what}_ms_total"
+            lines.append(
+                f"# HELP {name} milliseconds the serving work lock was "
+                f"{verb}, by holder (loop, admit/*, cancel, stats, control)")
+            lines.append(f"# TYPE {name} counter")
+            for holder in sorted(ledger):
+                lines.append(
+                    f'{name}{{holder="{holder}"}} {ledger[holder][1]:.3f}')
     # Prefix-cache evictions by cause (rung 24): admission = LRU sweep
     # to fit an arrival; pressure = mid-decode pool-relief callback;
     # revive = post-poison scrub (device bytes untrusted, never
@@ -848,12 +862,19 @@ class StatusServer:
                     self.send_header(name, value)
                 self.end_headers()
                 self.close_connection = True
+                # The serving layer's stamp for each row's first line
+                # out (request_ms["first_write"]): asked for until
+                # every row has written one.
+                first_written = result.get("_first_written")
                 try:
                     for item in stream:
                         self.wfile.write(
                             (json.dumps(item) + "\n").encode()
                         )
                         self.wfile.flush()
+                        if (first_written is not None and "token" in item
+                                and not first_written(item["row"])):
+                            first_written = None
                 except BrokenPipeError:
                     # Client went away: close the stream so the serving
                     # layer cancels its rows at the next decode boundary

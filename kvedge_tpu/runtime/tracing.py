@@ -80,6 +80,17 @@ the first token read back from the prefill's logits: the caller
 waits, lock held, for its chunks and for the window the device was
 given before them).
 
+The work lock's ledger (ISSUE 38). The server's one lock is a
+:class:`TimedLock`: it stamps every acquire and release and adds the
+difference to one total that knows nothing of names. Every site that
+takes the lock does so through a :class:`Hold` that says who it is
+(:data:`LOCK_HOLDERS`): its wait goes to ``lock_wait_ms[name]`` and
+its hold, a phase ``lock/<name>`` with the three sinks above (so a
+capture shows ``kvedge/lock/<name>`` on the holder's line), to
+``lock_held_ms[name]``. A hold parked in a ``Condition.wait`` is
+paused: it holds nothing. What the names leave of the total is what
+some site took without saying who it was.
+
 Export targets:
 
 * ``GET /trace`` (runtime/status.py) returns
@@ -396,10 +407,13 @@ class Phase:
 
     __enter__ = start
 
-    def stop(self) -> None:
+    def stop(self, t1: float | None = None) -> None:
+        """``t1``: a stamp another primitive took at this same
+        boundary (the lock's own, of the acquire that ends a wait), so
+        one boundary has one stamp."""
         if self.t1 is not None:
             return
-        self.t1 = t1 = time.perf_counter()
+        self.t1 = t1 = time.perf_counter() if t1 is None else t1
         if self._chain is not None:
             self._chain.last = t1
             self._chain.open = None
@@ -444,12 +458,14 @@ class PhaseClock:
         return self.last
 
     def __call__(self, name: str, *, rid: str = "", ring: bool = True,
-                 args: dict | None = None) -> Phase:
+                 args: dict | None = None, chain=None) -> Phase:
         """``ring=False`` keeps an unsampled request's phase out of the
-        ring (its other two sinks stay on)."""
+        ring (its other two sinks stay on). ``chain``: a chain other
+        than the clock's own (a :class:`Hold`'s)."""
+        if chain is None and name in self._chained:
+            chain = self
         return Phase(name, self.sinks[name], self._annotate,
-                     self.tracer if ring else None, rid, args,
-                     self if name in self._chained else None)
+                     self.tracer if ring else None, rid, args, chain)
 
     def snapshot(self, now: float) -> dict:
         """name -> [count, total ms], for ``stats()``. The chain's
@@ -464,3 +480,189 @@ class PhaseClock:
         if name is not None:
             out[name][1] += max(0.0, now - self.last) * 1e3
         return out
+
+
+# ---- the work lock's ledger ----------------------------------------------
+
+# Who may hold the work lock: a site that takes it names itself one of
+# these (a :class:`Hold`), and any other name is a KeyError.
+LOCK_HOLDERS = ("loop", "admit/start", "admit/prefill_chunk",
+                "admit/first_pick", "cancel", "stats", "control")
+# What a request is doing, from submit to its last token: every
+# millisecond of its life belongs to one of these (``request_ms``).
+# ``first_write`` is the handler's, beside them: the first token's put
+# on the stream to the flush of its line.
+REQUEST_STATES = ("queued", "admit", "prefill_wait", "prefill", "pick",
+                  "join_wait", "decode", "swapped")
+
+
+class TimedLock:
+    """A lock that keeps its own account: ``perf_counter()`` at every
+    acquire and release, the difference added to ``held_ms_total``. It
+    wraps the lock it is given (``threading.Lock``, or
+    :class:`~kvedge_tpu.runtime.debuglock.DebugLock`, whose ownership
+    probes pass through), and a ``Condition`` made on it releases and
+    re-acquires through it, so a thread parked in ``wait`` holds
+    nothing. The stamps are written with the lock held."""
+
+    def __init__(self, inner):
+        self.inner = inner
+        self.t_acquired = 0.0
+        self.held_ms_total = 0.0
+        for probe in ("_is_owned", "assert_held"):
+            if hasattr(inner, probe):
+                setattr(self, probe, getattr(inner, probe))
+
+    def acquire(self, blocking: bool = True, timeout: float = -1) -> bool:
+        got = self.inner.acquire(blocking, timeout)
+        if got:
+            self.t_acquired = time.perf_counter()
+        return got
+
+    def release(self, now: float | None = None) -> None:
+        """``now``: the holder's own stamp of this boundary, where it
+        took one. Counted before the release: whoever asks first after
+        it gets the lock, and nothing new stands between the two."""
+        if now is None:
+            now = time.perf_counter()
+        self.held_ms_total += (now - self.t_acquired) * 1e3
+        self.inner.release()
+
+    __enter__ = acquire
+
+    def __exit__(self, *exc) -> None:
+        self.release()
+
+    def locked(self) -> bool:
+        return self.inner.locked()
+
+
+class Hold:
+    """One named hold of the work lock, entered where ``with lock:``
+    stood. It is a chain of ``lock/<name>`` phases (it is their
+    ``chain``: ``last``, ``open``) from the lock's own acquire stamp
+    to the stamp the lock counts its release by: :meth:`pause` and
+    :meth:`resume` stand around a ``Condition.wait`` inside it, and
+    :meth:`switch` hands the rest of the hold to another name. The
+    wait for the lock runs from the start of ``waited``, a phase the
+    thread started when it asked (a hold may be made ahead, like its
+    wait: nothing is built between a release and the next acquire),
+    and from now without one. Where that phase is the wait and
+    nothing more (``ends_wait``) it is stopped here, on the acquire's
+    stamp, and is the record; otherwise the wait goes to
+    ``lock_wait_ms[name]``."""
+
+    __slots__ = ("name", "waited", "t0", "last", "open", "_ledger",
+                 "_phase", "_ends_wait", "_rid", "_ring")
+
+    def __init__(self, ledger: "LockLedger", name: str, waited,
+                 ends_wait: bool, rid: str, ring: bool):
+        ledger.held["lock/" + name]  # an unknown holder is an error
+        self.name = name
+        self.waited = waited
+        self.t0 = self.last = 0.0
+        self.open = None
+        self._ledger = ledger
+        self._phase = None
+        self._ends_wait = ends_wait
+        self._rid = rid
+        self._ring = ring
+
+    def acquire(self, timeout: float = -1) -> bool:
+        """``timeout``: seconds to wait at most (False: not had, and
+        nothing recorded); -1 waits for as long as it takes."""
+        ledger = self._ledger
+        waited = self.waited
+        asked = time.perf_counter() if waited is None else waited.t0
+        if not ledger.lock.acquire(True, timeout):
+            return False
+        self.t0 = self.last = t = ledger.lock.t_acquired
+        if waited is not None and self._ends_wait:
+            waited.stop(t)
+        else:
+            ledger.wait[self.name].observe((t - asked) * 1e3)
+        self._begin()
+        return True
+
+    def _begin(self) -> None:
+        self._ledger.current = self
+        self._phase = self._ledger.clock(
+            "lock/" + self.name, rid=self._rid, ring=self._ring,
+            chain=self).start()
+
+    def pause(self) -> None:
+        """Before a ``Condition.wait`` (which releases the lock)."""
+        self._phase.stop()
+        self._ledger.current = None
+
+    def resume(self) -> None:
+        """After it: held again since the lock's own stamp."""
+        self.last = self._ledger.lock.t_acquired
+        self._begin()
+
+    def switch(self, name: str) -> float:
+        """The hold goes on under another name; returns the stamp of
+        the boundary."""
+        self._ledger.held["lock/" + name]
+        self._phase.stop()
+        self.name = name
+        self._begin()
+        return self.last
+
+    def release(self) -> None:
+        # One stamp for the hold's end and the lock's release: between
+        # two stamps a thread can lose the interpreter for 5 ms.
+        self._phase.stop()
+        self._ledger.current = None
+        self._ledger.lock.release(self.last)
+
+    def __enter__(self) -> "Hold":
+        self.acquire()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.release()
+        return False
+
+
+class LockLedger:
+    """The work lock's account by holder: the :class:`TimedLock`, the
+    accumulators of every holder's waits and holds, and the hold under
+    way (``current``, written by the thread that holds the lock).
+    ``waits`` gives the holders whose wait an accumulator of the
+    server's already measures (the loop's ``loop/lock_wait``, a
+    chunk's ``admit/lock_wait``): one record, two names."""
+
+    def __init__(self, lock: TimedLock, tracer: "Tracer | None" = None,
+                 waits: dict | None = None):
+        self.lock = lock
+        self.held = {"lock/" + name: PhaseSum() for name in LOCK_HOLDERS}
+        self.wait = {name: PhaseSum() for name in LOCK_HOLDERS}
+        self.wait.update(waits or {})
+        self.clock = PhaseClock(self.held, tracer)
+        self.current: Hold | None = None
+
+    def hold(self, name: str, *, waited: Phase | None = None,
+             ends_wait: bool = True, rid: str = "",
+             ring: bool = False) -> Hold:
+        return Hold(self, name, waited, ends_wait, rid, ring)
+
+    def snapshot(self, now: float) -> dict:
+        """``lock_held_ms_total``, ``lock_held_ms`` and
+        ``lock_wait_ms`` (name -> [count, total ms]) for ``stats()``,
+        taken with the lock held: the hold under way is counted as far
+        as it has got, in its name and in the total alike, so both
+        gain the same between two snapshots."""
+        held = {name[len("lock/"):]: [acc.n, acc.total]
+                for name, acc in self.held.items()}
+        total = self.lock.held_ms_total
+        hold = self.current
+        if hold is not None and hold.open is not None:
+            held[hold.name][1] += max(0.0, now - hold.last) * 1e3
+            total += max(0.0, now - self.lock.t_acquired) * 1e3
+        return {
+            "lock_held_ms_total": total,
+            "lock_held_ms": held,
+            "lock_wait_ms": {name: [acc.n, acc.total]
+                             for name, acc in self.wait.items()},
+        }

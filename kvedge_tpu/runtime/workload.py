@@ -1996,7 +1996,18 @@ def _build_serve(cfg, base, tcfg, params, restored_step, *, cache=None,
                             "request_id": rid,
                         }
 
-                    return {"_stream": ndjson(), "request_id": rid}
+                    unwritten = set(range(len(prompts)))
+
+                    def first_written(row: int) -> int:
+                        """The handler flushed row ``row``'s first
+                        line; returns the rows still to write one."""
+                        if row in unwritten:
+                            unwritten.discard(row)
+                            sources[row].first_written()
+                        return len(unwritten)
+
+                    return {"_stream": ndjson(), "request_id": rid,
+                            "_first_written": first_written}
 
                 rows: list = [None] * len(tokens)
 

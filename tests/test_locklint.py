@@ -386,7 +386,9 @@ def test_server_debug_locks_bit_identical_and_asserting():
 
     srv = _small_server(debug_locks=True)
     try:
-        assert isinstance(srv._lock, DebugLock)
+        # the work lock keeps its own account (tracing.TimedLock) over
+        # whichever lock the knob chose: the two compose
+        assert isinstance(srv._lock.inner, DebugLock)
         got = srv.submit(prompt, 8)
         assert got == expect  # assertions change nothing observable
         names = [n for n in dir(type(srv)) if n.endswith("_locked")]

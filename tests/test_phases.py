@@ -372,6 +372,23 @@ def test_a_capture_holds_every_phase_on_a_host_plane(capture, name):
         assert not where & loop_lines
 
 
+@pytest.mark.parametrize("holder", ["loop", "admit/start",
+                                    "admit/prefill_chunk",
+                                    "admit/first_pick"])
+def test_a_capture_holds_every_hold_of_the_lock_on_its_threads_line(
+        capture, holder):
+    """The lock's ledger (ISSUE 38) on the profiler's clock: a hold is
+    ``kvedge/lock/<holder>`` on the line of the thread that holds, the
+    loop's on the loop's line and a handler's on none of the loop's."""
+    where = capture["kvedge/lock/" + holder]
+    assert where and all(plane.startswith("/host:") for plane, _ in where)
+    loop_lines = {w for n in LOOP_PHASES for w in capture["kvedge/" + n]}
+    if holder == "loop":
+        assert where == loop_lines
+    else:
+        assert not where & loop_lines
+
+
 def test_tokens_do_not_depend_on_a_live_capture(params, tmp_path):
     def tokens():
         server = _server(params)

@@ -217,6 +217,23 @@ def test_preempt_resume_on_slice_cache_is_exact(params):
 # ---- fairness: ticketed same-class ordering (satellite 1) ----------------
 
 
+def gate_the_loop(server) -> threading.Event:
+    """While the returned event is set the decode loop stands still
+    between two trips (lock released): an occupier cannot finish and
+    hand its slot on before every waiter of a test has parked, however
+    slowly a loaded machine runs the waiters' threads."""
+    held = threading.Event()
+    trip = server._loop_once
+
+    def gated_trip():
+        while held.is_set():
+            time.sleep(0.001)
+        return trip()
+
+    server._loop_once = gated_trip
+    return held
+
+
 def test_same_class_waiters_admit_in_arrival_order(params):
     """Two same-class waiters must admit in ARRIVAL order. Under the
     old Condition.notify_all herd, admission order was whatever the
@@ -227,9 +244,11 @@ def test_same_class_waiters_admit_in_arrival_order(params):
     after its decode already finished."""
     server = sched_server(params, sched_swap_budget_mb=0)
     seqs = {}
+    held = gate_the_loop(server)
     try:
         occ = server.submit_stream([7, 7, 7], n_new=30)
         next(occ)
+        held.set()
 
         def worker(tag, prompt):
             h = server.submit_stream(prompt, n_new=2)
@@ -243,11 +262,13 @@ def test_same_class_waiters_admit_in_arrival_order(params):
         b.start()
         wait_for(lambda: parked_depth(server) == 2, what="B parked")
         occ.cancel()
+        held.clear()
         a.join(timeout=120)
         b.join(timeout=120)
         assert not a.is_alive() and not b.is_alive()
         assert seqs["A"] < seqs["B"]
     finally:
+        held.clear()
         server.close()
 
 
@@ -259,18 +280,9 @@ def test_strict_policy_admits_interactive_before_earlier_batch(params):
     test_same_class_waiters_admit_in_arrival_order)."""
     server = sched_server(params, sched_swap_budget_mb=0)
     seqs = {}
-    # The decode loop stands still between two trips (lock released)
-    # while both waiters park: the occupier cannot finish and hand the
-    # slot to the batch request before the interactive one has queued.
-    held = threading.Event()
-    trip = server._loop_once
-
-    def gated_trip():
-        while held.is_set():
-            time.sleep(0.001)
-        return trip()
-
-    server._loop_once = gated_trip
+    # The occupier cannot finish and hand the slot to the batch
+    # request before the interactive one has queued.
+    held = gate_the_loop(server)
     try:
         occ = server.submit_stream([7, 7, 7], n_new=30)
         next(occ)
@@ -307,9 +319,12 @@ def test_strict_policy_admits_interactive_before_earlier_batch(params):
 def test_cancel_while_parked_leaks_nothing(params):
     server = sched_server(params, sched_swap_budget_mb=0)
     errors = []
+    # The occupier (30 tokens) must still be there when it is counted.
+    held = gate_the_loop(server)
     try:
         occ = server.submit_stream([7, 7], n_new=30)
         next(occ)
+        held.set()
 
         def worker():
             try:
@@ -331,12 +346,14 @@ def test_cancel_while_parked_leaks_nothing(params):
         assert parked_depth(server) == 0
         assert server.stats()["in_flight"] == 1
         occ.cancel()
+        held.clear()
         with pytest.raises(RequestCancelled):
             list(occ)
         wait_for(lambda: server.stats()["in_flight"] == 0,
                  what="occupier release")
         assert_idle_fixpoint(server, pages=16)
     finally:
+        held.clear()
         server.close()
 
 

@@ -615,6 +615,11 @@ def test_http_generate_streams_ndjson(tmp_path):
         assert len(token_lines) == 4
         assert final["tokens"] == want["tokens"]
         assert [ln["token"] for ln in token_lines] == want["tokens"][0][3:]
+        # The handler stamped the flush of the streamed request's first
+        # line (the buffered request has no such line): one first_write,
+        # from that token's put on the stream, never negative.
+        wrote = handle.serve_fn.stats()["request_ms"]["first_write"]
+        assert wrote[0] == 1 and wrote[1] >= 0.0
     finally:
         handle.shutdown()
 

@@ -236,6 +236,12 @@ def test_tracing_is_token_bit_identical(params):
         assert (set(LOOP_PHASES) - {"loop/wait_work"}
                 | set(ADMIT_PHASES)) <= names
         assert "window" in names
+        # The two ledgers' spans (ISSUE 38) are on the same pin: a
+        # sampled request's root span, the states that had no span
+        # before, and its holds of the work lock.
+        assert {"request", "admit", "join_wait", "lock/admit/start",
+                "lock/admit/prefill_chunk",
+                "lock/admit/first_pick"} <= names
     assert off[0] == reference(params, [5, 9, 2, 7], 9)
 
 
