@@ -20,7 +20,7 @@ is one JSON object, ``{"ok": true, "device": {"platform": "tpu",
 not on a CPU backend, not on a degraded runtime handle, not when a
 request fails or the pool had to heal itself, not when the compiled
 decode kernel and the gather give different bits or tokens, and not
-when the SSM step kernel and the plain one-token form part.
+when the SSM or the delta step kernel and its plain one-token form part.
 
 One process holds the chip; nothing here starts a child that needs it.
 """
@@ -826,12 +826,20 @@ def phase_delta_op(smoke: Smoke) -> None:
     in blocks of 16 from the same state (its triangular solve as the
     backend expands it). Compiled by the backend's own compiler: on the
     TPU this is where a product or a solve in less than float32 would
-    show."""
+    show. Then the one-pass step kernel (ops/delta_step.py) against
+    ``_one_token`` on the whole stacked state, one position, the third
+    of the four rows not decoding, after a call that donates the state:
+    the live rows' new state and ``o`` within float32 rounding, and
+    every other layer, the row that is not decoding and the slot past
+    the batch unchanged in every bit. Compiled on the TPU, interpreted
+    elsewhere."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from kvedge_tpu.models import delta
+    from kvedge_tpu.ops import pallas_interpret
+    from kvedge_tpu.ops.delta_step import delta_step
 
     layers, slots, rows, heads, dk, dv, layer = 3, 5, 4, 4, 128, 128, 1
     positions, block = 48, 16
@@ -897,6 +905,45 @@ def phase_delta_op(smoke: Smoke) -> None:
         if not all(o <= 2e-5 and S <= 2e-5 for o, S in gaps.values()):
             raise SmokeFailure("a form of the delta-rule mixer disagrees "
                                "with the recurrence beyond float32 "
+                               "rounding")
+
+        now = [a[:, 0] for a in (q, k, v, g, beta)]
+        live = np.array([True, True, False, True])
+        before = np.asarray(state)
+        want_o, want = jax.jit(delta._one_token)(start, *now)
+        got_o, got = jax.jit(
+            lambda *args: delta_step(*args, interpret=pallas_interpret()),
+            donate_argnums=(0,),
+        )(state, jnp.asarray(layer, jnp.int32), *now, jnp.asarray(live))
+        got, got_o = np.asarray(got), np.asarray(got_o)
+        want, want_o = np.asarray(want)[live], np.asarray(want_o)[live]
+        touched = np.zeros(before.shape[:2], bool)
+        touched[layer, :rows] = live
+        moved = int((got[~touched].view(np.uint32)
+                     != before[~touched].view(np.uint32)).sum())
+        moved += int((got_o[~live] != 0).sum())
+        new = got[layer, :rows][live]
+        differing = int((new.view(np.uint32) != want.view(np.uint32)).sum())
+        state_gap = float(np.abs(new - want).max() / np.abs(want).max())
+        o_gap = float(np.abs(got_o[live] - want_o).max()
+                      / np.abs(want_o).max())
+        entry.update(elements=int(new.size), differing=differing,
+                     state_rel_gap=state_gap, o_rel_gap=o_gap,
+                     untouched_moved=moved)
+        smoke.say(f"delta op, kernel vs plain one-token form at {rows} rows "
+                  f"of {heads} heads of {dk} x {dv}, layer {layer} of "
+                  f"{layers}: {differing} of {new.size} float32 elements "
+                  f"of the new state differ, largest gap {state_gap:.3g} "
+                  f"of its scale; o within {o_gap:.3g} of its scale; "
+                  f"{moved} elements of the other layers, the dead row "
+                  f"and the slot past the batch moved")
+        if moved:
+            raise SmokeFailure(
+                f"the delta step kernel changed {moved} elements of state "
+                f"it was not asked to touch")
+        if not (state_gap <= 2e-6 and o_gap <= 1e-5):
+            raise SmokeFailure("the delta step kernel disagrees with the "
+                               "plain one-token form beyond float32 "
                                "rounding")
 
 

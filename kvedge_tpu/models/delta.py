@@ -32,11 +32,17 @@ quarter of a gigabyte a layer at the benchmark's sizes, written out and
 read back)
 
 * the **one-token form** (a decode step, Q = 1, :func:`_one_token`) is
-  those lines once for every row of the batch. The update reads the
-  state twice in turn: ``u`` needs ``S~^T k`` before ``S_t`` can be
-  written. ``o`` needs no third read: ``S_t^T q = S~^T q + (k . q) u``,
-  so both reductions are taken from the decayed state in one pass and
-  the update is the second;
+  those lines once for every row of the batch. ``o`` needs no read of
+  its own: ``S_t^T q = S~^T q + (k . q) u``, so both reductions are
+  taken from the decayed state together. Written in ``jax.numpy`` XLA
+  still makes two passes of it, the reductions and then the update in
+  place, because ``u`` needs ``S~^T k`` before ``S_t`` can be written;
+  in a decode step or window on a TPU backend, at sizes it tiles, it is
+  one pass in a kernel instead (ops/delta_step.py, handed the stacked
+  state whole and updating it in place; :func:`step_in_kernel` decides,
+  from the trace alone). ``_one_token`` stays the form of a one-token
+  prefill piece, of every other backend and size, and what the kernel
+  is held to;
 * the **chunk form** (a prefill chunk, Q > 1, :func:`_block`) is the
   recurrence unrolled over a block of T positions from the carried
   ``S_0``: ``S_t = diag(exp G_t) S_0 + sum_{s<=t} diag(exp(G_t - G_s))
@@ -67,12 +73,25 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from kvedge_tpu.models import ssm
+from kvedge_tpu.ops import delta_step
+
 _HIGHEST = lax.Precision.HIGHEST
 
 
 def conv_dim(cfg) -> int:
     """Channels the causal conv runs over: q | k | v."""
     return 2 * cfg.ssm_heads * cfg.ssm_state + cfg.ssm_inner
+
+
+def step_in_kernel(cfg, slot, q_len: int) -> bool:
+    """Whether this trace's one-token form is the kernel
+    (ops/delta_step.py): ``ssm.step_in_kernel``'s question, a decode
+    step or window on a TPU backend, at the sizes this kernel tiles.
+    Decided from what the trace can see; there is no option."""
+    return (slot is None and q_len == 1 and ssm._on_tpu()
+            and delta_step.tiles(cfg.ssm_heads, cfg.ssm_state,
+                                 cfg.ssm_head_dim))
 
 
 def _one_token(S, q, k, v, g, beta):
@@ -119,7 +138,7 @@ def _l2norm(x):
     return x * lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
 
 
-def delta_mixer(cfg, h, w: dict, state, tail, live=None):
+def delta_mixer(cfg, h, w: dict, state, tail, live=None, layer=None):
     """The mixer over normed activations ``h`` [R, Q, D] of R rows.
 
     ``w``: one layer's ``w_qkv`` [D, 2 H dk + H dv] (q | k | v),
@@ -132,6 +151,12 @@ def delta_mixer(cfg, h, w: dict, state, tail, live=None):
     live gets its state back untouched. Q == 1 runs the one-token
     form, Q > 1 the chunk form. Returns ``(out [R, Q, D], state,
     tail)``.
+
+    With ``layer`` given (:func:`step_in_kernel` said so), ``state`` is
+    the whole stacked state [delta layers, slots, H, dk, dv], the rows
+    are its first R slots, and the one-token form is the kernel on
+    layer ``layer`` of it, in place: what comes back is the stacked
+    state.
     """
     rows, q_len, _ = h.shape
     heads, dv, dk = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state
@@ -165,7 +190,14 @@ def delta_mixer(cfg, h, w: dict, state, tail, live=None):
     gate = jax.nn.sigmoid(
         (low[..., rank:2 * rank] @ w["w_g2"].astype(dtype)).astype(f32))
 
-    if q_len == 1:
+    if layer is not None:
+        from kvedge_tpu.ops import pallas_interpret
+
+        o, new_state = delta_step.delta_step(
+            state, layer, q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
+            live, interpret=pallas_interpret())
+        o = o[:, None]
+    elif q_len == 1:
         o, new_state = _one_token(state, q[:, 0], k[:, 0], v[:, 0], g[:, 0],
                                   beta[:, 0])
         o = o[:, None]
@@ -183,6 +215,8 @@ def delta_mixer(cfg, h, w: dict, state, tail, live=None):
     out = ((gate * o.reshape(rows, q_len, heads * dv)).astype(dtype)
            @ w["w_out"].astype(dtype))
     if live is not None:
-        new_state = jnp.where(live[:, None, None, None], new_state, state)
+        if layer is None:  # the kernel left those rows as they were
+            new_state = jnp.where(live[:, None, None, None], new_state,
+                                  state)
         new_tail = jnp.where(live[:, None], new_tail, tail)
     return out, new_state, new_tail.astype(tail.dtype)

@@ -62,9 +62,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from kvedge_tpu.models import delta
+from kvedge_tpu.models import delta, ssm
 from kvedge_tpu.models.moe import ffn_activation, held_experts_ffn
-from kvedge_tpu.models.ssm import mamba_mixer, step_in_kernel
 from kvedge_tpu.models.transformer import TransformerConfig, _rmsnorm
 
 # Leaf numbers of the recipe: a leaf keeps its number when others are
@@ -84,9 +83,11 @@ _LEAVES = {
 }
 
 _KINDS = ("mamba", "delta", "attention", "ffn")
-# A recurrent kind's mixer and the scope that names it in a trace.
-_MIXERS = {"mamba": (mamba_mixer, "kvedge/ssm"),
-           "delta": (delta.delta_mixer, "kvedge/delta")}
+# A recurrent kind's mixer, its answer to whether a trace's one-token
+# form is its kernel, and the scope that names it in a trace.
+_MIXERS = {"mamba": (ssm.mamba_mixer, ssm.step_in_kernel, "kvedge/ssm"),
+           "delta": (delta.delta_mixer, delta.step_in_kernel,
+                     "kvedge/delta")}
 
 
 def layers_of(cfg: TransformerConfig, kind: str) -> list[int]:
@@ -296,13 +297,8 @@ def run_layers(cfg: TransformerConfig, params: dict, x, pools, recurrent,
         def put(state, layer, new):
             return state.at[layer, slot].set(new[0])
 
-    # A decode step on the chip: the mamba mixer's kernel works on the
-    # stacked state where it lies, so there is nothing to take or put.
-    in_kernel = (cfg.recurrent_kind == "mamba"
-                 and step_in_kernel(cfg, slot, x.shape[1]))
-
     def body(carry, xs):
-        x, pools, ssm, conv, picks = carry
+        x, pools, state, conv, picks = carry
         weights, period = xs
         seen = dict.fromkeys(pattern, 0)
         for j, kind in enumerate(pattern):
@@ -314,34 +310,37 @@ def run_layers(cfg: TransformerConfig, params: dict, x, pools, recurrent,
                         h, w, period * n_att + seen[kind], pools)
             else:
                 layer = period * n_recurrent + seen[kind]
-                mixer, scope = _MIXERS[kind]
+                mixer, in_kernel, scope = _MIXERS[kind]
                 with jax.named_scope(scope):
-                    if in_kernel:
-                        out, ssm, new_tail = mamba_mixer(
-                            cfg, h, w, ssm, take(conv, layer), live,
+                    if in_kernel(cfg, slot, x.shape[1]):
+                        # A decode step on the chip: the mixer's kernel
+                        # works on the stacked state where it lies, so
+                        # there is nothing to take or put.
+                        out, state, new_tail = mixer(
+                            cfg, h, w, state, take(conv, layer), live,
                             layer=layer)
                     else:
-                        out, new_ssm, new_tail = mixer(
-                            cfg, h, w, take(ssm, layer),
+                        out, new_state, new_tail = mixer(
+                            cfg, h, w, take(state, layer),
                             take(conv, layer), live)
-                        ssm = put(ssm, layer, new_ssm)
+                        state = put(state, layer, new_state)
                     conv = put(conv, layer, new_tail)
             seen[kind] += 1
             x = x + r * out
             x, layer_picks = feed_forward(cfg, x, at(weights["ffn"], j),
                                           live)
             picks = picks + layer_picks
-        return (x, pools, ssm, conv, picks), None
+        return (x, pools, state, conv, picks), None
 
     periods = cfg.n_layers // len(pattern)
     weights = {kind: params.get(kind, {}) for kind in _KINDS}
-    (x, pools, ssm, conv, picks), _ = lax.scan(
+    (x, pools, state, conv, picks), _ = lax.scan(
         body,
         (x, pools, recurrent["ssm"], recurrent["conv"],
          recurrent["picks"]),
         (weights, jnp.arange(periods, dtype=jnp.int32)),
     )
-    return x, pools, {"ssm": ssm, "conv": conv, "picks": picks}
+    return x, pools, {"ssm": state, "conv": conv, "picks": picks}
 
 
 def state_shape(cfg: TransformerConfig) -> tuple:

@@ -320,7 +320,8 @@ def _patterned_cell_program(program: str, chip, monkeypatch,
 
     monkeypatch.setattr(kvedge_tpu.ops, "pallas_interpret", lambda: False)
     # jax.default_backend() is the CPU here; on the chip the decode
-    # window's one-token SSM form is the kernel (ssm.step_in_kernel).
+    # window's one-token form is the recurrent kind's kernel
+    # (ssm.step_in_kernel, delta.step_in_kernel: both ask ssm._on_tpu).
     monkeypatch.setattr(ssm, "_on_tpu", lambda: True)
     cell = cellspec.load_cell(name)
     payload = cell.config["payload"]
@@ -435,9 +436,11 @@ def test_the_delta_cell_fits_and_leaves_its_state_where_it_is(
     same array a mamba layer keeps; the 32-step window and the prefill
     chunk need under 9.5 GB with no temporary of a layer's state
     (0.27 GB) or of a weight's size, and the state donated and updated
-    in place. The window attends through the paged kernel at a query
-    group of 8 (64 query heads); the delta mixer has no kernel: XLA's
-    own fusions read and write the state."""
+    in place. The window holds four kernels: the one-pass delta step
+    (ops/delta_step.py) once for each of the period's three delta
+    layers, handed the stacked state whole, and the paged attention
+    kernel at a query group of 8 (64 query heads). A prefill chunk, one
+    row's slot given, takes neither."""
     cfg, params, state, lowered = _patterned_cell_program(
         program, chip, monkeypatch, "solar-open2-250b.batchgen")
     leaves = jax.tree_util.tree_leaves(params)
@@ -455,7 +458,16 @@ def test_the_delta_cell_fits_and_leaves_its_state_where_it_is(
     text = compiled.as_text()
     window = program == "decode_window"
     assert text.count('custom_call_target="tpu_custom_call"') \
-        == (1 if window else 0)
+        == (4 if window else 0)
+    assert lowered.as_text().count('kernel_name = "delta_step"') \
+        == (3 if window else 0)
+    if window:
+        # Nothing but the kernel makes an array of the state's size: it
+        # comes in as a parameter and goes through the three calls.
+        makers = set(re.findall(
+            r" = f32\[3,64,64,128,128\]\{[^}]*\} ([\w-]+)\(", text))
+        assert makers <= {"custom-call", "parameter", "get-tuple-element"}, \
+            makers
     memory = compiled.memory_analysis()
     needs = (memory.argument_size_in_bytes + memory.output_size_in_bytes
              - memory.alias_size_in_bytes + memory.temp_size_in_bytes)

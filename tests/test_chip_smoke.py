@@ -126,6 +126,35 @@ def test_the_delta_op_phase_holds_both_forms_to_the_recurrence(
             chip_smoke.phase_delta_op(smoke)
 
 
+def test_the_delta_op_phase_holds_the_kernel_to_the_one_token_form(
+        tmp_path, capsys, monkeypatch):
+    """The phase's second half: the one-pass step kernel (interpreted
+    here, compiled on the chip) against ``_one_token`` on the stacked
+    state, and a kernel that writes a row it was not asked to fails the
+    smoke."""
+    from kvedge_tpu.ops import delta_step
+
+    with chip_smoke.CompileMeter() as meter:
+        smoke = chip_smoke.Smoke(PROBE, chips=1, platform="cpu", seed=0,
+                                 workdir=str(tmp_path), meter=meter)
+        chip_smoke.phase_delta_op(smoke)
+        entry = smoke.report["phases"]["delta-op"]
+        assert entry["untouched_moved"] == 0
+        assert entry["elements"] == 3 * 4 * 128 * 128
+        assert entry["state_rel_gap"] <= 2e-6 and entry["o_rel_gap"] <= 1e-5
+        assert "delta op, kernel vs plain one-token form at 4 rows" \
+            in capsys.readouterr().out
+        real = delta_step.delta_step
+
+        def every_row_live(state, layer, *now, **kw):
+            return real(state, layer, *now[:-1], None, **kw)
+
+        monkeypatch.setattr(delta_step, "delta_step", every_row_live)
+        with pytest.raises(chip_smoke.SmokeFailure,
+                           match="not asked to touch"):
+            chip_smoke.phase_delta_op(smoke)
+
+
 def test_boot_once_on_a_degraded_runtime_exits_nonzero(tmp_path):
     """``kvedge-runtime boot --once`` has no /status reader, only an
     exit code: a degraded check must fail the command. The
