@@ -177,13 +177,18 @@ class ModelSpec:
     # parallel/pipeline1f1b.py documents the refusals).
     pipeline_schedule: str = ""
     # A patterned block (models/hybrid.py; SERVING.md "Recurrent
-    # state"): one period of layer kinds, "attention" and one
-    # recurrent kind ("mamba" or "delta", sized by the ``ssm_*`` keys),
+    # state", "Two page pools"): one period of layer kinds, "attention",
+    # "window" (attention over the last ``attention_window`` positions,
+    # always rotary, its keys and values in a page pool of its own) and
+    # at most one recurrent kind ("mamba" or "delta", sized by the
+    # ``ssm_*`` keys),
     # repeated to ``n_layers``. () = every layer rotary attention with a
     # GELU feed-forward, the block above. With a pattern every layer's
     # feed-forward is ``experts`` routed experts of width ``d_ff``,
     # ``expert_top_k`` (any number) a token, plus a shared expert of
-    # width ``shared_ff``, SiLU-gated when ``ffn_gated``; this device
+    # width ``shared_ff`` (0 = none), gated when ``ffn_gated`` by
+    # ``ffn_activation`` ("" = "silu"; "relu"); ``router_before_mixer``
+    # routes on the mixer's normed input; this device
     # holds ``experts_held`` of them (0 = all) from ``expert_first`` on.
     # ``head_dim`` is the attention heads' size (0 = d_model / n_heads),
     # ``attention_gate`` an output gate on the attention layers,
@@ -210,13 +215,19 @@ class ModelSpec:
     logits_scaling: float = 0.0
     rotary: bool = True
     norm_eps: float = 0.0  # 0.0 = 1e-6
+    attention_window: int = 0
+    router_before_mixer: bool = False
+    ffn_activation: str = ""  # "" = "silu"
+    # The rotary base, for the plain block too (0.0 = 10,000).
+    rope_theta: float = 0.0
 
     _PATTERN_INTS = (
         "ssm_heads", "ssm_head_dim", "ssm_state", "ssm_conv", "ssm_chunk",
         "experts_held", "expert_first", "shared_ff",
-        "ssm_gate_rank", "head_dim",
+        "ssm_gate_rank", "head_dim", "attention_window",
     )
-    _PATTERN_BOOLS = ("ffn_gated", "attention_gate", "untied_head")
+    _PATTERN_BOOLS = ("ffn_gated", "attention_gate", "untied_head",
+                      "router_before_mixer")
     _PATTERN_FLOATS = (
         "embedding_multiplier", "residual_multiplier",
         "attention_multiplier", "logits_scaling", "norm_eps",
@@ -225,7 +236,8 @@ class ModelSpec:
     # Keys a document states only where they are set: a block without
     # them keeps the document it had before they existed.
     _PATTERN_LATER = ("ssm_gate_rank", "head_dim", "attention_gate",
-                      "untied_head")
+                      "untied_head", "attention_window",
+                      "router_before_mixer")
 
     def validate(self) -> None:
         if self.preset not in _VALID_PRESETS:
@@ -254,7 +266,7 @@ class ModelSpec:
         if not all(isinstance(kind, str) for kind in self.layer_pattern):
             raise RuntimeConfigError(
                 "[model] layer_pattern must be a list of \"mamba\", "
-                "\"delta\" and \"attention\"")
+                "\"delta\", \"attention\" and \"window\"")
         for field_name in self._PATTERN_INTS:
             value = getattr(self, field_name)
             if not isinstance(value, int) or isinstance(value, bool) \
@@ -266,8 +278,17 @@ class ModelSpec:
                 raise RuntimeConfigError(
                     f"[model] {field_name} must be >= 0 (0 = the plain "
                     "block's)")
+        if self.ffn_activation not in ("", "silu", "relu"):
+            raise RuntimeConfigError(
+                "[model] ffn_activation must be \"silu\" or \"relu\" "
+                f"(\"\" = silu), got {self.ffn_activation!r}")
+        if self.rope_theta < 0:
+            raise RuntimeConfigError(
+                "[model] rope_theta must be >= 0 (0 = 10,000)")
         if not self.layer_pattern:
             stray = [k for k in self._PATTERN_KEYS if getattr(self, k)]
+            if self.ffn_activation:
+                stray.append("ffn_activation")
             if stray or not self.rotary:
                 raise RuntimeConfigError(
                     "[model] " + ", ".join(stray or ["rotary = false"])
@@ -670,6 +691,9 @@ class RuntimeConfig:
                     layer_pattern=tuple(
                         model_doc.get("layer_pattern", ())),
                     rotary=bool(model_doc.get("rotary", True)),
+                    ffn_activation=str(
+                        model_doc.get("ffn_activation", "")),
+                    rope_theta=float(model_doc.get("rope_theta", 0.0)),
                     **{key: bool(model_doc.get(key, False))
                        for key in ModelSpec._PATTERN_BOOLS},
                     **{key: int(model_doc.get(key, 0))
@@ -1180,17 +1204,32 @@ class RuntimeConfig:
             raise RuntimeConfigError(
                 "[model] layer_pattern needs [payload] serving = "
                 "\"paged\": the contiguous cache has no patterned block")
+        window = "window" in self.model.layer_pattern
         if self.serving_prefix_cache:
             raise RuntimeConfigError(
                 "[payload] serving_prefix_cache = true cannot serve a "
-                "model with [model] layer_pattern: a recurrent state "
-                "holds a row's whole prefix in one array and cannot be "
-                "shared by page; set serving_prefix_cache = false")
+                "model with [model] layer_pattern: " + (
+                    "a shared page that a \"window\" layer has given "
+                    "back cannot be attended again" if window else
+                    "a recurrent state holds a row's whole prefix in one "
+                    "array and cannot be shared by page")
+                + "; set serving_prefix_cache = false")
         if self.serving_speculative != 0:
             raise RuntimeConfigError(
                 "[payload] serving_speculative must be 0 for a model "
-                "with [model] layer_pattern: a recurrent state cannot "
-                "be rewound past the drafts a verify pass rejects")
+                "with [model] layer_pattern: " + (
+                    "a drafted position's page that a \"window\" layer "
+                    "has given back cannot be attended again"
+                    if window else
+                    "a recurrent state cannot be rewound past the drafts "
+                    "a verify pass rejects"))
+        if window and self.serving_kv_dtype == "int8":
+            raise RuntimeConfigError(
+                "[payload] serving_kv_dtype = \"int8\" cannot serve a "
+                "model with \"window\" layers in [model] layer_pattern: "
+                "the window layers' pool is held in the compute dtype "
+                "only")
+
 
     def _pattern_toml(self) -> str:
         """The patterned block's ``[model]`` keys; nothing for the plain
@@ -1201,6 +1240,8 @@ class RuntimeConfig:
         kinds = ", ".join(_toml_str(kind) for kind in m.layer_pattern)
         lines = [f"layer_pattern = [{kinds}]",
                  f"rotary = {str(m.rotary).lower()}"]
+        if m.ffn_activation:
+            lines.append(f"ffn_activation = {_toml_str(m.ffn_activation)}")
         for key in m._PATTERN_KEYS:
             value = getattr(m, key)
             if key in m._PATTERN_LATER and not value:
@@ -1242,6 +1283,10 @@ class RuntimeConfig:
             f"expert_top_k = {self.model.expert_top_k}\n"
             f"expert_capacity_factor = {self.model.expert_capacity_factor}\n"
             f"pipeline_schedule = {s(self.model.pipeline_schedule)}\n"
+            # stated only where it is set: a document without it is
+            # the document it was
+            + (f"rope_theta = {self.model.rope_theta!r}\n"
+               if self.model.rope_theta else "")
             + self._pattern_toml() +
             "\n[distributed]\n"
             f"num_processes = {self.distributed.num_processes}\n"

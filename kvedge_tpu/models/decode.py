@@ -100,8 +100,8 @@ def _attend_layer(cfg: TransformerConfig, x, layer_params, k_slab, v_slab,
     normed = _rmsnorm(x, ln_attn)
     q, k, v = split_qkv(cfg, normed @ w_qkv.astype(dtype))
     positions = pos + jnp.arange(q_len)
-    q = _rotary(q, positions)
-    k = _rotary(k, positions)
+    q = _rotary(q, positions, cfg.rope_theta)
+    k = _rotary(k, positions, cfg.rope_theta)
 
     k_slab = lax.dynamic_update_slice(k_slab, k, (0, pos, 0, 0))
     v_slab = lax.dynamic_update_slice(v_slab, v, (0, pos, 0, 0))

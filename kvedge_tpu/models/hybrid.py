@@ -1,6 +1,7 @@
-"""The patterned block: recurrent mixers (Mamba-2, or a delta rule)
-and attention mixers in a repeating pattern of layers, every layer with
-routed and shared gated experts.
+"""The patterned block: recurrent mixers (Mamba-2, or a delta rule),
+attention mixers and attention mixers bound to a window in a repeating
+pattern of layers, every layer with routed and, where the model has
+one, shared gated experts.
 
 Written once, for the paged serving path (models/kvcache.py runs it
 from ``_run_paged``); the trainer, the contiguous cache and a mesh of
@@ -10,7 +11,7 @@ several devices refuse a model with a ``layer_pattern``. The equations
 ``cfg.norm_eps``):
 
     x = e * E[tokens]
-    per layer:  x = x + r * Mixer(norm(x));  h = norm(x)
+    per layer:  a = norm(x);  x = x + r * Mixer(a);  h = norm(x)
                 x = x + r * (Routed(h) + Shared(h))
     logits = norm(x) @ E.T / s          (``head.T`` for ``E.T`` where the
                                          tree has a head of its own)
@@ -20,16 +21,26 @@ mixer ("mamba") or models/delta.py's delta rule ("delta"; a pattern
 holds one of the two), or kvcache's paged attention (no rotary when
 ``cfg.rotary`` is false, scores scaled by ``cfg.attention_multiplier``,
 ``sigmoid(h W_gate)`` times what it attended where the layer has a
-``w_gate``); ``Routed`` is moe.held_experts_ffn over the experts this
-device holds; ``Shared`` a gated SiLU MLP every token passes.
+``w_gate``), or the same attention bound to a window ("window": rotary
+always, base ``cfg.rope_theta``, query i over keys i -
+``cfg.attention_window`` + 1 to i, keys and values in the window
+layers' own pool; a pattern may hold both kinds, and need hold no
+recurrent one); ``Routed`` is moe.held_experts_ffn over the experts
+this device holds, its picks read off ``h`` or, with
+``cfg.router_before_mixer``, off ``a``; ``Shared`` a gated MLP every
+token passes (``cfg.shared_ff`` 0: none); the gate is SiLU, or ReLU
+where ``cfg.ffn_activation`` says so.
 
 **Weights** are a named tree per layer kind, every leaf stacked over
 ``[periods, layers of that kind in a period, ...]``:
 ``params["mamba"]`` or ``params["delta"]``, ``params["attention"]``,
+``params["window"]``,
 ``params["ffn"]`` (one entry per layer of the period), beside
 ``embedding``, ``ln_final`` and, with ``cfg.untied_head``, ``head``.
 The layer loop scans over periods and runs the period's layers in its
-body, so one period's program is compiled whatever the depth.
+body, so one period's program is compiled whatever the depth; the body
+slices each layer's leaves out of the stacked tree itself, by period
+and place (``run_layers``).
 
 **The initialiser draws leaf by leaf, layer by layer, in the serving
 dtype** (:func:`init_params`): each leaf is one jitted call that maps
@@ -80,9 +91,12 @@ _LEAVES = {
     ("delta", "w_f2"): 17, ("delta", "w_g2"): 18, ("delta", "A_log"): 19,
     ("delta", "dt_bias"): 20, ("delta", "w_out"): 21,
     ("attention", "w_gate"): 22, "head": 23,
+    ("window", "w_qkv"): 24, ("window", "w_out"): 25,
 }
 
-_KINDS = ("mamba", "delta", "attention", "ffn")
+_KINDS = ("mamba", "delta", "attention", "window", "ffn")
+# The kinds whose mixer is paged attention, each over a pool of its own.
+ATTENTION_KINDS = ("attention", "window")
 # A recurrent kind's mixer, its answer to whether a trace's one-token
 # form is its kernel, and the scope that names it in a trace.
 _MIXERS = {"mamba": (ssm.mamba_mixer, ssm.step_in_kernel, "kvedge/ssm"),
@@ -156,6 +170,13 @@ def _recipes(cfg: TransformerConfig) -> dict:
         if cfg.attention_gate:
             out[("attention", "w_gate")] = (
                 (d, h * dh), _normal(d ** -0.5), False)
+    if "window" in cfg.layer_pattern:
+        out.update({
+            ("window", "w_qkv"): ((d, (h + 2 * kv) * dh),
+                                  _normal(d ** -0.5), False),
+            ("window", "w_out"): ((h * dh, d),
+                                  _normal((h * dh) ** -0.5), False),
+        })
     out.update({
         ("ffn", "router"): ((d, cfg.n_experts), _normal(d ** -0.5), False),
         ("ffn", "experts_in"): ((cfg.held_experts, d, gate * f),
@@ -220,8 +241,9 @@ def init_params(key, cfg: TransformerConfig) -> dict:
     if params["delta"]:
         ones("delta", "norm", cfg.ssm_head_dim)
         ones("delta", "ln", cfg.d_model)
-    if params["attention"]:
-        ones("attention", "ln", cfg.d_model)
+    for kind in ATTENTION_KINDS:
+        if params[kind]:
+            ones(kind, "ln", cfg.d_model)
     ones("ffn", "ln", cfg.d_model)
     def table(leaf, scale):
         return jax.jit(
@@ -238,13 +260,16 @@ def init_params(key, cfg: TransformerConfig) -> dict:
 
 
 def _shared_expert(cfg: TransformerConfig, h, w_in, w_out):
-    act = ffn_activation(h @ w_in.astype(h.dtype), cfg.ffn_gated)
+    act = ffn_activation(h @ w_in.astype(h.dtype), cfg.ffn_gated,
+                         cfg.ffn_activation)
     return act @ w_out.astype(h.dtype)
 
 
-def feed_forward(cfg: TransformerConfig, x, w: dict, live):
+def feed_forward(cfg: TransformerConfig, x, w: dict, live, routed_on=None):
     """``x + r * (Routed(norm(x)) + Shared(norm(x)))`` over x [R, Q, D]
-    and the picks of the ``live`` rows' tokens (held_experts_ffn)."""
+    and the picks of the ``live`` rows' tokens (held_experts_ffn).
+    ``routed_on`` [R, Q, D], where given, is what the router reads
+    (``cfg.router_before_mixer``: the mixer's normed input)."""
     rows, q_len, d = x.shape
     with jax.named_scope("kvedge/experts"):
         h = _rmsnorm(x, w["ln"], cfg.norm_eps).reshape(rows * q_len, d)
@@ -252,7 +277,10 @@ def feed_forward(cfg: TransformerConfig, x, w: dict, live):
             h, w["router"], w["experts_in"], w["experts_out"],
             top_k=cfg.expert_top_k, first=cfg.expert_first,
             gated=cfg.ffn_gated, renormalize=True,
-            live=None if live is None else jnp.repeat(live, q_len))
+            live=None if live is None else jnp.repeat(live, q_len),
+            activation=cfg.ffn_activation,
+            routed_on=(None if routed_on is None
+                       else routed_on.reshape(rows * q_len, d)))
         if "shared_in" in w:
             out = out + _shared_expert(cfg, h, w["shared_in"],
                                        w["shared_out"])
@@ -263,24 +291,23 @@ def feed_forward(cfg: TransformerConfig, x, w: dict, live):
 def run_layers(cfg: TransformerConfig, params: dict, x, pools, recurrent,
                attend, slot, live):
     """The layer loop of a patterned block: a scan over periods, the
-    period's layers in its body. ``pools`` (the page pool's 4-tuple)
-    and ``recurrent`` (``ssm`` [recurrent layers, slots, a layer's
-    state: :func:`state_shape`], ``conv`` [recurrent layers, slots,
-    (K-1)*C], ``picks``) ride the carry whole and
-    are updated in place. ``attend(h, w, layer, pools) -> (out, pools)``
-    is the caller's paged attention over normed activations, ``layer``
-    its index into the pool. ``x``'s rows are the first R slots, or,
+    period's layers in its body. ``pools`` (kind -> that kind's page
+    pool, a 4-tuple, for each of :data:`ATTENTION_KINDS` the pattern
+    holds) and ``recurrent`` (``ssm`` [recurrent layers, slots, a
+    layer's state: :func:`state_shape`], ``conv`` [recurrent layers,
+    slots, (K-1)*C], both absent where the pattern has no recurrent
+    kind, and ``picks``) ride the carry whole and
+    are updated in place. ``attend(h, w, layer, pool, kind) -> (out,
+    pool)`` is the caller's paged attention over normed activations,
+    ``layer`` its index into ``kind``'s pool. ``x``'s rows are the first R slots, or,
     with ``slot`` given, that one prefilling slot; ``live`` [R] says
     which of them advance (None = all). Returns
     ``(x, pools, recurrent)``.
     """
     pattern = cfg.layer_pattern
-    n_recurrent = len(pattern) - pattern.count("attention")
-    n_att = pattern.count("attention")
+    n_of = {kind: pattern.count(kind) for kind in ATTENTION_KINDS}
+    n_recurrent = len(pattern) - sum(n_of.values())
     r = jnp.asarray(cfg.residual_multiplier, x.dtype)
-
-    def at(tree, i):
-        return jax.tree_util.tree_map(lambda a: a[i], tree)
 
     if slot is None:
         n_rows = x.shape[0]
@@ -297,17 +324,31 @@ def run_layers(cfg: TransformerConfig, params: dict, x, pools, recurrent,
         def put(state, layer, new):
             return state.at[layer, slot].set(new[0])
 
-    def body(carry, xs):
+    def at(tree, period, i):
+        """Layer ``i`` of period ``period`` of every stacked leaf, sliced
+        where it is used. As the scan's ``xs`` a whole period's leaves
+        were sliced out at the top of the body, and with more periods
+        than one the chip's compiler made that slice a copy: 1.9 GB of
+        expert matrices a period and step at 64 experts of 2,560 x
+        1,536 in 4 layers (tests/test_chip_compile.py holds the
+        window's temporaries under a layer of a pool)."""
+        return jax.tree_util.tree_map(
+            lambda a: lax.dynamic_slice(
+                a, (period, i) + (0,) * (a.ndim - 2),
+                (1, 1) + a.shape[2:]).reshape(a.shape[2:]), tree)
+
+    def body(carry, period):
         x, pools, state, conv, picks = carry
-        weights, period = xs
         seen = dict.fromkeys(pattern, 0)
         for j, kind in enumerate(pattern):
-            w = at(weights[kind], seen[kind])
+            w = at(weights[kind], period, seen[kind])
             h = _rmsnorm(x, w["ln"], cfg.norm_eps)
-            if kind == "attention":
-                with jax.named_scope("kvedge/attention"):
-                    out, pools = attend(
-                        h, w, period * n_att + seen[kind], pools)
+            if kind in ATTENTION_KINDS:
+                with jax.named_scope("kvedge/" + kind):
+                    out, pool = attend(
+                        h, w, period * n_of[kind] + seen[kind],
+                        pools[kind], kind)
+                pools = {**pools, kind: pool}
             else:
                 layer = period * n_recurrent + seen[kind]
                 mixer, in_kernel, scope = _MIXERS[kind]
@@ -327,8 +368,9 @@ def run_layers(cfg: TransformerConfig, params: dict, x, pools, recurrent,
                     conv = put(conv, layer, new_tail)
             seen[kind] += 1
             x = x + r * out
-            x, layer_picks = feed_forward(cfg, x, at(weights["ffn"], j),
-                                          live)
+            x, layer_picks = feed_forward(
+                cfg, x, at(weights["ffn"], period, j), live,
+                h if cfg.router_before_mixer else None)
             picks = picks + layer_picks
         return (x, pools, state, conv, picks), None
 
@@ -336,10 +378,12 @@ def run_layers(cfg: TransformerConfig, params: dict, x, pools, recurrent,
     weights = {kind: params.get(kind, {}) for kind in _KINDS}
     (x, pools, state, conv, picks), _ = lax.scan(
         body,
-        (x, pools, recurrent["ssm"], recurrent["conv"],
+        (x, pools, recurrent.get("ssm"), recurrent.get("conv"),
          recurrent["picks"]),
-        (weights, jnp.arange(periods, dtype=jnp.int32)),
+        jnp.arange(periods, dtype=jnp.int32),
     )
+    if state is None:
+        return x, pools, {"picks": picks}
     return x, pools, {"ssm": state, "conv": conv, "picks": picks}
 
 
@@ -357,14 +401,18 @@ def fresh_recurrent(cfg: TransformerConfig, slots: int) -> dict:
     """Zeroed recurrent state for ``slots`` rows: the float32 state of
     the pattern's recurrent kind (:func:`state_shape`), the conv's tail
     in the compute dtype, flat so that its minor dimension is whole
-    lanes, and the window's pick counters (moe.held_experts_ffn)."""
+    lanes, and the window's pick counters (moe.held_experts_ffn); the
+    counters alone where the pattern has no recurrent kind."""
+    picks = jnp.zeros((3 + cfg.held_experts,), jnp.int32)
+    if not cfg.recurrent_kind:
+        return {"picks": picks}
     layers = cfg.ssm_layers
     shape, channels = state_shape(cfg)
     return {
         "ssm": jnp.zeros((layers, slots, *shape), jnp.float32),
         "conv": jnp.zeros((layers, slots, (cfg.ssm_conv - 1) * channels),
                           jnp.dtype(cfg.dtype)),
-        "picks": jnp.zeros((3 + cfg.held_experts,), jnp.int32),
+        "picks": picks,
     }
 
 

@@ -78,8 +78,22 @@ class PagedState:
     # fresh_recurrent): per recurrent layer and SLOT (not bucket row:
     # it cannot be rebuilt from the host as tables are) the float32
     # state and the conv's tail, and the window's expert-pick counters.
-    # The pool then holds the attention layers only. None otherwise.
+    # The pool then holds the full attention layers only ("attention"
+    # in the pattern; the "window" layers' live below). None otherwise.
     recurrent: "dict | None" = None
+    # A block with layers bound to a window (``"window"`` in
+    # ``cfg.layer_pattern``) keeps those layers' keys and values in a
+    # pool of their own, ``[window layers, P_w, page, K*Dh]``, under a
+    # table of their own, ``[B, cap]``: a row holds the pages that carry
+    # its last ``cfg.attention_window`` positions and has given the
+    # rest back, so its table starts at position ``win_first[b]`` (a
+    # multiple of the page) and not at 0. The pool above then holds the
+    # full layers only. None otherwise, and every program is the
+    # program it was.
+    win_pool_k: "jax.Array | None" = None  # [Lw, P_w, page, K*Dh]
+    win_pool_v: "jax.Array | None" = None
+    win_tables: "jax.Array | None" = None  # [B, cap] int32 page ids
+    win_first: "jax.Array | None" = None   # [B] int32 positions
 
     @property
     def page_size(self) -> int:
@@ -118,7 +132,11 @@ _PAGED_KERNEL_AUTO_MIN_SEQ = 2048
 def _use_paged_kernel(cfg: TransformerConfig, page_size: int,
                       width: int, max_pages: int | None = None) -> bool:
     """Resolve ``cfg.paged_attention`` at trace time (page_size/width/
-    max_pages are static pool-shape facts under jit). "auto" picks the
+    max_pages are static pool-shape facts under jit), for one kind of
+    attention layer: ``max_pages`` is the width of that kind's table (a
+    full layer's spans ``max_seq``, a window layer's the window and one
+    advance: PagedKVCache.window_cap), so a block with both kinds
+    settles each apart, by its own scratch. "auto" picks the
     Pallas block-table kernel where it wins: TPU, long-context caps
     (max_seq >= 2048), page_size % 128 == 0 (each page's score columns
     land at lane offset j * page in the kernel's phase-2 scratch, which
@@ -226,7 +244,8 @@ class PagedKVCache:
 
     def __init__(self, cfg: TransformerConfig, *, slots: int, pages: int,
                  page_size: int = 16, max_pages_per_seq: int | None = None,
-                 kv_dtype: str = "", min_bucket: int = 0):
+                 kv_dtype: str = "", min_bucket: int = 0,
+                 window_advance: int = 0):
         from kvedge_tpu.models.moe import warn_if_train_serve_divergence
 
         cfg.validate()
@@ -285,6 +304,32 @@ class PagedKVCache:
                 )
         dtype = jnp.int8 if self.kv_quantized else jnp.dtype(cfg.dtype)
         shape = (cfg.kv_layers, pages, page_size, cfg.kv_heads * cfg.d_head)
+        # The window layers' pool (PagedState.win_pool_k; None of this
+        # where the block has no such layer). ``window_advance`` is the
+        # most positions a row moves on between two givings-back (a
+        # prefill chunk, a decode window; 0 = up to ``max_seq``), which
+        # with the window bounds the pages a row ever holds:
+        # :attr:`window_cap`, the width of its table. The pool holds
+        # every slot's cap, so it never holds an admission back.
+        self.window = cfg.attention_window if cfg.window_layers else 0
+        self.window_cap = 0
+        self.num_window_pages = 0
+        if self.window:
+            if self.kv_quantized:
+                raise ValueError(
+                    "kv_dtype = 'int8' with 'window' layers in "
+                    "layer_pattern: the window layers' pool is held in "
+                    "the compute dtype only")
+            self.window_cap = min(
+                self.max_pages_per_seq,
+                -(-(self.window + (window_advance or cfg.max_seq))
+                  // page_size) + 1)
+            self.num_window_pages = slots * self.window_cap
+        self._wfree: list[int] = list(range(self.num_window_pages))[::-1]
+        self._wpages_of: dict[int, list[int]] = {}
+        self._wfirst = [0] * slots  # position of each table's first page
+        self._host_wtables = [[0] * self.window_cap for _ in range(slots)]
+        self.window_pages_released = 0
         self.state = self._init_state(shape, dtype)
         # What the window programs counted of the routed experts' picks
         # (a patterned block): [all, on held experts, each held expert,
@@ -298,8 +343,11 @@ class PagedKVCache:
 
             self.expert_picks = _np.zeros(3 + cfg.held_experts, _np.int64)
         # A phase of the serving layer's clock around the state reset
-        # of an admission (``admit/state_reset``), when it has one.
+        # of an admission (``admit/state_reset``), when it has one, and
+        # around giving window pages back after a prefill chunk
+        # (``admit/window_release``).
         self.reset_phase = contextlib.nullcontext
+        self.window_phase = contextlib.nullcontext
         self._free: list[int] = list(range(pages))[::-1]  # pop() -> lowest last
         self._pages_of: dict[int, list[int]] = {}
         self._host_tables = [
@@ -378,6 +426,20 @@ class PagedKVCache:
             scale_k=scale(),
             scale_v=scale(),
             recurrent=self._init_recurrent(),
+            **self._init_window(shape, dtype),
+        )
+
+    def _init_window(self, shape, dtype) -> dict:
+        """The window layers' zeroed pool and table (nothing where the
+        block has no such layer)."""
+        if not self.window:
+            return {}
+        wshape = (self.cfg.window_layers, self.num_window_pages) + shape[2:]
+        return dict(
+            win_pool_k=jnp.zeros(wshape, dtype),
+            win_pool_v=jnp.zeros(wshape, dtype),
+            win_tables=jnp.zeros((self.bucket, self.window_cap), jnp.int32),
+            win_first=jnp.zeros((self.bucket,), jnp.int32),
         )
 
     def _init_recurrent(self):
@@ -466,6 +528,13 @@ class PagedKVCache:
     def free_pages(self) -> int:
         return len(self._free)
 
+    def free_window_pages(self) -> int:
+        return len(self._wfree)
+
+    def window_pages_held(self, slot: int) -> int:
+        """Pages of the window layers' pool in ``slot``'s table."""
+        return len(self._wpages_of.get(slot, ()))
+
     def page_accounting(self) -> dict:
         """Full-pool page census for the conservation audit
         (``serving_debug_pages`` and the chaos soak's invariant 1).
@@ -477,7 +546,24 @@ class PagedKVCache:
         no negative refcounts, and no page both free and referenced.
         Pure host bookkeeping: no device work, safe at any boundary."""
         free_set = set(self._free)
+        window = {}
+        if self.window:
+            # The window layers' pool: a page is on its free list or in
+            # exactly one row's table (nothing shares a window page).
+            held = [p for pages in self._wpages_of.values() for p in pages]
+            window = {
+                "window_free": len(self._wfree),
+                "window_live": len(set(held)),
+                "window_pages_total": self.num_window_pages,
+                "window_free_dup": len(self._wfree) - len(set(self._wfree)),
+                "window_held_dup": len(held) - len(set(held)),
+                "window_free_live": len(set(self._wfree) & set(held)),
+                "window_over_cap": sum(
+                    1 for pages in self._wpages_of.values()
+                    if len(pages) > self.window_cap),
+            }
         return {
+            **window,
             "free": len(self._free),
             "live": sum(1 for r in self._refs if r > 0),
             "pages_total": self.num_pages,
@@ -608,8 +694,13 @@ class PagedKVCache:
         for i, page in enumerate(self._pages_of[slot]):
             row[i] = page
         self._host_lengths[slot] = prompt_len
+        if self.window:
+            # No window page yet: a prefill chunk brings its own
+            # (prefill_chunk), a resumed row its snapshot's (swapin_slot).
+            self._wpages_of[slot] = []
+            self._wfirst[slot] = 0
         self._sync()
-        if self.state.recurrent is not None:
+        if self.state.recurrent is not None and "ssm" in self.state.recurrent:
             # Whoever had the slot left its state there (release needs
             # nothing): a row starts from zeros, or from what
             # swapin_row writes over them.
@@ -657,7 +748,67 @@ class PagedKVCache:
             pages.append(page)
             self._host_tables[slot][len(pages) - 1] = page
             grew = True
+        if self.window:
+            # What lies wholly behind the window of the next query goes
+            # first, so a row that advances by no more than
+            # ``window_advance`` between two calls never passes its cap.
+            grew |= bool(self._window_trim(slot, length))
+            grew |= self._window_grow(slot, length + n)
         return grew
+
+    # ---- the window layers' pages (host) --------------------------------
+
+    def _window_grow(self, slot: int, upto: int) -> bool:
+        """Pages for the window layers' keys at positions below
+        ``upto``, appended to the slot's table. True iff any was."""
+        pages = self._wpages_of[slot]
+        first, grew = self._wfirst[slot], False
+        while first + len(pages) * self.page_size < upto:
+            if len(pages) == self.window_cap:
+                raise PagedCacheError(
+                    f"slot {slot} would hold more than its cap of "
+                    f"{self.window_cap} window pages: it advanced by "
+                    "more than the window_advance the pool was sized for")
+            page = self._wfree.pop()
+            self._host_wtables[slot][len(pages)] = page
+            pages.append(page)
+            grew = True
+        return grew
+
+    def _window_trim(self, slot: int, next_pos: int) -> int:
+        """Give back the slot's window pages on which every position
+        lies more than ``window - 1`` behind ``next_pos``, the next
+        query's: no query of the row sees them again. Nothing is
+        copied: the table shifts and its first position moves on.
+        Returns the pages given back (the caller syncs)."""
+        pages = self._wpages_of[slot]
+        drop = min(len(pages),
+                   max(0, (next_pos - self.window + 1) // self.page_size
+                       - self._wfirst[slot] // self.page_size))
+        if not drop:
+            return 0
+        self._wfree.extend(pages[:drop])
+        del pages[:drop]
+        self._wfirst[slot] += drop * self.page_size
+        self._host_wtables[slot] = (
+            pages + [0] * (self.window_cap - len(pages)))
+        self.window_pages_released += drop
+        return drop
+
+    def release_window_pages(self, slots) -> int:
+        """Give back, for each of ``slots`` (rows whose harvested
+        window moved them on), the window pages its next query no
+        longer sees; one upload of the table if any went. Returns the
+        pages given back."""
+        if not self.window:
+            return 0
+        dropped = 0
+        for slot in slots:
+            if slot in self._wpages_of:
+                dropped += self._window_trim(slot, self._host_lengths[slot])
+        if dropped:
+            self._sync_window()
+        return dropped
 
     def release(self, slot: int) -> None:
         """Finish a sequence: drop its references (pages free at 0)."""
@@ -667,6 +818,10 @@ class PagedKVCache:
             self._unref(page)
         self._host_tables[slot] = [0] * self.max_pages_per_seq
         self._host_lengths[slot] = 0
+        if self.window:
+            self._wfree.extend(self._wpages_of.pop(slot))
+            self._host_wtables[slot] = [0] * self.window_cap
+            self._wfirst[slot] = 0
         # A released slot's device length must drop to 0 even while
         # other slots' spec windows are in flight (the merge in _sync
         # keeps only UNHARVESTED slots' device lengths).
@@ -692,6 +847,18 @@ class PagedKVCache:
             self.state,
             tables=jnp.asarray(self._host_tables[:b], jnp.int32),
             lengths=lengths,
+        )
+        if self.window:
+            self._sync_window()
+
+    def _sync_window(self) -> None:
+        """The window layers' table and each row's first position, from
+        the host's mirrors."""
+        b = self.bucket
+        self.state = dataclasses.replace(
+            self.state,
+            win_tables=jnp.asarray(self._host_wtables[:b], jnp.int32),
+            win_first=jnp.asarray(self._wfirst[:b], jnp.int32),
         )
 
     # ---- data plane (device) --------------------------------------------
@@ -808,9 +975,14 @@ class PagedKVCache:
         """Bytes of one slot's recurrent state (0 where none is kept):
         what :meth:`swapout_row` adds to a swap snapshot."""
         rec = self.state.recurrent
-        if rec is None:
-            return 0
-        return (rec["ssm"].nbytes + rec["conv"].nbytes) // self.slots
+        n = 0
+        if rec is not None and "ssm" in rec:
+            n = (rec["ssm"].nbytes + rec["conv"].nbytes) // self.slots
+        if self.window:
+            # at most a cap's worth of window pages travels with a row
+            pool = self.state.win_pool_k
+            n += 2 * self.window_cap * (pool.nbytes // pool.shape[1])
+        return n
 
     def swapout_row(self, slot: int) -> tuple:
         """Host copies of slot ``slot``'s recurrent state exactly as
@@ -821,12 +993,24 @@ class PagedKVCache:
         pages are."""
         import numpy as np
 
-        if self.state.recurrent is None:
-            return ()
-        from kvedge_tpu.models.hybrid import gather_rows
+        out = ()
+        rec = self.state.recurrent
+        if rec is not None and "ssm" in rec:
+            from kvedge_tpu.models.hybrid import gather_rows
 
-        return tuple(np.asarray(a) for a in gather_rows(
-            self.state.recurrent, jnp.asarray(slot, jnp.int32)))
+            out = tuple(np.asarray(a) for a in gather_rows(
+                rec, jnp.asarray(slot, jnp.int32)))
+        if self.window:
+            # The window layers' pages that hold the row's positions up
+            # to its length, as stored, and where its table starts: a
+            # row comes back to the window it left with.
+            first = self._wfirst[slot]
+            n = -(-(self._host_lengths[slot] - first) // self.page_size)
+            ids = jnp.asarray(self._wpages_of[slot][:max(n, 0)], jnp.int32)
+            out += (np.asarray(first, np.int32),) + tuple(
+                np.asarray(a) for a in _gather_window_pages(
+                    self.state, ids, self.cfg.kv_heads))
+        return out
 
     def swapin_slot(self, slot: int, arrays: tuple,
                     skip_pages: int = 0) -> None:
@@ -836,18 +1020,34 @@ class PagedKVCache:
         :meth:`swapout_row`'s into its recurrent state."""
         n = 4 if self.kv_quantized else 2
         self.swapin_pages(self.slot_pages(slot)[skip_pages:], arrays[:n])
-        if self.state.recurrent is not None:
-            if len(arrays) != n + 2:
-                raise PagedCacheError(
-                    f"swap snapshot carries {len(arrays)} arrays; a "
-                    f"block with recurrent state needs {n + 2} (a row "
-                    "cannot resume on a zero state)")
+        rec = self.state.recurrent
+        row = 2 if rec is not None and "ssm" in rec else 0
+        if len(arrays) != n + row + (3 if self.window else 0):
+            raise PagedCacheError(
+                f"swap snapshot carries {len(arrays)} arrays; this block "
+                f"needs {n + row + (3 if self.window else 0)}: a row's "
+                "pages, its recurrent state where it keeps one and its "
+                "window layers' pages where it has them (a row cannot "
+                "resume on a zero state)")
+        if row:
             from kvedge_tpu.models.hybrid import scatter_rows
 
             self.state = dataclasses.replace(
                 self.state, recurrent=scatter_rows(
-                    self.state.recurrent, jnp.asarray(slot, jnp.int32),
-                    *(jnp.asarray(a) for a in arrays[n:])))
+                    rec, jnp.asarray(slot, jnp.int32),
+                    *(jnp.asarray(a) for a in arrays[n:n + row])))
+        if self.window:
+            first, keys, values = arrays[n + row:]
+            self._wfree.extend(self._wpages_of[slot])
+            self._wpages_of[slot] = []
+            self._wfirst[slot] = int(first)
+            self._window_grow(
+                slot, int(first) + keys.shape[1] * self.page_size)
+            self.state = _scatter_window_pages(
+                self.state,
+                jnp.asarray(self._wpages_of[slot], jnp.int32),
+                (jnp.asarray(keys), jnp.asarray(values)))
+            self._sync_window()
 
     def cow_page(self, slot: int, index: int) -> int | None:
         """Copy-on-write divergence for table position ``index`` of
@@ -947,7 +1147,18 @@ class PagedKVCache:
                 f"chunk [{offset}, {offset + n}) exceeds slot {slot}'s "
                 f"admitted length {self._host_lengths[slot]}"
             )
-        return self._device_prefill(params, tokens, slot, offset)
+        if not self.window:
+            return self._device_prefill(params, tokens, slot, offset)
+        # The window layers' pages for this chunk, then, behind it, the
+        # pages its last query no longer sees: a prompt of any length
+        # holds no more than the cap.
+        if self._window_grow(slot, offset + n):
+            self._sync_window()
+        logits = self._device_prefill(params, tokens, slot, offset)
+        with self.window_phase():
+            if self._window_trim(slot, offset + n):
+                self._sync_window()
+        return logits
 
     def _device_prefill(self, params, tokens, slot: int, offset: int):
         """Device seam: run the prefill kernel and advance state."""
@@ -1511,6 +1722,16 @@ def _note_trace(name: str) -> None:
     _TRACE_EVENTS[name] = _TRACE_EVENTS.get(name, 0) + 1
 
 
+def _per_head(pages, kv_heads: int):
+    """[L, n, page, K*Dh] as it leaves the device: [L, n, page, K, Dh]."""
+    return pages.reshape(*pages.shape[:3], kv_heads, -1)
+
+
+def _merged(pages):
+    """[L, n, page, K, Dh] as the pool holds it: [L, n, page, K*Dh]."""
+    return pages.reshape(*pages.shape[:3], -1)
+
+
 def _gather_pages_impl(state: PagedState, idx, kv_heads: int):
     """Pages ``idx`` of every pool slab, values as stored: a 2-or-4
     tuple of fresh ``[L, n, page, K, Dh]`` / ``[L, n, page, K]`` arrays
@@ -1521,10 +1742,8 @@ def _gather_pages_impl(state: PagedState, idx, kv_heads: int):
     gather (runtime/sliceserve.py jits it with ``out_shardings``
     replicated, so the leader can read the swap snapshot host-side
     while followers hold the same bytes)."""
-    def per_head(pages):  # [L, n, page, K*Dh] -> [L, n, page, K, Dh]
-        return pages.reshape(*pages.shape[:3], kv_heads, -1)
-
-    out = [per_head(state.pool_k[:, idx]), per_head(state.pool_v[:, idx])]
+    out = [_per_head(state.pool_k[:, idx], kv_heads),
+           _per_head(state.pool_v[:, idx], kv_heads)]
     if state.scale_k is not None:
         out += [state.scale_k[:, idx], state.scale_v[:, idx]]
     return tuple(out)
@@ -1537,12 +1756,9 @@ def _scatter_pages_impl(state: PagedState, idx, arrays) -> PagedState:
     bit-exactness contract); the heads merge into the pool's lane
     dimension here. Shared by the single-host seams (swap-in,
     write_pages) and the slice cache's jitted donating scatter."""
-    def merged(pages):  # [L, n, page, K, Dh] -> [L, n, page, K*Dh]
-        return pages.reshape(*pages.shape[:3], -1)
-
     fields = dict(
-        pool_k=state.pool_k.at[:, idx].set(merged(arrays[0])),
-        pool_v=state.pool_v.at[:, idx].set(merged(arrays[1])),
+        pool_k=state.pool_k.at[:, idx].set(_merged(arrays[0])),
+        pool_v=state.pool_v.at[:, idx].set(_merged(arrays[1])),
     )
     if state.scale_k is not None:
         fields.update(
@@ -1550,6 +1766,25 @@ def _scatter_pages_impl(state: PagedState, idx, arrays) -> PagedState:
             scale_v=state.scale_v.at[:, idx].set(arrays[3]),
         )
     return dataclasses.replace(state, **fields)
+
+
+@functools.partial(jax.jit, static_argnames=("kv_heads",))
+def _gather_window_pages(state: PagedState, idx, kv_heads: int):
+    """Pages ``idx`` of the window layers' pool, as stored: fresh
+    ``[Lw, n, page, K, Dh]`` keys and values (:func:`_gather_pages_impl`
+    for the second pool)."""
+    return (_per_head(state.win_pool_k[:, idx], kv_heads),
+            _per_head(state.win_pool_v[:, idx], kv_heads))
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _scatter_window_pages(state: PagedState, idx, arrays) -> PagedState:
+    """Write :func:`_gather_window_pages`' arrays into pages ``idx`` of
+    the window layers' pool, verbatim."""
+    return dataclasses.replace(
+        state,
+        win_pool_k=state.win_pool_k.at[:, idx].set(_merged(arrays[0])),
+        win_pool_v=state.win_pool_v.at[:, idx].set(_merged(arrays[1])))
 
 
 def _cow_page_impl(state: PagedState, src, dst) -> PagedState:
@@ -1608,17 +1843,21 @@ def _scatter_rows(pool, scales, layer, page_idx, offset, rows):
     return pool.at[layer, page_idx, offset].set(rows, mode="drop"), scales
 
 
-def _scatter_token(pool, scales, layer, tables, lengths, kv_new, active):
+def _scatter_token(pool, scales, layer, tables, lengths, kv_new, active,
+                   first=None):
     """Write one [B, K, Dh] token row into each sequence's current page.
 
     The target of row b is page ``tables[b, lengths[b] // page]``,
-    offset ``lengths[b] % page`` of ``pool[layer]``. Inactive slots
+    offset ``lengths[b] % page`` of ``pool[layer]`` (``first`` [B],
+    where the table starts at that position and not at 0: entry
+    ``(lengths[b] - first[b]) // page``). Inactive slots
     (empty table rows would alias page 0) are routed out-of-bounds and
     dropped. Returns ``(pool, scales)`` (:func:`_scatter_rows`).
     """
     pages, page = pool.shape[1:3]
+    entry = (lengths if first is None else lengths - first) // page
     page_idx = jnp.take_along_axis(
-        tables, (lengths // page)[:, None], axis=1
+        tables, entry[:, None], axis=1
     )[:, 0]                                   # [B] page ids
     page_idx = jnp.where(active, page_idx, pages)  # OOB => dropped
     return _scatter_rows(pool, scales, layer, page_idx, lengths % page,
@@ -1656,7 +1895,7 @@ def _paged_attend_layer(cfg: TransformerConfig, state: PagedState, x,
 
 def _paged_attention(cfg: TransformerConfig, state: PagedState, normed,
                      w_qkv, w_out, layer, pools, q_positions, slot=None,
-                     write_mask=None, w_gate=None):
+                     write_mask=None, w_gate=None, window: int = 0):
     """The attention mixer of every paged program, over normed
     activations [B, Q, D]; q_positions: [B, Q] absolute
     positions of the new tokens. ``pools`` is the WHOLE pool
@@ -1671,7 +1910,14 @@ def _paged_attention(cfg: TransformerConfig, state: PagedState, normed,
     speculative verify pass drops sampled rows' draft-position writes so
     those rows need no slack pages; None = every offset writes.
     ``cfg.rotary`` false leaves q and k as projected (no positional
-    encoding); ``cfg.attention_multiplier`` non-zero scales the scores
+    encoding; the rotary base is ``cfg.rope_theta``). ``window`` > 0
+    makes this a layer bound to a window: ``pools`` is then the window
+    layers' pool, read and written through ``state.win_tables``, whose
+    row b starts at position ``state.win_first[b]``; q and k are always
+    rotated; and a query sees the last ``window`` key positions, its
+    own included (``ops.paged_attention.visible``, the one statement
+    of the mask, here and in the kernel).
+    ``cfg.attention_multiplier`` non-zero scales the scores
     by it and not by 1/sqrt(Dh). ``w_gate`` [D, H*Dh], where the layer
     has one, gates what was attended, channel by channel, before the
     output projection: ``(sigmoid(normed @ w_gate) * attended) @
@@ -1683,6 +1929,10 @@ def _paged_attention(cfg: TransformerConfig, state: PagedState, normed,
     new_pool_k, new_pool_v, new_scale_k, new_scale_v = pools
     quantized = new_scale_k is not None
     page = new_pool_k.shape[2]
+    if window:
+        all_tables, all_first = state.win_tables, state.win_first
+    else:
+        all_tables, all_first = state.tables, None
 
     q, k, v = split_qkv(cfg, normed @ w_qkv.astype(dtype))
 
@@ -1697,18 +1947,18 @@ def _paged_attention(cfg: TransformerConfig, state: PagedState, normed,
     # in prefill (B=1). Decode/verify rows each carry their own
     # positions: apply per-row via vmap (q_len 1 for plain decode,
     # 1 + draft_len for a speculative verify pass).
-    if not cfg.rotary:
+    if not (cfg.rotary or window):
         pass
     elif slot is None:
-        rot = jax.vmap(lambda t, p: _rotary(t[None], p)[0])
+        rot = jax.vmap(lambda t, p: _rotary(t[None], p, cfg.rope_theta)[0])
         q = rot(q, q_positions)
         k = rot(k, q_positions)
     else:
-        q = _rotary(q, q_positions[0])
-        k = _rotary(k, q_positions[0])
+        q = _rotary(q, q_positions[0], cfg.rope_theta)
+        k = _rotary(k, q_positions[0], cfg.rope_theta)
 
     if slot is None:
-        tables, lengths = state.tables, state.lengths
+        tables, first, lengths = all_tables, all_first, state.lengths
         active = lengths > 0
         # One scatter per query offset (static q_len): row b's token i
         # lands at position lengths[b] + i — multi-offset writes are how
@@ -1720,20 +1970,22 @@ def _paged_attention(cfg: TransformerConfig, state: PagedState, normed,
                         else active & write_mask[:, i])
             new_pool_k, new_scale_k = _scatter_token(
                 new_pool_k, new_scale_k, layer, tables, lengths + i,
-                k[:, i], w_active,
+                k[:, i], w_active, first,
             )
             new_pool_v, new_scale_v = _scatter_token(
                 new_pool_v, new_scale_v, layer, tables, lengths + i,
-                v[:, i], w_active,
+                v[:, i], w_active, first,
             )
     else:
         # Prefill: scatter q_len rows of one slot at their ABSOLUTE
         # positions (chunked prefill passes an offset, so a chunk's
         # positions are offset..offset+q_len-1; the first/whole-prompt
         # chunk starts at zero).
-        tables = state.tables[slot][None]
+        tables = all_tables[slot][None]
+        first = None if all_first is None else all_first[slot][None]
         positions = q_positions[0]
-        page_idx = tables[0][positions // page]
+        page_idx = tables[0][
+            (positions if first is None else positions - first[0]) // page]
         offset = positions % page
         new_pool_k, new_scale_k = _scatter_rows(
             new_pool_k, new_scale_k, layer, page_idx, offset, k[0])
@@ -1787,6 +2039,7 @@ def _paged_attention(cfg: TransformerConfig, state: PagedState, normed,
             layer, scale_k=new_scale_k, scale_v=new_scale_v,
             interpret=pallas_interpret(),
             score_scale=cfg.attention_multiplier or None,
+            **(dict(first=first, window=window) if window else {}),
         )  # [B, H, Dh], kv-major head layout — same as the einsum's
         out = gated(att.reshape(batch, 1, h * dh)) @ w_out.astype(dtype)
     else:
@@ -1800,9 +2053,13 @@ def _paged_attention(cfg: TransformerConfig, state: PagedState, normed,
             scores = scores * jnp.asarray(cfg.attention_multiplier, dtype)
         else:
             scores = scores / (dh ** 0.5)
-        key_pos = jnp.arange(gk.shape[1])
-        allowed = (key_pos[None, None, :]
-                   <= q_positions[:, :, None])  # [B, Q, S]
+        from kvedge_tpu.ops.paged_attention import visible
+
+        key_pos = jnp.arange(gk.shape[1])[None, None, :]
+        if window:  # the table's entries hold positions from ``first`` on
+            key_pos = key_pos + first[:, None, None]
+        allowed = visible(key_pos, q_positions[:, :, None],
+                          window)  # [B, Q, S]
         scores = jnp.where(
             allowed[:, None, None], scores, jnp.finfo(dtype).min
         )
@@ -1825,7 +2082,8 @@ def _run_paged(cfg, params, state, x, q_positions, slot=None,
     a 1.6 GB pool that was 14 of a decode step's 23 ms (PERF.md §5).
     A patterned block's recurrent state rides it the same way, and the
     scan is over the pattern's periods (:func:`_run_paged_pattern`).
-    Returns the logits and the state's new ``(pools, recurrent)``."""
+    Returns the logits and the state's new fields (:func:`_with_pools`).
+    """
     if cfg.layer_pattern:
         return _run_paged_pattern(cfg, params, state, x, q_positions, slot,
                                   all_positions, write_mask)
@@ -1848,7 +2106,7 @@ def _run_paged(cfg, params, state, x, q_positions, slot=None,
     logits = tied_readout(
         x if all_positions else x[:, -1], params["embedding"]
     )
-    return logits, (new_pools, state.recurrent)
+    return logits, _pool_fields(new_pools)
 
 
 def _run_paged_pattern(cfg, params, state, x, q_positions, slot,
@@ -1856,7 +2114,9 @@ def _run_paged_pattern(cfg, params, state, x, q_positions, slot,
     """:func:`_run_paged` for a block with a layer pattern
     (models/hybrid.py has the block and its equations): the attention
     layers through :func:`_paged_attention` on the pool, which holds
-    only them, the recurrent layers on ``state.recurrent``. A batched row
+    only them, the layers bound to a window through the same function
+    on the window layers' pool, the recurrent layers on
+    ``state.recurrent``. A batched row
     that is not decoding (length 0 in ``state``, as the decode step
     masks it) gets its recurrent state back untouched."""
     from kvedge_tpu.models import hybrid
@@ -1867,15 +2127,18 @@ def _run_paged_pattern(cfg, params, state, x, q_positions, slot,
             "step: a verify pass over drafts would have to rewind the "
             "state of the drafts it rejects (serving_speculative)")
 
-    def attend(normed, w, layer, pools):
+    def attend(normed, w, layer, pool, kind):
         return _paged_attention(
-            cfg, state, normed, w["w_qkv"], w["w_out"], layer, pools,
-            q_positions, slot, write_mask, w.get("w_gate"))
+            cfg, state, normed, w["w_qkv"], w["w_out"], layer, pool,
+            q_positions, slot, write_mask, w.get("w_gate"),
+            window=cfg.attention_window if kind == "window" else 0)
 
+    pools = {"attention": (state.pool_k, state.pool_v, state.scale_k,
+                           state.scale_v)}
+    if state.win_pool_k is not None:
+        pools["window"] = (state.win_pool_k, state.win_pool_v, None, None)
     x, pools, recurrent = hybrid.run_layers(
-        cfg, params, x,
-        (state.pool_k, state.pool_v, state.scale_k, state.scale_v),
-        state.recurrent, attend, slot,
+        cfg, params, x, pools, state.recurrent, attend, slot,
         None if slot is not None else state.lengths > 0)
     x = _rmsnorm(x, params["ln_final"], cfg.norm_eps)
     # a head of its own where the tree has one, [V, D] as the embedding
@@ -1883,7 +2146,11 @@ def _run_paged_pattern(cfg, params, state, x, q_positions, slot,
         x if all_positions else x[:, -1],
         params.get("head", params["embedding"])
     ) / cfg.logits_scaling
-    return logits, (pools, recurrent)
+    fields = _pool_fields(pools["attention"], recurrent=recurrent)
+    if "window" in pools:
+        fields.update(win_pool_k=pools["window"][0],
+                      win_pool_v=pools["window"][1])
+    return logits, fields
 
 
 def _embed(cfg: TransformerConfig, params: dict, tokens):
@@ -1894,15 +2161,18 @@ def _embed(cfg: TransformerConfig, params: dict, tokens):
     return x
 
 
-def _with_pools(state: PagedState, carried, **extra) -> PagedState:
-    """A state whose pools/scales and recurrent state are replaced by
-    ``carried`` (what ``_run_paged`` returns beside the logits), plus
-    any other field."""
-    (new_k, new_v, new_sk, new_sv), recurrent = carried
-    return dataclasses.replace(
-        state, pool_k=new_k, pool_v=new_v, scale_k=new_sk,
-        scale_v=new_sv, recurrent=recurrent, **extra,
-    )
+def _pool_fields(pools, **more) -> dict:
+    """The state's fields a layer loop's carried 4-tuple stands for."""
+    new_k, new_v, new_sk, new_sv = pools
+    return dict(pool_k=new_k, pool_v=new_v, scale_k=new_sk,
+                scale_v=new_sv, **more)
+
+
+def _with_pools(state: PagedState, carried: dict, **extra) -> PagedState:
+    """A state whose pools/scales and, for a patterned block, recurrent
+    state and window layers' pools are replaced by ``carried`` (what
+    ``_run_paged`` returns beside the logits), plus any other field."""
+    return dataclasses.replace(state, **carried, **extra)
 
 
 def _paged_prefill_impl(params: dict, state: PagedState, prompt, slot,

@@ -187,26 +187,40 @@ def moe_ffn(x, router_w, w_up, w_down, *, capacity_factor: float,
     return out, aux_loss
 
 
-def ffn_activation(up, gated: bool):
+_GATES = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+# Tokens up to which the held experts' first product is one product over
+# all of them (held_experts_ffn): a decode batch, a 64-token chunk. At 64
+# tokens it reads 0 to 1.3% under the batch of products on the chip, at
+# 256 1.5% over, and inside a 256-token chunk's program it does not fit
+# (tools/expert_product_readings.py; PERF.md section 6, PR 40).
+_ONE_PRODUCT_TOKENS = 64
+
+
+def ffn_activation(up, gated: bool, gate: str = "silu"):
     """A feed-forward's hidden activation from its up-projection:
-    ``silu(u) * g`` over ``up = u | g`` when ``gated``, else
-    ``gelu(up)``."""
+    ``gate(u) * g`` over ``up = u | g`` when ``gated`` (``gate`` names
+    the function: "silu", or "relu" for a ReGLU), else ``gelu(up)``."""
     if not gated:
         return jax.nn.gelu(up)
     half = up.shape[-1] // 2
-    return jax.nn.silu(up[..., :half]) * up[..., half:]
+    return _GATES[gate](up[..., :half]) * up[..., half:]
 
 
 def held_experts_ffn(x, router_w, w_in, w_out, *, top_k: int,
                      first: int = 0, gated: bool = False,
-                     renormalize: bool | None = None, live=None):
+                     renormalize: bool | None = None, live=None,
+                     activation: str = "silu", routed_on=None):
     """The routed experts held here, for every token: the serving path.
 
     x: [N, D]; router_w [D, E] fp32 over ALL ``E`` routed experts;
     w_in [Eh, D, F] (``[Eh, D, 2F]`` when ``gated``: u | g, the expert
-    is ``(silu(u) * g) @ w_out``; ungated it is ``gelu(x @ w_in) @
-    w_out``) and w_out [Eh, F, D] are the ``Eh`` experts this device
-    holds, global indices ``first`` to ``first + Eh - 1``. Each token
+    is ``(activation(u) * g) @ w_out``, :func:`ffn_activation`; ungated it is
+    ``gelu(x @ w_in) @ w_out``) and w_out [Eh, F, D] are the ``Eh``
+    experts this device holds, global indices ``first`` to ``first +
+    Eh - 1``. ``routed_on`` [N, D], where given, is what the router
+    reads in ``x``'s place (a block whose router sits before the mixer
+    hands the mixer's normed input; the experts still take ``x``).
+    Each token
     routes over all ``E`` (:func:`_route`), droplessly, and the result
     is the part of its gated sum that the held experts give: with
     ``Eh == E`` the whole layer, on a chip that shares the layer the
@@ -230,15 +244,29 @@ def held_experts_ffn(x, router_w, w_in, w_out, *, top_k: int,
     ``Eh`` (PERF.md section 6 has both timed at the benchmark's
     widths).
     """
-    _, topk_idx, gates = _route(x, router_w, top_k, renormalize)
+    _, topk_idx, gates = _route(x if routed_on is None else routed_on,
+                                router_w, top_k, renormalize)
     held = w_in.shape[0]
     dtype = x.dtype
     # [N, k, Eh]: pick j of token n is held expert e.
     hit = (topk_idx[:, :, None] - first
            == jnp.arange(held, dtype=topk_idx.dtype)[None, None, :])
     gate = jnp.sum(jnp.where(hit, gates[:, :, None], 0.0), axis=1)  # [N, Eh]
-    act = ffn_activation(jnp.einsum("nd,edf->enf", x, w_in.astype(dtype)),
-                         gated)
+    if x.shape[0] > _ONE_PRODUCT_TOKENS:
+        # A prefill chunk of more tokens: as one product with the tokens
+        # shared by every expert, the chip's compiler, inside the
+        # chunk's program, wants the experts' matrices transposed and
+        # copies the whole stacked leaf to get them (3.75 GB at 64
+        # experts of 2,560 x 1,536 in 8 layers: 15.82 GB where the chip
+        # has 15.75; tests/test_chip_compile.py). With the tokens stated
+        # once an expert it is a batch of plain products over the
+        # matrices as they lie.
+        up = jnp.einsum("end,edf->enf",
+                        jnp.broadcast_to(x, (held,) + x.shape),
+                        w_in.astype(dtype))
+    else:
+        up = jnp.einsum("nd,edf->enf", x, w_in.astype(dtype))
+    act = ffn_activation(up, gated, activation)
     act = act * gate.T[:, :, None].astype(dtype)
     out = jnp.einsum("enf,efd->nd", act, w_out.astype(dtype))
     counted = hit if live is None else hit & live[:, None, None]

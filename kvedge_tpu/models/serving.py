@@ -349,16 +349,22 @@ class PagedGenerationServer:
                 "it with paged_attention='gather'")
         if cfg.layer_pattern and prefix_cache:
             raise ValueError(
-                "prefix_cache (serving_prefix_cache) cannot serve a block "
-                "with recurrent layers (layer_pattern): a recurrent "
-                "state holds a row's whole prefix in one array and "
-                "cannot be shared by page; pass prefix_cache=False")
+                "prefix_cache (serving_prefix_cache) cannot serve a "
+                "patterned block (layer_pattern): " + (
+                    "a shared page that a 'window' layer has given back "
+                    "cannot be attended again" if cfg.window_layers else
+                    "a recurrent state holds a row's whole prefix in one "
+                    "array and cannot be shared by page")
+                + "; pass prefix_cache=False")
         if cfg.layer_pattern and speculative:
             raise ValueError(
-                "speculative (serving_speculative) cannot serve a block "
-                "with recurrent layers (layer_pattern): a recurrent "
-                "state cannot be rewound past the drafts a verify pass "
-                "rejects")
+                "speculative (serving_speculative) cannot serve a "
+                "patterned block (layer_pattern): " + (
+                    "a drafted position's page that a 'window' layer "
+                    "has given back cannot be attended again"
+                    if cfg.window_layers else
+                    "a recurrent state cannot be rewound past the "
+                    "drafts a verify pass rejects"))
         self._params = params
         self._weights_gb, self._weights_dtype = weights_summary(params)
         self._cfg = cfg
@@ -377,6 +383,7 @@ class PagedGenerationServer:
         self._decode_row_steps = 0
         self._decode_bucket_steps = 0
         self._pages_live_steps = 0
+        self._window_pages_live_steps = 0  # the same, the window pool's
         self._tokens_emitted = 0
         # Device-window cap (steps per dispatched greedy decode scan).
         # The host round trip per dispatch is the paged path's tax — a
@@ -500,6 +507,14 @@ class PagedGenerationServer:
             # it admits a request (kvcache.PagedKVCache.admit).
             **({"admit/state_reset": PhaseSum()}
                if cfg.layer_pattern else {}),
+            # A block with layers bound to a window gives the pages
+            # behind the window back, on the host, lock held: at every
+            # harvested decode window (inside ``loop/emit``, not a
+            # phase of the loop's chain) and after every prefill chunk
+            # (inside ``admit/prefill_chunk``).
+            **({"loop/window_release": PhaseSum(),
+                "admit/window_release": PhaseSum()}
+               if cfg.window_layers else {}),
         }, tracer, chained=LOOP_PHASES)
         # The time the loop thread has run, by its own clock (what its
         # phases must add up to): finished threads in _loop_ran, the
@@ -619,10 +634,18 @@ class PagedGenerationServer:
             max_pages_per_seq=-(-(cfg.max_seq + self._spec)
                                 // page_size),
             kv_dtype=kv_dtype, min_bucket=min_bucket,
+            # The most a row moves on between two givings-back: a
+            # prefill chunk, or the longest decode window.
+            window_advance=max(
+                prefill_chunk or cfg.max_seq,
+                window_max if self._autotune is not None else window),
         )
         if cfg.layer_pattern:
             self._cache.reset_phase = functools.partial(
                 self._phase, "admit/state_reset")
+        if cfg.window_layers:
+            self._cache.window_phase = functools.partial(
+                self._phase, "admit/window_release")
         # Bucketed compile cache (SERVING.md rung 21): the device batch
         # dim is the cache's current BUCKET, not ``slots`` — every
         # dispatch-array site below sizes on ``self._cache.bucket``.
@@ -729,6 +752,11 @@ class PagedGenerationServer:
             self._cache.tracer = tracer
         self._pages_total = pages
         self._reserved = 0  # worst-case pages of every in-flight request
+        # The window layers' pool (kvcache.PagedState.win_pool_k; 0
+        # pages where the block has none) holds every slot's cap, so a
+        # slot is all an admission needs of it.
+        self._window_pages_total = getattr(self._cache,
+                                           "num_window_pages", 0)
         # Lock discipline ([payload] serving_debug_locks, SERVING.md
         # rung 19): the ownership-asserting DebugLock makes every
         # *_locked call and every Condition wait/notify verify the
@@ -1725,10 +1753,24 @@ class PagedGenerationServer:
         if acct_fn is None:  # injected cache without the census
             return
         acct = acct_fn()
+        window_ok = (
+            "window_free" not in acct
+            or (acct["window_free"] + acct["window_live"]
+                == acct["window_pages_total"]
+                and not acct["window_free_dup"]
+                and not acct["window_held_dup"]
+                and not acct["window_free_live"]
+                and not acct["window_over_cap"]))
         if (acct["free"] + acct["live"] == acct["pages_total"]
                 and not acct["free_dup"] and not acct["neg_refs"]
-                and not acct["free_live"]):
+                and not acct["free_live"] and window_ok):
             return
+        if not window_ok:
+            raise PageAccountingError(
+                "page conservation violated in the window layers' pool "
+                "at a quiescent boundary: " + ", ".join(
+                    f"{k}={v}" for k, v in acct.items()
+                    if k.startswith("window_")))
         raise PageAccountingError(
             f"page conservation violated at a quiescent boundary: "
             f"free={acct['free']} live={acct['live']} "
@@ -3056,6 +3098,16 @@ class PagedGenerationServer:
             "pages_live_steps_total": self._pages_live_steps,
             "tokens_emitted_total": self._tokens_emitted,
         }
+        if self._window_pages_total:
+            # The window layers' pool, beside the full layers' above
+            # (``free_pages``, ``pages_total``, ``reserved_pages`` and
+            # ``pages_live_steps_total`` keep meaning the full pool).
+            out["window_pages_total"] = self._window_pages_total
+            out["window_free_pages"] = self._cache.free_window_pages()
+            out["window_pages_released_total"] = (
+                self._cache.window_pages_released)
+            out["window_pages_live_steps_total"] = (
+                self._window_pages_live_steps)
         recurrent = self._cache.state.recurrent
         if recurrent is not None:
             # State of the second kind (SERVING.md "Recurrent state"):
@@ -3063,9 +3115,10 @@ class PagedGenerationServer:
             # not, so slots bound admission as memory too. The picks
             # are the decode windows' own counts, summed at harvest.
             picks = self._cache.expert_picks
-            out["state_rows"] = self._cache.slots
-            out["state_gb"] = (recurrent["ssm"].nbytes
-                               + recurrent["conv"].nbytes) / 1e9
+            if "ssm" in recurrent:
+                out["state_rows"] = self._cache.slots
+                out["state_gb"] = (recurrent["ssm"].nbytes
+                                   + recurrent["conv"].nbytes) / 1e9
             out["expert_picks_total"] = int(picks[0])
             out["expert_picks_held_total"] = int(picks[1])
             out["expert_picks_by_expert"] = [int(n) for n in picks[2:-1]]
@@ -3448,6 +3501,9 @@ class PagedGenerationServer:
             live += (-(-self._cache.slot_length(slot) // page)
                      - len(req.shared_pages))
         self._pages_live_steps += live * steps
+        if self._window_pages_total:
+            self._window_pages_live_steps += steps * sum(
+                self._cache.window_pages_held(slot) for slot, _ in rows)
 
     @staticmethod
     def _draft(req: _Request, k: int) -> list[int]:
@@ -4259,10 +4315,15 @@ class PagedGenerationServer:
                 req.inflight -= adv
             self._decode_row_steps += sum(
                 adv for _, _, adv in rec["parts"])
-            self._count_steps_locked(
-                w, rec["bucket"],
-                [(slot, req) for slot, req, _ in rec["parts"]
-                 if self._active.get(slot) is req])
+            rows = [(slot, req) for slot, req, _ in rec["parts"]
+                    if self._active.get(slot) is req]
+            self._count_steps_locked(w, rec["bucket"], rows)
+            if self._window_pages_total:
+                # What the rows' windows have moved past goes back to
+                # the window layers' pool now, not when the rows end.
+                with self._phase("loop/window_release"):
+                    self._cache.release_window_pages(
+                        [slot for slot, _ in rows])
             stop_row = produced[w + 1]
             for slot, req, adv in rec["parts"]:
                 if self._active.get(slot) is not req or req.stopped:

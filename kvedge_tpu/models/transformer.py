@@ -120,14 +120,21 @@ class TransformerConfig:
     # path (multi-query shapes).
     paged_attention: str = "auto"
     # A patterned block (models/hybrid.py; served by the paged path
-    # only). ``layer_pattern`` is one period of layer kinds, "attention"
-    # and one recurrent kind, "mamba" (models/ssm.py) or "delta"
-    # (models/delta.py), repeated ``n_layers / len(layer_pattern)``
-    # times; () is the block above: every layer rotary attention and a
-    # GELU feed-forward. With a pattern every layer's feed-forward is
-    # ``n_experts`` routed experts of width ``d_ff`` (``expert_top_k``
-    # a token, gates a softmax over the picked logits) plus a shared
-    # expert of width ``shared_ff``, all SiLU-gated when ``ffn_gated``.
+    # only). ``layer_pattern`` is one period of layer kinds: "attention"
+    # (full attention, rotary or not as ``rotary`` says), "window"
+    # (attention over the last ``attention_window`` positions, always
+    # rotary, with keys and values in a page pool of its own:
+    # models/kvcache.py) and at most one recurrent kind, "mamba"
+    # (models/ssm.py) or "delta" (models/delta.py), repeated
+    # ``n_layers / len(layer_pattern)`` times; () is the block above:
+    # every layer rotary attention and a GELU feed-forward. With a
+    # pattern every layer's feed-forward is ``n_experts`` routed
+    # experts of width ``d_ff`` (``expert_top_k`` a token, gates a
+    # softmax over the picked logits) plus, where ``shared_ff`` is set,
+    # a shared expert of that width, all gated when ``ffn_gated``
+    # (``ffn_activation``: the gate's function, "silu" or "relu").
+    # ``router_before_mixer`` routes a layer's tokens on the mixer's
+    # normed input and not on the feed-forward's own.
     # ``experts_held`` of the routed experts live here, from index
     # ``expert_first`` on (0 held = all): the layer routes over all
     # ``n_experts`` and returns the held experts' part of the sum.
@@ -151,9 +158,14 @@ class TransformerConfig:
     expert_first: int = 0
     shared_ff: int = 0
     ffn_gated: bool = False
+    ffn_activation: str = "silu"
+    router_before_mixer: bool = False
     head_dim: int = 0
     attention_gate: bool = False
     untied_head: bool = False
+    # Positions a "window" layer's query sees, its own included (query
+    # i attends keys i - attention_window + 1 .. i).
+    attention_window: int = 0
     # x = embedding_multiplier * E[tokens]; each residual add takes
     # residual_multiplier * f(norm(x)); attention scores are
     # attention_multiplier * q.k (0 = 1/sqrt(d_head)); logits are
@@ -163,20 +175,31 @@ class TransformerConfig:
     attention_multiplier: float = 0.0
     logits_scaling: float = 1.0
     rotary: bool = True     # False = no positional encoding at all
+    rope_theta: float = 10000.0  # the rotary base, wherever it is applied
     norm_eps: float = 1e-6
 
     @property
     def kv_layers(self) -> int:
-        """Layers that keep keys and values in the page pool."""
+        """Layers that keep keys and values in the page pool: every
+        layer of the plain block, the full attention layers of a
+        patterned one (its window layers have a pool of their own)."""
         if not self.layer_pattern:
             return self.n_layers
         return (self.n_layers // len(self.layer_pattern)
                 * self.layer_pattern.count("attention"))
 
     @property
+    def window_layers(self) -> int:
+        """Layers that keep keys and values in the window layers' pool."""
+        if not self.layer_pattern:
+            return 0
+        return (self.n_layers // len(self.layer_pattern)
+                * self.layer_pattern.count("window"))
+
+    @property
     def ssm_layers(self) -> int:
         """Layers that keep a recurrent state per slot."""
-        return self.n_layers - self.kv_layers
+        return self.n_layers - self.kv_layers - self.window_layers
 
     @property
     def held_experts(self) -> int:
@@ -257,12 +280,17 @@ class TransformerConfig:
             self._validate_pattern()
         elif (self.experts_held or self.expert_first or self.shared_ff
               or self.ffn_gated or not self.rotary or self.head_dim
-              or self.attention_gate or self.untied_head):
+              or self.attention_gate or self.untied_head
+              or self.attention_window or self.router_before_mixer
+              or self.ffn_activation != "silu"):
             raise ValueError(
                 "experts_held, expert_first, shared_ff, ffn_gated, "
-                "head_dim, attention_gate, untied_head and "
+                "ffn_activation, router_before_mixer, head_dim, "
+                "attention_gate, untied_head, attention_window and "
                 "rotary = false belong to a patterned block: set "
                 "layer_pattern")
+        if self.rope_theta <= 0:
+            raise ValueError("rope_theta, the rotary base, must be > 0")
         if self.n_experts:
             if self.expert_top_k not in (1, 2) and not self.layer_pattern:
                 raise ValueError("expert_top_k must be 1 or 2")
@@ -321,10 +349,25 @@ class TransformerConfig:
 
     def _validate_pattern(self) -> None:
         kinds = set(self.layer_pattern)
-        if not kinds <= {"mamba", "delta", "attention"}:
+        known = {"mamba", "delta", "attention", "window"}
+        if not kinds <= known:
             raise ValueError(
-                "layer_pattern holds 'mamba', 'delta' and 'attention', "
-                f"got {sorted(kinds - {'mamba', 'delta', 'attention'})}")
+                "layer_pattern holds 'mamba', 'delta', 'attention' and "
+                f"'window', got {sorted(kinds - known)}")
+        if ("window" in kinds) != (self.attention_window > 0):
+            raise ValueError(
+                "layer_pattern's 'window' layers and attention_window, "
+                "the positions their queries see, go together: "
+                f"attention_window = {self.attention_window} with "
+                f"{'a' if 'window' in kinds else 'no'} 'window' layer")
+        if self.ffn_activation not in ("silu", "relu"):
+            raise ValueError(
+                "ffn_activation, a gated feed-forward's gate, must be "
+                f"'silu' or 'relu', got {self.ffn_activation!r}")
+        if self.ffn_activation != "silu" and not self.ffn_gated:
+            raise ValueError(
+                "ffn_activation names the gate of a gated feed-forward: "
+                "set ffn_gated")
         if {"mamba", "delta"} <= kinds:
             raise ValueError(
                 "layer_pattern holds one recurrent kind, 'mamba' or "
@@ -520,12 +563,13 @@ def _rmsnorm(x, gain, eps: float = 1e-6):
     return (x * scale.astype(x.dtype)) * gain.astype(x.dtype)
 
 
-def _rotary(x, positions):
-    """Rotary position embedding over the head dim (applied to q and k)."""
+def _rotary(x, positions, base: float = 10000.0):
+    """Rotary position embedding over the head dim (applied to q and k),
+    with frequencies ``base ** (-i / half)`` (``cfg.rope_theta``)."""
     *_, dh = x.shape
     half = dh // 2
     freqs = jnp.exp(
-        -jnp.arange(0, half, dtype=jnp.float32) * (jnp.log(10000.0) / half)
+        -jnp.arange(0, half, dtype=jnp.float32) * (jnp.log(base) / half)
     )
     angles = positions[:, None].astype(jnp.float32) * freqs[None, :]  # [T, half]
     cos = jnp.cos(angles).astype(x.dtype)
@@ -586,8 +630,8 @@ def _layer(cfg: TransformerConfig, x, layer_params, mesh=None,
         # seq here is the LOCAL chunk length; chunks are contiguous in
         # sequence order, so global positions offset by the ring index.
         positions = lax.axis_index(seq_manual[0]) * seq + positions
-    q = _rotary(q, positions)
-    k = _rotary(k, positions)
+    q = _rotary(q, positions, cfg.rope_theta)
+    k = _rotary(k, positions, cfg.rope_theta)
     if kv != h:
         # GQA at train time: broadcast each KV head over its query group.
         # XLA fuses the broadcast into the batched matmuls — no repeated

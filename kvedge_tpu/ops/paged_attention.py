@@ -71,6 +71,16 @@ layer's slab out or reshapes it for the kernel's sake.
   Dh-aligned partial sums changes no bits). The [H, width] output's
   per-head slot is extracted outside.
 
+A layer bound to a window (``window`` > 0, a static argument) hands
+the kernel a table that does not start at position 0: ``first`` [B]
+is the position of each row's first table entry (a multiple of the
+page; the pages before it were given back, models/kvcache.py), a row's
+live pages are entries 0 to ``(position - first) // page``, a score
+column's key position is ``first`` plus its place in the table, and the
+mask is the gather's, ``q_pos - window < key_pos <= q_pos``: only the
+oldest page's leading columns fall to its lower bound. A call with no
+window is handed no ``first`` and traces none of this.
+
 The serving stack selects this kernel per ``TransformerConfig
 .paged_attention`` ("auto" = kernel on TPU at long-context caps,
 einsum gather elsewhere); the verify pass (multi-query) and prefill
@@ -91,6 +101,19 @@ _SCALE_VMEM_BUDGET = 8 * 1024 * 1024  # bytes, BOTH scale arrays
 _SCRATCH_VMEM_BUDGET = 12 * 1024 * 1024  # bytes, score + V-image scratch
 _PAD_VMEM_BUDGET = 2 * 1024 * 1024  # bytes of it, the page landing pads
 _MAX_BLOCK_PAGES = 8  # pages of one block: 16 K and V copies started together
+
+
+def visible(key_pos, q_pos, window: int = 0):
+    """Which keys a query sees, on absolute positions: the one
+    statement of the mask, for the gather (models/kvcache.py) and the
+    kernel below. Causal, and with ``window`` > 0 no further back than
+    the last ``window`` positions, the query's own included:
+    ``q_pos - window < key_pos <= q_pos``. ``window`` is static: with
+    none, none of the bound is traced."""
+    seen = key_pos <= q_pos
+    if window:
+        seen = seen & (key_pos > q_pos - window)
+    return seen
 
 
 def scales_fit_vmem(rows: int, kv_heads: int) -> bool:
@@ -134,9 +157,10 @@ def decode_scratch_fits_vmem(max_pages: int, page: int, width: int,
     return need <= _SCRATCH_VMEM_BUDGET
 
 
-def _decode_flat_kernel(tables_ref, pos_ref, layer_ref, q_ref, *rest,
+def _decode_flat_kernel(tables_ref, pos_ref, layer_ref, *rest,
                         page: int, width: int, dh: int, dtype,
-                        quantized: bool, score_scale: float | None):
+                        quantized: bool, score_scale: float | None,
+                        window: int = 0):
     """One program per SEQUENCE, two phases (module docstring).
 
     Layout: the pools arrive whole, [L, P, page, width] as PagedState
@@ -161,7 +185,12 @@ def _decode_flat_kernel(tables_ref, pos_ref, layer_ref, q_ref, *rest,
     head's Dh columns by a 0/1 dot and applied with the gather's exact
     dequant formula BEFORE any compute touches the page — from there
     the two variants share one body, which is how the int8 kernel
-    bit-matches the int8 gather."""
+    bit-matches the int8 gather. With ``window`` a fourth prefetched
+    scalar row, ``first_ref`` [B], leads ``rest`` (module docstring)."""
+    if window:
+        first_ref, q_ref, *rest = rest
+    else:
+        q_ref, *rest = rest
     if quantized:
         (scale_k_ref, scale_v_ref, k_hbm, v_hbm, o_ref,
          kbuf, vbuf, scores, vimg, sems, state_ref) = rest
@@ -179,6 +208,10 @@ def _decode_flat_kernel(tables_ref, pos_ref, layer_ref, q_ref, *rest,
 
     def pages_of(r):
         """Row r's live pages; none where its position is negative."""
+        if window:
+            return jax.lax.select(
+                pos_ref[r] < 0, 0,
+                jax.lax.div(pos_ref[r] - first_ref[r] + page, page))
         return jax.lax.max(jax.lax.div(pos_ref[r] + page, page), 0)
 
     def next_live(r):
@@ -289,7 +322,10 @@ def _decode_flat_kernel(tables_ref, pos_ref, layer_ref, q_ref, *rest,
             key_pos = j * page + jax.lax.broadcasted_iota(
                 jnp.int32, s16.shape, 1
             )
-            s = jnp.where(key_pos <= q_pos, s16, jnp.finfo(dtype).min)
+            if window:
+                key_pos = key_pos + first_ref[b]
+            s = jnp.where(visible(key_pos, q_pos, window), s16,
+                          jnp.finfo(dtype).min)
             scores[:, pl.ds(j * page, page)] = s.astype(jnp.float32)
             vimg[pl.ds(j * page, page), :] = vj
             return carry
@@ -354,12 +390,14 @@ def _decode_flat_kernel(tables_ref, pos_ref, layer_ref, q_ref, *rest,
 # for each of seven buckets at the benchmark cell's start-up) calls this
 # with the same shapes, and tracing the kernel's body is the costliest
 # part of lowering one.
-@functools.partial(jax.jit, static_argnames=("interpret", "score_scale"),
+@functools.partial(jax.jit,
+                   static_argnames=("interpret", "score_scale", "window"),
                    inline=True)
 def paged_decode_attention(q, pool_k, pool_v, tables, q_positions,
                            layer, *, scale_k=None, scale_v=None,
                            interpret: bool = False,
-                           score_scale: float | None = None):
+                           score_scale: float | None = None,
+                           first=None, window: int = 0):
     """Decode attention over layer ``layer`` of a paged KV pool,
     block-table-indexed.
 
@@ -375,7 +413,12 @@ def paged_decode_attention(q, pool_k, pool_v, tables, q_positions,
     ``scale_k``/``scale_v`` ([L, P, page, K] fp32) mark an int8 pool:
     the kernel streams pages as stored and dequantizes in VMEM with the
     gather's exact formula. ``score_scale`` multiplies the scores
-    (None = divide them by sqrt(Dh)). Returns [B, H, Dh], a live row's
+    (None = divide them by sqrt(Dh)). ``window`` > 0 bounds a query to
+    the last ``window`` key positions, its own included, over a table
+    whose first entry holds position ``first[b]`` ([B] int32, multiples
+    of the page): row b attends key positions ``max(first[b],
+    q_positions[b] - window + 1)`` to ``q_positions[b]``.
+    Returns [B, H, Dh], a live row's
     BIT-IDENTICAL to the gather path's decode attention. DMA cost and
     program time scale with the LIVE rows' page counts; the pool itself
     is neither sliced nor reshaped.
@@ -388,6 +431,11 @@ def paged_decode_attention(q, pool_k, pool_v, tables, q_positions,
             f"pool width {width} is not a whole number of heads of "
             f"{dh} (q is [B, H, Dh], the pools [L, P, page, K*Dh])"
         )
+    if bool(window) != (first is not None):
+        raise ValueError(
+            "a window and the position of each row's first table entry "
+            f"go together: window = {window}, first "
+            f"{'given' if first is not None else 'not given'}")
     kv = width // dh
     group = h // kv
     s_cap = max_pages * page
@@ -423,7 +471,7 @@ def paged_decode_attention(q, pool_k, pool_v, tables, q_positions,
     place = (head_slot[:, None] == col_slot[None, :])  # [H, width]
     q2 = jnp.where(place[None], jnp.tile(q, (1, 1, kv)), 0)
 
-    q_spec = pl.BlockSpec((1, h, width), lambda b, t, p, l: (b, 0, 0))
+    q_spec = pl.BlockSpec((1, h, width), lambda b, *_: (b, 0, 0))
     pool_specs = [
         pl.BlockSpec(memory_space=pl.ANY),  # pools stay in HBM;
         pl.BlockSpec(memory_space=pl.ANY),  # the kernel DMAs pages
@@ -444,24 +492,28 @@ def paged_decode_attention(q, pool_k, pool_v, tables, q_positions,
                     pl.BlockSpec(memory_space=pltpu.VMEM),
                     pl.BlockSpec(memory_space=pltpu.VMEM),
                     *pool_specs]
-        args = (tables.astype(jnp.int32), q_positions.astype(jnp.int32),
-                layer, q2,
+        args = (q2,
                 scale_k[layer[0]].astype(jnp.float32),
                 scale_v[layer[0]].astype(jnp.float32), pool_k, pool_v)
     else:
         in_specs = [q_spec, *pool_specs]
-        args = (tables.astype(jnp.int32), q_positions.astype(jnp.int32),
-                layer, q2, pool_k, pool_v)
+        args = (q2, pool_k, pool_v)
+    scalars = (tables.astype(jnp.int32), q_positions.astype(jnp.int32),
+               layer)
     kernel = functools.partial(
         _decode_flat_kernel, page=page, width=width, dh=dh,
         dtype=q.dtype, quantized=quantized, score_scale=score_scale,
     )
+    if window:
+        scalars += (first.astype(jnp.int32),)
+        kernel = functools.partial(kernel, window=window)
+    args = scalars + args
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=len(scalars),
         grid=(batch,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, h, width), lambda b, t, p, l: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, h, width), lambda b, *_: (b, 0, 0)),
         scratch_shapes=scratch,
     )
     out_wide = pl.pallas_call(
@@ -473,6 +525,7 @@ def paged_decode_attention(q, pool_k, pool_v, tables, q_positions,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name="paged_attention",
     )(*args)
     # Each head's own Dh-slot of the [H, width] output.
     out = jnp.take_along_axis(

@@ -88,6 +88,20 @@ _SERVE_METRIC_FIELDS = (
      "unreferenced KV pages in the pool (paged backend)"),
     ("reserved_pages", "serve_reserved_pages", "gauge",
      "worst-case pages reserved by in-flight requests (paged backend)"),
+    # The window layers' pool (a [model] layer_pattern with "window"
+    # layers; SERVING.md "Two page pools"): absent otherwise.
+    ("window_pages_total", "serve_window_pages_total", "gauge",
+     "total pages of the window layers' KV pool (paged backend)"),
+    ("window_free_pages", "serve_window_free_pages", "gauge",
+     "pages of the window layers' pool in no row's table (paged backend)"),
+    ("window_pages_released_total", "serve_window_pages_released_total",
+     "counter",
+     "window-pool pages given back by live rows whose window moved past "
+     "them (paged backend)"),
+    ("window_pages_live_steps_total",
+     "serve_window_pages_live_steps_total", "counter",
+     "window-pool pages in live rows' tables x decode steps (paged "
+     "backend)"),
     # Capacity semantics (SERVING.md rung 21): total pool size, the
     # compile bucket the device batch dim currently runs at, and the
     # free-page watermarks the scheduler's shed/resume decisions key on.

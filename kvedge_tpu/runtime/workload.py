@@ -195,6 +195,10 @@ def derive_model_config(cfg: RuntimeConfig, *, seq: int):
         expert_first=spec.expert_first,
         shared_ff=spec.shared_ff,
         ffn_gated=spec.ffn_gated,
+        ffn_activation=spec.ffn_activation or "silu",
+        router_before_mixer=spec.router_before_mixer,
+        attention_window=spec.attention_window,
+        rope_theta=spec.rope_theta or TransformerConfig.rope_theta,
         embedding_multiplier=spec.embedding_multiplier or 1.0,
         residual_multiplier=spec.residual_multiplier or 1.0,
         attention_multiplier=spec.attention_multiplier,

@@ -64,10 +64,11 @@ def chip(v5e):
 
 
 def _paged(*, batch=8, heads=16, kv=4, dh=64, page=128, max_pages=16,
-           pool_pages=64, layers=2, int8=False):
+           pool_pages=64, layers=2, int8=False, window=0):
     """(fn, arg shapes) for one paged_decode_attention geometry: the
     whole [L, P, page, K*Dh] pool and a traced layer index, as the
-    layer loop hands them over."""
+    layer loop hands them over; with ``window``, each row's first
+    position beside them."""
     from kvedge_tpu.ops.paged_attention import paged_decode_attention
 
     pool_dtype = jnp.int8 if int8 else jnp.bfloat16
@@ -75,6 +76,13 @@ def _paged(*, batch=8, heads=16, kv=4, dh=64, page=128, max_pages=16,
     args = [((batch, heads, dh), jnp.bfloat16), pool, pool,
             ((batch, max_pages), jnp.int32), ((batch,), jnp.int32),
             ((), jnp.int32)]
+    if window:
+        def bound(q, pool_k, pool_v, tables, positions, layer, first):
+            return paged_decode_attention(
+                q, pool_k, pool_v, tables, positions, layer, first=first,
+                window=window)
+
+        return bound, args + [((batch,), jnp.int32)]
     if not int8:
         return paged_decode_attention, args
     scale = ((layers, pool_pages, page, kv), jnp.float32)
@@ -137,6 +145,17 @@ _CASES = {
     "paged_decode_bf16_cell": lambda: _paged(
         batch=64, heads=24, kv=2, dh=128, max_pages=24, pool_pages=768,
         layers=16),
+    # The window cell's two kernels: 64 rows, 28 query / 4 KV heads of
+    # 128 (a query group of 7, a pool 512 wide). A full layer's table is
+    # 64 pages (8,192 positions: scores, V image and pads are 11.4 of the
+    # 12 MB budget); a window layer's is a row's cap of 35 pages, with
+    # the bound of 4,096 positions and each row's first position.
+    "paged_decode_bf16_window_cell_full": lambda: _paged(
+        batch=64, heads=28, kv=4, dh=128, max_pages=64, pool_pages=2816,
+        layers=2),
+    "paged_decode_bf16_window_cell_window": lambda: _paged(
+        batch=64, heads=28, kv=4, dh=128, max_pages=35, pool_pages=2240,
+        layers=6, window=4096),
     # The flagship preset serves MHA: 8 KV heads of 64 (width 512).
     "paged_decode_bf16_flagship": lambda: _paged(heads=8, kv=8),
     "flash_attention_fwd_bwd_t2048": _flash_fwd_bwd,
@@ -350,14 +369,28 @@ def _patterned_cell_program(program: str, chip, monkeypatch,
                                                  cfg))
     pool = on_chip((cfg.kv_layers, pages, page, cfg.kv_heads * cfg.d_head),
                    jnp.bfloat16)
+    window = {}
+    if cfg.window_layers:
+        # the window layers' pool, sized as the server sizes it
+        # (PagedKVCache: the cap from the window and one advance)
+        chunk = payload["serving_prefill_chunk"]
+        cap = -(-(cfg.attention_window
+                  + max(chunk, payload["serving_window"])) // page) + 1
+        wpool = on_chip((cfg.window_layers, slots * cap, page,
+                         cfg.kv_heads * cfg.d_head), jnp.bfloat16)
+        window = dict(win_pool_k=wpool, win_pool_v=wpool,
+                      win_tables=on_chip((slots, cap), jnp.int32),
+                      win_first=on_chip((slots,), jnp.int32))
     state = kvcache.PagedState(
         pool_k=pool, pool_v=pool,
         tables=on_chip((slots, cfg.max_seq // page), jnp.int32),
         lengths=on_chip((slots,), jnp.int32),
-        recurrent=abstract(lambda: hybrid.fresh_recurrent(cfg, slots)))
+        recurrent=abstract(lambda: hybrid.fresh_recurrent(cfg, slots)),
+        **window)
     if program == "prefill":
         lowered = kvcache._paged_prefill.lower(
-            params, state, on_chip((64,), jnp.int32),
+            params, state,
+            on_chip((payload.get("serving_prefill_chunk", 64),), jnp.int32),
             on_chip((), jnp.int32), cfg, on_chip((), jnp.int32))
     else:
         def row(dtype):
@@ -480,6 +513,79 @@ def test_the_delta_cell_fits_and_leaves_its_state_where_it_is(
         f"{program}: {memory.temp_size_in_bytes / 1e9:.2f} GB of "
         "temporaries is a layer's recurrent state or more")
     assert memory.alias_size_in_bytes >= rows.size * 4
+
+
+@pytest.mark.parametrize("program", ["decode_window", "prefill"])
+def test_the_window_cell_fits_with_both_pools(chip, monkeypatch, program):
+    """``smallthinker-21ba3b.longmix`` compiled for the chip at its
+    shapes, held to ISSUE 40's arithmetic: 3.967 B parameters, 7.93 GB
+    in bf16, no leaf float32 but the small ones; a pool of the two full
+    layers' keys and values (2,816 pages of 0.5 MiB, 1.48 GB) and one of
+    the six window layers' (2,240 = 64 x 35 pages of 1.5 MiB, 3.52 GB),
+    both donated and updated in place; the 32-step window and the
+    256-token prefill chunk need under the chip's 15.75 GB with no
+    temporary of either pool's size. The window's step body, a scan
+    over the two periods, holds four kernels: the paged attention
+    kernel at a query group of 7 once for each layer of a period, one
+    over a table of 64 pages and three, with the bound, over a table of
+    a row's cap of 35. A prefill chunk, one row's slot given, takes the
+    gather."""
+    cfg, params, state, lowered = _patterned_cell_program(
+        program, chip, monkeypatch, "smallthinker-21ba3b.longmix")
+    leaves = jax.tree_util.tree_leaves(params)
+    weights = sum(a.size * a.dtype.itemsize for a in leaves)
+    assert sum(a.size for a in leaves) == pytest.approx(3.967e9, rel=1e-3)
+    assert 7.92e9 < weights < 7.95e9
+    assert max(a.size for a in leaves if a.dtype == jnp.float32) \
+        == cfg.n_layers * cfg.d_model * cfg.n_experts  # the router
+    assert (cfg.n_heads, cfg.kv_heads, cfg.d_head) == (28, 4, 128)
+    assert (cfg.kv_layers, cfg.window_layers, cfg.ssm_layers) == (2, 6, 0)
+    assert set(state.recurrent) == {"picks"}
+    assert state.pool_k.shape == (2, 2816, 128, 512)
+    assert state.win_pool_k.shape == (6, 2240, 128, 512)
+    assert state.win_tables.shape == (64, 35)
+    pools = 2 * (state.pool_k.size + state.win_pool_k.size) * 2
+    assert pools == pytest.approx(1.476e9 + 3.523e9, rel=1e-3)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    window = program == "decode_window"
+    assert text.count('custom_call_target="tpu_custom_call"') \
+        == (4 if window else 0)
+    assert lowered.as_text().count('kernel_name = "paged_attention"') \
+        == (4 if window else 0)
+    memory = compiled.memory_analysis()
+    needs = (memory.argument_size_in_bytes + memory.output_size_in_bytes
+             - memory.alias_size_in_bytes + memory.temp_size_in_bytes)
+    print(f"{program} at the window cell's shapes: needs "
+          f"{needs / 1e9:.3f} GB, {memory.temp_size_in_bytes / 1e9:.3f} GB "
+          f"of it temporaries")
+    assert needs < 15.75e9
+    assert memory.temp_size_in_bytes < state.pool_k.size * 2 // 2, (
+        f"{program}: {memory.temp_size_in_bytes / 1e9:.2f} GB of "
+        "temporaries is a layer of a pool or more")
+    assert memory.alias_size_in_bytes >= pools
+
+
+def test_one_product_over_all_experts_does_not_fit_a_256_token_chunk(
+        chip, monkeypatch):
+    """Why ``moe.held_experts_ffn`` states the tokens once an expert
+    above 64 of them: written as one product over all 64 experts, the
+    window cell's 256-token prefill chunk has the chip's compiler copy
+    the experts' stacked leaf transposed (3.75 GB), and the program
+    needs 15.82 GB where the chip has 15.75. When this compiles, the
+    fork and ``_ONE_PRODUCT_TOKENS`` can go."""
+    from kvedge_tpu.models import moe
+
+    monkeypatch.setattr(moe, "_ONE_PRODUCT_TOKENS", 1 << 30)
+    # a chunk's trace is cached by its shapes, not by the constant
+    jax.clear_caches()
+    try:
+        _, _, _, lowered = _patterned_cell_program(
+            "prefill", chip, monkeypatch, "smallthinker-21ba3b.longmix")
+        with pytest.raises(Exception, match="RESOURCE_EXHAUSTED"):
+            lowered.compile()
+    finally:
+        jax.clear_caches()
 
 
 def test_scale_budget_case_sits_on_the_budget():
