@@ -193,8 +193,24 @@ class ModelSpec:
     # ``head_dim`` is the attention heads' size (0 = d_model / n_heads),
     # ``attention_gate`` an output gate on the attention layers,
     # ``untied_head`` a head that is not the embedding.
+    # ``dense_layers`` leading layers (counted in ``n_layers``) come
+    # before the periods with a gated MLP of ``dense_ff`` in the experts'
+    # place and the mixer the pattern, continued backwards, gives them.
+    # ``router_score`` ("" = "softmax"; "sigmoid": each expert scored
+    # alone, the gates the picked scores over their sum times
+    # ``router_scale``, 0.0 = 1; ``router_bias``: a choice bias an
+    # expert, drawn with the tree). ``qk_norm``: an RMSNorm of each
+    # head's q and k; ``norm_after``: a sublayer's norm on its output,
+    # none on its input.
     # Served by ``serving = "paged"`` on one device, nothing else.
     layer_pattern: tuple = ()
+    dense_layers: int = 0
+    dense_ff: int = 0
+    router_score: str = ""  # "" = "softmax"
+    router_bias: bool = False
+    router_scale: float = 0.0
+    qk_norm: bool = False
+    norm_after: bool = False
     ssm_heads: int = 0
     ssm_head_dim: int = 0
     ssm_state: int = 0
@@ -225,19 +241,24 @@ class ModelSpec:
         "ssm_heads", "ssm_head_dim", "ssm_state", "ssm_conv", "ssm_chunk",
         "experts_held", "expert_first", "shared_ff",
         "ssm_gate_rank", "head_dim", "attention_window",
+        "dense_layers", "dense_ff",
     )
     _PATTERN_BOOLS = ("ffn_gated", "attention_gate", "untied_head",
-                      "router_before_mixer")
+                      "router_before_mixer", "router_bias", "qk_norm",
+                      "norm_after")
     _PATTERN_FLOATS = (
         "embedding_multiplier", "residual_multiplier",
         "attention_multiplier", "logits_scaling", "norm_eps",
+        "router_scale",
     )
     _PATTERN_KEYS = _PATTERN_INTS + _PATTERN_BOOLS + _PATTERN_FLOATS
     # Keys a document states only where they are set: a block without
     # them keeps the document it had before they existed.
     _PATTERN_LATER = ("ssm_gate_rank", "head_dim", "attention_gate",
                       "untied_head", "attention_window",
-                      "router_before_mixer")
+                      "router_before_mixer", "dense_layers", "dense_ff",
+                      "router_bias", "router_scale", "qk_norm",
+                      "norm_after")
 
     def validate(self) -> None:
         if self.preset not in _VALID_PRESETS:
@@ -285,10 +306,16 @@ class ModelSpec:
         if self.rope_theta < 0:
             raise RuntimeConfigError(
                 "[model] rope_theta must be >= 0 (0 = 10,000)")
+        if self.router_score not in ("", "softmax", "sigmoid"):
+            raise RuntimeConfigError(
+                "[model] router_score must be \"softmax\" or \"sigmoid\" "
+                f"(\"\" = softmax), got {self.router_score!r}")
         if not self.layer_pattern:
             stray = [k for k in self._PATTERN_KEYS if getattr(self, k)]
             if self.ffn_activation:
                 stray.append("ffn_activation")
+            if self.router_score:
+                stray.append("router_score")
             if stray or not self.rotary:
                 raise RuntimeConfigError(
                     "[model] " + ", ".join(stray or ["rotary = false"])
@@ -693,6 +720,7 @@ class RuntimeConfig:
                     rotary=bool(model_doc.get("rotary", True)),
                     ffn_activation=str(
                         model_doc.get("ffn_activation", "")),
+                    router_score=str(model_doc.get("router_score", "")),
                     rope_theta=float(model_doc.get("rope_theta", 0.0)),
                     **{key: bool(model_doc.get(key, False))
                        for key in ModelSpec._PATTERN_BOOLS},
@@ -1242,6 +1270,8 @@ class RuntimeConfig:
                  f"rotary = {str(m.rotary).lower()}"]
         if m.ffn_activation:
             lines.append(f"ffn_activation = {_toml_str(m.ffn_activation)}")
+        if m.router_score:
+            lines.append(f"router_score = {_toml_str(m.router_score)}")
         for key in m._PATTERN_KEYS:
             value = getattr(m, key)
             if key in m._PATTERN_LATER and not value:

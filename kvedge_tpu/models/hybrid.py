@@ -16,6 +16,20 @@ several devices refuse a model with a ``layer_pattern``. The equations
     logits = norm(x) @ E.T / s          (``head.T`` for ``E.T`` where the
                                          tree has a head of its own)
 
+With ``cfg.norm_after`` a sublayer's norm is on its output and its
+input is the stream as it stands: ``x = x + r * norm(Mixer(x))``, ``x =
+x + r * norm(Routed(x) + Shared(x))``. ``cfg.dense_layers`` leading
+layers run before the periods, unscanned, through the same two
+functions as a period's layers (:func:`run_layers`): their mixer is the
+pattern's, continued backwards (``cfg.leading_kinds``), their
+feed-forward a gated MLP of ``cfg.dense_ff`` with no router
+(``params["leading"]`` and ``params["dense"]``, stacked over them).
+With ``cfg.qk_norm`` an attention layer of either kind has gains
+``q_norm`` and ``k_norm`` [Dh] for an RMSNorm of each head's q and k;
+with ``cfg.router_score`` "sigmoid" the router is moe._route's sigmoid
+form, ``router_bias`` [E] float32 its choice bias where the tree has
+one.
+
 ``Mixer`` is the pattern's recurrent kind, models/ssm.py's Mamba-2
 mixer ("mamba") or models/delta.py's delta rule ("delta"; a pattern
 holds one of the two), or kvcache's paged attention (no rotary when
@@ -92,9 +106,13 @@ _LEAVES = {
     ("delta", "dt_bias"): 20, ("delta", "w_out"): 21,
     ("attention", "w_gate"): 22, "head": 23,
     ("window", "w_qkv"): 24, ("window", "w_out"): 25,
+    ("dense", "w_in"): 26, ("dense", "w_out"): 27,
+    ("ffn", "router_bias"): 28,
 }
 
 _KINDS = ("mamba", "delta", "attention", "window", "ffn")
+# The leading dense layers' two trees, stacked over those layers alone.
+_LEADING = ("leading", "dense")
 # The kinds whose mixer is paged attention, each over a pool of its own.
 ATTENTION_KINDS = ("attention", "window")
 # A recurrent kind's mixer, its answer to whether a trace's one-token
@@ -105,10 +123,13 @@ _MIXERS = {"mamba": (ssm.mamba_mixer, ssm.step_in_kernel, "kvedge/ssm"),
 
 
 def layers_of(cfg: TransformerConfig, kind: str) -> list[int]:
-    """Global indices of the layers of ``kind`` ("ffn": every layer)."""
-    period = len(cfg.layer_pattern)
-    return [i for i in range(cfg.n_layers)
-            if kind == "ffn" or cfg.layer_pattern[i % period] == kind]
+    """Global indices of the periods' layers of ``kind`` ("ffn": every
+    one of them; "leading", "dense": the layers before them)."""
+    period, lead = len(cfg.layer_pattern), cfg.dense_layers
+    if kind in ("leading", "dense"):
+        return list(range(lead))
+    return [i for i in range(lead, cfg.n_layers)
+            if kind == "ffn" or cfg.layer_pattern[(i - lead) % period] == kind]
 
 
 def _normal(scale):
@@ -177,6 +198,24 @@ def _recipes(cfg: TransformerConfig) -> dict:
             ("window", "w_out"): ((h * dh, d),
                                   _normal((h * dh) ** -0.5), False),
         })
+    if cfg.dense_layers:
+        kind, df = cfg.leading_kinds[0], cfg.dense_ff
+        out.update({
+            # A leading layer's mixer draws as its kind's does, from
+            # the kind's leaf numbers and its own layer index.
+            ("leading", leaf): out[kind, leaf]
+            for leaf in ("w_qkv", "w_out", "w_gate") if (kind, leaf) in out})
+        out.update({
+            ("dense", "w_in"): ((d, 2 * df), _normal(d ** -0.5), False),
+            ("dense", "w_out"): ((df, d), _normal(df ** -0.5), False),
+        })
+    if cfg.router_bias:
+        # Small against the scores' spread (a sigmoid of logits of the
+        # stream's scale), large against the gaps between neighbouring
+        # scores near the top: it changes picks (tests/test_exaone_block.py
+        # counts them).
+        out[("ffn", "router_bias")] = ((cfg.n_experts,), _normal(0.01),
+                                       False)
     out.update({
         ("ffn", "router"): ((d, cfg.n_experts), _normal(d ** -0.5), False),
         ("ffn", "experts_in"): ((cfg.held_experts, d, gate * f),
@@ -195,7 +234,7 @@ def _recipes(cfg: TransformerConfig) -> dict:
 
 # Leaves the equations read in float32 (transformer.serving_params
 # names the ones held in the compute dtype).
-_FLOAT32 = frozenset({"router", "A_log", "dt_bias"})
+_FLOAT32 = frozenset({"router", "router_bias", "A_log", "dt_bias"})
 
 
 def init_params(key, cfg: TransformerConfig) -> dict:
@@ -206,11 +245,17 @@ def init_params(key, cfg: TransformerConfig) -> dict:
         raise ValueError("hybrid.init_params draws a patterned block's "
                          "tree: cfg.layer_pattern is empty")
     dtype = jnp.dtype(cfg.dtype)
-    periods = cfg.n_layers // len(cfg.layer_pattern)
+    periods = cfg.periods
     first = cfg.expert_first
 
+    def stacked_over(kind):
+        """The dimensions a kind's leaves are stacked over."""
+        n = len(layers_of(cfg, kind))
+        return (n,) if kind in _LEADING else (periods, n // periods)
+
     def stacked(kind, leaf, shape, draw, per_expert):
-        leaf_key = jax.random.fold_in(key, _LEAVES[kind, leaf])
+        drawn_as = cfg.leading_kinds[0] if kind == "leading" else kind
+        leaf_key = jax.random.fold_in(key, _LEAVES[drawn_as, leaf])
         layers = jnp.asarray(layers_of(cfg, kind), jnp.int32)
         to = jnp.float32 if leaf in _FLOAT32 else dtype
 
@@ -224,15 +269,14 @@ def init_params(key, cfg: TransformerConfig) -> dict:
 
         flat = jax.jit(lambda ls: lax.map(one_layer, ls))(layers)
         return jax.block_until_ready(
-            flat.reshape(periods, len(layers) // periods, *shape))
+            flat.reshape(*stacked_over(kind), *shape))
 
-    params: dict = {kind: {} for kind in _KINDS}
+    params: dict = {kind: {} for kind in _KINDS + _LEADING}
     for (kind, leaf), recipe in _recipes(cfg).items():
         params[kind][leaf] = stacked(kind, leaf, *recipe)
 
     def ones(kind, leaf, width):
-        n = len(layers_of(cfg, kind)) // periods
-        params[kind][leaf] = jnp.ones((periods, n, width), jnp.float32)
+        params[kind][leaf] = jnp.ones((*stacked_over(kind), width), jnp.float32)
 
     if params["mamba"]:
         ones("mamba", "D", cfg.ssm_heads)
@@ -241,10 +285,15 @@ def init_params(key, cfg: TransformerConfig) -> dict:
     if params["delta"]:
         ones("delta", "norm", cfg.ssm_head_dim)
         ones("delta", "ln", cfg.d_model)
-    for kind in ATTENTION_KINDS:
+    for kind in ATTENTION_KINDS + ("leading",):
         if params[kind]:
             ones(kind, "ln", cfg.d_model)
+            if cfg.qk_norm:
+                ones(kind, "q_norm", cfg.d_head)
+                ones(kind, "k_norm", cfg.d_head)
     ones("ffn", "ln", cfg.d_model)
+    if cfg.dense_layers:
+        ones("dense", "ln", cfg.d_model)
     def table(leaf, scale):
         return jax.jit(
             lambda k: (jax.random.normal(k, (cfg.vocab, cfg.d_model),
@@ -269,21 +318,33 @@ def feed_forward(cfg: TransformerConfig, x, w: dict, live, routed_on=None):
     """``x + r * (Routed(norm(x)) + Shared(norm(x)))`` over x [R, Q, D]
     and the picks of the ``live`` rows' tokens (held_experts_ffn).
     ``routed_on`` [R, Q, D], where given, is what the router reads
-    (``cfg.router_before_mixer``: the mixer's normed input)."""
+    (``cfg.router_before_mixer``: the mixer's normed input). A tree
+    with no ``router`` is a leading dense layer's: one gated MLP, and
+    no picks (None). ``cfg.norm_after``: the norm is on the sum, not on
+    ``x``."""
     rows, q_len, d = x.shape
-    with jax.named_scope("kvedge/experts"):
-        h = _rmsnorm(x, w["ln"], cfg.norm_eps).reshape(rows * q_len, d)
-        out, picks = held_experts_ffn(
-            h, w["router"], w["experts_in"], w["experts_out"],
-            top_k=cfg.expert_top_k, first=cfg.expert_first,
-            gated=cfg.ffn_gated, renormalize=True,
-            live=None if live is None else jnp.repeat(live, q_len),
-            activation=cfg.ffn_activation,
-            routed_on=(None if routed_on is None
-                       else routed_on.reshape(rows * q_len, d)))
-        if "shared_in" in w:
-            out = out + _shared_expert(cfg, h, w["shared_in"],
-                                       w["shared_out"])
+    routed = "router" in w
+    with jax.named_scope("kvedge/experts" if routed else "kvedge/dense"):
+        h = (x if cfg.norm_after
+             else _rmsnorm(x, w["ln"], cfg.norm_eps)).reshape(rows * q_len, d)
+        if routed:
+            out, picks = held_experts_ffn(
+                h, w["router"], w["experts_in"], w["experts_out"],
+                top_k=cfg.expert_top_k, first=cfg.expert_first,
+                gated=cfg.ffn_gated, renormalize=True,
+                live=None if live is None else jnp.repeat(live, q_len),
+                activation=cfg.ffn_activation,
+                routed_on=(None if routed_on is None
+                           else routed_on.reshape(rows * q_len, d)),
+                score=cfg.router_score, bias=w.get("router_bias"),
+                scale=cfg.router_scale)
+            if "shared_in" in w:
+                out = out + _shared_expert(cfg, h, w["shared_in"],
+                                           w["shared_out"])
+        else:
+            out, picks = _shared_expert(cfg, h, w["w_in"], w["w_out"]), None
+        if cfg.norm_after:
+            out = _rmsnorm(out, w["ln"], cfg.norm_eps)
         r = jnp.asarray(cfg.residual_multiplier, x.dtype)
         return x + r * out.reshape(rows, q_len, d), picks
 
@@ -306,6 +367,8 @@ def run_layers(cfg: TransformerConfig, params: dict, x, pools, recurrent,
     """
     pattern = cfg.layer_pattern
     n_of = {kind: pattern.count(kind) for kind in ATTENTION_KINDS}
+    # A kind's leading layers hold the first places of its pool.
+    n_lead = {kind: cfg.leading_kinds.count(kind) for kind in ATTENTION_KINDS}
     n_recurrent = len(pattern) - sum(n_of.values())
     r = jnp.asarray(cfg.residual_multiplier, x.dtype)
 
@@ -337,51 +400,65 @@ def run_layers(cfg: TransformerConfig, params: dict, x, pools, recurrent,
                 a, (period, i) + (0,) * (a.ndim - 2),
                 (1, 1) + a.shape[2:]).reshape(a.shape[2:]), tree)
 
-    def body(carry, period):
+    def one_layer(carry, kind, w, ffn_of, layer):
+        """A layer of ``kind``, ``layer`` its place among the layers
+        that share its pool or state: mixer, then feed-forward over
+        the tree ``ffn_of()`` slices out (where it is used, as
+        :func:`at` says)."""
         x, pools, state, conv, picks = carry
+        h = x if cfg.norm_after else _rmsnorm(x, w["ln"], cfg.norm_eps)
+        if kind in ATTENTION_KINDS:
+            with jax.named_scope("kvedge/" + kind):
+                out, pool = attend(h, w, layer, pools[kind], kind)
+            pools = {**pools, kind: pool}
+        else:
+            mixer, in_kernel, scope = _MIXERS[kind]
+            with jax.named_scope(scope):
+                if in_kernel(cfg, slot, x.shape[1]):
+                    # A decode step on the chip: the mixer's kernel
+                    # works on the stacked state where it lies, so
+                    # there is nothing to take or put.
+                    out, state, new_tail = mixer(
+                        cfg, h, w, state, take(conv, layer), live,
+                        layer=layer)
+                else:
+                    out, new_state, new_tail = mixer(
+                        cfg, h, w, take(state, layer),
+                        take(conv, layer), live)
+                    state = put(state, layer, new_state)
+                conv = put(conv, layer, new_tail)
+        if cfg.norm_after:
+            out = _rmsnorm(out, w["ln"], cfg.norm_eps)
+        x = x + r * out
+        x, layer_picks = feed_forward(
+            cfg, x, ffn_of(), live, h if cfg.router_before_mixer else None)
+        if layer_picks is not None:
+            picks = picks + layer_picks
+        return x, pools, state, conv, picks
+
+    def body(carry, period):
         seen = dict.fromkeys(pattern, 0)
         for j, kind in enumerate(pattern):
-            w = at(weights[kind], period, seen[kind])
-            h = _rmsnorm(x, w["ln"], cfg.norm_eps)
-            if kind in ATTENTION_KINDS:
-                with jax.named_scope("kvedge/" + kind):
-                    out, pool = attend(
-                        h, w, period * n_of[kind] + seen[kind],
-                        pools[kind], kind)
-                pools = {**pools, kind: pool}
-            else:
-                layer = period * n_recurrent + seen[kind]
-                mixer, in_kernel, scope = _MIXERS[kind]
-                with jax.named_scope(scope):
-                    if in_kernel(cfg, slot, x.shape[1]):
-                        # A decode step on the chip: the mixer's kernel
-                        # works on the stacked state where it lies, so
-                        # there is nothing to take or put.
-                        out, state, new_tail = mixer(
-                            cfg, h, w, state, take(conv, layer), live,
-                            layer=layer)
-                    else:
-                        out, new_state, new_tail = mixer(
-                            cfg, h, w, take(state, layer),
-                            take(conv, layer), live)
-                        state = put(state, layer, new_state)
-                    conv = put(conv, layer, new_tail)
+            carry = one_layer(
+                carry, kind, at(weights[kind], period, seen[kind]),
+                functools.partial(at, weights["ffn"], period, j),
+                (period * n_of[kind] + (n_lead[kind] + seen[kind])
+                 if kind in ATTENTION_KINDS
+                 else period * n_recurrent + seen[kind]))
             seen[kind] += 1
-            x = x + r * out
-            x, layer_picks = feed_forward(
-                cfg, x, at(weights["ffn"], period, j), live,
-                h if cfg.router_before_mixer else None)
-            picks = picks + layer_picks
-        return (x, pools, state, conv, picks), None
+        return carry, None
 
-    periods = cfg.n_layers // len(pattern)
     weights = {kind: params.get(kind, {}) for kind in _KINDS}
+    carry = (x, pools, recurrent.get("ssm"), recurrent.get("conv"),
+             recurrent["picks"])
+    for i, kind in enumerate(cfg.leading_kinds):
+        carry = one_layer(
+            carry, kind,
+            jax.tree_util.tree_map(lambda a: a[i], params["leading"]),
+            lambda: jax.tree_util.tree_map(lambda a: a[i], params["dense"]),
+            i)
     (x, pools, state, conv, picks), _ = lax.scan(
-        body,
-        (x, pools, recurrent.get("ssm"), recurrent.get("conv"),
-         recurrent["picks"]),
-        jnp.arange(periods, dtype=jnp.int32),
-    )
+        body, carry, jnp.arange(cfg.periods, dtype=jnp.int32))
     if state is None:
         return x, pools, {"picks": picks}
     return x, pools, {"ssm": state, "conv": conv, "picks": picks}

@@ -130,10 +130,19 @@ _PAGED_KERNEL_AUTO_MIN_SEQ = 2048
 
 
 def _use_paged_kernel(cfg: TransformerConfig, page_size: int,
-                      width: int, max_pages: int | None = None) -> bool:
+                      width: int, max_pages: int | None = None) -> str:
     """Resolve ``cfg.paged_attention`` at trace time (page_size/width/
     max_pages are static pool-shape facts under jit), for one kind of
-    attention layer: ``max_pages`` is the width of that kind's table (a
+    attention layer, to the kernel's form or "" for the gather: "whole"
+    where the table's scores and V image fit the kernel's scratch,
+    "blocked" where only its scores do and the V pages stream through
+    in blocks (ops/paged_attention.decode_scratch_form: a shape takes
+    one form, and no option chooses). So a block whose full layers'
+    table does not fit whole (64 heads over 8,192 positions of a
+    1,024-wide pool) keeps working in proportion to live tokens, where
+    it used to fall to the gather, which reads the cap; its window
+    layers, a few pages a row, take the whole form beside it.
+    In detail: ``max_pages`` is the width of that kind's table (a
     full layer's spans ``max_seq``, a window layer's the window and one
     advance: PagedKVCache.window_cap), so a block with both kinds
     settles each apart, by its own scratch. "auto" picks the
@@ -184,21 +193,22 @@ def _use_paged_kernel(cfg: TransformerConfig, page_size: int,
     trace time — one process whose arrays span several chips — is
     settled before the pool is built, by
     :func:`settle_paged_attention`."""
-    if cfg.paged_attention == "kernel":
-        return True
     if cfg.paged_attention == "gather":
-        return False
-    from kvedge_tpu.ops.paged_attention import decode_scratch_fits_vmem
+        return ""
+    from kvedge_tpu.ops.paged_attention import decode_scratch_form
 
     if max_pages is None:
         max_pages = -(-cfg.max_seq // max(page_size, 1))
-    return (jax.default_backend() == "tpu"
+    form = decode_scratch_form(max_pages, page_size, width, cfg.n_heads)
+    if cfg.paged_attention == "kernel":
+        return form or "whole"  # what fits neither refuses at the call
+    if (jax.default_backend() == "tpu"
             and jax.process_count() == 1
             and cfg.max_seq >= _PAGED_KERNEL_AUTO_MIN_SEQ
             and page_size % 128 == 0
-            and width % 128 == 0
-            and decode_scratch_fits_vmem(
-                max_pages, page_size, width, cfg.n_heads))
+            and width % 128 == 0):
+        return form
+    return ""
 
 
 def settle_paged_attention(cfg: TransformerConfig,
@@ -1895,7 +1905,8 @@ def _paged_attend_layer(cfg: TransformerConfig, state: PagedState, x,
 
 def _paged_attention(cfg: TransformerConfig, state: PagedState, normed,
                      w_qkv, w_out, layer, pools, q_positions, slot=None,
-                     write_mask=None, w_gate=None, window: int = 0):
+                     write_mask=None, w_gate=None, window: int = 0,
+                     qk_norm=None):
     """The attention mixer of every paged program, over normed
     activations [B, Q, D]; q_positions: [B, Q] absolute
     positions of the new tokens. ``pools`` is the WHOLE pool
@@ -1935,6 +1946,9 @@ def _paged_attention(cfg: TransformerConfig, state: PagedState, normed,
         all_tables, all_first = state.tables, None
 
     q, k, v = split_qkv(cfg, normed @ w_qkv.astype(dtype))
+    if qk_norm is not None:
+        q = _rmsnorm(q, qk_norm[0], cfg.norm_eps)
+        k = _rmsnorm(k, qk_norm[1], cfg.norm_eps)
 
     def gated(attended):
         if w_gate is None:
@@ -2018,9 +2032,11 @@ def _paged_attention(cfg: TransformerConfig, state: PagedState, normed,
             )
     else:
         scales_fit = True
-    if (kernel_eligible and scales_fit
-            and _use_paged_kernel(cfg, page, kv * dh,
-                                  max_pages=tables.shape[1])):
+    form = (_use_paged_kernel(cfg, page, kv * dh, max_pages=tables.shape[1])
+            if kernel_eligible and scales_fit else "")
+    if form == "blocked" and quantized and cfg.paged_attention != "kernel":
+        form = ""  # the blocked form has no int8 variant: the gather
+    if form:
         # Single-query decode (steps and windows): attention directly
         # over the block table — K/V pages stream up to each row's LIVE
         # length out of pool[layer] through the Pallas kernel; neither
@@ -2040,6 +2056,7 @@ def _paged_attention(cfg: TransformerConfig, state: PagedState, normed,
             interpret=pallas_interpret(),
             score_scale=cfg.attention_multiplier or None,
             **(dict(first=first, window=window) if window else {}),
+            **(dict(blocked=True) if form == "blocked" else {}),
         )  # [B, H, Dh], kv-major head layout — same as the einsum's
         out = gated(att.reshape(batch, 1, h * dh)) @ w_out.astype(dtype)
     else:
@@ -2131,7 +2148,8 @@ def _run_paged_pattern(cfg, params, state, x, q_positions, slot,
         return _paged_attention(
             cfg, state, normed, w["w_qkv"], w["w_out"], layer, pool,
             q_positions, slot, write_mask, w.get("w_gate"),
-            window=cfg.attention_window if kind == "window" else 0)
+            window=cfg.attention_window if kind == "window" else 0,
+            qk_norm=(w["q_norm"], w["k_norm"]) if cfg.qk_norm else None)
 
     pools = {"attention": (state.pool_k, state.pool_v, state.scale_k,
                            state.scale_v)}

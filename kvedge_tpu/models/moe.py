@@ -87,7 +87,8 @@ def expert_capacity(n_tokens: int, n_experts: int,
     return max(1, math.ceil(n_tokens * capacity_factor / n_experts))
 
 
-def _route(x, router_w, top_k: int, renormalize: bool | None = None):
+def _route(x, router_w, top_k: int, renormalize: bool | None = None,
+           score: str = "softmax", bias=None, scale: float = 1.0):
     """Shared routing decision. Returns (probs [N, E], idx [N, k],
     gates [N, k] fp32).
 
@@ -100,9 +101,22 @@ def _route(x, router_w, top_k: int, renormalize: bool | None = None):
     the two cannot disagree about gating. The logits are a float32
     product in full: a token's picks are read off them, and the
     device's one-pass float32 product moves near-ties.
+
+    ``score`` "sigmoid" is the router that scores each expert alone:
+    ``s = sigmoid(logits)``, the picks the ``top_k`` largest of ``s +
+    bias`` (``bias`` [E] float32, where the tree has one: it moves the
+    choice and never a gate), the gates the picked ``s`` over their sum
+    times ``scale``.
     """
     logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
                      precision=lax.Precision.HIGHEST)
+    if score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)                     # [N, E]
+        _, topk_idx = lax.top_k(
+            scores if bias is None else scores + bias, top_k)
+        picked = jnp.take_along_axis(scores, topk_idx, axis=-1)
+        gates = picked / jnp.sum(picked, axis=-1, keepdims=True) * scale
+        return scores, topk_idx, gates
     probs = jax.nn.softmax(logits, axis=-1)                 # [N, E]
     topk_probs, topk_idx = lax.top_k(probs, top_k)          # [N, k]
     if top_k > 1 if renormalize is None else renormalize:
@@ -209,7 +223,9 @@ def ffn_activation(up, gated: bool, gate: str = "silu"):
 def held_experts_ffn(x, router_w, w_in, w_out, *, top_k: int,
                      first: int = 0, gated: bool = False,
                      renormalize: bool | None = None, live=None,
-                     activation: str = "silu", routed_on=None):
+                     activation: str = "silu", routed_on=None,
+                     score: str = "softmax", bias=None,
+                     scale: float = 1.0):
     """The routed experts held here, for every token: the serving path.
 
     x: [N, D]; router_w [D, E] fp32 over ALL ``E`` routed experts;
@@ -220,6 +236,7 @@ def held_experts_ffn(x, router_w, w_in, w_out, *, top_k: int,
     Eh - 1``. ``routed_on`` [N, D], where given, is what the router
     reads in ``x``'s place (a block whose router sits before the mixer
     hands the mixer's normed input; the experts still take ``x``).
+    ``score``, ``bias`` and ``scale`` are the router's (:func:`_route`).
     Each token
     routes over all ``E`` (:func:`_route`), droplessly, and the result
     is the part of its gated sum that the held experts give: with
@@ -244,8 +261,10 @@ def held_experts_ffn(x, router_w, w_in, w_out, *, top_k: int,
     ``Eh`` (PERF.md section 6 has both timed at the benchmark's
     widths).
     """
-    _, topk_idx, gates = _route(x if routed_on is None else routed_on,
-                                router_w, top_k, renormalize)
+    with jax.named_scope("kvedge/router"):
+        _, topk_idx, gates = _route(
+            x if routed_on is None else routed_on, router_w, top_k,
+            renormalize, score, bias, scale)
     held = w_in.shape[0]
     dtype = x.dtype
     # [N, k, Eh]: pick j of token n is held expert e.

@@ -3124,10 +3124,11 @@ class PagedGenerationServer:
             out["expert_picks_by_expert"] = [int(n) for n in picks[2:-1]]
             # (layer, held expert, step) triples in which the expert
             # got a live row's pick, of the ``expert_reads_per_step``
-            # (layers x held experts) matrices every step reads.
+            # (routed layers x held experts) matrices every step reads.
             out["expert_touched_total"] = int(picks[-1])
             out["expert_reads_per_step"] = (
-                self._cfg.n_layers * self._cfg.held_experts)
+                (self._cfg.n_layers - self._cfg.dense_layers)
+                * self._cfg.held_experts)
         if self._autotune is not None:
             # Online window controller (SERVING.md rung 26): the
             # current pick and its EWMA inputs — R (host turnaround

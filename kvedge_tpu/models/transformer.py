@@ -147,7 +147,26 @@ class TransformerConfig:
     # gives an attention layer an output gate (``w_gate``) and
     # ``untied_head`` the tree a head of its own (``head``): the layer
     # bodies read both from the tree they are handed.
+    # ``dense_layers`` leading layers come before the periods, each with
+    # a gated MLP of ``dense_ff`` where the others have experts and with
+    # the mixer the pattern, continued backwards, gives its place
+    # (leading layer l is of kind ``layer_pattern[(l - dense_layers) %
+    # period]``; they are of one kind): ``n_layers`` counts them too.
+    # ``router_score`` "sigmoid" scores each expert alone: the picks are
+    # the ``expert_top_k`` largest of score plus the tree's
+    # ``router_bias`` (where ``router_bias`` says it has one), the gates
+    # the picked scores over their sum, times ``router_scale``.
+    # ``qk_norm`` gives each head's q and k an RMSNorm of their own
+    # (before rotary); ``norm_after`` puts a sublayer's norm on its
+    # output, ``x + norm(f(x))``, and none on its input.
     layer_pattern: tuple = ()
+    dense_layers: int = 0
+    dense_ff: int = 0
+    router_score: str = "softmax"
+    router_bias: bool = False
+    router_scale: float = 1.0
+    qk_norm: bool = False
+    norm_after: bool = False
     ssm_heads: int = 0      # recurrent heads ...
     ssm_head_dim: int = 0   # ... of this many (value) channels each
     ssm_state: int = 0      # state size N per channel (key channels)
@@ -179,22 +198,38 @@ class TransformerConfig:
     norm_eps: float = 1e-6
 
     @property
+    def periods(self) -> int:
+        """Whole periods of ``layer_pattern`` behind the leading dense
+        layers."""
+        return (self.n_layers - self.dense_layers) // len(self.layer_pattern)
+
+    @property
+    def leading_kinds(self) -> tuple:
+        """The mixer kind of each leading dense layer: the pattern
+        continued backwards from the first period."""
+        period = len(self.layer_pattern)
+        return tuple(self.layer_pattern[(i - self.dense_layers) % period]
+                     for i in range(self.dense_layers))
+
+    def layers_of_kind(self, kind: str) -> int:
+        return (self.periods * self.layer_pattern.count(kind)
+                + self.leading_kinds.count(kind))
+
+    @property
     def kv_layers(self) -> int:
         """Layers that keep keys and values in the page pool: every
         layer of the plain block, the full attention layers of a
         patterned one (its window layers have a pool of their own)."""
         if not self.layer_pattern:
             return self.n_layers
-        return (self.n_layers // len(self.layer_pattern)
-                * self.layer_pattern.count("attention"))
+        return self.layers_of_kind("attention")
 
     @property
     def window_layers(self) -> int:
         """Layers that keep keys and values in the window layers' pool."""
         if not self.layer_pattern:
             return 0
-        return (self.n_layers // len(self.layer_pattern)
-                * self.layer_pattern.count("window"))
+        return self.layers_of_kind("window")
 
     @property
     def ssm_layers(self) -> int:
@@ -289,6 +324,16 @@ class TransformerConfig:
                 "attention_gate, untied_head, attention_window and "
                 "rotary = false belong to a patterned block: set "
                 "layer_pattern")
+        else:
+            stray = [name for name, default in (
+                ("dense_layers", 0), ("dense_ff", 0),
+                ("router_score", "softmax"), ("router_bias", False),
+                ("router_scale", 1.0), ("qk_norm", False),
+                ("norm_after", False)) if getattr(self, name) != default]
+            if stray:
+                raise ValueError(
+                    ", ".join(stray) + " belong to a patterned block: "
+                    "set layer_pattern")
         if self.rope_theta <= 0:
             raise ValueError("rope_theta, the rotary base, must be > 0")
         if self.n_experts:
@@ -373,10 +418,47 @@ class TransformerConfig:
                 "layer_pattern holds one recurrent kind, 'mamba' or "
                 "'delta': a slot's state is one array a layer, sized by "
                 "the kind")
-        if self.n_layers % len(self.layer_pattern):
+        if self.dense_layers < 0 or bool(self.dense_layers) != bool(
+                self.dense_ff):
             raise ValueError(
-                f"n_layers {self.n_layers} must be whole periods of "
+                "dense_layers, the leading layers with a gated MLP in "
+                "the experts' place, and dense_ff, its width, go "
+                f"together: dense_layers = {self.dense_layers} with "
+                f"dense_ff = {self.dense_ff}")
+        if (self.n_layers - self.dense_layers) % len(self.layer_pattern) \
+                or self.n_layers <= self.dense_layers:
+            raise ValueError(
+                f"n_layers {self.n_layers} must be "
+                + (f"dense_layers ({self.dense_layers}) and "
+                   if self.dense_layers else "")
+                + f"whole periods of "
                 f"layer_pattern ({len(self.layer_pattern)} layers)")
+        if self.dense_layers and not self.ffn_gated:
+            raise ValueError(
+                "dense_layers: a leading layer's feed-forward is a gated "
+                "MLP; set ffn_gated")
+        lead = set(self.leading_kinds)
+        if len(lead) > 1 or not lead <= {"attention", "window"}:
+            raise ValueError(
+                "dense_layers: the leading layers are attention layers "
+                "of one kind ('attention' or 'window', by the pattern "
+                f"continued backwards), got {list(self.leading_kinds)}")
+        if self.router_score not in ("softmax", "sigmoid"):
+            raise ValueError(
+                "router_score must be 'softmax' or 'sigmoid', got "
+                f"{self.router_score!r}")
+        if self.router_score == "softmax" and (
+                self.router_bias or self.router_scale != 1.0):
+            raise ValueError(
+                "router_bias and router_scale belong to router_score = "
+                "'sigmoid': the softmax router's gates are the softmax "
+                "over the picked logits")
+        if self.router_scale <= 0:
+            raise ValueError("router_scale must be > 0")
+        if self.qk_norm and not kinds & {"attention", "window"}:
+            raise ValueError(
+                "qk_norm norms an attention layer's q and k: "
+                "layer_pattern holds no 'attention' or 'window' layer")
         if self.recurrent_kind and not (
                 self.ssm_heads and self.ssm_head_dim and self.ssm_state
                 and self.ssm_conv > 1 and self.ssm_chunk > 0):

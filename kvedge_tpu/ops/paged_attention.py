@@ -81,6 +81,24 @@ mask is the gather's, ``q_pos - window < key_pos <= q_pos``: only the
 oldest page's leading columns fall to its lower bound. A call with no
 window is handed no ``first`` and traces none of this.
 
+**The blocked form** (``blocked=True``, :func:`_decode_blocked_kernel`,
+a call named ``paged_attention_blocked``) is for a table whose V image
+does not fit the scratch whole (64 heads over 8,192 positions of a
+1,024-wide pool: 2 MiB of scores, 16 MiB of image). Phase 1 streams the
+row's K pages only and parks the masked, rounded scores whole, as
+above; the softmax runs over the whole row, as above, into a scratch of
+weights in the compute dtype; then the row's V pages stream through the
+same landing pads, block by block, and each live page's ``w[:, page] @
+V_page`` is summed in float32. Every K and V page of a live row is
+still read from HBM once, a dead row still costs nothing, and behind a
+row's last V block the next live row's first K block is in flight. What
+differs from the whole form is the order of the float32 additions in
+the V contraction (a page's partial products, then the pages), nothing
+else: scores and weights are the gather's bit for bit, the output
+agrees within float32 summation order. Which form a table takes is
+static, by its shape (:func:`decode_scratch_form`); an int8 pool has no
+blocked form.
+
 The serving stack selects this kernel per ``TransformerConfig
 .paged_attention`` ("auto" = kernel on TPU at long-context caps,
 einsum gather elsewhere); the verify pass (multi-query) and prefill
@@ -131,12 +149,13 @@ def scales_fit_vmem(rows: int, kv_heads: int) -> bool:
 
 
 def block_pages(max_pages: int, page: int, width: int,
-                itemsize: int = 2) -> int:
+                itemsize: int = 2, kinds: int = 2) -> int:
     """Pages of one fetched block: as many as the landing pads' share
-    of the scratch budget holds twice over (two slots, K and V) at this
-    page's bytes, at most ``_MAX_BLOCK_PAGES`` and a row's cap, at
-    least one. 8 at the 64 KB pages of a [128, 256] bf16 pool."""
-    fit = _PAD_VMEM_BUDGET // (4 * page * width * itemsize)
+    of the scratch budget holds twice over (two slots, of ``kinds``
+    kinds of page: K and V) at this page's bytes, at most
+    ``_MAX_BLOCK_PAGES`` and a row's cap, at least one. 8 at the 64 KB
+    pages of a [128, 256] bf16 pool."""
+    fit = _PAD_VMEM_BUDGET // (2 * kinds * page * width * itemsize)
     return max(1, min(fit, _MAX_BLOCK_PAGES, max_pages))
 
 
@@ -155,6 +174,88 @@ def decode_scratch_fits_vmem(max_pages: int, page: int, width: int,
             + s_cap * width * 2      # V image, compute dtype (<= 2 B)
             + pads)
     return need <= _SCRATCH_VMEM_BUDGET
+
+
+def blocked_block_pages(max_pages: int, page: int, width: int,
+                        itemsize: int = 2) -> int:
+    """Pages of one fetched block of the blocked form: its landing pads
+    are two slots of one kind of page (K, then V), so a block is twice
+    as deep as :func:`block_pages`' at the same budget."""
+    return block_pages(max_pages, page, width, itemsize, kinds=1)
+
+
+def blocked_scratch_fits_vmem(max_pages: int, page: int, width: int,
+                              n_heads: int) -> bool:
+    """Whether the blocked form's VMEM scratch fits: the fp32 score
+    rows and the compute-dtype weights ([H, S_cap] each), the fp32
+    accumulator [H, width] and the landing pads of two blocks of pages
+    (:func:`blocked_block_pages`). 64 heads reach 16,384 positions, 28
+    heads of a 512-wide pool 32,768."""
+    s_cap = max_pages * page
+    pads = 2 * blocked_block_pages(max_pages, page, width) * page * width * 2
+    need = (n_heads * s_cap * (4 + 2)   # scores fp32, weights <= 2 B
+            + n_heads * width * 4       # the accumulator
+            + pads)
+    return need <= _SCRATCH_VMEM_BUDGET
+
+
+def decode_scratch_form(max_pages: int, page: int, width: int,
+                        n_heads: int) -> str:
+    """The form of the kernel a table of ``max_pages`` takes, by what
+    its scratch holds: "whole" where scores and V image fit
+    (:func:`decode_scratch_fits_vmem`), else "blocked" where scores and
+    weights do (:func:`blocked_scratch_fits_vmem`), else "" (the
+    gather). Static: a shape takes one form, and nothing chooses."""
+    if decode_scratch_fits_vmem(max_pages, page, width, n_heads):
+        return "whole"
+    if blocked_scratch_fits_vmem(max_pages, page, width, n_heads):
+        return "blocked"
+    return ""
+
+
+def _pages_of(pos_ref, first_ref, r, page: int, window: int):
+    """Row r's live pages; none where its position is negative. In lax
+    primitives, like the kernels' other bookkeeping: every jnp operator
+    on a traced scalar is a nested jit, and a kernel's body is traced
+    once for each decode-window program a server compiles or loads at
+    start-up."""
+    if window:
+        return jax.lax.select(
+            pos_ref[r] < 0, 0,
+            jax.lax.div(pos_ref[r] - first_ref[r] + page, page))
+    return jax.lax.max(jax.lax.div(pos_ref[r] + page, page), 0)
+
+
+def _next_live(pos_ref, r, rows):
+    """The first row after r that has pages, or ``rows``."""
+    return jax.lax.while_loop(
+        lambda x: jax.lax.bitwise_and(
+            x < rows, pos_ref[jax.lax.min(x, rows - 1)] < 0),
+        lambda x: x + 1, r + 1)
+
+
+def _page_scores(q2, kj, key0, first, q_pos, scale, *, dtype, window: int,
+                 divide: bool):
+    """A page's masked score columns [H, page] float32, the gather's
+    softmax input image: the fp32-accumulated dot, the round to the
+    compute dtype, the scale in that dtype (``divide``: by sqrt(Dh);
+    else times the block's multiplier), the mask on absolute positions
+    (``key0`` the page's place in its table, ``first`` the table's first
+    position under a window), the upcast."""
+    s32 = jax.lax.dot_general(
+        q2, kj,
+        dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )  # [H, page] — exact per-head scores (zero slots add nothing)
+    # Mirror the gather path's visible rounding: dtype scores, dtype
+    # scale division, then the fp32 upcast its softmax does.
+    s16 = s32.astype(dtype) / scale if divide else s32.astype(dtype) * scale
+    key_pos = key0 + jax.lax.broadcasted_iota(jnp.int32, s16.shape, 1)
+    if window:
+        key_pos = key_pos + first
+    s = jnp.where(visible(key_pos, q_pos, window), s16,
+                  jnp.finfo(dtype).min)
+    return s.astype(jnp.float32)
 
 
 def _decode_flat_kernel(tables_ref, pos_ref, layer_ref, *rest,
@@ -201,25 +302,10 @@ def _decode_flat_kernel(tables_ref, pos_ref, layer_ref, *rest,
     b = pl.program_id(0)
     rows = pl.num_programs(0)
     block = kbuf.shape[1]
-    # The rows' bookkeeping below is written in lax primitives where it
-    # could be in operators: every jnp operator on a traced scalar is a
-    # nested jit, and this body is traced once for each decode-window
-    # program a server compiles or loads at start-up.
-
-    def pages_of(r):
-        """Row r's live pages; none where its position is negative."""
-        if window:
-            return jax.lax.select(
-                pos_ref[r] < 0, 0,
-                jax.lax.div(pos_ref[r] - first_ref[r] + page, page))
-        return jax.lax.max(jax.lax.div(pos_ref[r] + page, page), 0)
-
-    def next_live(r):
-        """The first row after r that has pages, or ``rows``."""
-        return jax.lax.while_loop(
-            lambda x: jax.lax.bitwise_and(
-                x < rows, pos_ref[jax.lax.min(x, rows - 1)] < 0),
-            lambda x: x + 1, r + 1)
+    pages_of = functools.partial(_pages_of, pos_ref,
+                                 first_ref if window else None,
+                                 page=page, window=window)
+    next_live = functools.partial(_next_live, pos_ref, rows=rows)
 
     def block_dmas(r, i, slot, act):
         """Start (or wait for) the copies of block i of row r into
@@ -310,23 +396,10 @@ def _decode_flat_kernel(tables_ref, pos_ref, layer_ref, *rest,
                 # int8 -> fp32 (exact), * fp32 scale, round to dtype.
                 kj = (kj.astype(jnp.float32) * sk).astype(dtype)
                 vj = (vj.astype(jnp.float32) * sv).astype(dtype)
-            s32 = jax.lax.dot_general(
-                q2, kj,
-                dimension_numbers=(((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )  # [H, page] — exact per-head scores (zero slots add nothing)
-            # Mirror the gather path's visible rounding: dtype scores,
-            # dtype scale division, then the fp32 upcast its softmax does.
-            s16 = (s32.astype(dtype) / scale if score_scale is None
-                   else s32.astype(dtype) * scale)
-            key_pos = j * page + jax.lax.broadcasted_iota(
-                jnp.int32, s16.shape, 1
-            )
-            if window:
-                key_pos = key_pos + first_ref[b]
-            s = jnp.where(visible(key_pos, q_pos, window), s16,
-                          jnp.finfo(dtype).min)
-            scores[:, pl.ds(j * page, page)] = s.astype(jnp.float32)
+            scores[:, pl.ds(j * page, page)] = _page_scores(
+                q2, kj, j * page, first_ref[b] if window else None, q_pos,
+                scale, dtype=dtype, window=window,
+                divide=score_scale is None)
             vimg[pl.ds(j * page, page), :] = vj
             return carry
 
@@ -385,19 +458,168 @@ def _decode_flat_kernel(tables_ref, pos_ref, layer_ref, *rest,
     pl.when(n_pages > 0)(live_row)
 
 
+def _decode_blocked_kernel(tables_ref, pos_ref, layer_ref, *rest,
+                           page: int, width: int, dh: int, dtype,
+                           score_scale: float | None, window: int = 0):
+    """The blocked form (module docstring): one program per sequence;
+    the row's K pages, then its V pages, stream block by block through
+    ``buf`` [2, block, page, width], two slots of landing pads with a
+    DMA semaphore each (``sems`` [2]). A row makes as many K blocks as V
+    blocks, so every row's first K block lands in slot 0: that is how a
+    block started behind one row's last V block is waited for by the
+    next. ``scores`` [H, S_cap] fp32 and ``wts`` [H, S_cap] in the
+    compute dtype hold the row between the phases, ``acc`` [H, width]
+    fp32 the V contraction's sum."""
+    first_ref = None
+    if window:
+        first_ref, *rest = rest
+    q_ref, k_hbm, v_hbm, o_ref, buf, scores, wts, acc, sems = rest
+
+    b = pl.program_id(0)
+    rows = pl.num_programs(0)
+    block = buf.shape[1]
+    pages_of = functools.partial(_pages_of, pos_ref, first_ref,
+                                 page=page, window=window)
+
+    def block_dmas(hbm, r, i, slot, act):
+        """Start (or wait for) the copies of block i of row r's pages
+        in ``hbm`` into landing-pad slot ``slot``: its live pages only."""
+        first = i * block
+
+        def one(j, carry):
+            act(pltpu.make_async_copy(
+                hbm.at[layer_ref[0], tables_ref[r, j]],
+                buf.at[slot, j - first], sems.at[slot]))
+            return carry
+
+        jax.lax.fori_loop(
+            first, jax.lax.min(first + block, pages_of(r)), one, 0)
+
+    def start(hbm, r, i, slot):
+        block_dmas(hbm, r, i, slot, lambda copy: copy.start())
+
+    def wait(hbm, r, i, slot):
+        # Same refs and semaphore as the start: the descriptor
+        # identifies the transfer.
+        block_dmas(hbm, r, i, slot, lambda copy: copy.wait())
+
+    n_pages = pages_of(b)
+
+    def live_row():
+        q_pos = pos_ref[b]
+        n_blocks = jax.lax.div(n_pages + block - 1, block)
+        q2 = q_ref[0]  # [H, width], zero outside each head's own slot
+        scale = jnp.asarray(
+            dh ** 0.5 if score_scale is None else score_scale, dtype)
+        # Dead pages' score columns are never stored (the whole form's
+        # pre-fill, for the same softmax over the same padded row).
+        scores[...] = jnp.full(
+            scores.shape, jnp.finfo(dtype).min, jnp.float32)
+        after = _next_live(pos_ref, b, rows)
+
+        def pages_in(i, one_page):
+            first = i * block
+            jax.lax.fori_loop(first, jax.lax.min(first + block, n_pages),
+                              functools.partial(one_page, first), 0)
+
+        def k_block(i, carry):
+            slot = jax.lax.rem(i, 2)
+            last = i + 1 == n_blocks
+
+            # Fetched while this block computes: the next K block or,
+            # behind the last, the first V block, which then streams
+            # under the softmax.
+            @pl.when(jnp.logical_not(last))
+            def _():
+                start(k_hbm, b, i + 1, 1 - slot)
+
+            @pl.when(last)
+            def _():
+                start(v_hbm, b, 0, 1 - slot)
+
+            wait(k_hbm, b, i, slot)
+
+            def one_page(first, j, carry):
+                scores[:, pl.ds(j * page, page)] = _page_scores(
+                    q2, buf[slot, j - first], j * page,
+                    first_ref[b] if window else None, q_pos, scale,
+                    dtype=dtype, window=window, divide=score_scale is None)
+                return carry
+
+            pages_in(i, one_page)
+            return carry
+
+        jax.lax.fori_loop(0, n_blocks, k_block, 0)
+
+        # The gather's softmax on the assembled row: same function, same
+        # fp32 values, same reduced-axis length, the same rounding.
+        wts[...] = jax.nn.softmax(scores[...], axis=-1).astype(dtype)
+        acc[...] = jnp.zeros(acc.shape, jnp.float32)
+
+        def v_block(i, carry):
+            slot = jax.lax.rem(n_blocks + i, 2)
+            last = i + 1 == n_blocks
+
+            # Behind the last V block the next live row's first K block
+            # (into slot 0: 1 - slot there), so no row but the first
+            # waits for its first page.
+            @pl.when(jnp.logical_not(last))
+            def _():
+                start(v_hbm, b, i + 1, 1 - slot)
+
+            @pl.when(jax.lax.bitwise_and(last, after < rows))
+            def _():
+                start(k_hbm, after, 0, 1 - slot)
+
+            wait(v_hbm, b, i, slot)
+
+            def one_page(first, j, carry):
+                # Live pages only: a dead page's weights are zeros, and
+                # what its landing pad holds is anything.
+                acc[...] += jax.lax.dot_general(
+                    wts[:, pl.ds(j * page, page)], buf[slot, j - first],
+                    dimension_numbers=(((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                return carry
+
+            pages_in(i, one_page)
+            return carry
+
+        jax.lax.fori_loop(0, n_blocks, v_block, 0)
+        o_ref[0] = acc[...].astype(o_ref.dtype)  # head slots taken outside
+
+    @pl.when(b == 0)
+    def _():
+        # Nothing is in flight yet: the first live row's first K block.
+        first = _next_live(pos_ref, -1, rows)
+
+        @pl.when(first < rows)
+        def _():
+            start(k_hbm, first, 0, 0)
+
+    @pl.when(n_pages == 0)
+    def _():
+        # A dead row: no copy, no scratch, no phase 2.
+        o_ref[0] = jnp.zeros(o_ref.shape[1:], o_ref.dtype)
+
+    pl.when(n_pages > 0)(live_row)
+
+
 # Jitted for its trace cache alone, and inlined so that the enclosing
 # program is what it was: every decode-window program of a bucket (three
 # for each of seven buckets at the benchmark cell's start-up) calls this
 # with the same shapes, and tracing the kernel's body is the costliest
 # part of lowering one.
 @functools.partial(jax.jit,
-                   static_argnames=("interpret", "score_scale", "window"),
+                   static_argnames=("interpret", "score_scale", "window",
+                                    "blocked"),
                    inline=True)
 def paged_decode_attention(q, pool_k, pool_v, tables, q_positions,
                            layer, *, scale_k=None, scale_v=None,
                            interpret: bool = False,
                            score_scale: float | None = None,
-                           first=None, window: int = 0):
+                           first=None, window: int = 0,
+                           blocked: bool = False):
     """Decode attention over layer ``layer`` of a paged KV pool,
     block-table-indexed.
 
@@ -418,6 +640,9 @@ def paged_decode_attention(q, pool_k, pool_v, tables, q_positions,
     whose first entry holds position ``first[b]`` ([B] int32, multiples
     of the page): row b attends key positions ``max(first[b],
     q_positions[b] - window + 1)`` to ``q_positions[b]``.
+    ``blocked`` takes the blocked form (module docstring), for a table
+    whose V image does not fit the scratch: the same scores and
+    weights, the V contraction summed page by page.
     Returns [B, H, Dh], a live row's
     BIT-IDENTICAL to the gather path's decode attention. DMA cost and
     program time scale with the LIVE rows' page counts; the pool itself
@@ -453,11 +678,18 @@ def paged_decode_attention(q, pool_k, pool_v, tables, q_positions,
             f"j * page, which Mosaic requires tile-aligned), got "
             f"{page}; use paged_attention='gather' for this pool"
         )
-    if not decode_scratch_fits_vmem(max_pages, page, width, h) \
-            and not interpret:
+    if blocked and quantized:
+        raise ValueError(
+            "the paged decode kernel's blocked form has no int8 "
+            "variant; use paged_attention='gather' for this pool")
+    if not interpret and not (
+            blocked_scratch_fits_vmem if blocked
+            else decode_scratch_fits_vmem)(max_pages, page, width, h):
         raise ValueError(
             f"paged decode kernel scratch (fp32 scores [{h}, {s_cap}] "
-            f"+ V image [{s_cap}, {width}]) exceeds the VMEM budget; "
+            + ("and weights" if blocked
+               else f"+ V image [{s_cap}, {width}]")
+            + ") exceeds the VMEM budget; "
             f"use paged_attention='gather' for this pool geometry"
         )
 
@@ -476,34 +708,47 @@ def paged_decode_attention(q, pool_k, pool_v, tables, q_positions,
         pl.BlockSpec(memory_space=pl.ANY),  # pools stay in HBM;
         pl.BlockSpec(memory_space=pl.ANY),  # the kernel DMAs pages
     ]
-    block = block_pages(max_pages, page, width, pool_k.dtype.itemsize)
-    scratch = [
-        pltpu.VMEM((2, block, page, width), pool_k.dtype),
-        pltpu.VMEM((2, block, page, width), pool_v.dtype),
-        pltpu.VMEM((h, s_cap), jnp.float32),   # phase-2 score rows
-        pltpu.VMEM((s_cap, width), q.dtype),   # phase-2 V image
-        pltpu.SemaphoreType.DMA((2, 2)),
-        pltpu.SMEM((2,), jnp.int32),           # what a row leaves the next
-    ]
-    if quantized:
-        # The layer's scale arrays ride whole in VMEM (a few MB) and
-        # are indexed by page id — no extra DMA machinery.
-        in_specs = [q_spec,
-                    pl.BlockSpec(memory_space=pltpu.VMEM),
-                    pl.BlockSpec(memory_space=pltpu.VMEM),
-                    *pool_specs]
-        args = (q2,
-                scale_k[layer[0]].astype(jnp.float32),
-                scale_v[layer[0]].astype(jnp.float32), pool_k, pool_v)
+    in_specs = [q_spec, *pool_specs]
+    args = (q2, pool_k, pool_v)
+    if blocked:
+        block = blocked_block_pages(max_pages, page, width,
+                                    pool_k.dtype.itemsize)
+        scratch = [
+            pltpu.VMEM((2, block, page, width), pool_k.dtype),
+            pltpu.VMEM((h, s_cap), jnp.float32),   # score rows
+            pltpu.VMEM((h, s_cap), q.dtype),       # their softmax
+            pltpu.VMEM((h, width), jnp.float32),   # the V contraction
+            pltpu.SemaphoreType.DMA((2,)),
+        ]
+        kernel = functools.partial(_decode_blocked_kernel, page=page,
+                                   width=width, dh=dh, dtype=q.dtype,
+                                   score_scale=score_scale)
     else:
-        in_specs = [q_spec, *pool_specs]
-        args = (q2, pool_k, pool_v)
+        block = block_pages(max_pages, page, width, pool_k.dtype.itemsize)
+        scratch = [
+            pltpu.VMEM((2, block, page, width), pool_k.dtype),
+            pltpu.VMEM((2, block, page, width), pool_v.dtype),
+            pltpu.VMEM((h, s_cap), jnp.float32),   # phase-2 score rows
+            pltpu.VMEM((s_cap, width), q.dtype),   # phase-2 V image
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((2,), jnp.int32),       # what a row leaves the next
+        ]
+        if quantized:
+            # The layer's scale arrays ride whole in VMEM (a few MB) and
+            # are indexed by page id — no extra DMA machinery.
+            in_specs = [q_spec,
+                        pl.BlockSpec(memory_space=pltpu.VMEM),
+                        pl.BlockSpec(memory_space=pltpu.VMEM),
+                        *pool_specs]
+            args = (q2,
+                    scale_k[layer[0]].astype(jnp.float32),
+                    scale_v[layer[0]].astype(jnp.float32), pool_k, pool_v)
+        kernel = functools.partial(
+            _decode_flat_kernel, page=page, width=width, dh=dh,
+            dtype=q.dtype, quantized=quantized, score_scale=score_scale,
+        )
     scalars = (tables.astype(jnp.int32), q_positions.astype(jnp.int32),
                layer)
-    kernel = functools.partial(
-        _decode_flat_kernel, page=page, width=width, dh=dh,
-        dtype=q.dtype, quantized=quantized, score_scale=score_scale,
-    )
     if window:
         scalars += (first.astype(jnp.int32),)
         kernel = functools.partial(kernel, window=window)
@@ -525,7 +770,8 @@ def paged_decode_attention(q, pool_k, pool_v, tables, q_positions,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-        name="paged_attention",
+        # A capture tells the two forms apart by the call's name.
+        name="paged_attention_blocked" if blocked else "paged_attention",
     )(*args)
     # Each head's own Dh-slot of the [H, width] output.
     out = jnp.take_along_axis(

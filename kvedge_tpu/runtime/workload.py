@@ -197,6 +197,13 @@ def derive_model_config(cfg: RuntimeConfig, *, seq: int):
         ffn_gated=spec.ffn_gated,
         ffn_activation=spec.ffn_activation or "silu",
         router_before_mixer=spec.router_before_mixer,
+        dense_layers=spec.dense_layers,
+        dense_ff=spec.dense_ff,
+        router_score=spec.router_score or "softmax",
+        router_bias=spec.router_bias,
+        router_scale=spec.router_scale or 1.0,
+        qk_norm=spec.qk_norm,
+        norm_after=spec.norm_after,
         attention_window=spec.attention_window,
         rope_theta=spec.rope_theta or TransformerConfig.rope_theta,
         embedding_multiplier=spec.embedding_multiplier or 1.0,

@@ -64,7 +64,7 @@ def chip(v5e):
 
 
 def _paged(*, batch=8, heads=16, kv=4, dh=64, page=128, max_pages=16,
-           pool_pages=64, layers=2, int8=False, window=0):
+           pool_pages=64, layers=2, int8=False, window=0, blocked=False):
     """(fn, arg shapes) for one paged_decode_attention geometry: the
     whole [L, P, page, K*Dh] pool and a traced layer index, as the
     layer loop hands them over; with ``window``, each row's first
@@ -80,9 +80,11 @@ def _paged(*, batch=8, heads=16, kv=4, dh=64, page=128, max_pages=16,
         def bound(q, pool_k, pool_v, tables, positions, layer, first):
             return paged_decode_attention(
                 q, pool_k, pool_v, tables, positions, layer, first=first,
-                window=window)
+                window=window, blocked=blocked)
 
         return bound, args + [((batch,), jnp.int32)]
+    if blocked:
+        return functools.partial(paged_decode_attention, blocked=True), args
     if not int8:
         return paged_decode_attention, args
     scale = ((layers, pool_pages, page, kv), jnp.float32)
@@ -156,6 +158,27 @@ _CASES = {
     "paged_decode_bf16_window_cell_window": lambda: _paged(
         batch=64, heads=28, kv=4, dh=128, max_pages=35, pool_pages=2240,
         layers=6, window=4096),
+    # The blocked form (the V pages in blocks, for a table whose V image
+    # does not fit): the K-EXAONE cell's full layer, 64 rows of 64 query
+    # / 8 KV heads of 128 over 64 pages of a 1,024-wide pool (2 MiB of
+    # scores, 1 of weights, 2 of landing pads: blocks of 4 pages); the
+    # window cell's full layer at its published 16,384 positions (28
+    # heads, a 512-wide pool, 128 pages: ROADMAP R2 (d)); and, so that
+    # the bound is compiled in this form too, a window layer's table.
+    "paged_decode_bf16_blocked_exaone_full": lambda: _paged(
+        batch=64, heads=64, kv=8, dh=128, max_pages=64, pool_pages=2816,
+        layers=1, blocked=True),
+    "paged_decode_bf16_blocked_window_cell_16384": lambda: _paged(
+        batch=64, heads=28, kv=4, dh=128, max_pages=128, pool_pages=2816,
+        layers=2, blocked=True),
+    "paged_decode_bf16_blocked_bound": lambda: _paged(
+        batch=64, heads=64, kv=8, dh=128, max_pages=72, pool_pages=512,
+        layers=4, window=8192, blocked=True),
+    # The K-EXAONE cell's window layers keep the whole form: a row's cap
+    # of 4 pages, the bound of 128 positions.
+    "paged_decode_bf16_exaone_window": lambda: _paged(
+        batch=64, heads=64, kv=8, dh=128, max_pages=4, pool_pages=256,
+        layers=4, window=128),
     # The flagship preset serves MHA: 8 KV heads of 64 (width 512).
     "paged_decode_bf16_flagship": lambda: _paged(heads=8, kv=8),
     "flash_attention_fwd_bwd_t2048": _flash_fwd_bwd,
@@ -563,6 +586,63 @@ def test_the_window_cell_fits_with_both_pools(chip, monkeypatch, program):
     assert memory.temp_size_in_bytes < state.pool_k.size * 2 // 2, (
         f"{program}: {memory.temp_size_in_bytes / 1e9:.2f} GB of "
         "temporaries is a layer of a pool or more")
+    assert memory.alias_size_in_bytes >= pools
+
+
+@pytest.mark.parametrize("program", ["decode_window", "prefill"])
+def test_the_exaone_cell_fits_and_its_full_layer_takes_the_blocked_form(
+        chip, monkeypatch, program):
+    """``k-exaone-236b-a23b.longmix`` compiled for the chip at its
+    shapes, held to ISSUE 43's arithmetic reckoned with the tree's own
+    leaves: 3.715 B parameters, 7.43 GB in bf16, no leaf float32 but the
+    small ones (the routers, their biases, the gains); a pool of the one
+    full layer's keys and values (2,816 pages of 0.5 MiB, 1.48 GB) and
+    one of the four window layers' (256 = 64 x 4 pages of 2 MiB, 0.54
+    GB), both donated and updated in place. The window's program holds
+    five kernels: the leading dense layer's, a window layer over a
+    table of a row's cap of 4 pages, before the scan; in the period's
+    body three more of those and, for the full layer, whose table of 64
+    pages of a 1,024-wide pool at 64 heads does not fit the scratch
+    whole, the blocked form, where the parent took the gather. A
+    prefill chunk, one row's slot given, takes the gather."""
+    cfg, params, state, lowered = _patterned_cell_program(
+        program, chip, monkeypatch, "k-exaone-236b-a23b.longmix")
+    leaves = jax.tree_util.tree_leaves(params)
+    weights = sum(a.size * a.dtype.itemsize for a in leaves)
+    assert sum(a.size for a in leaves) == pytest.approx(3.715e9, rel=1e-3)
+    assert 7.42e9 < weights < 7.45e9
+    assert max(a.size for a in leaves if a.dtype == jnp.float32) \
+        == (cfg.n_layers - 1) * cfg.d_model * cfg.n_experts  # the routers
+    assert (cfg.n_heads, cfg.kv_heads, cfg.d_head) == (64, 8, 128)
+    assert (cfg.kv_layers, cfg.window_layers, cfg.ssm_layers) == (1, 4, 0)
+    assert (cfg.dense_layers, cfg.leading_kinds, cfg.periods) \
+        == (1, ("window",), 1)
+    assert set(state.recurrent) == {"picks"}
+    assert state.pool_k.shape == (1, 2816, 128, 1024)
+    assert state.win_pool_k.shape == (4, 256, 128, 1024)
+    assert state.win_tables.shape == (64, 4)
+    pools = 2 * (state.pool_k.size + state.win_pool_k.size) * 2
+    assert pools == pytest.approx(1.476e9 + 0.537e9, rel=1e-3)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    window = program == "decode_window"
+    assert text.count('custom_call_target="tpu_custom_call"') \
+        == (5 if window else 0)
+    lowered_text = lowered.as_text()
+    assert lowered_text.count('kernel_name = "paged_attention"') \
+        == (4 if window else 0)
+    assert lowered_text.count('kernel_name = "paged_attention_blocked"') \
+        == (1 if window else 0)
+    memory = compiled.memory_analysis()
+    needs = (memory.argument_size_in_bytes + memory.output_size_in_bytes
+             - memory.alias_size_in_bytes + memory.temp_size_in_bytes)
+    print(f"{program} at the exaone cell's shapes: needs "
+          f"{needs / 1e9:.3f} GB, {memory.temp_size_in_bytes / 1e9:.3f} GB "
+          f"of it temporaries")
+    assert needs < 15.75e9
+    assert memory.temp_size_in_bytes < state.pool_k.size * 2, (
+        f"{program}: {memory.temp_size_in_bytes / 1e9:.2f} GB of "
+        "temporaries is the full layer's pool or more")
     assert memory.alias_size_in_bytes >= pools
 
 
