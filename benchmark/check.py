@@ -8,10 +8,16 @@ At each served position the reference's best logit is compared with its
 logit of the token the server chose: 0 where they agree, the gap where
 the server's arithmetic (bf16, its kernels, its cache) tipped a near
 tie. Two numbers are held to limits of their own, kept in the cell's
-file with the readings they were set from (PERF.md): the widest gap, and
-the mean gap over all checked tokens, which is steadier and grows with
-the square of the arithmetic's error. Decoding is greedy and output
-lengths are forced, so every served token can be checked.
+file with the readings they were set from (``check.set_from``; PERF.md
+section 6): the mean gap over all checked tokens, which is steady and
+grows with the square of the arithmetic's error, so its limit lies
+between the program's largest reading and the int8 control's smallest
+and tells the precisions apart; and the widest gap, the extreme of
+thousands of tokens with a long tail, whose limit is a guard against
+gross faults (another row's pages, a broken kernel's token) at twice the
+largest reading on record or more, and which the control need not fail.
+Decoding is greedy and output lengths are forced, so every served token
+can be checked.
 """
 
 from __future__ import annotations

@@ -150,19 +150,17 @@ def test_the_exaone_cell_is_what_the_issue_sized():
     assert 2 * 0.00539 < limits["token_gap_mean"] < 0.02844 / 2
     entry = next(w for w in BENCH["workloads"] if w["name"] == CELL)
     assert (entry["chips"], entry["traffic"]) == (1, "longmix")
-    assert len(BENCH["workloads"]) == 5
-    assert not [w for w in BENCH["workloads"] if w["chips"] != 1]
     plan = schedule.build(cell.traffic, cell.load, 1, 48.0, model["vocab"])
     totals = [r["prompt"] + r["n_new"] for r in plan["requests"]]
     assert max(totals) <= payload["seq"]
     assert sum(t > 4096 for t in totals) > len(totals) // 2
     # it reports everything the first cell does and one metric of its
-    # own, the last entry, listed for this cell alone
+    # own, listed for this cell alone
     names = {m["name"] for m in cell.per_layer}
     first = {m["name"] for m in
              cellspec.load_cell("starcoder2-3b.batchgen").per_layer}
     assert names - first == {NEW} and first <= names
-    assert BENCH["per_layer"][-1] == {
+    assert next(m for m in BENCH["per_layer"] if m["name"] == NEW) == {
         "name": NEW, "unit": "%", "better": "higher",
         "source": "device_trace", "layer": "kernels", "moves": "out_tok_s",
         "workloads": [CELL]}

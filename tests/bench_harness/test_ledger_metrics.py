@@ -64,7 +64,9 @@ def test_the_six_are_appended_entries_with_a_reader_each():
         "lock_held_pct.closed", "lock_pick_held_pct.closed",
         "lock_unnamed_pct.closed", "slot_decode_pct.closed",
         "join_wait_ms.closed", "http_first_write_ms.closed"]
-    assert BENCH["per_layer"][-6:] == added  # appended, in one block
+    # appended in one block, wherever later entries have left it
+    at = BENCH["per_layer"].index(added[0])
+    assert BENCH["per_layer"][at:at + 6] == added
     assert all(m["source"] == "program_counter" and "workloads" not in m
                and m["moves"] == "out_tok_s" for m in added)
     layers = {m["name"]: m["layer"] for m in added}
@@ -161,6 +163,7 @@ def test_an_entry_with_no_list_of_cells_is_reported_by_all_three():
     cells = [w["name"] for w in BENCH["workloads"]]
     unlisted = {m["name"] for m in BENCH["per_layer"]
                 if "workloads" not in m}
-    assert len(cells) == 3 and len(unlisted) >= 23 + 6
+    # "all three" were the cells of PR 38; every cell since is held too
+    assert len(cells) >= 3 and len(unlisted) >= 23 + 6
     for cell in cells:
         assert unlisted <= _names(cell), cell

@@ -120,10 +120,12 @@ def test_the_window_cell_is_what_the_issue_sized():
     # 0.022 to 0.041 on four seeds and over 8 0.017 to 0.045 on eight
     # (now and then a request reads several times the others), too wide
     # to hold a limit under the int8 control's 0.082 (PERF.md section 6,
-    # PR 40)
-    assert cell.load["check"] == {
-        "requests": 16,
-        "limits": {"token_gap_max": 3.0, "token_gap_mean": 0.058}}
+    # PR 40); the extreme's limit is a guard at twice the largest
+    # reading or more (test_check_limits.py holds it to the file's own)
+    assert cell.load["check"]["requests"] == 16
+    limits = cell.load["check"]["limits"]
+    assert limits["token_gap_mean"] == 0.058
+    assert limits["token_gap_max"] >= 2 * 2.05
     assert cell.traffic["prompt"] == {"dist": "uniform", "min": 512,
                                       "max": 5120, "multiple": 256}
     assert cell.traffic["output"] == {"dist": "uniform", "min": 1024,
