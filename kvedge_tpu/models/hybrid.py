@@ -87,7 +87,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from kvedge_tpu.models import delta, ssm
+from kvedge_tpu.models import delta, moe, ssm
 from kvedge_tpu.models.moe import ffn_activation, held_experts_ffn
 from kvedge_tpu.models.transformer import TransformerConfig, _rmsnorm
 
@@ -314,14 +314,33 @@ def _shared_expert(cfg: TransformerConfig, h, w_in, w_out):
     return act @ w_out.astype(h.dtype)
 
 
-def feed_forward(cfg: TransformerConfig, x, w: dict, live, routed_on=None):
+def walks_touched(cfg: TransformerConfig, n_tokens: int) -> bool:
+    """Whether a program of ``n_tokens`` tokens a layer (a decode
+    batch's rows, a prefill chunk's positions) reads only the held
+    experts a live token picked (``moe.walks_touched`` at this block's
+    sizes): what :func:`run_layers` asks, and the server's count of the
+    matrices its windows read."""
+    return moe.walks_touched(n_tokens, cfg.expert_top_k, cfg.n_experts,
+                             cfg.held_experts, cfg.d_model, cfg.d_ff)
+
+
+def expert_reads_per_step(cfg: TransformerConfig) -> int:
+    """The held experts' matrices of every routed layer: what a step
+    reads that reads them all."""
+    return (cfg.n_layers - cfg.dense_layers) * cfg.held_experts
+
+
+def feed_forward(cfg: TransformerConfig, x, w: dict, live, routed_on=None,
+                 layer=None):
     """``x + r * (Routed(norm(x)) + Shared(norm(x)))`` over x [R, Q, D]
     and the picks of the ``live`` rows' tokens (held_experts_ffn).
     ``routed_on`` [R, Q, D], where given, is what the router reads
     (``cfg.router_before_mixer``: the mixer's normed input). A tree
     with no ``router`` is a leading dense layer's: one gated MLP, and
     no picks (None). ``cfg.norm_after``: the norm is on the sum, not on
-    ``x``."""
+    ``x``. With ``layer`` given the tree's ``experts_in`` and
+    ``experts_out`` are the stacked leaves, [layers, Eh, ...], and the
+    layer's experts are at ``layer`` of them (:func:`walks_touched`)."""
     rows, q_len, d = x.shape
     routed = "router" in w
     with jax.named_scope("kvedge/experts" if routed else "kvedge/dense"):
@@ -337,7 +356,7 @@ def feed_forward(cfg: TransformerConfig, x, w: dict, live, routed_on=None):
                 routed_on=(None if routed_on is None
                            else routed_on.reshape(rows * q_len, d)),
                 score=cfg.router_score, bias=w.get("router_bias"),
-                scale=cfg.router_scale)
+                scale=cfg.router_scale, layer=layer)
             if "shared_in" in w:
                 out = out + _shared_expert(cfg, h, w["shared_in"],
                                            w["shared_out"])
@@ -400,11 +419,25 @@ def run_layers(cfg: TransformerConfig, params: dict, x, pools, recurrent,
                 a, (period, i) + (0,) * (a.ndim - 2),
                 (1, 1) + a.shape[2:]).reshape(a.shape[2:]), tree)
 
+    def ffn_at(period, i):
+        """Layer ``i`` of period ``period`` of the feed-forward's tree
+        and, where the experts' sum walks the touched experts, the
+        layer's index into the experts' two leaves, which then stay
+        whole, their layers in one leading dimension: the kernel
+        reads the layer's touched experts where they lie."""
+        tree = weights["ffn"]
+        if not walks:
+            return at(tree, period, i), None
+        whole = {name: tree[name].reshape((-1,) + tree[name].shape[2:])
+                 for name in ("experts_in", "experts_out")}
+        rest = {name: a for name, a in tree.items() if name not in whole}
+        return {**at(rest, period, i), **whole}, period * len(pattern) + i
+
     def one_layer(carry, kind, w, ffn_of, layer):
         """A layer of ``kind``, ``layer`` its place among the layers
         that share its pool or state: mixer, then feed-forward over
         the tree ``ffn_of()`` slices out (where it is used, as
-        :func:`at` says)."""
+        :func:`at` says) and the index it gives beside it."""
         x, pools, state, conv, picks = carry
         h = x if cfg.norm_after else _rmsnorm(x, w["ln"], cfg.norm_eps)
         if kind in ATTENTION_KINDS:
@@ -430,8 +463,10 @@ def run_layers(cfg: TransformerConfig, params: dict, x, pools, recurrent,
         if cfg.norm_after:
             out = _rmsnorm(out, w["ln"], cfg.norm_eps)
         x = x + r * out
+        ffn, ffn_layer = ffn_of()
         x, layer_picks = feed_forward(
-            cfg, x, ffn_of(), live, h if cfg.router_before_mixer else None)
+            cfg, x, ffn, live, h if cfg.router_before_mixer else None,
+            ffn_layer)
         if layer_picks is not None:
             picks = picks + layer_picks
         return x, pools, state, conv, picks
@@ -441,7 +476,7 @@ def run_layers(cfg: TransformerConfig, params: dict, x, pools, recurrent,
         for j, kind in enumerate(pattern):
             carry = one_layer(
                 carry, kind, at(weights[kind], period, seen[kind]),
-                functools.partial(at, weights["ffn"], period, j),
+                functools.partial(ffn_at, period, j),
                 (period * n_of[kind] + (n_lead[kind] + seen[kind])
                  if kind in ATTENTION_KINDS
                  else period * n_recurrent + seen[kind]))
@@ -449,13 +484,15 @@ def run_layers(cfg: TransformerConfig, params: dict, x, pools, recurrent,
         return carry, None
 
     weights = {kind: params.get(kind, {}) for kind in _KINDS}
+    walks = walks_touched(cfg, x.shape[0] * x.shape[1])
     carry = (x, pools, recurrent.get("ssm"), recurrent.get("conv"),
              recurrent["picks"])
     for i, kind in enumerate(cfg.leading_kinds):
         carry = one_layer(
             carry, kind,
             jax.tree_util.tree_map(lambda a: a[i], params["leading"]),
-            lambda: jax.tree_util.tree_map(lambda a: a[i], params["dense"]),
+            lambda: (jax.tree_util.tree_map(lambda a: a[i],
+                                            params["dense"]), None),
             i)
     (x, pools, state, conv, picks), _ = lax.scan(
         body, carry, jnp.arange(cfg.periods, dtype=jnp.int32))

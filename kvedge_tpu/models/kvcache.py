@@ -348,6 +348,12 @@ class PagedKVCache:
         # (``_picks_of``: the windows not harvested yet).
         self.expert_picks = None
         self._picks_of: dict = {}
+        # The (layer, held expert, step) matrices those windows read: a
+        # window whose program walks the touched experts
+        # (hybrid.walks_touched, static for a compiled program) reads
+        # the triples it counted as touched, any other every held
+        # expert of every routed layer at every step.
+        self.expert_reads = 0
         if cfg.layer_pattern:
             import numpy as _np
 
@@ -1361,9 +1367,11 @@ class PagedKVCache:
         window is already queued behind it (the overlap)."""
         import numpy as _np
 
-        picks = self._picks_of.pop(id(handle), None)
+        picks, reads = self._picks_of.pop(id(handle), (None, None))
         if picks is not None:
-            self.expert_picks += _np.asarray(picks)
+            picks = _np.asarray(picks)
+            self.expert_picks += picks
+            self.expert_reads += int(picks[-1]) if reads is None else reads
         return _np.asarray(handle)
 
     def _note_window(self, out, n_steps: int):
@@ -1371,8 +1379,13 @@ class PagedKVCache:
         returns its pick counters beside its tokens, kept until the
         tokens are harvested."""
         if self.state.recurrent is not None:
+            from kvedge_tpu.models import hybrid
+
             toks, picks = out
-            self._picks_of[id(toks)] = picks
+            # What the window read, where that is not what it touched.
+            self._picks_of[id(toks)] = (
+                picks, None if hybrid.walks_touched(self.cfg, self.bucket)
+                else n_steps * hybrid.expert_reads_per_step(self.cfg))
         else:
             toks = out
         self._carry = (toks, n_steps)

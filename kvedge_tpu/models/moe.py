@@ -43,6 +43,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from kvedge_tpu.ops import expert_walk
+
 
 def warn_if_train_serve_divergence(cfg) -> None:
     """Warn when cached serving can silently disagree with training.
@@ -201,13 +203,48 @@ def moe_ffn(x, router_w, w_up, w_down, *, capacity_factor: float,
     return out, aux_loss
 
 
-_GATES = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 # Tokens up to which the held experts' first product is one product over
 # all of them (held_experts_ffn): a decode batch, a 64-token chunk. At 64
 # tokens it reads 0 to 1.3% under the batch of products on the chip, at
 # 256 1.5% over, and inside a 256-token chunk's program it does not fit
 # (tools/expert_product_readings.py; PERF.md section 6, PR 40).
 _ONE_PRODUCT_TOKENS = 64
+# The share of the experts that must go untouched, under even routing,
+# before the walk over the touched ones (ops/expert_walk.py) is taken
+# for the one product. With every held expert on its list the walk
+# reads within a hundredth of the one product at 64 tokens on the chip,
+# us a layer, the one product first: 1,937 and 1,905 to 1,935 at 40
+# experts of 4,096 x 1,280; 1,011 and 1,013 at 36 of 4,096 x 768; 1,134
+# and 1,150 (1.4% over) at 64 of 2,560 x 768; 1,968 and 1,853 (6%
+# under) at 16 of 6,144 x 2,048; and with a third off the list 1,296 to
+# 1,313, 719, 809 and 1,329 (tools/expert_product_readings.py; PERF.md
+# section 5, PR 45). So the kernel's stream costs 1.4% at most, and
+# twice that untouched pays for it at every shape read.
+_WALK_UNTOUCHED = 0.03
+
+
+def _on_tpu() -> bool:
+    """Apart from ``ops.pallas_interpret``, as ``ssm._on_tpu`` is, so
+    that a CPU test can take the walk and run it in the interpreter."""
+    return jax.default_backend() == "tpu"
+
+
+def walks_touched(n_tokens: int, top_k: int, experts: int, held: int,
+                  d: int, f: int) -> bool:
+    """Whether this trace's held experts' feed-forward is the walk over
+    the touched experts (ops/expert_walk.py) and not the one product
+    over all ``held`` of them: ``n_tokens`` tokens of width ``d``, each
+    picking ``top_k`` of ``experts`` experts ``f`` wide. On a TPU
+    backend, at sizes the kernel tiles, at a decode batch's or a short
+    chunk's tokens, and where so few picks fall on so many experts that
+    leaving the untouched unread pays for what the kernel's stream may
+    cost (``_WALK_UNTOUCHED``):
+    under even routing an expert goes untouched with probability ``(1 -
+    top_k / experts) ** n_tokens``, whichever of the experts are held.
+    Decided from what the trace can see; there is no option."""
+    return (_on_tpu() and held > 0 and n_tokens <= _ONE_PRODUCT_TOKENS
+            and expert_walk.tiles(n_tokens, d, f)
+            and (1 - top_k / experts) ** n_tokens > _WALK_UNTOUCHED)
 
 
 def ffn_activation(up, gated: bool, gate: str = "silu"):
@@ -215,9 +252,9 @@ def ffn_activation(up, gated: bool, gate: str = "silu"):
     ``gate(u) * g`` over ``up = u | g`` when ``gated`` (``gate`` names
     the function: "silu", or "relu" for a ReGLU), else ``gelu(up)``."""
     if not gated:
-        return jax.nn.gelu(up)
+        return expert_walk.hidden(up)
     half = up.shape[-1] // 2
-    return _GATES[gate](up[..., :half]) * up[..., half:]
+    return expert_walk.hidden(up[..., :half], up[..., half:], gate)
 
 
 def held_experts_ffn(x, router_w, w_in, w_out, *, top_k: int,
@@ -225,7 +262,7 @@ def held_experts_ffn(x, router_w, w_in, w_out, *, top_k: int,
                      renormalize: bool | None = None, live=None,
                      activation: str = "silu", routed_on=None,
                      score: str = "softmax", bias=None,
-                     scale: float = 1.0):
+                     scale: float = 1.0, layer=None):
     """The routed experts held here, for every token: the serving path.
 
     x: [N, D]; router_w [D, E] fp32 over ALL ``E`` routed experts;
@@ -260,17 +297,40 @@ def held_experts_ffn(x, router_w, w_in, w_out, *, top_k: int,
     of its picks: ``N * top_k`` matrices a layer where this reads
     ``Eh`` (PERF.md section 6 has both timed at the benchmark's
     widths).
+
+    With ``layer`` given (:func:`walks_touched` said so), ``w_in`` and
+    ``w_out`` are the stacked leaves whole, [layers, Eh, ...], the
+    layer's experts are at ``layer`` of them, and the sum is the walk
+    over the experts a ``live`` token picked (ops/expert_walk.py): the
+    others, whose every live gate is zero, are not read. A token that
+    is not live gets the touched experts' part of its sum, which
+    nothing reads.
     """
     with jax.named_scope("kvedge/router"):
         _, topk_idx, gates = _route(
             x if routed_on is None else routed_on, router_w, top_k,
             renormalize, score, bias, scale)
-    held = w_in.shape[0]
+    held = w_out.shape[-3]
     dtype = x.dtype
     # [N, k, Eh]: pick j of token n is held expert e.
     hit = (topk_idx[:, :, None] - first
            == jnp.arange(held, dtype=topk_idx.dtype)[None, None, :])
     gate = jnp.sum(jnp.where(hit, gates[:, :, None], 0.0), axis=1)  # [N, Eh]
+    counted = hit if live is None else hit & live[:, None, None]
+    by_expert = jnp.sum(counted, axis=(0, 1), dtype=jnp.int32)
+    n_live = (x.shape[0] if live is None
+              else jnp.sum(live, dtype=jnp.int32))
+    picks = jnp.concatenate([
+        jnp.stack([jnp.asarray(n_live * top_k, jnp.int32),
+                   jnp.sum(by_expert)]), by_expert,
+        jnp.sum(by_expert > 0, dtype=jnp.int32)[None]])
+    if layer is not None:
+        from kvedge_tpu.ops import pallas_interpret
+
+        out = expert_walk.expert_walk(
+            x, gate, w_in, w_out, layer, by_expert > 0, gated=gated,
+            gate=activation, interpret=pallas_interpret())
+        return out.astype(dtype), picks
     if x.shape[0] > _ONE_PRODUCT_TOKENS:
         # A prefill chunk of more tokens: as one product with the tokens
         # shared by every expert, the chip's compiler, inside the
@@ -288,14 +348,6 @@ def held_experts_ffn(x, router_w, w_in, w_out, *, top_k: int,
     act = ffn_activation(up, gated, activation)
     act = act * gate.T[:, :, None].astype(dtype)
     out = jnp.einsum("enf,efd->nd", act, w_out.astype(dtype))
-    counted = hit if live is None else hit & live[:, None, None]
-    by_expert = jnp.sum(counted, axis=(0, 1), dtype=jnp.int32)
-    n_live = (x.shape[0] if live is None
-              else jnp.sum(live, dtype=jnp.int32))
-    picks = jnp.concatenate([
-        jnp.stack([jnp.asarray(n_live * top_k, jnp.int32),
-                   jnp.sum(by_expert)]), by_expert,
-        jnp.sum(by_expert > 0, dtype=jnp.int32)[None]])
     return out, picks
 
 

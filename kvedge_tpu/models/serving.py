@@ -3114,6 +3114,8 @@ class PagedGenerationServer:
             # every slot holds its rows' whether a request is in it or
             # not, so slots bound admission as memory too. The picks
             # are the decode windows' own counts, summed at harvest.
+            from kvedge_tpu.models import hybrid
+
             picks = self._cache.expert_picks
             if "ssm" in recurrent:
                 out["state_rows"] = self._cache.slots
@@ -3126,9 +3128,12 @@ class PagedGenerationServer:
             # got a live row's pick, of the ``expert_reads_per_step``
             # (routed layers x held experts) matrices every step reads.
             out["expert_touched_total"] = int(picks[-1])
-            out["expert_reads_per_step"] = (
-                (self._cfg.n_layers - self._cfg.dense_layers)
-                * self._cfg.held_experts)
+            out["expert_reads_per_step"] = hybrid.expert_reads_per_step(
+                self._cfg)
+            # The matrices the decode windows did read: the touched
+            # ones where a window's program walks the list of them,
+            # ``expert_reads_per_step`` a step where it does not.
+            out["expert_reads_total"] = self._cache.expert_reads
         if self._autotune is not None:
             # Online window controller (SERVING.md rung 26): the
             # current pick and its EWMA inputs — R (host turnaround

@@ -175,6 +175,10 @@ _SERVE_METRIC_FIELDS = (
     ("pipeline_joins_total", "serve_pipeline_joins_total", "counter",
      "rows that entered an overlapped window from the host's row (a "
      "newcomer joining on the carry, no boundary taken)"),
+    ("expert_reads_total", "serve_expert_reads_total", "counter",
+     "(layer, held expert, step) expert matrices the decode windows "
+     "read: the ones a live row picked where a window's program walks "
+     "them, every held one where it does not (a [model] layer_pattern)"),
     ("spec_passes", "serve_spec_passes_total", "counter",
      "speculative verify passes run (paged backend, "
      "serving_speculative > 0)"),

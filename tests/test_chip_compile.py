@@ -126,6 +126,31 @@ def _fused_xent_fwd_bwd():
     ]
 
 
+def _expert_walk(tokens=64):
+    """The walk over the touched experts (ops/expert_walk.py) at the
+    delta cell's published widths: 40 held experts of 4,096 x 2,560
+    (u | g) and 1,280 x 4,096 in bf16, in stacked leaves of 4 layers,
+    inside a scan over the layers that hands the kernel the leaves
+    whole and the layer's index."""
+    from kvedge_tpu.ops.expert_walk import expert_walk
+
+    layers, held, d, f = 4, 40, 4096, 1280
+
+    def run(x, gate_of, w_in, w_out, touched):
+        def one_layer(x, layer):
+            out = expert_walk(x, gate_of, w_in, w_out, layer, touched[layer],
+                              gated=True, interpret=False)
+            return x + out.astype(x.dtype), None
+        return jax.lax.scan(one_layer, x,
+                            jnp.arange(layers, dtype=jnp.int32))[0]
+
+    return run, [
+        ((tokens, d), jnp.bfloat16), ((tokens, held), jnp.float32),
+        ((layers, held, d, 2 * f), jnp.bfloat16),
+        ((layers, held, f, d), jnp.bfloat16), ((layers, held), jnp.bool_),
+    ]
+
+
 # 209M widths unless said: 16 query / 4 KV heads of 64, 128-token pages,
 # a 2,048-token cap (16 pages per sequence), 8 rows.
 _CASES = {
@@ -181,6 +206,10 @@ _CASES = {
         layers=4, window=128),
     # The flagship preset serves MHA: 8 KV heads of 64 (width 512).
     "paged_decode_bf16_flagship": lambda: _paged(heads=8, kv=8),
+    # The delta cell's held experts at a decode batch's 64 rows and at
+    # its shorter prefill tail's 32 positions.
+    "expert_walk_delta_cell_64": _expert_walk,
+    "expert_walk_delta_cell_32": lambda: _expert_walk(32),
     "flash_attention_fwd_bwd_t2048": _flash_fwd_bwd,
     "rmsnorm_fwd_16384x1024": _rmsnorm_fwd,
     "fused_xent_fwd_bwd_4096x32000": _fused_xent_fwd_bwd,
@@ -196,6 +225,26 @@ def test_kernel_compiles_for_v5e(chip, case):
     assert "tpu_custom_call" in compiled.as_text(), (
         f"{case}: compiled for the chip without a Mosaic kernel in it"
     )
+
+
+def test_the_walk_reads_the_experts_where_they_lie(chip):
+    """The stacked leaves of 5.03 GB go into the kernel as they come:
+    the program's temporaries hold no copy of them, nor of one layer's
+    1.26 GB, nor of one expert's 31 MB (a product written the wrong way
+    round once had the compiler copy a 3.75 GB leaf transposed: the
+    last test but two of this file)."""
+    fn, shapes = _expert_walk()
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+            for shape, dtype in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes > 5.03e9
+    assert memory.temp_size_in_bytes < 4 << 20, memory.temp_size_in_bytes
+    makers = set(re.findall(
+        r" = bf16\[4,40,(?:4096,2560|1280,4096)\]\{[^}]*\} ([\w-]+)\(", text))
+    assert makers <= {"parameter", "get-tuple-element"}, makers
 
 
 @pytest.mark.parametrize("pool_spec", [
@@ -357,7 +406,7 @@ def _patterned_cell_program(program: str, chip, monkeypatch,
     import kvedge_tpu.ops
     from benchmark import cellspec
     from kvedge_tpu.config.runtime_config import RuntimeConfig
-    from kvedge_tpu.models import hybrid, kvcache, ssm
+    from kvedge_tpu.models import hybrid, kvcache, moe, ssm
     from kvedge_tpu.runtime.workload import derive_model_config
 
     monkeypatch.setattr(kvedge_tpu.ops, "pallas_interpret", lambda: False)
@@ -365,6 +414,9 @@ def _patterned_cell_program(program: str, chip, monkeypatch,
     # window's one-token form is the recurrent kind's kernel
     # (ssm.step_in_kernel, delta.step_in_kernel: both ask ssm._on_tpu).
     monkeypatch.setattr(ssm, "_on_tpu", lambda: True)
+    # and the held experts' sum walks the touched experts where
+    # moe.walks_touched's rule takes the walk at the cell's shapes
+    monkeypatch.setattr(moe, "_on_tpu", lambda: True)
     cell = cellspec.load_cell(name)
     payload = cell.config["payload"]
     one = jax.devices()[:1]
@@ -495,8 +547,13 @@ def test_the_delta_cell_fits_and_leaves_its_state_where_it_is(
     in place. The window holds four kernels: the one-pass delta step
     (ops/delta_step.py) once for each of the period's three delta
     layers, handed the stacked state whole, and the paged attention
-    kernel at a query group of 8 (64 query heads). A prefill chunk, one
-    row's slot given, takes neither."""
+    kernel at a query group of 8 (64 query heads), and beside them,
+    once a layer, the walk over the touched experts (ops/expert_walk.py:
+    320 experts, 8 a token and 64 rows leave a fifth of them untouched
+    under even routing, so ``moe.walks_touched`` takes it), handed the
+    experts' stacked leaves whole. A prefill chunk, one row's slot
+    given, takes neither of the first two, and the walk by the same
+    rule as the window: a chunk's 64 tokens are a batch's."""
     cfg, params, state, lowered = _patterned_cell_program(
         program, chip, monkeypatch, "solar-open2-250b.batchgen")
     leaves = jax.tree_util.tree_leaves(params)
@@ -514,9 +571,10 @@ def test_the_delta_cell_fits_and_leaves_its_state_where_it_is(
     text = compiled.as_text()
     window = program == "decode_window"
     assert text.count('custom_call_target="tpu_custom_call"') \
-        == (4 if window else 0)
+        == (8 if window else 4)
     assert lowered.as_text().count('kernel_name = "delta_step"') \
         == (3 if window else 0)
+    assert lowered.as_text().count('kernel_name = "expert_walk"') == 4
     if window:
         # Nothing but the kernel makes an array of the state's size: it
         # comes in as a parameter and goes through the three calls.
@@ -524,6 +582,9 @@ def test_the_delta_cell_fits_and_leaves_its_state_where_it_is(
             r" = f32\[3,64,64,128,128\]\{[^}]*\} ([\w-]+)\(", text))
         assert makers <= {"custom-call", "parameter", "get-tuple-element"}, \
             makers
+    # The experts' leaves come in as parameters and go to the walk as
+    # they are: nothing makes an array of a layer's experts (1.26 GB).
+    assert not re.findall(r" = bf16\[(?:1,)*40,4096,2560\]", text)
     memory = compiled.memory_analysis()
     needs = (memory.argument_size_in_bytes + memory.output_size_in_bytes
              - memory.alias_size_in_bytes + memory.temp_size_in_bytes)

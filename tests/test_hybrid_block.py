@@ -682,6 +682,90 @@ def test_two_rows_touch_an_expert_once_a_step():
     assert np.asarray(every)[-1] >= picks[-1]
 
 
+def test_the_delta_block_on_the_walk_serves_what_the_one_product_serves(
+        monkeypatch):
+    """The delta block at widths the walk over the touched experts
+    tiles (a hidden size and experts of 128, 16 slots), three requests
+    decoding together beside thirteen dead rows: put onto the walk
+    (ops/expert_walk.py in the interpreter; ``moe.walks_touched`` asks
+    for a TPU, so the test answers in its place), prefill chunks and
+    windows serve the tokens the one product serves, the reference's
+    choice at every position, and count the same picks. ``stats()``'s
+    ``expert_reads_total`` is what each program read: the touched
+    matrices on the walk, every held one a step on the one product.
+    ``/metrics`` renders it."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    from kvedge_tpu.ops import expert_walk
+    from kvedge_tpu.runtime.status import render_metrics
+    from tests.test_tracing import check_prometheus_text
+
+    block = BLOCKS["delta"]
+    published = _DELTA | {"hidden_size": 128, "moe_intermediate_size": 128,
+                          "n_routed_experts": 4}
+    model = block.reference.model_of(published)
+    cfg = config_of(model=model, block=block)
+    assert (cfg.d_model, cfg.d_ff, cfg.held_experts, cfg.n_experts) \
+        == (128, 128, 4, 16)
+    params = hybrid.init_params(jax.random.PRNGKey(0), cfg)
+    prompts = [prompt_of(seed, n) for seed, n in ((3, 40), (4, 23), (5, 32))]
+    n_new = 12
+
+    def serve():
+        jax.clear_caches()  # a program's trace is cached by its shapes
+        server = server_of(params, cfg, slots=16)
+        try:
+            with ThreadPoolExecutor(3) as pool:
+                served = list(pool.map(
+                    lambda prompt: server.submit(prompt, n_new), prompts))
+            return served, server.stats()
+        finally:
+            server.close()
+
+    try:
+        one, one_stats = serve()
+        assert not hybrid.walks_touched(cfg, 16)
+        # wherever the kernel tiles: the windows' 16 rows and a chunk
+        # of 16, and not the prompts' tails of 8 and 7
+        monkeypatch.setattr(
+            moe, "walks_touched",
+            lambda n, top_k, experts, held, d, f: expert_walk.tiles(n, d, f))
+        assert hybrid.walks_touched(cfg, 16)
+        assert not hybrid.walks_touched(cfg, 8)
+        walk, walk_stats = serve()
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
+    assert walk == one
+    weights = block.reference.make_weights(model)
+    wants = block.reference.logits(model, weights, walk,
+                                   [len(p) - 1 for p in prompts])
+    for served, prompt, want in zip(walk, prompts, wants):
+        generated = served[len(prompt):]
+        gaps = want[:n_new].max(axis=-1) - want[np.arange(n_new), generated]
+        assert gaps.max() <= block.tolerance
+    # (how many steps the three rows shared, and so how often two of
+    # them touched one expert in one step, is the threads' timing)
+    for key in ("expert_picks_total", "expert_picks_held_total",
+                "expert_picks_by_expert", "expert_reads_per_step"):
+        assert walk_stats[key] == one_stats[key], key
+    per_step = one_stats["expert_reads_per_step"]
+    assert per_step == 8 * 4
+    assert one_stats["expert_reads_total"] \
+        == one_stats["decode_steps_total"] * per_step
+    assert walk_stats["expert_reads_total"] \
+        == walk_stats["expert_touched_total"]
+    assert 0 < walk_stats["expert_reads_total"] \
+        < walk_stats["decode_steps_total"] * per_step
+    text = render_metrics({"ok": True, "boot_count": 1, "uptime_s": 2.5,
+                           "heartbeat_seq": 3, "heartbeat_age_s": 0.1,
+                           "serving": walk_stats})
+    assert check_prometheus_text(text)[
+        "kvedge_serve_expert_reads_total"] == "counter"
+    assert (f"kvedge_serve_expert_reads_total "
+            f"{walk_stats['expert_reads_total']}\n") in text
+
+
 # ---- the weights, leaf by leaf -------------------------------------------
 
 
