@@ -252,6 +252,13 @@ class PagedKVCache:
     Unused slots keep ``lengths == 0`` and are masked out of attention.
     """
 
+    # A window can be waited for (:meth:`await_window`) and a first
+    # token picked and kept on the device (:meth:`pick_first`) with no
+    # read through the pool: the serving layer waits with its work lock
+    # released. A slice cache reads through its op stream, under the
+    # lock like every op: False there.
+    unlocked_reads = True
+
     def __init__(self, cfg: TransformerConfig, *, slots: int, pages: int,
                  page_size: int = 16, max_pages_per_seq: int | None = None,
                  kv_dtype: str = "", min_bucket: int = 0,
@@ -407,6 +414,14 @@ class PagedKVCache:
         # the carries (drop_carry) so a revived/reformed pool never
         # reuses arrays from torn-down device state.
         self._dev_memo: dict = {}
+        # Each slot's pending first token, on the device ([slots] int32;
+        # :meth:`pick_first` writes a row's, a window's input takes it
+        # where the host states ``FIRST_ON_DEVICE``). Scratch between
+        # requests: a row is read only after its pick wrote it.
+        self._firsts = self._init_firsts()
+
+    def _init_firsts(self):
+        return jnp.zeros((self.slots,), jnp.int32)
 
     def _dev_const(self, kind: str, arr):
         """Device copy of a small host operand, reused while its bytes
@@ -1266,11 +1281,13 @@ class PagedKVCache:
         harvested; ``tokens=None`` feeds the previous dispatch's final
         token row (the device-resident carry), so no host round trip
         separates back-to-back windows. The choice is per row: an
-        entry of ``tokens`` below 0 takes that row of the carry and
+        entry of ``tokens`` of -1 takes that row of the carry, one of
+        ``FIRST_ON_DEVICE`` the row's pending first token
+        (:meth:`pick_first`, which the host has not read either) and
         every other entry is fed as given, joined on the device
         (:func:`_join_carry`), so a row that sat out the window in
-        flight (a newcomer, whose first token the host knows) enters
-        the next one beside rows whose tokens the host has not seen.
+        flight (a newcomer) enters the next one beside rows whose
+        tokens the host has not seen.
 
         ``steps_left`` [slots] int32 is each row's remaining decode
         budget (None = no cap): row b advances ``min(n_steps,
@@ -1359,6 +1376,44 @@ class PagedKVCache:
             row(jnp.bool_), row(jnp.int32), row(jnp.int32),
         )
 
+    def pick_first(self, logits, slot: int, sampling=None):
+        """Pick a request's first token from its last prefill chunk's
+        ``logits`` [V] on the device, and keep it there: greedy, or
+        for ``sampling`` (raw key data [2] uint32, temperature, top_p)
+        with ``fold_in(seed, 0)`` and the nucleus filter, as
+        ``decode.generate`` picks token 0. Nothing is read: the token
+        goes into the row of first tokens at ``slot``, from where the
+        row's first window takes it (an input entry of
+        ``FIRST_ON_DEVICE``), and is returned as a device scalar for
+        the host to read once a window that carried the row is
+        harvested. One program a sampling mode: it depends on the
+        vocabulary's width and on ``slots``."""
+        import numpy as _np
+
+        if sampling is None:
+            self._firsts, token = _pick_first_greedy(
+                logits, self._firsts, _np.int32(slot))
+        else:
+            key_data, temperature, top_p = sampling
+            self._firsts, token = _pick_first_sampled(
+                logits, self._firsts, _np.int32(slot),
+                _np.asarray(key_data, _np.uint32),
+                _np.float32(temperature), _np.float32(top_p))
+        return token
+
+    def await_window(self, handle) -> None:
+        """Block until the device has finished a dispatched window and
+        its block is on the host, touching nothing of the pool: the
+        serving loop calls this with its work lock released, and then
+        :meth:`harvest_window`, which no longer waits, with the lock
+        held."""
+        import numpy as _np
+
+        _np.asarray(handle)
+        picks, _ = self._picks_of.get(id(handle), (None, None))
+        if picks is not None:
+            _np.asarray(picks)
+
     def harvest_window(self, handle):
         """Force a dispatched window's tokens to the host
         ([n_steps + 2, slots] int32: the produced tokens plus the
@@ -1393,8 +1448,9 @@ class PagedKVCache:
 
     def _host_tokens(self, tokens) -> "np.ndarray":
         """A window's input row as the host states it: [bucket] int32,
-        an entry below 0 standing for the carry's (``None``: all of
-        them)."""
+        an entry of -1 standing for the carry's (``None``: all of
+        them) and one of ``FIRST_ON_DEVICE`` for the row's pending
+        first token."""
         import numpy as _np
 
         if tokens is None:
@@ -1402,27 +1458,34 @@ class PagedKVCache:
         return _np.asarray(tokens, _np.int32)
 
     def _with_carry(self, tokens):
-        """``tokens`` (on the device) with every entry below 0 replaced
-        by the carry's: the last token row of the window dispatched
-        before this one, which the host may not have read yet."""
+        """``tokens`` (on the device) with every entry of -1 replaced
+        by the carry's (the last token row of the window dispatched
+        before this one) and every ``FIRST_ON_DEVICE`` by the row's
+        pending first token: neither may the host have read yet."""
         if self._carry is None:
             raise PagedCacheError(
                 "no window in flight to carry tokens from — the first "
                 "window of a pipeline must pass explicit tokens"
             )
         block, n = self._carry
-        return _join_carry(block, tokens, n - 1)
+        return _join_carry(block, tokens, self._firsts, n - 1)
 
     def _window_tokens(self, tokens, memo: str):
-        """Device seam's input row: the host's where it states every
-        row (the first window of a pipeline), else joined with the
-        carry. A pipeline whose rows stand states the same row window
-        after window (every live row the carry's), and it rides the
-        memo."""
+        """Device seam's input row: the host's, joined on the device
+        with the pending first tokens (the first window of a pipeline)
+        or with those and the carry. A row the host states whole goes
+        through the first join too: the window programs then take
+        their tokens from a program's result whoever states them, and
+        are lowered once a window length, not once more for an array
+        fresh from the host (which a warm-up whose newcomers' tokens
+        are all on the device would never have dispatched). A pipeline
+        whose rows stand states the same row window after window
+        (every live row the carry's), and it rides the memo."""
         host = self._host_tokens(tokens)
-        if (host >= 0).all():
-            return jnp.asarray(host)
-        return self._with_carry(self._dev_const(memo, host))
+        dev = self._dev_const(memo, host)
+        if (host != -1).all():
+            return _join_firsts(dev, self._firsts)
+        return self._with_carry(dev)
 
     def drop_carry(self) -> None:
         """Forget the device-resident carries (recovery: a revived pool
@@ -2504,16 +2567,60 @@ _paged_spec_window_sampled = functools.partial(
 )(_paged_spec_window_sampled_impl)
 
 
+# An entry of a window's input row that stands for the row's pending
+# first token, picked and kept on the device (``pick_first``); -1
+# stands for the carry's.
+FIRST_ON_DEVICE = -2
+
+
+@jax.jit
+def _join_firsts(tokens, firsts):
+    """``tokens`` [B] int32 with every ``FIRST_ON_DEVICE`` replaced by
+    that row of ``firsts`` [slots] (None: a pool that keeps none, and
+    nothing to replace). One small program a bucket."""
+    if firsts is None:
+        return tokens
+    return jnp.where(tokens == FIRST_ON_DEVICE,
+                     firsts[:tokens.shape[0]], tokens)
+
+
 @functools.partial(jax.jit, static_argnames=("row",))
-def _join_carry(block, tokens, row: int):
+def _join_carry(block, tokens, firsts, row: int):
     """A window's input tokens on the device: row ``row`` of the window
     before it (``block``: its harvest block, [steps + 2, B], perhaps
-    still running) where ``tokens`` [B] int32 is below 0, ``tokens``
-    itself elsewhere. One small program a (window length, bucket): it
-    stands where the eager slice of that row stood, and every
-    overlapped dispatch runs it, newcomer or none, so a warm-up that
-    overlaps two windows has compiled what an admission needs."""
-    return jnp.where(tokens < 0, block[row], tokens)
+    still running) where ``tokens`` [B] int32 is -1, the row's pending
+    first token where it is ``FIRST_ON_DEVICE``
+    (:func:`_join_firsts`), ``tokens`` itself elsewhere. One small
+    program a (window length, bucket): it stands where the eager slice
+    of that row stood, and every overlapped dispatch runs it, newcomer
+    or none, so a warm-up that overlaps two windows has compiled what
+    an admission needs."""
+    return jnp.where(tokens == -1, block[row],
+                     _join_firsts(tokens, firsts))
+
+
+def _put_first(firsts, slot, token):
+    return firsts.at[slot].set(token), token
+
+
+@jax.jit
+def _pick_first_greedy(logits, firsts, slot):
+    """The greedy pick of :meth:`PagedKVCache.pick_first`."""
+    return _put_first(firsts, slot,
+                      jnp.argmax(logits, axis=-1).astype(jnp.int32))
+
+
+@jax.jit
+def _pick_first_sampled(logits, firsts, slot, key_data, temperature,
+                        top_p):
+    """The sampled pick of :meth:`PagedKVCache.pick_first`: token 0 of
+    the request's key schedule, through the filter every later token
+    goes through."""
+    from kvedge_tpu.models.decode import row_sample_keys, sample_token
+
+    keys = row_sample_keys(jax.random.wrap_key_data(key_data[None]), 0)
+    return _put_first(firsts, slot, sample_token(
+        logits[None], keys, temperature, top_p)[0])
 
 
 def _picks_zeroed(state: PagedState) -> PagedState:

@@ -44,6 +44,7 @@ from kvedge_tpu.runtime.failures import (
     classify_failure,
 )
 from kvedge_tpu.runtime.journal import JournalEntry, RequestJournal
+from kvedge_tpu.models.kvcache import FIRST_ON_DEVICE
 from kvedge_tpu.models.scheduler import AdmissionScheduler, _Hist
 from kvedge_tpu.runtime.tracing import (
     LOOP_PHASES,
@@ -132,7 +133,15 @@ class _Request:
     # is decode.py's: token t samples with fold_in(seed_key, t) — a pure
     # function of the request, so batch composition changes nothing.
     sampling: tuple | None = None
+    # The pending token: produced, not yet emitted, the next window's
+    # input for this row. ``FIRST_ON_DEVICE`` while it is the first
+    # token and the host has not read it: picked on the device after
+    # the last prefill chunk (``first_dev``, the scalar) and fed to
+    # the row's first window from there; the host reads it when it
+    # harvests that window (``_first_token_locked``), and only then
+    # is there a time to first token.
     next_token: int = -1
+    first_dev: object = None
     # Early-termination token (rung 23): generation finishes the moment
     # this token is PRODUCED — it is emitted as the final token, then
     # the request completes with its remaining budget unused. -1 (no
@@ -429,6 +438,10 @@ class PagedGenerationServer:
         # most 1 — double buffering, not an unbounded queue — so the
         # admission-latency price is bounded at one extra window.
         self._inflight: dict | None = None
+        # The window the loop is reading back with the lock released
+        # (``_harvest_locked``): dispatched, unreconciled, and no
+        # longer (or not only) ``_inflight``.
+        self._harvesting: dict | None = None
         self._overlap_windows = 0
         # Per-window latency histograms (ms; exported via /metrics):
         # dispatch->harvest wall time (the device+RTT leg), host
@@ -446,6 +459,10 @@ class PagedGenerationServer:
         # (pipeline_collapses_total{cause=...}; _boundary_wanted_locked
         # names them).
         self._pipeline_joins = 0
+        # First tokens picked and kept on the device: against the
+        # count of ``phase_ms["admit/first_pick"]``, the share of
+        # picks nobody waited for with the lock in hand.
+        self._first_tokens_on_device = 0
         self._pipeline_collapses = dict.fromkeys(
             ("cancel", "newcomer", "bucket", "stop", "checkpoint",
              "scheduler"), 0)
@@ -640,6 +657,12 @@ class PagedGenerationServer:
                 prefill_chunk or cfg.max_seq,
                 window_max if self._autotune is not None else window),
         )
+        # Can a window be waited for, and a first token kept on the
+        # device, with no read through the pool (the loop then waits
+        # with the work lock released)? The pool says (a slice cache:
+        # no); one injected that does not say reads as always.
+        self._unlocked_reads = bool(
+            getattr(self._cache, "unlocked_reads", False))
         if cfg.layer_pattern:
             self._cache.reset_phase = functools.partial(
                 self._phase, "admit/state_reset")
@@ -1357,32 +1380,40 @@ class PagedGenerationServer:
                     # request.
                     if self._closed:
                         raise self._refusal()
-                    # The pick reads the logits back: this thread
-                    # waits, lock held, for the chunks above and for
-                    # whatever window the device was given before them.
-                    req.next_token = req.pick(logits, 0)
+                    if self._first_token_stays_locked():
+                        # The pick is a program queued behind the
+                        # chunks above, and nothing is read: nobody
+                        # waits for the device with the lock in hand.
+                        # The row's first window takes the token from
+                        # the device, and the host reads it when it
+                        # harvests that window.
+                        req.first_dev = self._cache.pick_first(
+                            logits, slot,
+                            req.sampling and (req.key_data,
+                                              *req.sampling[1:]))
+                        req.next_token = FIRST_ON_DEVICE
+                        self._first_tokens_on_device += 1
+                    else:
+                        # The pick reads the logits back: this thread
+                        # waits, lock held, for the chunks above and
+                        # for whatever window the device was given
+                        # before them.
+                        req.next_token = req.pick(logits, 0)
                 finally:
-                    # The pick's hold ends with the token read, on one
-                    # stamp with the phase (its wait for the lock and
-                    # this hold are the phase); the activation below
-                    # is the admission's.
+                    # The pick's hold ends with the token dispatched
+                    # (or read), on one stamp with the phase (its wait
+                    # for the lock and this hold are the phase); the
+                    # activation below is the admission's.
                     picked.stop(hold.switch("admit/start"))
-                t_first = picked.t1
-                self._to_state(req, "join_wait", t_first)
-                # Time to first token: submit -> the prefill logits'
-                # pick. This is the serving-visible TTFT (the first
-                # emission rides the next loop iteration, but the
-                # token is decided here; first_emit_ms is that ride).
-                # The stamp is kept on the request: finish pairs it
-                # with the final token for the per-request inter-token
-                # gap (rung 25).
-                req.t_first = t_first
-                self._hist_ttft.observe((t_first - req.t_submit) * 1e3)
+                t_pick = picked.t1
+                self._to_state(req, "join_wait", t_pick)
+                if req.first_dev is None:
+                    self._first_token_known(req, t_pick)
                 if req.trace:
                     # Admission to the pick: the parent, by time, of
                     # this request's chunk phases in the ring.
                     self.tracer.span(
-                        "prefill", "serve", req.t_admit, t_first,
+                        "prefill", "serve", req.t_admit, t_pick,
                         rid=req.rid,
                         args={"prompt": len(req.prompt),
                               "shared": shared_tokens,
@@ -1417,6 +1448,43 @@ class PagedGenerationServer:
                     self._poison_locked(e)
             raise
         return req
+
+    def _first_token_stays_locked(self) -> bool:
+        """Does a request's first token stay on the device (lock
+        held)? Where the pool can keep it there, unless the server
+        speculates (drafting reads the tokens on the host, a spec
+        window's carry is built from them) or checkpoints (the
+        boundary a newcomer joins at journals its pending token):
+        there a newcomer joins at a boundary, which needs its token on
+        the host, and the handler reads it back as it always did."""
+        return (self._unlocked_reads and self._spec == 0
+                and self._checkpoint_every == 0)
+
+    def _first_token_known(self, req: _Request, now: float) -> None:
+        """The host has the request's first token (``now``: a stamp
+        taken there). Time to first token is submit to this: for a
+        token read back by the handler, the read; for one kept on the
+        device, the harvest of the first window that carried the row,
+        which is when a client can be given it. The stamp is kept on
+        the request: the first emission's ride is counted from it
+        (``first_emit_ms``), and the finish pairs it with the final
+        token for the per-request inter-token gap (rung 25)."""
+        req.t_first = now
+        self._hist_ttft.observe((now - req.t_submit) * 1e3)
+
+    def _first_token_locked(self, req: _Request) -> None:
+        """Read a first token kept on the device (lock held). The
+        pick was queued before every window that carried the row, so
+        at the harvest of one it is long computed; only the two sites
+        that need the token of a row no window carried yet wait here,
+        at a boundary with nothing else in flight, for the row's own
+        prefill chunks: the finish of a request asked for one token,
+        and a preemption (the row of first tokens is by slot, which a
+        resume changes)."""
+        if req.first_dev is not None:
+            req.next_token = int(req.first_dev)
+            req.first_dev = None
+            self._first_token_known(req, time.perf_counter())
 
     def _admit_wait(self, req: _Request, off: int) -> Hold:
         """The submit path's next hold of the lock, made and its wait
@@ -1524,7 +1592,8 @@ class PagedGenerationServer:
         # With nothing dispatched-unharvested the resize is safe here:
         # the loop's next dispatch at a boundary is always first=True
         # (host tokens), so the carry set_bucket drops was dead anyway.
-        if self._inflight is None and not self._cache.spec_pending():
+        if (self._inflight is None and self._harvesting is None
+                and not self._cache.spec_pending()):
             self._cache.set_bucket(
                 self._cache.bucket_for(self._free_slots[0] + 1)
             )
@@ -2721,6 +2790,7 @@ class PagedGenerationServer:
             # carry — a revived pipeline restarts from host tokens
             # (a slice cache's reform() already dropped its own).
             self._inflight = None
+            self._harvesting = None
             self._finish_ready.clear()
             self._stops_pending = 0
             self._cache.drop_carry()
@@ -3031,6 +3101,7 @@ class PagedGenerationServer:
             "window_device_ms": self._hist_device.snapshot(),
             "window_inflight_depth": self._hist_depth.snapshot(),
             "pipeline_joins_total": self._pipeline_joins,
+            "first_tokens_on_device_total": self._first_tokens_on_device,
             "pipeline_collapses": dict(self._pipeline_collapses),
             # Per-request stage histograms (SERVING.md rung 18):
             # TTFT and the queue-vs-decode split.
@@ -3485,6 +3556,7 @@ class PagedGenerationServer:
 
     def _emit_pending_locked(self, req: _Request) -> None:
         """Emit the request's pending token alone, and book it."""
+        self._first_token_locked(req)
         before = len(req.generated)
         self._emit(req, req.next_token)
         self._note_emitted_locked(req, before)
@@ -3867,6 +3939,9 @@ class PagedGenerationServer:
             if victim is None:
                 return
             req = self._active[victim]
+            # A first token still on the device sits in the pool's row
+            # at this slot, and the resume takes another.
+            self._first_token_locked(req)
             saved_len = len(req.prompt) + len(req.generated)
             n_pages = -(-saved_len // self._cache.page_size)
             # slot_pages is position-ordered; pages grown past the
@@ -3942,9 +4017,12 @@ class PagedGenerationServer:
         round trip between the two — this is the overlap), then
         harvests and processes the previous window's tokens while the
         next one runs. An admission does not end this: a newcomer
-        (active, nothing of it in flight, its first token picked on
-        the host) enters the next overlapped window from the host's
-        row beside the rows that ride the carry. Whenever exactness
+        (active, nothing of it in flight, its first token picked and
+        still on the device, or read back by its handler) enters the
+        next overlapped window beside the rows that ride the carry,
+        and the read of the previous window's tokens is made with the
+        lock released (``_harvest_locked``), so that its prefill
+        chunks are not held up by it either. Whenever exactness
         needs a boundary (``_boundary_wanted_locked``: a cancel, a
         bucket step, a stop, a due checkpoint, the scheduler's resume
         or preemption, a newcomer to a server that speculates or
@@ -3956,167 +4034,185 @@ class PagedGenerationServer:
         budget (frozen rows stop scattering K/V and stop advancing
         length — kvcache._paged_decode_window_capped_impl), and the
         host truncates each row's emitted stream at its own cap.
+
+        One hold of the lock, and as many iterations as it takes to
+        leave it (``_iterate_locked``): an iteration that gave the lock
+        up for its wait for a window ("again") goes round in the same
+        hold, so that between a window's tokens and the next dispatch
+        the loop asks for the lock once, after that wait, and not a
+        second time behind whatever chain of prefill chunks got in.
+        The handlers had the lock for the length of the wait: that is
+        the hand-off ``_loop`` makes between two holds.
         """
-        phase = self._phase
         with self._hold("loop", waited=self._lock_wait) as hold, \
                 self._published_on_exit():
             # The wait for the lock ended on the acquire's stamp; the
             # next one is made now.
-            self._lock_wait = phase("loop/lock_wait")
-            while (not self._active and self._inflight is None
-                   and not self._closed
-                   and not self._sched_attention_locked()
-                   and not (self._draining
-                            and not self._prefilling)):
-                hold.pause()
-                with phase("loop/wait_work"):
-                    self._work.wait()
-                hold.resume()
-            if (self._draining and not self._active
-                    and self._inflight is None
-                    and not self._prefilling
-                    and not self._sched.resume_pending_locked()):
-                return "exit"
-            if self._closed:
-                # Hard close: abandon the in-flight window unforced
-                # (the device finishes it harmlessly; never block a
-                # close on a potentially dead op stream) and fail the
-                # waiters.
-                rec, self._inflight = self._inflight, None
-                if rec is not None:
-                    for _, req, adv in rec["parts"]:
-                        req.inflight -= adv
-                for req in self._active.values():
-                    self._fail_request(req, ServerClosed(
-                        "server shut down mid-request"))
-                self._active.clear()
-                self._fail_swapped_closed_locked()
-                return "exit"
-            try:
-                if self._inflight is None:
-                    with phase("loop/boundary"):
-                        self._sweep_cancelled_locked()
-                        self._sweep_finished_locked()
-                        # Preemption/resume join ONLY here — the
-                        # non-overlapped boundary, where every row's
-                        # tokens are reconciled and cache state is
-                        # quiescent. Checkpoints share the boundary
-                        # for the same reason: the swapout bytes must
-                        # cover a reconciled, nothing-in-flight
-                        # snapshot.
-                        self._maybe_resume_locked()
-                        self._maybe_preempt_locked()
-                        self._maybe_step_bucket_locked()
-                        self._maybe_checkpoint_locked()
-                        self._observe_boundary_locked()
-                    if not self._active:
-                        return "ran"
-                    if (self._spec > 0
-                            and any(req.sampling is None
-                                    for req in self._active.values())):
-                        all_greedy = all(
-                            req.sampling is None
-                            for req in self._active.values()
-                        )
-                        if (self._spec_window > 0
-                                and (all_greedy
-                                     or self._spec_sampled_window)):
-                            # Device-resident spec windows: draft +
-                            # verify + accept/reject run IN the
-                            # dispatched scan, so spec mode joins the
-                            # double-buffered pipeline instead of
-                            # forcing a boundary per pass. Sampled
-                            # co-tenants ride the scan too (rung 23,
-                            # knob-gated): one token per pass with
-                            # their positional keys split on device.
-                            with phase("loop/dispatch"):
-                                self._inflight = (
-                                    self._dispatch_spec_window_locked(
-                                        first=True
-                                    )
-                                )
-                            return "ran"
-                        if self._spec_window > 0:
-                            # Mixed batch with the sampled-window knob
-                            # off: the one remaining windowed-path
-                            # collapse, now counted instead of silent.
-                            self._spec_window_fallbacks["sampled"] += 1
-                        # Legacy per-pass speculation: drafting reads
-                        # emitted tokens on the host, so passes run at
-                        # boundaries only and never overlap.
-                        self._spec_pass()
-                        return "ran"
-                    with phase("loop/dispatch"):
-                        self._inflight = self._dispatch_window_locked(
-                            first=True
-                        )
+            self._lock_wait = self._phase("loop/lock_wait")
+            while True:
+                verdict = self._iterate_locked(hold)
+                if verdict != "again":
+                    return verdict
+
+    def _iterate_locked(self, hold: Hold) -> str:
+        """One iteration of ``_loop_once`` (lock held, by ``hold``):
+        "exit" ends the loop, "ran" the hold, and "again" says the
+        lock was let go and taken back inside the iteration."""
+        phase = self._phase
+        while (not self._active and self._inflight is None
+               and not self._closed
+               and not self._sched_attention_locked()
+               and not (self._draining
+                        and not self._prefilling)):
+            hold.pause()
+            with phase("loop/wait_work"):
+                self._work.wait()
+            hold.resume()
+        if (self._draining and not self._active
+                and self._inflight is None
+                and not self._prefilling
+                and not self._sched.resume_pending_locked()):
+            return "exit"
+        if self._closed:
+            # Hard close: abandon the in-flight window unforced
+            # (the device finishes it harmlessly; never block a
+            # close on a potentially dead op stream) and fail the
+            # waiters.
+            rec, self._inflight = self._inflight, None
+            if rec is not None:
+                for _, req, adv in rec["parts"]:
+                    req.inflight -= adv
+            for req in self._active.values():
+                self._fail_request(req, ServerClosed(
+                    "server shut down mid-request"))
+            self._active.clear()
+            self._fail_swapped_closed_locked()
+            return "exit"
+        try:
+            if self._inflight is None:
+                with phase("loop/boundary"):
+                    self._sweep_cancelled_locked()
+                    self._sweep_finished_locked()
+                    # Preemption/resume join ONLY here — the
+                    # non-overlapped boundary, where every row's
+                    # tokens are reconciled and cache state is
+                    # quiescent. Checkpoints share the boundary
+                    # for the same reason: the swapout bytes must
+                    # cover a reconciled, nothing-in-flight
+                    # snapshot.
+                    self._maybe_resume_locked()
+                    self._maybe_preempt_locked()
+                    self._maybe_step_bucket_locked()
+                    self._maybe_checkpoint_locked()
+                    self._observe_boundary_locked()
+                if not self._active:
                     return "ran"
-                prev, self._inflight = self._inflight, None
-                try:
-                    with phase("loop/boundary"):
-                        collapse = self._boundary_wanted_locked(prev)
-                    if collapse:
-                        # Overlap boundary: the pipeline collapses so a
-                        # cancel/swap/resize can join reconciled.
-                        self._pipeline_collapses[collapse] += 1
-                        if self.tracer is not None:
-                            self.tracer.event(
-                                "boundary", "serve",
-                                args={"reason": "reconcile",
-                                      "cause": collapse})
-                    else:
-                        # Enqueue N+1 on the carry BEFORE touching
-                        # N's result — the device starts N+1 the
-                        # moment N retires, while the host is still
-                        # in the harvest below. The next window rides
-                        # the SAME carry kind as the previous one
-                        # (plain and spec carries are separate device
-                        # state); a kind change joins at a boundary.
-                        if prev.get("kind") not in ("spec",
-                                                    "spec_sampled"):
-                            with phase("loop/dispatch"):
-                                self._inflight = (
-                                    self._dispatch_window_locked(
-                                        first=False
-                                    )
+                if (self._spec > 0
+                        and any(req.sampling is None
+                                for req in self._active.values())):
+                    all_greedy = all(
+                        req.sampling is None
+                        for req in self._active.values()
+                    )
+                    if (self._spec_window > 0
+                            and (all_greedy
+                                 or self._spec_sampled_window)):
+                        # Device-resident spec windows: draft +
+                        # verify + accept/reject run IN the
+                        # dispatched scan, so spec mode joins the
+                        # double-buffered pipeline instead of
+                        # forcing a boundary per pass. Sampled
+                        # co-tenants ride the scan too (rung 23,
+                        # knob-gated): one token per pass with
+                        # their positional keys split on device.
+                        with phase("loop/dispatch"):
+                            self._inflight = (
+                                self._dispatch_spec_window_locked(
+                                    first=True
                                 )
-                        elif (self._spec > 0
-                              and self._spec_window > 0):
-                            # Kind-matched redispatch: both spec kinds
-                            # share the device spec carry (pending +
-                            # drafting context), so a mixed pipeline
-                            # whose sampled rows all finished simply
-                            # redispatches as plain "spec" on the same
-                            # carry.
-                            with phase("loop/dispatch"):
-                                self._inflight = (
-                                    self._dispatch_spec_window_locked(
-                                        first=False
-                                    )
+                            )
+                        return "ran"
+                    if self._spec_window > 0:
+                        # Mixed batch with the sampled-window knob
+                        # off: the one remaining windowed-path
+                        # collapse, now counted instead of silent.
+                        self._spec_window_fallbacks["sampled"] += 1
+                    # Legacy per-pass speculation: drafting reads
+                    # emitted tokens on the host, so passes run at
+                    # boundaries only and never overlap.
+                    self._spec_pass()
+                    return "ran"
+                with phase("loop/dispatch"):
+                    self._inflight = self._dispatch_window_locked(
+                        first=True
+                    )
+                return "ran"
+            prev, self._inflight = self._inflight, None
+            try:
+                with phase("loop/boundary"):
+                    collapse = self._boundary_wanted_locked(prev)
+                if collapse:
+                    # Overlap boundary: the pipeline collapses so a
+                    # cancel/swap/resize can join reconciled.
+                    self._pipeline_collapses[collapse] += 1
+                    if self.tracer is not None:
+                        self.tracer.event(
+                            "boundary", "serve",
+                            args={"reason": "reconcile",
+                                  "cause": collapse})
+                else:
+                    # Enqueue N+1 on the carry BEFORE touching
+                    # N's result — the device starts N+1 the
+                    # moment N retires, while the host is still
+                    # in the harvest below. The next window rides
+                    # the SAME carry kind as the previous one
+                    # (plain and spec carries are separate device
+                    # state); a kind change joins at a boundary.
+                    if prev.get("kind") not in ("spec",
+                                                "spec_sampled"):
+                        with phase("loop/dispatch"):
+                            self._inflight = (
+                                self._dispatch_window_locked(
+                                    first=False
                                 )
-                        else:
-                            # Speculation was disabled with a spec
-                            # window in flight — collapse to a
-                            # boundary (counted: the next boundary
-                            # runs the non-windowed path).
-                            self._spec_window_fallbacks["spec_off"] += 1
-                    if prev.get("kind") in ("spec", "spec_sampled"):
-                        self._harvest_spec_window_locked(prev)
+                            )
+                    elif (self._spec > 0
+                          and self._spec_window > 0):
+                        # Kind-matched redispatch: both spec kinds
+                        # share the device spec carry (pending +
+                        # drafting context), so a mixed pipeline
+                        # whose sampled rows all finished simply
+                        # redispatches as plain "spec" on the same
+                        # carry.
+                        with phase("loop/dispatch"):
+                            self._inflight = (
+                                self._dispatch_spec_window_locked(
+                                    first=False
+                                )
+                            )
                     else:
-                        self._harvest_locked(prev)
-                except Exception:
-                    # prev was not reconciled — restore its inflight
-                    # accounting and drain it with whatever else is
-                    # queued, then poison below.
-                    self._drain_rec_locked(prev)
-                    raise
-            except Exception as e:
-                # Poison path: drain the in-flight window FIRST so
-                # recovery (revive/reform) never races a queued device
-                # program, then fail every waiter loudly.
-                self._drain_inflight_locked()
-                self._poison_locked(classify_failure(e))
-                return "exit"
+                        # Speculation was disabled with a spec
+                        # window in flight — collapse to a
+                        # boundary (counted: the next boundary
+                        # runs the non-windowed path).
+                        self._spec_window_fallbacks["spec_off"] += 1
+                if prev.get("kind") in ("spec", "spec_sampled"):
+                    self._harvest_spec_window_locked(prev)
+                elif self._harvest_locked(prev, hold):
+                    return "again"
+            except Exception:
+                # prev was not reconciled — restore its inflight
+                # accounting and drain it with whatever else is
+                # queued, then poison below.
+                self._drain_rec_locked(prev)
+                raise
+        except Exception as e:
+            # Poison path: drain the in-flight window FIRST so
+            # recovery (revive/reform) never races a queued device
+            # program, then fail every waiter loudly.
+            self._drain_inflight_locked()
+            self._poison_locked(classify_failure(e))
+            return "exit"
         return "ran"
 
     def _boundary_wanted_locked(self, prev: dict) -> str:
@@ -4135,11 +4231,11 @@ class PagedGenerationServer:
         newcomer joins at ticks the checkpoint clock, and at a cadence
         of 1 journals it before its first step, which rung 22 keeps.
         Otherwise a window's carry is one token a row, and the
-        newcomer's is host-known (its pick is done, nothing of it is
-        in flight): the next overlapped dispatch feeds that row from
-        the host and the others from the carry
-        (``_dispatch_window_locked``), so a newcomer to such a
-        pipeline is no cause. ``scheduler``: a
+        newcomer's is picked (on the device, or host-known; nothing
+        of it is in flight): the next overlapped dispatch feeds that
+        row from the pool's row of first tokens or from the host, and
+        the others from the carry (``_dispatch_window_locked``), so a
+        newcomer to such a pipeline is no cause. ``scheduler``: a
         resumable or starved-but-preemptable head collapses the
         pipeline to a boundary, where the swap may join. ``bucket``:
         the device batch dim can only resize with nothing in flight.
@@ -4194,9 +4290,12 @@ class PagedGenerationServer:
         feeds it the previous window's final token row, still resident
         on device, while a row with nothing in flight (a newcomer: it
         sat out the window in flight, and ``next_token`` is its pick)
-        is fed from the host like a first window's; where every row is
-        such a one (all that were in flight end with that window), the
-        whole row is the host's and the carry is not read. The per-row
+        is fed as a first window's is: ``next_token`` as the host has
+        it, which for a pick still on the device is
+        ``FIRST_ON_DEVICE``, and the cache takes the row's token from
+        where the pick put it; where every row is such a one (all that
+        were in flight end with that window), the carry is not read.
+        The per-row
         cap is ``n_new - len(generated) - inflight - 1``: committed position
         plus the pending token the finish-check emits stepless, so a
         speculative window can never decode past a budget the host
@@ -4287,13 +4386,58 @@ class PagedGenerationServer:
         return {"window": w, "parts": recs, "handle": handle,
                 "depth": 0 if first else 1, "bucket": n, "t0": t0}
 
-    def _harvest_locked(self, rec: dict) -> None:
+    def _await_window_unlocked(self, rec: dict, hold: Hold,
+                               waited) -> None:
+        """Wait for the device to finish ``rec`` with the work lock
+        released (held on entry and on return, by ``hold``, the loop's:
+        let go and taken again inside it, as around a
+        ``Condition.wait``, so the ledgers stay exact). Prefill
+        chunks, admissions and cancels get the lock meanwhile; none
+        dispatches or harvests a decode window, and ``_harvesting``
+        tells them one is out that ``_inflight`` may not show.
+        ``waited`` (``loop/harvest_wait``) ends with the read, and the
+        wait to have the lock back is a ``loop/lock_wait`` of its
+        own."""
+        self._harvesting = rec
+        # What this hold did so far, for a reader who finds the lock
+        # taken later: the loop may not leave its hold for many
+        # windows (``_loop_once``).
+        self._publish_locked(self._phase.last)
+        hold.release()
+        try:
+            # Whoever waits for the lock runs first, even if the
+            # window is there already (``_loop``'s hand-off).
+            time.sleep(0)
+            self._cache.await_window(rec["handle"])
+        finally:
+            waited.stop()
+            hold.reacquire(self._phase("loop/lock_wait").start())
+            self._harvesting = None
+
+    def _harvest_locked(self, rec: dict, hold: Hold) -> bool:
         """Force an in-flight window's tokens and reconcile (lock
         held): emission, budget finishes, carry of the new pending
         token. Each row's stream truncates at its own dispatch-time
         cap (``adv``) — rows past their cap were frozen on device and
-        their produced entries merely repeat the last live token."""
+        their produced entries merely repeat the last live token.
+        True where the lock was let go and taken back on the way.
+
+        The blocking read is made with the lock released where the
+        pool allows it (``_await_window_unlocked``); what the lock
+        guards is looked at again after it: a hard close or a
+        poisoning that landed meanwhile has failed the waiters, and
+        the window is dropped unreconciled for the loop's next
+        iteration to exit on; a row cancelled or released meanwhile is
+        seen row by row below, as it always was."""
         with self._phase("loop/harvest_wait") as waited:
+            if self._unlocked_reads:
+                self._await_window_unlocked(rec, hold, waited)
+                if self._closed:
+                    rec["counted"] = True
+                    for _, req, adv in rec["parts"]:
+                        req.inflight -= adv
+                    return True
+            # No wait is left in this where the loop waited above.
             produced = np.asarray(
                 self._cache.harvest_window(rec["handle"]))
         # Attribution (rung 25): the forced transfer is where the host
@@ -4347,7 +4491,18 @@ class PagedGenerationServer:
                 # bookkeeping both, and the host never compares
                 # per-token.
                 stop_at = int(stop_row[slot])
-                if 0 < stop_at and not req.cancelled:
+                hit = stop_at > 0
+                if req.first_dev is not None:
+                    # The row's first window: its first token was the
+                    # device's until now (computed before this window
+                    # ran, so the read waits for nothing). Had it been
+                    # the row's stop token the host would have ended
+                    # the request on it with no step at all: it ends
+                    # there now, and the steps are discarded.
+                    self._first_token_locked(req)
+                    if req.next_token == req.stop_token:
+                        hit, stop_at = True, 0
+                if hit and not req.cancelled:
                     # Emit the pending token plus everything up to AND
                     # INCLUDING the stop token, then finish; steps past
                     # the stop decoded garbage inside the granted cap
@@ -4390,6 +4545,7 @@ class PagedGenerationServer:
                 window=w,
             )
             self._window = self._autotune.window()
+        return self._unlocked_reads
 
     def _dispatch_spec_window_locked(self, first: bool) -> dict | None:
         """Enqueue one device-resident spec window — ``_spec_window``

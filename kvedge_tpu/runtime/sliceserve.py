@@ -298,6 +298,16 @@ class SlicePagedKVCache(PagedKVCache):
             max_pages_per_seq=max_pages_per_seq, kv_dtype=kv_dtype,
         )
 
+    # Every read of this pool goes through the op stream (a flush, a
+    # deadline), which the server's work lock serializes: the serving
+    # layer reads its windows and first tokens with the lock held, and
+    # no first token stays on the device (a prefill chunk's logits are
+    # read back by the op that made them).
+    unlocked_reads = False
+
+    def _init_firsts(self):
+        return None
+
     # ---- refused host I/O ------------------------------------------------
 
     def snapshot_pages(self, ids):

@@ -173,8 +173,15 @@ _SERVE_METRIC_FIELDS = (
      "dispatched-but-unharvested windows right now (0 or 1 — the "
      "pipeline is double-buffered, never deeper)"),
     ("pipeline_joins_total", "serve_pipeline_joins_total", "counter",
-     "rows that entered an overlapped window from the host's row (a "
-     "newcomer joining on the carry, no boundary taken)"),
+     "rows that entered an overlapped window as newcomers (joining on "
+     "the carry, no boundary taken), their first token the host's or "
+     "still on the device"),
+    ("first_tokens_on_device_total", "serve_first_tokens_on_device_total",
+     "counter",
+     "first tokens picked by a program after the last prefill chunk and "
+     "left on the device, read when the row's first window is harvested "
+     "(the rest, on a server that speculates or checkpoints, were read "
+     "back with the work lock held)"),
     ("expert_reads_total", "serve_expert_reads_total", "counter",
      "(layer, held expert, step) expert matrices the decode windows "
      "read: the ones a live row picked where a window's program walks "

@@ -69,16 +69,19 @@ chunks run), ``loop/wait_work`` (inside ``Condition.wait``, nothing to
 do), ``loop/boundary`` (sweeps, resume/preempt, bucket step,
 checkpoint, observe), ``loop/dispatch`` (building and enqueueing a
 window), ``loop/harvest_wait`` (the blocking read of a window's
-tokens; the ``window_device_ms`` histogram) and ``loop/emit``
+tokens, the work lock released where the pool allows it, and the wait
+to have it back then a ``loop/lock_wait`` of its own; the
+``window_device_ms`` histogram) and ``loop/emit``
 (bookkeeping after it; ``window_host_ms``). The submit path's, per
 prefill chunk on the caller's thread (:data:`ADMIT_PHASES`):
 ``admit/lock_wait`` (asking for the work lock to holding it;
 ``prefill_lock_wait_ms``) and ``admit/prefill_chunk`` (lock held, the
 chunk dispatched and whatever it blocks on; ``prefill_chunk_ms``);
 once per request ``admit/first_pick`` (asking for the lock again to
-the first token read back from the prefill's logits: the caller
-waits, lock held, for its chunks and for the window the device was
-given before them).
+the first token picked from the prefill's logits: dispatched as a
+program and left on the device, or, on a server that speculates or
+checkpoints, read back, the caller waiting, lock held, for its chunks
+and for the window the device was given before them).
 
 The work lock's ledger (ISSUE 38). The server's one lock is a
 :class:`TimedLock`: it stamps every acquire and release and adds the
@@ -88,8 +91,10 @@ takes the lock does so through a :class:`Hold` that says who it is
 its hold, a phase ``lock/<name>`` with the three sinks above (so a
 capture shows ``kvedge/lock/<name>`` on the holder's line), to
 ``lock_held_ms[name]``. A hold parked in a ``Condition.wait`` is
-paused: it holds nothing. What the names leave of the total is what
-some site took without saying who it was.
+paused: it holds nothing; nor does the loop's while it reads a window
+back, the lock released and taken again inside the one hold
+(:meth:`Hold.release`, :meth:`Hold.reacquire`). What the names leave
+of the total is what some site took without saying who it was.
 
 Export targets:
 
@@ -615,6 +620,18 @@ class Hold:
         self._phase.stop()
         self._ledger.current = None
         self._ledger.lock.release(self.last)
+
+    def reacquire(self, waited: "Phase") -> None:
+        """The hold goes on after a :meth:`release` inside the block
+        it was entered for: the holder let the lock go for a wait that
+        needs nothing of what it guards (the loop's read of a window),
+        and is the same holder again from the lock's own stamp.
+        ``waited``, a phase the caller started when it asked, is the
+        wait for the lock and its record, and ends on that stamp."""
+        self._ledger.lock.acquire()
+        self.last = t = self._ledger.lock.t_acquired
+        waited.stop(t)
+        self._begin()
 
     def __enter__(self) -> "Hold":
         self.acquire()

@@ -707,6 +707,56 @@ def test_the_exaone_cell_fits_and_its_full_layer_takes_the_blocked_form(
     assert memory.alias_size_in_bytes >= pools
 
 
+@pytest.mark.parametrize("program", ["pick_greedy", "pick_sampled",
+                                     "join_firsts", "join_carry"])
+def test_the_first_tokens_programs_compile_once_for_the_claimed_cell(
+        chip, program):
+    """What keeps a request's first token on the device
+    (kvcache.PagedKVCache.pick_first and the two joins; ISSUE 47), at
+    the shapes of ``granite-4.0-h-small.batchgen``: the chip's compiler
+    takes each, the pick is one program a sampling mode (its arguments
+    are the logits, the pool's row of first tokens and scalars: no
+    bucket, no chunk length, and the slot is traced) and the join with
+    the carry one a (window length, bucket)."""
+    from benchmark import cellspec
+    from kvedge_tpu.models import kvcache
+
+    cell = cellspec.load_cell("granite-4.0-h-small.batchgen")
+    payload = cell.config["payload"]
+    slots, window = payload["serving_slots"], payload["serving_window"]
+    vocab = cell.config["model"]["vocab"]
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    logits, firsts = on_chip((vocab,), jnp.float32), on_chip((slots,),
+                                                             jnp.int32)
+    slot, row = on_chip((), jnp.int32), on_chip((slots,), jnp.int32)
+    if program == "pick_greedy":
+        lowered = kvcache._pick_first_greedy.lower(logits, firsts, slot)
+        shapes = [(vocab,), (slots,), ()]
+    elif program == "pick_sampled":
+        lowered = kvcache._pick_first_sampled.lower(
+            logits, firsts, slot, on_chip((2,), jnp.uint32),
+            on_chip((), jnp.float32), on_chip((), jnp.float32))
+        shapes = [(vocab,), (slots,), (), (2,), (), ()]
+    elif program == "join_firsts":
+        lowered = kvcache._join_firsts.lower(row, firsts)
+        shapes = [(slots,), (slots,)]
+    else:
+        lowered = kvcache._join_carry.lower(
+            on_chip((window + 2, slots), jnp.int32), row, firsts,
+            window - 1)
+        shapes = [(window + 2, slots), (slots,), (slots,)]
+    assert [a.shape for a in jax.tree_util.tree_leaves(
+        lowered.in_avals)] == shapes
+    compiled = lowered.compile()
+    out = jax.tree_util.tree_leaves(compiled.out_avals if hasattr(
+        compiled, "out_avals") else lowered.out_info)
+    assert [tuple(a.shape) for a in out] == (
+        [(slots,), ()] if program.startswith("pick") else [(slots,)])
+
+
 def test_one_product_over_all_experts_does_not_fit_a_256_token_chunk(
         chip, monkeypatch):
     """Why ``moe.held_experts_ffn`` states the tokens once an expert

@@ -267,12 +267,15 @@ def test_the_parked_loop_and_a_parked_waiter_hold_nothing(params):
                                    page_size=PAGE, prefill_chunk=CHUNK,
                                    window=4)
     gate = threading.Event()
-    trip = server._loop_once
+    wait = server._cache.await_window
 
-    def gated_trip():
+    def gated_wait(handle):
+        # where the loop stands with the lock free since PR 47: in its
+        # wait for a window (it leaves its hold for nothing else while
+        # windows are in flight)
         while gate.is_set():
             time.sleep(0.001)
-        return trip()
+        return wait(handle)
 
     try:
         limit = time.monotonic() + 60
@@ -282,7 +285,7 @@ def test_the_parked_loop_and_a_parked_waiter_hold_nothing(params):
         idle = [server.stats()]
         time.sleep(0.1)
         idle.append(server.stats())
-        server._loop_once = gated_trip
+        server._cache.await_window = gated_wait
         occupier = server.submit_stream([7, 7, 7], 30)
         next(occupier)
         gate.set()                          # the loop stands still, lock free

@@ -30,8 +30,10 @@ from kvedge_tpu.models.kvcache import PagedCacheError, PagedKVCache
 from kvedge_tpu.models.serving import (
     PagedGenerationServer,
     RequestCancelled,
+    ServerClosed,
 )
-from kvedge_tpu.runtime.failures import ServingFailure
+from kvedge_tpu.runtime.failures import DeviceOpTimeout, ServingFailure
+from kvedge_tpu.runtime.tracing import LOCK_HOLDERS
 from kvedge_tpu.runtime.status import render_metrics
 
 pytestmark = pytest.mark.overlap
@@ -588,4 +590,395 @@ def test_one_token_newcomer_finishes_beside_a_running_pipeline(params):
         assert LONG[0] + [first] + list(stream) \
             == long_reference(params, *LONG)
     finally:
+        server.close()
+
+
+# ---- the first token stays on the device (ISSUE 47) ----------------------
+
+
+class Gate:
+    """The loop's wait for a window (``cache.await_window``, made with
+    the work lock released) held back by an event: while the gate is
+    shut and ``entered`` is set the loop stands in
+    ``loop/harvest_wait`` and holds nothing, for as long as the test
+    needs to admit, cancel or close beside it."""
+
+    def __init__(self, server):
+        self._real = server._cache.await_window
+        self.open = threading.Event()
+        self.open.set()
+        self.entered = threading.Event()
+        server._cache.await_window = self
+
+    def __call__(self, handle):
+        self.entered.set()
+        assert self.open.wait(120), "the test never opened the gate"
+        return self._real(handle)
+
+    def shut(self):
+        """Returns once the loop waits at the shut gate."""
+        self.entered.clear()
+        self.open.clear()
+        assert self.entered.wait(60), "the loop harvested nothing"
+
+
+def _host_picks(server):
+    """The parent's path: the handler reads its pick back, lock held,
+    and the loop reads its windows lock held."""
+    server._unlocked_reads = False
+    return server
+
+
+def _until(cond, what, seconds=60.0):
+    deadline = time.monotonic() + seconds
+    while not cond():
+        assert time.monotonic() < deadline, f"never: {what}"
+        time.sleep(0.005)
+
+
+FIRST_TOKEN_CASES = {
+    "greedy": ([5, 9, 2, 7, 1, 1, 4], 21, None),
+    "sampled": ([1, 2, 3, 4], 24, SAMPLING),
+    "one-token": ([5, 9, 2], 1, None),
+    "one-token-sampled": ([1, 2, 3, 4], 1, SAMPLING),
+    "stop-is-first": ([5, 9, 2, 7, 1, 1, 4], 21, None),
+    "stop-is-later": ([5, 9, 2, 7, 1, 1, 4], 21, None),
+}
+
+
+def _first_token_case(params, kind, on_device):
+    """One request, ``kind``'s, served alone (its first window is a
+    boundary's) and then as a newcomer to the long request's pipeline
+    (it joins an overlapped window beside a row on the carry, or,
+    asked for one token or stopped on its first, finishes beside it).
+    Returns both streams, the long request's, and the stats."""
+    prompt, n_new, sampling = FIRST_TOKEN_CASES[kind]
+    kw = {"sampling": sampling}
+    if kind.startswith("stop"):
+        whole = long_reference(params, prompt, n_new)[len(prompt):]
+        kw["stop_token"] = whole[0 if kind == "stop-is-first" else 6]
+    server = PagedGenerationServer(params, LONG_CFG, slots=3, pages=80,
+                                   window=4, prefill_chunk=3,
+                                   prefix_cache=False)
+    if not on_device:
+        _host_picks(server)
+    try:
+        alone = server.submit(prompt, n_new, **kw)
+        _slowed(server, 0.02)
+        stream = server.submit_stream(*LONG)
+        first = next(stream)
+        joined = server.submit(prompt, n_new, timeout=60.0, **kw)
+        long = LONG[0] + [first] + list(stream)
+        return alone, joined, long, server.stats()
+    finally:
+        server.close()
+
+
+@pytest.mark.parametrize("kind", sorted(FIRST_TOKEN_CASES))
+def test_a_first_token_kept_on_the_device_is_the_hosts_pick(params, kind):
+    """Token for token what the handler's own read of the pick serves
+    (the parent's path, kept where a server speculates or
+    checkpoints), alone and joining a running pipeline; every pick
+    stayed on the device and none was read with the lock in hand."""
+    want = _first_token_case(params, kind, on_device=False)
+    got = _first_token_case(params, kind, on_device=True)
+    assert got[:3] == want[:3]
+    assert got[0] == got[1]
+    assert got[2] == long_reference(params, *LONG)
+    prompt, n_new, sampling = FIRST_TOKEN_CASES[kind]
+    if not kind.startswith("stop"):
+        assert got[0] == long_reference(params, prompt, n_new, sampling)
+    elif kind == "stop-is-first":
+        assert len(got[0]) == len(prompt) + 1
+    picks = got[3]["phase_ms"]["admit/first_pick"][0]
+    assert picks == 3
+    assert got[3]["first_tokens_on_device_total"] == picks
+    assert want[3]["first_tokens_on_device_total"] == 0
+    assert got[3]["stop_finishes_total"] == want[3]["stop_finishes_total"]
+    # A row that can still step joins on the carry; one that ends on
+    # its first token without a step takes the boundary it always took.
+    if "one" in kind:
+        assert got[3]["pipeline_joins_total"] == 0
+        assert got[3]["pipeline_collapses"]["stop"] >= 1
+    else:
+        assert got[3]["pipeline_joins_total"] == 1
+    text = render_metrics({"serving": got[3]})
+    assert f"kvedge_serve_first_tokens_on_device_total {picks}" in text
+
+
+def test_a_cancel_between_the_pick_and_the_join_ends_the_request(params):
+    """The newcomer's pick is dispatched and the request active while
+    the loop waits for a window (lock released), and it is cancelled
+    before any window carried its row: the host never reads the token,
+    the request ends cancelled, and the long request's stream is
+    generate's."""
+    server = PagedGenerationServer(params, LONG_CFG, slots=2, pages=80,
+                                   window=4, prefix_cache=False)
+    try:
+        gate = Gate(server)
+        stream = server.submit_stream(*LONG)
+        first = next(stream)
+        gate.shut()
+        src = server.submit_stream([1, 2, 3], 150)
+        _until(lambda: server.stats()["in_flight"] == 2,
+               "the newcomer active beside a loop that waits")
+        req = src._req
+        assert req.first_dev is not None and req.state == "join_wait"
+        src.cancel()
+        gate.open.set()
+        with pytest.raises(RequestCancelled):
+            list(src)
+        assert req.first_dev is not None and not req.generated
+        assert not req.t_first
+        stats = server.stats()
+        assert stats["pipeline_collapses"]["cancel"] >= 1
+        assert stats["first_tokens_on_device_total"] == 2
+        assert LONG[0] + [first] + list(stream) \
+            == long_reference(params, *LONG)
+    finally:
+        server.close()
+
+
+def test_time_to_first_token_is_stamped_when_the_host_has_it(params):
+    """Admitted while the loop waits at a shut gate, a request is
+    picked, active and in ``join_wait``, and has no time to first
+    token: that comes with the harvest of the first window that
+    carried its row, just before the token's put on its stream."""
+    server = PagedGenerationServer(params, LONG_CFG, slots=2, pages=80,
+                                   window=4, prefix_cache=False)
+    try:
+        gate = Gate(server)
+        stream = server.submit_stream(*LONG)
+        next(stream)
+        gate.shut()
+        src = server.submit_stream([5, 9, 2, 7], 9)
+        req = src._req
+        _until(lambda: req.first_dev is not None, "the pick dispatched")
+        ttft = server.stats()["ttft_ms"]["count"]
+        time.sleep(0.2)
+        assert not req.t_first and not req.generated
+        assert server.stats()["ttft_ms"]["count"] == ttft
+        gate.open.set()
+        got = list(src)
+        assert [5, 9, 2, 7] + got == long_reference(params,
+                                                    [5, 9, 2, 7], 9)
+        assert req.first_dev is None
+        # the pick was dispatched 0.2 s and more before the host read
+        # the token, and the token was on the stream at once
+        assert req.t_first - req.t_admit >= 0.2
+        assert 0 <= req.t_emit - req.t_first < 0.1
+        assert req.state_ms["join_wait"] >= 150
+        assert server.stats()["ttft_ms"]["count"] == ttft + 1
+        list(stream)
+    finally:
+        server.close()
+
+
+def test_a_preempted_row_takes_its_first_token_to_the_host(params):
+    """A batch request preempted before any window carried its row:
+    the row of first tokens is by slot, which the resume changes, so
+    the swap reads the token; the resumed stream is generate's."""
+    server = PagedGenerationServer(
+        params, LONG_CFG, slots=1, pages=80, window=4,
+        prefix_cache=False, sched_policy="strict",
+        sched_swap_budget_mb=64)
+    try:
+        with server._hold("control"):
+            # Admitted and picked while the loop cannot run: the
+            # preemption finds a row no window has carried.
+            batch = threading.Thread(target=lambda: got.append(
+                server.submit([5, 9, 2, 7], 30, priority="batch",
+                              timeout=120.0)))
+            got: list = []
+            batch.start()
+        _until(lambda: server.stats()["first_tokens_on_device_total"] == 1,
+               "the batch request picked")
+        fast = server.submit([1, 2, 3], 5, timeout=120.0)
+        batch.join(timeout=120)
+        assert fast == long_reference(params, [1, 2, 3], 5)
+        assert got == [long_reference(params, [5, 9, 2, 7], 30)]
+    finally:
+        server.close()
+
+
+def test_the_pick_and_the_joins_are_lowered_once(params):
+    """The pick program depends on the vocabulary's width and on the
+    sampling mode (and the pool's slots), the join on the window
+    length and the bucket: a second round of the same admissions into
+    a running pipeline lowers nothing, whichever slot a row takes."""
+    lowered: list = []
+
+    def on_duration(event, duration, **kw):
+        if event == "/jax/core/compile/jaxpr_to_mlir_module_duration":
+            lowered.append(str(kw.get("fun_name")))
+
+    import jax.monitoring as mon
+    from jax._src import monitoring as mon_src
+
+    server = PagedGenerationServer(params, LONG_CFG, slots=5, pages=120,
+                                   window=4, prefill_chunk=3,
+                                   prefix_cache=False)
+    mon.register_event_duration_secs_listener(on_duration)
+    try:
+        _slowed(server, 0.02)
+
+        def round_trip():
+            stream = server.submit_stream(*LONG)
+            first = next(stream)
+            got = [server.submit(prompt, n_new, sampling=sampling)
+                   for prompt, n_new, sampling in NEWCOMERS]
+            return [first] + list(stream), got
+
+        want = round_trip()
+        picks = [name for name in lowered if "_pick_first" in name]
+        assert sorted(picks) == ["jit(_pick_first_greedy)",
+                                 "jit(_pick_first_sampled)"]
+        # one join a window length, over the one bucket: the windows
+        # of 4 steps and what the budgets' tails cut them to
+        joins = [name for name in lowered if "_join_carry" in name]
+        assert 1 <= len(joins) <= 3
+        del lowered[:]
+        assert round_trip() == want
+        assert lowered == []
+        stats = server.stats()
+        assert stats["first_tokens_on_device_total"] == 8
+        assert stats["pipeline_joins_total"] == 6
+    finally:
+        mon_src.unregister_event_duration_listener(on_duration)
+        server.close()
+
+
+# ---- the loop reads a window back with the lock released (ISSUE 47) ------
+
+
+def _gated_server(params, **kw):
+    """A long request streams, and the loop stands at a shut gate in
+    its wait for a window: ``(server, gate, stream, first token)``."""
+    server = PagedGenerationServer(params, LONG_CFG, slots=2, pages=80,
+                                   window=4, prefill_chunk=3,
+                                   prefix_cache=False, **kw)
+    try:
+        gate = Gate(server)
+        stream = server.submit_stream(*LONG)
+        first = next(stream)
+        gate.shut()
+    except BaseException:
+        server.close()
+        raise
+    return server, gate, stream, first
+
+
+def _lock_gain(a: dict, b: dict) -> tuple:
+    """(the lock's own total, what the holders' names account for)
+    gained between two snapshots, ms."""
+    total = b["lock_held_ms_total"] - a["lock_held_ms_total"]
+    named = sum(b["lock_held_ms"][n][1] - a["lock_held_ms"][n][1]
+                for n in LOCK_HOLDERS)
+    return total, named
+
+
+@pytest.mark.parametrize("debug_locks", [False, True],
+                         ids=["lock", "debuglock"])
+def test_prefill_chunks_get_the_lock_while_the_loop_reads_a_window(
+        params, debug_locks):
+    """The loop is inside ``loop/harvest_wait`` and holds nothing: a
+    newcomer's admission, its four chunks and its pick all take the
+    lock and finish beside it; the wait is the phase's and nobody's
+    hold, and every millisecond the lock was held has a name."""
+    server, gate, stream, first = _gated_server(params,
+                                                debug_locks=debug_locks)
+    try:
+        a = server.stats()
+        assert server._harvesting is not None
+        prompt = [100, 50, 7, 7, 7, 2, 9, 9, 4, 1, 6]
+        src = server.submit_stream(prompt, 12)
+        _until(lambda: server.stats()["in_flight"] == 2,
+               "the newcomer active beside a loop that waits")
+        time.sleep(0.25)
+        b = server.stats()
+        assert b["phase_ms"]["admit/prefill_chunk"][0] \
+            - a["phase_ms"]["admit/prefill_chunk"][0] == 4
+        assert b["lock_held_ms"]["admit/first_pick"][0] \
+            - a["lock_held_ms"]["admit/first_pick"][0] == 1
+        # the loop dispatched and harvested nothing meanwhile
+        assert b["lock_held_ms"]["loop"] == a["lock_held_ms"]["loop"]
+        assert b["overlap_windows_total"] == a["overlap_windows_total"]
+        wall = (b["clock_s"] - a["clock_s"]) * 1e3
+        waited = (b["phase_ms"]["loop/harvest_wait"][1]
+                  - a["phase_ms"]["loop/harvest_wait"][1])
+        assert wall >= 250.0 and waited == pytest.approx(wall, abs=5.0)
+        total, named = _lock_gain(a, b)
+        assert total < wall - 200.0      # the wait is in no hold
+        assert total == pytest.approx(named, abs=1e-6)
+        gate.open.set()
+        assert prompt + list(src) == long_reference(params, prompt, 12)
+        assert LONG[0] + [first] + list(stream) \
+            == long_reference(params, *LONG)
+        c = server.stats()
+        # (a loop parked for work, at the end, stamps its pause and
+        # its release apart: microseconds, as before this issue)
+        total, named = _lock_gain(a, c)
+        assert total == pytest.approx(named, abs=0.5)
+        assert c["loop_ms_total"] == pytest.approx(
+            sum(c["phase_ms"][name][1] for name in c["phase_ms"]
+                if name.startswith("loop/")
+                and name != "loop/window_release"), rel=1e-6)
+    finally:
+        gate.open.set()
+        server.close()
+
+
+@pytest.mark.parametrize("what", ["close", "poisoning", "cancel"])
+def test_what_lands_during_the_wait_is_seen_when_the_lock_is_back(
+        params, what):
+    """A hard close, a poisoning (a newcomer's prefill chunk fails
+    terminally) and a cancel each take the lock while the loop waits
+    for its window without it; the loop finds them when it has the
+    lock again: the first two end it without a token more, the third
+    it honours at the boundary it always took."""
+    server, gate, stream, first = _gated_server(params)
+    try:
+        loop = server._thread
+        emitted = len(stream._req.generated)
+        if what == "close":
+            closer = threading.Thread(target=server.close)
+            closer.start()
+            _until(lambda: server._closed, "the close under the lock")
+            gate.open.set()
+            closer.join(timeout=60)
+            with pytest.raises(ServerClosed):
+                list(stream)
+        elif what == "poisoning":
+            def failing(*a, **kw):
+                raise DeviceOpTimeout("injected: the chunk's op hung")
+
+            server._cache.prefill_chunk = failing
+            with pytest.raises(DeviceOpTimeout):
+                server.submit([5, 9, 2], 4)
+            assert server._poison is not None and server._closed
+            gate.open.set()
+            with pytest.raises(DeviceOpTimeout):
+                list(stream)
+        else:
+            stream.cancel()
+            gate.open.set()
+            with pytest.raises(RequestCancelled):
+                list(stream)
+            assert server.stats()["pipeline_collapses"]["cancel"] == 1
+            # the server lives on
+            assert server.submit([5, 9, 2, 7], 9) == long_reference(
+                params, [5, 9, 2, 7], 9)
+        if what != "cancel":
+            loop.join(timeout=60)
+            assert not loop.is_alive()
+            # not a token of the window it waited for was emitted
+            assert len(stream._req.generated) == emitted
+            assert stream._req.inflight == 0
+        assert server._harvesting is None
+        stats = server.stats()
+        total = stats["lock_held_ms_total"]
+        named = sum(stats["lock_held_ms"][n][1] for n in LOCK_HOLDERS)
+        assert total == pytest.approx(named, abs=0.5)
+    finally:
+        gate.open.set()
         server.close()

@@ -219,18 +219,22 @@ def test_preempt_resume_on_slice_cache_is_exact(params):
 
 def gate_the_loop(server) -> threading.Event:
     """While the returned event is set the decode loop stands still
-    between two trips (lock released): an occupier cannot finish and
-    hand its slot on before every waiter of a test has parked, however
-    slowly a loaded machine runs the waiters' threads."""
+    where it has released the lock, between two trips or, with a
+    window in flight (it then goes round inside one trip), in its wait
+    for that window: an occupier cannot finish and hand its slot on
+    before every waiter of a test has parked, however slowly a loaded
+    machine runs the waiters' threads."""
     held = threading.Event()
-    trip = server._loop_once
 
-    def gated_trip():
-        while held.is_set():
-            time.sleep(0.001)
-        return trip()
+    def gated(step):
+        def stand_then(*args):
+            while held.is_set():
+                time.sleep(0.001)
+            return step(*args)
+        return stand_then
 
-    server._loop_once = gated_trip
+    server._loop_once = gated(server._loop_once)
+    server._cache.await_window = gated(server._cache.await_window)
     return held
 
 
