@@ -103,14 +103,22 @@ class MeshSpec:
 _VALID_PRESETS = ("", "probe", "flagship")
 
 
-def _parse_speculative(value):
-    """``serving_speculative``: an int draft length or the string
-    "auto" (resolved at serve boot by the spec-economics probe,
-    models/serving.py resolve_speculation). Type errors surface in
-    validate() with the full accepted-values message."""
-    if isinstance(value, str):
-        return value  # validate() accepts only "auto"
-    return int(value)
+# [payload] keys retired in PR 48 with their old defaults: a document an
+# older ``to_toml`` wrote still parses, anything else is refused by name.
+_RETIRED_SPECULATION_KEYS = {
+    "serving_speculative": 0,
+    "serving_spec_window": 0,
+    "serving_spec_sampled_window": True,
+}
+
+
+def _refuse_speculation(payload_doc: Mapping) -> None:
+    for key, default in _RETIRED_SPECULATION_KEYS.items():
+        value = payload_doc.get(key, default)
+        if type(value) is not type(default) or value != default:
+            raise RuntimeConfigError(
+                f"[payload] {key} = {value!r} is refused: the paged "
+                "server does not speculate since PR 48")
 
 
 def _parse_window(value):
@@ -495,37 +503,6 @@ class RuntimeConfig:
     # bounds the compiled-program set and admission latency.
     serving_window_min: int = 1
     serving_window_max: int = 256
-    # Server-wide speculative decoding for the paged backend: draft
-    # length K (0 = off), or "auto". Greedy traffic advances by batched
-    # verify passes — K prompt-lookup drafts per slot, up to K+1 tokens
-    # per slot per model forward, token-for-token identical to plain
-    # greedy decode (drafts accept only where they equal the model's
-    # own argmax). Pays where decode is weight-bandwidth-bound. GREEDY
-    # requests' page budgets grow by K slack positions (sampled ones
-    # can never accept a draft and reserve nothing extra). "auto"
-    # probes verify-pass and window cost at serve boot (draft length
-    # 4) and turns speculation off when windowed decode dominates its
-    # best case;
-    # an explicit K keeps the operator's choice but logs a loud
-    # warning under the same test (single-host serve only).
-    serving_speculative: int | str = 0
-    # Device-resident speculative windows (SERVING.md rung 20): W > 0
-    # batches W draft+verify passes into ONE dispatched device program
-    # — the n-gram drafting, accept/reject, KV commits, budget
-    # freezing, and the pending-token chain all run in the scan, so
-    # the host round trip amortizes over up to W*(1+K) tokens instead
-    # of taxing every pass (one round trip per pass was the paged-spec
-    # soft spot). Requires serving_speculative > 0 and the overlapped
-    # loop; an all-greedy batch rides windows. Token streams are
-    # bit-identical either way. 0 = off (legacy per-pass speculation).
-    serving_spec_window: int = 0
-    # Rung 23: keep mixed greedy+sampled batches on the windowed spec
-    # path (sampled rows draw their next token on device, exact key
-    # schedule preserved). false = a sampled co-tenant collapses the
-    # batch to the legacy per-pass program (counted in
-    # spec_window_fallbacks_total{cause="sampled"}). No effect unless
-    # serving_spec_window > 0.
-    serving_spec_sampled_window: bool = True
     # Retry-after hint (seconds) carried by poisoned-pool refusals and
     # /healthz while degraded — what a refused client is told to wait
     # before retrying. When the recovery supervisor is active and a
@@ -672,6 +649,7 @@ class RuntimeConfig:
         dist_doc = dict(doc.get("distributed", {}))
         status = dict(doc.get("status", {}))
         payload_doc = dict(doc.get("payload", {}))
+        _refuse_speculation(payload_doc)
 
         axes_doc = mesh_doc.get("axes", dict(MeshSpec.axes))
         if not isinstance(axes_doc, Mapping):
@@ -814,18 +792,6 @@ class RuntimeConfig:
                 serving_window_max=int(
                     payload_doc.get("serving_window_max",
                                     cls.serving_window_max)
-                ),
-                serving_spec_window=int(
-                    payload_doc.get("serving_spec_window",
-                                    cls.serving_spec_window)
-                ),
-                serving_spec_sampled_window=payload_doc.get(
-                    "serving_spec_sampled_window",
-                    cls.serving_spec_sampled_window
-                ),
-                serving_speculative=_parse_speculative(
-                    payload_doc.get("serving_speculative",
-                                    cls.serving_speculative)
                 ),
                 serving_retry_after_s=float(
                     payload_doc.get("serving_retry_after_s",
@@ -1072,28 +1038,6 @@ class RuntimeConfig:
                 "[payload] serving_window_min must be <= "
                 "serving_window_max (controller bounds)"
             )
-        if self.serving_speculative != "auto" and not (
-            isinstance(self.serving_speculative, int)
-            and 0 <= self.serving_speculative <= 16
-        ):
-            raise RuntimeConfigError(
-                "[payload] serving_speculative (draft length) must be "
-                "in [0, 16] (0 = off) or 'auto'"
-            )
-        if not 0 <= self.serving_spec_window <= 64:
-            raise RuntimeConfigError(
-                "[payload] serving_spec_window must be in [0, 64] "
-                "(0 = one spec pass per dispatch)"
-            )
-        if self.serving_spec_window > 0 and self.serving_speculative == 0:
-            raise RuntimeConfigError(
-                "[payload] serving_spec_window > 0 needs speculative "
-                "decoding (serving_speculative 'auto' or > 0)"
-            )
-        if not isinstance(self.serving_spec_sampled_window, bool):
-            raise RuntimeConfigError(
-                "[payload] serving_spec_sampled_window must be a boolean"
-            )
         if self.serving_retry_after_s <= 0:
             raise RuntimeConfigError(
                 "[payload] serving_retry_after_s must be > 0 "
@@ -1242,15 +1186,6 @@ class RuntimeConfig:
                     "a recurrent state holds a row's whole prefix in one "
                     "array and cannot be shared by page")
                 + "; set serving_prefix_cache = false")
-        if self.serving_speculative != 0:
-            raise RuntimeConfigError(
-                "[payload] serving_speculative must be 0 for a model "
-                "with [model] layer_pattern: " + (
-                    "a drafted position's page that a \"window\" layer "
-                    "has given back cannot be attended again"
-                    if window else
-                    "a recurrent state cannot be rewound past the drafts "
-                    "a verify pass rejects"))
         if window and self.serving_kv_dtype == "int8":
             raise RuntimeConfigError(
                 "[payload] serving_kv_dtype = \"int8\" cannot serve a "
@@ -1352,11 +1287,6 @@ class RuntimeConfig:
             f"{s(self.serving_window) if isinstance(self.serving_window, str) else self.serving_window}\n"
             f"serving_window_min = {self.serving_window_min}\n"
             f"serving_window_max = {self.serving_window_max}\n"
-            "serving_speculative = "
-            f"{s(self.serving_speculative) if isinstance(self.serving_speculative, str) else self.serving_speculative}\n"
-            f"serving_spec_window = {self.serving_spec_window}\n"
-            "serving_spec_sampled_window = "
-            f"{'true' if self.serving_spec_sampled_window else 'false'}\n"
             f"serving_retry_after_s = {self.serving_retry_after_s}\n"
             f"serving_recovery_attempts = {self.serving_recovery_attempts}\n"
             f"serving_sched_policy = {s(self.serving_sched_policy)}\n"

@@ -394,17 +394,6 @@ class PagedKVCache:
         # sliced on device, so dispatching N+1 never forces N's result
         # to the host.
         self._carry = None
-        # Device-resident speculative carry for the windowed-spec
-        # pipeline: (pending [slots], ctx [slots, S_ctx],
-        # ctx_len [slots]) of the most recent dispatch_spec_window.
-        # Unlike the greedy carry, the next window needs the whole
-        # drafting context, not just the last token row.
-        self._spec_carry = None
-        # Worst-case tokens per slot advanced by dispatched-but-not-yet
-        # -harvested spec windows. While any are in flight, the DEVICE
-        # lengths are data-dependent (acceptance counts the host learns
-        # only at harvest) and _sync must merge instead of clobber.
-        self._spec_unharvested = [0] * slots
         # Memoized host->device uploads for the small per-dispatch
         # operand rows (active mask, per-row caps, stop tokens): in
         # pipeline steady state these repeat verbatim window after
@@ -494,22 +483,6 @@ class PagedKVCache:
             b *= 2
         return min(b, self.slots)
 
-    def quiescent(self) -> bool:
-        """No device-resident carry (greedy or spec) and no unharvested
-        spec reservation — the state in which :meth:`set_bucket` is
-        safe AND free: nothing in flight references the old batch
-        shape."""
-        return (self._carry is None and self._spec_carry is None
-                and not any(self._spec_unharvested))
-
-    def spec_pending(self) -> bool:
-        """Any dispatched-but-unharvested spec reservation? The ONE
-        hard blocker for :meth:`set_bucket` (device lengths are
-        data-dependent until harvest); mere carries are droppable at a
-        pipeline boundary, where the next dispatch re-feeds host
-        tokens."""
-        return any(self._spec_unharvested)
-
     def rows_in_use(self) -> int:
         """1 + the highest admitted slot (0 when empty): the smallest
         device batch dim that still covers every live row — what the
@@ -518,7 +491,7 @@ class PagedKVCache:
 
     def set_bucket(self, n: int) -> None:
         """Resize the DEVICE batch dim to bucket ``n`` (a quiescent-point
-        operation: no window/spec carry may be in flight — the serving
+        operation: no window carry may be in flight — the serving
         loop collapses its pipeline to a boundary first). The page pool
         never moves; only tables/lengths rebuild from the host mirrors,
         so the resize is a host->device upload of two small arrays and
@@ -537,12 +510,6 @@ class PagedKVCache:
                 f"bucket {n} is not on this cache's ladder "
                 f"(powers of two from {self.min_bucket} capped at "
                 f"{self.slots})"
-            )
-        if any(self._spec_unharvested):
-            raise PagedCacheError(
-                "cannot resize the device batch dim with spec windows "
-                "in flight — harvest them first (device lengths are "
-                "data-dependent until then)"
             )
         top = max(self._pages_of, default=-1)
         if top >= n:
@@ -570,9 +537,9 @@ class PagedKVCache:
         """Full-pool page census for the conservation audit
         (``serving_debug_pages`` and the chaos soak's invariant 1).
         Every page is either on the free list (ref 0) or referenced by
-        some holder — a slot table, a registry pin, or a spec-window
-        pre-allocation, all of which live inside slot page lists and
-        therefore inside ``live``. Conservation holds iff
+        some holder — a slot table or a registry pin, both of which live
+        inside slot page lists and therefore inside ``live``.
+        Conservation holds iff
         ``free + live == pages_total`` with no duplicate free entries,
         no negative refcounts, and no page both free and referenced.
         Pure host bookkeeping: no device work, safe at any boundary."""
@@ -598,7 +565,6 @@ class PagedKVCache:
             "free": len(self._free),
             "live": sum(1 for r in self._refs if r > 0),
             "pages_total": self.num_pages,
-            "spec_unharvested": sum(self._spec_unharvested),
             "free_dup": len(self._free) - len(free_set),
             "neg_refs": sum(1 for r in self._refs if r < 0),
             "free_live": sum(
@@ -642,9 +608,8 @@ class PagedKVCache:
     def slot_length(self, slot: int) -> int:
         """The slot's committed host-mirror length: positions
         ``[0, slot_length)`` hold valid K/V for tokens 0..length-1 of
-        prompt + generated (spec drafts scribble only at or past the
-        committed length and are overwritten on acceptance), which is
-        what makes finish-time prefix registration exact."""
+        prompt + generated, which is what makes finish-time prefix
+        registration exact."""
         if slot not in self._pages_of:
             raise PagedCacheError(f"slot {slot} is not admitted")
         return self._host_lengths[slot]
@@ -853,31 +818,14 @@ class PagedKVCache:
             self._wfree.extend(self._wpages_of.pop(slot))
             self._host_wtables[slot] = [0] * self.window_cap
             self._wfirst[slot] = 0
-        # A released slot's device length must drop to 0 even while
-        # other slots' spec windows are in flight (the merge in _sync
-        # keeps only UNHARVESTED slots' device lengths).
-        self._spec_unharvested[slot] = 0
         self._sync()
 
     def _sync(self) -> None:
-        import numpy as _np
-
         b = self.bucket
-        lengths = jnp.asarray(self._host_lengths[:b], jnp.int32)
-        if any(self._spec_unharvested):
-            # Spec windows in flight advance their slots' DEVICE
-            # lengths by data-dependent acceptance counts the host
-            # learns only at harvest — a sync triggered by an unrelated
-            # admit/grow/release must keep those slots' device lengths,
-            # not clobber them with the stale host mirror.
-            mask = jnp.asarray(
-                _np.asarray(self._spec_unharvested[:b]) > 0
-            )
-            lengths = jnp.where(mask, self.state.lengths, lengths)
         self.state = dataclasses.replace(
             self.state,
             tables=jnp.asarray(self._host_tables[:b], jnp.int32),
-            lengths=lengths,
+            lengths=jnp.asarray(self._host_lengths[:b], jnp.int32),
         )
         if self.window:
             self._sync_window()
@@ -1488,14 +1436,9 @@ class PagedKVCache:
         return self._with_carry(dev)
 
     def drop_carry(self) -> None:
-        """Forget the device-resident carries (recovery: a revived pool
-        restarts its pipelines from host tokens — greedy carry AND the
-        windowed-spec drafting context), and forget any unharvested
-        spec advance (the slots it covered are being torn down; their
-        host lengths are authoritative again)."""
+        """Forget the device-resident carry (recovery: a revived pool
+        restarts its pipeline from host tokens)."""
         self._carry = None
-        self._spec_carry = None
-        self._spec_unharvested = [0] * self.slots
         self._picks_of.clear()
         # The operand memo holds device arrays from the same stream
         # the carries rode — a revived pool must re-upload.
@@ -1555,236 +1498,6 @@ class PagedKVCache:
                             _np.asarray(stop_tokens, _np.int32)),
         )
         return self._note_window(out, n_steps)
-
-    def step_spec(self, params, tokens, active, spec_mask):
-        """One speculative verify pass (see :func:`_spec_verify_core`).
-
-        ``tokens`` [slots, 1+K] int32; ``spec_mask`` [slots] bool marks
-        rows whose drafts may accept (greedy rows — sampled rows ride
-        with acceptance 0 and their draft scatters dropped). Greedy
-        rows grow pages for the worst case (all K drafts accepted) up
-        front — legal because the serving layer reserves each
-        SPECULATIVE request's slack budget at admission; sampled rows
-        grow one position only, exactly like a plain step, so they
-        carry no slack reservation. Returns ``(emitted [slots, K+1],
-        accepted [slots] np.int64, logits0 [slots, V])``.
-        """
-        import numpy as _np
-
-        slots = self._step_slots(active)
-        spec_np = _np.asarray(spec_mask, bool)
-        k_len = tokens.shape[1] - 1
-        grew = False
-        for slot in slots:
-            grew |= self.grow_to(
-                slot, (k_len + 1) if spec_np[slot] else 1
-            )
-        if grew:
-            self._sync()
-        emitted, accepted, logits0 = self._device_spec(
-            params, tokens, active, spec_mask
-        )
-        accepted_np = _np.asarray(accepted)
-        for slot in slots:
-            self._host_lengths[slot] += 1 + int(accepted_np[slot])
-        return emitted, accepted_np, logits0
-
-    def _device_spec(self, params, tokens, active, spec_mask):
-        """Device seam: one batched verify pass over current state."""
-        import numpy as _np
-
-        emitted, accepted, logits0, self.state = _paged_spec_verify(
-            params, self.state, jnp.asarray(tokens, jnp.int32), self.cfg,
-            self._active_array(self.state, active),
-            jnp.asarray(_np.asarray(spec_mask, bool)),
-        )
-        return emitted, accepted, logits0
-
-    # ---- windowed speculative decode (device-resident passes) -----------
-
-    def spec_window_caps(self, n_passes: int, k_len: int,
-                         budgets, sampled_mask=None) -> "np.ndarray":
-        """Worst-case token advance per slot for ONE dispatched spec
-        window: a row runs verify passes while its remaining budget is
-        positive, each advancing 1 + accepted <= 1 + K, so the last
-        pass may overshoot the budget by up to K (the host truncates
-        the stream at harvest, exactly like the legacy per-pass path).
-        Pages, host inflight accounting, and ``_spec_unharvested`` all
-        reserve THIS bound; the true advance (the sum of the window's
-        acceptance counts) is only known at harvest.
-
-        A SAMPLED row (``sampled_mask``) advances exactly one token per
-        live pass — acceptance is forced to 0 — so its cap is EXACT,
-        not a bound: ``min(budget, n_passes)``. Exactness matters
-        beyond page thrift: the serving layer prices ``base_steps`` for
-        the next pipelined window off inflight (= this cap), and the
-        sampler key schedule is only bit-identical to the per-pass path
-        when inflight equals the true advance.
-        """
-        import numpy as _np
-
-        budgets_np = _np.maximum(
-            _np.asarray(budgets, _np.int64), 0
-        ).astype(_np.int32)
-        caps = _np.minimum(budgets_np + k_len, n_passes * (k_len + 1))
-        if sampled_mask is not None:
-            caps = _np.where(
-                _np.asarray(sampled_mask, bool),
-                _np.minimum(budgets_np, n_passes), caps,
-            )
-        return _np.where(budgets_np > 0, caps, 0).astype(_np.int32)
-
-    def dispatch_spec_window(self, params, tokens, n_passes: int,
-                             k_len: int, budgets, active=None,
-                             ctx=None, ctx_len=None, sampling=None):
-        """Enqueue ``n_passes`` speculative draft+verify passes in ONE
-        device program, WITHOUT forcing the result.
-
-        The windowed twin of :meth:`step_spec`: drafting (the n-gram
-        proposer over a device-resident context), verification, KV
-        commits for accepted drafts, acceptance-capped freezing, and
-        the pending-token chain all run inside the scan — the host pays
-        one dispatch + one harvest for up to ``n_passes * (1 + K)``
-        tokens instead of one round trip per pass. Greedy rows only
-        (``budgets[b] > 0`` marks participants); sampled co-tenants
-        keep the legacy per-pass path.
-
-        First window of a pipeline: ``tokens`` [slots] int32 is each
-        row's pending token and ``ctx``/``ctx_len`` its drafting
-        context (prompt + generated + pending; [slots, S_ctx] /
-        [slots]). Subsequent windows pass ``tokens=None`` to ride the
-        device-resident spec carry — pending, context, and context
-        lengths never visit the host between back-to-back windows.
-
-        Returns an UNFORCED handle for :meth:`harvest_spec_window`.
-        Page growth and ``_spec_unharvested`` reserve the worst case
-        (:meth:`spec_window_caps`); host lengths advance only at
-        harvest, by the true acceptance counts.
-
-        ``sampling`` (rung 23) carries a mixed batch's sampled
-        co-tenants through the SAME window: a ``(key_data, base_steps,
-        temps, top_ps, sampled_mask)`` tuple (the capped mixed
-        window's inputs) routes the dispatch through
-        :func:`_paged_spec_window_sampled_impl` — sampled rows ride
-        verify passes with acceptance 0 and draw their next token on
-        device; None keeps the greedy-only program.
-        """
-        import numpy as _np
-
-        slots = self._step_slots(active)
-        sampled_mask = sampling[4] if sampling is not None else None
-        caps = self.spec_window_caps(n_passes, k_len, budgets,
-                                     sampled_mask)
-        budgets_np = _np.maximum(
-            _np.asarray(budgets, _np.int64), 0
-        ).astype(_np.int32)
-        grew = False
-        for slot in slots:
-            if caps[slot] > 0:
-                grew |= self.grow_to(
-                    slot, self._spec_unharvested[slot] + int(caps[slot])
-                )
-        if grew:
-            self._sync()
-        if tokens is None:
-            if self._spec_carry is None:
-                raise PagedCacheError(
-                    "no spec window in flight to carry from — the "
-                    "first spec window of a pipeline must pass "
-                    "explicit tokens and drafting context"
-                )
-        elif ctx is None or ctx_len is None:
-            raise PagedCacheError(
-                "a spec window dispatched from host tokens needs "
-                "its drafting context (ctx, ctx_len)"
-            )
-        emitted, counts, pend_out = self._device_spec_window(
-            params, tokens, n_passes, k_len, active, budgets_np,
-            ctx, ctx_len, sampling,
-        )
-        for slot in slots:
-            if caps[slot] > 0:
-                self._spec_unharvested[slot] += int(caps[slot])
-        return {
-            "emitted": emitted,      # [n_passes, slots, K+1], unforced
-            "counts": counts,        # [n_passes, slots], unforced
-            "pending": pend_out,     # [slots], unforced
-            "caps": caps,            # host worst-case reservation
-        }
-
-    def _device_spec_window(self, params, tokens, n_passes: int,
-                            k_len: int, active, budgets, ctx, ctx_len,
-                            sampling=None):
-        """Device seam: enqueue a windowed spec program (no read).
-        ``tokens=None`` rides the device-resident spec carry; the seam
-        owns the carry resolution AND the carry update, so a slice
-        override can broadcast the host inputs and keep a per-process
-        carry (runtime/sliceserve.py) with the base host bookkeeping
-        unchanged. The greedy and mixed programs share one carry triple
-        (pending, ctx, ctx_len), so a pipeline may hand a carry between
-        them when the batch's sampled population drains."""
-        import numpy as _np
-
-        if tokens is None:
-            pending, ctx_dev, ctx_len_dev = self._spec_carry
-        else:
-            pending = jnp.asarray(_np.asarray(tokens, _np.int32))
-            ctx_dev = jnp.asarray(_np.asarray(ctx, _np.int32))
-            ctx_len_dev = jnp.asarray(_np.asarray(ctx_len, _np.int32))
-        if sampling is None:
-            (emitted, counts, pend_out, ctx_out, ctx_len_out,
-             self.state) = _paged_spec_window(
-                params, self.state, pending, self.cfg, n_passes, k_len,
-                self._active_array(self.state, active),
-                jnp.asarray(_np.asarray(budgets, _np.int32)), ctx_dev,
-                ctx_len_dev,
-            )
-        else:
-            key_data, base_steps, temps, top_ps, sampled_mask = sampling
-            (emitted, counts, pend_out, ctx_out, ctx_len_out,
-             self.state) = _paged_spec_window_sampled(
-                params, self.state, pending, self.cfg, n_passes, k_len,
-                self._active_array(self.state, active),
-                jnp.asarray(_np.asarray(budgets, _np.int32)), ctx_dev,
-                ctx_len_dev,
-                jnp.asarray(_np.asarray(key_data, _np.uint32)),
-                jnp.asarray(_np.asarray(base_steps, _np.int32)),
-                jnp.asarray(_np.asarray(temps, _np.float32)),
-                jnp.asarray(_np.asarray(top_ps, _np.float32)),
-                jnp.asarray(_np.asarray(sampled_mask, bool)),
-            )
-        self._spec_carry = (pend_out, ctx_out, ctx_len_out)
-        return emitted, counts, pend_out
-
-    def _force_spec_window(self, handle):
-        """Read a dispatched spec window's results to the host — the
-        blocking seam (a slice cache deadline-bounds it and reads its
-        local replicated shard)."""
-        import numpy as _np
-
-        return (_np.asarray(handle["emitted"]),
-                _np.asarray(handle["counts"]),
-                _np.asarray(handle["pending"]))
-
-    def harvest_spec_window(self, handle):
-        """Force a dispatched spec window to the host and settle the
-        bookkeeping its dispatch could only bound: host lengths advance
-        by each slot's TRUE acceptance-counted advance (the sum of its
-        per-pass counts), and the worst-case ``_spec_unharvested``
-        reservation is returned. Returns ``(emitted [n_passes, slots,
-        K+1], counts [n_passes, slots], pending [slots])`` as numpy."""
-        emitted, counts, pending = self._force_spec_window(handle)
-        caps = handle["caps"]
-        for slot in range(len(caps)):
-            # A slot released (or released and re-admitted) while its
-            # window was in flight already had its bookkeeping zeroed —
-            # release()/drop_carry() are authoritative; settling here
-            # would resurrect a dead reservation.
-            if (caps[slot] > 0 and slot in self._pages_of
-                    and self._spec_unharvested[slot] >= int(caps[slot])):
-                self._host_lengths[slot] += int(counts[:, slot].sum())
-                self._spec_unharvested[slot] -= int(caps[slot])
-        return emitted, counts, pending
 
 
 # ---- jitted kernels ------------------------------------------------------
@@ -1951,12 +1664,11 @@ def _scatter_token(pool, scales, layer, tables, lengths, kv_new, active,
 
 
 def _paged_attend_layer(cfg: TransformerConfig, state: PagedState, x,
-                        layer_params, layer, pools, q_positions, slot=None,
-                        write_mask=None):
+                        layer_params, layer, pools, q_positions, slot=None):
     """Shared block body. x: [B, Q, D]; q_positions: [B, Q] absolute
-    positions of the new tokens. ``pools``, ``layer``, ``slot`` and
-    ``write_mask`` are :func:`_paged_attention`'s; returns the block's
-    output and the updated pools."""
+    positions of the new tokens. ``pools``, ``layer`` and ``slot`` are
+    :func:`_paged_attention`'s; returns the block's output and the
+    updated pools."""
     if cfg.n_experts:
         w_qkv, w_out, router, w_up, w_down, ln_attn, ln_mlp = layer_params
     else:
@@ -1964,7 +1676,7 @@ def _paged_attend_layer(cfg: TransformerConfig, state: PagedState, x,
     dtype = x.dtype
     attended, pools = _paged_attention(
         cfg, state, _rmsnorm(x, ln_attn), w_qkv, w_out, layer, pools,
-        q_positions, slot, write_mask)
+        q_positions, slot)
     x = x + attended
 
     normed = _rmsnorm(x, ln_mlp)
@@ -1981,8 +1693,7 @@ def _paged_attend_layer(cfg: TransformerConfig, state: PagedState, x,
 
 def _paged_attention(cfg: TransformerConfig, state: PagedState, normed,
                      w_qkv, w_out, layer, pools, q_positions, slot=None,
-                     write_mask=None, w_gate=None, window: int = 0,
-                     qk_norm=None):
+                     w_gate=None, window: int = 0, qk_norm=None):
     """The attention mixer of every paged program, over normed
     activations [B, Q, D]; q_positions: [B, Q] absolute
     positions of the new tokens. ``pools`` is the WHOLE pool
@@ -1992,10 +1703,7 @@ def _paged_attention(cfg: TransformerConfig, state: PagedState, normed,
     it lies; it returns the mixer's output (what the residual stream
     adds) and the updated pools. ``state`` supplies tables
     and lengths only. ``slot`` non-None = single-sequence
-    prefill (B == 1 view of that slot). ``write_mask`` [B, Q] bool
-    (batched paths only) gates which query offsets persist K/V — the
-    speculative verify pass drops sampled rows' draft-position writes so
-    those rows need no slack pages; None = every offset writes.
+    prefill (B == 1 view of that slot).
     ``cfg.rotary`` false leaves q and k as projected (no positional
     encoding; the rotary base is ``cfg.rope_theta``). ``window`` > 0
     makes this a layer bound to a window: ``pools`` is then the window
@@ -2034,9 +1742,8 @@ def _paged_attention(cfg: TransformerConfig, state: PagedState, normed,
         return attended * gate.astype(dtype)
 
     # rotary wants [T]-shaped positions; rows share a position vector only
-    # in prefill (B=1). Decode/verify rows each carry their own
-    # positions: apply per-row via vmap (q_len 1 for plain decode,
-    # 1 + draft_len for a speculative verify pass).
+    # in prefill (B=1). Decode rows each carry their own positions:
+    # apply per-row via vmap.
     if not (cfg.rotary or window):
         pass
     elif slot is None:
@@ -2051,20 +1758,15 @@ def _paged_attention(cfg: TransformerConfig, state: PagedState, normed,
         tables, first, lengths = all_tables, all_first, state.lengths
         active = lengths > 0
         # One scatter per query offset (static q_len): row b's token i
-        # lands at position lengths[b] + i — multi-offset writes are how
-        # a verify pass persists the drafts' K/V in the same program
-        # that scores them (intra-pass causality is free: writes land
-        # before the gather, and the mask is on absolute positions).
+        # lands at position lengths[b] + i.
         for i in range(q_len):
-            w_active = (active if write_mask is None
-                        else active & write_mask[:, i])
             new_pool_k, new_scale_k = _scatter_token(
                 new_pool_k, new_scale_k, layer, tables, lengths + i,
-                k[:, i], w_active, first,
+                k[:, i], active, first,
             )
             new_pool_v, new_scale_v = _scatter_token(
                 new_pool_v, new_scale_v, layer, tables, lengths + i,
-                v[:, i], w_active, first,
+                v[:, i], active, first,
             )
     else:
         # Prefill: scatter q_len rows of one slot at their ABSOLUTE
@@ -2088,10 +1790,9 @@ def _paged_attention(cfg: TransformerConfig, state: PagedState, normed,
     # oversized pools to the gather; a FORCED kernel that cannot run
     # refuses loudly (PagedKVCache.__init__ rejects it up front; this
     # trace-time raise is the defense for direct kernel callers). Only
-    # traces the kernel could actually take refuse: prefill and spec-
-    # verify (slot set / q_len > 1) always run the gather, so raising
-    # there would kill legitimate programs a forced-kernel pool still
-    # needs.
+    # traces the kernel could actually take refuse: prefill (slot set,
+    # q_len > 1) always runs the gather, so raising there would kill
+    # legitimate programs a forced-kernel pool still needs.
     kernel_eligible = slot is None and q_len == 1
     if quantized:
         from kvedge_tpu.ops.paged_attention import scales_fit_vmem
@@ -2165,8 +1866,7 @@ def _paged_attention(cfg: TransformerConfig, state: PagedState, normed,
     return out, (new_pool_k, new_pool_v, new_scale_k, new_scale_v)
 
 
-def _run_paged(cfg, params, state, x, q_positions, slot=None,
-               all_positions: bool = False, write_mask=None):
+def _run_paged(cfg, params, state, x, q_positions, slot=None):
     """The layer loop of every paged program. The pool rides the carry
     WHOLE, beside the activations; only the layer's weights and its
     index are scanned over. As ``xs``/``ys`` of the scan each layer's
@@ -2178,15 +1878,14 @@ def _run_paged(cfg, params, state, x, q_positions, slot=None,
     Returns the logits and the state's new fields (:func:`_with_pools`).
     """
     if cfg.layer_pattern:
-        return _run_paged_pattern(cfg, params, state, x, q_positions, slot,
-                                  all_positions, write_mask)
+        return _run_paged_pattern(cfg, params, state, x, q_positions, slot)
 
     def body(carry, xs):
         x, pools = carry
         layer_params, layer = xs
         return _paged_attend_layer(
             cfg, state, x, layer_params, layer, pools,
-            q_positions, slot, write_mask,
+            q_positions, slot,
         ), None
 
     (x, new_pools), _ = jax.lax.scan(
@@ -2196,14 +1895,11 @@ def _run_paged(cfg, params, state, x, q_positions, slot=None,
          jnp.arange(cfg.n_layers, dtype=jnp.int32)),
     )
     x = _rmsnorm(x, params["ln_final"])
-    logits = tied_readout(
-        x if all_positions else x[:, -1], params["embedding"]
-    )
+    logits = tied_readout(x[:, -1], params["embedding"])
     return logits, _pool_fields(new_pools)
 
 
-def _run_paged_pattern(cfg, params, state, x, q_positions, slot,
-                       all_positions, write_mask):
+def _run_paged_pattern(cfg, params, state, x, q_positions, slot):
     """:func:`_run_paged` for a block with a layer pattern
     (models/hybrid.py has the block and its equations): the attention
     layers through :func:`_paged_attention` on the pool, which holds
@@ -2214,16 +1910,10 @@ def _run_paged_pattern(cfg, params, state, x, q_positions, slot,
     masks it) gets its recurrent state back untouched."""
     from kvedge_tpu.models import hybrid
 
-    if slot is None and x.shape[1] != 1:
-        raise ValueError(
-            "a block with recurrent layers takes one token a row and "
-            "step: a verify pass over drafts would have to rewind the "
-            "state of the drafts it rejects (serving_speculative)")
-
     def attend(normed, w, layer, pool, kind):
         return _paged_attention(
             cfg, state, normed, w["w_qkv"], w["w_out"], layer, pool,
-            q_positions, slot, write_mask, w.get("w_gate"),
+            q_positions, slot, w.get("w_gate"),
             window=cfg.attention_window if kind == "window" else 0,
             qk_norm=(w["q_norm"], w["k_norm"]) if cfg.qk_norm else None)
 
@@ -2237,8 +1927,7 @@ def _run_paged_pattern(cfg, params, state, x, q_positions, slot,
     x = _rmsnorm(x, params["ln_final"], cfg.norm_eps)
     # a head of its own where the tree has one, [V, D] as the embedding
     logits = tied_readout(
-        x if all_positions else x[:, -1],
-        params.get("head", params["embedding"])
+        x[:, -1], params.get("head", params["embedding"])
     ) / cfg.logits_scaling
     fields = _pool_fields(pools["attention"], recurrent=recurrent)
     if "window" in pools:
@@ -2313,258 +2002,6 @@ def _decode_step_core(params: dict, state: PagedState, tokens,
 _paged_decode_step = functools.partial(
     jax.jit, static_argnames=("cfg",), donate_argnums=(1,)
 )(_decode_step_core)
-
-
-def _spec_verify_core(params: dict, state: PagedState, tokens,
-                      cfg: TransformerConfig, active, spec_mask):
-    """One batched speculative verify pass over the paged cache.
-
-    ``tokens`` is [B, 1+K]: each active row's pending token followed by
-    K drafted tokens. One forward with 1+K query positions per row
-    scores every draft (y[b, i] = the model's greedy token after row
-    b's prefix extended by tokens[b, :i+1]) and writes all 1+K tokens'
-    K/V; acceptance is the leading-agreement count, exactly the
-    contiguous speculative decoder's rule (models/speculative.py), so
-    emitted tokens are token-for-token the plain greedy decode.
-
-    ``spec_mask`` [B] bool: rows whose drafts may accept. A sampled row
-    rides the same pass with acceptance forced to 0 — it advances by
-    exactly its pending token (position ``length``), and its draft
-    offsets' K/V scatters are DROPPED (``write_mask``): a row that can
-    never accept a draft must not consume pages past its real length,
-    so sampled requests reserve no speculative slack
-    (models/serving.py ``_pages_needed``). Its draft-position *scores*
-    read whatever stale data sits past ``length`` in the pool — finite
-    garbage whose outputs (y[:, 1:]) are discarded for that row, since
-    acceptance is 0 and only the pending position's logits are used.
-
-    Returns ``(emitted [B, K+1], accepted [B], logits0 [B, V], state)``:
-    row b's first ``accepted[b]`` emitted entries are its accepted
-    drafts, entry ``accepted[b]`` is the bonus token (the model's own
-    argmax after them); ``logits0`` is the pending-token position's
-    logits for host-side sampling. Lengths advance by
-    ``1 + accepted`` per active row — the pending token's K/V plus the
-    accepted drafts'; the bonus token's K/V is the next pass's pending
-    write, exactly like plain decode.
-    """
-    _note_trace("spec_verify")
-    k_len = tokens.shape[1] - 1
-    x = _embed(cfg, params, tokens)  # [B, 1+K, D]
-    q_positions = (state.lengths[:, None]
-                   + jnp.arange(1 + k_len)[None])  # [B, 1+K]
-    masked = dataclasses.replace(
-        state, lengths=jnp.where(active, state.lengths, 0)
-    )
-    # Offset 0 (the pending token) always writes; draft offsets write
-    # only for rows that can accept them.
-    write_mask = (spec_mask[:, None]
-                  | (jnp.arange(1 + k_len) == 0)[None, :])
-    logits, pools = _run_paged(
-        cfg, params, masked, x, q_positions, all_positions=True,
-        write_mask=write_mask,
-    )  # [B, 1+K, V]
-    y = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [B, 1+K]
-    draft = tokens[:, 1:]
-    agree = jnp.cumprod(
-        (draft == y[:, :k_len]).astype(jnp.int32), axis=1
-    )
-    accepted = jnp.sum(agree, axis=1) * spec_mask.astype(jnp.int32)
-    idx = jnp.arange(k_len + 1)[None]
-    emitted = jnp.where(
-        idx < accepted[:, None],
-        jnp.concatenate([draft, y[:, -1:]], axis=1),
-        jnp.take_along_axis(y, accepted[:, None], axis=1),
-    ).astype(jnp.int32)
-    state = _with_pools(
-        state, pools,
-        lengths=state.lengths + active.astype(jnp.int32) * (1 + accepted),
-    )
-    return emitted, accepted, logits[:, 0], state
-
-
-_paged_spec_verify = functools.partial(
-    jax.jit, static_argnames=("cfg",), donate_argnums=(1,)
-)(_spec_verify_core)
-
-
-def _paged_spec_window_impl(params: dict, state: PagedState, tokens,
-                            cfg: TransformerConfig, n_passes: int,
-                            k_len: int, active, budgets, ctx, ctx_len):
-    """``n_passes`` speculative draft+verify passes in ONE program —
-    the windowed twin of :func:`_spec_verify_core`, with the host
-    removed from the loop entirely.
-
-    The legacy path pays a full host round trip per verify pass: read
-    back the emitted tokens, re-draft on the host, re-dispatch. Here
-    the scan carries everything that loop needed the host for:
-
-    * ``pending`` [B] — the pending-token chain (each pass's bonus
-      token feeds the next pass, exactly the legacy
-      ``req.next_token`` hand-off);
-    * ``ctx`` [B, S_ctx] / ``ctx_len`` [B] — the drafting context
-      (prompt + generated + pending). Each pass drafts K tokens with
-      the SAME n-gram proposer the host drafter mirrors
-      (models/speculative.py ``_propose_ngram``), appends its accepted
-      tokens + bonus, and drafts the next pass from the updated
-      context — so the windowed drafts equal the legacy host drafts
-      token for token, and (since greedy verify makes the emitted
-      stream independent of draft quality anyway) the emitted stream
-      is bit-identical to both the legacy spec path and plain greedy;
-    * ``rem`` [B] — each row's remaining emission budget. A pass runs
-      a row only while ``rem > 0``; a frozen row's scatters drop, its
-      length holds, and its pending/context freeze (the same
-      discipline as :func:`_paged_decode_window_capped_impl`), so a
-      speculatively dispatched window can never scribble past a stop
-      the host hasn't seen. The LAST live pass may overshoot the
-      budget by up to K accepted drafts — the host truncates at
-      harvest, exactly like the legacy per-pass path's ``room`` cap.
-
-    Each pass verifies through :func:`_spec_verify_core` (the single
-    jitted-pass body — windowed and per-pass spec stay the same
-    program, the invariant the windowed/per-step greedy pair already
-    keeps). Returns ``(emitted [n_passes, B, K+1], counts
-    [n_passes, B], pending [B], ctx, ctx_len, state)`` where
-    ``counts[p, b] = 1 + accepted`` for rows pass p advanced (0 for
-    frozen rows): row b's pass-p emissions are its pending token
-    followed by ``emitted[p, b, :counts[p, b] - 1]``, and
-    ``emitted[p, b, counts[p, b] - 1]`` is the next pending.
-    """
-    from kvedge_tpu.models.speculative import _propose_ngram
-
-    _note_trace("spec_window")
-    s_ctx = ctx.shape[1]
-
-    def body(carry, _):
-        state, pending, rem, ctx, ctx_len = carry
-        live = active & (rem > 0)
-        draft = jax.vmap(
-            lambda c, n: _propose_ngram(c, n, k_len)
-        )(ctx, ctx_len)
-        toks = jnp.concatenate([pending[:, None], draft], axis=1)
-        emitted, accepted, _logits0, state = _spec_verify_core(
-            params, state, toks, cfg, live, live
-        )
-        count = live.astype(jnp.int32) * (1 + accepted)
-        bonus = jnp.take_along_axis(
-            emitted, accepted[:, None], axis=1
-        )[:, 0]
-        pending = jnp.where(live, bonus, pending)
-        # Append this pass's a+1 new tokens (accepted drafts + bonus)
-        # to the drafting context; frozen rows' writes drop out of
-        # bounds. emitted[b, i] for i > accepted[b] repeats the bonus,
-        # so masking by offset <= accepted writes exactly the stream.
-        idx = jnp.arange(k_len + 1)[None, :]
-        pos = ctx_len[:, None] + idx
-        ok = live[:, None] & (idx <= accepted[:, None])
-        pos = jnp.where(ok, pos, s_ctx)
-        ctx = jax.vmap(
-            lambda c, p, e: c.at[p].set(e, mode="drop")
-        )(ctx, pos, emitted)
-        ctx_len = ctx_len + count
-        rem = rem - count
-        return (state, pending, rem, ctx, ctx_len), (emitted, count)
-
-    carry0 = (state, tokens, budgets, ctx, ctx_len)
-    (state, pending, _rem, ctx, ctx_len), (emitted, counts) = (
-        jax.lax.scan(body, carry0, length=n_passes)
-    )
-    return emitted, counts, pending, ctx, ctx_len, state
-
-
-_paged_spec_window = functools.partial(
-    jax.jit, static_argnames=("cfg", "n_passes", "k_len"),
-    donate_argnums=(1,),
-)(_paged_spec_window_impl)
-
-
-def _paged_spec_window_sampled_impl(params: dict, state: PagedState,
-                                    tokens, cfg: TransformerConfig,
-                                    n_passes: int, k_len: int, active,
-                                    budgets, ctx, ctx_len, key_data,
-                                    base_steps, temps, top_ps,
-                                    sampled_mask):
-    """Mixed greedy/sampled :func:`_paged_spec_window_impl` — the
-    device-resident endgame for the sampled co-tenant (SERVING.md
-    rung 23): one sampled row no longer collapses the whole batch to
-    the legacy per-pass path.
-
-    Speculative sampling degenerates for this repo's greedy-verify
-    scheme: a sampled row's acceptance is forced to 0 (it rejects at
-    the first draft position), so "residual resampling on first
-    rejection" reduces to drawing the replacement token from the
-    nucleus-filtered target distribution at the PENDING position —
-    exactly what the legacy per-pass path does with
-    ``_sample_slots(logits0, ...)`` on the host. Here that draw moves
-    into the scan carry: ``spec_live = live & ~sampled_mask`` rides
-    :func:`_spec_verify_core` as the spec mask (acceptance 0, draft
-    K/V scatters dropped, length +1 per pass — the documented
-    sampled-row contract of the verify core), and the pending chain
-    for sampled rows feeds ``sample_token(logits0, fold_in(seed,
-    base + i), temp, top_p)`` instead of the bonus argmax.
-
-    The key schedule is bit-identical to the legacy path because a
-    live sampled row advances by EXACTLY one token per pass (counts
-    1 + accepted = 1), liveness is a prefix of the window (``rem``
-    only decreases), and the serving layer dispatches ``base_steps =
-    len(generated) + inflight + 1`` — so scan index ``i`` IS the
-    row's emitted offset, the same ``fold_in(seed, len(generated)+1)``
-    the per-pass path folds. ``emitted[p, b, 0]`` is patched to the
-    sampled draw so the harvest path reads sampled and greedy rows
-    through one code path (row b's pass-p count is 1: pending emits,
-    the sampled token is the next pending).
-    """
-    from kvedge_tpu.models.decode import sample_token
-    from kvedge_tpu.models.speculative import _propose_ngram
-
-    _note_trace("spec_window_sampled")
-    s_ctx = ctx.shape[1]
-    keys = jax.random.wrap_key_data(key_data)
-
-    def body(carry, i):
-        state, pending, rem, ctx, ctx_len = carry
-        live = active & (rem > 0)
-        spec_live = live & ~sampled_mask
-        draft = jax.vmap(
-            lambda c, n: _propose_ngram(c, n, k_len)
-        )(ctx, ctx_len)
-        toks = jnp.concatenate([pending[:, None], draft], axis=1)
-        emitted, accepted, logits0, state = _spec_verify_core(
-            params, state, toks, cfg, live, spec_live
-        )
-        step_keys = jax.vmap(jax.random.fold_in)(keys, base_steps + i)
-        sampled = sample_token(
-            logits0, step_keys, temps[:, None], top_ps[:, None]
-        )
-        count = live.astype(jnp.int32) * (1 + accepted)
-        bonus = jnp.take_along_axis(
-            emitted, accepted[:, None], axis=1
-        )[:, 0]
-        bonus = jnp.where(sampled_mask, sampled, bonus).astype(jnp.int32)
-        emitted = jnp.where(sampled_mask[:, None], bonus[:, None],
-                            emitted)
-        pending = jnp.where(live, bonus, pending)
-        idx = jnp.arange(k_len + 1)[None, :]
-        pos = ctx_len[:, None] + idx
-        ok = live[:, None] & (idx <= accepted[:, None])
-        pos = jnp.where(ok, pos, s_ctx)
-        ctx = jax.vmap(
-            lambda c, p, e: c.at[p].set(e, mode="drop")
-        )(ctx, pos, emitted)
-        ctx_len = ctx_len + count
-        rem = rem - count
-        return (state, pending, rem, ctx, ctx_len), (emitted, count)
-
-    carry0 = (state, tokens, budgets, ctx, ctx_len)
-    (state, pending, _rem, ctx, ctx_len), (emitted, counts) = (
-        jax.lax.scan(body, carry0, jnp.arange(n_passes))
-    )
-    return emitted, counts, pending, ctx, ctx_len, state
-
-
-_paged_spec_window_sampled = functools.partial(
-    jax.jit, static_argnames=("cfg", "n_passes", "k_len"),
-    donate_argnums=(1,),
-)(_paged_spec_window_sampled_impl)
 
 
 # An entry of a window's input row that stands for the row's pending

@@ -156,10 +156,7 @@ class _Request:
     # must survive until that window retires. The forced boundary's
     # finish sweep completes it.
     stopped: bool = False
-    # Pages reserved at admission — stored on the request so release is
-    # symmetric even if the server's spec mode changes mid-flight (the
-    # auto guard rail can zero _spec; recomputing at release would then
-    # under-release a greedy request's slack). With a prefix-cache hit
+    # Pages reserved at admission. With a prefix-cache hit
     # this is the PRIVATE part only (pages_needed − full shared pages);
     # the shared pages are covered by leases (serving._lease).
     pages_reserved: int = 0
@@ -315,8 +312,6 @@ class PagedGenerationServer:
     def __init__(self, params: dict, cfg, *, slots: int = 4,
                  pages: int = 64, page_size: int = 16,
                  prefill_chunk: int = 0, prefix_cache: bool = True,
-                 speculative: int = 0, spec_window: int = 0,
-                 spec_sampled_window: bool = True,
                  window: int | str = 64,
                  window_min: int = 1, window_max: int = 256,
                  kv_dtype: str = "", cache=None,
@@ -365,15 +360,6 @@ class PagedGenerationServer:
                     "a recurrent state holds a row's whole prefix in one "
                     "array and cannot be shared by page")
                 + "; pass prefix_cache=False")
-        if cfg.layer_pattern and speculative:
-            raise ValueError(
-                "speculative (serving_speculative) cannot serve a "
-                "patterned block (layer_pattern): " + (
-                    "a drafted position's page that a 'window' layer "
-                    "has given back cannot be attended again"
-                    if cfg.window_layers else
-                    "a recurrent state cannot be rewound past the "
-                    "drafts a verify pass rejects"))
         self._params = params
         self._weights_gb, self._weights_dtype = weights_summary(params)
         self._cfg = cfg
@@ -431,7 +417,7 @@ class PagedGenerationServer:
         # of N+1 — steps/s moves from 1/(R + W*t) toward
         # 1/max(R, W*t) (SERVING.md rung 16). The loop falls back to a
         # non-overlapped boundary whenever exactness needs one:
-        # admissions, cancellations, legacy speculative passes.
+        # admissions, cancellations.
         # The one in-flight (dispatched, unharvested) window record:
         # {"window": steps, "parts": [(slot, req, adv)], "handle":
         # unforced device tokens, "t0": dispatch stamp}. Depth is at
@@ -549,59 +535,6 @@ class PagedGenerationServer:
         # realized. Cancels/failures don't count — goodput is good.
         self._done_total = 0
         self._tokens_done_total = 0
-        # Speculative mode (draft length K, 0 = off): greedy slots
-        # advance by batched verify passes — K prompt-lookup drafts per
-        # slot, one (1+K)-query forward for the whole batch, up to K+1
-        # tokens emitted per slot per pass (exact: drafts accept only
-        # where they equal the model's own argmax). Sampled slots ride
-        # the same pass advancing one token. A GREEDY request's page
-        # budget carries K slack positions (a verify pass writes K/V at
-        # length..length+K even when nothing accepts); sampled requests
-        # reserve none — they can never accept a draft and the verify
-        # kernel drops their draft-position scatters (_pages_needed).
-        self._spec = int(speculative)
-        self._spec_passes = 0
-        self._spec_emitted = 0      # tokens emitted by greedy slots
-        self._spec_slot_passes = 0  # greedy-slot participations
-        # Device-resident spec windows ([payload] serving_spec_window,
-        # SERVING.md rung 20): W > 0 batches W draft+verify passes into
-        # ONE dispatched device program — drafting, accept/reject, KV
-        # commits, budget freezing, and the pending-token chain all run
-        # in the scan, so the host RTT amortizes over up to W*(1+K)
-        # tokens instead of taxing every pass. Requires spec mode
-        # (speculative > 0); an all-greedy active set rides windows,
-        # any sampled co-tenant falls back to the legacy per-pass path
-        # (identical tokens either way — windows are a scheduling
-        # change, not a semantic one).
-        if spec_window < 0:
-            raise ValueError("spec_window must be >= 0")
-        if spec_window > 0 and self._spec <= 0:
-            raise ValueError(
-                "spec_window needs speculative mode (speculative > 0)"
-            )
-        self._spec_window = int(spec_window)
-        # Operator ceiling for the controller's spec-depth channel
-        # (rung 26): with serving_window=auto the effective
-        # _spec_window floats in [1, cap] at true boundaries; a static
-        # window pins it to the configured value forever.
-        self._spec_window_cap = int(spec_window)
-        self._spec_windows = 0
-        # On-device sampled verify ([payload] serving_spec_sampled_window,
-        # SERVING.md rung 23): with the knob ON (default), a mixed
-        # greedy+sampled batch STAYS on the windowed spec path — sampled
-        # rows ride the verify scan advancing one token per pass with
-        # their positional fold_in keys split inside the scan, emitting
-        # the SAME tokens as the legacy per-pass path (pinned by tests).
-        # OFF restores the rung-20 behaviour (one sampled co-tenant
-        # collapses the batch to _spec_pass) and counts the collapse.
-        self._spec_sampled_window = bool(spec_sampled_window)
-        # Windowed-path collapses, labelled by cause (exported as
-        # spec_window_fallbacks_total{cause=...}): a spec window was
-        # configured but a boundary ran the legacy per-pass path
-        # anyway. "sampled" = mixed batch with the sampled-window knob
-        # off; "spec_off" = speculation disabled with a spec carry in
-        # flight.
-        self._spec_window_fallbacks = {"sampled": 0, "spec_off": 0}
         # Device-resident finish bookkeeping (rung 23): slots whose
         # NEXT boundary sweep should examine them for completion —
         # registered by every site that sets a pending token that
@@ -617,17 +550,6 @@ class PagedGenerationServer:
         # finish sweep completes them and zeroes this.
         self._stops_pending = 0
         self._stop_finishes = 0
-        # Drafting-context capacity for the device-resident proposer:
-        # prompt + generated + pending never exceeds max_seq + 1, and
-        # the device appends at most K past the budget before freezing.
-        self._spec_ctx_cap = int(cfg.max_seq) + int(speculative) + 2
-        # Per-window emitted-tokens histogram (tokens a single request
-        # realized from one dispatched spec window, post-truncation) —
-        # the in-window acceptance E the rung-20 perf model needs, and
-        # the Perfetto counterpart showing logical passes per dispatch.
-        self._hist_spec_tokens = _Hist(
-            (0.0, 1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0, 128.0)
-        )
         # Chunked prefill granule (0 = whole-prompt): long prompts land
         # in fixed-size chunks with the lock RELEASED between chunks, so
         # in-flight requests keep decoding during an admission and XLA
@@ -642,14 +564,8 @@ class PagedGenerationServer:
         if cache is not None:
             slots, pages = cache.slots, cache.num_pages
             page_size = cache.page_size
-        # Spec mode widens the per-sequence table cap by the draft
-        # slack so a full-length (prompt + n_new == max_seq) request
-        # still admits; an injected cache was built with the same
-        # formula (workload._serving_pool_dims).
         self._cache = cache or PagedKVCache(
             cfg, slots=slots, pages=pages, page_size=page_size,
-            max_pages_per_seq=-(-(cfg.max_seq + self._spec)
-                                // page_size),
             kv_dtype=kv_dtype, min_bucket=min_bucket,
             # The most a row moves on between two givings-back: a
             # prefill chunk, or the longest decode window.
@@ -761,7 +677,6 @@ class PagedGenerationServer:
         self._prefix_shadow: dict[int, dict] = {}
         self._persist_stop: threading.Event | None = None
         self._persist_thread: threading.Thread | None = None
-        self._spec_decision: dict | None = None
         # Registry pins live OUTSIDE any request's reservation, so the
         # cache needs a way to reclaim them when a mid-decode grow finds
         # the free list empty — otherwise one tenant's growth would
@@ -1106,9 +1021,7 @@ class PagedGenerationServer:
                 f"prompt ({len(prompt)}) + n_new ({n_new}) exceeds the "
                 f"model's max_seq ({self._cfg.max_seq})"
             )
-        pages_needed = self._pages_needed(
-            total, self._spec > 0 and sampling is None
-        )
+        pages_needed = self._pages_needed(total)
         if pages_needed > self._cache.max_pages_per_seq:
             raise ValueError(
                 f"request needs {pages_needed} pages > max_pages_per_seq "
@@ -1451,14 +1364,13 @@ class PagedGenerationServer:
 
     def _first_token_stays_locked(self) -> bool:
         """Does a request's first token stay on the device (lock
-        held)? Where the pool can keep it there, unless the server
-        speculates (drafting reads the tokens on the host, a spec
-        window's carry is built from them) or checkpoints (the
-        boundary a newcomer joins at journals its pending token):
-        there a newcomer joins at a boundary, which needs its token on
-        the host, and the handler reads it back as it always did."""
-        return (self._unlocked_reads and self._spec == 0
-                and self._checkpoint_every == 0)
+        held)? Where the pool can keep it there (``unlocked_reads``),
+        unless the server checkpoints (``checkpoint_every > 0``: the
+        boundary a newcomer joins at journals its pending token), the
+        only reasons left: there a newcomer joins at a boundary, which
+        needs its token on the host, and the handler reads it back as
+        it always did."""
+        return self._unlocked_reads and self._checkpoint_every == 0
 
     def _first_token_known(self, req: _Request, now: float) -> None:
         """The host has the request's first token (``now``: a stamp
@@ -1592,8 +1504,7 @@ class PagedGenerationServer:
         # With nothing dispatched-unharvested the resize is safe here:
         # the loop's next dispatch at a boundary is always first=True
         # (host tokens), so the carry set_bucket drops was dead anyway.
-        if (self._inflight is None and self._harvesting is None
-                and not self._cache.spec_pending()):
+        if self._inflight is None and self._harvesting is None:
             self._cache.set_bucket(
                 self._cache.bucket_for(self._free_slots[0] + 1)
             )
@@ -1609,8 +1520,6 @@ class PagedGenerationServer:
         drained out of the bucket's top half and nothing is queued.
         Quiescent points only; no-op with bucketing disabled."""
         if not self._cache.min_bucket or self._inflight is not None:
-            return
-        if self._cache.spec_pending():
             return
         bucket = self._cache.bucket
         want = self._cache.rows_in_use()
@@ -1653,8 +1562,7 @@ class PagedGenerationServer:
         #
         # * Delta-skip — a request whose (gen_len, next_token) match
         #   its standing entry would re-serialize byte-identical state
-        #   (decode only appends; KV below saved_len never mutates, and
-        #   spec slack past saved_len is outside the restore contract),
+        #   (decode only appends; KV below saved_len never mutates),
         #   so it keeps the old entry at zero device work. A quiescent
         #   boundary now costs O(changes), not O(live).
         # * Coalesced gather — every page the boundary DOES need
@@ -2428,192 +2336,6 @@ class PagedGenerationServer:
         )
         self._persist_thread.start()
 
-    # ---- speculative-mode economics (VERDICT r4 #7) ----------------------
-
-    def resolve_speculation(self, auto: bool,
-                            timings: dict | None = None) -> dict:
-        """Decide whether speculative mode can pay at THIS deployment's
-        host round trip per dispatch, before traffic arrives. Call
-        once, right after construction (single-host caches only — the
-        probe runs device ops).
-
-        Measures (or takes from ``timings`` — the test seam) the wall
-        cost of one K-draft verify pass and one ``window``-step decode
-        window at full batch, each including the host round trip, and
-        compares best-case speculative throughput — every draft
-        accepted, ``(K+1) / verify_s`` — against the windowed path's
-        ``window / window_s``. When windows dominate even speculation's
-        BEST case, the mode is a pure regression for greedy traffic
-        (7x in a pre-PR-1 chip run, record removed in PR 21, not
-        comparable with today's code):
-        ``auto=True`` falls back to windowed decode (speculation off);
-        ``auto=False`` keeps the operator's explicit choice but logs a
-        loud warning. Returns the decision dict, also exposed under
-        ``stats()["spec_decision"]``.
-        """
-        if self._spec <= 0:
-            raise RuntimeError("resolve_speculation needs spec mode on")
-        t = timings or self._probe_spec_timings()
-        return self._apply_spec_decision(auto, t)
-
-    def disable_speculation(self, reason: str) -> dict:
-        """Turn speculation off without probing, recording why — the
-        multi-host slice path's resolution of "auto": the economics
-        probe is single-host only (its device ops would enter the
-        slice op-stream), and UNMEASURED speculation over a long host
-        round trip is the exact regression auto mode exists to prevent,
-        so unmeasured resolves to windows. Operators who want speculation
-        on a slice set an explicit K."""
-        with self._hold("control"):
-            self._spec = 0
-        decision = {"mode": f"windowed ({reason})",
-                    "windows_dominate": None}
-        self._spec_decision = decision
-        return decision
-
-    def _apply_spec_decision(self, auto: bool, t: dict) -> dict:
-        k = self._spec
-        window = t.get("probed_window", self._window)
-        spec_best = (k + 1) / t["verify_s"]
-        windowed = window / t["window_s"]
-        fallback = windowed > spec_best
-        decision = {
-            "verify_ms": round(t["verify_s"] * 1e3, 2),
-            "window_ms": round(t["window_s"] * 1e3, 2),
-            "window": window,
-            "draft_len": k,
-            "spec_best_tokens_per_sec": round(spec_best, 1),
-            "windowed_tokens_per_sec": round(windowed, 1),
-            "windows_dominate": fallback,
-            "mode": ("windowed (auto fallback)" if fallback and auto
-                     else "speculative" if not fallback
-                     else "speculative (operator override)"),
-        }
-        if self._spec_window > 0 and "spec_window_s" in t:
-            # Sampled co-tenant pricing (rung 23): a sampled row
-            # advances one token per pass on either path, so the
-            # choice is W host round trips (legacy _spec_pass) vs one
-            # (the windowed scan). Both rates are measured, not
-            # modelled — the same W-pass token count divided by W
-            # per-pass RTTs vs one windowed dispatch+harvest.
-            w = self._spec_window
-            legacy = 1 / t["verify_s"]
-            windowed_sampled = w / t["spec_window_s"]
-            decision["spec_window_ms"] = round(
-                t["spec_window_s"] * 1e3, 2
-            )
-            decision["sampled_cotenant_legacy_tokens_per_sec"] = (
-                round(legacy, 1)
-            )
-            decision["sampled_cotenant_windowed_tokens_per_sec"] = (
-                round(windowed_sampled, 1)
-            )
-            decision["sampled_window_pays"] = (
-                windowed_sampled >= legacy
-            )
-        if fallback:
-            action = ("falling back to windowed decode"
-                      if auto else
-                      "serving_speculative is set explicitly — keeping "
-                      "it; expect slower greedy traffic")
-            print(
-                "[kvedge-serve] WARNING: windowed decode dominates "
-                f"speculation's best case at this host round trip "
-                f"({windowed:.0f} vs {spec_best:.0f} tok/s best-case "
-                f"per slot); {action}", flush=True,
-            )
-            if auto:
-                with self._hold("control"):
-                    self._spec = 0
-        self._spec_decision = decision
-        return decision
-
-    def _probe_spec_timings(self) -> dict:
-        """Measure one verify pass and one decode window on the live
-        cache (slot 0, one-token prompt, admitted and released around
-        each measurement so lengths never accumulate; compile excluded
-        by a warmup call — the programs are the same ones real traffic
-        uses, so the warmup cost is front-loaded, not added)."""
-        import numpy as _np
-
-        k = self._spec
-        n = self._cache.bucket
-        probe_tokens = _np.zeros((n, 1 + k), _np.int32)
-        step_tokens = _np.zeros((n,), _np.int32)
-        active = _np.zeros((n,), bool)
-        active[0] = True
-        spec_mask = active.copy()
-        # The probed window must fit the model (positions 1..1+w) and
-        # be one the serving loop can actually run:
-        # _dispatch_window_locked floors to a power of two, so probe
-        # the floored value — timing an unrealizable window would
-        # overstate the windowed rate near the crossover (and compile
-        # a program real traffic never reuses).
-        window = min(self._window, self._cfg.max_seq - 1 - k)
-        if window > 1:
-            window = 1 << (window.bit_length() - 1)
-        with self._hold("control"):
-            import jax.numpy as jnp
-
-            def timed(op) -> float:
-                self._cache.admit(0, 1)
-                self._cache.prefill(
-                    self._params, 0, jnp.zeros((1,), jnp.int32)
-                )
-                start = time.perf_counter()
-                _np.asarray(op())
-                elapsed = time.perf_counter() - start
-                self._cache.release(0)
-                return elapsed
-
-            def verify():
-                emitted, _, _ = self._cache.step_spec(
-                    self._params, probe_tokens, active=active,
-                    spec_mask=spec_mask,
-                )
-                return emitted
-
-            def run_window():
-                # The capped window the decode loop dispatches, forced
-                # at once: one dispatch + harvest on slot 0.
-                handle = self._cache.dispatch_window(
-                    self._params, step_tokens, window, active=active,
-                )
-                produced = self._cache.harvest_window(handle)
-                self._cache.drop_carry()
-                return produced
-
-            def run_spec_window():
-                # One full spec-window dispatch+harvest on slot 0 —
-                # the program the windowed sampled co-tenant rides, so
-                # its price is measured with the RTT amortization the
-                # rung-23 decision needs.
-                budgets = _np.zeros((n,), _np.int32)
-                budgets[0] = self._spec_window
-                ctx = _np.zeros((n, self._spec_ctx_cap), _np.int32)
-                ctx_len = _np.zeros((n,), _np.int32)
-                ctx_len[0] = 2  # prefilled token + pending
-                handle = self._cache.dispatch_spec_window(
-                    self._params, step_tokens, self._spec_window, k,
-                    budgets, ctx=ctx, ctx_len=ctx_len,
-                )
-                emitted, _, _ = self._cache.harvest_spec_window(handle)
-                self._cache.drop_carry()
-                return emitted
-
-            timed(verify)  # compile + first-execution cost, untimed
-            timed(run_window)
-            verify_s = min(timed(verify) for _ in range(2))
-            window_s = min(timed(run_window) for _ in range(2))
-            out = {"verify_s": verify_s, "window_s": window_s,
-                   "probed_window": window}
-            if self._spec_window > 0:
-                timed(run_spec_window)
-                out["spec_window_s"] = min(
-                    timed(run_spec_window) for _ in range(2)
-                )
-        return out
-
     def close(self, drain: bool = False) -> None:
         """Shut down. Hard close (default) poisons in-flight requests
         with :class:`ServerClosed`; ``drain=True`` stops admission
@@ -2637,7 +2359,7 @@ class PagedGenerationServer:
             self._work.notify_all()
         self._thread.join(timeout=600 if drain else 30)
         if not drain and self._thread.is_alive():
-            # A healthy-but-slow step (first-time window/spec compile on
+            # A healthy-but-slow step (first-time window compile on
             # a large model can exceed 30 s) must not be classified as a
             # wedged follower below — retry the join once before
             # deciding the thread is dead.
@@ -3125,11 +2847,7 @@ class PagedGenerationServer:
             "checkpoint_unchanged_total": self._checkpoints_unchanged,
             "journal_restores_total": self._journal_restores,
             # Device-resident endgame (SERVING.md rung 23):
-            # windowed-path collapses by cause (rendered as one
-            # labelled Prometheus counter) and stop-token finishes.
-            "spec_window_fallbacks": dict(
-                self._spec_window_fallbacks
-            ),
+            # stop-token finishes.
             "stop_finishes_total": self._stop_finishes,
             # The clock inside the loop and the submit path (phases,
             # runtime/tracing.py). clock_s is stamped inside this lock
@@ -3221,35 +2939,6 @@ class PagedGenerationServer:
         out.update(self._sched.stats_locked())
         if self._degraded_reason:
             out["degraded_reason"] = self._degraded_reason
-        if self._spec:
-            # Realized acceleration PER GREEDY SLOT: mean tokens a
-            # greedy slot emits per verify pass it participates in
-            # (1.0 = speculation never paid; K+1 = every draft
-            # accepted) — normalized by slot-participations, not
-            # passes, so concurrency cannot inflate it.
-            out["spec_draft_len"] = self._spec
-            out["spec_passes"] = self._spec_passes
-            out["spec_emitted_per_pass"] = round(
-                self._spec_emitted / self._spec_slot_passes, 3
-            ) if self._spec_slot_passes else 0.0
-        if self._spec_window:
-            # Device-resident spec windows (SERVING.md rung 20):
-            # the knob, the dispatch count, and the per-window
-            # emitted-tokens histogram (in-window acceptance E —
-            # logical passes per dispatch for the Perfetto view).
-            out["spec_window"] = self._spec_window
-            out["spec_windows_total"] = self._spec_windows
-            out["spec_window_sampled"] = (
-                1 if self._spec_sampled_window else 0
-            )
-            out["spec_window_emitted_tokens"] = (
-                self._hist_spec_tokens.snapshot()
-            )
-        if self._spec_decision is not None:
-            # The boot-time economics decision (resolve_speculation)
-            # — present even after an auto fallback zeroed _spec, so
-            # an operator can see WHY speculation is off.
-            out["spec_decision"] = dict(self._spec_decision)
         return out
 
     def _stats_merge_unlocked(self, out: dict) -> None:
@@ -3302,9 +2991,6 @@ class PagedGenerationServer:
             "pages_total": self._pages_total,
             "page_size": self._cache.page_size,
             "window": self._window,
-            "speculative": self._spec,
-            "spec_window": self._spec_window,
-            "spec_sampled_window": int(self._spec_sampled_window),
             "prefill_chunk": self._prefill_chunk,
             "prefix_cache": int(self._prefix_enabled),
             "checkpoint_every": self._checkpoint_every,
@@ -3447,7 +3133,7 @@ class PagedGenerationServer:
         """Complete a finished request (lock held): decode-stage
         histogram, completion span, slot/reservation release, waiter
         wakeup — the ONE exit path every normal finish site (budget
-        sweep, inline overlap finish, speculative pass) shares."""
+        sweep, inline overlap finish) shares."""
         t1 = time.perf_counter()
         if req.t_admit:
             self._hist_decode.observe((t1 - req.t_admit) * 1e3)
@@ -3494,16 +3180,9 @@ class PagedGenerationServer:
             req.stream.put(_STREAM_DONE)
         req.done.set()
 
-    def _pages_needed(self, total: int, slack: bool) -> int:
-        """Worst-case pages for a ``total``-token request. ``slack``
-        (greedy requests under spec mode) adds the K draft positions a
-        verify pass writes at length..length+K regardless of
-        acceptance. Sampled requests carry NO slack: they can never
-        accept a draft, and the verify kernel drops their
-        draft-position scatters (kvcache._spec_verify_core), so their
-        footprint is exactly a plain request's."""
-        pad = self._spec if slack else 0
-        return -(-(total + pad) // self._cache.page_size)
+    def _pages_needed(self, total: int) -> int:
+        """Worst-case pages for a ``total``-token request."""
+        return -(-total // self._cache.page_size)
 
     @staticmethod
     def _pages_for(req: _Request) -> int:
@@ -3563,7 +3242,7 @@ class PagedGenerationServer:
 
     def _count_steps_locked(self, steps: int, bucket: int,
                             rows) -> None:
-        """Book one window (or single step, or verify pass) of
+        """Book one window (or single step) of
         ``steps`` decode steps over ``bucket`` device rows, before its
         ``rows`` ((slot, request), live and still admitted) emit and
         release: the steps, the row-steps the device computed, and
@@ -3584,137 +3263,10 @@ class PagedGenerationServer:
                 self._cache.window_pages_held(slot) for slot, _ in rows)
 
     @staticmethod
-    def _draft(req: _Request, k: int) -> list[int]:
-        """K prompt-lookup drafts for a greedy request (host-side
-        mirror of models/speculative.py's n-gram proposer — drafting
-        needs no device work because the host owns every emitted
-        token). Any draft is legal; verification makes correctness
-        draft-independent."""
-        ctx = req.prompt + req.generated + [req.next_token]
-        g0, g1 = ctx[-2] if len(ctx) > 1 else ctx[-1], ctx[-1]
-        for p in range(len(ctx) - 3, -1, -1):
-            if ctx[p] == g0 and ctx[p + 1] == g1:
-                start = max(0, min(p + 2, len(ctx) - k))
-                cand = ctx[start:start + k]
-                return cand + [g1] * (k - len(cand))
-        return [g1] * k
-
-    def _spec_pass(self) -> None:
-        """One speculative verify pass for the active batch (lock
-        held). Greedy slots emit their pending token plus up to K
-        accepted drafts and a bonus; sampled slots advance exactly one
-        sampled token from the pass's pending-position logits —
-        identical schedule semantics to the per-step path, so the
-        key-schedule exactness holds unchanged."""
-        k = self._spec
-        n = self._cache.bucket
-        phase = self._phase
-        with phase("loop/dispatch"):
-            tokens = np.zeros((n, k + 1), np.int32)
-            mask = np.zeros((n,), bool)
-            spec_mask = np.zeros((n,), bool)
-            for slot, req in self._active.items():
-                tokens[slot, 0] = req.next_token
-                mask[slot] = True
-                if req.sampling is None:
-                    spec_mask[slot] = True
-                    tokens[slot, 1:] = self._draft(req, k)
-        joined = phase.last  # the end of loop/dispatch
-        for req in self._active.values():
-            if req.state != "decode":
-                self._to_state(req, "decode", joined)
-        with phase("loop/harvest_wait",
-                   args={"rows": len(self._active), "spec": k}):
-            emitted, accepted, logits0 = self._cache.step_spec(
-                self._params, tokens, active=mask, spec_mask=spec_mask
-            )
-            emitted = np.asarray(emitted)
-            sampled_next = self._sample_slots(logits0, {
-                slot: req for slot, req in self._active.items()
-                if req.sampling is not None
-            })
-        with phase("loop/emit"):
-            self._spec_passes += 1
-            # One verify pass is one decode step of every active row.
-            self._count_steps_locked(1, n, self._active.items())
-            self._decode_row_steps += len(self._active)
-            for slot in list(self._active):
-                req = self._active[slot]
-                if req.sampling is not None:
-                    self._emit_pending_locked(req)
-                    req.next_token = sampled_next[slot]
-                    self._note_finish_candidate_locked(slot, req)
-                    continue
-                a = int(accepted[slot])
-                before = len(req.generated)
-                room = req.n_new - before
-                seq = [req.next_token] + [int(t)
-                                          for t in emitted[slot, :a]]
-                emit_n, stopped = 0, False
-                for t in seq[:room]:
-                    self._emit(req, t)
-                    emit_n += 1
-                    if t == req.stop_token:
-                        stopped = True
-                        break
-                self._note_emitted_locked(req, before)
-                self._spec_emitted += emit_n
-                self._spec_slot_passes += 1
-                if stopped:
-                    # Passes run at boundaries only (nothing in
-                    # flight): the stop finish never needs the
-                    # deferred path.
-                    self._stop_finishes += 1
-                    self._finish_request_locked(slot, req)
-                elif len(req.generated) >= req.n_new:
-                    self._finish_request_locked(slot, req)
-                else:
-                    # room > len(seq) here: room <= len(seq) means the
-                    # request just filled its budget and took the
-                    # finished branch above. The bonus token becomes
-                    # pending.
-                    req.next_token = int(emitted[slot, a])
-                    self._note_finish_candidate_locked(slot, req)
-
-    @staticmethod
     def _key_data_shape(samplers) -> tuple:
         """Trailing shape of one row's raw key data (threefry: (2,));
         taken from a live request so the impl is never hardcoded."""
         return next(iter(samplers.values())).key_data.shape
-
-    @staticmethod
-    def _sample_slots(logits, samplers: dict) -> dict[int, int]:
-        """Sampled slots' tokens from [slots, V] logits: ONE vmapped
-        fold_in (token index = each request's len(generated)+1, the
-        cross-backend key schedule) + ONE batched filter/categorical +
-        one host transfer. Shared by the per-step path and the
-        speculative pass, which samples from the pass's pending-position
-        logits without paying the greedy argmax."""
-        if not samplers:
-            return {}
-        import jax
-        import jax.numpy as jnp
-
-        from kvedge_tpu.models.decode import sample_token
-
-        slots = sorted(samplers)
-        seed_keys = jnp.stack(
-            [samplers[s].sampling[0] for s in slots]
-        )
-        steps = jnp.asarray(
-            [len(samplers[s].generated) + 1 for s in slots], jnp.int32
-        )
-        keys = jax.vmap(jax.random.fold_in)(seed_keys, steps)
-        temps = jnp.asarray(
-            [samplers[s].sampling[1] for s in slots], jnp.float32
-        )[:, None]
-        top_ps = jnp.asarray(
-            [samplers[s].sampling[2] for s in slots], jnp.float32
-        )[:, None]
-        picked = np.asarray(sample_token(
-            logits[jnp.asarray(slots)], keys, temps, top_ps
-        ))
-        return {s: int(picked[i]) for i, s in enumerate(slots)}
 
     def _sweep_cancelled_locked(self) -> None:
         """Cancelled requests leave at a boundary: slot and pages
@@ -3895,8 +3447,7 @@ class PagedGenerationServer:
                 # now if nothing is in flight, else at the next
                 # boundary (this method only runs at boundaries, so
                 # the flag lands one iteration later at worst).
-                if (self._inflight is None
-                        and not self._cache.spec_pending()):
+                if self._inflight is None:
                     self._cache.set_bucket(
                         self._cache.bucket_for(self._free_slots[0] + 1)
                     )
@@ -4011,8 +3562,8 @@ class PagedGenerationServer:
         Two alternating shapes. At a NON-OVERLAPPED BOUNDARY
         (``_inflight is None``) it reconciles with nothing in flight —
         cancel sweep, finish sweep, admissions implicitly via
-        ``_active``, speculative passes — then DISPATCHES a window
-        without harvesting it. With a window IN FLIGHT it first
+        ``_active`` — then DISPATCHES a window without harvesting
+        it. With a window IN FLIGHT it first
         enqueues the next window on the device-resident carry (no host
         round trip between the two — this is the overlap), then
         harvests and processes the previous window's tokens while the
@@ -4025,9 +3576,9 @@ class PagedGenerationServer:
         chunks are not held up by it either. Whenever exactness
         needs a boundary (``_boundary_wanted_locked``: a cancel, a
         bucket step, a stop, a due checkpoint, the scheduler's resume
-        or preemption, a newcomer to a server that speculates or
-        checkpoints) it harvests WITHOUT dispatching, so the next
-        iteration reconciles at a boundary.
+        or preemption, a newcomer to a server that checkpoints) it
+        harvests WITHOUT dispatching, so the next iteration
+        reconciles at a boundary.
 
         A speculatively dispatched window can never corrupt state: each
         row's device-side ``steps_left`` cap freezes it at its true
@@ -4107,41 +3658,6 @@ class PagedGenerationServer:
                     self._observe_boundary_locked()
                 if not self._active:
                     return "ran"
-                if (self._spec > 0
-                        and any(req.sampling is None
-                                for req in self._active.values())):
-                    all_greedy = all(
-                        req.sampling is None
-                        for req in self._active.values()
-                    )
-                    if (self._spec_window > 0
-                            and (all_greedy
-                                 or self._spec_sampled_window)):
-                        # Device-resident spec windows: draft +
-                        # verify + accept/reject run IN the
-                        # dispatched scan, so spec mode joins the
-                        # double-buffered pipeline instead of
-                        # forcing a boundary per pass. Sampled
-                        # co-tenants ride the scan too (rung 23,
-                        # knob-gated): one token per pass with
-                        # their positional keys split on device.
-                        with phase("loop/dispatch"):
-                            self._inflight = (
-                                self._dispatch_spec_window_locked(
-                                    first=True
-                                )
-                            )
-                        return "ran"
-                    if self._spec_window > 0:
-                        # Mixed batch with the sampled-window knob
-                        # off: the one remaining windowed-path
-                        # collapse, now counted instead of silent.
-                        self._spec_window_fallbacks["sampled"] += 1
-                    # Legacy per-pass speculation: drafting reads
-                    # emitted tokens on the host, so passes run at
-                    # boundaries only and never overlap.
-                    self._spec_pass()
-                    return "ran"
                 with phase("loop/dispatch"):
                     self._inflight = self._dispatch_window_locked(
                         first=True
@@ -4164,41 +3680,14 @@ class PagedGenerationServer:
                     # Enqueue N+1 on the carry BEFORE touching
                     # N's result — the device starts N+1 the
                     # moment N retires, while the host is still
-                    # in the harvest below. The next window rides
-                    # the SAME carry kind as the previous one
-                    # (plain and spec carries are separate device
-                    # state); a kind change joins at a boundary.
-                    if prev.get("kind") not in ("spec",
-                                                "spec_sampled"):
-                        with phase("loop/dispatch"):
-                            self._inflight = (
-                                self._dispatch_window_locked(
-                                    first=False
-                                )
+                    # in the harvest below.
+                    with phase("loop/dispatch"):
+                        self._inflight = (
+                            self._dispatch_window_locked(
+                                first=False
                             )
-                    elif (self._spec > 0
-                          and self._spec_window > 0):
-                        # Kind-matched redispatch: both spec kinds
-                        # share the device spec carry (pending +
-                        # drafting context), so a mixed pipeline
-                        # whose sampled rows all finished simply
-                        # redispatches as plain "spec" on the same
-                        # carry.
-                        with phase("loop/dispatch"):
-                            self._inflight = (
-                                self._dispatch_spec_window_locked(
-                                    first=False
-                                )
-                            )
-                    else:
-                        # Speculation was disabled with a spec
-                        # window in flight — collapse to a
-                        # boundary (counted: the next boundary
-                        # runs the non-windowed path).
-                        self._spec_window_fallbacks["spec_off"] += 1
-                if prev.get("kind") in ("spec", "spec_sampled"):
-                    self._harvest_spec_window_locked(prev)
-                elif self._harvest_locked(prev, hold):
+                        )
+                if self._harvest_locked(prev, hold):
                     return "again"
             except Exception:
                 # prev was not reconciled — restore its inflight
@@ -4222,14 +3711,10 @@ class PagedGenerationServer:
 
         ``cancel``: a cancel must be honored. ``newcomer``: a slot is
         active that the in-flight window never dispatched, and the
-        server speculates: a speculative window's carry (``spec`` /
-        ``spec_sampled``) holds each row's drafting context, which a
-        newcomer does not have on the device, and a plain window under
-        speculation (every row sampled) changes kind with a greedy
-        newcomer, so there it may only join at a boundary; or the
-        server checkpoints (``checkpoint_every``): the boundary a
-        newcomer joins at ticks the checkpoint clock, and at a cadence
-        of 1 journals it before its first step, which rung 22 keeps.
+        server checkpoints (``checkpoint_every > 0``, the only reason
+        left): the boundary a newcomer joins at ticks the checkpoint
+        clock, and at a cadence of 1 journals it before its first
+        step, which rung 22 keeps.
         Otherwise a window's carry is one token a row, and the
         newcomer's is picked (on the device, or host-known; nothing
         of it is in flight): the next overlapped dispatch feeds that
@@ -4246,8 +3731,7 @@ class PagedGenerationServer:
         token, or whose first token is its stop)."""
         if any(req.cancelled for req in self._active.values()):
             return "cancel"
-        if (self._spec > 0 or self._checkpoint_every > 0
-                or prev.get("kind") in ("spec", "spec_sampled")):
+        if self._checkpoint_every > 0:
             dispatched = {slot for slot, _, _ in prev["parts"]}
             if any(slot not in dispatched for slot in self._active):
                 return "newcomer"
@@ -4547,226 +4031,6 @@ class PagedGenerationServer:
             self._window = self._autotune.window()
         return self._unlocked_reads
 
-    def _dispatch_spec_window_locked(self, first: bool) -> dict | None:
-        """Enqueue one device-resident spec window — ``_spec_window``
-        draft+verify passes in a single dispatched program — for every
-        active greedy slot with budget remaining (lock held); returns
-        the in-flight record (``kind="spec"``), or None when no slot
-        can advance.
-
-        ``first`` distinguishes the boundary dispatch (host-known
-        pending tokens plus each row's drafting context: prompt +
-        generated + pending) from the overlapped dispatch
-        (``tokens=None`` — pending, context, and context lengths ride
-        the device-resident spec carry). The per-row budget is
-        ``n_new - len(generated) - inflight`` — the pending token is
-        CONSUMED by the window (each pass emits it), unlike the plain
-        window path's stepless finish-check emission, so there is no
-        ``- 1``. The request's ``inflight`` advances by the cache's
-        worst-case cap (``min(budget + K, W*(1+K))``); the true
-        advance lands at harvest, truncated at the budget exactly like
-        the legacy per-pass path's room cap.
-
-        SAMPLED rows (rung 23, ``spec_sampled_window``) join the same
-        window: the scan advances them exactly one token per live pass
-        with on-device ``fold_in(seed, base + i)`` keys, so their cap
-        is EXACT (``min(budget, W)`` — kvcache.spec_window_caps) and
-        ``base = len(generated) + inflight + 1`` reproduces the legacy
-        per-pass schedule bit-identically even across pipelined
-        redispatches. The record's kind is ``"spec_sampled"`` when any
-        sampled row rides (``"spec"`` otherwise); both kinds share the
-        device spec carry, so kind-matched redispatch treats them as
-        one family.
-        """
-        k = self._spec
-        w = self._spec_window
-        n = self._cache.bucket
-        budgets = np.zeros((n,), np.int32)
-        parts = []
-        for slot, req in self._active.items():
-            room = req.n_new - len(req.generated) - req.inflight
-            if room > 0 and not req.stopped:
-                budgets[slot] = room
-                parts.append((slot, req))
-            elif req.inflight == 0:
-                # Same self-healing backstop as the plain dispatch.
-                self._finish_ready.add(slot)
-        if not parts:
-            return None
-        samplers = {slot: req for slot, req in parts
-                    if req.sampling is not None}
-        sampling = None
-        if samplers:
-            key_data = np.zeros(
-                (n,) + self._key_data_shape(samplers), np.uint32
-            )
-            base_steps = np.zeros((n,), np.int32)
-            temps = np.ones((n,), np.float32)
-            top_ps = np.ones((n,), np.float32)
-            smask = np.zeros((n,), bool)
-            for slot, req in samplers.items():
-                key_data[slot] = req.key_data
-                # Committed position, as in the plain sampled window:
-                # token t samples with fold_in(seed, t) regardless of
-                # pipelining, because a sampled row's in-window advance
-                # is exactly its cap (1 token per live pass).
-                base_steps[slot] = (len(req.generated)
-                                    + req.inflight + 1)
-                temps[slot] = float(req.sampling[1])
-                top_ps[slot] = float(req.sampling[2])
-                smask[slot] = True
-            sampling = (key_data, base_steps, temps, top_ps, smask)
-        if first:
-            ctx = np.zeros((n, self._spec_ctx_cap), np.int32)
-            ctx_len = np.zeros((n,), np.int32)
-            tokens = np.zeros((n,), np.int32)
-            for slot, req in parts:
-                seq = req.prompt + req.generated + [req.next_token]
-                ctx[slot, :len(seq)] = seq
-                ctx_len[slot] = len(seq)
-                tokens[slot] = req.next_token
-            handle = self._cache.dispatch_spec_window(
-                self._params, tokens, w, k, budgets,
-                ctx=ctx, ctx_len=ctx_len, sampling=sampling,
-            )
-        else:
-            handle = self._cache.dispatch_spec_window(
-                self._params, None, w, k, budgets, sampling=sampling,
-            )
-        recs = []
-        t0 = time.perf_counter()
-        for slot, req in parts:
-            cap = int(handle["caps"][slot])
-            req.inflight += cap
-            recs.append((slot, req, cap))
-            if req.state != "decode":
-                self._to_state(req, "decode", t0)
-        self._hist_depth.observe(0.0 if first else 1.0)
-        return {"kind": "spec_sampled" if samplers else "spec",
-                "window": w, "parts": recs,
-                "handle": handle, "depth": 0 if first else 1,
-                "bucket": n, "t0": t0}
-
-    def _harvest_spec_window_locked(self, rec: dict) -> None:
-        """Force an in-flight spec window's results and reconcile
-        (lock held). Each row replays its pending-token chain — pass
-        ``p`` emits the pending token plus the accepted drafts
-        (``counts[p] - 1`` of the emitted row; the final entry is the
-        next pending) — truncated at the row's remaining budget, so a
-        device-side overshoot (the last live pass may exceed the
-        budget by up to K) never over-emits, exactly like the legacy
-        path's room cap."""
-        with self._phase("loop/harvest_wait") as waited:
-            emitted, counts, _pending = self._cache.harvest_spec_window(
-                rec["handle"]
-            )
-        # Attribution (rung 25), as in _harvest_locked.
-        t_harvest = waited.t1
-        rtt_ms = (t_harvest - rec["t0"]) * 1e3
-        self._hist_rtt.observe(rtt_ms)
-        if self.tracer is not None:
-            self.tracer.span(
-                "spec-window", "serve", rec["t0"], t_harvest,
-                args={"w": rec["window"],
-                      "rows": len(rec["parts"]),
-                      "depth": rec.get("depth", 0)},
-            )
-        with self._phase("loop/emit") as emit:
-            rec["counted"] = True
-            self._ckpt_clock += 1  # window of progress at risk (rung 22)
-            for _, req, cap in rec["parts"]:
-                req.inflight -= cap
-            self._spec_passes += rec["window"]
-            # A step of a spec window is one draft+verify pass.
-            self._count_steps_locked(
-                rec["window"], rec["bucket"],
-                [(slot, req) for slot, req, _ in rec["parts"]
-                 if self._active.get(slot) is req])
-            for slot, req, cap in rec["parts"]:
-                if self._active.get(slot) is not req or req.stopped:
-                    # Released while in flight (normally unreachable —
-                    # cancels resolve at boundaries) or stop-terminated
-                    # at an earlier harvest awaiting its deferred
-                    # finish; nothing to emit into.
-                    continue
-                before = len(req.generated)
-                stopped = False
-                counts_col = counts[:, slot].tolist()
-                for p in range(rec["window"]):
-                    c = counts_col[p]
-                    if c == 0:
-                        # Frozen pass: the row's budget ran out on
-                        # device (rem <= 0) — no tokens, no pending
-                        # advance.
-                        continue
-                    self._decode_row_steps += 1
-                    room = max(req.n_new - len(req.generated), 0)
-                    # Sampled rows advance exactly one token per pass
-                    # (c == 1): seq is just the pending token and the
-                    # device-sampled token becomes the next pending —
-                    # the legacy _spec_pass semantics, scanned.
-                    row = emitted[p, slot, :c].tolist()
-                    seq = ([req.next_token] + row[:-1])[:room]
-                    try:
-                        # Host-side stop truncation, now a C-level
-                        # list search instead of a per-token compare
-                        # loop: later passes decoded garbage and are
-                        # discarded.
-                        stop_i = seq.index(req.stop_token)
-                        seq = seq[:stop_i + 1]
-                        stopped = True
-                    except ValueError:
-                        pass
-                    self._emit_many(req, seq)
-                    emit_n = len(seq)
-                    req.next_token = row[-1]
-                    if req.sampling is None:
-                        # Greedy acceleration stats only — sampled rows
-                        # ride at one token per pass by construction
-                        # and would drag the realized-acceptance gauge
-                        # down.
-                        self._spec_emitted += emit_n
-                        self._spec_slot_passes += 1
-                    if stopped:
-                        break
-                self._note_emitted_locked(req, before)
-                self._hist_spec_tokens.observe(
-                    float(len(req.generated) - before)
-                )
-                if stopped and not req.cancelled:
-                    self._finish_stopped_locked(slot, req)
-                elif (len(req.generated) >= req.n_new
-                        and not req.cancelled):
-                    # Inline finish, as in the plain harvest: a
-                    # saturated pipeline may never visit a boundary.
-                    # The cancelled guard preserves cancel-beats-finish
-                    # ordering.
-                    self._finish_request_locked(slot, req)
-                else:
-                    # The carried pending may itself be the stop token
-                    # (a sampled row's device-sampled next, or a bonus
-                    # token): register it for the boundary sweep.
-                    self._note_finish_candidate_locked(slot, req)
-            self._spec_windows += 1
-            self._overlap_windows += 1
-        if self._autotune is not None:
-            # Spec-depth channel (rung 26): verify passes have their
-            # own per-pass device cost t_v, so the spec window keeps
-            # its own EWMA stream. The pick applies only at a TRUE
-            # boundary (nothing in flight — the next spec dispatch is
-            # first=True and rebuilds from host tokens), never between
-            # kind-matched carry redispatches, and never above the
-            # operator's configured depth cap.
-            self._autotune.observe(
-                rtt_ms=rtt_ms, device_ms=waited.ms, host_ms=emit.ms,
-                window=rec["window"], channel="spec",
-            )
-            if self._inflight is None and self._spec_window_cap > 0:
-                pick = self._autotune.window(
-                    "spec", default=self._spec_window_cap)
-                self._spec_window = max(
-                    1, min(self._spec_window_cap, pick))
-
     def _drain_rec_locked(self, rec: dict | None) -> None:
         """Unwind one in-flight record on the failure path: restore
         the inflight counters and block (deadline-bounded for a slice
@@ -4780,10 +4044,7 @@ class PagedGenerationServer:
             for _, req, adv in rec["parts"]:
                 req.inflight -= adv
         try:
-            if rec.get("kind") in ("spec", "spec_sampled"):
-                self._cache.harvest_spec_window(rec["handle"])
-            else:
-                self._cache.harvest_window(rec["handle"])
+            self._cache.harvest_window(rec["handle"])
         except Exception:
             pass
 

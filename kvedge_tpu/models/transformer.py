@@ -116,8 +116,7 @@ class TransformerConfig:
     # chip at long-context caps (max_seq >= 2048, 128-token pages) and
     # the gather elsewhere; over several chips see
     # kvcache.settle_paged_attention.
-    # Prefill and the speculative verify pass always use the gather
-    # path (multi-query shapes).
+    # Prefill always uses the gather path (multi-query shapes).
     paged_attention: str = "auto"
     # A patterned block (models/hybrid.py; served by the paged path
     # only). ``layer_pattern`` is one period of layer kinds: "attention"

@@ -101,8 +101,8 @@ blocked form.
 
 The serving stack selects this kernel per ``TransformerConfig
 .paged_attention`` ("auto" = kernel on TPU at long-context caps,
-einsum gather elsewhere); the verify pass (multi-query) and prefill
-keep the einsum path.
+einsum gather elsewhere); prefill (multi-query) keeps the einsum
+path.
 """
 
 from __future__ import annotations

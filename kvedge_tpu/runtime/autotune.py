@@ -1,4 +1,4 @@
-"""Online window/spec-depth controller (SERVING.md rung 26).
+"""Online window controller (SERVING.md rung 26).
 
 The overlap pipeline's throughput law (rung 16) is
 
@@ -6,16 +6,14 @@ The overlap pipeline's throughput law (rung 16) is
 
 where ``W`` is the dispatched window, ``t`` the per-step device time,
 and ``R`` the per-boundary host turnaround (bookkeeping + dispatch +
-harvest — everything the device window must hide). The device-resident
-spec window (rung 20) obeys the same shape with the verify-pass time
-``t_v`` and an emitted-tokens multiplier: ``E * W / max(R, W * t_v)``.
-Both laws saturate once ``W * t >= R`` — beyond that point a larger
+harvest — everything the device window must hide). The law
+saturates once ``W * t >= R`` — beyond that point a larger
 window buys no throughput and only adds boundary staleness (cancels,
 newcomers, and checkpoints wait up to a full window). The optimal
 window is therefore the SMALLEST power of two whose device time covers
 the host turnaround.
 
-This module closes the loop on those written-down models using the
+This module closes the loop on that written-down model using the
 rung-25 measurements the serving layer already takes at every harvest:
 
 * ``device_ms``  — the forced device sync inside the harvest
@@ -31,7 +29,7 @@ GC pause) does not whipsaw the window.
 
 Correctness note: the window is pure SCHEDULING — the greedy argmax
 and the positional ``fold_in(seed, t)`` key schedule make emitted
-tokens identical for every window size (rung 16/20 exactness tests).
+tokens identical for every window size (rung 16 exactness tests).
 The controller can therefore never violate bit-identity; it only moves
 work between host and device. That is also why the controller lives
 OUTSIDE the lock discipline: it is plain-data, owned by the serving
@@ -75,11 +73,8 @@ def pick_window(r_ms: float, t_ms: float, lo: int, hi: int) -> int:
 class WindowController:
     """EWMA state + the :func:`pick_window` law for one serving loop.
 
-    One instance can drive several channels (the plain decode window
-    and the spec-window depth) — each channel keeps its own (R, t)
-    estimate because verify passes and decode steps have different
-    per-step device costs. All methods are plain-data and called with
-    the serving work lock held; the instance itself takes no locks.
+    All methods are plain-data and called with the serving work lock
+    held; the instance itself takes no locks.
     """
 
     __slots__ = ("lo", "hi", "alpha", "_r", "_t", "_updates")
@@ -94,13 +89,12 @@ class WindowController:
             raise ValueError("window bounds inverted: "
                              f"[{lo}, {hi}]")
         self.alpha = float(alpha)
-        self._r: dict[str, float] = {}
-        self._t: dict[str, float] = {}
-        self._updates: dict[str, int] = {}
+        self._r = 0.0
+        self._t = 0.0
+        self._updates = 0
 
     def observe(self, *, rtt_ms: float, device_ms: float,
-                host_ms: float, window: int,
-                channel: str = "decode") -> None:
+                host_ms: float, window: int) -> None:
         """Feed one harvested window's measurements (lock held).
 
         ``window`` is the size that was actually dispatched — the
@@ -112,35 +106,32 @@ class WindowController:
         r = max(float(rtt_ms) - float(device_ms), 0.0) + float(host_ms)
         t = max(float(device_ms), 0.0) / float(window)
         a = self.alpha
-        if channel in self._updates:
-            self._r[channel] += a * (r - self._r[channel])
-            self._t[channel] += a * (t - self._t[channel])
-            self._updates[channel] += 1
+        if self._updates:
+            self._r += a * (r - self._r)
+            self._t += a * (t - self._t)
         else:
-            self._r[channel] = r
-            self._t[channel] = t
-            self._updates[channel] = 1
+            self._r = r
+            self._t = t
+        self._updates += 1
 
-    def window(self, channel: str = "decode",
-               default: int | None = None) -> int:
+    def window(self, default: int | None = None) -> int:
         """Current recommendation: :func:`pick_window` on the EWMAs.
         Before the first observation returns ``default`` (clamped) —
         the operator's static seed — or ``hi`` when none given."""
-        if channel not in self._updates:
+        if not self._updates:
             if default is None:
                 return self.hi
             return max(self.lo, min(self.hi,
                                     _pow2_floor(max(1, default))))
-        return pick_window(self._r[channel], self._t[channel],
-                           self.lo, self.hi)
+        return pick_window(self._r, self._t, self.lo, self.hi)
 
-    def snapshot(self, channel: str = "decode") -> dict:
+    def snapshot(self) -> dict:
         """Plain-dict state for /status + the flight recorder."""
         return {
-            "window": self.window(channel),
-            "r_ms": self._r.get(channel, 0.0),
-            "t_ms": self._t.get(channel, 0.0),
-            "updates": self._updates.get(channel, 0),
+            "window": self.window(),
+            "r_ms": self._r,
+            "t_ms": self._t,
+            "updates": self._updates,
             "lo": self.lo,
             "hi": self.hi,
         }

@@ -73,10 +73,7 @@ from kvedge_tpu.models.kvcache import (
     _paged_decode_window_capped_impl,
     _paged_decode_window_sampled_capped_impl,
     _paged_prefill_impl,
-    _paged_spec_window_impl,
-    _paged_spec_window_sampled_impl,
     _scatter_pages_impl,
-    _spec_verify_core,
 )
 
 # Op codes (header[0]). STOP ends the follower loop. WINDOWP/WSAMPLEP
@@ -87,18 +84,16 @@ from kvedge_tpu.models.kvcache import (
 # wire protocol within a run, not across versions: leader and followers
 # boot from one image, so a code means what this file says on every
 # process of a slice.
-(OP_STOP, OP_SYNC, OP_PREFILL, OP_STEP, OP_SPEC, OP_WINDOWP,
- OP_WSAMPLEP, OP_SWAPOUT, OP_SWAPIN, OP_SPECW, OP_SPECWS, OP_MULTI,
- OP_COWP) = range(13)
+(OP_STOP, OP_SYNC, OP_PREFILL, OP_STEP, OP_WINDOWP, OP_WSAMPLEP,
+ OP_SWAPOUT, OP_SWAPIN, OP_MULTI, OP_COWP) = range(10)
 _HEADER_LEN = 4  # [op, a, b, c] — meanings per op below.
 
 # Human names for follower-side replay spans (runtime/tracing.py).
 _OP_NAMES = {
     OP_STOP: "stop", OP_SYNC: "sync", OP_PREFILL: "prefill",
-    OP_STEP: "step", OP_SPEC: "spec", OP_WINDOWP: "windowp",
+    OP_STEP: "step", OP_WINDOWP: "windowp",
     OP_WSAMPLEP: "wsamplep", OP_SWAPOUT: "swapout",
-    OP_SWAPIN: "swapin", OP_SPECW: "specw", OP_SPECWS: "specws",
-    OP_MULTI: "multi", OP_COWP: "cowp",
+    OP_SWAPIN: "swapin", OP_MULTI: "multi", OP_COWP: "cowp",
 }
 
 # Ops whose payloads may ride a coalesced OP_MULTI frame (SERVING.md
@@ -108,8 +103,7 @@ _OP_NAMES = {
 # from its own [op, a, b, c] header, which is what lets the follower
 # carve a packed frame without any out-of-band shape agreement.
 _COALESCABLE = frozenset((
-    OP_SYNC, OP_SWAPIN, OP_WINDOWP, OP_WSAMPLEP, OP_SPECW, OP_SPECWS,
-    OP_COWP,
+    OP_SYNC, OP_SWAPIN, OP_WINDOWP, OP_WSAMPLEP, OP_COWP,
 ))
 
 
@@ -155,10 +149,6 @@ def _slice_kernels(mesh, cfg, quantized: bool = False):
         _decode_step_core, static_argnames=("cfg",),
         donate_argnums=(1,), out_shardings=(rep, state_sh),
     )
-    spec = jax.jit(
-        _spec_verify_core, static_argnames=("cfg",),
-        donate_argnums=(1,), out_shardings=(rep, rep, rep, state_sh),
-    )
     window_capped = jax.jit(
         _paged_decode_window_capped_impl,
         static_argnames=("cfg", "n_steps"), donate_argnums=(1,),
@@ -168,26 +158,6 @@ def _slice_kernels(mesh, cfg, quantized: bool = False):
         _paged_decode_window_sampled_capped_impl,
         static_argnames=("cfg", "n_steps"), donate_argnums=(1,),
         out_shardings=(rep, state_sh),
-    )
-    # Device-resident spec windows (SERVING.md rung 20): emitted,
-    # counts, and the pending/context carry all pin REPLICATED so the
-    # leader host-reads results from its shard and every process holds
-    # its own copy of the carry for the next window's dispatch.
-    specw = jax.jit(
-        _paged_spec_window_impl,
-        static_argnames=("cfg", "n_passes", "k_len"),
-        donate_argnums=(1,),
-        out_shardings=(rep, rep, rep, rep, rep, state_sh),
-    )
-    # Mixed greedy/sampled spec window (SERVING.md rung 23): same
-    # carry triple and output shardings as the greedy program — the
-    # two share one device-resident carry, so a pipeline may hand the
-    # carry between them when the batch's sampled population drains.
-    specws = jax.jit(
-        _paged_spec_window_sampled_impl,
-        static_argnames=("cfg", "n_passes", "k_len"),
-        donate_argnums=(1,),
-        out_shardings=(rep, rep, rep, rep, rep, state_sh),
     )
     # Preemptive swap (SERVING.md rung 17): the gather pins REPLICATED
     # outputs — an all-gather over the model-sharded pool dims, so the
@@ -209,9 +179,8 @@ def _slice_kernels(mesh, cfg, quantized: bool = False):
     cow = jax.jit(
         _cow_pair_core, donate_argnums=(0,), out_shardings=state_sh,
     )
-    return (rep, state_sh, prefill, step, spec, window_capped,
-            wsample_capped, swap_gather, swap_scatter, specw, specws,
-            cow)
+    return (rep, state_sh, prefill, step, window_capped,
+            wsample_capped, swap_gather, swap_scatter, cow)
 
 
 def _cow_pair_core(state, pair):
@@ -253,10 +222,8 @@ class SlicePagedKVCache(PagedKVCache):
         cfg = dataclasses.replace(cfg, paged_attention="gather")
         self.mesh = mesh
         (self._rep, self._state_sh, self._k_prefill, self._k_step,
-         self._k_spec,
          self._k_window_capped, self._k_wsample_capped,
          self._k_swapout, self._k_swapin,
-         self._k_specw, self._k_specws,
          self._k_cow) = _slice_kernels(
              mesh, cfg, quantized=kv_dtype == "int8"
          )
@@ -496,20 +463,6 @@ class SlicePagedKVCache(PagedKVCache):
                     ((n,), np.int32), ((n,), np.float32),
                     ((n,), np.float32), ((n,), bool), ((n,), np.int32),
                     ((n,), np.int32))
-        if op == OP_SPECW:
-            # a = n_passes, b = k_len, c = ctx width (0 = carry).
-            width = c if c > 0 else 1
-            return (((n,), np.int32), ((n,), bool), ((n,), np.int32),
-                    ((n, width), np.int32), ((n,), np.int32))
-        if op == OP_SPECWS:
-            # a = n_passes, b = k_len * 256 + key-data width,
-            # c = ctx width (0 = carry).
-            kw = b % 256
-            width = c if c > 0 else 1
-            return (((n,), np.int32), ((n,), bool), ((n,), np.int32),
-                    ((n, width), np.int32), ((n,), np.int32),
-                    ((n, kw), np.uint32), ((n,), np.int32),
-                    ((n,), np.float32), ((n,), np.float32), ((n,), bool))
         raise PagedCacheError(f"op {op} is not coalescable")
 
     def _replay_packed(self, params, op: int, a: int, b: int, c: int,
@@ -529,13 +482,6 @@ class SlicePagedKVCache(PagedKVCache):
         elif op == OP_WSAMPLEP:
             self._exec_window_sampled_pipelined(
                 params, *payload, n_steps=a, carry=bool(c))
-        elif op == OP_SPECW:
-            self._exec_spec_window(
-                params, *payload, n_passes=a, k_len=b, carry=c == 0)
-        elif op == OP_SPECWS:
-            self._exec_spec_window(
-                params, *payload, n_passes=a, k_len=b // 256,
-                carry=c == 0)
         else:  # pragma: no cover - _multi_templates already refused
             raise PagedCacheError(f"op {op} is not coalescable")
 
@@ -858,135 +804,6 @@ class SlicePagedKVCache(PagedKVCache):
                     np.zeros(shape[:-1], np.float32)]
         return tuple(out)
 
-    def _device_spec(self, params, tokens, active, spec_mask):
-        self._check_live()
-        self._flush_ops()
-        tokens = np.asarray(tokens, np.int32)
-        mask = self._active_np(active)
-
-        def op():
-            self._send_header(OP_SPEC, tokens.shape[1] - 1)
-            sent, m, smask = self._bcast(
-                (tokens, mask, np.asarray(spec_mask, bool))
-            )
-            return self._exec_spec(params, np.asarray(sent),
-                                   np.asarray(m), np.asarray(smask))
-
-        return self._traced_run(("spec", tokens.shape[1]), op)
-
-    def _exec_spec(self, params, tokens: np.ndarray, mask: np.ndarray,
-                   spec_mask: np.ndarray):
-        emitted, accepted, logits0, self.state = self._k_spec(
-            params, self.state, self._global(tokens.astype(np.int32)),
-            self.cfg, self._global(mask.astype(bool)),
-            self._global(spec_mask.astype(bool)),
-        )
-        return (self._read(emitted), self._read(accepted),
-                self._read(logits0))
-
-    def _device_spec_window(self, params, tokens, n_passes: int,
-                            k_len: int, active, budgets, ctx, ctx_len,
-                            sampling=None):
-        """Leader: broadcast + enqueue one device-resident spec window
-        WITHOUT reading the result (the windowed twin of OP_WINDOWP).
-        ``tokens=None`` selects the device-resident spec carry —
-        pending token, drafting context, and context lengths from the
-        previous window, which every process holds replicated from its
-        own execution, so nothing blocks between back-to-back windows.
-        Header ``c`` carries the drafting-context width (0 = carry, so
-        followers know which payload template to expect).
-
-        ``sampling`` (rung 23) switches the op to OP_SPECWS — the
-        mixed greedy/sampled program — whose header ``b`` packs
-        ``k_len * 256 + key-data width`` (both are tiny; the follower
-        unpacks with divmod) and whose payload appends the five
-        sampler arrays. The two programs share one carry triple, so a
-        pipeline hands the carry between them freely."""
-        self._check_live()
-        carry = tokens is None
-        if carry:
-            tokens_np = np.zeros((self.slots,), np.int32)
-            ctx_np = np.zeros((self.slots, 1), np.int32)
-            ctx_len_np = np.zeros((self.slots,), np.int32)
-            width = 0
-        else:
-            tokens_np = np.asarray(tokens, np.int32)
-            ctx_np = np.asarray(ctx, np.int32)
-            ctx_len_np = np.asarray(ctx_len, np.int32)
-            width = int(ctx_np.shape[1])
-        mask = self._active_np(active)
-        budgets_np = np.asarray(budgets, np.int32)
-        payload = (tokens_np, mask, budgets_np, ctx_np, ctx_len_np)
-        if sampling is None:
-            hdr = (OP_SPECW, n_passes, k_len, width)
-        else:
-            key_data, base_steps, temps, top_ps, smask = sampling
-            key_data = np.asarray(key_data, np.uint32)
-            payload = payload + (
-                key_data,
-                np.asarray(base_steps, np.int32),
-                np.asarray(temps, np.float32),
-                np.asarray(top_ps, np.float32),
-                np.asarray(smask, bool),
-            )
-            hdr = (OP_SPECWS, n_passes,
-                   k_len * 256 + key_data.shape[1], width)
-
-        self._queue_op(
-            hdr, payload,
-            lambda: self._exec_spec_window(
-                params, *payload,
-                n_passes=n_passes, k_len=k_len, carry=carry,
-            ),
-        )
-        return self._flush_ops((_OP_NAMES[hdr[0]], n_passes, k_len))
-
-    def _exec_spec_window(self, params, tokens: np.ndarray,
-                          mask: np.ndarray, budgets: np.ndarray,
-                          ctx: np.ndarray, ctx_len: np.ndarray,
-                          key_data=None, base_steps=None, temps=None,
-                          top_ps=None, smask=None, *,
-                          n_passes: int, k_len: int, carry: bool):
-        if carry:
-            pending, ctx_dev, ctx_len_dev = self._spec_carry
-        else:
-            pending = self._global(tokens.astype(np.int32))
-            ctx_dev = self._global(ctx.astype(np.int32))
-            ctx_len_dev = self._global(ctx_len.astype(np.int32))
-        if key_data is None:
-            kernel, extra = self._k_specw, ()
-        else:
-            kernel = self._k_specws
-            extra = (
-                self._global(np.asarray(key_data).astype(np.uint32)),
-                self._global(np.asarray(base_steps).astype(np.int32)),
-                self._global(np.asarray(temps).astype(np.float32)),
-                self._global(np.asarray(top_ps).astype(np.float32)),
-                self._global(np.asarray(smask).astype(bool)),
-            )
-        (emitted, counts, pend_out, ctx_out, ctx_len_out,
-         self.state) = kernel(
-            params, self.state, pending, self.cfg, n_passes, k_len,
-            self._global(mask.astype(bool)),
-            self._global(budgets.astype(np.int32)),
-            ctx_dev, ctx_len_dev, *extra,
-        )
-        self._spec_carry = (pend_out, ctx_out, ctx_len_out)
-        return emitted, counts, pend_out
-
-    def _force_spec_window(self, handle):
-        """Leader: force a dispatched spec window's results. Like
-        ``harvest_window``: deadline-bounded but NOT a broadcast — the
-        outputs are replicated and followers never read them."""
-        self._check_live()
-        self._flush_ops()
-        return self._traced_run(
-            ("specwharvest",),
-            lambda: (self._read(handle["emitted"]),
-                     self._read(handle["counts"]),
-                     self._read(handle["pending"])),
-        )
-
     def stop(self) -> None:
         """Leader: release the followers (end of serve). Idempotent —
         the serving layer calls this from ``close()`` UNDER the server
@@ -1139,14 +956,6 @@ class SlicePagedKVCache(PagedKVCache):
                 np.zeros((self.slots,), bool),
             ))
             self._exec_step(params, np.asarray(tokens), np.asarray(mask))
-        elif op == OP_SPEC:
-            tokens, mask, smask = self._bcast((
-                np.zeros((self.slots, a + 1), np.int32),
-                np.zeros((self.slots,), bool),
-                np.zeros((self.slots,), bool),
-            ))
-            self._exec_spec(params, np.asarray(tokens),
-                            np.asarray(mask), np.asarray(smask))
         elif op == OP_SWAPOUT:
             # a = page count. The gather's replicated result is
             # discarded — only the leader's host copy becomes the

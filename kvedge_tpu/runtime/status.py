@@ -180,34 +180,14 @@ _SERVE_METRIC_FIELDS = (
      "counter",
      "first tokens picked by a program after the last prefill chunk and "
      "left on the device, read when the row's first window is harvested "
-     "(the rest, on a server that speculates or checkpoints, were read "
-     "back with the work lock held)"),
+     "(the rest, on a server that checkpoints, were read back with the "
+     "work lock held)"),
     ("expert_reads_total", "serve_expert_reads_total", "counter",
      "(layer, held expert, step) expert matrices the decode windows "
      "read: the ones a live row picked where a window's program walks "
      "them, every held one where it does not (a [model] layer_pattern)"),
-    ("spec_passes", "serve_spec_passes_total", "counter",
-     "speculative verify passes run (paged backend, "
-     "serving_speculative > 0)"),
-    ("spec_emitted_per_pass", "serve_spec_emitted_per_pass", "gauge",
-     "mean greedy tokens emitted per verify pass — the realized "
-     "speculative acceleration (paged backend)"),
-    # Device-resident spec windows (SERVING.md rung 20): W draft+
-    # verify passes per dispatch, so the host RTT amortizes over up to
-    # W*(1+K) tokens instead of taxing every pass.
-    ("spec_window", "serve_spec_window", "gauge",
-     "speculative passes batched per device dispatch (paged backend, "
-     "serving_spec_window > 0; absent = windows off)"),
-    ("spec_windows_total", "serve_spec_windows_total", "counter",
-     "device-resident speculative windows harvested (paged backend, "
-     "serving_spec_window)"),
-    # Device-resident endgame (SERVING.md rung 23): whether mixed
-    # greedy+sampled batches stay on the windowed spec path, and how
-    # many finishes the device-side stop detection completed.
-    ("spec_window_sampled", "serve_spec_window_sampled", "gauge",
-     "1 if sampled co-tenants ride the windowed spec path on device "
-     "(serving_spec_sampled_window; 0 = mixed batches fall back to "
-     "the legacy per-pass program)"),
+    # Device-resident endgame (SERVING.md rung 23): how many finishes
+    # the device-side stop detection completed.
     ("stop_finishes_total", "serve_stop_finishes_total", "counter",
      "requests finished by per-row stop-token detection inside the "
      "device scan (paged backend; stop_token set on the request)"),
@@ -422,10 +402,6 @@ _SERVE_HISTOGRAM_FIELDS = (
     ("window_inflight_depth", "serve_window_inflight_depth",
      "pipeline depth observed at each window dispatch (0 = boundary "
      "dispatch, 1 = overlapped dispatch)"),
-    ("spec_window_emitted_tokens", "serve_spec_window_emitted_tokens",
-     "tokens a request realized from one device-resident speculative "
-     "window (serving_spec_window; low buckets mean drafts are not "
-     "landing and the window is mostly frozen passes)"),
     ("sched_queue_wait_ms_interactive",
      "serve_sched_queue_wait_ms_interactive",
      "admission queue wait in ms for interactive-class requests "
@@ -551,22 +527,6 @@ def render_metrics(snapshot: dict) -> str:
         lines.append(f"# HELP {name} {help_text}")
         lines.append(f"# TYPE {name} {mtype}")
         lines.append(f"{name} {value}")
-    # Labelled counter (the one non-scalar serving metric): spec-window
-    # fallbacks by cause. "sampled" must pin to 0 in mixed steady state
-    # once serving_spec_sampled_window is on — that is rung 23's
-    # acceptance gate, so the cause label is load-bearing, not garnish.
-    fallbacks = serving.get("spec_window_fallbacks")
-    if isinstance(fallbacks, dict) and fallbacks:
-        name = "kvedge_serve_spec_window_fallbacks_total"
-        lines.append(
-            f"# HELP {name} decode rounds that fell off the windowed "
-            "spec path, by cause (sampled = mixed batch with "
-            "serving_spec_sampled_window off; spec_off = speculation "
-            "disabled mid-flight)")
-        lines.append(f"# TYPE {name} counter")
-        for cause in sorted(fallbacks):
-            lines.append(
-                f'{name}{{cause="{cause}"}} {fallbacks[cause]}')
     # Why the decode pipeline fell back to a boundary instead of
     # queueing the next window behind the running one.
     collapses = serving.get("pipeline_collapses")
@@ -575,7 +535,7 @@ def render_metrics(snapshot: dict) -> str:
         lines.append(
             f"# HELP {name} overlapped decode pipelines that collapsed "
             "to a boundary, by cause (an admission is none: newcomer "
-            "counts servers that speculate or checkpoint only)")
+            "counts servers that checkpoint only)")
         lines.append(f"# TYPE {name} counter")
         for cause in sorted(collapses):
             lines.append(

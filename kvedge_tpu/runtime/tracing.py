@@ -79,9 +79,9 @@ prefill chunk on the caller's thread (:data:`ADMIT_PHASES`):
 chunk dispatched and whatever it blocks on; ``prefill_chunk_ms``);
 once per request ``admit/first_pick`` (asking for the lock again to
 the first token picked from the prefill's logits: dispatched as a
-program and left on the device, or, on a server that speculates or
-checkpoints, read back, the caller waiting, lock held, for its chunks
-and for the window the device was given before them).
+program and left on the device, or, on a server that checkpoints,
+read back, the caller waiting, lock held, for its chunks and for the
+window the device was given before them).
 
 The work lock's ledger (ISSUE 38). The server's one lock is a
 :class:`TimedLock`: it stamps every acquire and release and adds the

@@ -956,16 +956,6 @@ def _run_multihost_serve(cfg: RuntimeConfig, base, tcfg, mesh):
     ), serve_fn
 
 
-def _spec_draft_len(cfg) -> int:
-    """The draft length ``serving_speculative`` resolves to BEFORE the
-    boot probe: "auto" sizes pools for draft 4 (the probe may still
-    turn speculation off at boot — sizing for it keeps the pool
-    derivation independent of the probe's outcome)."""
-    if cfg.serving_speculative == "auto":
-        return 4
-    return cfg.serving_speculative
-
-
 def _serving_page_bytes(cfg, tcfg) -> int:
     """HBM bytes ONE pool page costs: K and V slabs across every layer
     (``[n_layers, page_size, kv_heads * d_head]`` each), plus the two
@@ -990,9 +980,7 @@ def _serving_pool_dims(cfg, tcfg) -> tuple[int, int, int, int]:
     pool — ONE derivation for the single-host server and the slice
     cache (the two must never size differently). ``serving_pages = 0``
     auto-sizes so every slot can hold a worst-case request — admission
-    then only ever waits on slots, never on pages. Speculative mode
-    widens both by the draft slack (a verify pass writes K positions
-    past a GREEDY request's budget even when nothing accepts).
+    then only ever waits on slots, never on pages.
 
     ``serving_hbm_budget_mb`` sizes the pool from a BYTE budget instead
     (mutually exclusive with ``serving_pages`` — config validation
@@ -1002,7 +990,7 @@ def _serving_pool_dims(cfg, tcfg) -> tuple[int, int, int, int]:
     not an error — but a budget too small for even ONE worst-case
     request can never admit anything and fails loudly here."""
     slots, page_size = cfg.serving_slots, cfg.serving_page_size
-    mpps = -(-(tcfg.max_seq + _spec_draft_len(cfg)) // page_size)
+    mpps = -(-tcfg.max_seq // page_size)
     if cfg.serving_hbm_budget_mb:
         pages = (cfg.serving_hbm_budget_mb * 2**20
                  ) // _serving_page_bytes(cfg, tcfg)
@@ -1010,8 +998,8 @@ def _serving_pool_dims(cfg, tcfg) -> tuple[int, int, int, int]:
             raise MeshConfigError(
                 f"serving_hbm_budget_mb = {cfg.serving_hbm_budget_mb} "
                 f"buys {pages} pages, but one worst-case request needs "
-                f"{mpps} (max_seq {tcfg.max_seq} + draft slack at page "
-                f"size {page_size}); raise the budget or shrink max_seq"
+                f"{mpps} (max_seq {tcfg.max_seq} at page size "
+                f"{page_size}); raise the budget or shrink max_seq"
             )
     else:
         pages = cfg.serving_pages or slots * mpps
@@ -1238,9 +1226,7 @@ def _parse_generate_request(doc: dict, tcfg, *, max_rows: int,
         if paged:
             raise ValueError(
                 "per-request 'speculative' runs on the contiguous "
-                "backend; the paged backend speculates server-wide "
-                "via [payload] serving_speculative (the batch-level "
-                "schedule is a server policy, not a request knob)"
+                "backend; the paged backend does not speculate"
             )
         if len(tokens) != 1:
             raise ValueError(
@@ -1465,7 +1451,6 @@ def _build_serve(cfg, base, tcfg, params, restored_step, *, cache=None,
             # the cache's pages can never drift apart; an injected
             # cache carries its own pool from the SAME derivation.
             slots, pages, page_size, _ = _serving_pool_dims(cfg, tcfg)
-            spec_draft = _spec_draft_len(cfg)
             # SLO engine ([payload] serving_slo*, SERVING.md rung 25):
             # objectives travel as one frozen value object; None keeps
             # the engine (and its boundary feed) out of the process.
@@ -1486,16 +1471,6 @@ def _build_serve(cfg, base, tcfg, params, restored_step, *, cache=None,
                 prefill_chunk=cfg.serving_prefill_chunk,
                 prefix_cache=cfg.serving_prefix_cache,
                 prefix_host_mb=cfg.serving_prefix_host_mb,
-                speculative=spec_draft,
-                # Device-resident spec windows (SERVING.md rung 20):
-                # only meaningful when spec_draft resolved > 0 — the
-                # server validates the pairing, and _spec_draft_len
-                # already pins "auto" before construction, so a zero
-                # draft with a nonzero window is a config error here,
-                # not a silent fallback.
-                spec_window=(cfg.serving_spec_window
-                             if spec_draft > 0 else 0),
-                spec_sampled_window=cfg.serving_spec_sampled_window,
                 # "auto" hands window choice to the online controller
                 # (SERVING.md rung 26) inside the min/max bounds; a
                 # static int keeps the operator's cap.
@@ -1591,33 +1566,6 @@ def _build_serve(cfg, base, tcfg, params, restored_step, *, cache=None,
                             pass
 
                 paged_server.on_degraded = _record_failure
-            # Spec-mode economics probe (VERDICT r4 #7): measure this
-            # session's verify-pass and window costs before traffic;
-            # "auto" falls back to windowed decode when windows
-            # dominate speculation's BEST case, an explicit K keeps
-            # the choice but warns loudly. Single-host only — the
-            # probe's device ops would broadcast into the slice
-            # op-stream before followers expect traffic shapes.
-            if spec_draft > 0 and cache is None:
-                decision = paged_server.resolve_speculation(
-                    auto=cfg.serving_speculative == "auto"
-                )
-                print(f"[kvedge-serve] speculative mode: "
-                      f"{decision['mode']} (best-case "
-                      f"{decision['spec_best_tokens_per_sec']}/s vs "
-                      f"windowed {decision['windowed_tokens_per_sec']}"
-                      f"/s per slot)", flush=True)
-            elif (spec_draft > 0 and cache is not None
-                    and cfg.serving_speculative == "auto"):
-                # "auto" promises measured economics; unmeasured
-                # speculation over a long host round trip is the
-                # regression the mode exists to prevent. Explicit K still runs
-                # speculation on a slice.
-                decision = paged_server.disable_speculation(
-                    "auto unmeasured on a slice"
-                )
-                print(f"[kvedge-serve] speculative mode: "
-                      f"{decision['mode']}", flush=True)
             # Prefix persistence (single-host only: the slice cache's
             # pool is a global array the leader cannot dump alone):
             # warm prefixes from the previous pod generation re-pin at

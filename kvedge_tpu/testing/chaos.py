@@ -6,7 +6,7 @@ resume-after-revive) is accepted against: a campaign drives several
 ROUNDS of seeded traffic into one long-lived server wearing a
 :class:`~kvedge_tpu.testing.servingfaults.FaultyCache`, arms a fresh
 seeded :class:`~kvedge_tpu.testing.servingfaults.FaultPlan` each round
-(so faults land mid-window, mid-spec-harvest, mid-swap, mid-prefill —
+(so faults land mid-window, mid-harvest, mid-swap, mid-prefill —
 wherever the seam counter happens to fall), heals every poison with
 ``revive()``, and checks the GLOBAL invariants after every round:
 
@@ -101,15 +101,17 @@ class _Sub:
 def _draw_config(rng: random.Random) -> dict:
     """The campaign's server shape: checkpoints always ON (this is the
     durability soak), the rest drawn so the seeded fleet covers
-    one-step and longer windows, legacy speculation, and windowed
-    speculation."""
+    one-step and longer windows."""
+    # The two draws the retired speculation knobs took are still taken:
+    # a seed's prompts and fault plans stay what they were chosen for.
     spec = rng.choice([0, 0, 2])
-    return {
+    shape = {
         "checkpoint_every": rng.choice([1, 2]),
         "window": rng.choice([1, 2, 4]),
-        "speculative": spec,
-        "spec_window": rng.choice([0, 2]) if spec else 0,
     }
+    if spec:
+        rng.choice([0, 2])
+    return shape
 
 
 def run_chaos_campaign(params, tcfg, seed: int, *, rounds: int = 2,

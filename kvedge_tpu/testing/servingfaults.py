@@ -152,10 +152,6 @@ class FaultyCache(PagedKVCache):
         self._seam("step")
         return super()._device_step(params, tokens, active)
 
-    def _device_spec(self, params, tokens, active, spec_mask):
-        self._seam("spec")
-        return super()._device_spec(params, tokens, active, spec_mask)
-
     # Preemptive-swap seams (models/scheduler.py, SERVING.md rung 17):
     # a swap-out dies with the victim's pages still intact on device
     # (the poison path must not leak its snapshot-to-be); a swap-in
@@ -195,25 +191,6 @@ class FaultyCache(PagedKVCache):
     def harvest_window(self, handle):
         self._seam("wharvest")
         return super().harvest_window(handle)
-
-    # Windowed-spec seams (SERVING.md rung 20): like the overlapped
-    # pair, dispatch and harvest are separate failure boundaries — a
-    # spec-window dispatch can die with an earlier spec window still in
-    # flight, and a harvest can die on a healthy dispatch. The drained
-    # poison path must settle (or cleanly abandon) the worst-case
-    # _spec_unharvested reservation either way.
-    def _device_spec_window(self, params, tokens, n_passes: int,
-                            k_len: int, active, budgets, ctx, ctx_len,
-                            sampling=None):
-        self._seam(f"specw[{n_passes}]")
-        return super()._device_spec_window(
-            params, tokens, n_passes, k_len, active, budgets, ctx,
-            ctx_len, sampling,
-        )
-
-    def _force_spec_window(self, handle):
-        self._seam("specwharvest")
-        return super()._force_spec_window(handle)
 
 
 class FaultySliceTransport:

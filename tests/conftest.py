@@ -57,3 +57,21 @@ def kvedge_init() -> pathlib.Path:
         ["make", "-C", str(_NATIVE_DIR)], check=True, capture_output=True
     )
     return _NATIVE_DIR / "build" / "kvedge-init"
+
+
+@pytest.fixture(scope="session")
+def probe_blocks() -> dict:
+    """``{"recurrent": (cfg, params), "window-block": (cfg, params)}``:
+    the probe configurations of tests/test_hybrid_block.py (pattern
+    m m a m) and tests/test_window_block.py (pattern f w w w, a window
+    of 24), for the tests that run the server's loop, locks and ledgers
+    on the blocks four of five cells serve. A patterned block takes
+    ``prefix_cache=False``, and ``decode.generate`` refuses it: its
+    reference is the same server, served another way."""
+    from kvedge_tpu.models import hybrid
+    from tests import test_hybrid_block, test_window_block
+
+    cfgs = {"recurrent": test_hybrid_block.config_of(),
+            "window-block": test_window_block.config_of()}
+    return {name: (cfg, hybrid.init_params(jax.random.PRNGKey(0), cfg))
+            for name, cfg in cfgs.items()}

@@ -1,6 +1,6 @@
-"""Online window/spec-depth controller (SERVING.md rung 26).
+"""Online window controller (SERVING.md rung 26).
 
-The controller closes the loop on the rung-16/20 throughput models:
+The controller closes the loop on the rung-16 throughput model:
 steps/s = W / max(R, W*t) saturates at the smallest power-of-two
 window whose device time covers the measured host turnaround, so the
 law is ``W* = min pow2 in [lo, hi] with W*t >= R``. These tests pin
@@ -114,17 +114,17 @@ def test_pick_window_clamps_bounds_to_pow2():
 # ---- EWMA convergence to the model optimum -------------------------------
 
 
-def _drive(ctl, rng, r_true, t_true, n, channel="decode"):
+def _drive(ctl, rng, r_true, t_true, n):
     """Feed n synthetic harvests: the controller's own current pick is
     dispatched (as the serving loop does), measurements are the true
     (R, t) split under +/-10% multiplicative noise."""
     for _ in range(n):
-        w = ctl.window(channel)
+        w = ctl.window()
         dev = w * t_true * rng.uniform(0.9, 1.1)
         host = 0.4 * r_true * rng.uniform(0.9, 1.1)
         transport = 0.6 * r_true * rng.uniform(0.9, 1.1)
         ctl.observe(rtt_ms=dev + transport, device_ms=dev,
-                    host_ms=host, window=w, channel=channel)
+                    host_ms=host, window=w)
 
 
 def test_controller_converges_to_model_optimum():
@@ -152,16 +152,6 @@ def test_controller_first_observation_seeds_directly():
     assert snap["r_ms"] == pytest.approx(8.0)   # (12-8) + 4
     assert snap["t_ms"] == pytest.approx(0.5)   # 8 / 16
     assert ctl.window() == 16
-
-
-def test_controller_channels_are_independent():
-    ctl = WindowController(lo=1, hi=256)
-    rng = np.random.default_rng(1)
-    _drive(ctl, rng, r_true=8.0, t_true=0.5, n=40)
-    _drive(ctl, rng, r_true=2.0, t_true=2.0, n=40, channel="spec")
-    assert ctl.window() == 16
-    assert ctl.window("spec") == 1  # 1*2.0 >= 2.0 already saturates
-    assert ctl.snapshot("spec")["updates"] == 40
 
 
 def test_controller_default_before_first_observation():

@@ -233,13 +233,21 @@ def test_bucket_steps_down_when_load_drains(params):
 # ---- bit-identity across bucket transitions ------------------------------
 
 
+@pytest.mark.parametrize("block", ["plain", "recurrent", "window-block"])
 @pytest.mark.parametrize("window", [1, 64], ids=["w1", "w64"])
-def test_bucketed_tokens_match_pinned_path(params, window):
+def test_bucketed_tokens_match_pinned_path(params, probe_blocks, window,
+                                           block):
     """The same request set through a bucketed server (stepping 1->2->4
-    under load) and a slots-pinned server produces IDENTICAL tokens —
-    and both match contiguous generate. Carries migrate or drop at
+    under load, and down again under the solo request that follows) and
+    a slots-pinned server produces IDENTICAL tokens — and, on the plain
+    block, both match contiguous generate. Carries migrate or drop at
     bucket steps without moving a single token, at one-step windows
-    (a trip a token) and at the default."""
+    (a trip a token) and at the default. On a patterned block
+    (``probe_blocks``; the pinned path is the reference there) a step
+    carries each live row's recurrent state, or both pools' tables."""
+    cfg = CFG
+    if block != "plain":
+        cfg, params = probe_blocks[block]
     requests = [
         ([5, 9, 2], 8),
         ([1, 1, 4, 3, 7, 7], 4),
@@ -249,73 +257,19 @@ def test_bucketed_tokens_match_pinned_path(params, window):
     outs = []
     for min_bucket in (0, 1):
         server = PagedGenerationServer(
-            params, CFG, slots=4, pages=32, page_size=4,
+            params, cfg, slots=4, pages=32, page_size=4,
             min_bucket=min_bucket, window=window, prefix_cache=False,
         )
         try:
-            outs.append(run_concurrent(server, requests))
+            outs.append((run_concurrent(server, requests),
+                         server.submit([3, 1, 4], 8)))
         finally:
             server.close()
     pinned, bucketed = outs
     assert pinned == bucketed
-    for i, (prompt, n_new) in enumerate(requests):
-        assert bucketed[i] == reference(params, prompt, n_new)
-
-
-def test_bucketed_spec_window_overlap_bit_identical(params):
-    """The hardest composition: device-resident speculative windows +
-    the overlap pipeline + bucket steps. Spec reservations BLOCK a
-    resize until harvested (device lengths are data-dependent while a
-    window is unharvested), so steps land only at quiescent boundaries
-    — and the tokens still match plain greedy exactly."""
-    requests = [
-        ([5, 9, 2], 10),
-        ([1, 1, 4, 3], 8),
-        ([100, 50], 12),
-    ]
-    server = PagedGenerationServer(
-        params, CFG, slots=4, pages=32, page_size=4, min_bucket=1,
-        speculative=2, spec_window=2, prefix_cache=False,
-    )
-    try:
-        first = server.submit(requests[0][0], requests[0][1])
-        assert first == reference(params, *requests[0])
-        got = run_concurrent(server, requests)
+    if block == "plain":
         for i, (prompt, n_new) in enumerate(requests):
-            assert got[i] == reference(params, prompt, n_new)
-    finally:
-        server.close()
-
-
-def test_spec_pending_blocks_resize(params):
-    """An unharvested spec window pins the bucket (the ONE hard
-    blocker): set_bucket refuses until the harvest settles the
-    data-dependent device lengths."""
-    cache = PagedKVCache(CFG, slots=4, pages=24, page_size=4,
-                         min_bucket=2)
-    assert cache.bucket == 2
-    prompt = [5, 9, 2]
-    cache.admit(0, len(prompt))
-    logits = cache.prefill(params, 0, jnp.asarray(prompt, jnp.int32))
-    pend = np.zeros((2,), np.int32)
-    pend[0] = int(jnp.argmax(logits))
-    s_ctx = CFG.max_seq + 8
-    ctx = np.zeros((2, s_ctx), np.int32)
-    seq = prompt + [int(pend[0])]
-    ctx[0, :len(seq)] = seq
-    ctx_len = np.zeros((2,), np.int32)
-    ctx_len[0] = len(seq)
-    handle = cache.dispatch_spec_window(
-        params, pend, 2, 3, np.array([10, 0], np.int32),
-        ctx=ctx, ctx_len=ctx_len,
-    )
-    assert cache.spec_pending()
-    with pytest.raises(PagedCacheError, match="spec"):
-        cache.set_bucket(4)
-    cache.harvest_spec_window(handle)
-    assert not cache.spec_pending()
-    cache.set_bucket(4)
-    assert cache.bucket == 4
+            assert bucketed[0][i] == reference(params, prompt, n_new)
 
 
 # ---- preempt/resume and poison/revive at a bucket boundary ---------------
@@ -471,21 +425,20 @@ def _payload_cfg(**payload):
 
 
 def test_max_rows_matches_legacy_for_auto_pools():
-    cfg = _payload_cfg(serving_slots=4, serving_page_size=4,
-                       serving_speculative=0)
+    cfg = _payload_cfg(serving_slots=4, serving_page_size=4)
     assert _serve_max_rows(cfg, CFG) == 4 * 4  # pages//mpps == slots
 
 
 def test_max_rows_follows_page_budget():
     # serving_pages holds 2 worst-case requests on 4 slots: the ceiling
     # tracks the POOL (4 x 2), not the slot count (4 x 4).
-    mpps = -(-CFG.max_seq // 4)  # speculative off
+    mpps = -(-CFG.max_seq // 4)
     cfg = _payload_cfg(serving_slots=4, serving_page_size=4,
-                       serving_speculative=0, serving_pages=2 * mpps)
+                       serving_pages=2 * mpps)
     assert _serve_max_rows(cfg, CFG) == 4 * 2
     # ...and never collapses to zero for a one-request pool.
     cfg = _payload_cfg(serving_slots=4, serving_page_size=4,
-                       serving_speculative=0, serving_pages=mpps)
+                       serving_pages=mpps)
     assert _serve_max_rows(cfg, CFG) == 4
 
 
@@ -497,7 +450,6 @@ def test_hbm_budget_sizes_pool():
     mpps = -(-CFG.max_seq // 4)
     budget_mb = -(-3 * mpps * page_bytes // 2**20)  # >= 3 requests
     cfg = _payload_cfg(serving_slots=8, serving_page_size=4,
-                       serving_speculative=0,
                        serving_hbm_budget_mb=int(budget_mb))
     slots, pages, page_size, got_mpps = _serving_pool_dims(cfg, CFG)
     assert (slots, page_size, got_mpps) == (8, 4, mpps)
@@ -512,7 +464,7 @@ def test_hbm_budget_sizes_pool():
 
 def test_hbm_budget_too_small_fails_loudly():
     cfg = _payload_cfg(serving_slots=4, serving_page_size=4,
-                       serving_speculative=0, serving_hbm_budget_mb=1)
+                       serving_hbm_budget_mb=1)
     if _serving_page_bytes(cfg, CFG) * (-(-CFG.max_seq // 4)) <= 2**20:
         pytest.skip("tiny model: 1 MiB already fits a request")
     with pytest.raises(MeshConfigError, match="worst-case request"):
@@ -670,50 +622,6 @@ def test_poison_with_swapped_victim_revives_all(params):
         assert stats["journal_restores_total"] == 3
         assert stats["journal_entries"] == 0
         assert stats["sched_swap_bytes_host"] == 0
-    finally:
-        server.close()
-
-
-def test_checkpointed_spec_overlap_revive_bit_identical(params):
-    """Rung 22 x rungs 16/20/21: boundary checkpoints compose with the
-    overlapped pipeline, device-resident spec windows, and bucketing.
-    The fault lands INSIDE the second checkpoint's swapout — the first
-    checkpoint is already durable, so revive resumes from it and the
-    stream completes bit-identical with no replayed token."""
-    server = PagedGenerationServer(
-        params, CFG, slots=4, pages=32, page_size=4, min_bucket=1,
-        speculative=2, spec_window=2, checkpoint_every=1,
-        prefix_cache=False,
-    )
-    # Long enough that the second checkpoint is reached whatever the
-    # drafts accept: two spec windows of two passes emit at most
-    # 2 * 2 * (2 + 1) = 12 tokens, and the boundary after them
-    # checkpoints a request that is still running.
-    prompt, n_new = [5, 9, 2], 30
-    want = reference(params, prompt, n_new)
-    cache = server._cache
-    real = cache.swapout_pages
-    calls = [0]
-
-    def dying(ids):
-        calls[0] += 1
-        if calls[0] == 2:
-            raise RuntimeError("injected: swapout died mid-checkpoint")
-        return real(ids)
-
-    cache.swapout_pages = dying
-    dying_thread = server._thread
-    try:
-        got, done, errs = _stream_in_background(server, prompt, n_new)
-        _wait_degraded(server)
-        cache.swapout_pages = real
-        dying_thread.join(timeout=30)
-        assert not dying_thread.is_alive()
-        assert server.revive() == 1
-        assert done.wait(timeout=60)
-        assert not errs, errs
-        assert prompt + got == want
-        assert server.stats()["journal_restores_total"] == 1
     finally:
         server.close()
 

@@ -1,7 +1,7 @@
 """Crash-surviving in-flight requests: the chaos soak (SERVING.md rung 22).
 
 The durability contract under test: with boundary checkpoints on, a
-pool that poisons mid-decode — mid-window, mid-spec-harvest, mid-swap,
+pool that poisons mid-decode — mid-window, mid-harvest, mid-swap,
 mid-pipeline-harvest — revives with every journaled in-flight request
 restored into a fresh slot and completes it BIT-IDENTICAL to an
 uninterrupted run, while the global invariants hold at every settle
@@ -12,8 +12,7 @@ Two legs share one harness (``testing/chaos.py``):
 
 * a short deterministic subset — pinned server shapes, seeds chosen to
   exercise revive-with-restore at one-step windows, at longer windows,
-  under legacy speculative passes and under windowed speculation —
-  fast enough for tier-1;
+  on a recurrent block and on a window block — fast enough for tier-1;
 * the seeded soak — ``@slow``, 24 campaigns whose whole decision
   stream (server shape, prompts, consumer mix, fault plans) derives
   from the campaign seed.
@@ -53,19 +52,10 @@ CFG = TransformerConfig(
 # raise is SURE to poison with a journaled request still in flight,
 # whatever the interleaving: the first boundary checkpoints
 # (checkpoint_every=1) and a request of n_new=6 outlives the seams up
-# to 3; under SPEC (checkpoint_every=2, up to three tokens a pass)
-# only seam 2 comes after the first checkpoint and before a request
-# can finish.
-ONESTEP = dict(checkpoint_every=1, window=1,
-               speculative=0, spec_window=0)
-OVERLAP = dict(checkpoint_every=1, window=2,
-               speculative=0, spec_window=0)
-SPEC = dict(checkpoint_every=2, window=2,
-            speculative=2, spec_window=0)
-SPECW = dict(checkpoint_every=1, window=2,
-             speculative=2, spec_window=2)
+# to 3.
+ONESTEP = dict(checkpoint_every=1, window=1)
+OVERLAP = dict(checkpoint_every=1, window=2)
 SURE = (1, 3)
-SURE_SPEC = (2, 2)
 
 ROUNDS = 2
 PER_ROUND = 3
@@ -121,18 +111,46 @@ def _assert_armed(res, sure):
         f"re-pin it ({[m[0] for m in plans]})")
 
 
+def _block_oracle(cfg, params):
+    """A patterned block's fault-free reference (``decode.generate``
+    refuses a pattern): a server of the same block that nothing
+    wounds, memoized like the plain one."""
+    memo: dict = {}
+
+    def fn(prompt, n_new):
+        key = (tuple(prompt), n_new)
+        if key not in memo:
+            server = PagedGenerationServer(params, cfg, slots=1, pages=24,
+                                           page_size=4, prefix_cache=False)
+            try:
+                memo[key] = server.submit(list(prompt), n_new)
+            finally:
+                server.close()
+        return memo[key]
+
+    return fn
+
+
 @pytest.mark.parametrize(
-    "seed,config,sure",
-    [(17, ONESTEP, SURE), (19, ONESTEP, SURE), (2, OVERLAP, SURE),
-     (5, SPEC, SURE_SPEC), (9, SPECW, SURE)],
-    ids=["w1-17", "w1-19", "overlap-2", "spec-5", "specw-9"],
+    "seed,config,sure,block",
+    [(17, ONESTEP, SURE, ""), (19, ONESTEP, SURE, ""),
+     (2, OVERLAP, SURE, ""), (5, OVERLAP, SURE, "recurrent"),
+     (9, OVERLAP, SURE, "window-block")],
+    ids=["w1-17", "w1-19", "overlap-2", "recurrent-5", "window-block-9"],
 )
-def test_deterministic_campaign(params, oracle, seed, config, sure):
+def test_deterministic_campaign(params, oracle, probe_blocks, seed, config,
+                                sure, block):
     """Seeds pinned to poison at least once per campaign: the run must
     revive, restore journaled requests, and finish every survivor
-    bit-identical (the harness raises InvariantViolation otherwise)."""
+    bit-identical (the harness raises InvariantViolation otherwise).
+    On a patterned block (``probe_blocks``) what the journal brings
+    back holds a row's recurrent state, or its second pool's pages."""
+    cfg = CFG
+    if block:
+        cfg, params = probe_blocks[block]
+        oracle = _block_oracle(cfg, params)
     res = run_chaos_campaign(
-        params, CFG, seed=seed, rounds=ROUNDS,
+        params, cfg, seed=seed, rounds=ROUNDS,
         requests_per_round=PER_ROUND, n_new=6, config=config,
         oracle=oracle, wound=_loop_seams_only,
     )

@@ -1,11 +1,10 @@
 """Device-resident endgame composition tests (SERVING.md rung 23).
 
 Rung 23 moves the last per-token host costs into the dispatched scans:
-sampled rows accept/reject ON DEVICE inside spec windows (mixed
-greedy+sampled batches stay windowed), and stop-token/budget finishes
-are detected in the scan carry and harvested as packed finish rows (the
-boundary sweep does O(active-finishes) work, not O(bucket)). These
-tests pin the new machinery COMPOSED with everything beneath it:
+stop-token/budget finishes are detected in the scan carry and
+harvested as packed finish rows (the boundary sweep does
+O(active-finishes) work, not O(bucket)). These tests pin the new
+machinery COMPOSED with everything beneath it:
 
 * stop tokens — device-side detection, host-side truncation contract
   (first produced occurrence emitted last, rest of budget unused), the
@@ -15,7 +14,7 @@ tests pin the new machinery COMPOSED with everything beneath it:
   stop token, bit-identical to the never-preempted run;
 * rung 22 — poison with a journaled sampled+stop request in flight,
   revive restores it from the checkpoint and it completes exactly;
-* rung 21 — within a warm bucket, the new program shapes (sampled spec
+* rung 21 — within a warm bucket, the new program shapes (sampled
   windows, capped windows with stop rows) retrace zero times.
 
 All fixed-seed and fast: these run in the tier-1 gate under the
@@ -158,11 +157,52 @@ def test_stop_mid_pipeline_defers_without_perturbing_cotenant(params):
         server.close()
 
 
-def test_stop_composes_with_sampled_spec_windows(params):
-    """Rung 23 full house: a greedy row and a sampled co-tenant, each
-    with its own stop token, served by the windowed speculative
-    pipeline — both truncate exactly where the fault-free references
-    do, and the mixed batch never fell back to the legacy pass."""
+@pytest.mark.parametrize("block", ["recurrent", "window-block"])
+@pytest.mark.parametrize("where", ["first-token", "mid-window"])
+def test_a_stop_on_a_patterned_block_ends_its_row_alone(probe_blocks, block,
+                                                        where):
+    """A stop token that is the request's first token (no step: the
+    boundary's sweep ends it) and one mid-window, beside a co-tenant
+    that keeps decoding, on a recurrent block and on a window block.
+    The reference is the same server, each request alone and unstopped
+    (``decode.generate`` refuses a pattern). The row is then used
+    again: what the next request is served there is what it is served
+    alone (a recurrent state zeroed before reuse, a window table
+    started anew), and the co-tenant's stream is untouched."""
+    cfg, params = probe_blocks[block]
+    p_stop, p_go, p_next = [5, 9, 2], [7, 7, 7, 7, 7, 1, 4], [3, 1, 4, 1]
+    server = PagedGenerationServer(params, cfg, slots=2, pages=64,
+                                   page_size=4, window=4,
+                                   prefix_cache=False)
+    try:
+        full = server.submit(p_stop, 20)
+        want_go = server.submit(p_go, 60)
+        want_next = server.submit(p_next, 12)
+        gen = full[len(p_stop):]
+        stop = gen[0] if where == "first-token" else pick_stop(
+            full, len(p_stop))
+        want_stop = truncate_at(full, len(p_stop), stop)
+        assert len(want_stop) < len(full)  # the stop really fires
+        if where == "first-token":
+            assert len(want_stop) == len(p_stop) + 1
+
+        cotenant = server.submit_stream(p_go, 60)
+        first = next(cotenant)
+        assert server.submit(p_stop, 20, stop_token=stop) == want_stop
+        assert server.submit(p_next, 12) == want_next
+        assert p_go + [first] + list(cotenant) == want_go
+        stats = server.stats()
+        assert stats["stop_finishes_total"] == 1
+        assert server._stops_pending == 0
+        assert stats["free_pages"] == stats["pages_total"]
+    finally:
+        server.close()
+
+
+def test_stop_composes_with_a_sampled_cotenant(params):
+    """A greedy row and a sampled co-tenant, each with its own stop
+    token, served by the sampled window — both truncate exactly where
+    the fault-free references do."""
     p_g, p_s = [5, 9, 2, 7], [1, 2, 3, 4]
     full_g = reference(params, p_g, 14)
     full_s = sampled_reference(params, p_s, 14)
@@ -172,8 +212,7 @@ def test_stop_composes_with_sampled_spec_windows(params):
     want_s = truncate_at(full_s, len(p_s), stop_s)
 
     server = PagedGenerationServer(params, CFG, slots=2, pages=32,
-                                   page_size=4, speculative=3,
-                                   spec_window=4)
+                                   page_size=4, window=4)
     try:
         stream = server.submit_stream(p_s, n_new=14, sampling=SAMPLING,
                                       stop_token=stop_s)
@@ -184,7 +223,6 @@ def test_stop_composes_with_sampled_spec_windows(params):
         assert got_g == want_g
         assert got_s == want_s
         assert stats["stop_finishes_total"] == 2
-        assert stats["spec_window_fallbacks"]["sampled"] == 0
     finally:
         server.close()
 
@@ -204,8 +242,7 @@ def test_preempt_resume_sampled_stream_with_stop(params):
 
     server = PagedGenerationServer(
         params, CFG, slots=1, pages=16, page_size=4, window=4,
-        speculative=3, spec_window=2, sched_policy="strict",
-        sched_swap_budget_mb=64,
+        sched_policy="strict", sched_swap_budget_mb=64,
     )
     try:
         victim = server.submit_stream(victim_prompt, n_new=40,
@@ -298,14 +335,13 @@ def test_poison_revive_restores_sampled_stop_request(params):
 
 
 def test_endgame_shapes_zero_retraces_within_bucket(params):
-    """The rung-23 programs (sampled spec windows, capped windows with
+    """The rung-23 programs (sampled windows, capped windows with
     stop rows) key on the same bucketed shapes as everything else:
     after one warm pass per request shape, repeating the identical
     requests — sampled, stopped, and mixed — triggers zero new
     traces."""
     server = PagedGenerationServer(params, CFG, slots=2, pages=32,
                                    page_size=4, min_bucket=1,
-                                   speculative=3, spec_window=4,
                                    prefix_cache=False)
     p_g, p_s = [5, 9, 2, 7], [1, 2, 3, 4]
     full_g = reference(params, p_g, 8)

@@ -439,18 +439,23 @@ def test_prefill_in_chunks_equals_prefill_in_one_piece(block, cfg, params,
 # ---- (f) what refuses to start, each by name ----------------------------
 
 
-@pytest.mark.parametrize("change, named", [
-    ({"payload": {"serving_prefix_cache": True}}, "serving_prefix_cache"),
-    ({"payload": {"serving_speculative": 2}}, "serving_speculative"),
-    ({"payload": {"kind": "train", "corpus": "/tmp/x"}}, "kind = 'train'"),
-    ({"payload": {"serving": "contiguous"}}, "serving = \"paged\""),
+@pytest.mark.parametrize("change, named, why", [
+    ({"payload": {"serving_prefix_cache": True}}, "serving_prefix_cache",
+     "layer_pattern"),
+    # refused for every block since PR 48, this one included
+    ({"payload": {"serving_speculative": 2}}, "serving_speculative",
+     "does not speculate"),
+    ({"payload": {"kind": "train", "corpus": "/tmp/x"}}, "kind = 'train'",
+     "layer_pattern"),
+    ({"payload": {"serving": "contiguous"}}, "serving = \"paged\"",
+     "layer_pattern"),
 ])
 def test_the_runtime_config_refuses_what_cannot_run_the_block(block, change,
-                                                              named):
+                                                              named, why):
     with pytest.raises(RuntimeConfigError) as refused:
         RuntimeConfig.from_mapping(document(block=block, **change))
     assert named in str(refused.value)
-    assert "layer_pattern" in str(refused.value)
+    assert why in str(refused.value)
 
 
 def test_a_mesh_of_several_devices_refuses_the_block(block):
@@ -465,15 +470,10 @@ def test_a_mesh_of_several_devices_refuses_the_block(block):
         derive_model_config(cfg, seq=SEQ)
 
 
-@pytest.mark.parametrize("kw, named", [
-    ({"prefix_cache": True}, "serving_prefix_cache"),
-    ({"prefix_cache": False, "speculative": 2}, "serving_speculative"),
-])
-def test_the_server_refuses_prefix_cache_and_speculation(cfg, params, kw,
-                                                         named):
-    with pytest.raises(ValueError, match=named):
+def test_the_server_refuses_the_prefix_cache(cfg, params):
+    with pytest.raises(ValueError, match="serving_prefix_cache"):
         PagedGenerationServer(params, cfg, slots=2, pages=16, page_size=16,
-                              **kw)
+                              prefix_cache=True)
 
 
 def test_the_other_paths_refuse_the_block_by_the_key_s_name(cfg, params):

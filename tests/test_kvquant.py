@@ -4,7 +4,7 @@ The contract: per-token-row symmetric quantization (one fp32 scale per
 row per kv head, values round(x/scale) int8) halves the cached-token
 HBM bill; decode through the quantized pool is NEAR the bf16 pool —
 bounded per-row error, high token agreement on the test model — and
-every serving mechanism (windows, spec passes, prefix sharing,
+every serving mechanism (windows, prefix sharing,
 persistence, the slice protocol) composes with it unchanged.
 """
 
@@ -88,7 +88,7 @@ def test_int8_cache_decode_near_bf16():
 
 def test_int8_serving_end_to_end(params):
     """The full server over an int8 pool: concurrent greedy requests,
-    a sampled request, spec mode off/on — everything serves, and
+    a sampled request — everything serves, and
     greedy output stays near the exact contiguous decode."""
     import threading
 
@@ -113,21 +113,6 @@ def test_int8_serving_end_to_end(params):
         assert len(results["s"]) == 9
     finally:
         server.close()
-
-    # Spec mode over int8: drafts verify against the quantized pool's
-    # own argmax, so emission is self-consistent (greedy == the int8
-    # server's own non-spec output).
-    plain = PagedGenerationServer(params, CFG, slots=2, pages=24,
-                                  page_size=4, kv_dtype="int8")
-    spec = PagedGenerationServer(params, CFG, slots=2, pages=40,
-                                 page_size=4, kv_dtype="int8",
-                                 speculative=4)
-    try:
-        p = [6, 6, 6, 6]
-        assert spec.submit(p, 8) == plain.submit(p, 8)
-    finally:
-        plain.close()
-        spec.close()
 
 
 def test_int8_prefix_persistence_round_trip(params, tmp_path):

@@ -50,10 +50,24 @@ def params():
     return init_params(jax.random.PRNGKey(0), CFG)
 
 
-def _server(params, **kw):
+def _server(params, block=None, **kw):
+    """``block``: a ``(cfg, params)`` of ``probe_blocks`` in place of
+    the plain block's."""
     kw.setdefault("window", 4)
-    return PagedGenerationServer(params, CFG, slots=4, pages=48,
+    cfg = CFG
+    if block is not None:
+        cfg, params = block
+        kw["prefix_cache"] = False
+    return PagedGenerationServer(params, cfg, slots=4, pages=48,
                                  page_size=PAGE, prefill_chunk=CHUNK, **kw)
+
+
+# The plain block, and the two ledgers over a patterned one: a recurrent
+# block (no pages for the state) and a window block (two pools, pages
+# given back mid-request: a request of 48 positions passes the window
+# of 24).
+BLOCKS = pytest.mark.parametrize(
+    "block", ["plain", "recurrent", "window-block"])
 
 
 def _unnamed_pct(a: dict, b: dict) -> float:
@@ -190,9 +204,10 @@ def test_every_lock_site_of_the_server_says_who_it_is():
     assert {arg.value for arg in named} == set(LOCK_HOLDERS)
 
 
+@BLOCKS
 def test_the_lock_ledger_closes_over_admissions_cancels_stats_and_a_close(
-        params):
-    server = _server(params)
+        params, probe_blocks, block):
+    server = _server(params, probe_blocks.get(block))
     stop = threading.Event()
     seen = []
 
@@ -222,6 +237,8 @@ def test_the_lock_ledger_closes_over_admissions_cancels_stats_and_a_close(
     last = server.stats()
     assert len(seen) > 10 and seen == sorted(seen)
     assert _unnamed_pct(first, last) < 1.0
+    if block == "window-block":
+        assert last["window_pages_released_total"] > 0
     held, waits = last["lock_held_ms"], last["lock_wait_ms"]
     for name in LOCK_HOLDERS:
         assert held[name][0] >= 1 and held[name][1] > 0.0, name
@@ -396,10 +413,13 @@ RUNS = {"streamed": _run_streamed, "buffered": _run_buffered,
         "prefix-hit": _run_prefix_hit}
 
 
-@pytest.mark.parametrize("kind", sorted(RUNS))
-def test_a_requests_states_add_up_to_its_life(params, kind):
+@pytest.mark.parametrize("kind, block", [
+    *((kind, "plain") for kind in sorted(RUNS)),
+    ("streamed", "recurrent"), ("streamed", "window-block")])
+def test_a_requests_states_add_up_to_its_life(params, probe_blocks, kind,
+                                              block):
     tr = Tracer(sample=1.0)
-    server = _server(params, tracer=tr)
+    server = _server(params, probe_blocks.get(block), tracer=tr)
     seen = _spy_on_requests(server)
     try:
         req = RUNS[kind](server, seen)

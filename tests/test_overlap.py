@@ -16,6 +16,7 @@ the tier-1 gate.
 """
 
 import dataclasses
+import functools
 import threading
 import time
 
@@ -547,27 +548,20 @@ def test_a_bucket_step_still_collapses_the_pipeline(params):
     assert stats["pipeline_collapses"]["newcomer"] == 0
 
 
-@pytest.mark.parametrize("server_kw", [
-    {"speculative": 3, "spec_window": 4}, {"checkpoint_every": 1},
-], ids=["speculating", "checkpointing"])
+@pytest.mark.parametrize("server_kw", [{"checkpoint_every": 1}],
+                         ids=["checkpointing"])
 def test_a_newcomer_still_collapses_such_a_pipeline(params, server_kw):
-    """A spec window's carry holds every row's drafting context, which
-    a newcomer does not have on the device; a server that checkpoints
-    journals a newcomer at the boundary it joins at (rung 22). Either
-    way it joins at a boundary, as before."""
+    """A server that checkpoints journals a newcomer at the boundary it
+    joins at (rung 22): it joins at a boundary, as before."""
     got, stats = _join_run(params, NEWCOMERS[:1], slow_s=0.02, slots=2,
                            pages=80, window=4, **server_kw)
     prompt, n_new, _ = NEWCOMERS[0]
     assert got["long"] == long_reference(params, *LONG)
     assert got[0] == long_reference(params, prompt, n_new)
-    if "spec_window" in server_kw:
-        assert stats["spec_windows_total"] >= 1
-        assert stats["pipeline_collapses"]["newcomer"] >= 1
-    else:
-        # at a cadence of 1 every other iteration is a boundary anyway:
-        # the newcomer may find one open and collapse nothing itself
-        assert stats["checkpoints_total"] >= 2
-        assert stats["pipeline_collapses"]["checkpoint"] >= 1
+    # at a cadence of 1 every other iteration is a boundary anyway:
+    # the newcomer may find one open and collapse nothing itself
+    assert stats["checkpoints_total"] >= 2
+    assert stats["pipeline_collapses"]["checkpoint"] >= 1
     assert stats["pipeline_joins_total"] == 0
 
 
@@ -646,7 +640,31 @@ FIRST_TOKEN_CASES = {
 }
 
 
-def _first_token_case(params, kind, on_device):
+def _first_token_server(params, cfg):
+    return PagedGenerationServer(params, cfg, slots=3, pages=80, window=4,
+                                 prefill_chunk=3, prefix_cache=False)
+
+
+def _block_reference(block):
+    """A patterned block's reference (``decode.generate`` refuses a
+    pattern): the same server on the host's pick, the request alone."""
+    cfg, params = block
+
+    @functools.cache
+    def served(prompt, n_new, sampled):
+        server = _host_picks(_first_token_server(params, cfg))
+        try:
+            return server.submit(list(prompt), n_new,
+                                 sampling=SAMPLING if sampled else None)
+        finally:
+            server.close()
+
+    return lambda _, prompt, n_new, sampling=None: served(
+        tuple(prompt), n_new, sampling is not None)
+
+
+def _first_token_case(params, kind, on_device, cfg=LONG_CFG,
+                      reference=long_reference):
     """One request, ``kind``'s, served alone (its first window is a
     boundary's) and then as a newcomer to the long request's pipeline
     (it joins an overlapped window beside a row on the carry, or,
@@ -655,11 +673,9 @@ def _first_token_case(params, kind, on_device):
     prompt, n_new, sampling = FIRST_TOKEN_CASES[kind]
     kw = {"sampling": sampling}
     if kind.startswith("stop"):
-        whole = long_reference(params, prompt, n_new)[len(prompt):]
+        whole = reference(params, prompt, n_new)[len(prompt):]
         kw["stop_token"] = whole[0 if kind == "stop-is-first" else 6]
-    server = PagedGenerationServer(params, LONG_CFG, slots=3, pages=80,
-                                   window=4, prefill_chunk=3,
-                                   prefix_cache=False)
+    server = _first_token_server(params, cfg)
     if not on_device:
         _host_picks(server)
     try:
@@ -674,20 +690,28 @@ def _first_token_case(params, kind, on_device):
         server.close()
 
 
+@pytest.mark.parametrize("block", ["plain", "recurrent", "window-block"])
 @pytest.mark.parametrize("kind", sorted(FIRST_TOKEN_CASES))
-def test_a_first_token_kept_on_the_device_is_the_hosts_pick(params, kind):
+def test_a_first_token_kept_on_the_device_is_the_hosts_pick(
+        params, probe_blocks, kind, block):
     """Token for token what the handler's own read of the pick serves
-    (the parent's path, kept where a server speculates or
-    checkpoints), alone and joining a running pipeline; every pick
-    stayed on the device and none was read with the lock in hand."""
-    want = _first_token_case(params, kind, on_device=False)
-    got = _first_token_case(params, kind, on_device=True)
+    (the parent's path, kept where a server checkpoints), alone and
+    joining a running pipeline; every pick stayed on the device and
+    none was read with the lock in hand. On a recurrent block the
+    newcomer's state, on a window block its second table, enter the
+    overlapped window beside the carry's rows the same way."""
+    cfg, reference = LONG_CFG, long_reference
+    if block != "plain":
+        cfg, params = probe_blocks[block]
+        reference = _block_reference(probe_blocks[block])
+    want = _first_token_case(params, kind, False, cfg, reference)
+    got = _first_token_case(params, kind, True, cfg, reference)
     assert got[:3] == want[:3]
     assert got[0] == got[1]
-    assert got[2] == long_reference(params, *LONG)
+    assert got[2] == reference(params, *LONG)
     prompt, n_new, sampling = FIRST_TOKEN_CASES[kind]
     if not kind.startswith("stop"):
-        assert got[0] == long_reference(params, prompt, n_new, sampling)
+        assert got[0] == reference(params, prompt, n_new, sampling)
     elif kind == "stop-is-first":
         assert len(got[0]) == len(prompt) + 1
     picks = got[3]["phase_ms"]["admit/first_pick"][0]

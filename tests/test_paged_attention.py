@@ -479,24 +479,17 @@ def test_longctx_token_agreement_at_page_boundaries():
     )
 
 
-def test_spec_and_prefill_paths_unaffected_by_kernel_flag(params):
-    """The verify pass and prefill are multi-query — they keep the
-    gather path, so spec decoding under the kernel flag still matches
-    the gather config exactly."""
-    def spec_run(cfg):
+def test_prefill_path_unaffected_by_kernel_flag(params):
+    """Prefill is multi-query — it keeps the gather path, so its
+    logits under the kernel flag are the gather config's exactly."""
+    def prefill_run(cfg):
         cache = PagedKVCache(cfg, slots=2, pages=32, page_size=4)
         cache.admit(0, 4)
-        cache.prefill(params, 0, jnp.asarray([6, 6, 6, 6], jnp.int32))
-        tokens = np.zeros((2, 5), np.int32)
-        tokens[0, 0] = 6
-        tokens[0, 1:] = 6
-        active = np.array([True, False])
-        emitted, accepted, logits0 = cache.step_spec(
-            params, tokens, active=active, spec_mask=active
-        )
-        return (np.asarray(emitted).tolist(), np.asarray(accepted).tolist())
+        logits = cache.prefill(params, 0,
+                               jnp.asarray([6, 6, 6, 6], jnp.int32))
+        return np.asarray(logits).tolist()
 
-    assert spec_run(KERNEL_CFG) == spec_run(CFG)
+    assert prefill_run(KERNEL_CFG) == prefill_run(CFG)
 
 
 def test_auto_never_picks_kernel_multiprocess(monkeypatch):
@@ -545,9 +538,9 @@ def test_pool_over_several_devices_settles_on_the_gather(params, spec):
 
 def test_vmem_refusal_spares_gather_only_traces(params, monkeypatch):
     """The trace-time VMEM refusal fires only where the kernel could
-    actually run (single-query decode). Prefill and spec-verify always
-    take the gather, so a forced-kernel int8 pool must still trace
-    them — refusing there would kill programs the pool needs."""
+    actually run (single-query decode). Prefill always takes the
+    gather, so a forced-kernel int8 pool must still trace it —
+    refusing there would kill a program the pool needs."""
     cfg = dataclasses.replace(CFG, paged_attention="kernel")
     # Distinct pool geometry: reusing another test's shapes would hit
     # the jit cache and skip the trace whose refusal is under test.
@@ -557,8 +550,6 @@ def test_vmem_refusal_spares_gather_only_traces(params, monkeypatch):
                         lambda rows, kv_heads: False)
     cache.admit(0, 3)
     cache.prefill(params, 0, jnp.asarray([5, 9, 2], jnp.int32))
-    tokens = np.zeros((2, 2), np.int32)
     active = np.array([True, False])
-    cache.step_spec(params, tokens, active=active, spec_mask=active)
     with pytest.raises(ValueError, match="VMEM budget"):
         cache.step(params, jnp.asarray([1, 0], jnp.int32), active=active)

@@ -42,15 +42,15 @@ REQUESTS = [([5, 9, 2, 7, 1, 3, 4, 6, 8], 9), ([11, 12, 13, 14, 15], 12),
             ([21, 22, 23, 24, 25, 26, 27], 1), ([31, 32, 33], 10)]
 # The shapes a trip of the one decode loop takes: greedy windows; a
 # batch with a sampled row (``dispatch_window_sampled``); one-step
-# windows (a program of its own, a harvest a token); legacy
-# speculative passes at the loop's boundaries; device-resident
-# speculative windows.
+# windows (a program of its own, a harvest a token); the same loop over
+# a recurrent block and over a window block (``probe_blocks``: state a
+# row, a second page pool given back mid-request).
 LOOPS = {
     "overlap": {},
     "sampled": {},
     "one-step": {"window": 1},
-    "spec": {"speculative": 2},
-    "spec-window": {"speculative": 2, "spec_window": 2},
+    "recurrent": {"block": "recurrent"},
+    "window-block": {"block": "window-block"},
 }
 
 
@@ -59,10 +59,21 @@ def params():
     return init_params(jax.random.PRNGKey(0), CFG)
 
 
-def _server(params, **kw):
+def _server(params, block=None, **kw):
+    """``block``: a ``(cfg, params)`` of ``probe_blocks`` in place of
+    the plain block's."""
     kw.setdefault("window", 4)
-    return PagedGenerationServer(params, CFG, slots=4, pages=48,
+    cfg = CFG
+    if block is not None:
+        cfg, params = block
+        kw["prefix_cache"] = False
+    return PagedGenerationServer(params, cfg, slots=4, pages=48,
                                  page_size=PAGE, prefill_chunk=CHUNK, **kw)
+
+
+def _loop_server(params, probe_blocks, loop):
+    kw = dict(LOOPS[loop])
+    return _server(params, probe_blocks.get(kw.pop("block", None)), **kw)
 
 
 def _serve(server, requests=REQUESTS, loop=""):
@@ -159,8 +170,9 @@ def test_between_two_snapshots_the_loops_phases_gain_the_time_between(
 
 
 @pytest.mark.parametrize("loop", sorted(LOOPS))
-def test_the_loops_phases_add_up_to_the_loop_threads_time(params, loop):
-    server = _server(params, **LOOPS[loop])
+def test_the_loops_phases_add_up_to_the_loop_threads_time(
+        params, probe_blocks, loop):
+    server = _loop_server(params, probe_blocks, loop)
     try:
         _serve(server, loop=loop)
     finally:
@@ -176,9 +188,7 @@ def test_the_loops_phases_add_up_to_the_loop_threads_time(params, loop):
     # (the lock released for it since PR 47) for a window: never more
     # than the time outside them
     busy = covered - phases["loop/lock_wait"][1] \
-        - phases["loop/wait_work"][1]
-    if loop != "spec-window":  # whose harvest keeps its hold
-        busy -= phases["loop/harvest_wait"][1]
+        - phases["loop/wait_work"][1] - phases["loop/harvest_wait"][1]
     assert 0 < stats["loop_lock_held_ms_total"] <= stats["loop_ms_total"]
     assert stats["loop_lock_held_ms_total"] >= 0.98 * busy
     # the two histograms ARE two of the phases: one record, two names
@@ -194,7 +204,7 @@ def test_the_loops_phases_add_up_to_the_loop_threads_time(params, loop):
 
 def _count_steps_at_the_cache(server):
     """Decode steps as the cache itself is asked for them, whatever the
-    server books: window lengths at dispatch, single steps, passes."""
+    server books: window lengths at dispatch, single steps."""
     cache, seen = server._cache, {"steps": 0}
 
     def wrap(name, steps_of):
@@ -208,15 +218,14 @@ def _count_steps_at_the_cache(server):
 
     wrap("dispatch_window", lambda a, kw: a[2])
     wrap("dispatch_window_sampled", lambda a, kw: a[2])
-    wrap("dispatch_spec_window", lambda a, kw: a[2])
     wrap("step", lambda a, kw: 1)
-    wrap("step_spec", lambda a, kw: 1)
     return seen
 
 
 @pytest.mark.parametrize("loop", sorted(LOOPS))
-def test_count_identities_after_a_fixed_set_of_requests(params, loop):
-    server = _server(params, **LOOPS[loop])
+def test_count_identities_after_a_fixed_set_of_requests(
+        params, probe_blocks, loop):
+    server = _loop_server(params, probe_blocks, loop)
     seen = _count_steps_at_the_cache(server)
     clocks = [server.stats()["clock_s"]]
     try:

@@ -102,10 +102,9 @@ def _wait_stats(server, pred, timeout_s=30.0, what="condition"):
 def test_cow_divergence_bit_identical(params, sampled):
     """A probe whose prompt diverges INSIDE a cached entry's last page
     admits via cow_page and must emit exactly what a prefix_cache=off
-    server emits — with the overlapped pipeline AND device-resident
-    spec windows on, greedy and sampled (the acceptance pin)."""
-    kw = dict(slots=3, pages=48, page_size=4, window=4,
-              speculative=2, spec_window=2)
+    server emits — with the overlapped pipeline on, greedy and
+    sampled (the acceptance pin)."""
+    kw = dict(slots=3, pages=48, page_size=4, window=4)
     warm = STEM + [5, 3]
     probe = STEM + [5, 8, 9]  # shares 1 token of warm's third page
 

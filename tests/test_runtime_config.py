@@ -83,48 +83,6 @@ def test_serving_pool_knobs_round_trip_and_validate():
             RuntimeConfig.parse(f"[payload]\n{bad}\n")
 
 
-def test_serving_spec_window_round_trips_and_validates():
-    cfg = RuntimeConfig.parse(
-        "[payload]\nserving = 'paged'\nserving_speculative = 4\n"
-        "serving_spec_window = 8\n"
-    )
-    assert cfg.serving_spec_window == 8
-    assert RuntimeConfig.parse(cfg.to_toml()) == cfg
-    assert RuntimeConfig.parse("").serving_spec_window == 0  # off
-    # "auto" speculation may still carry a window (the boot probe can
-    # keep or drop speculation; the window follows it).
-    auto = RuntimeConfig.parse(
-        "[payload]\nserving_speculative = 'auto'\n"
-        "serving_spec_window = 4\n"
-    )
-    assert auto.serving_spec_window == 4
-    for bad in (
-        "serving_spec_window = -1",
-        "serving_spec_window = 65",
-        # Windows without speculation have no drafts to run.
-        "serving_spec_window = 4",
-    ):
-        with pytest.raises(RuntimeConfigError):
-            RuntimeConfig.parse(f"[payload]\n{bad}\n")
-
-
-def test_serving_spec_sampled_window_round_trips_and_validates():
-    """Rung 23 knob: default ON (mixed batches stay windowed), TOML
-    round-trip, and the boolean validation matches the other flags."""
-    cfg = RuntimeConfig.parse(
-        "[payload]\nserving = 'paged'\nserving_speculative = 4\n"
-        "serving_spec_window = 8\n"
-        "serving_spec_sampled_window = false\n"
-    )
-    assert cfg.serving_spec_sampled_window is False
-    assert RuntimeConfig.parse(cfg.to_toml()) == cfg
-    assert RuntimeConfig.parse("").serving_spec_sampled_window is True
-    with pytest.raises(RuntimeConfigError):
-        RuntimeConfig.parse(
-            "[payload]\nserving_spec_sampled_window = 'yes'\n"
-        )
-
-
 def test_model_section_parses_and_round_trips():
     cfg = RuntimeConfig.parse(
         "[model]\npreset = \"flagship\"\nn_kv_heads = 2\nexperts = 4\n"
@@ -214,24 +172,46 @@ def test_wrongly_typed_values_raise_config_error():
         RuntimeConfig.parse('[runtime]\nheartbeat_interval_s = "fast"\n')
 
 
-def test_serving_window_and_auto_speculative_round_trip():
+def test_serving_window_round_trips():
     cfg = RuntimeConfig.parse(
         "[payload]\nserving = 'paged'\nserving_window = 128\n"
-        "serving_speculative = 'auto'\n"
     )
     assert cfg.serving_window == 128
-    assert cfg.serving_speculative == "auto"
-    assert RuntimeConfig.parse(cfg.to_toml()) == cfg
-    # Explicit int still parses and round-trips.
-    cfg = RuntimeConfig.parse("[payload]\nserving_speculative = 6\n")
-    assert cfg.serving_speculative == 6
     assert RuntimeConfig.parse(cfg.to_toml()) == cfg
     assert RuntimeConfig.parse("").serving_window == 64
-    for bad in ("serving_window = 0", "serving_window = 2048",
-                "serving_speculative = 'always'",
-                "serving_speculative = -1"):
+    for bad in ("serving_window = 0", "serving_window = 2048"):
         with pytest.raises(RuntimeConfigError):
             RuntimeConfig.parse(f"[payload]\n{bad}\n")
+
+
+# The paged server does not speculate since PR 48: the three keys are
+# refused by name at any value but the default an older to_toml wrote.
+RETIRED = [("serving_speculative", "3", "0"),
+           ("serving_spec_window", "4", "0"),
+           ("serving_spec_sampled_window", "false", "true")]
+
+
+@pytest.mark.parametrize("key, value, default", RETIRED)
+def test_a_retired_speculation_key_is_refused_by_name(key, value, default):
+    with pytest.raises(RuntimeConfigError, match=key) as refused:
+        RuntimeConfig.parse(f"[payload]\nserving = 'paged'\n{key} = {value}\n")
+    assert "does not speculate since PR 48" in str(refused.value)
+    if key == "serving_speculative":
+        with pytest.raises(RuntimeConfigError, match=key):
+            RuntimeConfig.parse(f"[payload]\n{key} = 'auto'\n")
+
+
+@pytest.mark.parametrize("key, value, default", RETIRED)
+def test_a_retired_speculation_key_parses_at_its_old_default(key, value,
+                                                             default):
+    """A document an older ``to_toml`` wrote still parses, to what a
+    document without the key parses to; ``to_toml`` no longer writes
+    it, and the round trip holds."""
+    cfg = RuntimeConfig.parse(f"[payload]\n{key} = {default}\n")
+    assert cfg == RuntimeConfig.parse("")
+    assert not hasattr(cfg, key)
+    assert key not in cfg.to_toml()
+    assert RuntimeConfig.parse(cfg.to_toml()) == cfg
 
 
 def test_serving_trace_knob_round_trips_and_validates():

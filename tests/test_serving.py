@@ -859,124 +859,7 @@ def test_prefix_cache_load_is_boot_time_only(params, tmp_path):
         server.close()
 
 
-# ---- paged speculative decoding (round 4) --------------------------------
-
-
-def spec_server(params, **kw):
-    kw.setdefault("slots", 3)
-    kw.setdefault("pages", 60)
-    kw.setdefault("page_size", 4)
-    kw.setdefault("speculative", 4)
-    return PagedGenerationServer(params, CFG, **kw)
-
-
-def test_spec_concurrent_requests_each_match_generate(params):
-    """The exactness bar, spec edition: concurrent ragged greedy
-    requests through verify passes — repetitive prompts (drafts accept)
-    and arbitrary ones (drafts reject) — each equal their own
-    contiguous decode, and the realized acceleration is observable."""
-    server = spec_server(params)
-    requests = [
-        ([5, 9, 2, 5, 9, 2, 5, 9], 12),  # bigram-repetitive: accepts
-        ([1, 7, 3], 8),
-        ([42, 17, 8, 99, 3, 2, 1], 10),
-        ([6, 6, 6, 6, 6], 9),            # constant: accepts heavily
-    ]
-    results: dict[int, list[int]] = {}
-    errors: list[Exception] = []
-
-    def worker(i, prompt, n_new):
-        try:
-            results[i] = server.submit(prompt, n_new)
-        except Exception as e:
-            errors.append(e)
-
-    try:
-        threads = [
-            threading.Thread(target=worker, args=(i, p, n))
-            for i, (p, n) in enumerate(requests)
-        ]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join(timeout=300)
-        assert not errors, errors
-        for i, (p, n) in enumerate(requests):
-            assert results[i] == reference(params, p, n), i
-        stats = server.stats()
-        assert stats["spec_passes"] > 0
-        assert stats["spec_emitted_per_pass"] >= 1.0
-    finally:
-        server.close()
-
-
-def test_spec_budget_edge_and_page_boundaries(params):
-    """Acceptance overshooting the budget truncates exactly at n_new
-    (the client never sees overshoot tokens), including when the verify
-    window crosses page boundaries and when prompt + n_new == max_seq
-    (the draft slack must not shrink the servable request space)."""
-    server = spec_server(params, slots=2)
-    try:
-        # Constant prompt accepts aggressively; tiny budgets must cut
-        # exactly.
-        for n_new in (1, 2, 3, 5):
-            p = [6, 6, 6, 6]
-            assert server.submit(p, n_new) == reference(params, p, n_new)
-        # Full-length request: prompt + n_new == max_seq (64).
-        p = [3, 1, 4, 1, 5, 9, 2, 6] * 5  # 40 tokens
-        assert server.submit(p, 24) == reference(params, p, 24)
-    finally:
-        server.close()
-
-
-def test_spec_sampled_rides_verify_pass_exactly(params):
-    """A sampled request concurrent with greedy spec traffic advances
-    one token per pass with the SAME key schedule as the per-step path
-    — tokens equal a non-speculative paged server's."""
-    import jax
-
-    key = jax.random.fold_in(jax.random.PRNGKey(7), 0)
-    sampling = (key, jnp.float32(0.8), jnp.float32(0.9))
-    prompt_s, prompt_g = [9, 8, 7], [5, 9, 2, 5, 9, 2]
-
-    plain = PagedGenerationServer(params, CFG, slots=2, pages=24,
-                                  page_size=4)
-    try:
-        want_sampled = plain.submit(prompt_s, 6, sampling=sampling)
-    finally:
-        plain.close()
-
-    server = spec_server(params, slots=2)
-    results: dict = {}
-    try:
-        t = threading.Thread(
-            target=lambda: results.update(
-                g=server.submit(prompt_g, 8)
-            )
-        )
-        t.start()
-        results["s"] = server.submit(prompt_s, 6, sampling=sampling)
-        t.join(timeout=300)
-        assert results["s"] == want_sampled
-        assert results["g"] == reference(params, prompt_g, 8)
-    finally:
-        server.close()
-
-
-def test_spec_composes_with_prefix_sharing_and_streaming(params):
-    """Spec mode + prefix reuse + streaming: the second (shared-prefix,
-    streamed) request still matches contiguous decode token for token."""
-    server = spec_server(params, slots=2)
-    try:
-        base = [7, 3, 9, 1, 5, 5, 2, 8]
-        first = server.submit(base + [4, 6], n_new=6)
-        assert first == reference(params, base + [4, 6], 6)
-        streamed = list(server.submit_stream(base + [9, 9], n_new=6))
-        assert (base + [9, 9] + streamed
-                == reference(params, base + [9, 9], 6))
-        assert server.stats()["prefix_hits"] == 1
-    finally:
-        server.close()
+# ---- wide windows --------------------------------------------------------
 
 
 def test_multipage_window_matches_generate(params):
@@ -1045,107 +928,6 @@ def test_admission_joins_between_wide_windows(params):
         server.close()
 
 
-def test_spec_slack_reserved_only_for_greedy(params):
-    """Speculative slack accounting (VERDICT r4 #9): a SAMPLED request
-    under spec mode reserves exactly a plain request's page budget —
-    it can never accept a draft and the verify kernel drops its
-    draft-position scatters — while a greedy request reserves the
-    K-position slack."""
-    import jax
-
-    server = spec_server(params, slots=2)  # page_size=4, K=4
-    try:
-        key = jax.random.fold_in(jax.random.PRNGKey(3), 0)
-        sampling = (key, jnp.float32(0.8), jnp.float32(0.9))
-        # Sampled: 4 prompt + 8 new = 12 tokens -> 3 pages, NO slack.
-        # (Asserted on the request's own stored reservation — the
-        # aggregate gauge races request completion.)
-        hs = server.submit_stream([1, 2, 3, 4], n_new=8,
-                                  sampling=sampling)
-        assert hs._req.pages_reserved == 3
-        # Greedy: 12 tokens + 4 slack -> 4 pages.
-        hg = server.submit_stream([5, 6, 7, 8], n_new=8)
-        assert hg._req.pages_reserved == 4
-        list(hs)
-        list(hg)
-        # Both released their exact reservations: gauge returns to 0.
-        deadline = __import__("time").monotonic() + 30
-        while (server.stats()["reserved_pages"]
-               and __import__("time").monotonic() < deadline):
-            __import__("time").sleep(0.01)
-        assert server.stats()["reserved_pages"] == 0
-    finally:
-        server.close()
-
-
-def test_resolve_speculation_auto_fallback_and_override(params):
-    """The spec-mode guard rail (VERDICT r4 #7): when windowed decode
-    beats speculation's best case, auto mode turns speculation off,
-    explicit mode keeps it; both expose the decision in stats()."""
-    # Windows dominate: window/window_s = 640/s vs best (4+1)/verify_s
-    # = 50/s.
-    slow_spec = {"verify_s": 0.1, "window_s": 0.1, "probed_window": 64}
-    server = spec_server(params)
-    try:
-        decision = server.resolve_speculation(auto=True,
-                                              timings=slow_spec)
-        assert decision["windows_dominate"] is True
-        assert decision["mode"] == "windowed (auto fallback)"
-        assert server._spec == 0  # speculation actually off
-        assert server.stats()["spec_decision"]["mode"] == (
-            "windowed (auto fallback)"
-        )
-        # Greedy traffic now rides plain windows, still exact.
-        assert server.submit([5, 1, 5, 1], 6) == reference(
-            params, [5, 1, 5, 1], 6
-        )
-    finally:
-        server.close()
-
-    server = spec_server(params)
-    try:
-        decision = server.resolve_speculation(auto=False,
-                                              timings=slow_spec)
-        assert decision["mode"] == "speculative (operator override)"
-        assert server._spec == 4  # operator's choice kept
-        stats = server.stats()
-        assert stats["spec_decision"]["windows_dominate"] is True
-        assert stats["spec_draft_len"] == 4
-    finally:
-        server.close()
-
-    # Speculation wins (verify pass nearly free vs a slow window).
-    fast_spec = {"verify_s": 0.001, "window_s": 10.0,
-                 "probed_window": 64}
-    server = spec_server(params)
-    try:
-        decision = server.resolve_speculation(auto=True,
-                                              timings=fast_spec)
-        assert decision["windows_dominate"] is False
-        assert decision["mode"] == "speculative"
-        assert server._spec == 4
-    finally:
-        server.close()
-
-
-def test_resolve_speculation_real_probe_runs(params):
-    """The probe itself (no injected timings): runs real device ops on
-    the live cache, leaves no slot admitted, and returns coherent
-    timings."""
-    server = spec_server(params, slots=2)
-    try:
-        decision = server.resolve_speculation(auto=False)
-        assert decision["verify_ms"] > 0
-        assert decision["window_ms"] > 0
-        assert server.stats()["in_flight"] == 0
-        assert server._cache.free_pages() == 60  # everything released
-        # The server still serves correctly after the probe.
-        p = [6, 6, 6, 6]
-        assert server.submit(p, 5) == reference(params, p, 5)
-    finally:
-        server.close()
-
-
 def test_periodic_dump_survives_sigkill(params, tmp_path):
     """The kill drill (VERDICT r4 #10): a server with periodic prefix
     persistence is SIGKILL'd mid-serve — no drain, no close — and a
@@ -1207,26 +989,3 @@ while True:  # hold the pool live until the parent SIGKILLs us
         fresh.close()
 
 
-def test_disable_speculation_unmeasured(params):
-    """The slice path's "auto" resolution: unmeasured speculation turns
-    off (with the reason recorded), and in-flight accounting stays
-    symmetric — a greedy request admitted with slack BEFORE the
-    disable still releases exactly what it reserved."""
-    server = spec_server(params, slots=2)
-    try:
-        # Greedy admitted with slack: 4 prompt + 8 new + 4 slack -> 4
-        # pages at page_size 4.
-        h = server.submit_stream([1, 2, 3, 4], n_new=8)
-        assert server.stats()["reserved_pages"] == 4
-        decision = server.disable_speculation("auto unmeasured on a slice")
-        assert decision["mode"] == "windowed (auto unmeasured on a slice)"
-        assert server._spec == 0
-        list(h)  # decode out; release must drop the SLACKED reservation
-        deadline = __import__("time").monotonic() + 30
-        while (server.stats()["reserved_pages"]
-               and __import__("time").monotonic() < deadline):
-            __import__("time").sleep(0.01)
-        assert server.stats()["reserved_pages"] == 0
-        assert server.stats()["spec_decision"]["windows_dominate"] is None
-    finally:
-        server.close()

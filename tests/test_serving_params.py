@@ -42,9 +42,8 @@ def _same(a, b, what):
 
 def _programs(cfg, params):
     """Every kind of paged program once over ``params``: a prompt in
-    two prefill chunks, a single step, a decode window and a speculative
-    verify pass. Returns what each hands back, logits where it has
-    them."""
+    two prefill chunks, a single step and a decode window. Returns
+    what each hands back, logits where it has them."""
     cache = PagedKVCache(cfg, slots=2, pages=16, page_size=4)
     prompt = jnp.asarray([5, 9, 2, 7, 1, 3, 8, 4], jnp.int32)
     cache.admit(0, 8)
@@ -57,14 +56,6 @@ def _programs(cfg, params):
     pending = jnp.argmax(out["step"], axis=-1).astype(jnp.int32)
     out["window"] = cache.harvest_window(
         cache.dispatch_window(params, pending, 4, active))[:4]
-    cache.drop_carry()
-    last = jnp.int32(out["window"][-1, 0])
-    draft = jnp.stack([jnp.stack([last, last, last]),
-                       jnp.zeros((3,), jnp.int32)])
-    emitted, accepted, logits0 = cache.step_spec(
-        params, draft, active, np.array([True, False]))
-    out.update(spec_emitted=emitted[0], spec_accepted=accepted[0],
-               spec_logits=logits0[0])
     return out
 
 
@@ -89,28 +80,25 @@ def test_cast_tree_is_bit_identical_to_the_masters(block):
     for name in want:
         _same(want[name], got[name], f"{block}: {name}")
 
-    # The same through the server: chunked prefill, windows, and the
-    # speculative path, by the tokens a client reads.
+    # The same through the server: chunked prefill and windows, by
+    # the tokens a client reads.
     prompts = [[3, 1, 4, 1, 5, 9, 2, 6], [2, 7, 1, 8, 2, 8]]
     served = {}
     for tree_name, tree in (("masters", masters), ("cast", cast)):
-        for spec in (0, 2):
-            server = PagedGenerationServer(
-                tree, cfg, slots=2, pages=32, page_size=4,
-                prefill_chunk=4, window=4, speculative=spec)
-            try:
-                served[tree_name, spec] = [
-                    server.submit(p, n_new=9) for p in prompts]
-                stats = server.stats()
-            finally:
-                server.close()
-            assert stats["weights_dtype"] == (
-                "float32" if tree_name == "masters" else "bfloat16")
-            assert stats["weights_gb"] == pytest.approx(sum(
-                a.size * a.dtype.itemsize for a in tree.values()) / 1e9)
-    for spec in (0, 2):
-        assert served["masters", spec] == served["cast", spec]
-    assert served["cast", 0] == served["cast", 2]
+        server = PagedGenerationServer(
+            tree, cfg, slots=2, pages=32, page_size=4,
+            prefill_chunk=4, window=4)
+        try:
+            served[tree_name] = [
+                server.submit(p, n_new=9) for p in prompts]
+            stats = server.stats()
+        finally:
+            server.close()
+        assert stats["weights_dtype"] == (
+            "float32" if tree_name == "masters" else "bfloat16")
+        assert stats["weights_gb"] == pytest.approx(sum(
+            a.size * a.dtype.itemsize for a in tree.values()) / 1e9)
+    assert served["masters"] == served["cast"]
 
 
 def test_float32_config_is_served_as_it_is():

@@ -357,24 +357,28 @@ def _decode_pair(params, server, label):
     return greedy, sampled
 
 
-@pytest.mark.parametrize("shape", [
-    dict(window=1),
-    dict(),
-    dict(speculative=3, spec_window=2),
-], ids=["one-step", "overlap", "spec-window"])
-def test_observability_on_is_token_bit_identical(params, shape):
+@pytest.mark.parametrize("block, shape", [
+    ("", dict(window=1)),
+    ("", dict()),
+    ("window-block", dict(window=4, prefix_cache=False)),
+], ids=["one-step", "overlap", "window-block"])
+def test_observability_on_is_token_bit_identical(params, probe_blocks,
+                                                 block, shape):
     """The acceptance bar: SLO engine + occupancy ring + full-sample
     tracing all ON change no served token — greedy and sampled, at
-    one-step windows and the default, device-resident spec windows
-    included."""
-    off_server = PagedGenerationServer(params, CFG, slots=2, pages=32,
+    one-step windows and the default, and on a window block
+    (``probe_blocks``), whose occupancy counts a second pool."""
+    cfg = CFG
+    if block:
+        cfg, params = probe_blocks[block]
+    off_server = PagedGenerationServer(params, cfg, slots=2, pages=32,
                                        **shape)
     try:
         off = _decode_pair(params, off_server, "off")
     finally:
         off_server.close()
     on_server = PagedGenerationServer(
-        params, CFG, slots=2, pages=32, tracer=Tracer(sample=1.0),
+        params, cfg, slots=2, pages=32, tracer=Tracer(sample=1.0),
         **_OBS, **shape,
     )
     try:
@@ -385,7 +389,8 @@ def test_observability_on_is_token_bit_identical(params, shape):
     assert off == on, f"observability changed tokens ({shape})"
     assert stats["slo_snapshots_total"] >= 1
     assert stats["occupancy_samples_total"] >= 1
-    assert off[0] == reference(params, [5, 9, 2, 7], 9)
+    if not block:
+        assert off[0] == reference(params, [5, 9, 2, 7], 9)
 
 
 def test_device_time_itl_and_occupancy_fill(params):

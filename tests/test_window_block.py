@@ -553,14 +553,10 @@ def test_the_reference_rotates_as_the_program_does():
 # ---- what cannot run it, and what it counts --------------------------------
 
 
-@pytest.mark.parametrize("kw, named", [
-    ({"prefix_cache": True}, "shared page that a 'window' layer"),
-    ({"speculative": 3}, "drafted position's page"),
-])
-def test_the_server_refuses_prefix_cache_and_speculation(cfg, params, kw,
-                                                         named):
-    with pytest.raises(ValueError, match=named):
-        server_of(params, cfg, **kw)
+def test_the_server_refuses_the_prefix_cache(cfg, params):
+    with pytest.raises(ValueError,
+                       match="shared page that a 'window' layer"):
+        server_of(params, cfg, prefix_cache=True)
 
 
 @pytest.mark.parametrize("payload, named", [

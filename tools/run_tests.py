@@ -41,11 +41,9 @@ Usage::
                                          # tests (-m trace: flight
                                          # recorder, Chrome export,
                                          # bit-identity); fast, tier-1
-    python tools/run_tests.py --window   # only the device-resident
-                                         # spec-window tests (-m window:
-                                         # windowed-spec bit-identity +
-                                         # the paged kernel's exactness/
-                                         # agreement pins); fast, tier-1
+    python tools/run_tests.py --window   # only the paged kernel's
+                                         # exactness/agreement pins
+                                         # (-m window); fast, tier-1
     python tools/run_tests.py --capacity # only the capacity-driven
                                          # batching tests (-m capacity:
                                          # bucketed compile cache, HBM
@@ -53,7 +51,7 @@ Usage::
                                          # resume); fast, tier-1
     python tools/run_tests.py --endgame  # only the device-resident
                                          # endgame composition tests
-                                         # (-m endgame: sampled spec
+                                         # (-m endgame: sampled
                                          # windows, device stop
                                          # finishes, composed with
                                          # preempt/revive/buckets);
@@ -224,10 +222,8 @@ def main(argv: list[str] | None = None) -> int:
                     help="run only the request-tracing tests "
                          "(forwards -m trace)")
     ap.add_argument("--window", action="store_true",
-                    help="run only the device-resident spec-window "
-                         "tests (forwards -m window: windowed-spec "
-                         "bit-identity, composition, and the paged "
-                         "kernel exactness pins)")
+                    help="run only the paged kernel exactness pins "
+                         "(forwards -m window)")
     ap.add_argument("--capacity", action="store_true",
                     help="run only the capacity-driven batching tests "
                          "(forwards -m capacity: bucketed compile "
@@ -242,7 +238,7 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--endgame", action="store_true",
                     help="run only the device-resident endgame "
                          "composition tests (forwards -m endgame: "
-                         "sampled spec windows, device stop finishes, "
+                         "sampled windows, device stop finishes, "
                          "composed with preempt/revive/bucketing)")
     ap.add_argument("--prefix", action="store_true",
                     help="run only the prefix-cache tests (forwards "
